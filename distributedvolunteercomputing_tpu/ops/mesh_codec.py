@@ -59,13 +59,16 @@ CPU-platform tier-1 run never pays a jit compile it didn't ask for.
 
 Degraded-slice fallback (mesh-networks paper, PAPERS.md: slice-level
 failures are a normal operating mode, not a crash): every device op runs
-through ``_run``, and the FIRST failure — a chip dropping out of the local
-mesh, a PJRT error, an injected chaos fault — permanently degrades this
-codec to the host backend, replays the failed op on host, and surfaces the
-reason in ``stats()``. Mid-round state is handled by the callers: the
-stateless codec ops re-run losslessly; ``MeshMeanFolder`` pulls its last
-good device accumulator back to host and keeps folding there, so a round
-in flight COMMITS through a mesh shrink instead of dying with it.
+through ``_run``, and the FIRST device failure — a chip dropping out of the
+local mesh, a PJRT runtime error, an injected chaos fault — permanently
+degrades this codec to the host backend, replays the failed op on host, and
+surfaces the reason in ``stats()``. Mid-round state is handled by the
+callers: the stateless codec ops re-run losslessly; ``MeshMeanFolder`` pulls
+its last good device accumulator back to host and keeps folding there, so a
+round in flight COMMITS through a mesh shrink instead of dying with it.
+A kernel that fails to trace or compile is NOT a lost device: its first
+call raises ``MeshKernelError`` (``_jit``), which ``_run`` lets through, so
+a broken kernel stops the volunteer instead of hiding behind the host path.
 """
 
 from __future__ import annotations
@@ -89,6 +92,11 @@ FOLDER_FLUSH_BYTES = 16 << 20
 
 class MeshCodecError(RuntimeError):
     """An injected (chaos) or real device failure inside a mesh op."""
+
+
+class MeshKernelError(RuntimeError):
+    """A device program failed to trace or compile on its first call — a
+    bug in the kernel or the installation, never absorbed as a degrade."""
 
 
 def _batcher_pairs(m: int) -> List[Tuple[int, int]]:
@@ -127,8 +135,7 @@ def _batcher_pairs(m: int) -> List[Tuple[int, int]]:
 # blocking keeps the codec's VMEM footprint bounded and off the train
 # step's working set. They are gated (``_pallas_mode``): compiled on TPU
 # silicon, interpreted under DVC_MESH_PALLAS=interpret (CPU equivalence
-# tests), and skipped otherwise — a Pallas failure falls back to the jnp
-# body, never to the host.
+# tests), and skipped otherwise.
 
 _PALLAS_LANES = 128
 _PALLAS_ROWS = 512  # block = (512, 128) f32 -> 256 KB VMEM per operand
@@ -185,6 +192,7 @@ class MeshCodec:
         self._codec_mesh = None  # built lazily on first device op
         self._ndev = 1
         self._jit_cache: Dict[tuple, Callable] = {}
+        self._compiled_once: set = set()  # (key, arg signature), see _jit
         self.degraded = False
         self.degrade_reason = ""
         self._fail_injected = 0
@@ -222,13 +230,9 @@ class MeshCodec:
             return "host"
         if env in ("1", "mesh", "on"):
             return "mesh"
-        try:
-            from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
+        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
-            return "mesh" if tpu_backend() else "host"
-        except Exception as e:  # noqa: BLE001 — no usable jax == host codec
-            log.debug("mesh codec auto-select failed (%s); using host", errstr(e))
-            return "host"
+        return "mesh" if tpu_backend() else "host"
 
     @staticmethod
     def _resolve_pallas(pallas: Optional[str]) -> str:
@@ -239,12 +243,9 @@ class MeshCodec:
             return {"1": "compiled", "on": "compiled", "0": "off", "off": "off"}.get(
                 pallas, "interpret"
             )
-        try:
-            from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
+        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
-            return "compiled" if tpu_backend() else "off"
-        except Exception:  # noqa: BLE001
-            return "off"
+        return "compiled" if tpu_backend() else "off"
 
     @staticmethod
     def _resolve_collective(collective: Optional[str]) -> str:
@@ -262,12 +263,9 @@ class MeshCodec:
             return "off"
         if collective != "auto":
             raise ValueError(f"unknown mesh collective {collective!r}")
-        try:
-            from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
+        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
-            return "ring" if tpu_backend() else "off"
-        except Exception:  # noqa: BLE001 — no usable jax == no collective
-            return "off"
+        return "ring" if tpu_backend() else "off"
 
     @property
     def backend(self) -> str:
@@ -332,22 +330,28 @@ class MeshCodec:
 
     def _run(self, op: Callable, host: Callable):
         """Run ``op`` on device, falling back to ``host`` (and permanently
-        degrading) on ANY failure. The stateless codec ops lose nothing in
-        the fallback — the same inputs re-run on host."""
+        degrading) when the DEVICE fails: a runtime error out of the
+        backend, or an injected fault. The stateless codec ops lose nothing
+        in the fallback — the same inputs re-run on host. Anything else —
+        ``MeshKernelError`` from a program that would not trace or compile,
+        a Python error in the op — is a bug and propagates."""
         if not self.active:
             self.ops_host += 1
             return host()
+        import jax
+
         t0 = time.perf_counter()
         try:
             self._check_injected()
             out = op()
-            self.device_s += time.perf_counter() - t0
-            self.ops_mesh += 1
-            return out
-        except Exception as e:  # noqa: BLE001 — chip loss must not kill the round
+        except (MeshCodecError, jax.errors.JaxRuntimeError) as e:
+            # Chip loss must not kill the round.
             self._degrade(e)
             self.ops_host += 1
             return host()
+        self.device_s += time.perf_counter() - t0
+        self.ops_mesh += 1
+        return out
 
     # -- device plumbing ---------------------------------------------------
 
@@ -404,35 +408,42 @@ class MeshCodec:
         return jax.device_put(stack, self._sharding(P(None, "codec"))), t
 
     def _jit(self, key: tuple, build: Callable[[], Callable]) -> Callable:
+        """The cached device program for ``key``. Its first call per
+        argument signature is the one that traces and compiles (execution
+        is dispatched asynchronously and fails later, at the host read): a
+        failure there raises ``MeshKernelError`` so ``_run`` cannot mistake
+        a kernel the compiler refuses for a lost chip."""
         fn = self._jit_cache.get(key)
         if fn is None:
-            fn = self._jit_cache[key] = build()
+            program = build()
+
+            def fn(*args):
+                sig = (key, tuple((a.shape, a.dtype) for a in args))
+                if sig in self._compiled_once:
+                    return program(*args)
+                try:
+                    out = program(*args)
+                except Exception as e:
+                    raise MeshKernelError(
+                        f"mesh codec program {key} failed on first use: {errstr(e)}"
+                    ) from e
+                self._compiled_once.add(sig)
+                return out
+
+            self._jit_cache[key] = fn
         return fn
 
     def _shard_map(self, fn, in_specs, out_specs, **jit_kw):
         """jit(shard_map(fn)) over the codec mesh — the SNIPPETS.md [2]
         wrapping pattern. All codec ops are elementwise over the sharded
         dim, so replication checking has nothing to reject; it stays off to
-        keep scatter ops eligible. Spans the jax API split: ``jax.shard_map``
-        (new, check_vma) when present, ``jax.experimental.shard_map``
-        (0.4.x, check_rep) otherwise — tier-1 runs on the old API and the
-        MULTICHIP driver on the new one."""
+        keep scatter ops eligible."""
         import jax
 
-        mesh = self._ensure_mesh()
-        sm = getattr(jax, "shard_map", None)
-        if sm is not None:
-            try:
-                wrapped = sm(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-            except TypeError:  # intermediate versions: no check_vma kwarg
-                wrapped = sm(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-        else:
-            from jax.experimental.shard_map import shard_map
-
-            wrapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=False)
+        wrapped = jax.shard_map(
+            fn, mesh=self._ensure_mesh(), in_specs=in_specs,
+            out_specs=out_specs, check_vma=False,
+        )
         return jax.jit(wrapped, **jit_kw)
 
     # -- pallas inner bodies ----------------------------------------------

@@ -27,12 +27,16 @@ round result crosses the host link once.
 Lowering ladder (``DVC_RING_LOWER`` overrides; auto follows the codec's
 pallas mode):
 
-- ``compiled``  — the Pallas kernel on TPU silicon, remote DMA + a REGULAR
-  capacity-semaphore handshake (a partial slot is overwritten only after
-  its last send completed; the interpreter serializes and needs none).
+- ``compiled``  — the Pallas kernel on TPU silicon: remote DMA, an entry
+  barrier with both ring neighbors, and a REGULAR capacity-semaphore
+  handshake (a partial slot is overwritten only after its last send
+  completed; the interpreter serializes and needs neither). Compiled for a
+  described v5e by tests/test_tpu_compile.py, run against the host fold on
+  four chips by ``chip_smoke.py --chips 4``.
 - ``interpret`` — the SAME kernel body interpreted on CPU: tier-1 tests and
-  the MULTICHIP dryrun gate cover the exact grid schedule, DMA descriptors,
-  and fold math bit-for-bit against the host path.
+  the dryrun_multichip gate cover the exact grid schedule, DMA descriptors,
+  and fold math bit-for-bit against the host path. The interpreter accepts
+  what Mosaic refuses, so it says nothing about whether the kernel compiles.
 - ``xla``       — the same math and placement with the collective lowered
   by XLA (``lax.psum_scatter`` / ``lax.all_gather``) instead of the hand
   ring: the fast CPU lowering (interpret-mode Pallas is a Python emulator)
@@ -68,6 +72,7 @@ log = logging.getLogger("dvc.mesh_collective")
 _VMEM_CAP_BYTES = int(
     float(os.environ.get("DVC_RING_VMEM_MB", "10")) * (1 << 20)
 )
+_LANES = 128  # vector lane width: the kernel's (rows, lanes) block minor dim
 
 
 def ring_available(codec) -> bool:
@@ -84,12 +89,25 @@ def ring_available(codec) -> bool:
 # ---------------------------------------------------------------------------
 
 
+def _neighbor_barrier(left, right):
+    """Both ring neighbors are inside this kernel before anything is
+    written into their memory: a remote DMA or semaphore signal that lands
+    before the target entered would hit whatever lives at those addresses."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    barrier = pltpu.get_barrier_semaphore()
+    for peer in (left, right):
+        pltpu.semaphore_signal(
+            barrier, 1, device_id=peer, device_id_type=pltpu.DeviceIdType.LOGICAL
+        )
+    pltpu.semaphore_wait(barrier, 2)
+
+
 def _ring_fold_kernel(
     nd,
     per_dev,
-    shard,
     n_tiles,
-    handshake,
+    compiled,
     tiles_ref,
     ws_ref,
     bits_ref,
@@ -97,6 +115,8 @@ def _ring_fold_kernel(
     o_ref,
     buf_ref,
     ctmp_ref,
+    bits_vmem,
+    bits_sem,
     send_sem,
     recv_sem,
     cap_sem,
@@ -105,14 +125,23 @@ def _ring_fold_kernel(
 
     Device ``d`` at step ``s`` works shard ``b = (d - s - 1) mod nd``: it
     starts the DMA forwarding step ``s-1``'s partial to the right neighbor,
-    then (while that DMA is in flight) decodes its local chunks' ``b``-slice
-    and folds it into the scratch partial, then waits the DMA and adds the
-    scratch into the freshly received slot. The partial for shard ``b``
-    terminates at device ``b`` on the last step, where it folds into the
-    resident accumulator shard. ``handshake`` (compiled mode) closes the
-    one-step-ahead race: a slot is re-targeted only after the right
-    neighbor confirms its send from that slot completed — the interpreter
-    has no remote signal and serializes safely without it.
+    then (while that DMA is in flight) pulls its local chunks' ``b``-slice
+    from HBM, decodes it and folds it into the scratch partial, then waits
+    the DMA and adds the scratch into the freshly received slot. The
+    partial for shard ``b`` terminates at device ``b`` on the last step,
+    where it folds into the resident accumulator shard.
+
+    Layout: every (tile, shard) slice is a ``(rows, lanes)`` block, so the
+    vector work runs on full (sublane, lane) tiles and tiles/chunks/shards
+    are picked by dynamic index on untiled leading dims only. The wire bits
+    stay in HBM (``bits_ref``, ``[per_dev, nd, rows, lanes]``) and reach
+    VMEM by DMA; partials, accumulator and output live in VMEM.
+
+    ``compiled`` adds what only silicon needs: the entry barrier, and the
+    capacity handshake closing the one-step-ahead race (a slot is
+    re-targeted only after the right neighbor confirms its send from that
+    slot completed). The interpreter executes each DMA at ``start`` in
+    lockstep across devices and has no remote semaphore signal.
     """
     import jax
     from jax.experimental import pallas as pl
@@ -127,6 +156,8 @@ def _ring_fold_kernel(
     prev = jax.lax.rem(s + 1, 2)
     b = jax.lax.rem(d - s - 1 + 2 * nd, nd)
 
+    load = pltpu.make_async_copy(bits_ref.at[:, b], bits_vmem, bits_sem)
+    load.start()
     fwd = pltpu.make_async_remote_copy(
         src_ref=buf_ref.at[prev],
         dst_ref=buf_ref.at[slot],
@@ -136,7 +167,11 @@ def _ring_fold_kernel(
         device_id_type=pltpu.DeviceIdType.LOGICAL,
     )
 
-    if handshake:
+    if compiled:
+
+        @pl.when(s == 0)
+        def _enter():
+            _neighbor_barrier(left, right)
 
         @pl.when(s > 0)
         def _window_open():
@@ -151,60 +186,49 @@ def _ring_fold_kernel(
     # Fused decode+fold for this step's shard slice — runs while the DMA is
     # in flight. Across the nd grid steps the slices partition tile_elems,
     # so every wire element is decoded exactly once.
-    ctmp_ref[...] = jnp.zeros((n_tiles, shard), jnp.float32)
+    ctmp_ref[...] = jnp.zeros(ctmp_ref.shape, jnp.float32)
+    load.wait()
 
     def _fold_one(i, carry):
         t = tiles_ref[i]
-        w = ws_ref[i]
-        bits = pl.load(bits_ref, (pl.ds(i, 1), pl.ds(b * shard, shard)))
-        row = pl.load(ctmp_ref, (pl.ds(t, 1), slice(None)))
-        pl.store(
-            ctmp_ref,
-            (pl.ds(t, 1), slice(None)),
-            row + w * _bf16_widen(bits),
-        )
+        ctmp_ref[t] = ctmp_ref[t] + ws_ref[i] * _bf16_widen(bits_vmem[i])
         return carry
 
     jax.lax.fori_loop(0, per_dev, _fold_one, 0)
 
     @pl.when(s == 0)
     def _seed():
-        pl.store(
-            buf_ref,
-            (pl.ds(0, 1), slice(None), slice(None)),
-            ctmp_ref[...][None],
-        )
+        buf_ref[0] = ctmp_ref[...]
 
     @pl.when(s > 0)
     def _accumulate():
         fwd.wait()
-        got = pl.load(buf_ref, (pl.ds(slot, 1), slice(None), slice(None)))
-        pl.store(
-            buf_ref,
-            (pl.ds(slot, 1), slice(None), slice(None)),
-            got + ctmp_ref[...][None],
-        )
+        buf_ref[slot] = buf_ref[slot] + ctmp_ref[...]
 
-    if handshake:
+    if compiled:
 
         @pl.when(s < nd - 1)
         def _window_grant():
             # My send from buf[prev] completed (fwd.wait above covers the
             # send side at s>0; at s==0 the slot is virgin): the left
             # neighbor may target it next step.
-            pltpu.semaphore_signal(cap_sem, 1, device_id=left)
+            pltpu.semaphore_signal(
+                cap_sem, 1, device_id=left,
+                device_id_type=pltpu.DeviceIdType.LOGICAL,
+            )
 
     @pl.when(s == nd - 1)
     def _emit():
-        final = pl.load(buf_ref, (pl.ds(slot, 1), slice(None), slice(None)))
-        o_ref[...] = acc_ref[...] + final[0]
+        o_ref[...] = acc_ref[...] + buf_ref[slot]
 
 
-def _ring_ag_kernel(nd, x_ref, o_ref, send_sem, recv_sem):
+def _ring_ag_kernel(nd, compiled, x_ref, o_ref, copy_sem, send_sem, recv_sem):
     """Ring all-gather: step ``s`` forwards the block received at ``s-1``
-    (own block at ``s==0``) to the right neighbor. Every step's DMA targets
-    a distinct block slot on the receiver, so no capacity handshake is
-    needed — the send/recv semaphores alone order the chain."""
+    (own block at ``s==0``) to the right neighbor. Input and output stay in
+    HBM and every move is a DMA, so the kernel holds nothing in VMEM. Every
+    step's DMA targets a distinct block slot on the receiver, so no
+    capacity handshake is needed — the send/recv semaphores alone order
+    the chain."""
     import jax
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -216,11 +240,11 @@ def _ring_ag_kernel(nd, x_ref, o_ref, send_sem, recv_sem):
 
     @pl.when(s == 0)
     def _own():
-        pl.store(
-            o_ref,
-            (pl.ds(d, 1), slice(None), slice(None)),
-            x_ref[...][None],
-        )
+        own = pltpu.make_async_copy(x_ref, o_ref.at[d], copy_sem)
+        own.start()
+        own.wait()
+        if compiled:
+            _neighbor_barrier(jax.lax.rem(d + nd - 1, nd), right)
 
     fwd = pltpu.make_async_remote_copy(
         src_ref=o_ref.at[blk],
@@ -292,12 +316,13 @@ class RingMeanFolder(MeshMeanFolder):
     def _lower_for(self, per_dev: int) -> str:
         """The flush lowering for one batch size: compiled falls back to
         xla when the kernel working set would blow VMEM (two partial slots
-        + scratch partial + acc shard + out, f32, plus the u16 bits)."""
+        + scratch partial + acc shard + out, f32, plus one shard slice of
+        the local chunks' u16 bits)."""
         lower = self._lower_cfg
         if lower != "compiled":
             return lower
         buf_bytes = self.n_tiles * self.shard * 4
-        est = 5 * buf_bytes + 2 * per_dev * self.tile_elems
+        est = 5 * buf_bytes + 2 * per_dev * self.shard
         if est > _VMEM_CAP_BYTES:
             self._note_vmem_fallback("flush", est)
             return "xla"
@@ -456,6 +481,14 @@ class RingMeanFolder(MeshMeanFolder):
             body, in_specs, P(None, "codec"), donate_argnums=(0,)
         )
 
+    def _block(self) -> Tuple[int, int, int]:
+        """The accumulator shard as the kernels see it: ``(n_tiles, rows,
+        lanes)``, each (tile, shard) slice one (rows, lanes) block — full
+        vector tiles where the shard allows it (always, at the real 1 MiB
+        wire chunk), with tiles on an untiled leading dim."""
+        lanes = _LANES if self.shard % _LANES == 0 else self.shard
+        return (self.n_tiles, self.shard // lanes, lanes)
+
     def _build_flush(self, lower: str, per_dev: int):
         import jax
         from jax.sharding import PartitionSpec as P
@@ -465,9 +498,6 @@ class RingMeanFolder(MeshMeanFolder):
         nd = codec._ndev
         shard = self.shard
         n_tiles = self.n_tiles
-        tile_elems = self.tile_elems
-
-        del tile_elems  # width only flows through nd * shard below
 
         if lower == "xla":
 
@@ -489,36 +519,45 @@ class RingMeanFolder(MeshMeanFolder):
                 return a.at[t_].add(w_[:, None] * _bf16_widen(mine))
 
         else:
-            interp = lower == "interpret"
+            compiled = lower == "compiled"
+            block = self._block()
+            _, rows, lanes = block
             kern = functools.partial(
-                _ring_fold_kernel, nd, per_dev, shard, n_tiles, not interp
+                _ring_fold_kernel, nd, per_dev, n_tiles, compiled
             )
 
             def body(a, x_, t_, w_):
                 from jax.experimental import pallas as pl
                 from jax.experimental.pallas import tpu as pltpu
 
-                return pl.pallas_call(
+                out = pl.pallas_call(
                     kern,
                     grid=(nd,),
-                    out_shape=jax.ShapeDtypeStruct((n_tiles, shard), jnp.float32),
+                    out_shape=jax.ShapeDtypeStruct(block, jnp.float32),
                     in_specs=[
                         pl.BlockSpec(memory_space=pltpu.SMEM),
                         pl.BlockSpec(memory_space=pltpu.SMEM),
-                        pl.BlockSpec(memory_space=pltpu.ANY),
-                        pl.BlockSpec(memory_space=pltpu.ANY),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                        pl.BlockSpec(memory_space=pltpu.VMEM),
                     ],
-                    out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+                    out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
                     scratch_shapes=[
-                        pltpu.VMEM((2, n_tiles, shard), jnp.float32),
-                        pltpu.VMEM((n_tiles, shard), jnp.float32),
+                        pltpu.VMEM((2,) + block, jnp.float32),
+                        pltpu.VMEM(block, jnp.float32),
+                        pltpu.VMEM((per_dev, rows, lanes), jnp.uint16),
+                        pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.DMA,
                         pltpu.SemaphoreType.REGULAR,
                     ],
-                    interpret=interp,
-                    compiler_params=_compiler_params(interp),
-                )(t_, w_, x_, a)
+                    interpret=not compiled,
+                    compiler_params=_compiler_params(compiled, collective_id=0),
+                )(
+                    t_, w_,
+                    x_.reshape(per_dev, nd, rows, lanes),
+                    a.reshape(block),
+                )
+                return out.reshape(n_tiles, shard)
 
         # The pallas ring folds each device's OWN chunks step by step
         # (tiles/ws row-sharded); the xla all_to_all hands every device all
@@ -535,8 +574,8 @@ class RingMeanFolder(MeshMeanFolder):
 
     def result(self) -> np.ndarray:
         """Flush the tail, then reassemble the sharded accumulator with the
-        ring all-gather — one device pass, one host fetch. Falls back to
-        the inherited sharded host gather on any device failure. The xla
+        ring all-gather — one device pass, one host fetch. A device lost
+        mid-gather degrades to the inherited sharded host gather. The xla
         lowering skips the device all-gather: XLA's host pull of a sharded
         array already fetches each shard exactly once, and replicating the
         full accumulator on every device first is pure extra traffic."""
@@ -567,56 +606,36 @@ class RingMeanFolder(MeshMeanFolder):
         nd = codec._ndev
         shard = self.shard
         n_tiles = self.n_tiles
-        lower = self._lower_cfg
-        gather_bytes = 2 * nd * n_tiles * shard * 4
-        if lower == "compiled" and gather_bytes > _VMEM_CAP_BYTES:
-            self._note_vmem_fallback("gather", gather_bytes)
-            lower = "xla"
+        compiled = self._lower_cfg == "compiled"
+        block = self._block()
+        kern = functools.partial(_ring_ag_kernel, nd, compiled)
 
-        if lower == "xla":
+        def body(a):
+            from jax.experimental import pallas as pl
+            from jax.experimental.pallas import tpu as pltpu
 
-            def body(a):
-                return jax.lax.all_gather(a, "codec", axis=1, tiled=True)
-
-        else:
-            interp = lower == "interpret"
-            kern = functools.partial(_ring_ag_kernel, nd)
-
-            def body(a):
-                from jax.experimental import pallas as pl
-                from jax.experimental.pallas import tpu as pltpu
-
-                o = pl.pallas_call(
-                    kern,
-                    grid=(nd - 1,),
-                    out_shape=jax.ShapeDtypeStruct(
-                        (nd, n_tiles, shard), jnp.float32
-                    ),
-                    in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-                    out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-                    scratch_shapes=[
-                        pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA,
-                    ],
-                    interpret=interp,
-                    compiler_params=_compiler_params(interp),
-                )(a)
-                return jnp.swapaxes(o, 0, 1).reshape(n_tiles, nd * shard)
+            o = pl.pallas_call(
+                kern,
+                grid=(nd - 1,),
+                out_shape=jax.ShapeDtypeStruct((nd,) + block, jnp.float32),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.SemaphoreType.DMA] * 3,
+                interpret=not compiled,
+                compiler_params=_compiler_params(compiled, collective_id=1),
+            )(a.reshape(block))
+            o = o.reshape(nd, n_tiles, shard)
+            return jnp.swapaxes(o, 0, 1).reshape(n_tiles, nd * shard)
 
         return codec._shard_map(body, (P(None, "codec"),), P(None, None))
 
 
-def _compiler_params(interp: bool):
-    """Mark the kernel side-effecting for the compiled lowering (remote
-    DMA + semaphores must not be DCE'd); the interpreter takes none."""
-    if interp:
+def _compiler_params(compiled: bool, collective_id: int):
+    """The compiled lowering's kernels signal neighbors (barrier semaphore
+    ``collective_id``) and move data by remote DMA, which must not be
+    DCE'd; the interpreter takes no params."""
+    if not compiled:
         return None
-    try:
-        from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas import tpu as pltpu
 
-        params = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams", None
-        )
-        return params(has_side_effects=True) if params else None
-    except Exception:  # noqa: BLE001 — params are a silicon-only hint
-        return None
+    return pltpu.CompilerParams(has_side_effects=True, collective_id=collective_id)

@@ -7,23 +7,20 @@ backward is the standard two-kernel flash recomputation (dq from k-blocks,
 dk/dv from q-blocks) using the saved logsumexp, wired up through
 ``jax.custom_vjp``.
 
-r5 redesign, motivated by the r4 hardware sweep
-(experiments/results/attn_sweep.json):
+Design (speed not measured on the current code; numerics checked on the
+v5e by chip_smoke.py):
 
 - **K/V stream through the GRID** (innermost "arbitrary" dimension) with
   online-softmax state in VMEM scratch, instead of pulling the whole key
-  sequence into VMEM per grid step. VMEM footprint is now O(block) not
-  O(T), and the Mosaic program is one small k-block body regardless of
-  sequence length — the r4 kernel's full-[T, D] windows were the prime
-  suspect for the remote-compile failures at f32 T>=4096 / bf16 T=8192
-  (the shapes where XLA cliffs to 360 ms and flash exists to win).
+  sequence into VMEM per grid step. VMEM footprint is O(block) not O(T),
+  and the Mosaic program is one small k-block body regardless of sequence
+  length.
 - **Matmuls run in the INPUT dtype** (``preferred_element_type=f32``
-  accumulation). The r4 kernel upcast q/k/v to f32 before every dot,
-  forcing f32 MXU throughput — the measured reason flash LOST to XLA in
-  bf16 at T=512-2048 (0.56-0.94x). bf16 x bf16 products are exact in the
-  f32 accumulator, so the bf16 path loses no precision on the score
-  matmul; the p @ v / gradient matmuls round p/ds to the input dtype (the
-  standard flash trade, applied only when inputs are sub-f32).
+  accumulation), so bf16 inputs hit the MXU at its bf16 rate. bf16 x bf16
+  products are exact in the f32 accumulator, so the bf16 path loses no
+  precision on the score matmul; the p @ v / gradient matmuls round p/ds
+  to the input dtype (the standard flash trade, applied only when inputs
+  are sub-f32).
 - Causal blocks that are fully masked skip their compute via ``pl.when``
   (the grid still visits them — index-remapping them away is not worth
   the complexity at these shapes).
@@ -44,6 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
+
 NEG_INF = -1e30
 # Per-row softmax stats (lse, delta) are carried with a broadcast 128-lane
 # trailing dim: Mosaic requires the last block dim to be 128-divisible or
@@ -53,7 +52,7 @@ LANES = 128
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return not tpu_backend()
 
 
 def _pad_seq(x: jax.Array, block: int) -> jax.Array:

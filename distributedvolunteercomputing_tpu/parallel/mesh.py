@@ -48,22 +48,10 @@ def make_mesh(
 
 def shard_map_manual(fn, mesh: Mesh, in_specs, out_specs, axis: str):
     """``shard_map`` manual over ONE axis, automatic (GSPMD) over the
-    rest — spanning the jax API split the same way the mesh codec's shim
-    does (ops/mesh_codec.py): ``jax.shard_map(axis_names={axis},
-    check_vma=False)`` on new jax, ``jax.experimental.shard_map`` with
-    the complementary ``auto`` frozenset (and ``check_rep=False``) on
-    the tier-1 jax, where ``jax.shard_map``/``axis_names`` don't exist."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names={axis}, check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    rest."""
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=frozenset(mesh.axis_names) - {axis},
+        axis_names={axis}, check_vma=False,
     )
 
 

@@ -418,7 +418,7 @@ class Tracer:
 
     Ended spans also land in the registry as the
     ``swarm.span_seconds{span=<name>}`` histogram — the metrics half of
-    the span taxonomy, scrapeable without pulling whole traces.
+    the span vocabulary, scrapeable without pulling whole traces.
     """
 
     MAX_SPANS = 4096
@@ -529,7 +529,7 @@ class Tracer:
 class FlightRecorder:
     """Bounded ring buffer of structured swarm events for post-mortems.
 
-    Event kinds recorded by the swarm tier (the documented taxonomy —
+    Event kinds recorded by the swarm tier (the documented vocabulary —
     docs/OBSERVABILITY.md keeps the authoritative list):
 
     - ``leader_deposed`` — this node decided a deposition (failover).

@@ -464,9 +464,7 @@ class Transport:
 
     async def close(self) -> None:
         if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+            self._server.close()  # stop accepting; waited for at the end
         # Tear down the client pool: cancel demux loops (they close their
         # writers) and any dial still in flight.
         tasks = []
@@ -488,6 +486,13 @@ class Transport:
         self._server_tasks.clear()
         if tasks:
             await asyncio.gather(*tasks, return_exceptions=True)
+        if self._server is not None:
+            # Last: since Python 3.12 wait_closed() waits for every inbound
+            # connection to end, and a peer's pooled connection to us stays
+            # open until it is force-closed above — waiting first left two
+            # volunteers that finish together each waiting for the other.
+            await self._server.wait_closed()
+            self._server = None
 
     # -- counters ----------------------------------------------------------
 

@@ -1255,7 +1255,22 @@ class Volunteer:
         report_task = asyncio.create_task(self._report_loop())
         try:
             self.summary = await asyncio.to_thread(self._train_blocking)
+            # What this result ran on: the device as jax reports it, what
+            # was compiled (and whether the persistent cache served it),
+            # and the device allocator's high-water mark where reported.
+            self.summary["device"] = self.trainer.device
+            self.summary["compile"] = self.trainer.compile_summary()
+            self.summary["peak_bytes_in_use"] = (
+                jax.local_devices()[0].memory_stats() or {}
+            ).get("peak_bytes_in_use")
             if self.averager is not None:
+                from distributedvolunteercomputing_tpu import native
+
+                # Settled long before the end of a run: the first round
+                # (or run_volunteer.py at startup) built or failed to.
+                self.summary["native"] = (
+                    "built" if native.ensure_built() else "numpy"
+                )
                 self.summary.update(self.averager.stats())
             # WAN accounting: every byte this volunteer moved over DCN
             # (averaging payloads dominate; DHT/heartbeat traffic is noise).

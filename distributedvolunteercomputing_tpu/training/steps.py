@@ -145,8 +145,7 @@ def make_multi_step(
     (state, per_step_losses)``.
 
     Host-loop amortization (Trainer ``steps_per_call``): a Python loop
-    dispatches one program per step, so per-dispatch overhead (tens of µs
-    locally; a full HTTP round-trip on a tunneled runtime) sits on the
+    dispatches one program per step, so per-dispatch overhead sits on the
     step's critical path. ``lax.scan`` over the SAME traced body
     (``train_step_body`` — identical math to the single step, by
     construction) moves the loop on-device: one dispatch per N steps, and

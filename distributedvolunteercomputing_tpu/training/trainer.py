@@ -173,11 +173,17 @@ class Trainer:
                 f"accum_steps={accum_steps} must be >=1 and divide batch_size={batch_size}"
             )
         # Persistent XLA compilation cache: volunteers churn (rejoin =
-        # re-trace + re-compile, 20-40s on the chip); the cache turns every
-        # rejoin after the first into a disk hit. DVC_COMPILE_CACHE= opts out.
-        from distributedvolunteercomputing_tpu.utils.jaxenv import enable_compile_cache
+        # re-trace + re-compile); the cache turns every rejoin after the
+        # first into a disk hit.
+        from distributedvolunteercomputing_tpu.utils.jaxenv import (
+            compile_log,
+            device_record,
+            enable_compile_cache,
+        )
 
-        enable_compile_cache()
+        self.compile_cache_dir = enable_compile_cache()
+        self._compile_log = compile_log()  # listening before the step compiles
+        self.device = device_record()
         self.bundle = bundle
         self.batch_size = batch_size
         self.accum_steps = accum_steps
@@ -338,6 +344,11 @@ class Trainer:
         # batches never collide with any training batch at any seed.
         self._eval_rng = jax.random.fold_in(data_rng, 0x5EED)
         self.metrics = MetricsWriter(metrics_path, volunteer_id)
+        # Header: every later record in this stream names what it ran on.
+        self.metrics.record_event(
+            0, "header",
+            {**self.device, "compile_cache_dir": self.compile_cache_dir},
+        )
         self.on_step = on_step
         # Host-side (step, params) snapshot for concurrent readers (the
         # state-sync provider serves fetches from the asyncio thread while
@@ -352,6 +363,16 @@ class Trainer:
         # advancing it).
         self.mutation_counter = 0
         self._take_snapshot(0)
+
+    def compile_summary(self) -> dict:
+        """What this process compiled so far (``CompileLog.summary``) with
+        the train step as the named program, and where the persistent cache
+        lives."""
+        fn = self._step_fn if self._step_fn is not None else self._grad_fn
+        return {
+            "cache_dir": self.compile_cache_dir,
+            **self._compile_log.summary(f"jit({fn.__name__})"),
+        }
 
     def adopt_params(self, params: Any, step: Optional[int] = None) -> None:
         """Replace params (and optionally the step counter) in place — the

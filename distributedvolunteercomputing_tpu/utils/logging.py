@@ -111,9 +111,8 @@ def errstr(e: BaseException) -> str:
 
     Logging the bare exception renders common failures invisibly:
     ``str(asyncio.TimeoutError())`` and ``str(CancelledError())`` are "",
-    which produced real ``averaging at step 90 failed: `` lines during the
-    round-4 hardware overlap run — the one context (a wedged chip, a timed-
-    out round) where the TYPE is the whole diagnosis."""
+    which produced real ``averaging at step 90 failed: `` lines — in the
+    one context (a timed-out round) where the TYPE is the whole diagnosis."""
     msg = str(e)
     name = type(e).__name__
     return f"{name}: {msg}" if msg else name

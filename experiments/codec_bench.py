@@ -47,7 +47,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distributedvolunteercomputing_tpu.utils.jaxenv import pin_platform  # noqa: E402
+from distributedvolunteercomputing_tpu.utils.jaxenv import force_host_devices  # noqa: E402
 
 RESULTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "results")
 CHUNK_BYTES = 1 << 20  # transport default: tiles == wire chunks
@@ -389,7 +389,8 @@ def main() -> None:
     # The bench compares backends, not platforms: run the mesh arm on
     # whatever jax platform is active (CPU in the sandbox, the TPU slice
     # on hardware) and say which in the artifact.
-    pin_platform(None, min_host_devices=args.devices or None)
+    if args.devices:
+        force_host_devices(args.devices)
     from distributedvolunteercomputing_tpu import native
 
     native.ensure_built()

@@ -94,15 +94,6 @@ def main() -> int:
     else:
         missing.append("scale16.json")
 
-    probe = _load_json("tpu_probe_success.json")
-    if probe:
-        print("\n## Latest banked TPU probe record\n")
-        print(f"- {probe.get('value')} {probe.get('unit')} "
-              f"({probe.get('metric')}), est_mfu {probe.get('est_mfu', '—')}, "
-              f"recorded {probe.get('recorded_at')}")
-    else:
-        missing.append("tpu_probe_success.json")
-
     soak = _load_jsonl("soak.jsonl")
     if soak:
         ok_rows = [r for r in soak if r.get("ok")]

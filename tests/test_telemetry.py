@@ -1,5 +1,5 @@
 """Telemetry-plane tests: unified metrics registry, cross-volunteer round
-tracing (span taxonomy + frame-meta trace propagation), flight recorder,
+tracing (span vocabulary + frame-meta trace propagation), flight recorder,
 stats() snapshot semantics, the versioned coord.status telemetry schema,
 and the telemetry overhead smoke.
 
@@ -224,7 +224,7 @@ class TestTracing:
         seen = run(main())
         assert seen == ["trace-xyz", None]
 
-    def test_span_taxonomy_and_cross_volunteer_stitch(self):
+    def test_span_vocabulary_and_cross_volunteer_stitch(self):
         """One committed round: every phase span present, all volunteers'
         spans share the round's trace id (the matchmaking epoch), the
         leader's handler-side fold.push stitches in via the frame meta,
@@ -263,7 +263,7 @@ class TestTracing:
         assert phase_sum <= root["dur_s"] * 1.05
         assert phase_sum >= root["dur_s"] * 0.5, (
             f"phases {phase_sum:.4f}s vs wall {root['dur_s']:.4f}s: "
-            "the taxonomy no longer covers the round"
+            "the vocabulary no longer covers the round"
         )
         # Span histogram lands in the registry (scrapeable without traces).
         summary = vols[0]["tele"].summary()

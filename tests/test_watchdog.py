@@ -734,7 +734,7 @@ class TestFlightCursor:
         assert second["events"][0]["sev"] == "warn"
         assert third["events"] == [], "repeated dumps must be incremental"
 
-    def test_all_taxonomy_kinds_carry_severity(self):
+    def test_all_vocabulary_kinds_carry_severity(self):
         rec = T.FlightRecorder(peer_id="p")
         for kind in T.KIND_SEVERITY:
             rec.record(kind)
