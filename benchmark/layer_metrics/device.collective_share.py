@@ -1,0 +1,14 @@
+"""device.collective_share (%): time of the collective operations (all-reduce,
+all-gather, reduce-scatter, collective-permute, all-to-all) over the traced
+window, mean over chips. Layer: device. Moves tok_s_chip."""
+
+from benchmark import trace
+
+
+def compute(run):
+    if run.get("trace") is None:
+        return None
+    c = trace.collective_time(run["trace"])
+    if c is None or c["window_s"] <= 0:
+        return None
+    return 100.0 * c["collective_s"] / c["window_s"]

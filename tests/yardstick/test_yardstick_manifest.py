@@ -1,0 +1,154 @@
+"""The manifest, the files it names, and that a later PR can add by adding files."""
+
+import dataclasses
+import json
+import os
+import shutil
+
+import pytest
+
+from benchmark import readers, references
+from benchmark.manifest import NAME_RE, REPO_ROOT, UNIT_RE, Manifest, ManifestError
+
+M = Manifest(REPO_ROOT)
+DOC = M.doc
+ALL_METRICS = DOC["end_to_end"] + DOC["per_layer"]
+
+
+def test_manifest_has_exactly_the_contract_keys_and_checks():
+    assert set(DOC) == {"command", "paths", "run_seconds", "configs", "workloads",
+                        "end_to_end", "per_layer"}
+    M.check()
+    assert 1 <= DOC["run_seconds"] <= 51
+    assert len(json.dumps(DOC)) < 64 * 1024
+    for path in DOC["paths"]:
+        assert os.path.isdir(os.path.join(REPO_ROOT, path))
+    # the command names no file outside `paths`
+    assert DOC["command"][1].startswith("benchmark/")
+
+
+@pytest.mark.parametrize("cell", DOC["workloads"], ids=lambda w: w["name"])
+def test_cell_resolves_by_name(cell):
+    cfg = M.load_config(cell["config"])
+    traffic = M.load_traffic(cell["traffic"])
+    assert cfg["name"] == cell["config"] and traffic["name"] == cell["traffic"]
+    assert cfg["chips"] == cell["chips"]
+    assert len(cell["why"]) <= 200 and "\n" not in cell["why"]
+    e2e = {m["name"] for m in M.metrics_for(cell["name"], "end_to_end")}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layer = M.metrics_for(cell["name"], "per_layer")
+    assert layer and all(m["moves"] in e2e for m in layer)
+
+
+def test_at_most_a_quarter_of_the_cells_take_four_chips():
+    four = [w for w in DOC["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(DOC["workloads"]) // 4)
+    pairs = [(w["config"], w["traffic"]) for w in DOC["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
+@pytest.mark.parametrize("metric", ALL_METRICS, ids=lambda m: m["name"])
+def test_metric_names_units_and_bounds(metric):
+    assert NAME_RE.match(metric["name"]) and UNIT_RE.match(metric["unit"])
+    allowed = {"name", "unit", "better", "source", "workloads"}
+    if metric in DOC["end_to_end"]:
+        assert set(metric) <= allowed | {"bound"}
+        assert 0.01 <= metric["bound"] <= 0.1
+        assert metric["source"] in ("host_clock", "device_trace")
+    else:
+        assert set(metric) <= allowed | {"layer", "moves"}
+        assert metric["layer"] == metric["layer"].strip() and len(metric["layer"]) <= 200
+
+
+@pytest.mark.parametrize("metric", DOC["per_layer"], ids=lambda m: m["name"])
+def test_layer_metric_reader_agrees_with_the_manifest(metric):
+    path = M.layer_metric_path(metric["name"])
+    if path.endswith(".json"):
+        with open(path) as fh:
+            decl = json.load(fh)
+        for key in ("name", "layer", "unit", "moves", "source", "better"):
+            assert decl[key] == metric[key], key
+    else:
+        with open(path) as fh:
+            head = fh.read()
+        assert metric["name"] in head and "def compute(run)" in head
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in DOC["configs"]])
+def test_configuration_file_is_what_the_program_runs(name):
+    from distributedvolunteercomputing_tpu.models import get_model
+    from distributedvolunteercomputing_tpu.swarm.volunteer import VolunteerConfig
+
+    cfg = M.load_config(name)
+    entry = M.config_entry(name)
+    assert cfg["source"] == entry["source"] and cfg["reduced"] == entry["reduced"] == []
+    bundle = get_model(cfg["registry_model"], **cfg["model_overrides"])
+    references.load(cfg["family"]).check_config(bundle.config, cfg)
+    fields = {f.name for f in dataclasses.fields(VolunteerConfig)}
+    assert set(cfg["volunteer"]) <= fields
+
+
+@pytest.mark.parametrize("name", sorted({w["traffic"] for w in DOC["workloads"]}))
+def test_traffic_file_uses_volunteer_config_fields(name):
+    from distributedvolunteercomputing_tpu.swarm.volunteer import VolunteerConfig
+
+    traffic = M.load_traffic(name)
+    fields = {f.name for f in dataclasses.fields(VolunteerConfig)}
+    assert set(traffic["volunteer"]) <= fields
+    # everything not named stays at the volunteer's default: the senses are on
+    for sense in ("telemetry", "health_probe", "watchdog"):
+        assert sense not in traffic["volunteer"]
+    for peer in traffic["peers"]:
+        assert peer["peer_id"] < traffic["volunteer"]["peer_id"], "the stub must sort first to lead"
+
+
+def test_program_config_mismatch_is_refused():
+    from distributedvolunteercomputing_tpu.models import get_model
+
+    cfg = dict(M.load_config("gpt2-medium"), n_layer=23)
+    with pytest.raises(ValueError, match="n_layer"):
+        references.load("gpt2").check_config(get_model("gpt2_medium").config, cfg)
+
+
+def test_a_later_pr_adds_a_config_a_mix_a_cell_and_a_metric_with_files_only(tmp_path):
+    """Copy the benchmark, add one throw-away file of each kind and one
+    manifest entry each, edit nothing that exists: it resolves and reads."""
+    root = tmp_path / "repo"
+    shutil.copytree(os.path.join(REPO_ROOT, "benchmark"), root / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = root / "benchmark"
+    cfg = M.load_config("gpt2-medium")
+    cfg.update(name="throwaway-model")
+    (bench / "configs" / "throwaway-model.json").write_text(json.dumps(cfg))
+    traffic = M.load_traffic("solo")
+    traffic.update(name="throwaway-mix")
+    (bench / "traffic" / "throwaway-mix.json").write_text(json.dumps(traffic))
+    (bench / "layer_metrics" / "throwaway.count.json").write_text(json.dumps({
+        "name": "throwaway.count", "layer": "train loop", "unit": "count",
+        "better": "higher", "moves": "tok_s_chip", "source": "program_counter",
+        "read": {"stat": "steps.window", "scale": 2},
+    }))
+    doc = json.loads(json.dumps(DOC))
+    doc["configs"].append({"name": "throwaway-model", "source": cfg["source"],
+                           "file": "benchmark/configs/throwaway-model.json",
+                           "reduced": [], "why": "test"})
+    doc["workloads"].append({"name": "throwaway-cell", "config": "throwaway-model",
+                             "traffic": "throwaway-mix", "chips": 1, "why": "test"})
+    doc["per_layer"].append({"name": "throwaway.count", "unit": "count", "better": "higher",
+                             "source": "program_counter", "layer": "train loop",
+                             "moves": "tok_s_chip", "workloads": ["throwaway-cell"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    with pytest.raises(ManifestError, match="throwaway-cell"):
+        Manifest(str(root)).check()  # the cell does not report the metric it would move
+    next(e for e in doc["end_to_end"] if e["name"] == "tok_s_chip")["workloads"].append(
+        "throwaway-cell")
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    m = Manifest(str(root))
+    m.check()
+    assert m.load_config(m.cell("throwaway-cell")["config"])["name"] == "throwaway-model"
+    names = [x["name"] for x in m.metrics_for("throwaway-cell", "per_layer")]
+    assert "throwaway.count" in names and "round.wire_s" not in names
+    value = readers.compute(m.layer_metric_path("throwaway.count"), {"stats": {"steps.window": 21}})
+    assert value == 42.0
+    with pytest.raises(ManifestError):
+        m.cell("no-such-cell")
