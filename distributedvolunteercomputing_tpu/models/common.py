@@ -115,6 +115,7 @@ def _project_vocab(x: jax.Array, head: jax.Array, head_layout: str) -> jax.Array
     return jnp.einsum(eq, x, head.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
+@jax.named_scope("loss_head")  # names the head's ops in a profiler trace
 def lm_xent_chunked(
     x: jax.Array,
     head: jax.Array,

@@ -73,10 +73,11 @@ a broken kernel stops the volunteer instead of hiding behind the host path.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
 import threading
-import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -153,6 +154,14 @@ def _dec_axpy_kernel(b_ref, a_ref, w_ref, o_ref):
     o_ref[...] = a_ref[...] + w_ref[0, 0] * _bf16_widen(b_ref[...])
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under a stable ``__name__``: jit names its program after it,
+    and a profiler trace is read by program name. Every codec program is
+    ``encode_*``, ``decode_*`` or ``body_<op>``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _jnp():
     import jax.numpy as jnp
 
@@ -191,6 +200,7 @@ class MeshCodec:
         self._mesh_arg = mesh
         self._codec_mesh = None  # built lazily on first device op
         self._ndev = 1
+        self._host_args = False  # see _ensure_mesh
         self._jit_cache: Dict[tuple, Callable] = {}
         self._compiled_once: set = set()  # (key, arg signature), see _jit
         self.degraded = False
@@ -200,11 +210,16 @@ class MeshCodec:
         # attached by the volunteer so a degrade event lands in the
         # telemetry plane's ring buffer beside the depositions and fences.
         self.recorder = None
+        # Optional span tracer (swarm/telemetry.py ``Tracer``), attached the
+        # same way: each device op is a ``codec.op`` span under the round
+        # that called it, split into what the host waited for, in order:
+        # ``codec.h2d``, ``codec.run`` (the program's wait in the chip's
+        # in-order queue and its execution), ``codec.d2h``.
+        self.tracer = None
         # gauges
         self.ops_mesh = 0
         self.ops_host = 0
         self.fallbacks = 0
-        self.device_s = 0.0
         # Ring-lowering gauges, written by RingMeanFolder: the configured
         # lowering, the last lowering actually used, and how many flushes
         # were quietly re-lowered to xla by the VMEM estimate. Without
@@ -286,7 +301,6 @@ class MeshCodec:
             "ops_mesh": int(self.ops_mesh),
             "ops_host": int(self.ops_host),
             "fallbacks": int(self.fallbacks),
-            "device_s": round(self.device_s, 6),
             "degraded": bool(self.degraded),
             "degrade_reason": self.degrade_reason,
             "ring_lower": self.ring_lower,
@@ -328,30 +342,51 @@ class MeshCodec:
             except Exception:  # noqa: BLE001 — recording must not affect the fallback
                 pass
 
-    def _run(self, op: Callable, host: Callable):
+    def _phase(self, name: str, **attrs: Any):
+        """Span + profiler annotation (``Tracer.phase``) around a part of a
+        device op; nothing without a tracer."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.phase(name, **attrs)
+
+    def _run(self, op: Callable, host: Callable, name: str, elems: int):
         """Run ``op`` on device, falling back to ``host`` (and permanently
         degrading) when the DEVICE fails: a runtime error out of the
         backend, or an injected fault. The stateless codec ops lose nothing
         in the fallback — the same inputs re-run on host. Anything else —
         ``MeshKernelError`` from a program that would not trace or compile,
-        a Python error in the op — is a bug and propagates."""
+        a Python error in the op — is a bug and propagates. ``name`` and
+        ``elems`` label the op's ``codec.op`` span."""
         if not self.active:
             self.ops_host += 1
             return host()
         import jax
 
-        t0 = time.perf_counter()
         try:
-            self._check_injected()
-            out = op()
+            with self._phase("codec.op", op=name, elems=int(elems)):
+                self._check_injected()
+                out = op()
         except (MeshCodecError, jax.errors.JaxRuntimeError) as e:
             # Chip loss must not kill the round.
             self._degrade(e)
             self.ops_host += 1
             return host()
-        self.device_s += time.perf_counter() - t0
         self.ops_mesh += 1
         return out
+
+    def _call(self, fn: Callable, *args):
+        """Dispatch a device program and wait until its result is ready: the
+        wait the blocking host read made anyway, taken apart from the copy
+        (``_fetch``) so that queueing and running show without the transfer."""
+        import jax
+
+        with self._phase("codec.run"):
+            return jax.block_until_ready(fn(*args))
+
+    def _fetch(self, dev) -> np.ndarray:
+        """The host copy of a ready device result."""
+        with self._phase("codec.d2h"):
+            return np.asarray(dev)
 
     # -- device plumbing ---------------------------------------------------
 
@@ -367,6 +402,11 @@ class MeshCodec:
                 devices = np.asarray(jax.devices()[:1])
             self._codec_mesh = Mesh(devices, ("codec",))
             self._ndev = devices.size
+            # One CPU device: XLA:CPU consumes aligned numpy zero-copy, and
+            # an explicit device_put would just be a memcpy. On a chip the
+            # same hand-over is a transfer inside the call; made explicit
+            # there, it is timed as ``codec.h2d`` and not as the program.
+            self._host_args = devices.size == 1 and devices[0].platform == "cpu"
         return self._codec_mesh
 
     def _sharding(self, spec):
@@ -376,21 +416,20 @@ class MeshCodec:
 
     def _put_flat(self, arr: np.ndarray):
         """Pad a flat host array to an ndev multiple and place it split over
-        the codec axis. Returns (device_array, original_size). On a
-        single-device codec mesh the host array is handed to jit directly —
-        XLA:CPU consumes aligned numpy zero-copy, and the explicit
-        device_put would just be a memcpy."""
+        the codec axis. Returns (device_array, original_size). On a single
+        CPU device the host array is handed to jit directly (_ensure_mesh)."""
         import jax
         from jax.sharding import PartitionSpec as P
 
         self._ensure_mesh()
         n = arr.size
-        pad = (-n) % self._ndev
-        if pad:
-            arr = np.pad(arr, (0, pad))
-        if self._ndev == 1:
-            return arr, n
-        return jax.device_put(arr, self._sharding(P("codec"))), n
+        with self._phase("codec.h2d"):
+            pad = (-n) % self._ndev
+            if pad:
+                arr = np.pad(arr, (0, pad))
+            if self._host_args:
+                return arr, n
+            return jax.device_put(arr, self._sharding(P("codec"))), n
 
     def _put_stack(self, stack: np.ndarray):
         """[n, T] host stack placed with the tile dim split over the codec
@@ -400,12 +439,13 @@ class MeshCodec:
 
         self._ensure_mesh()
         t = stack.shape[1]
-        pad = (-t) % self._ndev
-        if pad:
-            stack = np.pad(stack, ((0, 0), (0, pad)))
-        if self._ndev == 1:
-            return stack, t
-        return jax.device_put(stack, self._sharding(P(None, "codec"))), t
+        with self._phase("codec.h2d"):
+            pad = (-t) % self._ndev
+            if pad:
+                stack = np.pad(stack, ((0, 0), (0, pad)))
+            if self._host_args:
+                return stack, t
+            return jax.device_put(stack, self._sharding(P(None, "codec"))), t
 
     def _jit(self, key: tuple, build: Callable[[], Callable]) -> Callable:
         """The cached device program for ``key``. Its first call per
@@ -417,6 +457,7 @@ class MeshCodec:
         if fn is None:
             program = build()
 
+            @functools.wraps(program)  # keeps the program's name and the program
             def fn(*args):
                 sig = (key, tuple((a.shape, a.dtype) for a in args))
                 if sig in self._compiled_once:
@@ -433,18 +474,19 @@ class MeshCodec:
             self._jit_cache[key] = fn
         return fn
 
-    def _shard_map(self, fn, in_specs, out_specs, **jit_kw):
+    def _shard_map(self, fn, in_specs, out_specs, name: str, **jit_kw):
         """jit(shard_map(fn)) over the codec mesh — the SNIPPETS.md [2]
         wrapping pattern. All codec ops are elementwise over the sharded
         dim, so replication checking has nothing to reject; it stays off to
-        keep scatter ops eligible."""
+        keep scatter ops eligible. ``name`` is the program's: a trace shows
+        it as ``jit_<name>`` (see ``_named``)."""
         import jax
 
         wrapped = jax.shard_map(
             fn, mesh=self._ensure_mesh(), in_specs=in_specs,
             out_specs=out_specs, check_vma=False,
         )
-        return jax.jit(wrapped, **jit_kw)
+        return jax.jit(_named(wrapped, name), **jit_kw)
 
     # -- pallas inner bodies ----------------------------------------------
 
@@ -524,12 +566,12 @@ class MeshCodec:
 
             fn = self._jit(
                 ("enc", use_pallas),
-                lambda: self._shard_map(body, (P("codec"),), P("codec")),
+                lambda: self._shard_map(body, (P("codec"),), P("codec"), "encode_bf16"),
             )
             x, n = self._put_flat(buf)
-            return np.asarray(fn(x))[:n]
+            return self._fetch(self._call(fn, x))[:n]
 
-        return self._run(dev, lambda: native.f32_to_bf16(buf))
+        return self._run(dev, lambda: native.f32_to_bf16(buf), "encode_bf16", buf.size)
 
     def decode_bf16(self, bits: np.ndarray, out: Optional[np.ndarray] = None) -> np.ndarray:
         """uint16 bf16 bit patterns -> float32 (exact: bf16 ⊂ f32)."""
@@ -547,16 +589,19 @@ class MeshCodec:
                 return _bf16_widen(b)
 
             fn = self._jit(
-                ("dec",), lambda: self._shard_map(body, (P("codec"),), P("codec"))
+                ("dec",),
+                lambda: self._shard_map(body, (P("codec"),), P("codec"), "decode_bf16"),
             )
             b, n = self._put_flat(bits)
-            res = np.asarray(fn(b))[:n]
+            res = self._fetch(self._call(fn, b))[:n]
             if out is not None:
                 out[: res.size] = res
                 return out[: res.size]
             return res
 
-        return self._run(dev, lambda: native.bf16_to_f32(bits, out=out))
+        return self._run(
+            dev, lambda: native.bf16_to_f32(bits, out=out), "decode_bf16", bits.size
+        )
 
     def decode_axpy(self, acc: np.ndarray, bits: np.ndarray, w: float) -> np.ndarray:
         """acc + w · decode(bits) in ONE fused device pass (the host path
@@ -585,18 +630,18 @@ class MeshCodec:
             fn = self._jit(
                 ("dec_axpy", use_pallas),
                 lambda: self._shard_map(
-                    body, (P("codec"), P("codec"), P()), P("codec")
+                    body, (P("codec"), P("codec"), P()), P("codec"), "body_dec_axpy"
                 ),
             )
             a, n = self._put_flat(acc)
             b, _ = self._put_flat(bits)
-            return np.asarray(fn(a, b, np.float32([w])))[:n]
+            return self._fetch(self._call(fn, a, b, np.float32([w])))[:n]
 
         def host() -> np.ndarray:
             native.weighted_sum_inplace(acc, native.bf16_to_f32(bits), float(w))
             return acc
 
-        return self._run(dev, host)
+        return self._run(dev, host, "dec_axpy", acc.size)
 
     # -- window folds ------------------------------------------------------
 
@@ -634,14 +679,14 @@ class MeshCodec:
                 ).astype(np.float32)
                 fn = self._jit(("wmean", n), self._build_wmean)
                 d, t = self._put_stack(s)
-                return np.asarray(fn(d, wn))[:t]
+                return self._fetch(self._call(fn, d, wn))[:t]
             trim = int(kw.get("trim", 1)) if method == "trimmed_mean" else None
             key = (method, n, trim)
             fn = self._jit(key, lambda: self._build_window(method, n, trim))
             d, t = self._put_stack(s)
-            return np.asarray(fn(d))[:t]
+            return self._fetch(self._call(fn, d))[:t]
 
-        return self._run(dev, host)
+        return self._run(dev, host, method, stack.size)
 
     def _build_wmean(self) -> Callable:
         from jax.sharding import PartitionSpec as P
@@ -649,7 +694,7 @@ class MeshCodec:
         def body(s, w):
             return (s * w[:, None]).sum(axis=0)
 
-        return self._shard_map(body, (P(None, "codec"), P()), P("codec"))
+        return self._shard_map(body, (P(None, "codec"), P()), P("codec"), "body_wmean")
 
     def _build_window(self, method: str, n: int, trim: Optional[int]) -> Callable:
         """Sorting-network window estimator over the peer axis: rows are
@@ -683,7 +728,7 @@ class MeshCodec:
             kept = rows[trim : n - trim]
             return sum(kept[1:], kept[0]) / jnp.float32(len(kept))
 
-        return self._shard_map(body, (P(None, "codec"),), P("codec"))
+        return self._shard_map(body, (P(None, "codec"),), P("codec"), f"body_{method}")
 
     def aggregate_bits(self, bits_stack: np.ndarray, method: str, **kw) -> np.ndarray:
         """Window fold straight from bf16 wire bits [n, T] — the decode
@@ -691,13 +736,12 @@ class MeshCodec:
         from distributedvolunteercomputing_tpu import native
         from distributedvolunteercomputing_tpu.ops import robust
 
-        def host() -> np.ndarray:
-            dec = np.stack([native.bf16_to_f32(row) for row in bits_stack])
-            return robust.aggregate(dec, method, **kw)
+        def host_decode() -> np.ndarray:
+            return np.stack([native.bf16_to_f32(row) for row in bits_stack])
 
         if not self.active:
             self.ops_host += 1
-            return host()
+            return robust.aggregate(host_decode(), method, **kw)
 
         def dev_decode() -> np.ndarray:
             import jax
@@ -710,14 +754,14 @@ class MeshCodec:
 
             fn = self._jit(
                 ("dec2d",),
-                lambda: self._shard_map(body, (P(None, "codec"),), P(None, "codec")),
+                lambda: self._shard_map(
+                    body, (P(None, "codec"),), P(None, "codec"), "decode_bf16_stack"
+                ),
             )
             d, t = self._put_stack(np.ascontiguousarray(bits_stack, np.uint16))
-            return np.asarray(fn(d))[:, :t]
+            return self._fetch(self._call(fn, d))[:, :t]
 
-        dec = self._run(dev_decode, lambda: np.stack(
-            [native.bf16_to_f32(row) for row in bits_stack]
-        ))
+        dec = self._run(dev_decode, host_decode, "decode_bf16_stack", bits_stack.size)
         return self.aggregate(dec, method, **kw)
 
     # -- PowerSGD ----------------------------------------------------------
@@ -740,14 +784,15 @@ class MeshCodec:
             # Matmul + QR want the whole matrix: replicated compute (the
             # matrices are one TENSOR's, small next to the flat buffer; the
             # elementwise codec ops are where the sharding pays).
-            fn = self._jit(("psgd_iter",), lambda: jax.jit(body))
-            p, q_new = fn(
+            fn = self._jit(("psgd_iter",), lambda: jax.jit(_named(body, "body_psgd_iter")))
+            p, q_new = self._call(
+                fn,
                 np.ascontiguousarray(mat, np.float32),
                 np.ascontiguousarray(q, np.float32),
             )
             return (
-                np.ascontiguousarray(np.asarray(p), np.float32),
-                np.ascontiguousarray(np.asarray(q_new), np.float32),
+                np.ascontiguousarray(self._fetch(p), np.float32),
+                np.ascontiguousarray(self._fetch(q_new), np.float32),
             )
 
         def host() -> Tuple[np.ndarray, np.ndarray]:
@@ -755,7 +800,7 @@ class MeshCodec:
             p = np.ascontiguousarray(p, np.float32)
             return p, mat.T @ p
 
-        return self._run(dev, host)
+        return self._run(dev, host, "psgd_iter", mat.size)
 
     def lowrank_reconstruct(self, p: np.ndarray, q: np.ndarray) -> np.ndarray:
         """Dense rank-r reconstruction (P·Qᵀ).ravel() — the decoder's hot
@@ -764,15 +809,21 @@ class MeshCodec:
         def dev() -> np.ndarray:
             import jax
 
-            fn = self._jit(("psgd_rec",), lambda: jax.jit(lambda a, b: a @ b.T))
-            return np.asarray(
-                fn(
+            fn = self._jit(
+                ("psgd_rec",),
+                lambda: jax.jit(_named(lambda a, b: a @ b.T, "body_psgd_rec")),
+            )
+            return self._fetch(
+                self._call(
+                    fn,
                     np.ascontiguousarray(p, np.float32),
                     np.ascontiguousarray(q, np.float32),
                 )
             ).ravel()
 
-        return self._run(dev, lambda: (p @ q.T).ravel())
+        return self._run(
+            dev, lambda: (p @ q.T).ravel(), "psgd_rec", p.shape[0] * q.shape[0]
+        )
 
     # -- streaming mean folder --------------------------------------------
 
@@ -897,7 +948,7 @@ class MeshMeanFolder:
                 )
             return True
 
-        self.codec._run(dev, host)
+        self.codec._run(dev, host, "folder_dense", self.n_elems)
 
     # -- device plumbing ---------------------------------------------------
 
@@ -905,16 +956,17 @@ class MeshMeanFolder:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        if self.codec._ndev == 1:
-            return arr  # XLA:CPU consumes aligned numpy zero-copy
-        return jax.device_put(arr, self.codec._sharding(P(None, "codec")))
+        if self.codec._host_args:
+            return arr  # see MeshCodec._ensure_mesh
+        with self.codec._phase("codec.h2d"):
+            return jax.device_put(arr, self.codec._sharding(P(None, "codec")))
 
     def _fold_jit(self, body, n_in: int):
         from jax.sharding import PartitionSpec as P
 
         specs = (P(None, "codec"),) * (1 + n_in) + (P(),) * 1
         return self.codec._shard_map(
-            body, specs, P(None, "codec"), donate_argnums=(0,)
+            body, specs, P(None, "codec"), "body_folder_dense", donate_argnums=(0,)
         )
 
     def _device_acc(self):
@@ -1002,6 +1054,7 @@ class MeshMeanFolder:
                 body,
                 (P(None, "codec"), P(None, "codec"), P(), P()),
                 P(None, "codec"),
+                "body_folder_flush",
                 donate_argnums=(0,),
             ),
         )
@@ -1033,7 +1086,8 @@ class MeshMeanFolder:
             return
         self.flushes += 1
         self.codec._run(
-            lambda: self._flush_dev(batch), lambda: self._flush_host(batch)
+            lambda: self._flush_dev(batch), lambda: self._flush_host(batch),
+            "folder_flush", sum(len(data) for _, _, data in batch) // self.esz,
         )
 
     def result(self) -> np.ndarray:

@@ -393,6 +393,7 @@ class RingMeanFolder(MeshMeanFolder):
         self.codec._run(
             lambda: self._flush_dev(batch, pend),
             lambda: self._flush_host(batch),
+            "ring_flush", sum(len(data) for _, _, data in batch) // self.esz,
         )
 
     # -- flush ------------------------------------------------------------
@@ -478,7 +479,7 @@ class RingMeanFolder(MeshMeanFolder):
 
         in_specs = (P(None, "codec"), P(), P()) + (P("codec"),) * kb
         return codec._shard_map(
-            body, in_specs, P(None, "codec"), donate_argnums=(0,)
+            body, in_specs, P(None, "codec"), "body_ring_rows", donate_argnums=(0,)
         )
 
     def _block(self) -> Tuple[int, int, int]:
@@ -567,6 +568,7 @@ class RingMeanFolder(MeshMeanFolder):
             body,
             (P(None, "codec"), P("codec", None), meta_spec, meta_spec),
             P(None, "codec"),
+            f"body_ring_fold_{lower}",
             donate_argnums=(0,),
         )
 
@@ -595,7 +597,9 @@ class RingMeanFolder(MeshMeanFolder):
                 self._acc = None
             return full.ravel()[: self.n_elems].copy()
 
-        return self.codec._run(dev, lambda: super(RingMeanFolder, self).result())
+        return self.codec._run(
+            dev, lambda: super(RingMeanFolder, self).result(), "ring_gather", self.n_elems
+        )
 
     def _build_gather(self):
         import jax
@@ -627,7 +631,7 @@ class RingMeanFolder(MeshMeanFolder):
             o = o.reshape(nd, n_tiles, shard)
             return jnp.swapaxes(o, 0, 1).reshape(n_tiles, nd * shard)
 
-        return codec._shard_map(body, (P(None, "codec"),), P(None, None))
+        return codec._shard_map(body, (P(None, "codec"),), P(None, None), "body_ring_gather")
 
 
 def _compiler_params(compiled: bool, collective_id: int):

@@ -176,6 +176,7 @@ def _make_constrain_opt(mesh: Mesh, zero1: bool, fsdp: bool):
     moments without this. Shared by the single-step and scanned builders so
     their layouts can't diverge."""
 
+    @jax.named_scope("optimizer")  # the update's scope (steps.apply_half)
     def constrain_opt(state: TrainState) -> TrainState:
         if not (zero1 or fsdp):
             return state

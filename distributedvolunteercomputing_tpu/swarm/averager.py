@@ -355,6 +355,10 @@ class AveragerBase:
         # default, selected once at volunteer startup and surfaced in
         # stats()["mesh_codec"].
         self._mesh_codec = mesh_codec
+        # Trace id (round key) of the round the last average() call ran, None
+        # when it formed no group: the volunteer hands it to the train loop
+        # beside the result.
+        self.last_trace: Optional[str] = None
         # Tail-optimal hedged recovery (OptiReduce, ROADMAP item 2): when
         # this node LEADS a streaming round, predicted-late peers' missing
         # tile ranges are re-requested over a second stream ahead of the
@@ -558,6 +562,7 @@ class AveragerBase:
             # (The lazily-resolved process default is hooked by the
             # volunteer, which configures it.)
             self._mesh_codec.recorder = self.telemetry.recorder
+            self._mesh_codec.tracer = self.telemetry.tracer
         reg.source("aggregation", lambda: dict(self._agg_gauges))
         if self.group_schedule is not None:
             reg.source("groups", self.group_stats)
@@ -2726,6 +2731,7 @@ class SyncAverager(AveragerBase):
         self._apply_controller()
         await self._maybe_backoff()
         tele = self.telemetry
+        self.last_trace = None
         # Round-trace bookkeeping: the JOIN phase (rendezvous + formation)
         # runs before the trace id — the matchmaking epoch — exists, so its
         # wall/duration are captured here and the span recorded
@@ -2765,7 +2771,7 @@ class SyncAverager(AveragerBase):
         # which already hashes the group-scoped rendezvous key (rotation,
         # group index, hierarchy level). Recovery generations ride as span
         # attributes so a recovered round stays ONE trace.
-        trace = group.epoch
+        trace = self.last_trace = group.epoch
         asg = self._last_group
         level = asg.level if asg is not None else "flat"
         group_id = group.group_id or (asg.group_id if asg is not None else "")

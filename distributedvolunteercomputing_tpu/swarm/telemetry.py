@@ -40,8 +40,8 @@ substrate those surfaces re-register into:
 Everything is advisory and bounded: a telemetry bug must never fail a
 round, so record paths swallow their own exceptions, ring buffers cap
 memory, and ``Telemetry(enabled=False)`` turns every hot-path call into a
-cheap no-op (the overhead smoke in tests/test_telemetry.py holds the
-enabled path within 5% of disabled commit latency).
+cheap no-op (the overhead smoke in tests/test_telemetry.py counts that the
+disabled path touches neither ring, histogram, hook nor profiler).
 """
 
 from __future__ import annotations
@@ -143,6 +143,26 @@ def reset_current_trace(token: contextvars.Token) -> None:
         # Token from another context (a handler that migrated tasks) —
         # the var is request-scoped anyway; losing the reset is harmless.
         pass
+
+
+# The span open in this context (thread or task), set for the body of
+# ``Tracer.span`` / ``Tracer.phase``: a span started inside it, in the same
+# trace, names it as its ``parent``. ``asyncio.to_thread`` copies the
+# context, so a worker thread's spans hang under the phase that spawned it.
+_CURRENT_SPAN: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
+    "dvc_span", default=None
+)
+
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation("dvc:" + name)``: a host event on the
+    profiler's clock for as long as the ``with`` body runs (the prefix tells
+    a reader of an ``.xplane.pb`` the program's phases from its own). With
+    no profiler session open it costs a TraceMe activity check. Imported
+    lazily: the coordinator's processes use this module and never load jax."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation("dvc:" + name)
 
 
 # -- metrics registry --------------------------------------------------------
@@ -377,12 +397,19 @@ class MetricsRegistry:
 class Span:
     """One timed phase of a round. End exactly once (idempotent)."""
 
-    __slots__ = ("tracer", "name", "trace", "attrs", "t0", "_pc0", "dur_s", "_done")
+    __slots__ = (
+        "tracer", "name", "trace", "parent", "attrs", "t0", "_pc0", "dur_s", "_done",
+    )
 
     def __init__(self, tracer: "Tracer", name: str, trace: str, attrs: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
         self.trace = trace
+        # The span that caused this one: open in this context, same trace.
+        cur = _CURRENT_SPAN.get()
+        self.parent = (
+            cur.name if cur is not None and cur.trace == trace and not cur._done else None
+        )
         self.attrs = attrs
         # Wall timestamp on the telemetry clock (ClockSync-aligned when the
         # volunteer has one) for cross-volunteer stitching; duration from
@@ -409,6 +436,7 @@ class Span:
             "peer": self.tracer.peer_id,
             "t0": round(self.t0, 6),
             "dur_s": round(self.dur_s, 6) if self.dur_s is not None else None,
+            **({"parent": self.parent} if self.parent else {}),
             **({"attrs": self.attrs} if self.attrs else {}),
         }
 
@@ -422,6 +450,12 @@ class Tracer:
     """
 
     MAX_SPANS = 4096
+    # Trace id of a span whose round key does not exist yet (the train
+    # loop's launch ends before matchmaking names the round): ending it stops
+    # its clock but records nothing until adopt() gives it the key. A class
+    # attribute, because the train loop holds a tracer and must not import
+    # this module.
+    PENDING = "<pending>"
 
     def __init__(
         self,
@@ -452,6 +486,8 @@ class Tracer:
         return Span(self, name, trace, attrs)
 
     def _finish(self, span: Span) -> None:
+        if span.trace == self.PENDING:
+            return  # its owner holds it until adopt() names the round
         try:
             sp = span.as_dict()
             with self._lock:
@@ -462,6 +498,14 @@ class Tracer:
                 self.on_record(sp)
         except Exception as e:  # noqa: BLE001 — tracing must never fail the round
             log.debug("span finish failed: %s", errstr(e))
+
+    def adopt(self, span: Optional[Span], trace: str) -> None:
+        """Record a span that was started under ``PENDING`` and has ended,
+        now that its round's key exists."""
+        if span is None or span.trace != self.PENDING:
+            return
+        span.trace = trace
+        self._finish(span)
 
     def record(
         self, name: str, trace: str, t0: float, dur_s: float, **attrs: Any
@@ -492,11 +536,37 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, name: str, trace: Optional[str] = None, **attrs: Any) -> Iterator[Optional[Span]]:
         sp = self.start(name, trace, **attrs)
+        if sp is None:
+            yield None
+            return
+        token = _CURRENT_SPAN.set(sp)
         try:
             yield sp
         finally:
-            if sp is not None:
-                sp.end()
+            try:
+                _CURRENT_SPAN.reset(token)
+            except ValueError:
+                pass  # ended in another context: see reset_current_trace
+            sp.end()
+
+    @contextlib.contextmanager
+    def phase(self, name: str, trace: Optional[str] = None, **attrs: Any) -> Iterator[Optional[Span]]:
+        """A SYNCHRONOUS phase (begins and ends on one thread, no ``await``
+        inside): the span, and for the same lifetime the profiler annotation
+        ``dvc:<name>``, so a profiler trace taken by anyone holds the
+        program's host phases on the clock of the device planes. Outside any
+        trace the annotation alone is opened; disabled, neither."""
+        if not self.enabled:
+            yield None
+            return
+        with annotation(name), self.span(name, trace, **attrs) as sp:
+            yield sp
+
+    def annotate(self, name: str):
+        """The profiler annotation alone, for phases too frequent for a span
+        (one per train step would turn the ring over and call the hook a
+        thousand times a second on a small model)."""
+        return annotation(name) if self.enabled else contextlib.nullcontext()
 
     @contextlib.contextmanager
     def trace_scope(self, trace: str) -> Iterator[None]:
@@ -521,6 +591,37 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._done.clear()
+
+
+def self_seconds(spans: List[dict]) -> List[Optional[float]]:
+    """Self time of each span dict, in the order given: its duration less
+    the part of its interval that its children cover. A child names the
+    span as ``parent``, shares its trace and peer and starts inside its
+    interval (names repeat within a trace: one ``codec.op`` per op);
+    children that overlap each other are counted once. None for a span that
+    has not ended."""
+    children: Dict[tuple, List[dict]] = {}
+    for s in spans:
+        if s.get("parent") and s.get("dur_s") is not None:
+            children.setdefault((s["trace"], s.get("peer"), s["parent"]), []).append(s)
+    out: List[Optional[float]] = []
+    for s in spans:
+        if s.get("dur_s") is None:
+            out.append(None)
+            continue
+        t0, t1 = s["t0"], s["t0"] + s["dur_s"]
+        covered, edge = 0.0, t0
+        for c in sorted(
+            children.get((s["trace"], s.get("peer"), s["name"]), ()), key=lambda c: c["t0"]
+        ):
+            if not t0 <= c["t0"] <= t1:
+                continue
+            c1 = min(c["t0"] + c["dur_s"], t1)
+            if c1 > edge:
+                covered += c1 - max(c["t0"], edge)
+                edge = c1
+        out.append(max(s["dur_s"] - covered, 0.0))
+    return out
 
 
 # -- flight recorder ---------------------------------------------------------
@@ -770,6 +871,9 @@ class Telemetry:
     SUMMARY_SPANS = (
         "round", "join", "encode", "wire", "fold", "commit", "health",
         "fetch", "recover",
+        # what stops the chip on the volunteer's side of a round: the train
+        # thread's merge and snapshot, the codec's wait in the device queue
+        "loop.merge", "loop.snapshot", "codec.run",
     )
 
     def summary(self) -> dict:

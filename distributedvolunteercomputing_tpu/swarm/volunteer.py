@@ -590,6 +590,10 @@ class Volunteer:
         except Exception as e:
             log.warning("averaging at step %d failed: %s", step, errstr(e))
             return None
+        finally:
+            # The round's key beside the result: the train loop files its
+            # launch and merge spans under it (one round, one trace).
+            self.trainer.round_trace = self.averager.last_trace
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -835,8 +839,10 @@ class Volunteer:
             backend=self.cfg.mesh_codec,
             collective=self.cfg.mesh_collective,
         )
-        # Slice-loss degrades land in this volunteer's flight recorder.
+        # Slice-loss degrades land in this volunteer's flight recorder, the
+        # codec's device ops in its span ring.
         codec.recorder = self.telemetry.recorder
+        codec.tracer = self.telemetry.tracer
         log.info(
             "swarm data path: %s backend (mesh=%s)",
             codec.backend, self.cfg.mesh or "single-device",
@@ -885,6 +891,7 @@ class Volunteer:
             outer_optimizer=self.cfg.outer_optimizer,
             outer_lr=self.cfg.outer_lr,
             outer_momentum=self.cfg.outer_momentum,
+            tracer=self.telemetry.tracer,
         )
         if self.averager is not None:
             # Checkpoint sidecars persist the averager's compressor state

@@ -96,6 +96,7 @@ def grad_half(
     return grads, metrics, rng
 
 
+@jax.named_scope("optimizer")  # names the update's ops in a profiler trace
 def apply_half(
     tx: optax.GradientTransformation,
     state: TrainState,

@@ -23,6 +23,7 @@ algorithm, not an optimization.
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import os
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
@@ -147,6 +148,12 @@ class Trainer:
         outer_optimizer: str = "none",
         outer_lr: float = 0.7,
         outer_momentum: float = 0.9,
+        # The volunteer's span tracer (swarm/telemetry.py ``Tracer``, handed
+        # in so this module never imports the swarm): the phases in which
+        # the train thread holds the chip up (launch, merge, snapshot, log
+        # sync) become spans and profiler annotations. None: every site is a
+        # no-op.
+        tracer: Optional[Any] = None,
     ):
         if eval_every and eval_batches < 1:
             raise ValueError(f"eval_batches must be >= 1, got {eval_batches}")
@@ -201,6 +208,15 @@ class Trainer:
         # what makes heterogeneous contributions weigh correctly.
         self.steps_since_merge: int = average_every
         self._last_merge_step: Optional[int] = None
+        self.tracer = tracer
+        # Trace id of the phases opened now: "loop" for those that belong to
+        # no round, a round's key (or the tracer's PENDING) inside
+        # _round_phase.
+        self._phase_trace = "loop"
+        # Written by the averager callback before it returns: the trace id
+        # (round key) of the round it just ran, None when no group formed.
+        # Read after the call, or after the future that carried it resolved.
+        self.round_trace: Optional[str] = None
         self.averager = averager
         self.average_what = average_what
         # ``seed`` is PER-VOLUNTEER: it drives the data order and the step
@@ -245,6 +261,8 @@ class Trainer:
             else None
         )
         self._inflight: Optional[tuple] = None  # (launch_step, payload0, future)
+        # The in-flight launch's spans, which wait for their round's key.
+        self._launch_spans: tuple = ()
         if mesh is None and (fsdp or seq_sharded):
             raise ValueError("fsdp/seq_sharded require a mesh (--mesh dp=...,tp=...)")
         if outer_optimizer not in ("none", "nesterov"):
@@ -397,6 +415,35 @@ class Trainer:
         self._outer_m = None
         self._take_snapshot(int(self.state.step))
 
+    # -- spans and profiler annotations (no-ops without a tracer) ------------
+
+    def _phase(self, name: str, **attrs: Any):
+        """Span + ``dvc:<name>`` annotation around a phase of this thread."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.phase(name, self._phase_trace, **attrs)
+
+    @contextlib.contextmanager
+    def _round_phase(self, name: str, trace: Optional[str] = None):
+        """A phase that belongs to a round: it and the phases opened inside
+        it carry the round's ``trace``, so one round is one tree from launch
+        to merge. None: the round has no key yet, and the spans wait for
+        ``_adopt_launch``."""
+        if trace is None:
+            trace = getattr(self.tracer, "PENDING", "")
+        prev, self._phase_trace = self._phase_trace, trace
+        try:
+            with self._phase(name) as sp:
+                yield sp
+        finally:
+            self._phase_trace = prev
+
+    def _mark(self, name: str):
+        """Annotation alone: the per-step phases."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.annotate(name)
+
     @staticmethod
     def _host_tree(tree: Any) -> Any:
         """Gather a pytree to host with every leaf's device-to-host DMA
@@ -418,10 +465,15 @@ class Trainer:
     def _take_snapshot(self, step_no: int) -> None:
         """D2H copy of params at a point where the buffers are live (between
         steps, on the trainer thread). One copy per averaging interval."""
-        self._snapshot = (
-            step_no,
-            self._host_tree(self.state.params),
-        )
+        with self._phase("loop.snapshot", step=step_no) as sp:
+            self._snapshot = (
+                step_no,
+                self._host_tree(self.state.params),
+            )
+            if sp is not None:
+                sp.attrs["bytes"] = sum(
+                    x.nbytes for x in jax.tree_util.tree_leaves(self._snapshot[1])
+                )
 
     def host_snapshot(self):
         """(step, host params pytree) — safe to read from any thread."""
@@ -437,10 +489,15 @@ class Trainer:
         """Replace params on device, keep opt_state/step/rng, refresh the
         cross-thread snapshot. The ONE place a merge becomes live state —
         the overlap and blocking paths must not diverge here."""
+        with self._phase("loop.merge.h2d"):
+            # What the host waits: device_put returns before the copy lands.
+            params = (
+                jax.device_put(new_params, self._param_shardings)
+                if self._param_shardings is not None
+                else jax.device_put(new_params)
+            )
         self.state = TrainState(
-            params=jax.device_put(new_params, self._param_shardings)
-            if self._param_shardings is not None
-            else jax.device_put(new_params),
+            params=params,
             opt_state=self.state.opt_state,
             step=self.state.step,
             rng=self.state.rng,
@@ -635,28 +692,51 @@ class Trainer:
         if self._last_merge_step is not None:
             self.steps_since_merge = max(1, step_no - self._last_merge_step)
 
+    def _adopt_launch(self, held: tuple) -> str:
+        """Record a launch's spans, which ended before their round had a
+        key, under the key the averager callback left in ``round_trace``
+        (``loop`` when no group formed). Returns that trace id."""
+        trace = self.round_trace or "loop"
+        if self.tracer is not None:
+            for sp in held:
+                self.tracer.adopt(sp, trace)
+        return trace
+
     def _run_average_round(self, tree: Any, step_no: int, what: str) -> Optional[Any]:
         """One WAN round: select payload -> averager -> record -> merge.
         Returns the merged tree, or None when no group formed / round failed.
+        A params round's merge is swapped in here, inside its merge phase.
 
         The payload crosses to HOST first — the AveragerFn contract is host
         numpy (the overlap path already guarantees it; for a mesh-sharded
         state this is also the gather from the slice's shards). D2H DMAs
         issue up front and drain in parallel (_host_tree)."""
-        payload = self._host_tree(self.bundle.avg_select(tree))
-        if what == "params":
-            self._note_window_progress(step_no)
+        with self._round_phase("loop.launch") as launch:
+            with self._phase("loop.launch.d2h") as d2h:
+                payload = self._host_tree(self.bundle.avg_select(tree))
+            if what == "params":
+                self._note_window_progress(step_no)
         t_avg = time.monotonic()
+        self.round_trace = None
         averaged = self.averager(payload, step_no)
+        trace = self._adopt_launch((launch, d2h))
         self.metrics.record_event(
             step_no, "avg_round",
             {"avg_s": time.monotonic() - t_avg, "ok": averaged is not None, "what": what},
         )
         if averaged is None:
             return None
-        if what == "params":
-            averaged = self._outer_transform(averaged)
-        return self.bundle.avg_merge(tree, jax.tree_util.tree_map(np.asarray, averaged))
+        with self._round_phase("loop.merge", trace):
+            with self._phase("loop.merge.host"):
+                if what == "params":
+                    averaged = self._outer_transform(averaged)
+                merged = self.bundle.avg_merge(
+                    tree, jax.tree_util.tree_map(np.asarray, averaged)
+                )
+            if what == "params":
+                self._swap_params(merged, step_no)
+                self._last_merge_step = step_no
+        return merged
 
     # -- overlapped averaging (params mode) --------------------------------
 
@@ -670,13 +750,17 @@ class Trainer:
         copies overlap the boundary step's still-dispatching tail, and the
         round then streams on the pool while the next step runs — the
         device never idles for the contribution transfer."""
-        payload0 = self._host_tree(self.bundle.avg_select(self.state.params))
-        self._note_window_progress(step_no)
-        t0 = time.monotonic()
-        fut = self._avg_pool.submit(
-            lambda: (self.averager(payload0, step_no), time.monotonic() - t0)
-        )
+        with self._round_phase("loop.launch") as launch:
+            with self._phase("loop.launch.d2h") as d2h:
+                payload0 = self._host_tree(self.bundle.avg_select(self.state.params))
+            self._note_window_progress(step_no)
+            t0 = time.monotonic()
+            self.round_trace = None
+            fut = self._avg_pool.submit(
+                lambda: (self.averager(payload0, step_no), time.monotonic() - t0)
+            )
         self._inflight = (step_no, payload0, fut)
+        self._launch_spans = (launch, d2h)
 
     def _finish_overlap_round(self, step_no: int, wait: bool = False) -> None:
         """Merge a completed round: new = averaged + (current - snapshot).
@@ -695,11 +779,13 @@ class Trainer:
             # margin here only guards against a wedged callback at exit.
             averaged, avg_s = fut.result(timeout=600.0 if wait else 0.0)
         except Exception as e:  # noqa: BLE001 — a failed round never kills training
+            self._adopt_launch(self._launch_spans)
             log.warning("overlapped averaging launched at step %d failed: %s", launch_step, errstr(e))
             self.metrics.record_event(
                 step_no, "avg_round", {"ok": False, "what": "params", "overlap": True}
             )
             return
+        trace = self._adopt_launch(self._launch_spans)
         staleness = step_no - launch_step
         ok = averaged is not None
         if ok and self.max_staleness and staleness > self.max_staleness:
@@ -717,13 +803,17 @@ class Trainer:
         # Outer step first, local-progress delta on top: the contraction
         # toward (outer-updated) consensus happens on the snapshot term,
         # the steps taken while the round was in flight are preserved.
-        averaged = self._outer_transform(averaged)
-        current = self._host_tree(self.bundle.avg_select(self.state.params))
-        merged_payload = jax.tree_util.tree_map(
-            lambda avg, cur, p0: np.asarray(avg, np.float32) + (cur - p0),
-            averaged, current, payload0,
-        )
-        self._swap_params(self.bundle.avg_merge(self.state.params, merged_payload), step_no)
+        with self._round_phase("loop.merge", trace):
+            with self._phase("loop.merge.d2h"):
+                current = self._host_tree(self.bundle.avg_select(self.state.params))
+            with self._phase("loop.merge.host"):
+                averaged = self._outer_transform(averaged)
+                merged_payload = jax.tree_util.tree_map(
+                    lambda avg, cur, p0: np.asarray(avg, np.float32) + (cur - p0),
+                    averaged, current, payload0,
+                )
+                merged = self.bundle.avg_merge(self.state.params, merged_payload)
+            self._swap_params(merged, step_no)
         # Progress up to the LAUNCH step entered the average (the delta term
         # above preserved the rest locally).
         self._last_merge_step = launch_step
@@ -793,7 +883,8 @@ class Trainer:
                         lambda *xs: jnp.stack(xs), *prefix
                     )
                     t_chunk = time.perf_counter()
-                    self.state, losses = self._multi_fn(self.state, stacked)
+                    with self._mark("dispatch"):
+                        self.state, losses = self._multi_fn(self.state, stacked)
                     ran_steps += n - 1
                     if self.averager is not None and self.average_interval_s > 0:
                         # One sync per chunk: the real chunk duration feeds
@@ -840,9 +931,10 @@ class Trainer:
                                     break
                     else:
                         self.metrics.count_samples(self.batch_size * (n - 1))
-            batch = next(it)
-            if self._put_batch is not None:
-                batch = self._put_batch(batch)
+            with self._mark("data"):
+                batch = next(it)
+                if self._put_batch is not None:
+                    batch = self._put_batch(batch)
             step_no = start_step + ran_steps + 1
             if profile_dir and not profiling and i == profile_start:
                 jax.profiler.start_trace(profile_dir)
@@ -863,11 +955,18 @@ class Trainer:
                 if step_no % self.average_every == 0:
                     self._take_snapshot(step_no)
             else:
-                self.state, m = self._step_fn(self.state, batch)
+                with self._mark("dispatch"):
+                    self.state, m = self._step_fn(self.state, batch)
             ran_steps += 1
             at_log_point = bool(log_every) and step_no % log_every == 0
             if sync_every_step or at_log_point:
-                last_loss = float(m["loss"])
+                # A span for the log point's sync only: with a sink or a
+                # target every step syncs, and a span a step floods the ring.
+                with (
+                    contextlib.nullcontext() if sync_every_step
+                    else self._phase("loop.log_sync", step=step_no)
+                ):
+                    last_loss = float(m["loss"])
                 self.metrics.record(step_no, m, n_samples=self.batch_size)
             else:
                 self.metrics.count_samples(self.batch_size)
@@ -897,11 +996,7 @@ class Trainer:
                         # would bootstrap thousands of steps behind.
                         self._take_snapshot(step_no)
                 elif self._avg_due(step_no):
-                    merged = self._run_average_round(self.state.params, step_no, "params")
-                    if merged is not None:
-                        self._swap_params(merged, step_no)
-                        self._last_merge_step = step_no
-                    else:
+                    if self._run_average_round(self.state.params, step_no, "params") is None:
                         # Snapshot at the cadence regardless of round outcome
                         # (see overlap branch).
                         self._take_snapshot(step_no)
