@@ -95,18 +95,20 @@ def stacked_init(layer_init, rng: jax.Array, n_layers: int) -> Params:
     return jax.vmap(layer_init)(keys)
 
 
-def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True) -> jax.Array:
+def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_outputs: bool = False):
     """Run ``x`` through stacked ``blocks`` with ``lax.scan``; ``body`` is
     ``(layer_params, x) -> x``. With ``remat`` each layer's activations are
     rematerialized in backward (checkpoint-per-scan-step), the standard
-    O(sqrt)-free layerwise remat that keeps HBM at one layer's activations."""
+    O(sqrt)-free layerwise remat that keeps HBM at one layer's activations.
+    ``with_outputs``: ``body`` returns ``(x, y)`` and the layers' ``y`` come
+    back stacked beside the final ``x``."""
     fn = jax.checkpoint(body) if remat else body
 
     def step(h, p):
-        return fn(p, h), None
+        return fn(p, h) if with_outputs else (fn(p, h), None)
 
-    x, _ = jax.lax.scan(step, x, blocks)
-    return x
+    x, ys = jax.lax.scan(step, x, blocks)
+    return (x, ys) if with_outputs else x
 
 
 def _project_vocab(x: jax.Array, head: jax.Array, head_layout: str) -> jax.Array:

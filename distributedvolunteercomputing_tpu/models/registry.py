@@ -142,6 +142,24 @@ def _gpt2_moe(**overrides: Any) -> ModelBundle:
     )
 
 
+def _olmoe(**overrides: Any) -> ModelBundle:
+    """OLMoE-1B-7B at its published sizes (models/olmoe.py): 16 layers need a
+    four-chip host; ``n_layers`` cuts the depth to what a chip holds."""
+    from distributedvolunteercomputing_tpu.models import olmoe
+    from distributedvolunteercomputing_tpu.training import data
+
+    cfg = dataclasses.replace(olmoe.OlmoeConfig(), **overrides)
+    return ModelBundle(
+        name="olmoe_1b_7b",
+        config=cfg,
+        init=lambda rng: olmoe.init(rng, cfg),
+        loss_fn=lambda p, b, rng: olmoe.loss_fn(p, b, rng, cfg),
+        make_batch=lambda rng, bs: data.synthetic_lm_batch(
+            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
+        ),
+    )
+
+
 def _vit(**overrides: Any) -> ModelBundle:
     from distributedvolunteercomputing_tpu.models import vit
     from distributedvolunteercomputing_tpu.training import data
@@ -188,6 +206,7 @@ _REGISTRY: Dict[str, Callable[..., ModelBundle]] = {
     "gpt2_medium": lambda **kw: _gpt2_preset("medium", **kw),
     "gpt2_large": lambda **kw: _gpt2_preset("large", **kw),
     "gpt2_moe": _gpt2_moe,
+    "olmoe_1b_7b": _olmoe,
     "llama_lora": _llama_lora,
 }
 

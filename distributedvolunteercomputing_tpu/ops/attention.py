@@ -255,8 +255,22 @@ def multi_head_attention(
     return merge_heads(out)
 
 
-def rope(x: jax.Array, positions: Optional[jax.Array] = None, base: float = 10000.0) -> jax.Array:
-    """Rotary position embedding over the last dim of ``x`` [B, H, T, D]."""
+def rope(
+    x: jax.Array,
+    positions: Optional[jax.Array] = None,
+    base: float = 10000.0,
+    layout: str = "interleaved",
+) -> jax.Array:
+    """Rotary position embedding over the last dim of ``x`` [B, H, T, D].
+
+    ``layout`` says which two coordinates a frequency rotates together:
+    ``"interleaved"`` pairs (2i, 2i+1) (the RoFormer paper; what the Llama
+    proxy here trains with), ``"half"`` pairs (i, i + D/2) (``rotate_half`` in
+    the public GPT-NeoX / OLMoE implementations). The two are one rotation
+    under a fixed permutation of each head's coordinates, so a model trained
+    from scratch may use either; weights published for one need that one."""
+    if layout not in ("interleaved", "half"):
+        raise ValueError(f"unknown rope layout {layout!r}")
     d = x.shape[-1]
     t = x.shape[-2]
     if positions is None:
@@ -264,7 +278,12 @@ def rope(x: jax.Array, positions: Optional[jax.Array] = None, base: float = 1000
     freqs = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, D/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+    xf = x.astype(jnp.float32)
+    if layout == "half":
+        x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.astype(x.dtype)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
     rx1 = x1 * cos - x2 * sin
     rx2 = x1 * sin + x2 * cos
     out = jnp.stack([rx1, rx2], axis=-1).reshape(x.shape)

@@ -36,6 +36,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 # the TRAILING dims and right-aligned by _fit_spec, so the same rule covers a
 # stacked leaf and an unstacked one (e.g. lm_head, which has no layer axis).
 _RULES: List[Tuple[str, P]] = [
+    # OLMoE's SwiGLU expert stacks [E, d, f] / [E, f, d] (models/olmoe.py):
+    # before the dense w_gate / w_up / w_down rules, which the names also match.
+    (r".*/experts/(w_gate|w_up)$", P("ep", None, "tp")),
+    (r".*/experts/w_down$", P("ep", "tp", None)),
     (r".*/(qkv|mlp_in)/w$", P(None, "tp")),
     (r".*/(qkv|mlp_in)/b$", P("tp")),
     (r".*/(attn_out|mlp_out)/w$", P("tp", None)),

@@ -775,9 +775,9 @@ class Telemetry:
             self.registry, self.recorder, peer_id,
             enabled=bool(enabled and watchdog_enabled), clock=clock,
         )
-        if self.watchdog.enabled:
-            # Ended round spans feed the per-level wall detectors.
-            self.tracer.on_record = self.watchdog.observe_span
+        # Ended spans feed the watchdog's per-level wall detectors and, from
+        # a sparse-expert model's ``moe.route`` spans, the routing gauges.
+        self.tracer.on_record = self._observe_span
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         """Adopt the ClockSync-corrected clock once the volunteer builds
@@ -805,6 +805,45 @@ class Telemetry:
                 "swarm.attention_core",
                 "traced attention calls by the core that took them",
             ).inc(impl=impl, T=str(t), D=str(d), dtype=dtype)
+
+    def count_moe_dispatch(self, impl: str, n_experts: int, top_k: int, rows: int) -> None:
+        """One TRACED expert dispatch took grouped matmul ``impl``
+        ("megablox" | "ragged_dot"): ops.moe_dispatch's observer."""
+        if self.enabled:
+            self.registry.counter(
+                "swarm.moe_dispatch",
+                "traced expert dispatches by the grouped matmul that took them",
+            ).inc(impl=impl, E=str(n_experts), k=str(top_k), rows=str(rows))
+
+    def _observe_span(self, sp: dict) -> None:
+        if self.watchdog.enabled:
+            self.watchdog.observe_span(sp)
+        if sp.get("name") == "moe.route":
+            attrs = sp.get("attrs") or {}
+            mean = float(attrs.get("moe_load_mean") or 0.0)
+            if mean > 0:
+                self.registry.gauge(
+                    "swarm.moe_load_max_over_mean",
+                    "fullest expert's rows over the even share, at the last log point",
+                ).set(float(attrs.get("moe_load_max", 0.0)) / mean)
+            dropped = self.registry.gauge(
+                "swarm.moe_dropped_total", "routed rows no expert computed, over all log points"
+            )
+            dropped.set((dropped.value() or 0.0) + float(attrs.get("moe_dropped", 0.0)))
+
+    def moe(self) -> dict:
+        """Routing of a sparse-expert model: traced dispatches per grouped
+        matmul, and the two gauges; empty for a dense model."""
+        out: Dict[str, Any] = {}
+        for rec in self.registry.counter("swarm.moe_dispatch")._scrape()["values"]:
+            by = out.setdefault("dispatch", {})
+            impl = rec["labels"].get("impl", "?")
+            by[impl] = by.get(impl, 0) + int(rec["value"])
+        for key in ("load_max_over_mean", "dropped_total"):
+            v = self.registry.gauge(f"swarm.moe_{key}").value()
+            if v is not None:
+                out[key] = v
+        return out
 
     def attention_cores(self) -> Dict[str, int]:
         """Traced attention calls per core, all shapes together."""
@@ -915,6 +954,8 @@ class Telemetry:
             "spans": spans,
             # how often the fused attention core engaged, in traced calls
             "attention_core": self.attention_cores(),
+            # a sparse-expert model's dispatches and routing gauges ({} if dense)
+            "moe": self.moe(),
         }
 
 

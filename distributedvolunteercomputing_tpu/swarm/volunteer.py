@@ -521,6 +521,9 @@ class Volunteer:
         from distributedvolunteercomputing_tpu.ops.attention import set_core_observer
 
         set_core_observer(self.telemetry.count_attention_core if cfg.telemetry else None)
+        from distributedvolunteercomputing_tpu.ops.moe_dispatch import set_dispatch_observer
+
+        set_dispatch_observer(self.telemetry.count_moe_dispatch if cfg.telemetry else None)
         self._metrics_server = None
         # Structured-log identity: with DVC_LOG_JSON=1 every line this
         # process emits carries who/where, join-able against traces.
@@ -1275,6 +1278,11 @@ class Volunteer:
             # Traced attention calls by core ({"flash": n} or {"xla": n};
             # empty with telemetry off).
             self.summary["attention_core"] = self.telemetry.attention_cores()
+            moe = self.telemetry.moe()
+            if moe:
+                # a sparse-expert model: traced dispatches by grouped matmul,
+                # fullest expert over the even share, rows dropped (0: dropless)
+                self.summary["moe"] = moe
             self.summary["peak_bytes_in_use"] = (
                 jax.local_devices()[0].memory_stats() or {}
             ).get("peak_bytes_in_use")

@@ -423,6 +423,18 @@ class Trainer:
             return contextlib.nullcontext()
         return self.tracer.phase(name, self._phase_trace, **attrs)
 
+    def _record_routing(self, step_no: int, m: Dict[str, Any]) -> None:
+        """A sparse-expert step's routing statistics as the attributes of a
+        ``moe.route`` span (child of ``loop.log_sync`` at a log point, where
+        the loss has just been read: the step's other outputs are ready)."""
+        attrs = {
+            k: float(m[k])
+            for k in ("moe_load_max", "moe_load_mean", "moe_dropped", "aux_loss", "lm_loss")
+            if k in m
+        }
+        with self._phase("moe.route", step=step_no, **attrs):
+            pass
+
     @contextlib.contextmanager
     def _round_phase(self, name: str, trace: Optional[str] = None):
         """A phase that belongs to a round: it and the phases opened inside
@@ -982,6 +994,8 @@ class Trainer:
                     else self._phase("loop.log_sync", step=step_no)
                 ):
                     last_loss = float(m["loss"])
+                    if at_log_point and "moe_load_max" in m:
+                        self._record_routing(step_no, m)
                 self.metrics.record(step_no, m, n_samples=self.batch_size)
             else:
                 self.metrics.count_samples(self.batch_size)

@@ -99,7 +99,7 @@ def as_on_the_chip(monkeypatch):
     monkeypatch.setattr(pallas_attention, "tpu_backend", lambda: True)
 
 
-def _step_text(v5e, model: str, dp: int, tp: int, batch: int) -> str:
+def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2) -> str:
     """The compiled HLO of the sharded train step of ``model`` (two layers:
     the scanned block appears once whatever the depth) on a dp x tp mesh of
     described chips."""
@@ -114,7 +114,7 @@ def _step_text(v5e, model: str, dp: int, tp: int, batch: int) -> str:
     from distributedvolunteercomputing_tpu.training.steps import TrainState
 
     mesh = Mesh(np.asarray(v5e[: dp * tp]).reshape(dp, 1, 1, 1, tp), AXES)
-    bundle = get_model(model, n_layers=2)
+    bundle = get_model(model, n_layers=n_layers)
     tx = make_optimizer("adam", lr=1e-3)
     abstract = jax.eval_shape(
         lambda: TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(1))
@@ -154,6 +154,31 @@ def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     calls = _kernel_calls(_step_text(v5e, "gpt2_medium", 1, 1, 16))
     assert len(calls) == 3
     assert all("bf16[16,16,1024,64]" in ln for ln in calls)
+
+
+def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
+    """olmoe-solo's step (one layer of OLMoE-1B-7B at its published widths,
+    4 x 4,096 tokens): the fused attention core at head dim 128 and T=4,096,
+    forward, recomputed forward and backward, and twelve megablox calls over
+    the 131,072 routed rows (gate, up, down: forward, recomputed forward, the
+    backward by the rows' side; three by the weights' side), under the names
+    the benchmark's readers match. That it compiles says it fits the chip."""
+    import re
+
+    from benchmark import moe_trace
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    # the choice itself counts this host's 8 CPUs as chips
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    calls = _kernel_calls(_step_text(v5e, "olmoe_1b_7b", 1, 1, 4, n_layers=1))
+    names = [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
+    flash = [n for n in names if n.startswith("dvc_flash_")]
+    gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
+    assert len(flash) == 3 and len(gmm) == 12 and len(names) == 15, names
+    assert sum(n.startswith("tgmm") for n in gmm) == 3
+    assert all("bf16[4,16,4096,128]" in ln for ln in calls if "dvc_flash_" in ln)
+    assert all("[131072," in ln or "bf16[64," in ln for ln in calls if "gmm" in ln)
 
 
 def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
