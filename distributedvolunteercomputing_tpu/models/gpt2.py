@@ -2,7 +2,7 @@
 (BASELINE.json:10, north_star: GPT-2-small on 4x v4-8 volunteer slices).
 
 Pre-LN transformer decoder with learned positional embeddings and tied
-input/output embeddings. Flagship model for bench.py and __graft_entry__.
+input/output embeddings. Flagship model for the benchmark's cells and __graft_entry__.
 
 TPU-first layout decisions:
 - Blocks are ONE stacked pytree scanned with ``lax.scan`` (common.scan_blocks)

@@ -108,7 +108,7 @@ def _gpt2(**overrides: Any) -> ModelBundle:
 def _gpt2_preset(preset: str, **overrides: Any) -> ModelBundle:
     """gpt2_medium / gpt2_large as first-class registry names: the scale
     rungs above the flagship (GPT2Config.medium/.large presets), nameable
-    from the CLI (--model) and the bench (DVC_BENCH_MODEL) without a
+    from the CLI (--model) and a benchmark configuration without a
     config-override incantation. Overrides still apply on top."""
     from distributedvolunteercomputing_tpu.models import gpt2
     from distributedvolunteercomputing_tpu.training import data

@@ -4,8 +4,8 @@ The library is compiled ON FIRST USE with the system g++ (no pybind11 in the
 environment — plain C ABI + ctypes, per SURVEY.md §2's native-code
 checklist) and cached next to the source; a stale .so (older than the .cpp)
 is rebuilt. Every caller goes through ``get_lib()`` and falls back to numpy
-when the toolchain is missing or ``DVC_NATIVE=0`` — the native core is a
-throughput upgrade for the WAN path, never a hard dependency.
+when the library did not build or load — the native core is a throughput
+upgrade for the WAN path, never a hard dependency.
 """
 
 from __future__ import annotations
@@ -124,7 +124,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
     run mid-round. Use ensure_built() at process start to wait for it.
     """
     global _builder
-    if _done or os.environ.get("DVC_NATIVE", "1") == "0":
+    if _done:
         return _lib
     with _lock:
         if _done:

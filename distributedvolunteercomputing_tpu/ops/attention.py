@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -66,8 +65,8 @@ def step_mesh(mesh):
 # (pallas kernel, ops/pallas_attention.py), or "auto": flash on a TPU for
 # mask-free square attention from the sequence length at which it was
 # measured to win, xla otherwise (masked attention, rectangular causal, short
-# sequences, every other backend). DVC_ATTN_IMPL=xla|flash forces either core.
-_impl = os.environ.get("DVC_ATTN_IMPL", "auto")
+# sequences, every other backend). ``set_attention_impl`` forces either core.
+_impl = "auto"
 # Crossover for auto routing, for the dtypes it was measured in (MEASURED, PR 27,
 # TPU v5e: experiments/attention_sweep.py; the table is in PERF.md,
 # Findings of PR 27): forward + backward of both cores inside a rematerialised

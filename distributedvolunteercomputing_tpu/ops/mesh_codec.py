@@ -50,12 +50,12 @@ stacks split their tile dim the same way with the peer dim replicated. A
 single-device mesh degenerates to plain jit with zero overhead, so one code
 path serves the 8-chip slice and the laptop volunteer alike.
 
-Backend selection happens ONCE per volunteer at startup (``configure`` /
-``select_backend``): ``"mesh"`` when the default jax backend is TPU silicon
-(``utils.jaxenv.tpu_backend``) or when forced via ``DVC_MESH_CODEC=1``;
-``"host"`` otherwise (and always under ``DVC_MESH_CODEC=0``) — the host
-path delegates straight to ``native``/``ops.robust`` numpy, so a
-CPU-platform tier-1 run never pays a jit compile it didn't ask for.
+Backend selection happens ONCE per volunteer at startup (``configure``), in
+``choose_data_path``: ``"mesh"`` when the default jax backend is TPU silicon
+(``utils.jaxenv.tpu_backend``), ``"host"`` otherwise — the host path
+delegates straight to ``native``/``ops.robust`` numpy, so a CPU-platform
+tier-1 run never pays a jit compile it didn't ask for. A constructor
+argument forces a path (benches, equivalence tests).
 
 Degraded-slice fallback (mesh-networks paper, PAPERS.md: slice-level
 failures are a normal operating mode, not a crash): every device op runs
@@ -75,7 +75,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import os
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -135,7 +134,7 @@ def _batcher_pairs(m: int) -> List[Tuple[int, int]]:
 # versions exist for the TPU backend, where explicit (rows, 128)-lane
 # blocking keeps the codec's VMEM footprint bounded and off the train
 # step's working set. They are gated (``_pallas_mode``): compiled on TPU
-# silicon, interpreted under DVC_MESH_PALLAS=interpret (CPU equivalence
+# silicon, interpreted under ``pallas="interpret"`` (CPU equivalence
 # tests), and skipped otherwise.
 
 _PALLAS_LANES = 128
@@ -177,12 +176,28 @@ def _bf16_widen(bits):
     return jax.lax.bitcast_convert_type(bits, _jnp().bfloat16).astype(_jnp().float32)
 
 
+def choose_data_path(n_devices: int) -> Tuple[str, str, str]:
+    """``(backend, pallas, collective)`` for a codec over ``n_devices`` local
+    devices, from what the process can observe. On TPU silicon: the mesh
+    backend, the compiled Pallas codec kernels, and the ring fold where the
+    codec axis has a neighbour to send to. Elsewhere: the host path, no
+    kernels, the staged folder. The one place this is decided: a
+    ``MeshCodec`` argument overrides its part, a test patches this."""
+    from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
+
+    if not tpu_backend():
+        return "host", "off", "off"
+    return "mesh", "compiled", "ring" if n_devices >= 2 else "off"
+
+
 class MeshCodec:
     """One volunteer's on-mesh codec + fold engine (or its host fallback).
 
-    ``backend``: "auto" (mesh on TPU silicon / DVC_MESH_CODEC=1, host
-    otherwise), "mesh" (force the device path — used by benches and
-    equivalence tests on the CPU platform), or "host". ``mesh`` is the
+    ``backend`` ("mesh" | "host"), ``pallas`` ("compiled" | "interpret" |
+    "off": the bf16 kernels' lowering) and ``collective`` ("ring" | "off":
+    the fused reduce pipeline, ops.mesh_collective) are each what
+    ``choose_data_path`` says unless given — benches and equivalence tests
+    force the device path on the CPU platform that way. ``mesh`` is the
     volunteer's training Mesh; its devices are re-viewed as the 1-D codec
     axis. ``None`` uses the default jax device only.
     """
@@ -190,12 +205,22 @@ class MeshCodec:
     def __init__(
         self,
         mesh=None,
-        backend: str = "auto",
+        backend: Optional[str] = None,
         pallas: Optional[str] = None,
         collective: Optional[str] = None,
     ):
-        if backend not in ("auto", "mesh", "host"):
-            raise ValueError(f"unknown mesh-codec backend {backend!r}")
+        for name, value, allowed in (
+            ("backend", backend, ("mesh", "host")),
+            ("pallas", pallas, ("compiled", "interpret", "off")),
+            ("collective", collective, ("ring", "off")),
+        ):
+            if value is not None and value not in allowed:
+                raise ValueError(f"unknown mesh-codec {name} {value!r}")
+        n_devices = 1 if mesh is None else int(np.asarray(mesh.devices).size)
+        auto_backend, auto_pallas, auto_collective = choose_data_path(n_devices)
+        self._backend = backend or auto_backend
+        self._pallas_mode = pallas or auto_pallas
+        self._collective = collective or auto_collective
         self._lock = threading.Lock()
         self._mesh_arg = mesh
         self._codec_mesh = None  # built lazily on first device op
@@ -223,64 +248,13 @@ class MeshCodec:
         # Ring-lowering gauges, written by RingMeanFolder: the configured
         # lowering, the last lowering actually used, and how many flushes
         # were quietly re-lowered to xla by the VMEM estimate. Without
-        # these a fleet pinned to xla by DVC_RING_VMEM_MB (or a mis-sized
+        # these a fleet re-lowered to xla by the VMEM cap (or a mis-sized
         # estimate) is indistinguishable from one running the kernel.
         self.ring_lower: Optional[str] = None
         self.ring_lower_effective: Optional[str] = None
         self.ring_lower_fallback: Optional[str] = None
         self.ring_vmem_fallbacks = 0
         self._ring_vmem_warned = False
-        self._pallas_mode = self._resolve_pallas(pallas)
-        self._backend = self._resolve_backend(backend)
-        self._collective = self._resolve_collective(collective)
-
-    # -- selection ---------------------------------------------------------
-
-    @staticmethod
-    def _resolve_backend(backend: str) -> str:
-        if backend != "auto":
-            return backend
-        env = os.environ.get("DVC_MESH_CODEC", "").strip().lower()
-        if env in ("0", "host", "off"):
-            return "host"
-        if env in ("1", "mesh", "on"):
-            return "mesh"
-        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
-
-        return "mesh" if tpu_backend() else "host"
-
-    @staticmethod
-    def _resolve_pallas(pallas: Optional[str]) -> str:
-        """"compiled" | "interpret" | "off" — the bf16 kernel lowering."""
-        if pallas is None:
-            pallas = os.environ.get("DVC_MESH_PALLAS", "auto").strip().lower()
-        if pallas in ("interpret", "0", "off", "1", "on"):
-            return {"1": "compiled", "on": "compiled", "0": "off", "off": "off"}.get(
-                pallas, "interpret"
-            )
-        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
-
-        return "compiled" if tpu_backend() else "off"
-
-    @staticmethod
-    def _resolve_collective(collective: Optional[str]) -> str:
-        """"ring" | "off" — the fused reduce pipeline (ops.mesh_collective).
-
-        Explicit "ring"/"off" wins; otherwise DVC_MESH_COLLECTIVE, then
-        auto: ring on TPU silicon (where the remote-DMA kernel compiles),
-        off elsewhere — the CPU test/bench planes opt in explicitly so the
-        PR 5 staged folder stays the default sharded path off-silicon."""
-        if collective is None:
-            collective = os.environ.get("DVC_MESH_COLLECTIVE", "auto").strip().lower()
-        if collective in ("ring", "1", "on"):
-            return "ring"
-        if collective in ("off", "0", "none", "host"):
-            return "off"
-        if collective != "auto":
-            raise ValueError(f"unknown mesh collective {collective!r}")
-        from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
-
-        return "ring" if tpu_backend() else "off"
 
     @property
     def backend(self) -> str:
@@ -1118,8 +1092,8 @@ _default_lock = threading.Lock()
 
 
 def get_default() -> MeshCodec:
-    """The process's codec; built on first use with auto backend selection
-    (host unless the default backend is TPU silicon or DVC_MESH_CODEC=1)."""
+    """The process's codec; built on first use with ``choose_data_path``'s
+    selection (host unless the default backend is TPU silicon)."""
     global _default
     if _default is None:
         with _default_lock:
@@ -1130,7 +1104,7 @@ def get_default() -> MeshCodec:
 
 def configure(
     mesh=None,
-    backend: str = "auto",
+    backend: Optional[str] = None,
     pallas: Optional[str] = None,
     collective: Optional[str] = None,
 ) -> MeshCodec:

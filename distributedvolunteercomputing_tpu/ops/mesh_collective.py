@@ -24,8 +24,7 @@ kernel (``_ring_ag_kernel``) is the matching ring all-gather used by
 ``result()`` — one device pass reassembles the full accumulator so the
 round result crosses the host link once.
 
-Lowering ladder (``DVC_RING_LOWER`` overrides; auto follows the codec's
-pallas mode):
+Lowering ladder (the codec's pallas mode decides):
 
 - ``compiled``  — the Pallas kernel on TPU silicon: remote DMA, an entry
   barrier with both ring neighbors, and a REGULAR capacity-semaphore
@@ -52,7 +51,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import List, Tuple
 
 import numpy as np
@@ -69,9 +67,10 @@ log = logging.getLogger("dvc.mesh_collective")
 # Compiled-mode working-set cap: buffers above this fall back to the xla
 # lowering rather than risk a VMEM OOM mid-round (the ring kernel keeps two
 # partial slots + the scratch partial + the accumulator shard resident).
-_VMEM_CAP_BYTES = int(
-    float(os.environ.get("DVC_RING_VMEM_MB", "10")) * (1 << 20)
-)
+# 10 MB: set with the kernel (PR 18) and the one value any run has used;
+# ``_lower_for`` counts against it what the kernel allocates. At the 1 MiB
+# chunk on four chips it covers three tiles.
+_VMEM_CAP_BYTES = 10 << 20
 _LANES = 128  # vector lane width: the kernel's (rows, lanes) block minor dim
 
 
@@ -304,14 +303,9 @@ class RingMeanFolder(MeshMeanFolder):
 
     @staticmethod
     def _resolve_lower(codec) -> str:
-        env = os.environ.get("DVC_RING_LOWER", "auto").strip().lower()
-        if env == "xla":
-            return "xla"
-        if env == "pallas":
-            return "compiled" if codec._pallas_mode == "compiled" else "interpret"
-        return {"compiled": "compiled", "interpret": "interpret"}.get(
-            codec._pallas_mode, "xla"
-        )
+        """The codec's Pallas mode is the ring's: the kernel compiled,
+        the kernel interpreted, or the collective left to XLA."""
+        return "xla" if codec._pallas_mode == "off" else codec._pallas_mode
 
     def _lower_for(self, per_dev: int) -> str:
         """The flush lowering for one batch size: compiled falls back to
@@ -332,8 +326,8 @@ class RingMeanFolder(MeshMeanFolder):
     def _note_vmem_fallback(self, site: str, est: int) -> None:
         """Book a compiled->xla re-lowering on the codec gauges and warn
         exactly once per codec — the fallback is correct but should never
-        be silent, or a whole fleet pinned to xla by DVC_RING_VMEM_MB
-        reads as if the kernel were live."""
+        be silent, or a whole fleet re-lowered to xla reads as if the
+        kernel were live."""
         codec = self.codec
         reason = "%s working set %.1fMB > VMEM cap %.0fMB" % (
             site,
@@ -345,11 +339,7 @@ class RingMeanFolder(MeshMeanFolder):
         codec.ring_vmem_fallbacks += 1
         if not codec._ring_vmem_warned:
             codec._ring_vmem_warned = True
-            log.warning(
-                "ring lowering fell back compiled->xla: %s "
-                "(raise DVC_RING_VMEM_MB to keep the kernel)",
-                reason,
-            )
+            log.warning("ring lowering fell back compiled->xla: %s", reason)
 
     # -- eager ingest (xla lowering) --------------------------------------
 

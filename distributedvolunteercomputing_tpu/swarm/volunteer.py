@@ -191,18 +191,6 @@ class VolunteerConfig:
     # the mesh's dp axis (ZeRO-3); ``seq_sharded`` turns on ring attention
     # over its sp axis.
     mesh: str = ""
-    # On-mesh swarm data path (ops.mesh_codec): run the bf16 wire codec,
-    # PowerSGD matmuls, and the leader's tile folds on this volunteer's
-    # local device mesh. "auto" selects mesh on TPU silicon and host on
-    # CPU platforms; "mesh"/"host" force. Selected once at startup,
-    # surfaced in stats()["mesh_codec"], degrades to host on slice failure.
-    mesh_codec: str = "auto"
-    # Fused ring reduce pipeline for the leader's mean folds
-    # (ops/mesh_collective.py): decode + fold + neighbor-forward in one
-    # pallas grid step over the codec mesh. "auto" selects ring on TPU
-    # silicon with >= 2 codec devices; "ring"/"off" force. Rides the
-    # mesh codec's degraded-slice contract.
-    mesh_collective: str = "auto"
     fsdp: bool = False
     seq_sharded: bool = False
     sp_impl: str = "ring"  # ring | ulysses (all-to-all seq<->heads)
@@ -837,23 +825,25 @@ class Volunteer:
             )
 
             mesh = make_mesh(**parse_mesh_spec(self.cfg.mesh))
-        # Select THIS volunteer's swarm data-path backend now that the
-        # local mesh exists (the averager resolves the process default
-        # lazily, so configuring here covers the averager built earlier).
+        # Build THIS volunteer's swarm data path (ops.mesh_codec: the bf16
+        # wire codec, PowerSGD matmuls and the leader's tile folds on the
+        # local device mesh where that is TPU silicon, host numpy elsewhere)
+        # now that the local mesh exists. The averager resolves the process
+        # default lazily, so configuring here covers the averager built
+        # earlier. Surfaced in stats()["mesh_codec"]; degrades to host on
+        # slice failure.
         from distributedvolunteercomputing_tpu.ops import mesh_codec as mesh_codec_mod
 
-        codec = mesh_codec_mod.configure(
-            mesh=mesh,
-            backend=self.cfg.mesh_codec,
-            collective=self.cfg.mesh_collective,
-        )
+        codec = mesh_codec_mod.configure(mesh=mesh)
         # Slice-loss degrades land in this volunteer's flight recorder, the
         # codec's device ops in its span ring.
         codec.recorder = self.telemetry.recorder
         codec.tracer = self.telemetry.tracer
+        chosen = codec.stats()
         log.info(
-            "swarm data path: %s backend (mesh=%s)",
-            codec.backend, self.cfg.mesh or "single-device",
+            "swarm data path: %s backend, pallas %s, collective %s (mesh=%s)",
+            codec.backend, chosen["pallas"], chosen["collective"],
+            self.cfg.mesh or "single-device",
         )
         self.trainer = Trainer(
             bundle,

@@ -102,8 +102,8 @@ class Trainer:
         seed: int = 0,
         init_seed: int = 0,
         # Cast floating params to this dtype after init ("bfloat16" for
-        # bf16 training — the bench's DVC_BENCH_PARAM_DTYPE arm, now a
-        # first-class trainer/CLI option); None keeps the model's dtype.
+        # bf16 training, the CLI's --param-dtype); None keeps the model's
+        # dtype.
         param_dtype: Optional[str] = None,
         # Microbatch count per optimizer step (gradient accumulation inside
         # the compiled step); batch_size must divide evenly. Semantics match

@@ -90,9 +90,8 @@ def tree_zeros_like(tree: Any) -> Any:
 def cast_floating(tree: Any, dtype: Any) -> Any:
     """Cast every FLOATING leaf to ``dtype``, leaving integer tables, bools,
     and step counters untouched — the one bf16-training cast shared by the
-    Trainer's param_dtype, the bench's DVC_BENCH_PARAM_DTYPE arm, and
-    checkpoint restore (which must re-apply a configured dtype over a
-    snapshot taken under another one)."""
+    Trainer's param_dtype and checkpoint restore (which must re-apply a
+    configured dtype over a snapshot taken under another one)."""
     import jax.numpy as jnp
 
     dt = jnp.dtype(dtype)

@@ -233,7 +233,7 @@ def _assert_ring_interpret_equivalence(mesh, n_devices: int) -> None:
     n_elems = tile * n_tiles
     folder = codec.mean_folder(n_elems, tile, n_tiles, "bf16")
     assert folder.kind == "ring", f"ring folder not selected: {folder.kind}"
-    # Pin the pallas interpret lowering regardless of DVC_RING_LOWER.
+    # The interpreted kernel, folding at flush (no eager ingest).
     folder._lower_cfg, folder._eager = "interpret", False
     rng = np.random.default_rng(3)
     weights = rng.uniform(0.2, 1.0, 3)

@@ -160,20 +160,6 @@ def main() -> None:
                     help="in-slice device mesh spec, e.g. dp=2,tp=2 — shards "
                          "the step over this volunteer's local chips (TPU "
                          "slice); empty = single device")
-    ap.add_argument("--mesh-codec", default="auto", choices=("auto", "mesh", "host"),
-                    help="swarm data-path backend: run the bf16 wire codec, "
-                         "PowerSGD matmuls, and leader tile folds on the "
-                         "local device mesh (auto = mesh on TPU silicon, "
-                         "host numpy otherwise; degrades to host on slice "
-                         "failure)")
-    ap.add_argument("--mesh-collective", default="auto",
-                    choices=("auto", "ring", "off"),
-                    help="fused reduce pipeline for leader mean folds: ring "
-                         "reduce-scatter kernel that decodes, folds, and "
-                         "forwards wire tiles in one device pass over the "
-                         "codec mesh (auto = ring on TPU silicon with >= 2 "
-                         "devices, staged path otherwise; degrades with the "
-                         "mesh codec)")
     ap.add_argument("--fsdp", action="store_true",
                     help="ZeRO-3: shard params+optimizer over the mesh's dp "
                          "axis (weights, grads, opt state at 1/dp per chip)")
@@ -358,8 +344,6 @@ def main() -> None:
         batch_size=args.batch_size,
         accum_steps=args.accum_steps,
         mesh=args.mesh,
-        mesh_codec=args.mesh_codec,
-        mesh_collective=args.mesh_collective,
         fsdp=args.fsdp,
         seq_sharded=args.seq_sharded,
         sp_impl=args.sp_impl,

@@ -482,8 +482,7 @@ class TestTrainerLocalSGD:
 
 
 def test_trainer_param_dtype_bf16():
-    """--param-dtype bfloat16: params AND optimizer moments run in bf16
-    (the bench's DVC_BENCH_PARAM_DTYPE arm as a first-class option);
+    """--param-dtype bfloat16: params AND optimizer moments run in bf16;
     training stays finite and integer leaves keep their dtypes."""
     import jax
     import jax.numpy as jnp

@@ -279,7 +279,7 @@ def test_gpt2_presets_have_expected_scale():
 
 def test_gpt2_scale_presets_are_registry_names():
     """gpt2_medium / gpt2_large are first-class registry names (r5: the
-    bench's DVC_BENCH_MODEL and the CLI's --model can name the scale rungs
+    CLI's --model and a benchmark configuration can name the scale rungs
     directly), overrides still apply on top, and a tiny-config step runs."""
     import jax
     import numpy as np

@@ -11,7 +11,7 @@ kernels, and the ring fold / ring all-gather on a 2x2 codec mesh at the
 takes the kernel, not that its results are right (chip_smoke.py does that).
 
 The program's backend checks see the CPU here, so each test steers
-compiled-vs-interpret itself (``interpret=False``, ``pallas="on"``).
+compiled-vs-interpret itself (``interpret=False``, ``pallas="compiled"``).
 """
 
 import os
@@ -232,7 +232,7 @@ def test_round_programs_copy_and_donate(v5e):
 @pytest.mark.parametrize("kernel", ["encode", "decode_axpy"])
 def test_codec_bf16_kernels(v5e, kernel):
     codec = mesh_codec.MeshCodec(
-        mesh=Mesh(np.asarray(v5e[:1]), ("x",)), backend="mesh", pallas="on"
+        mesh=Mesh(np.asarray(v5e[:1]), ("x",)), backend="mesh", pallas="compiled"
     )
     n = 4 * 512 * 128  # whole (512, 128) blocks
     assert codec._pallas_mode == "compiled" and codec._pallas_eligible(n)
@@ -252,7 +252,7 @@ def ring_folder(v5e):
     """A RingMeanFolder on the 2x2 host's codec mesh, compiled lowering, at
     the real tile size: 3 tiles of one wire chunk each."""
     codec = mesh_codec.MeshCodec(
-        mesh=Mesh(np.asarray(v5e), ("codec",)), backend="mesh", pallas="on",
+        mesh=Mesh(np.asarray(v5e), ("codec",)), backend="mesh", pallas="compiled",
         collective="ring",
     )
     folder = RingMeanFolder(codec, 3 * CHUNK_ELEMS, CHUNK_ELEMS, 3, "bf16")

@@ -247,16 +247,14 @@ class TestTrainLoopSpans:
 
 
 class TestCodecSpans:
-    def test_codec_ops_carry_the_rounds_trace_id(self, monkeypatch):
+    def test_codec_ops_carry_the_rounds_trace_id(self):
         """A two-peer bf16 sync round on the mesh codec: every hop from the
         round into the codec is ``asyncio.to_thread``, which copies the
         context. This fails if one stops carrying it."""
-        monkeypatch.setenv("DVC_MESH_CODEC", "1")
-
         async def main():
             vols = await spawn(2, wire="bf16")
             for v in vols:
-                v["avg"]._mesh_codec = MeshCodec()
+                v["avg"]._mesh_codec = MeshCodec(backend="mesh")
                 v["avg"]._register_telemetry()
                 assert v["avg"].mesh_codec.backend == "mesh"
             try:
