@@ -17,6 +17,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from distributedvolunteercomputing_tpu.ops import attention as attention_ops
 
 Params = Dict[str, Any]
 
@@ -44,6 +47,43 @@ def dense(p: Params, x: jax.Array, dtype: Optional[jnp.dtype] = None) -> jax.Arr
     dtype = dtype or compute_dtype()
     y = jnp.dot(x.astype(dtype), p["w"].astype(dtype))
     return y + p["b"].astype(dtype)
+
+
+def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """q, k, v as [B, H, T, hd] from a fused ``{"w": [d, 3d], "b": [3d]}``
+    leaf (columns q|k|v, each head-major) and the normed input [B, T, d].
+
+    Under a traced step whose mesh divides the heads over ``tp``
+    (``attention_ops.heads_tp``) the projection runs BY HEAD: the weight is
+    viewed as [d, 3, H, hd] and laid out over ``tp`` on H, so each chip
+    multiplies by the q, k and v columns of its own heads and the result is
+    born in the layout the per-shard kernel and the row-parallel ``attn_out``
+    want. The stored leaf is sharded on 3d in contiguous parts (chip 0 of a
+    pair holds all of q and half of k), so as one [d, 3d] product half of q
+    and of v, activations, cross the link in every pass of every layer; by
+    head only the weight's shards do. Elsewhere (no step mesh, ``tp`` 1 or
+    manual, heads that ``tp`` does not divide) it is one [d, 3d] product,
+    split, and ``split_heads``."""
+    tp = attention_ops.heads_tp()
+    by_head = tp > 1 and n_heads % tp == 0
+    attention_ops.observe_qkv("by_head" if by_head else "fused", tp)
+    if not by_head:
+        q, k, v = jnp.split(dense(p, x), 3, axis=-1)
+        return tuple(attention_ops.split_heads(a, n_heads) for a in (q, k, v))
+    dtype = compute_dtype()
+    d = x.shape[-1]
+    hd = p["b"].shape[-1] // (3 * n_heads)
+    w = attention_ops.constrain_in_step(
+        p["w"].astype(dtype).reshape(d, 3, n_heads, hd), P(None, None, "tp", None)
+    )
+    b = attention_ops.constrain_in_step(
+        p["b"].astype(dtype).reshape(3, n_heads, 1, hd), P(None, "tp", None, None)
+    )
+    x = x.astype(dtype)
+    # A product each, straight to [B, H, T, hd]: one einsum to [3, B, H, T, hd]
+    # costs a copy of each slice it is cut into (experiments/qkv_projection_sweep.py).
+    q, k, v = (jnp.einsum("btd,dhe->bhte", x, w[:, s]) + b[s] for s in range(3))
+    return q, k, v
 
 
 def embed_init(rng: jax.Array, vocab: int, d: int, scale: float = 0.02) -> jax.Array:

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import multi_head_attention
+from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,9 +83,8 @@ def _block(p: common.Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
     # (a profiler trace otherwise shows anonymous ``fusion.N``).
     with jax.named_scope("attention"):
         h = common.layernorm(p["ln1"], x)
-        qkv = common.dense(p["qkv"], h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        attn = multi_head_attention(q, k, v, cfg.n_heads, causal=True)
+        q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
+        attn = merge_heads(attention_core(q, k, v, causal=True))
         x = x + common.dense(p["attn_out"], attn)
     with jax.named_scope("mlp"):
         h = common.layernorm(p["ln2"], x)

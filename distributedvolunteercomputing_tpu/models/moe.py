@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.models.gpt2 import GPT2Config
-from distributedvolunteercomputing_tpu.ops.attention import multi_head_attention
+from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,9 +156,8 @@ def init(rng: jax.Array, cfg: GPT2MoEConfig) -> common.Params:
 def _block(p: common.Params, x_aux, cfg: GPT2MoEConfig):
     x, aux = x_aux
     h = common.layernorm(p["ln1"], x)
-    qkv = common.dense(p["qkv"], h)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    attn = multi_head_attention(q, k, v, cfg.n_heads, causal=True)
+    q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
+    attn = merge_heads(attention_core(q, k, v, causal=True))
     x = x + common.dense(p["attn_out"], attn)
     h = common.layernorm(p["ln2"], x)
     y, layer_aux = moe_ffn(p["moe"], h, cfg)

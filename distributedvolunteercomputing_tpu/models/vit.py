@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import multi_head_attention
+from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,9 +89,8 @@ def _patchify(x: jax.Array, cfg: ViTConfig) -> jax.Array:
 def _block(p: common.Params, x: jax.Array, cfg: ViTConfig) -> jax.Array:
     # Pre-LN (ViT standard): residuals stay un-normalized.
     h = common.layernorm(p["ln1"], x)
-    qkv = common.dense(p["qkv"], h)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    x = x + common.dense(p["attn_out"], multi_head_attention(q, k, v, cfg.n_heads))
+    q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
+    x = x + common.dense(p["attn_out"], merge_heads(attention_core(q, k, v)))
     h = common.layernorm(p["ln2"], x)
     return x + common.dense(p["mlp_out"], jax.nn.gelu(common.dense(p["mlp_in"], h)))
 

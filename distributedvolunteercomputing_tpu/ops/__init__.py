@@ -1,3 +1,3 @@
-from distributedvolunteercomputing_tpu.ops.attention import multi_head_attention, rope
+from distributedvolunteercomputing_tpu.ops.attention import rope
 
-__all__ = ["multi_head_attention", "rope"]
+__all__ = ["rope"]

@@ -3,7 +3,7 @@
 Plain-XLA reference path: one fused einsum-softmax-einsum that XLA maps onto
 the MXU. The pallas flash kernel (ops/pallas_attention.py) and the ring
 attention sequence-parallel path (parallel/ring_attention.py) are drop-in
-replacements for ``multi_head_attention``'s core; ``auto`` routing picks
+replacements for ``attention_core``; ``auto`` routing picks
 between the XLA core and the kernel by what it can observe (backend, mask,
 shape, dtype, the traced step's mesh), from crossovers measured on the v5e.
 
@@ -89,6 +89,42 @@ _core_observer = None
 def set_core_observer(fn) -> None:
     global _core_observer
     _core_observer = fn
+
+
+# Called once per TRACED fused qkv projection with (layout, tp): "by_head"
+# where the projection was divided by head over the step mesh's ``tp`` axis
+# (models/common.qkv_heads), "fused" where it ran as one [d, 3d] product
+# (swarm.qkv_projection, beside swarm.attention_core).
+_qkv_observer = None
+
+
+def set_qkv_observer(fn) -> None:
+    global _qkv_observer
+    _qkv_observer = fn
+
+
+def observe_qkv(layout: str, tp: int) -> None:
+    if _qkv_observer is not None:
+        _qkv_observer(layout, tp)
+
+
+def heads_tp() -> int:
+    """Over how many chips the traced step's mesh can divide a projection's
+    heads: the size of its ``tp`` axis, or 1 with no step mesh or where an
+    enclosing ``shard_map`` (a pipeline stage, Ulysses) has already made
+    ``tp`` manual, so that what the trace sees is one chip's share."""
+    if _mesh_ctx is None or "tp" in jax.sharding.get_abstract_mesh().manual_axes:
+        return 1
+    return _mesh_ctx.shape.get("tp", 1)
+
+
+def constrain_in_step(x: jax.Array, spec) -> jax.Array:
+    """``x`` laid out by ``spec`` over the traced step's mesh (inside an
+    enclosing ``shard_map`` the mesh to name is the context's own, as in
+    ``_flash_per_shard``)."""
+    ctx = jax.sharding.get_abstract_mesh()
+    mesh = ctx if ctx.manual_axes else _mesh_ctx
+    return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(mesh, spec))
 
 
 def set_attention_impl(name: str) -> None:
@@ -237,21 +273,6 @@ def split_heads(x: jax.Array, n_heads: int) -> jax.Array:
 def merge_heads(x: jax.Array) -> jax.Array:
     b, h, t, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
-
-
-def multi_head_attention(
-    q: jax.Array,  # [B, T, d_model] (already projected)
-    k: jax.Array,
-    v: jax.Array,
-    n_heads: int,
-    causal: bool = False,
-    mask: Optional[jax.Array] = None,
-) -> jax.Array:
-    out = attention_core(
-        split_heads(q, n_heads), split_heads(k, n_heads), split_heads(v, n_heads),
-        causal=causal, mask=mask,
-    )
-    return merge_heads(out)
 
 
 def rope(

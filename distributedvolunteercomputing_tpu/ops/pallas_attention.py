@@ -421,22 +421,3 @@ def _fa_bwd(causal, block_q, block_k, interpret, residuals, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
-
-
-def flash_mha(
-    q: jax.Array,  # [B, T, d_model] (already projected)
-    k: jax.Array,
-    v: jax.Array,
-    n_heads: int,
-    causal: bool = False,
-    block_q: Optional[int] = None,
-    block_k: Optional[int] = None,
-) -> jax.Array:
-    """Multi-head wrapper matching ops.attention.multi_head_attention."""
-    from distributedvolunteercomputing_tpu.ops.attention import merge_heads, split_heads
-
-    out = flash_attention(
-        split_heads(q, n_heads), split_heads(k, n_heads), split_heads(v, n_heads),
-        causal, block_q, block_k,
-    )
-    return merge_heads(out)

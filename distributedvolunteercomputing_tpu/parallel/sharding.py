@@ -7,10 +7,26 @@ generic rule table that covers every transformer in the zoo:
 - column-parallel (shard the OUTPUT feature dim over ``tp``): qkv / wq / wk /
   wv projections, mlp_in / w_gate / w_up — the matmul that *fans out*;
 - row-parallel (shard the INPUT feature dim over ``tp``): attn_out / wo /
-  mlp_out / w_down — the matmul that *fans in*, after which XLA emits the
-  layer's one allreduce over ICI;
+  mlp_out / w_down — the matmul that *fans in*, after which XLA emits an
+  all-reduce of the activations over ICI;
 - everything else (embeddings, norms, biases of row-parallel layers, LoRA
   adapters — rank ~8, not worth slicing) is replicated.
+
+What a transformer layer costs over ``tp`` in a rematerialised train step
+(read off the compiled ``dp=2,tp=2`` gpt2_large step, tests/test_tpu_compile.py,
+and its trace, PERF.md section 5): FIVE all-reduces of [B/dp, T, d]
+activations — after attn_out and mlp_out in the forward, after attn_out again
+in the recomputed forward, and for the input cotangent of each column-parallel
+product (mlp_in, qkv) in the backward — none of them hidden behind compute.
+That is the layout's price. The fused ``qkv`` leaf adds no activation traffic
+to it, though its stored sharding alone would: [d, 3d] cut over ``tp`` on 3d
+in contiguous parts gives chip 0 of a pair all of q and half of k, while
+attention runs heads 0..H/tp-1 of each of q, k and v there. The stored leaf
+keeps that layout (other volunteers average it leaf by leaf, checkpoints hold
+it); the head-aligned view — [d, 3, H, hd] laid out over ``tp`` on H — is
+taken inside the step by ``models/common.qkv_heads``, so what crosses the
+link for it is the weight's shards (an all-gather of the bf16 weight in each
+forward, one of its gradient), never q, k, v or their cotangents.
 
 This is the build-side TP addition documented in SURVEY.md §2 (reference is
 volunteer-DP only; TP within a slice is what `pjit` gives us for free).

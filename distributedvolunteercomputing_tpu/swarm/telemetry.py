@@ -806,6 +806,17 @@ class Telemetry:
                 "traced attention calls by the core that took them",
             ).inc(impl=impl, T=str(t), D=str(d), dtype=dtype)
 
+    def count_qkv_projection(self, layout: str, tp: int) -> None:
+        """One TRACED fused qkv projection ran ``layout`` ("by_head": divided
+        by head over the step mesh's ``tp`` chips | "fused": one [d, 3d]
+        product): models/common.qkv_heads through ops.attention's observer
+        (``set_qkv_observer``)."""
+        if self.enabled:
+            self.registry.counter(
+                "swarm.qkv_projection",
+                "traced fused qkv projections by how they were divided over tp",
+            ).inc(layout=layout, tp=str(tp))
+
     def count_moe_dispatch(self, impl: str, n_experts: int, top_k: int, rows: int) -> None:
         """One TRACED expert dispatch took grouped matmul ``impl``
         ("megablox" | "ragged_dot"): ops.moe_dispatch's observer."""
@@ -835,23 +846,30 @@ class Telemetry:
         """Routing of a sparse-expert model: traced dispatches per grouped
         matmul, and the two gauges; empty for a dense model."""
         out: Dict[str, Any] = {}
-        for rec in self.registry.counter("swarm.moe_dispatch")._scrape()["values"]:
-            by = out.setdefault("dispatch", {})
-            impl = rec["labels"].get("impl", "?")
-            by[impl] = by.get(impl, 0) + int(rec["value"])
+        dispatch = self._counts_by("swarm.moe_dispatch", "impl")
+        if dispatch:
+            out["dispatch"] = dispatch
         for key in ("load_max_over_mean", "dropped_total"):
             v = self.registry.gauge(f"swarm.moe_{key}").value()
             if v is not None:
                 out[key] = v
         return out
 
+    def _counts_by(self, counter: str, label: str) -> Dict[str, int]:
+        out: Dict[str, int] = {}
+        for rec in self.registry.counter(counter)._scrape()["values"]:
+            key = rec["labels"].get(label, "?")
+            out[key] = out.get(key, 0) + int(rec["value"])
+        return out
+
     def attention_cores(self) -> Dict[str, int]:
         """Traced attention calls per core, all shapes together."""
-        out: Dict[str, int] = {}
-        for rec in self.registry.counter("swarm.attention_core")._scrape()["values"]:
-            impl = rec["labels"].get("impl", "?")
-            out[impl] = out.get(impl, 0) + int(rec["value"])
-        return out
+        return self._counts_by("swarm.attention_core", "impl")
+
+    def qkv_projections(self) -> Dict[str, int]:
+        """Traced fused qkv projections per layout; empty for a model with
+        separate q, k and v leaves."""
+        return self._counts_by("swarm.qkv_projection", "layout")
 
     # -- RPC surface ---------------------------------------------------------
 
@@ -954,6 +972,8 @@ class Telemetry:
             "spans": spans,
             # how often the fused attention core engaged, in traced calls
             "attention_core": self.attention_cores(),
+            # how often the fused qkv projection was divided by head over tp
+            "qkv_projection": self.qkv_projections(),
             # a sparse-expert model's dispatches and routing gauges ({} if dense)
             "moe": self.moe(),
         }

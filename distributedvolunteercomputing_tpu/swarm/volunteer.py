@@ -506,9 +506,14 @@ class Volunteer:
         )
         # The attention core reports each traced call (which core took it):
         # the summary's "attention_core" says how often the fused one engaged.
-        from distributedvolunteercomputing_tpu.ops.attention import set_core_observer
+        # So does the fused qkv projection (divided by head over tp, or not).
+        from distributedvolunteercomputing_tpu.ops.attention import (
+            set_core_observer,
+            set_qkv_observer,
+        )
 
         set_core_observer(self.telemetry.count_attention_core if cfg.telemetry else None)
+        set_qkv_observer(self.telemetry.count_qkv_projection if cfg.telemetry else None)
         from distributedvolunteercomputing_tpu.ops.moe_dispatch import set_dispatch_observer
 
         set_dispatch_observer(self.telemetry.count_moe_dispatch if cfg.telemetry else None)
@@ -1268,6 +1273,9 @@ class Volunteer:
             # Traced attention calls by core ({"flash": n} or {"xla": n};
             # empty with telemetry off).
             self.summary["attention_core"] = self.telemetry.attention_cores()
+            # Traced fused qkv projections by layout ({"by_head": n} on a mesh
+            # whose tp divides the heads, {"fused": n} elsewhere).
+            self.summary["qkv_projection"] = self.telemetry.qkv_projections()
             moe = self.telemetry.moe()
             if moe:
                 # a sparse-expert model: traced dispatches by grouped matmul,

@@ -1,0 +1,141 @@
+#!/usr/bin/env python
+"""The by-head qkv projection against the fused one, on the chips.
+
+    chiprun --chips 4 -- python experiments/qkv_by_head_check.py
+
+``benchmark/probe.py:reference_check`` jits the loss outside any step builder,
+so on four chips it traces with no step mesh and holds the *fused* branch of
+``models/common.qkv_heads`` to the float32 reference; the by-head branch,
+which a ``dp=2,tp=2`` volunteer's step takes, it never sees. This script
+closes that: loss and gradients of ``--model`` on its initial parameters and
+one seeded batch, traced under the step's mesh (the kernel per shard, the
+projection by head), against the same trace with the projection held to its
+fused form, by the reference check's own measures (loss difference, relative
+error of the whole gradient and of its worst leaf). With ``--reference
+benchmark/configs/gpt2-large.json`` (and ``--batch 2 --seq-len 512``, the
+reference check's size: the float32 reference keeps every T x T tensor) both
+are also held to the benchmark's plain float32 reference, which says how much
+of their distance from each other is bf16 rounding met in another order. One
+JSON line, also in ``chiprun_out/qkv_by_head_check.json``.
+
+On the CPU (``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``
+with ``--override n_layers=2 --override d_model=64 --override n_heads=4
+--override d_ff=128 --override max_len=32 --override vocab=128 --batch 8``)
+it rehearses the paths, not the numbers.
+"""
+
+import argparse
+import json
+import math
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from distributedvolunteercomputing_tpu.models import get_model
+from distributedvolunteercomputing_tpu.ops import attention
+from distributedvolunteercomputing_tpu.parallel import make_mesh, make_param_shardings
+from distributedvolunteercomputing_tpu.parallel.mesh import parse_mesh_spec
+from distributedvolunteercomputing_tpu.parallel.sharding import batch_sharding
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--model", default="gpt2_large")
+    ap.add_argument("--mesh", default="dp=2,tp=2")
+    ap.add_argument("--batch", type=int, default=32)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--override", action="append", default=[], metavar="KEY=INT")
+    ap.add_argument("--seq-len", type=int, default=None, help="cut the batch's sequences to this")
+    ap.add_argument("--reference", default=None, metavar="CONFIG.json",
+                    help="a benchmark configuration whose family's float32 reference to hold both to")
+    args = ap.parse_args()
+
+    overrides = {k: int(v) for k, v in (o.split("=") for o in args.override)}
+    bundle = get_model(args.model, **overrides)
+    mesh = make_mesh(**parse_mesh_spec(args.mesh))
+    shardings = make_param_shardings(
+        mesh, jax.eval_shape(bundle.init, jax.random.PRNGKey(args.seed))
+    )
+    # born sharded: the whole float32 tree never sits on one chip
+    params = jax.jit(bundle.init, out_shardings=shardings)(jax.random.PRNGKey(args.seed))
+    batch = bundle.make_batch(jax.random.PRNGKey(args.seed + 1), args.batch)
+    batch = jax.device_put(
+        jax.tree_util.tree_map(lambda a: a[:, :args.seq_len], batch), batch_sharding(mesh)
+    )
+    layouts = []
+    attention.set_qkv_observer(lambda layout, tp: layouts.append(layout))
+
+    def make_loss_and_grads():  # a function of its own each time: jit caches traces by function
+        def loss_and_grads(params, batch):
+            with attention.step_mesh(mesh):  # what parallel/train_step.py announces
+                return jax.value_and_grad(
+                    lambda p: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
+                )(params)
+
+        return jax.jit(loss_and_grads)
+
+    @jax.jit
+    def compare(got, want):
+        num = jax.tree_util.tree_map(
+            lambda a, b: jnp.sum((a.astype(jnp.float32) - b.astype(jnp.float32)) ** 2), got, want
+        )
+        return num, jax.tree_util.tree_map(lambda b: jnp.sum(b.astype(jnp.float32) ** 2), want)
+
+    loss_head, grads_head = make_loss_and_grads()(params, batch)
+    traced_by_head = list(layouts)
+    heads_tp, attention.heads_tp = attention.heads_tp, lambda: 1  # the fused form, same mesh
+    try:
+        loss_fused, grads_fused = make_loss_and_grads()(params, batch)
+    finally:
+        attention.heads_tp = heads_tp
+
+    def rel_errs(got, want):
+        num, den = compare(got, want)
+        num = [float(x) for x in jax.tree_util.tree_leaves(num)]
+        den = [float(x) for x in jax.tree_util.tree_leaves(den)]
+        return (math.sqrt(sum(num) / sum(den)),
+                max(math.sqrt(n / d) for n, d in zip(num, den) if d > 0))
+
+    grad_rel_err, worst_leaf_rel_err = rel_errs(grads_head, grads_fused)
+    dev = jax.devices()[0]
+    result = {
+        "model": args.model, "mesh": args.mesh, "batch": args.batch, "seed": args.seed,
+        "device": {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count()},
+        "traced": {"by_head": traced_by_head, "fused": layouts[len(traced_by_head):]},
+        "loss_by_head": float(loss_head), "loss_fused": float(loss_fused),
+        "loss_abs_err": abs(float(loss_head) - float(loss_fused)),
+        "grad_rel_err": grad_rel_err, "worst_leaf_rel_err": worst_leaf_rel_err,
+    }
+    if args.reference:
+        from benchmark import references
+
+        with open(args.reference) as fh:
+            file_cfg = json.load(fh)
+        loss_ref, grads_ref = jax.jit(references.load(file_cfg["family"]).make_loss_and_grad(file_cfg))(
+            params, batch["tokens"], batch["targets"]
+        )
+        result["against_float32_reference"] = {
+            name: {"loss_abs_err": abs(float(loss) - float(loss_ref)),
+                   "grad_rel_err": rel_errs(grads, grads_ref)[0]}
+            for name, loss, grads in (("by_head", loss_head, grads_head),
+                                      ("fused", loss_fused, grads_fused))
+        }
+    line = json.dumps(result)
+    out_dir = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "qkv_by_head_check.json"), "w") as f:
+        f.write(line + "\n")
+    print(line)
+    ok = (
+        set(traced_by_head) == {"by_head"} and set(result["traced"]["fused"]) == {"fused"}
+        and result["loss_abs_err"] <= 0.005 and result["grad_rel_err"] <= 0.04
+    )
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -105,6 +105,95 @@ def test_sharded_step_matches_single_device(eight_devices, dp, tp):
     assert float(metrics2["loss"]) == float(metrics2["loss"])
 
 
+# The four models that keep q, k and v in one fused leaf, small enough for the
+# CPU and with a width both head counts below divide.
+_LM = dict(vocab=128, max_len=32, d_model=96, n_layers=2, d_ff=192)
+FUSED_QKV_MODELS = {
+    "gpt2_small": _LM,
+    "bert_mlm": _LM,
+    "cifar10_vit": dict(image_size=16, patch_size=4, d_model=96, n_layers=2, d_ff=192),
+    "gpt2_moe": dict(_LM, n_experts=4),
+}
+
+
+@pytest.fixture
+def qkv_layouts():
+    """Counts of ``swarm.qkv_projection`` as a volunteer's telemetry takes them."""
+    from distributedvolunteercomputing_tpu.ops import attention
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+
+    tel = Telemetry(peer_id="t")
+    attention.set_qkv_observer(tel.count_qkv_projection)
+    yield lambda: tel.summary()["qkv_projection"]  # what coord.status shows per peer
+    attention.set_qkv_observer(None)
+
+
+@pytest.mark.parametrize("n_heads,layout", [(4, "by_head"), (3, "fused")])
+@pytest.mark.parametrize("model", sorted(FUSED_QKV_MODELS))
+def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_heads, layout):
+    """dp=2,tp=2: the fused qkv projection is divided by head where tp divides
+    the heads and stays one product where it does not; either way the loss and
+    every gradient leaf (plain SGD at lr 1: the step's change of a leaf) are
+    the single-device step's."""
+    import optax
+
+    bundle = get_model(model, n_heads=n_heads, remat=False, **FUSED_QKV_MODELS[model])
+    tx = optax.sgd(1.0)
+    params = bundle.init(jax.random.PRNGKey(0))
+    batch = bundle.make_batch(jax.random.PRNGKey(1), 8)
+
+    def grads(state):
+        return jax.tree_util.tree_map(lambda a, b: np.asarray(a) - np.asarray(b), params, state.params)
+
+    ref_state, ref_metrics = make_train_step(bundle.loss_fn, tx, donate=False)(
+        TrainState.create(params, tx, jax.random.PRNGKey(2)), batch
+    )
+    assert qkv_layouts() == {"fused": 1}  # no step mesh: one trace, one product
+
+    mesh = make_mesh(dp=2, tp=2)
+    state, _ = shard_train_state(TrainState.create(params, tx, jax.random.PRNGKey(2)), mesh, tx)
+    stored = jax.tree_util.tree_map(lambda x: x.sharding, state.params)
+    state, metrics = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False)(
+        state, put_batch(batch, mesh)
+    )
+    assert qkv_layouts() == ({"fused": 2} if layout == "fused" else {"fused": 1, "by_head": 1})
+    # the head-aligned view lives inside the step: leaves keep their stored layout
+    assert stored["blocks"]["qkv"]["w"].spec == P(None, None, "tp")
+    assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+        lambda x, was: x.sharding.is_equivalent_to(was, x.ndim), state.params, stored
+    )))
+
+    np.testing.assert_allclose(float(metrics["loss"]), float(ref_metrics["loss"]), rtol=2e-4)
+    ref_grads = grads(ref_state)
+    assert float(np.abs(ref_grads["blocks"]["qkv"]["w"]).max()) > 1e-4  # not vacuous
+    jax.tree_util.tree_map(
+        lambda got, ref: np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-5),
+        grads(state), ref_grads,
+    )
+
+
+def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):
+    """Inside a ``shard_map`` that has made ``tp`` manual the trace sees one
+    chip's share: nothing is left to divide, the projection keeps its fused
+    form; a manual ``pp`` (a pipeline stage) leaves ``tp`` to divide."""
+    from distributedvolunteercomputing_tpu.models import common
+    from distributedvolunteercomputing_tpu.ops.attention import step_mesh
+    from distributedvolunteercomputing_tpu.parallel.mesh import shard_map_manual
+
+    mesh = make_mesh(dp=2, pp=2, tp=2)
+    leaf = common.dense_init(jax.random.PRNGKey(0), 32, 96)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
+    want = common.qkv_heads(leaf, x, 4)
+    for axis, layouts in (("tp", {"fused": 2}), ("pp", {"fused": 2, "by_head": 1})):
+        def project(x):
+            return jnp.stack(common.qkv_heads(leaf, x, 4))
+
+        with step_mesh(mesh):
+            got = jax.jit(shard_map_manual(project, mesh, P(), P(), axis))(x)
+        np.testing.assert_allclose(got, jnp.stack(want), rtol=1e-5, atol=1e-6)
+        assert qkv_layouts() == layouts
+
+
 def test_sharded_step_with_accum_matches_single_device(eight_devices):
     # Gradient accumulation inside the SHARDED step: dp-sharded [accum*B]
     # batch scanned as microbatches; numerics must still match the

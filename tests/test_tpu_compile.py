@@ -99,10 +99,10 @@ def as_on_the_chip(monkeypatch):
     monkeypatch.setattr(pallas_attention, "tpu_backend", lambda: True)
 
 
-def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2) -> str:
-    """The compiled HLO of the sharded train step of ``model`` (two layers:
-    the scanned block appears once whatever the depth) on a dp x tp mesh of
-    described chips."""
+def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2):
+    """The sharded train step of ``model`` (two layers: the scanned block
+    appears once whatever the depth), lowered for a dp x tp mesh of described
+    chips."""
     from distributedvolunteercomputing_tpu.models import get_model
     from distributedvolunteercomputing_tpu.parallel import sharding
     from distributedvolunteercomputing_tpu.parallel.mesh import AXES
@@ -141,7 +141,12 @@ def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2)
     )
     step = make_sharded_train_step(bundle.loss_fn, tx, mesh)
     with mesh:
-        return step.lower(state, batch_shape).compile().as_text()
+        return step.lower(state, batch_shape)
+
+
+def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2) -> str:
+    """The compiled HLO of that step."""
+    return _lowered_step(v5e, model, dp, tp, batch, n_layers).compile().as_text()
 
 
 def _kernel_calls(text: str):
@@ -195,6 +200,51 @@ def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
     for ln in calls:
         operands = set(re.findall(r"%[\w.\-]+", ln.split("custom-call(")[1]))
         assert not (gathered & operands), (gathered & operands)
+
+
+def _collectives(text: str):
+    """(kind, result type) of every collective in a compiled program."""
+    import re
+
+    return re.findall(
+        r"= (.+?) (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+        r"(?:-start)?\(", text,
+    )
+
+
+def test_four_chip_step_moves_no_activation_for_qkv(v5e, as_on_the_chip):
+    """large-solo-4chip's step: q, k and v are born on the chip that runs their
+    heads (``common.qkv_heads`` divides the projection by head over tp), so no
+    all-to-all and no collective-permute carries them or their cotangents. What
+    crosses a link at an activation's size is Megatron's price alone: the
+    all-reduce after each row-parallel product (attn_out and mlp_out forward,
+    attn_out in the recomputed forward) and before each column-parallel one in
+    the backward (mlp_in, qkv). The kernel still sees its own 10 heads."""
+    import re
+
+    text = _step_text(v5e, "gpt2_large", 2, 2, 32)
+    found = _collectives(text)
+    kinds = {kind for _, kind in found}
+    assert "all-to-all" not in kinds and "collective-permute" not in kinds, kinds
+    activation_sized = [kind for result, kind in found if re.search(r"\[16,1024,\d+\]", result)]
+    assert activation_sized and set(activation_sized) == {"all-reduce"}, activation_sized
+    assert len(activation_sized) <= 5, activation_sized
+    calls = _kernel_calls(text)
+    assert len(calls) == 3 and all("bf16[16,10,1024,64]" in ln for ln in calls)
+
+
+def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):
+    """medium-solo's step is the program it was: with one chip the projection
+    is one [.., d] x [d, 3d] product whose 3d-wide result feeds the split,
+    and ``common.qkv_heads`` lays nothing out (no head-major weight view, no
+    sharding constraint on it)."""
+    import re
+
+    text = _lowered_step(v5e, "gpt2_medium", 1, 1, 16).as_text()
+    assert re.search(r"stablehlo\.dot_general.*-> tensor<16x1024x3072xbf16>", text)
+    assert re.search(r"stablehlo\.slice.*tensor<16x1024x3072xbf16>\) -> tensor<16x1024x1024xbf16>", text)
+    assert "1024x3x16x64" not in text  # the weight as [d, 3, H, hd]
+    assert not re.search(r"sharding_constraint.*x16x64xbf16>", text)
 
 
 def test_round_programs_copy_and_donate(v5e):
