@@ -135,14 +135,34 @@ def stacked_init(layer_init, rng: jax.Array, n_layers: int) -> Params:
     return jax.vmap(layer_init)(keys)
 
 
+def remat_layer(body, layers: int = 1):
+    """``body`` (one layer) rematerialised in the backward pass: the one place
+    that decides what a rematerialised layer keeps. Beside the layer's inputs
+    that is what the flash kernel produced in it, its output and log-sum-exp
+    rows (``pallas_attention.KEPT_NAMES``: a layer input's worth of memory for
+    a kernel call's worth of time), so the recomputed forward runs no kernel.
+    A layer on the XLA core names nothing and its program is a bare
+    ``jax.checkpoint``'s. ``layers``: how many layers run this one trace (a
+    scan's length), for ``swarm.remat_kept``'s bytes."""
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import KEPT_NAMES
+
+    fn = jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+
+    def layer(*args):
+        with attention_ops.keeping_kernel_results(layers):
+            return fn(*args)
+
+    return layer
+
+
 def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_outputs: bool = False):
     """Run ``x`` through stacked ``blocks`` with ``lax.scan``; ``body`` is
     ``(layer_params, x) -> x``. With ``remat`` each layer's activations are
-    rematerialized in backward (checkpoint-per-scan-step), the standard
+    rematerialized in backward (``remat_layer`` per scan step), the standard
     O(sqrt)-free layerwise remat that keeps HBM at one layer's activations.
     ``with_outputs``: ``body`` returns ``(x, y)`` and the layers' ``y`` come
     back stacked beside the final ``x``."""
-    fn = jax.checkpoint(body) if remat else body
+    fn = remat_layer(body, jax.tree_util.tree_leaves(blocks)[0].shape[0]) if remat else body
 
     def step(h, p):
         return fn(p, h) if with_outputs else (fn(p, h), None)

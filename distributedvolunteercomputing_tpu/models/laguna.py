@@ -276,7 +276,7 @@ def _trunk(params: common.Params, tokens: jax.Array, cfg: LagunaConfig):
         def block(p, x, stats, layer=layer):
             return _layer(p, x, stats, cfg, layer)
 
-        x, stats, top_idx = (jax.checkpoint(block) if cfg.remat else block)(p, x, stats)
+        x, stats, top_idx = (common.remat_layer(block) if cfg.remat else block)(p, x, stats)
         if top_idx is not None:
             routes.append(top_idx)
     routes = jnp.stack(routes) if routes else jnp.zeros((0, tokens.size, cfg.top_k), jnp.int32)

@@ -108,6 +108,37 @@ def observe_qkv(layout: str, tp: int) -> None:
         _qkv_observer(layout, tp)
 
 
+# Called once per TRACED rematerialised layer whose checkpoint kept a kernel's
+# results (models/common.remat_layer) with (layers, bytes): how many layers run
+# that one trace (a scan's length) and the bytes one chip keeps of them a step
+# (swarm.remat_kept, beside swarm.attention_core). A layer that ran the XLA
+# core names nothing, keeps nothing and is not reported.
+_kept_observer = None
+# While remat_layer traces a body: the bytes kept of each kernel call in it.
+_kept_ctx = None
+
+
+def set_kept_observer(fn) -> None:
+    global _kept_observer
+    _kept_observer = fn
+
+
+@contextlib.contextmanager
+def keeping_kernel_results(layers: int):
+    """Around the trace of one rematerialised layer body that ``layers``
+    layers run: gathers what ``_flash_per_shard`` says a chip keeps of each
+    kernel call in it and reports the sum."""
+    global _kept_ctx
+    prev, _kept_ctx = _kept_ctx, []
+    kept = _kept_ctx
+    try:
+        yield
+    finally:
+        _kept_ctx = prev
+    if kept and _kept_observer is not None:
+        _kept_observer(layers, layers * sum(kept))
+
+
 def heads_tp() -> int:
     """Over how many chips the traced step's mesh can divide a projection's
     heads: the size of its ``tp`` axis, or 1 with no step mesh or where an
@@ -203,10 +234,13 @@ def _flash_per_shard(
     already made manual."""
     from jax.sharding import PartitionSpec as P
 
-    from distributedvolunteercomputing_tpu.ops.pallas_attention import flash_attention
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import flash_attention, kept_bytes
 
-    # blocks: pallas_attention.choose_blocks of the (shard's) shape
-    core = functools.partial(flash_attention, causal=causal, window=window)
+    def core(q, k, v):  # traced once, at one chip's shapes
+        if _kept_ctx is not None:
+            _kept_ctx.append(kept_bytes(q, k, window))
+        # blocks: pallas_attention.choose_blocks of the (shard's) shape
+        return flash_attention(q, k, v, causal=causal, window=window)
 
     if _mesh_ctx is None or _mesh_ctx.size == 1:
         return core(q, k, v)

@@ -56,6 +56,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,6 +74,9 @@ VMEM_BUDGET_BYTES = 64 * 1024 * 1024
 # of each other forward + backward, and everything smaller loses to the
 # per-block cost of the running statistics; at T=2,048 blocks of 1,024 win.
 PREFERRED_BLOCK = 1024
+# The names ``_fa_fwd`` gives the kernel's output and its log-sum-exp rows:
+# what a rematerialised layer keeps of a call (models/common.remat_layer).
+KEPT_NAMES = ("attention_out", "attention_lse")
 
 
 def _interpret_default() -> bool:
@@ -460,6 +464,17 @@ def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None) -> Tup
     return bq, bk, _interpret_default() if interpret is None else interpret
 
 
+def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None) -> int:
+    """Bytes of the two residuals named by ``KEPT_NAMES`` for one call at
+    ``choose_blocks``' blocks, as the chip lays them out: the output with its
+    head dim padded to whole lanes (a D=64 output takes a D=128 one's room:
+    the compiled step's kept stack is ``bf16[L,B,H,T,64]`` tiled (8, 128)),
+    and the f32 log-sum-exp rows of whole q-blocks."""
+    bq, _, _ = _resolve(q, k, None, None, False, True, window)
+    b, h, tq, d = q.shape
+    return b * h * (tq * _round_up(d, LANES) * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(
     q: jax.Array,  # [B, H, Tq, D]
@@ -487,6 +502,10 @@ def flash_attention(
 def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window)
     out, lse = _flash_forward(q, k, v, causal, bq, bk, interp, window)
+    # Named so that a checkpoint's policy can keep them (KEPT_NAMES): with the
+    # kernel's two results saved, a rematerialised layer's recomputed forward
+    # has no use for the kernel and its call there is dead code.
+    out, lse = checkpoint_name(out, KEPT_NAMES[0]), checkpoint_name(lse, KEPT_NAMES[1])
     return out, (q, k, v, out, lse)
 
 

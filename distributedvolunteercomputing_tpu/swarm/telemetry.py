@@ -822,6 +822,18 @@ class Telemetry:
                 "traced fused qkv projections by how they were divided over tp",
             ).inc(layout=layout, tp=str(tp))
 
+    def count_remat_kept(self, layers: int, nbytes: int) -> None:
+        """One TRACED rematerialised layer kept the attention kernel's output
+        and row statistics (models/common.remat_layer through ops.attention's
+        ``set_kept_observer``): ``layers`` layers run that trace, and
+        ``nbytes`` is what one chip keeps of them a step. A layer on the XLA
+        core keeps nothing and never comes here."""
+        if self.enabled:
+            self.registry.counter(
+                "swarm.remat_kept",
+                "traced rematerialised layers that kept the attention kernel's results",
+            ).inc(layers=str(layers), bytes=str(nbytes))
+
     def count_moe_dispatch(
         self, impl: str, n_experts: int, top_k: int, rows: int, held: Optional[int] = None
     ) -> None:
@@ -885,6 +897,18 @@ class Telemetry:
     def attention_cores(self) -> Dict[str, int]:
         """Traced attention calls per core, all shapes together."""
         return self._counts_by("swarm.attention_core", "impl")
+
+    def remat_kept(self) -> Dict[str, int]:
+        """Traced rematerialised layers whose checkpoint kept the attention
+        kernel's results, and the bytes one chip keeps of them a step; empty
+        where every layer ran the XLA core."""
+        recs = self.registry.counter("swarm.remat_kept")._scrape()["values"]
+        if not recs:
+            return {}
+        return {
+            "traced_layers": sum(int(r["value"]) for r in recs),
+            "bytes_a_step": sum(int(r["value"]) * int(r["labels"]["bytes"]) for r in recs),
+        }
 
     def qkv_projections(self) -> Dict[str, int]:
         """Traced fused qkv projections per layout; empty for a model with
@@ -994,6 +1018,8 @@ class Telemetry:
             "attention_core": self.attention_cores(),
             # how often the fused qkv projection was divided by head over tp
             "qkv_projection": self.qkv_projections(),
+            # what rematerialised layers kept of the attention kernel ({} on the XLA core)
+            "remat_kept": self.remat_kept(),
             # a sparse-expert model's dispatches and routing gauges ({} if dense)
             "moe": self.moe(),
         }

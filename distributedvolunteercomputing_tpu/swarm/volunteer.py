@@ -506,14 +506,17 @@ class Volunteer:
         )
         # The attention core reports each traced call (which core took it):
         # the summary's "attention_core" says how often the fused one engaged.
-        # So does the fused qkv projection (divided by head over tp, or not).
+        # So does the fused qkv projection (divided by head over tp, or not),
+        # and a rematerialised layer that kept the kernel's results.
         from distributedvolunteercomputing_tpu.ops.attention import (
             set_core_observer,
+            set_kept_observer,
             set_qkv_observer,
         )
 
         set_core_observer(self.telemetry.count_attention_core if cfg.telemetry else None)
         set_qkv_observer(self.telemetry.count_qkv_projection if cfg.telemetry else None)
+        set_kept_observer(self.telemetry.count_remat_kept if cfg.telemetry else None)
         from distributedvolunteercomputing_tpu.ops.moe_dispatch import set_dispatch_observer
 
         set_dispatch_observer(self.telemetry.count_moe_dispatch if cfg.telemetry else None)
@@ -1276,6 +1279,10 @@ class Volunteer:
             # Traced fused qkv projections by layout ({"by_head": n} on a mesh
             # whose tp divides the heads, {"fused": n} elsewhere).
             self.summary["qkv_projection"] = self.telemetry.qkv_projections()
+            # Traced rematerialised layers that kept the attention kernel's
+            # output and row statistics, and the bytes a chip keeps of them a
+            # step ({} where every layer ran the XLA core).
+            self.summary["remat_kept"] = self.telemetry.remat_kept()
             moe = self.telemetry.moe()
             if moe:
                 # a sparse-expert model: traced dispatches by grouped matmul,

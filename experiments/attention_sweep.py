@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.ops import attention
 from distributedvolunteercomputing_tpu.ops.pallas_attention import flash_attention
 
@@ -83,7 +84,7 @@ def remat_layers(impl, causal, n_heads, n_layers):
 
     def f(x, ws):
         def loss(x, ws):
-            body = jax.checkpoint(lambda h, w: (layer(h, w), None))
+            body = common.remat_layer(lambda h, w: (layer(h, w), None), n_layers)  # as scan_blocks
             h, _ = jax.lax.scan(body, x, ws)
             return jnp.sum(h.astype(jnp.float32))
 

@@ -1,0 +1,57 @@
+"""What the TPU compiler reserves for each benchmark cell's train step.
+
+Compiles the step of every cell at its real depth and batch for a v5e that is
+described and not attached (no chip: `tests/test_tpu_compile.py` has the
+method) and prints one JSON line a cell with ``memory_analysis()``'s bytes:
+the arguments (state and batch), the temporaries, and their sum with the
+outputs that do not alias an argument. ``memory_stats()`` on the chip counts
+live buffers and not a program's temporaries (PERF.md section 4), so this is
+where a change to what the step keeps (``models/common.remat_layer``) shows.
+
+    JAX_PLATFORMS=cpu python experiments/step_memory.py [cell ...]
+"""
+
+import json
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
+
+# cell -> (registry model, dp, tp, global batch, overrides): benchmark/configs/*.json
+CELLS = {
+    "medium-solo": ("gpt2_medium", 1, 1, 16, {"n_layers": 24}),
+    "large-solo-4chip": ("gpt2_large", 2, 2, 32, {"n_layers": 36}),
+    "olmoe-solo": ("olmoe_1b_7b", 1, 1, 4, {"n_layers": 1}),
+    "laguna-solo-8k": ("laguna_xs2", 1, 1, 4, {"n_layers": 5, "experts_held": 16, "vocab": 12544}),
+}
+
+
+def main(cells) -> None:
+    from jax.experimental import topologies
+
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention
+    from distributedvolunteercomputing_tpu.utils import jaxenv
+    from tests.test_tpu_compile import _kernel_calls, _lowered_step
+
+    # the program's backend checks answer as they do on the chip
+    jaxenv.tpu_backend = pallas_attention.tpu_backend = moe_dispatch.tpu_backend = lambda: True
+    moe_dispatch.grouped_matmul_impl = lambda m, k, n: "megablox"
+    v5e = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
+    for cell in cells:
+        model, dp, tp, batch, overrides = CELLS[cell]
+        compiled = _lowered_step(v5e, model, dp, tp, batch, **overrides).compile()
+        mem = compiled.memory_analysis()
+        print(json.dumps({
+            "cell": cell,
+            "argument_bytes": mem.argument_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "total_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes,
+            "code_bytes": mem.generated_code_size_in_bytes,
+            "kernel_calls": len(_kernel_calls(compiled.as_text())),
+        }), flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:] or list(CELLS))

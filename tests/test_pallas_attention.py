@@ -396,3 +396,186 @@ def test_trace_time_counter(enabled, want):
                                  "window": "none", "kv_heads": "4"}
     else:
         assert tel.summary()["attention_core"] == {}
+
+
+# -- what a rematerialised layer keeps of the kernel (models/common.remat_layer) --
+
+
+def _kernel_eqns(jaxpr, out=None):
+    """The names of the ``pallas_call`` equations of ``jaxpr`` and of every
+    jaxpr nested in it (a scan's body is there once whatever its length)."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _kernel_eqns(sub, out)
+    return out
+
+
+def _tiny_laguna(**kw):
+    """The benchmark's rehearsal cut of Laguna: five unrolled layers, two full
+    (6 heads) and three windowed (8 heads), T=64, head dim 16."""
+    from benchmark.manifest import Manifest
+    from distributedvolunteercomputing_tpu.models import get_model
+
+    return get_model(
+        "laguna_xs2", **Manifest().load_config("tiny-rehearsal-laguna")["model_overrides"], **kw)
+
+
+def _kept(b, h, t):
+    """Bytes a chip keeps of one f32 call: an output row takes 128 lanes
+    whatever the head dim, and a log-sum-exp a row."""
+    return b * h * t * (128 * 4 + 4)
+
+
+def _grad_of(bundle, batch_size=2):
+    params = bundle.init(jax.random.PRNGKey(0))
+    batch = bundle.make_batch(jax.random.PRNGKey(1), batch_size)
+    return jax.grad(lambda p: bundle.loss_fn(p, batch, jax.random.PRNGKey(2))[0]), params
+
+
+@pytest.fixture
+def bare_checkpoint(monkeypatch):
+    """Switches ``remat_layer`` to the bare ``jax.checkpoint`` it replaced
+    (take a new ``_grad_of`` after it: a traced function is cached)."""
+    from distributedvolunteercomputing_tpu.models import common
+
+    def switch():
+        monkeypatch.setattr(common, "remat_layer", lambda body, layers=1: jax.checkpoint(body))
+
+    return switch
+
+
+def _assert_bit_equal(got, want):
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want), strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("model,kept,bare", [
+    # a scanned block is in the jaxpr once: forward + backward, where the bare
+    # checkpoint's recomputed forward called the kernel again
+    (_tiny_gpt2, {"dvc_flash_fwd": 1, "dvc_flash_bwd": 1}, {"dvc_flash_fwd": 2, "dvc_flash_bwd": 1}),
+    # unrolled: two full layers and three windowed ones, each kind under its name
+    (_tiny_laguna,
+     {"dvc_flash_fwd": 2, "dvc_flash_bwd": 2, "dvc_flash_win_fwd": 3, "dvc_flash_win_bwd": 3},
+     {"dvc_flash_fwd": 4, "dvc_flash_bwd": 2, "dvc_flash_win_fwd": 6, "dvc_flash_win_bwd": 3}),
+], ids=["scanned-gpt2", "unrolled-laguna"])
+def test_remat_layer_runs_the_forward_kernel_once(model, kept, bare, bare_checkpoint):
+    """The gradient of a rematerialised model holds one forward kernel call a
+    layer (the layer's checkpoint kept the kernel's output and row statistics,
+    so the recomputed forward's call is dead code), and its gradients are the
+    bare ``jax.checkpoint``'s bit for bit."""
+    from collections import Counter
+
+    grad, params = _grad_of(model(remat=True))
+    try:
+        set_attention_impl("flash")
+        got_kernels = Counter(_kernel_eqns(jax.make_jaxpr(grad)(params).jaxpr))
+        got = jax.jit(grad)(params)
+        bare_checkpoint()
+        grad, params = _grad_of(model(remat=True))
+        want_kernels = Counter(_kernel_eqns(jax.make_jaxpr(grad)(params).jaxpr))
+        want = jax.jit(grad)(params)
+    finally:
+        set_attention_impl("auto")
+    assert got_kernels == kept and want_kernels == bare
+    _assert_bit_equal(got, want)
+
+
+def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkpoint):
+    """dp=2, tp=2: the kept names pass through ``_flash_per_shard``'s
+    ``shard_map``; one forward kernel call a layer, the bare checkpoint's
+    gradients bit for bit, and the bytes counted are one chip's share."""
+    from jax.sharding import Mesh
+
+    from distributedvolunteercomputing_tpu.ops import attention
+    from distributedvolunteercomputing_tpu.parallel.mesh import AXES
+
+    mesh = Mesh(np.array(eight_devices[:4]).reshape(2, 1, 1, 1, 2), AXES)
+    grad, params = _grad_of(_tiny_gpt2(remat=True), batch_size=4)
+    seen = []
+    attention.set_kept_observer(lambda layers, nbytes: seen.append((layers, nbytes)))
+    try:
+        set_attention_impl("flash")
+        with attention.step_mesh(mesh):
+            jaxpr = jax.make_jaxpr(grad)(params)
+            got = jax.jit(grad)(params)
+            bare_checkpoint()
+            grad, params = _grad_of(_tiny_gpt2(remat=True), batch_size=4)
+            bare = _kernel_eqns(jax.make_jaxpr(grad)(params).jaxpr)
+            want = jax.jit(grad)(params)
+    finally:
+        set_attention_impl("auto")
+        attention.set_kept_observer(None)
+    assert "shard_map" in str(jaxpr)
+    assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]
+    assert sorted(bare) == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_fwd"]
+    _assert_bit_equal(got, want)
+    # a chip's share, [2, 2, 32, 16] of [4, 4, 32, 16], for two layers; by the one
+    # trace of the helper's layer, never by the bare checkpoint's
+    assert seen == [(2, 2 * _kept(2, 2, 32))]
+
+
+def test_remat_layer_on_the_xla_core_is_the_bare_checkpoint(bare_checkpoint):
+    """A layer that ran the XLA core names nothing, so the policy keeps
+    nothing: the gradient's program is the bare checkpoint's, line for line."""
+    grad, params = _grad_of(_tiny_gpt2(remat=True))
+    got = jax.jit(grad).lower(params).as_text()
+    assert not _kernel_eqns(jax.make_jaxpr(grad)(params).jaxpr)
+    bare_checkpoint()
+    grad, params = _grad_of(_tiny_gpt2(remat=True))
+    assert got == jax.jit(grad).lower(params).as_text()
+
+
+@pytest.mark.parametrize("model,impl,want", [
+    (_tiny_gpt2, "flash", {"traced_layers": 1, "bytes_a_step": 2 * _kept(2, 4, 32)}),
+    (_tiny_laguna, "flash", {"traced_layers": 5, "bytes_a_step": 2 * _kept(2, 6, 64) + 3 * _kept(2, 8, 64)}),
+    (_tiny_gpt2, "auto", {}),  # the CPU's auto routing: the XLA core, nothing kept
+    (_tiny_laguna, "auto", {}),
+], ids=["gpt2-flash", "laguna-flash", "gpt2-xla", "laguna-xla"])
+def test_remat_kept_counter(model, impl, want):
+    """``swarm.remat_kept``: one count per TRACED layer whose checkpoint kept
+    a kernel's results (a scanned block is traced once for all its layers)
+    with the bytes kept a step (output rows of 128 lanes whatever the head
+    dim: the chip's layout), none per executed step, and nothing where the
+    layers ran the XLA core; in the summary that ``coord.status`` shows per peer."""
+    from distributedvolunteercomputing_tpu.ops import attention
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+
+    tel = Telemetry(peer_id="t", enabled=True)
+    grad, params = _grad_of(model(remat=True))
+    grad = jax.jit(grad)
+    attention.set_kept_observer(tel.count_remat_kept)
+    try:
+        set_attention_impl(impl)
+        jax.block_until_ready(grad(params))
+        assert tel.remat_kept() == want
+        jax.block_until_ready(grad(params))  # a compiled step counts nothing
+    finally:
+        set_attention_impl("auto")
+        attention.set_kept_observer(None)
+    assert tel.summary()["remat_kept"] == want
+    if want:
+        layers = {r["labels"]["layers"] for r in tel.registry.counter("swarm.remat_kept")._scrape()["values"]}
+        assert layers == ({"2"} if model is _tiny_gpt2 else {"1"})
+
+
+def test_remat_off_keeps_nothing():
+    """``remat=False`` is the body unwrapped: no checkpoint, nothing counted."""
+    from distributedvolunteercomputing_tpu.ops import attention
+
+    seen = []
+    grad, params = _grad_of(_tiny_gpt2(remat=False))
+    attention.set_kept_observer(lambda *a: seen.append(a))
+    try:
+        set_attention_impl("flash")
+        jaxpr = jax.make_jaxpr(grad)(params)
+    finally:
+        set_attention_impl("auto")
+        attention.set_kept_observer(None)
+    assert not seen
+    assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]

@@ -155,16 +155,17 @@ def _kernel_calls(text: str):
 
 def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     """medium-solo's step, auto routing: T=1,024 bf16 takes the fused core,
-    forward, recomputed forward and backward."""
+    forward and backward; the recomputed forward holds no kernel (the layer's
+    checkpoint kept its output and row statistics: ``common.remat_layer``)."""
     calls = _kernel_calls(_step_text(v5e, "gpt2_medium", 1, 1, 16))
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[16,16,1024,64]" in ln for ln in calls)
 
 
 def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     """olmoe-solo's step (one layer of OLMoE-1B-7B at its published widths,
     4 x 4,096 tokens): the fused attention core at head dim 128 and T=4,096,
-    forward, recomputed forward and backward, and twelve megablox calls over
+    forward and backward; the recomputed forward holds no kernel; and twelve megablox calls over
     the 131,072 routed rows (gate, up, down: forward, recomputed forward, the
     backward by the rows' side; three by the weights' side), under the names
     the benchmark's readers match. That it compiles says it fits the chip."""
@@ -180,7 +181,7 @@ def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     names = [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
     flash = [n for n in names if n.startswith("dvc_flash_")]
     gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
-    assert len(flash) == 3 and len(gmm) == 12 and len(names) == 15, names
+    assert len(flash) == 2 and len(gmm) == 12 and len(names) == 14, names
     assert sum(n.startswith("tgmm") for n in gmm) == 3
     assert all("bf16[4,16,4096,128]" in ln for ln in calls if "dvc_flash_" in ln)
     assert all("[131072," in ln or "bf16[64," in ln for ln in calls if "gmm" in ln)
@@ -197,8 +198,8 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     widths, sixteen of 256 experts held, an eighth of the vocabulary,
     4 x 8,192 tokens): the full-causal kernel at 48 query heads over 8
     key/value heads (layers 0 and 4) and the windowed one at 64 (layers 1-3),
-    each forward, recomputed forward and backward, under the names a device
-    trace tells them by; the expert layers' grouped matmuls over the bounded
+    each forward and backward (the recomputed forward holds no kernel), under
+    the names a device trace tells them by; the expert layers' grouped matmuls over the bounded
     chunk of rows, never the S x k = 262,144. That it compiles says the step
     fits the chip beside its state."""
     from benchmark import moe_trace
@@ -213,8 +214,8 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     names = _kernel_names(calls)
     full = [n for n in names if n.startswith(("dvc_flash_fwd", "dvc_flash_bwd"))]
     win = [n for n in names if n.startswith("dvc_flash_win_")]
-    assert len(full) == 6 and sum(n.startswith("dvc_flash_bwd") for n in full) == 2, names
-    assert len(win) == 9 and sum(n.startswith("dvc_flash_win_bwd") for n in win) == 3, names
+    assert len(full) == 4 and sum(n.startswith("dvc_flash_bwd") for n in full) == 2, names
+    assert len(win) == 6 and sum(n.startswith("dvc_flash_win_bwd") for n in win) == 3, names
     assert all("bf16[4,48,8192,128]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
     assert all("bf16[4,64,8192,128]" in ln for ln in calls if "dvc_flash_win_" in ln)
     assert all("bf16[4,8,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
@@ -230,28 +231,31 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
 @pytest.mark.parametrize("model,batch,layers,shape", [
     ("gpt2_medium", 16, 2, "bf16[16,16,1024,64]"), ("olmoe_1b_7b", 4, 1, "bf16[4,16,4096,128]")])
 def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, model, batch, layers, shape):
-    """The gpt2 and OLMoE cells trace exactly the attention kernels they
-    traced before the windowed ones existed: ``dvc_flash_fwd`` twice and
-    ``dvc_flash_bwd`` once a scanned layer, at equal head counts, and no
-    windowed name."""
+    """The gpt2 and OLMoE cells trace the attention kernels under the names
+    they had before the windowed ones existed: ``dvc_flash_fwd`` and
+    ``dvc_flash_bwd`` once a scanned layer, forward and backward; the
+    recomputed forward holds no kernel; at equal head counts, and no windowed
+    name."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     calls = _kernel_calls(_step_text(v5e, model, 1, 1, batch, n_layers=layers))
     flash = sorted(n.split(".")[0] for n in _kernel_names(calls) if n.startswith("dvc_flash"))
-    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_fwd"], flash
+    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd"], flash
     assert all(ln.count(shape) >= 3 for ln in calls if "dvc_flash_" in ln)  # q, k and v alike
 
 
 def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
     """large-solo-4chip's step (dp=2, tp=2, batch 32, 20 heads): each chip's
-    kernel sees its own 16 rows and 10 heads, and nothing gathered feeds it."""
+    kernel sees its own 16 rows and 10 heads, forward and backward; the
+    recomputed forward holds no kernel (the kept names pass through the
+    per-shard ``shard_map``); and nothing gathered feeds it."""
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
     calls = _kernel_calls(text)
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[16,10,1024,64]" in ln for ln in calls)
     assert not any("[32," in ln.split("custom-call(")[1].split(")")[0] for ln in calls)
     gathered = set(re.findall(r"(%all-gather[\w.\-]*) =", text))
@@ -277,7 +281,8 @@ def test_four_chip_step_moves_no_activation_for_qkv(v5e, as_on_the_chip):
     crosses a link at an activation's size is Megatron's price alone: the
     all-reduce after each row-parallel product (attn_out and mlp_out forward,
     attn_out in the recomputed forward) and before each column-parallel one in
-    the backward (mlp_in, qkv). The kernel still sees its own 10 heads."""
+    the backward (mlp_in, qkv). The kernel still sees its own 10 heads, forward
+    and backward."""
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
@@ -288,7 +293,7 @@ def test_four_chip_step_moves_no_activation_for_qkv(v5e, as_on_the_chip):
     assert activation_sized and set(activation_sized) == {"all-reduce"}, activation_sized
     assert len(activation_sized) <= 5, activation_sized
     calls = _kernel_calls(text)
-    assert len(calls) == 3 and all("bf16[16,10,1024,64]" in ln for ln in calls)
+    assert len(calls) == 2 and all("bf16[16,10,1024,64]" in ln for ln in calls)
 
 
 def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):
