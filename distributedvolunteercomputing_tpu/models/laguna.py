@@ -43,7 +43,7 @@ own among them).
 The cut a chip makes without touching a width: ``n_layers`` (the first n of
 the published lists), ``experts_held`` with ``expert_offset`` (which contiguous
 slice of the 256 this chip holds: the router keeps its 256 outputs and its
-top-8, and ``ops/moe_dispatch.share_swiglu_experts`` computes the held
+top-8, and ``ops/moe_dispatch.share_glu_experts`` computes the held
 experts' part of the sum; what the others would add is left out, as on one
 chip of an expert-parallel deployment before the combine), ``vocab`` (a slice
 of the vocabulary: embedding, head and loss over the slice).
@@ -68,7 +68,7 @@ from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads, yarn_inv_freq,
 )
-from distributedvolunteercomputing_tpu.ops.moe_dispatch import share_swiglu_experts
+from distributedvolunteercomputing_tpu.ops.moe_dispatch import share_glu_experts
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -249,7 +249,7 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Lag
         h = h.reshape(b * t, d)
         top_idx, weights, scores = route(p["router"], h, cfg.top_k, cfg.routed_scale)
         ex = p["experts"]
-        y, group_sizes, dropped, moved = share_swiglu_experts(
+        y, group_sizes, dropped, moved, _ = share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
             cfg.expert_offset, cfg.n_experts,
         )

@@ -57,7 +57,7 @@ from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads,
 )
-from distributedvolunteercomputing_tpu.ops.moe_dispatch import dropless_swiglu_experts
+from distributedvolunteercomputing_tpu.ops.moe_dispatch import dropless_glu_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,7 +164,7 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Olm
         h = common.rmsnorm(p["ln_mlp"], x, cfg.rms_eps).reshape(b * t, d)
         top_idx, top_gates, probs, logits = route(p["router"], h, cfg.top_k)
         ex = p["experts"]
-        y, group_sizes, dropped = dropless_swiglu_experts(
+        y, group_sizes, dropped, _ = dropless_glu_experts(
             h, top_idx, top_gates, ex["w_gate"], ex["w_up"], ex["w_down"]
         )
         x = x + y.reshape(b, t, d)

@@ -1,4 +1,7 @@
-"""Model registry: one bundle per reference workload (BASELINE.json:7-11).
+"""Model registry: one bundle per reference workload (BASELINE.json:7-11),
+and the public architectures run at their published sizes beyond them
+(``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``: each takes the
+overrides that cut it to one chip's share without touching a width).
 
 Bundles are built lazily so importing the registry never pays for the whole
 zoo. Each bundle closes over its config and exposes:
@@ -179,6 +182,26 @@ def _laguna(**overrides: Any) -> ModelBundle:
     )
 
 
+def _smallthinker(**overrides: Any) -> ModelBundle:
+    """SmallThinker-21BA3B-Instruct at its published sizes
+    (models/smallthinker.py): 52 layers of 64 experts are many chips' work;
+    ``n_layers`` (whole periods of four), ``experts_held`` / ``expert_offset``
+    and ``vocab`` cut it to one chip's share."""
+    from distributedvolunteercomputing_tpu.models import smallthinker
+    from distributedvolunteercomputing_tpu.training import data
+
+    cfg = dataclasses.replace(smallthinker.SmallThinkerConfig(), **overrides)
+    return ModelBundle(
+        name="smallthinker_21b_a3b",
+        config=cfg,
+        init=lambda rng: smallthinker.init(rng, cfg),
+        loss_fn=lambda p, b, rng: smallthinker.loss_fn(p, b, rng, cfg),
+        make_batch=lambda rng, bs: data.synthetic_lm_batch(
+            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
+        ),
+    )
+
+
 def _vit(**overrides: Any) -> ModelBundle:
     from distributedvolunteercomputing_tpu.models import vit
     from distributedvolunteercomputing_tpu.training import data
@@ -227,6 +250,7 @@ _REGISTRY: Dict[str, Callable[..., ModelBundle]] = {
     "gpt2_moe": _gpt2_moe,
     "olmoe_1b_7b": _olmoe,
     "laguna_xs2": _laguna,
+    "smallthinker_21b_a3b": _smallthinker,
     "llama_lora": _llama_lora,
 }
 

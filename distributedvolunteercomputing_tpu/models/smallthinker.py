@@ -1,0 +1,317 @@
+"""SmallThinker-21BA3B-Instruct (PowerInfer;
+https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct ``config.json``):
+a 52-layer decoder, d 2,560, 64 ReGLU experts of width 768 a layer, six chosen
+per token, no shared expert and no dense layer. 21 B parameters, 3 B of them
+at work on a token, context 16,384.
+
+By layer, from the published lists ``sliding_window_layout`` and
+``rope_layout``: layer ``l`` with ``l % 4 == 0`` is a GLOBAL layer (both 0:
+every earlier key, no position encoding at all); the three that follow are
+SLIDING layers (both 1: a 4,096-key window, rotary embedding). Every layer has
+28 query heads over 4 key/value heads of 128. RMSNorm eps 1e-6, no biases, no
+QK norm::
+
+    r  = Wr x                  [64] float32: the router reads the layer's INPUT,
+                               before the input norm and before attention
+    n  = rmsnorm(x)
+    q  = Wq n [28 x 128],  k = Wk n [4 x 128],  v = Wv n [4 x 128]
+    sliding layer: rotary on all 128 coordinates of q and k, theta 1,500,000,
+                   half-split (``rotate_half``) convention
+    global layer:  nothing (NoPE)
+    a  = causal softmax attention, scale 1/sqrt(128), query head h reads KV
+         head h // 7; sliding layer: query i sees keys j, i - 4096 < j <= i
+    h  = x + Wo a
+    n2 = rmsnorm(h)
+    T  = top6(r);  w_e = exp(r_e) / sum_{e' in T} exp(r_e')
+    expert_e(u) = Wdown_e (relu(Wgate_e u) * Wup_e u)
+    y  = h + sum_{e in T and held here} w_e expert_e(n2)
+
+Final RMSNorm, untied head. Loss = mean cross-entropy over the vocabulary
+(slice) + ``aux_coef`` x load balancing: ``E sum_e f_e P_e`` as
+``models/olmoe.py`` computes it (means over layers and tokens first, product
+after), ``P_e`` from the softmax of ``r`` over all 64.
+
+Assumed where ``config.json`` is silent (the benchmark's configuration file
+gives each reason): the router's input is the layer's input (the catalog's
+"router placed before attention"; no key says it); its weights are the softmax
+over all 64 renormalised over the chosen six
+(``moe_primary_router_apply_softmax`` and ``norm_topk_prob`` both true: the
+same number as the softmax over the six); no secondary experts (no key).
+
+The cut a chip makes without touching a width, as ``models/laguna.py``:
+``n_layers`` (whole periods of four), ``experts_held`` with ``expert_offset``
+(``ops/moe_dispatch.share_glu_experts`` computes the held experts' part of
+the sum; what the others would add is left out), ``vocab`` (a slice).
+
+Because the router reads the layer's input, the layer routes FIRST: the float32
+router product, the top-6 and the share's sort (``moe_dispatch.plan_share``)
+need nothing of attention, and the plan's S x k vectors are spent after it.
+
+All layers have one parameter shape and two kinds, so the model is scanned by
+period: ``params["blocks"] = {"global": [P, ...], "sliding": [P, 3, ...]}``
+(P periods), a ``lax.scan`` over the periods whose body runs the global layer
+and an inner scan over the three sliding layers, each layer rematerialised
+(``models/common.remat_layer``): one compiled layer of each kind, whatever the
+depth. Departures as in ``models/olmoe.py``: float32 parameters and bfloat16
+compute on a TPU, the router's product in float32 at the highest precision,
+rotary angles in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from distributedvolunteercomputing_tpu.models import common
+from distributedvolunteercomputing_tpu.ops.attention import (
+    attention_core, merge_heads, rope, split_heads,
+)
+from distributedvolunteercomputing_tpu.ops.moe_dispatch import plan_share, share_glu_experts
+
+GLOBAL, SLIDING = "global", "sliding"
+
+# Rows a chunk of the share's dispatch holds over the share's even part (the
+# dispatch's own default is 3, measured on Laguna's sigmoid router). MEASURED
+# (PR 35, TPU v5e, smallthinker-solo-16k, Adam at 1e-3 from scratch;
+# experiments/laguna_routing_trace.py --config smallthinker-21b-a3b; PERF.md,
+# Findings of PR 35): this router is a softmax over the raw residual stream and
+# collapses WHOLLY within four steps (the fullest held expert takes all 32,768
+# tokens of a step from step 4 on, for the rest of a 60-step run): every token
+# picks the same six experts, of which m fall on this chip's eight of 64
+# (hypergeometric: m >= 3 in 2.2% of layers, m >= 4 in 0.14%). At 3 (2.25 S
+# rows) a layer with m = 3 needs 3 S rows and runs two chunks for as long as
+# the collapse lasts: one run of seven read 33,487 tokens/s where the others
+# read 36,090-36,179. At 4.25 (3.19 S: three collapsed experts and the other
+# five at half their even share) such a layer is one chunk.
+SHARE_ROWS_SLACK = 4.25
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig:
+    """Defaults are the published sizes of SmallThinker-21BA3B-Instruct."""
+
+    vocab: int = 151936
+    max_len: int = 16384      # max_position_embeddings: the sequences a step trains on
+    d_model: int = 2560
+    head_dim: int = 128
+    n_heads: int = 28
+    n_kv_heads: int = 4
+    n_layers: int = 52
+    period: int = 4           # layer l is global where l % period == 0, sliding elsewhere
+    d_expert: int = 768       # one expert's width
+    n_experts: int = 64       # the router's outputs
+    top_k: int = 6
+    experts_held: int = 64    # how many of them this chip holds ...
+    expert_offset: int = 0    # ... from which on
+    window: int = 4096
+    rms_eps: float = 1e-6
+    rope_theta: float = 1500000.0
+    aux_coef: float = 0.01
+    remat: bool = True
+    xent_chunk: int = 512
+
+    # which stream the router reads (the ``moe.route`` span says it)
+    router_site = "layer_input"
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k={self.top_k} must be in [1, n_experts={self.n_experts}]")
+        if not (0 <= self.expert_offset and 1 <= self.experts_held
+                and self.expert_offset + self.experts_held <= self.n_experts):
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
+                f"are not a slice of the {self.n_experts}")
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"{self.n_kv_heads} key/value heads do not divide {self.n_heads} query heads")
+        if self.period < 2 or self.n_layers % self.period:
+            raise ValueError(
+                f"n_layers={self.n_layers} is not whole periods of {self.period} layers")
+
+    @property
+    def periods(self) -> int:
+        return self.n_layers // self.period
+
+    def attention_kind(self, layer: int) -> str:
+        return GLOBAL if layer % self.period == 0 else SLIDING
+
+
+def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
+    return jax.random.normal(rng, shape, jnp.float32) * scale
+
+
+def _layer_init(rng: jax.Array, cfg: SmallThinkerConfig) -> common.Params:
+    k = jax.random.split(rng, 8)
+    d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_expert
+    return {
+        "ln_attn": common.rmsnorm_init(d),
+        "wq": _matrix(k[0], (d, cfg.n_heads * hd)),
+        "wk": _matrix(k[1], (d, cfg.n_kv_heads * hd)),
+        "wv": _matrix(k[2], (d, cfg.n_kv_heads * hd)),
+        "wo": _matrix(k[3], (cfg.n_heads * hd, d)),
+        "ln_mlp": common.rmsnorm_init(d),
+        "router": _matrix(k[4], (d, cfg.n_experts)),
+        # the held experts stacked on a leading axis -> sharded over ep (parallel/sharding.py)
+        "experts": {"w_gate": _matrix(k[5], (cfg.experts_held, d, f)),
+                    "w_up": _matrix(k[6], (cfg.experts_held, d, f)),
+                    "w_down": _matrix(k[7], (cfg.experts_held, f, d))},
+    }
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def init(rng: jax.Array, cfg: SmallThinkerConfig) -> common.Params:
+    """One program for the whole tree. A layer's key is its index's, so layer
+    ``l`` is ``blocks["global"][l // 4]`` or ``blocks["sliding"][l // 4, l % 4 - 1]``."""
+    keys = jax.random.split(rng, 3)
+    layer_keys = jax.random.split(keys[1], cfg.n_layers).reshape(cfg.periods, cfg.period, -1)
+    one = functools.partial(_layer_init, cfg=cfg)
+    return {
+        "wte": common.embed_init(keys[0], cfg.vocab, cfg.d_model),
+        "blocks": {GLOBAL: jax.vmap(one)(layer_keys[:, 0]),
+                   SLIDING: jax.vmap(jax.vmap(one))(layer_keys[:, 1:])},
+        "ln_f": common.rmsnorm_init(cfg.d_model),
+        "lm_head": _matrix(keys[2], (cfg.d_model, cfg.vocab)),
+    }
+
+
+def route(p_router: jax.Array, x: jax.Array, top_k: int):
+    """Router of one layer on its INPUT ``x`` [S, d] -> (top_idx [S, k],
+    weights [S, k] float32, probs [S, E] float32). Logits from a float32
+    product at the highest precision; the weights are the softmax over the
+    chosen ``k`` logits, which is the softmax over all E renormalised over the
+    chosen; ``probs`` is that softmax over all E (the balancing term's)."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), p_router, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+    top_logits, top_idx = jax.lax.top_k(logits, top_k)
+    return top_idx, jax.nn.softmax(top_logits, axis=-1), jax.nn.softmax(logits, axis=-1)
+
+
+def _zero_stats(cfg: SmallThinkerConfig) -> Dict[str, jax.Array]:
+    zero = jnp.zeros((), jnp.float32)
+    return {
+        "choices": jnp.zeros((cfg.n_experts,), jnp.float32),  # sum over layers of f_e
+        "probs": jnp.zeros((cfg.n_experts,), jnp.float32),    # sum over layers of P_e
+        "load_max": zero,    # fullest held expert of any layer, rows
+        "rows_held": zero,   # assignments on held experts, all layers
+        "rows_moved": zero,  # rows the dispatch gathered to its grouped matmuls, all layers
+        "dropped": zero,     # held assignments no grouped matmul computed
+        "act_zeros": zero,   # entries of the held rows' gate that the ReLU set to zero
+    }
+
+
+def _attention(p: common.Params, x: jax.Array, cfg: SmallThinkerConfig, kind: str) -> jax.Array:
+    dtype = x.dtype
+    n = common.rmsnorm(p["ln_attn"], x, cfg.rms_eps)
+    q = split_heads(n @ p["wq"].astype(dtype), cfg.n_heads)
+    k = split_heads(n @ p["wk"].astype(dtype), cfg.n_kv_heads)
+    v = split_heads(n @ p["wv"].astype(dtype), cfg.n_kv_heads)
+    if kind == SLIDING:  # a global layer has no position encoding at all
+        q = rope(q, base=cfg.rope_theta, layout="half")
+        k = rope(k, base=cfg.rope_theta, layout="half")
+    a = attention_core(q, k, v, causal=True, window=cfg.window if kind == SLIDING else None)
+    return x + merge_heads(a) @ p["wo"].astype(dtype)
+
+
+def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: SmallThinkerConfig,
+           kind: str):
+    """One layer: (x, running routing statistics) -> the same, and the layer's
+    routes ``top_idx`` [S, k]."""
+    b, t, d = x.shape
+    held = cfg.experts_held
+    with jax.named_scope("moe_route"):  # all of the routing, before attention
+        top_idx, weights, probs = route(p["router"], x.reshape(b * t, d), cfg.top_k)
+        plan = (plan_share(top_idx, cfg.expert_offset, held, cfg.n_experts, SHARE_ROWS_SLACK)
+                if held < cfg.n_experts else None)
+    with jax.named_scope("attention"):
+        x = _attention(p, x, cfg, kind)
+    with jax.named_scope("moe"):
+        h = common.rmsnorm(p["ln_mlp"], x, cfg.rms_eps).reshape(b * t, d)
+        ex = p["experts"]
+        y, group_sizes, dropped, moved, zeros = share_glu_experts(
+            h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
+            cfg.expert_offset, cfg.n_experts, act="relu", plan=plan, slack=SHARE_ROWS_SLACK,
+        )
+        x = x + y.reshape(b, t, d)
+        chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1))
+        load = group_sizes.astype(jnp.float32)
+        stats = {
+            "choices": stats["choices"] + chosen / (b * t),
+            "probs": stats["probs"] + jnp.mean(probs, axis=0),
+            "load_max": jnp.maximum(stats["load_max"], jnp.max(load)),
+            "rows_held": stats["rows_held"] + jnp.sum(load),
+            "rows_moved": stats["rows_moved"] + moved.astype(jnp.float32),
+            "dropped": stats["dropped"] + dropped.astype(jnp.float32),
+            "act_zeros": stats["act_zeros"] + zeros.astype(jnp.float32),
+        }
+    return x, stats, top_idx
+
+
+def _trunk(params: common.Params, tokens: jax.Array, cfg: SmallThinkerConfig):
+    """Final hidden states [B, T, d], the routing statistics summed over the
+    layers, and the layers' routes ``[L, S, k]`` in layer order."""
+    x = params["wte"][tokens].astype(common.compute_dtype())
+
+    def layer_of(kind: str, layers: int):
+        def body(p, x, stats):
+            return _layer(p, x, stats, cfg, kind)
+
+        return common.remat_layer(body, layers) if cfg.remat else body
+
+    global_layer = layer_of(GLOBAL, cfg.periods)
+    sliding_layer = layer_of(SLIDING, cfg.periods * (cfg.period - 1))
+
+    def sliding_step(carry, p):
+        x, stats, top_idx = sliding_layer(p, *carry)
+        return (x, stats), top_idx
+
+    def period_step(carry, p):
+        x, stats, first = global_layer(p[GLOBAL], *carry)
+        carry, rest = jax.lax.scan(sliding_step, (x, stats), p[SLIDING])
+        return carry, jnp.concatenate([first[None], rest])
+
+    (x, stats), routes = jax.lax.scan(period_step, (x, _zero_stats(cfg)), params["blocks"])
+    routes = routes.reshape(cfg.n_layers, tokens.size, cfg.top_k)
+    return common.rmsnorm(params["ln_f"], x, cfg.rms_eps), stats, routes
+
+
+def loss_and_routes(
+    params: common.Params, batch: Dict[str, jax.Array], cfg: SmallThinkerConfig
+) -> Tuple[jax.Array, Dict[str, jax.Array], jax.Array]:
+    """(loss, metrics, the experts every layer chose ``[L, S, k]``); see
+    ``models/olmoe.loss_and_routes`` for what the routes are for."""
+    tokens = batch["tokens"]
+    x, stats, routes = _trunk(params, tokens, cfg)
+    lm = common.lm_xent_chunked(
+        x, params["lm_head"], batch["targets"], chunk=cfg.xent_chunk, head_layout="dv"
+    )
+    n = cfg.n_layers
+    aux = cfg.n_experts * jnp.sum((stats["choices"] / n) * (stats["probs"] / n))
+    loss = lm + cfg.aux_coef * aux
+    metrics = {
+        "loss": loss, "lm_loss": lm, "aux_loss": aux,
+        # as models/laguna.py: over the held experts, summed over the layers
+        "moe_load_max": stats["load_max"],
+        "moe_load_mean": jnp.asarray(tokens.size * cfg.top_k / cfg.n_experts, jnp.float32),
+        "moe_rows_held": stats["rows_held"],
+        "moe_rows_moved": stats["rows_moved"],
+        "moe_dropped": stats["dropped"],
+        # of the held assignments' d_expert hidden activations each, the share
+        # whose gate the ReLU set to exactly zero (what a sparse down-projection
+        # could skip); the mask is the activation's own
+        "moe_act_zero_share": stats["act_zeros"] / jnp.maximum(
+            stats["rows_held"] * cfg.d_expert, 1.0),
+    }
+    return loss, metrics, routes
+
+
+def loss_fn(
+    params: common.Params, batch: Dict[str, jax.Array], rng: Optional[jax.Array],
+    cfg: SmallThinkerConfig
+) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    return loss_and_routes(params, batch, cfg)[:2]

@@ -45,7 +45,9 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # (path regex, spec). First match wins; paths look like "blocks/qkv/w"
-# (stacked scan-over-layers layout: leaves carry a leading n_layers axis).
+# (stacked scan-over-layers layout: leaves carry a leading n_layers axis; a
+# model scanned by period, models/smallthinker.py, has "blocks/global/wq"
+# [P, d, q] and "blocks/sliding/wq" [P, 3, d, q]: the period axes lead).
 # Column-parallel weights are [L, d_in, d_out] → sharded on d_out; their
 # biases [L, d_out] → sharded on d_out. Row-parallel weights are sharded on
 # d_in; their biases are full-size → replicated. Specs below are written for

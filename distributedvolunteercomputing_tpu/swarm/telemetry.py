@@ -835,18 +835,20 @@ class Telemetry:
             ).inc(layers=str(layers), bytes=str(nbytes))
 
     def count_moe_dispatch(
-        self, impl: str, n_experts: int, top_k: int, rows: int, held: Optional[int] = None
+        self, impl: str, n_experts: int, top_k: int, rows: int, held: Optional[int] = None,
+        act: str = "swiglu",
     ) -> None:
         """One TRACED expert dispatch took grouped matmul ``impl``
         ("megablox" | "ragged_dot"): ops.moe_dispatch's observer. ``rows`` are
         the rows one grouped matmul is handed, ``held`` the experts this chip
-        holds of the ``n_experts`` routed over (all of them by default)."""
+        holds of the ``n_experts`` routed over (all of them by default),
+        ``act`` the experts' kind ("swiglu" | "reglu")."""
         if self.enabled:
             self.registry.counter(
                 "swarm.moe_dispatch",
                 "traced expert dispatches by the grouped matmul that took them",
             ).inc(impl=impl, E=str(n_experts), k=str(top_k), rows=str(rows),
-                  held=str(n_experts if held is None else held))
+                  held=str(n_experts if held is None else held), act=act)
 
     def _observe_span(self, sp: dict) -> None:
         if self.watchdog.enabled:
@@ -873,6 +875,12 @@ class Telemetry:
                 self.registry.gauge(
                     "swarm.moe_experts_held", "experts this chip holds of those routed over",
                 ).set(float(attrs.get("experts_held", 0.0)))
+            if "moe_act_zero_share" in attrs:  # a model with ReLU-gated experts
+                self.registry.gauge(
+                    "swarm.moe_act_zero_share",
+                    "share of the held rows' hidden activations that the ReLU gate set to "
+                    "zero, at the last log point",
+                ).set(float(attrs["moe_act_zero_share"]))
 
     def moe(self) -> dict:
         """Routing of a sparse-expert model: traced dispatches per grouped
@@ -881,7 +889,8 @@ class Telemetry:
         dispatch = self._counts_by("swarm.moe_dispatch", "impl")
         if dispatch:
             out["dispatch"] = dispatch
-        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held", "experts_held"):
+        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held", "experts_held",
+                    "act_zero_share"):
             v = self.registry.gauge(f"swarm.moe_{key}").value()
             if v is not None:
                 out[key] = v

@@ -66,7 +66,7 @@ COPIES_IN_FLIGHT = 2
 # ``moe.route`` span), and how often, in steps, the loop notes them between
 # its log points.
 ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
-                "moe_rows_moved", "aux_loss", "lm_loss")
+                "moe_rows_moved", "moe_act_zero_share", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
 
 
@@ -519,6 +519,9 @@ class Trainer:
         held = getattr(self.bundle.config, "experts_held", None)
         if held is not None:  # a model that holds a share of its experts says how many
             attrs["experts_held"] = int(held)
+        # which stream the layer's router reads: its input (before attention) or
+        # what attention made of it
+        attrs["router_site"] = getattr(self.bundle.config, "router_site", "post_attention")
         with self._phase("moe.route", step=step_no, **attrs):
             pass
 

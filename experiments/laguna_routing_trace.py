@@ -1,9 +1,12 @@
 """What the router sends one chip's share while the model trains: per step of
-laguna-solo-8k's first steps, the assignments on held experts (all expert
-layers together), the rows the dispatch gathered for them, and the fullest
-held expert: the reading ``SHARE_ROWS_SLACK`` in ops/moe_dispatch.py rests on.
+a cell's first steps (laguna-solo-8k's by default; ``--config
+smallthinker-21b-a3b`` for smallthinker-solo-16k's), the assignments on held
+experts (all expert layers together), the rows the dispatch gathered for them,
+and the fullest held expert: the reading ``SHARE_ROWS_SLACK`` in
+ops/moe_dispatch.py rests on.
 
     chiprun -- python experiments/laguna_routing_trace.py --seeds 2
+    chiprun -- python experiments/laguna_routing_trace.py --config smallthinker-21b-a3b --seeds 3 --steps 60
     python experiments/laguna_routing_trace.py --config tiny-rehearsal-laguna --steps 12 --seeds 1
 
 One JSON line per seed in ``chiprun_out/laguna_routing_trace.json``; with
@@ -22,9 +25,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
-from benchmark import datagen
+from benchmark import datagen, references
 from benchmark.manifest import Manifest
-from benchmark.references import laguna as ref
 from distributedvolunteercomputing_tpu.models import get_model
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
 from distributedvolunteercomputing_tpu.training.data import npz_batch_iter
@@ -42,7 +44,7 @@ def main() -> int:
     ap.add_argument("--out", default="chiprun_out/laguna_routing_trace.json")
     args = ap.parse_args()
     cfg = Manifest().load_config(args.config)
-    sizes = ref.sizes(cfg)
+    sizes = references.load(cfg["family"]).sizes(cfg)
     dev = jax.devices()[0]
     rows = []
     with tempfile.TemporaryDirectory(prefix="laguna_routing_") as workdir:  # under TMPDIR: the run's own ground
@@ -71,8 +73,10 @@ def one_seed(args, cfg, sizes, dev, workdir, seed):
         inner(step, m, n_samples=n_samples))
     tr.run(steps=args.steps, log_every=1)
     c = bundle.config
-    n_sparse = c.n_layers - c.dense_layers
-    bound = moe_dispatch.share_rows_bound(vol["batch_size"] * c.max_len, c.top_k, c.experts_held, c.n_experts)
+    n_sparse = c.n_layers - getattr(c, "dense_layers", 0)
+    slack = getattr(sys.modules[type(c).__module__], "SHARE_ROWS_SLACK", None)  # the model's own, if it brings one
+    bound = moe_dispatch.share_rows_bound(
+        vol["batch_size"] * c.max_len, c.top_k, c.experts_held, c.n_experts, slack)
     rec = {"seed": seed, "device": {"platform": dev.platform, "kind": dev.device_kind},
            "chunk_rows": bound, "expert_layers": n_sparse,
            "even_share_a_layer": vol["batch_size"] * c.max_len * c.top_k * c.experts_held / c.n_experts,

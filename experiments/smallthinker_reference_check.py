@@ -1,0 +1,156 @@
+"""How close the program comes to the plain reference at
+SmallThinker-21BA3B-Instruct's published widths (the cell's cut: one period of
+four layers, eight of 64 experts, an eighth of the vocabulary, one sequence of
+16,384 tokens): the readings that set ``reference_check`` in
+``benchmark/configs/smallthinker-21b-a3b.json``.
+
+    chiprun -- python experiments/smallthinker_reference_check.py --seeds 3 --left-out
+    python experiments/smallthinker_reference_check.py --config tiny-rehearsal-smallthinker --seeds 1 --left-out
+
+Per seed (the benchmark's own seeded sequence and seeded initial parameters):
+the program's loss and gradients (bf16 compute on a TPU) against
+``benchmark/references/smallthinker.py`` (float32, highest precision)
+
+- as the harness calls it, without routes: the error ``correct`` sees, and the
+  share of the L x S x k assignments on which the two picked another expert;
+- with the program's routes handed over: the arithmetic's error alone;
+- the reference on parameters rounded to bfloat16 and to an 8-bit float (e4m3),
+  same routes, against itself: what lower precisions read, which the limits
+  must refuse;
+- ``--left-out`` (first seed): the reference with one term of the layer
+  equations computed wrongly (``references/smallthinker.VARIANTS``: the router
+  after attention, SiLU for ReLU, rotary on the global layer, no window, top-6
+  weights not renormalised), against itself with the same routes (the router
+  after attention picks its own): each must land outside a limit.
+
+One JSON line per seed and a summary; all in
+``chiprun_out/smallthinker_reference_check.json``. A CPU run compares float32
+with float32 and checks the paths only.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark import datagen
+from benchmark.manifest import Manifest
+from benchmark.references import smallthinker as ref
+from distributedvolunteercomputing_tpu.models import get_model, smallthinker
+from experiments.olmoe_reference_check import _diff2, _norm2, rel_err
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--config", default="smallthinker-21b-a3b")
+    ap.add_argument("--seeds", type=int, default=3)
+    ap.add_argument("--first-seed", type=int, default=3500003301)
+    ap.add_argument("--seq-len", type=int, default=None)
+    ap.add_argument("--left-out", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/smallthinker_reference_check.json")
+    args = ap.parse_args()
+
+    cfg = Manifest().load_config(args.config)
+    rc = dict(cfg["reference_check"])
+    if args.seq_len:
+        rc["seq_len"] = args.seq_len
+    sizes = ref.sizes(cfg)
+    bundle = get_model(cfg["registry_model"], **cfg["model_overrides"])
+    ref.check_config(bundle.config, cfg)
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    hp = ref.hyper(cfg)
+    n_routed = bundle.config.n_experts
+
+    @jax.jit
+    def program(params, tokens, targets):
+        def f(p):
+            loss, _, routes = smallthinker.loss_and_routes(
+                p, {"tokens": tokens, "targets": targets}, bundle.config)
+            return loss, routes
+
+        (loss, routes), grads = jax.value_and_grad(f, has_aux=True)(params)
+        return loss, grads, routes
+
+    def reference_with(variant=None):
+        return jax.jit(lambda p, t, y, r=None: jax.value_and_grad(ref.loss)(p, t, y, hp, r, False, variant))
+
+    reference = reference_with()
+    # as the harness calls it, and the routes it chose (one compilation for both)
+    reference_alone = jax.jit(lambda p, t, y: jax.value_and_grad(
+        lambda p: ref.loss(p, t, y, hp, None, True), has_aux=True)(p))
+    rounded = {
+        # bfloat16's 8 exponent and 7 mantissa bits, e4m3's 4 and 3 (a convert
+        # there and back is folded away by the TPU compiler: it reads exactly 0)
+        "bf16": jax.jit(lambda p: jax.tree_util.tree_map(
+            lambda a: jax.lax.reduce_precision(a, exponent_bits=8, mantissa_bits=7), p)),
+        "e4m3": jax.jit(lambda p: jax.tree_util.tree_map(
+            lambda a: jax.lax.reduce_precision(a, exponent_bits=4, mantissa_bits=3), p)),
+    }
+
+    rows = []
+    for seed in range(args.first_seed, args.first_seed + args.seeds):
+        params = jax.jit(bundle.init)(jax.random.PRNGKey(seed))
+        arrays = datagen.lm_arrays(seed + 0x5EED, 1, rc["seq_len"], sizes["vocab"])
+        tok, tgt = arrays["tokens"][:1], arrays["targets"][:1]
+        lp, gp, mine = program(params, tok, tgt)
+        mine = np.asarray(mine)                                # [L, S, k]
+        (lr, theirs), gr = reference_alone(params, tok, tgt)
+        oh = lambda r: np.eye(n_routed, dtype=bool)[r].any(axis=-2)  # noqa: E731
+        rec = {"seed": seed, "seq_len": rc["seq_len"], "loss_program": float(lp),
+               "flipped_share": float(1.0 - (oh(mine) & oh(np.asarray(theirs))).sum() / mine.size)}
+        rec["loss_reference"] = float(lr)
+        rec["grad_rel_err_no_routes"] = rel_err(gp, gr)
+        rec["loss_abs_err_no_routes"] = abs(float(lp) - float(lr))
+        per_leaf = sorted(
+            ((math.sqrt(float(n) / float(d)), jax.tree_util.keystr(path))
+             for (path, n), d in zip(jax.tree_util.tree_leaves_with_path(_diff2(gp, gr)),
+                                     jax.tree_util.tree_leaves(_norm2(gr))) if float(d) > 0),
+            reverse=True)
+        rec["worst_leaves_no_routes"] = [[name, err] for err, name in per_leaf[:3]]
+        del gr
+        routes = jnp.asarray(mine)
+        lr2, gr2 = reference(params, tok, tgt, routes)
+        rec["grad_rel_err_with_routes"] = rel_err(gp, gr2)
+        rec["loss_abs_err_with_routes"] = abs(float(lp) - float(lr2))
+        del gp
+        for name, to in rounded.items():
+            lq, gq = reference(to(params), tok, tgt, routes)
+            rec[f"{name}_params_grad_rel_err"] = rel_err(gq, gr2)
+            rec[f"{name}_params_loss_abs_err"] = abs(float(lq) - float(lr2))
+            del gq
+        if args.left_out and seed == args.first_seed:
+            for variant in ref.VARIANTS:
+                own_routes = variant == "router_after_attention"  # another input picks other experts
+                lo, go = reference_with(variant)(params, tok, tgt, None if own_routes else routes)
+                rec[f"{variant}_grad_rel_err"] = rel_err(go, gr2)
+                rec[f"{variant}_loss_abs_err"] = abs(float(lo) - float(lr2))
+                del go
+        del gr2
+        rec["device"] = device
+        rows.append(rec)
+        print(json.dumps(rec), flush=True)
+        del params
+    keys = [k for k, v in rows[-1].items() if isinstance(v, float)]
+    summary = {"what": "summary", "device": device,
+               **{f"max_{k}": max(r[k] for r in rows if k in r) for k in keys},
+               **{f"min_{k}": min(r[k] for r in rows if k in r) for k in keys}}
+    summary["peak_bytes_in_use"] = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    print(json.dumps(summary), flush=True)
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as fh:
+        json.dump(rows + [summary], fh, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

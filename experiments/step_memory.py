@@ -24,6 +24,8 @@ CELLS = {
     "large-solo-4chip": ("gpt2_large", 2, 2, 32, {"n_layers": 36}),
     "olmoe-solo": ("olmoe_1b_7b", 1, 1, 4, {"n_layers": 1}),
     "laguna-solo-8k": ("laguna_xs2", 1, 1, 4, {"n_layers": 5, "experts_held": 16, "vocab": 12544}),
+    "smallthinker-solo-16k": ("smallthinker_21b_a3b", 1, 1, 2,
+                              {"n_layers": 4, "experts_held": 8, "vocab": 18992}),
 }
 
 

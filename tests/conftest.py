@@ -172,14 +172,23 @@ def pytest_collection_modifyitems(config, items):
 #   configuration, OLMoE's five metrics its last five and olmoe-solo the last
 #   name of four shared lists; PR 33 appended laguna-solo-8k after them.
 # tests/yardstick/test_yardstick_laguna.py asserts what both asserted, with
-# this cell's values. Strict and AssertionError only: either case fails loudly
-# once it passes (the next benchmark PR relaxes the assertions and deletes this).
+# this cell's values; PR 35 appended smallthinker-solo-16k after laguna-solo-8k
+# in turn (and to six of Laguna's lists), so that file's manifest test and the
+# new configuration's case of the first are marked too, and
+# tests/yardstick/test_yardstick_smallthinker.py asserts what they asserted,
+# one place up. Strict and AssertionError only: each case fails loudly once it
+# passes (the next benchmark PR relaxes the assertions and deletes this).
 _YARDSTICK_PINS = (
     ("test_configuration_file_is_what_the_program_runs", "[laguna-xs2]",
      "asserts reduced == []; laguna-xs2 lists its cut (checked in test_yardstick_laguna.py)"),
     ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_olmoe.py",
      "asserts that olmoe-solo ends the manifest; laguna-solo-8k was appended after it "
      "(checked in test_yardstick_laguna.py)"),
+    ("test_configuration_file_is_what_the_program_runs", "[smallthinker-21b-a3b]",
+     "asserts reduced == []; smallthinker-21b-a3b lists its cut (checked in test_yardstick_smallthinker.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_laguna.py",
+     "asserts that laguna-solo-8k ends the manifest and is alone in Laguna's lists; smallthinker-solo-16k "
+     "was appended after it (checked in test_yardstick_smallthinker.py)"),
 )
 
 

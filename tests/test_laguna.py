@@ -212,7 +212,8 @@ def test_the_step_announces_window_and_key_value_heads():
     assert cores == {("none", "2", "xla"): 2, ("8", "2", "xla"): 3}
     rec = tel.registry.counter("swarm.moe_dispatch")._scrape()["values"][0]
     rows = moe_dispatch.share_rows_bound(2 * 64, 4, 4, 16)
-    assert rec["labels"] == {"impl": "ragged_dot", "E": "16", "k": "4", "rows": str(rows), "held": "4"}
+    assert rec["labels"] == {"impl": "ragged_dot", "E": "16", "k": "4", "rows": str(rows), "held": "4",
+                             "act": "swiglu"}
     assert rec["value"] == 4
 
 
@@ -335,7 +336,7 @@ def test_a_share_is_its_experts_part_with_gradients(offset):
     held = [w[offset:offset + 4] for w in stacks]
 
     def share(x, weights, *held):
-        y, sizes, dropped, moved = moe_dispatch.share_swiglu_experts(x, idx, weights, *held, offset, 16)
+        y, sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(x, idx, weights, *held, offset, 16)
         return jnp.sum(jnp.sin(y)), (y, sizes, dropped, moved)
 
     def dense(x, weights, *held):
@@ -360,7 +361,7 @@ def test_no_held_assignment_is_dropped_when_every_token_picks_the_same_expert():
     x, _, weights, stacks = expert_layer_inputs()
     idx = jnp.tile(jnp.array([[5, 0, 9, 13]], jnp.int32), (48, 1))
     held = [w[4:8] for w in stacks]
-    y, sizes, dropped, moved = moe_dispatch.share_swiglu_experts(x, idx, weights, *held, 4, 16)
+    y, sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(x, idx, weights, *held, 4, 16)
     bound = moe_dispatch.share_rows_bound(48, 4, 4, 16)
     assert np.array_equal(np.asarray(sizes), [0, 48, 0, 0]) and int(dropped) == 0
     assert int(moved) == bound == 64  # one chunk holds them
@@ -368,14 +369,14 @@ def test_no_held_assignment_is_dropped_when_every_token_picks_the_same_expert():
                                rtol=1e-4, atol=1e-5)
     # every choice held: all S x k assignments on this share, still none dropped
     idx = jnp.tile(jnp.array([[4, 5, 6, 7]], jnp.int32), (48, 1))
-    y, sizes, dropped, moved = moe_dispatch.share_swiglu_experts(x, idx, weights, *held, 4, 16)
+    y, sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(x, idx, weights, *held, 4, 16)
     assert np.asarray(sizes).sum() == 192 and int(dropped) == 0 and int(moved) == 3 * bound
     np.testing.assert_allclose(np.asarray(y), np.asarray(dense_experts(x, idx, weights, stacks, range(4, 8))),
                                rtol=1e-4, atol=1e-5)
     # no choice held (a router collapsed onto other chips' experts): zeros, and
     # still one chunk, so that a step's cost does not follow the router
     idx = jnp.tile(jnp.array([[0, 1, 9, 13]], jnp.int32), (48, 1))
-    y, sizes, dropped, moved = moe_dispatch.share_swiglu_experts(x, idx, weights, *held, 4, 16)
+    y, sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(x, idx, weights, *held, 4, 16)
     assert np.asarray(sizes).sum() == 0 and int(dropped) == 0 and int(moved) == bound
     assert not np.asarray(y).any()
 
@@ -407,21 +408,21 @@ def test_the_dropped_count_reads_the_sizes_the_kernels_are_handed(monkeypatch):
     real = moe_dispatch._handed_sizes
     monkeypatch.setattr(moe_dispatch, "_handed_sizes",
                         lambda sizes, lo, rows: real(sizes, lo, rows).at[0].add(-2).at[-1].add(2))
-    *_, dropped, _ = moe_dispatch.share_swiglu_experts(x, idx, weights, *[w[:4] for w in stacks], 0, 16)
+    *_, dropped, _, _ = moe_dispatch.share_glu_experts(x, idx, weights, *[w[:4] for w in stacks], 0, 16)
     assert int(dropped) > 0
 
 
 def test_holding_every_expert_is_the_dispatch_as_it_was():
     x, idx, weights, stacks = expert_layer_inputs()
-    y, sizes, dropped, moved = moe_dispatch.share_swiglu_experts(x, idx, weights, *stacks, 0, 16)
-    y0, sizes0, dropped0 = moe_dispatch.dropless_swiglu_experts(x, idx, weights, *stacks)
+    y, sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(x, idx, weights, *stacks, 0, 16)
+    y0, sizes0, dropped0, _ = moe_dispatch.dropless_glu_experts(x, idx, weights, *stacks)
     assert np.array_equal(np.asarray(y), np.asarray(y0)) and np.array_equal(np.asarray(sizes), np.asarray(sizes0))
     assert int(dropped) == int(dropped0) == 0 and int(moved) == 48 * 4
     # the same program: no chunk loop, no second sort
-    text = jax.jit(lambda *a: moe_dispatch.share_swiglu_experts(*a, 0, 16)[0]).lower(
+    text = jax.jit(lambda *a: moe_dispatch.share_glu_experts(*a, 0, 16)[0]).lower(
         x, idx, weights, *stacks).as_text()
     assert "while" not in text
-    want = jax.jit(lambda *a: moe_dispatch.dropless_swiglu_experts(*a)[0]).lower(
+    want = jax.jit(lambda *a: moe_dispatch.dropless_glu_experts(*a)[0]).lower(
         x, idx, weights, *stacks).as_text()
     assert text.count("stablehlo.sort") == want.count("stablehlo.sort") == 1
 
@@ -433,7 +434,7 @@ def test_a_share_moves_a_bounded_chunk_of_rows_and_differentiates_into_gathers()
     x, idx, weights, stacks = expert_layer_inputs()
     held = [w[:4] for w in stacks]
     text = jax.jit(jax.grad(lambda x: jnp.sum(
-        moe_dispatch.share_swiglu_experts(x, idx, weights, *held, 0, 16)[0]))).lower(x).as_text()
+        moe_dispatch.share_glu_experts(x, idx, weights, *held, 0, 16)[0]))).lower(x).as_text()
     assert "192x16x" not in text  # no [S k, d] tensor: the chunk's 64 rows are what moves
     # the only scatters are of row indices (int32) and of the S k gates'
     # cotangents (scalars), never of rows

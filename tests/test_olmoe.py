@@ -131,7 +131,7 @@ def test_dispatch_equals_a_dense_computation_with_all_rows_on_one_expert():
          for i, shape in enumerate([(e, d, f), (e, d, f), (e, f, d)])]
     gates = jax.random.uniform(k[4], (s, 1))
     idx = jnp.full((s, 1), 2, jnp.int32)
-    y, sizes, dropped = moe_dispatch.dropless_swiglu_experts(x, idx, gates, *w)
+    y, sizes, dropped, _ = moe_dispatch.dropless_glu_experts(x, idx, gates, *w)
     want = gates * ((jax.nn.silu(x @ w[0][2]) * (x @ w[1][2])) @ w[2][2])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-6)
     assert sizes.tolist() == [0, 0, s, 0] and int(dropped) == 0
@@ -284,7 +284,7 @@ def test_megablox_interpreted_equals_ragged_dot(monkeypatch):
 
     def grads():
         return jax.grad(
-            lambda x, *w: jnp.sum(moe_dispatch.dropless_swiglu_experts(x, idx, gates, *w)[0] ** 2),
+            lambda x, *w: jnp.sum(moe_dispatch.dropless_glu_experts(x, idx, gates, *w)[0] ** 2),
             argnums=(0, 1, 3))(x, *w)
 
     assert moe_dispatch.grouped_matmul_impl(s * kk, d, f) == "ragged_dot"  # the CPU
@@ -414,7 +414,8 @@ def test_train_loop_records_routing_as_a_span_under_the_log_sync_and_as_gauges()
         routes[-1]["attrs"]["moe_load_max"] / routes[-1]["attrs"]["moe_load_mean"])
     assert moe["dispatch"] == {"ragged_dot": sum(moe["dispatch"].values())}
     rec = tel.registry.counter("swarm.moe_dispatch")._scrape()["values"][0]
-    assert rec["labels"] == {"impl": "ragged_dot", "E": "8", "k": "2", "rows": "128", "held": "8"}
+    assert rec["labels"] == {"impl": "ragged_dot", "E": "8", "k": "2", "rows": "128", "held": "8",
+                             "act": "swiglu"}
 
 
 def test_a_dense_model_reports_no_routing():
