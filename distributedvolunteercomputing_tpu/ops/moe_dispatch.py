@@ -25,7 +25,11 @@ many chunks as the step's routing needs: one, unless the router sends this
 share more than three times its part; then more, and still none dropped. Rows
 return to their tokens by gathers too: within a chunk the rows are put in token
 order, a token's (at most k) rows summed along their run, and each token takes
-its run's total.
+its run's total. A chunk is two grouped matmuls forward and five backward
+(``_share_rows``, ``_share_experts``): gate and up are one product against the
+two stacks side by side, and a row's gate scales ``hidden`` [rows, f] in front
+of the down product, not its result [rows, d]: passes over [rows, d] buffers
+are most of a chunk's time, and d is 3.3 to 4 times f.
 
 The grouped matmul is megablox ``gmm`` (a Pallas kernel in JAX's tree) on one
 TPU chip and ``jax.lax.ragged_dot`` elsewhere, from a measurement on the v5e at
@@ -272,7 +276,9 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
     """``[R, d] -> [S, d]``: every token's sum over its held rows, by gathers:
     rows into token order, a token's at most ``k`` rows summed along their run
     (shifted adds at distances 1, 2, 4, ..: after them a run's last row holds
-    the whole run's sum), and each token takes its run's last row."""
+    the whole run's sum), and each token takes its run's last row, zero where
+    it has no run (a select on the [S, d] result: no zero row is appended to
+    the ``[R, d]`` buffer, which would copy it)."""
     perm, tok_sorted, last_pos = where[2:]
     total = rows[perm]
     shift = 1
@@ -281,8 +287,9 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
         earlier = jnp.where(same[:, None], total[:-shift], jnp.zeros((), total.dtype))
         total = total + jnp.pad(earlier, ((shift, 0), (0, 0)))
         shift *= 2
-    total = jnp.concatenate([total, jnp.zeros((1, rows.shape[1]), rows.dtype)], axis=0)
-    return total[last_pos]
+    r = total.shape[0]
+    last = total[jnp.minimum(last_pos, r - 1)]
+    return jnp.where((last_pos < r)[:, None], last, jnp.zeros((), total.dtype))
 
 
 # The two are each other's transpose: neither differentiates into a scatter-add.
@@ -302,12 +309,19 @@ def _handed_sizes(group_sizes: jax.Array, lo, rows: int) -> jax.Array:
     return sizes.at[-1].add(rows - jnp.sum(sizes))
 
 
-def _share_rows(x, gates, w_gate, w_up, w_down, plan, lo, rows: int, k: int, act: str):
+def _share_rows(x, gates, w_gate_up, w_down, plan, lo, rows: int, k: int, act: str):
     """The held experts' part of the result ``[S, d]`` from sorted assignments
     ``lo .. lo + rows`` (``rows`` static) of ``plan`` = (order: ``token * k +
     choice``, keys: their held expert, ``held`` for those that are not this
     share's, group_sizes [held]); ``gates`` [S k] the weight of every
-    assignment. Also, as a tuple of counts, how many held assignments the
+    assignment; ``w_gate_up`` [held, d, 2 f] the gate and the up stacks side by
+    side. Two grouped matmuls: gate and up in one (the rows are read once, and
+    its transpose returns ONE ``[rows, d]`` cotangent), then down. A row's gate
+    scales ``hidden`` ``[rows, f]`` in front of the down product (the product
+    is linear and the weight one scalar a row: the same number as scaling its
+    ``[rows, d]`` result, at f / d of the pass), so the weight's cotangent is
+    ``sum(hidden * d hidden)`` over f and the derivative never needs the down
+    product's result. Also, as a tuple of counts, how many held assignments the
     grouped matmuls were handed in their own expert's group (read off the
     sizes, as ``rows_not_computed`` does) and, for a ReLU gate only, how many
     entries of the held rows' gate it set to zero."""
@@ -315,27 +329,30 @@ def _share_rows(x, gates, w_gate, w_up, w_down, plan, lo, rows: int, k: int, act
     order = jax.lax.dynamic_slice(order, (lo,), (rows,))
     keys = jax.lax.dynamic_slice(keys, (lo,), (rows,))
     sizes = _handed_sizes(group_sizes, lo, rows)
-    valid = keys < w_gate.shape[0]
+    f = w_down.shape[1]
+    valid = keys < w_down.shape[0]
     tok = order // k
     where = (tok, valid, *_token_runs(tok, valid, x.shape[0]))
-    taken = _spread(x, where, k)
-    gate = grouped_matmul(taken, w_gate, sizes)
-    up = grouped_matmul(taken, w_up, sizes)
-    hidden, zeros = _gated(gate, up, valid, act)
-    out = grouped_matmul(hidden, w_down, sizes)
-    y = _combine(out * gates[order][:, None].astype(out.dtype), where, k)
+    gate_up = grouped_matmul(_spread(x, where, k), w_gate_up, sizes)
+    hidden, zeros = _gated(gate_up[:, :f], gate_up[:, f:], valid, act)
+    hidden = hidden * gates[order][:, None].astype(hidden.dtype)
+    y = _combine(grouped_matmul(hidden, w_down, sizes), where, k)
     row = jnp.arange(rows, dtype=jnp.int32)
     computed_by = jnp.sum((row[:, None] >= jnp.cumsum(sizes)[None, :]).astype(jnp.int32), axis=1)
     computed = jnp.sum((valid & (computed_by == keys)).astype(jnp.int32))
     return y, ((computed,) if zeros is None else (computed, zeros))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _share_experts(x, gates, w_gate, w_up, w_down, plan, n_chunks, k, cap, act):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _share_experts(x, gates, w_gate_up, w_down, plan, n_chunks, k, cap, act):
     """``_share_rows`` summed over the first ``n_chunks`` (traced) chunks of
     ``cap`` sorted assignments: (y [S, d], its counts summed). The
     loop's length is data, so the derivative is written out: the backward loops
-    over the same chunks and sums each chunk's own. MEASURED (PR 33, compiled
+    over the same chunks and sums each chunk's own, which is FIVE grouped
+    matmuls a chunk: gate-up again (``hidden`` is not kept from the forward
+    loop), the cotangent of ``hidden`` (by the down stack), the down stack's,
+    the rows' and the gate-up stack's; the down product itself is dead code
+    there, since nothing reads its result. MEASURED (PR 33, compiled
     for a described v5e at laguna-solo-8k's sizes): this step's program takes
     5.5 GB of temporaries; ``lax.cond`` between the bound and all S k rows at
     once 15.6 GB (12.2 with that branch rematerialised) beside 5.9 GB of
@@ -344,16 +361,16 @@ def _share_experts(x, gates, w_gate, w_up, w_down, plan, n_chunks, k, cap, act):
 
     def body(state):
         c, y, counts = state
-        part, ns = _share_rows(x, gates, w_gate, w_up, w_down, plan, c * cap, cap, k, act)
+        part, ns = _share_rows(x, gates, w_gate_up, w_down, plan, c * cap, cap, k, act)
         return c + 1, y + part, tuple(a + n for a, n in zip(counts, ns))
 
     start = (jnp.int32(0), jnp.zeros_like(x), (jnp.int32(0),) * (2 if act == "relu" else 1))
     return jax.lax.while_loop(lambda st: st[0] < n_chunks, body, start)[1:]
 
 
-def _share_fwd(x, gates, w_gate, w_up, w_down, plan, n_chunks, k, cap, act):
-    out = _share_experts(x, gates, w_gate, w_up, w_down, plan, n_chunks, k, cap, act)
-    return out, (x, gates, w_gate, w_up, w_down, plan, n_chunks)
+def _share_fwd(x, gates, w_gate_up, w_down, plan, n_chunks, k, cap, act):
+    out = _share_experts(x, gates, w_gate_up, w_down, plan, n_chunks, k, cap, act)
+    return out, (x, gates, w_gate_up, w_down, plan, n_chunks)
 
 
 def _share_bwd(k, cap, act, res, cot):
@@ -452,8 +469,11 @@ def share_glu_experts(
     # same eight experts, none of them held), and a step's cost should not
     # follow it. A settled router never does.
     n_chunks = jnp.maximum((plan.n_held + cap - 1) // cap, 1)
+    # gate and up side by side, once a layer; their gradients come back apart
+    # through the concatenate, so the parameter tree keeps the published two
+    w_gate_up = jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], axis=2)
     y, counts = _share_experts(
-        x, top_gates.reshape(-1).astype(jnp.float32), w_gate.astype(dtype), w_up.astype(dtype),
-        w_down.astype(dtype), tuple(plan[:3]), n_chunks, k, cap, act)
+        x, top_gates.reshape(-1).astype(jnp.float32), w_gate_up, w_down.astype(dtype),
+        tuple(plan[:3]), n_chunks, k, cap, act)
     return (y, plan.group_sizes, plan.n_held - counts[0], n_chunks * cap,
             counts[1] if act == "relu" else None)

@@ -193,6 +193,24 @@ def _kernel_names(calls):
     return [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
 
 
+def _share_chunks_hold_seven_grouped_matmuls(names, text: str, layers: int, rows: int, d: int, f: int) -> None:
+    """Of the compiled step's kernel ``names`` and ``text``: each of the
+    ``layers`` traced expert layers runs two grouped matmuls in its forward
+    chunk loop (gate-up, down) and five in its backward one (gate-up again,
+    the cotangent of ``hidden``, the rows', and the two by the weights' side);
+    the recomputed forward of a rematerialised layer runs none. Twelve a layer
+    before PR 36. Gate and up are one product ``2 f`` wide, and no buffer of
+    ``rows + 1`` rows exists (a token takes its run's last row from the
+    ``[rows, d]`` buffer itself)."""
+    from benchmark import moe_trace
+
+    gmm = [n.split(".")[0] for n in names if moe_trace.GMM_RE.search(n)]
+    assert len(gmm) == 7 * layers, gmm
+    assert gmm.count("gmm") == 2 * layers and gmm.count("jvp_jit_gmm__") == layers, gmm
+    assert gmm.count("transpose_jvp_jit_gmm___") == gmm.count("transpose_jvp_jit_tgmm___") == 2 * layers, gmm
+    assert f"bf16[{rows},{2 * f}]" in text and f"[{rows + 1},{d}]" not in text
+
+
 def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip, monkeypatch):
     """laguna-solo-8k's step (five layers of Laguna-XS.2 at its published
     widths, sixteen of 256 experts held, an eighth of the vocabulary,
@@ -200,9 +218,9 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     key/value heads (layers 0 and 4) and the windowed one at 64 (layers 1-3),
     each forward and backward (the recomputed forward holds no kernel), under
     the names a device trace tells them by; the expert layers' grouped matmuls over the bounded
-    chunk of rows, never the S x k = 262,144. That it compiles says the step
-    fits the chip beside its state."""
-    from benchmark import moe_trace
+    chunk of rows, never the S x k = 262,144, seven a layer. That it compiles
+    says the step fits the chip beside its state; its temporaries are no more
+    than before PR 36."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -219,13 +237,14 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     assert all("bf16[4,48,8192,128]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
     assert all("bf16[4,64,8192,128]" in ln for ln in calls if "dvc_flash_win_" in ln)
     assert all("bf16[4,8,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
-    assert any(moe_trace.GMM_RE.search(n) for n in names)
     rows = moe_dispatch.share_rows_bound(4 * 8192, 8, 16, 256)
     assert rows == 49152  # three times the even share of 16,384: one chunk a layer on the chip
     assert f"[{rows},2048]" in text and "[262144,2048]" not in text
+    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=4, rows=rows, d=2048, f=512)
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert total < 15.75e9, total
+    assert mem.temp_size_in_bytes <= 8.113e9, mem.temp_size_in_bytes  # the parent of PR 36: 8.1125e9; 8.1079e9 with it
 
 
 def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_on_the_chip, monkeypatch):
@@ -239,7 +258,8 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
     windowed and ONE full kernel, each forward and backward, whatever the
     depth. The share's grouped matmuls see the bounded chunk of 104,448 rows
     (the model's own slack, 4.25 times the even share: models/smallthinker.py),
-    never the S x k = 196,608; arguments and temporaries stay under 15.0e9."""
+    never the S x k = 196,608, seven a traced layer; arguments and temporaries
+    stay under 15.0e9 and the temporaries are no more than before PR 36."""
     from distributedvolunteercomputing_tpu.models import smallthinker
     from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, pallas_attention
 
@@ -269,9 +289,12 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
     rows = moe_dispatch.share_rows_bound(2 * t, 6, 8, 64, smallthinker.SHARE_ROWS_SLACK)
     assert rows == 104448  # 3.19 S: three held experts that each take every token fit one chunk
     assert f"[{rows},2560]" in text and "[196608,2560]" not in text and "[73728,2560]" not in text
+    # one trace a layer kind: the scan's body holds each kind's loops once
+    _share_chunks_hold_seven_grouped_matmuls(_kernel_names(calls), text, layers=2, rows=rows, d=2560, f=768)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (
         mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    assert mem.temp_size_in_bytes <= 9.522e9, mem.temp_size_in_bytes  # the parent of PR 36: 9.5213e9; 9.3057e9 with it
 
 
 @pytest.mark.parametrize("model,batch,layers,shape", [
