@@ -48,8 +48,10 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import json
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
@@ -152,6 +154,41 @@ def reset_current_trace(token: contextvars.Token) -> None:
 _CURRENT_SPAN: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "dvc_span", default=None
 )
+
+
+# Trace id of the start-up tree (``Volunteer()`` to the first finished step):
+# one root span ``lifecycle`` a process start, its phases under it.
+LIFECYCLE = "lifecycle"
+
+# The enabled tracers alive in this process, for ``lifecycle_spans``.
+_LIVE_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+
+
+def lifecycle_spans() -> List[dict]:
+    """The finished ``lifecycle``-trace spans of every live tracer of this
+    process, oldest first: start-up as the program timed it, for a reader
+    that holds no volunteer (as ``utils.jaxenv.compile_log`` offers what was
+    compiled). Nothing once the tracers are gone."""
+    spans = [s for tracer in list(_LIVE_TRACERS) for s in tracer.spans(trace=LIFECYCLE)]
+    return sorted(spans, key=lambda s: s["t0"])
+
+
+def lifecycle_summary(spans: List[dict]) -> Dict[str, Any]:
+    """What an operator reads of a start: the root's duration as ``ready_s``,
+    whether anything missed the compile cache (``cold``), and the seconds of
+    each phase directly under the root (and of ``lifecycle.process``, which
+    came before it) under its name less the ``lifecycle.`` prefix. Empty
+    until the root has ended."""
+    root = next((s for s in spans if s["name"] == LIFECYCLE and s.get("dur_s") is not None), None)
+    if root is None:
+        return {}
+    out: Dict[str, Any] = {
+        "ready_s": round(root["dur_s"], 3), "cold": bool((root.get("attrs") or {}).get("cold")),
+    }
+    for s in spans:
+        if s is not root and s.get("parent") in (None, LIFECYCLE) and s.get("dur_s") is not None:
+            out[s["name"].removeprefix(LIFECYCLE + ".")] = round(s["dur_s"], 3)
+    return out
 
 
 def annotation(name: str):
@@ -476,6 +513,8 @@ class Tracer:
         # Finished-span hook (the watchdog's per-level round-wall feed):
         # called with each ended span's dict, exceptions swallowed.
         self.on_record: Optional[Callable[[dict], None]] = None
+        if enabled:
+            _LIVE_TRACERS.add(self)
 
     def start(self, name: str, trace: Optional[str] = None, **attrs: Any) -> Optional[Span]:
         if not self.enabled:
@@ -508,10 +547,12 @@ class Tracer:
         self._finish(span)
 
     def record(
-        self, name: str, trace: str, t0: float, dur_s: float, **attrs: Any
+        self, name: str, trace: str, t0: float, dur_s: float,
+        parent: Optional[str] = None, **attrs: Any
     ) -> None:
         """Append an already-measured span retroactively — for phases
-        (like ``join``) that finish before their round's trace id exists."""
+        (like ``join``) that finish before their round's trace id exists,
+        or (the entry script's, under ``parent``) before any tracer does."""
         if not self.enabled or not trace:
             return
         sp: Dict[str, Any] = {
@@ -521,6 +562,8 @@ class Tracer:
             "t0": round(t0, 6),
             "dur_s": round(dur_s, 6),
         }
+        if parent:
+            sp["parent"] = parent
         if attrs:
             sp["attrs"] = attrs
         with self._lock:
@@ -560,6 +603,24 @@ class Tracer:
             yield None
             return
         with annotation(name), self.span(name, trace, **attrs) as sp:
+            yield sp
+
+    @contextlib.contextmanager
+    def child(
+        self, parent: Optional[Span], name: str, sync: bool = False, **attrs: Any
+    ) -> Iterator[Optional[Span]]:
+        """A span (``sync``: a phase, with its annotation) in ``parent``'s
+        trace that names ``parent`` by hand: for a parent opened with
+        ``start`` that is never the ambient span, because it outlives every
+        ``with`` block and ends on another thread (the ``lifecycle`` root).
+        The spans opened inside this one find it through the context as
+        usual. None (tracing off): nothing is opened."""
+        if parent is None:
+            yield None
+            return
+        with (self.phase if sync else self.span)(name, parent.trace, **attrs) as sp:
+            if sp is not None:
+                sp.parent = parent.name
             yield sp
 
     def annotate(self, name: str):
@@ -778,6 +839,9 @@ class Telemetry:
         # Ended spans feed the watchdog's per-level wall detectors and, from
         # a sparse-expert model's ``moe.route`` spans, the routing gauges.
         self.tracer.on_record = self._observe_span
+        # ``lifecycle_summary`` of this start, taken as the root ends (the
+        # span ring turns over in a long run); {} until then.
+        self.lifecycle: Dict[str, Any] = {}
 
     def set_clock(self, clock: Callable[[], float]) -> None:
         """Adopt the ClockSync-corrected clock once the volunteer builds
@@ -853,6 +917,15 @@ class Telemetry:
     def _observe_span(self, sp: dict) -> None:
         if self.watchdog.enabled:
             self.watchdog.observe_span(sp)
+        if sp.get("name") == LIFECYCLE and sp.get("trace") == LIFECYCLE:
+            spans = self.tracer.spans(trace=LIFECYCLE)
+            self.lifecycle = lifecycle_summary(spans)
+            # Once a start, for whoever reads the log: every phase with its
+            # offset from the root's start, its seconds and its attributes.
+            log.info("lifecycle tree: %s", json.dumps([
+                [s["name"], round(s["t0"] - sp["t0"], 3), s["dur_s"], s.get("attrs", {})]
+                for s in sorted(spans, key=lambda s: s["t0"])
+            ]))
         if sp.get("name") == "moe.route":
             attrs = sp.get("attrs") or {}
             mean = float(attrs.get("moe_load_mean") or 0.0)
@@ -1002,6 +1075,9 @@ class Telemetry:
         # what stops the chip on the volunteer's side of a round: the train
         # thread's merge and snapshot, the codec's wait in the device queue
         "loop.merge", "loop.snapshot", "codec.run",
+        # how long this volunteer took to be useful: Volunteer() to its first
+        # finished step, and of that the first call of the step function
+        "lifecycle", "lifecycle.step_build",
     )
 
     def summary(self) -> dict:

@@ -21,7 +21,7 @@ import signal
 import threading
 import time
 import uuid
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 
@@ -33,6 +33,7 @@ from distributedvolunteercomputing_tpu.swarm.state_sync import StateSyncService
 from distributedvolunteercomputing_tpu.swarm.transport import Transport, read_secret
 from distributedvolunteercomputing_tpu.training.trainer import Trainer
 from distributedvolunteercomputing_tpu.utils.logging import errstr, get_logger
+from distributedvolunteercomputing_tpu.utils.pytree import tree_size_bytes
 
 log = get_logger(__name__)
 
@@ -491,19 +492,42 @@ def _parse_addrs(spec: Optional[str]) -> list:
 
 
 class Volunteer:
-    def __init__(self, cfg: VolunteerConfig):
+    def __init__(
+        self, cfg: VolunteerConfig,
+        process_phases: Sequence[Tuple[str, float, float]] = (),
+    ):
+        """``process_phases``: what the entry script timed before any
+        telemetry existed, as ``(name, wall time begun, wall time ended)``
+        (run_volunteer.py: ``imports``, ``backend``, ``native``)."""
         self.cfg = cfg
         # Telemetry plane: one bundle per volunteer process, shared by the
         # averager, membership, resilience policy, and mesh codec. Built
         # first so every later subsystem can register into it; adopts the
         # ClockSync-corrected clock once one exists (start()).
-        from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+        from distributedvolunteercomputing_tpu.swarm.telemetry import LIFECYCLE, Telemetry
 
         self.telemetry = Telemetry(
             peer_id=cfg.peer_id, enabled=cfg.telemetry,
             health_enabled=cfg.telemetry and cfg.health_probe,
             watchdog_enabled=cfg.telemetry and cfg.watchdog,
         )
+        # The start-up tree (docs/OBSERVABILITY.md, "Span vocabulary
+        # (start-up)"): its root runs from here until the first train step's
+        # outputs are ready, where the trainer's waiter ends it. Never the
+        # ambient span: its phases name it by hand (``Tracer.child``). None
+        # with telemetry off.
+        tracer = self.telemetry.tracer
+        self._lifecycle = tracer.start(
+            LIFECYCLE, LIFECYCLE, model=cfg.model, averaging=cfg.averaging
+        )
+        if process_phases:
+            process = f"{LIFECYCLE}.process"
+            began = min(t0 for _, t0, _ in process_phases)
+            tracer.record(
+                process, LIFECYCLE, began, max(t1 for _, _, t1 in process_phases) - began
+            )
+            for name, t0, t1 in process_phases:
+                tracer.record(f"{process}.{name}", LIFECYCLE, t0, t1 - t0, parent=process)
         # The attention core reports each traced call (which core took it):
         # the summary's "attention_core" says how often the fused one engaged.
         # So does the fused qkv projection (divided by head over tp, or not),
@@ -607,252 +631,265 @@ class Volunteer:
 
         # DVC_ASYNC_DEBUG=1: loop stall/race detectors (stopped at teardown)
         self._loop_monitor = maybe_enable_from_env()
-        await self.transport.start()
-        # Debug/collection surface: telemetry.scrape / telemetry.trace /
-        # telemetry.flight / telemetry.prom answer on this volunteer's
-        # transport (operators and experiments/trace_report.py dial them
-        # directly).
-        self.telemetry.register_rpcs(self.transport)
-        if self.cfg.metrics_port:
-            # Local Prometheus endpoint: any stock scraper can watch this
-            # volunteer without the coordinator (or the swarm transport).
-            from distributedvolunteercomputing_tpu.swarm.telemetry import (
-                MetricsHTTPServer,
+        tracer = self.telemetry.tracer
+        with tracer.child(self._lifecycle, "lifecycle.net") as net:
+            await self.transport.start()
+            # Debug/collection surface: telemetry.scrape / telemetry.trace /
+            # telemetry.flight / telemetry.prom answer on this volunteer's
+            # transport (operators and experiments/trace_report.py dial them
+            # directly).
+            self.telemetry.register_rpcs(self.transport)
+            if self.cfg.metrics_port:
+                # Local Prometheus endpoint: any stock scraper can watch this
+                # volunteer without the coordinator (or the swarm transport).
+                from distributedvolunteercomputing_tpu.swarm.telemetry import (
+                    MetricsHTTPServer,
+                )
+
+                # Loopback ONLY: the swarm transport binds cfg.host (often
+                # 0.0.0.0) with MAC-covered frames, but this endpoint is
+                # plain unauthenticated HTTP serving the full registry — the
+                # documented contract is a LOCAL scrape shim, so it must not
+                # ride the volunteer's public bind address.
+                self._metrics_server = MetricsHTTPServer(
+                    self.telemetry, "127.0.0.1", self.cfg.metrics_port
+                )
+                await self._metrics_server.start()
+            bootstrap = _parse_addrs(self.cfg.coordinator) or None
+            await self.dht.start(bootstrap=bootstrap)
+            from distributedvolunteercomputing_tpu.swarm.control_plane import (
+                ControlPlaneClient,
+                ControlPlaneReplica,
             )
 
-            # Loopback ONLY: the swarm transport binds cfg.host (often
-            # 0.0.0.0) with MAC-covered frames, but this endpoint is
-            # plain unauthenticated HTTP serving the full registry — the
-            # documented contract is a LOCAL scrape shim, so it must not
-            # ride the volunteer's public bind address.
-            self._metrics_server = MetricsHTTPServer(
-                self.telemetry, "127.0.0.1", self.cfg.metrics_port
+            # Control-plane failover client: discovers the elected replica set
+            # from DHT soft state and routes this volunteer's batched
+            # heartbeat/report traffic to its key-range shard owner, failing
+            # over on conn failure (fast-fail + bounded AIMD backoff). Always
+            # constructed — it costs nothing until a replica answers, and the
+            # direct DHT path remains the fallback every beat.
+            self.control_plane = ControlPlaneClient(
+                self.transport, self.dht, self.cfg.peer_id
             )
-            await self._metrics_server.start()
-        bootstrap = _parse_addrs(self.cfg.coordinator) or None
-        await self.dht.start(bootstrap=bootstrap)
-        from distributedvolunteercomputing_tpu.swarm.control_plane import (
-            ControlPlaneClient,
-            ControlPlaneReplica,
-        )
-
-        # Control-plane failover client: discovers the elected replica set
-        # from DHT soft state and routes this volunteer's batched
-        # heartbeat/report traffic to its key-range shard owner, failing
-        # over on conn failure (fast-fail + bounded AIMD backoff). Always
-        # constructed — it costs nothing until a replica answers, and the
-        # direct DHT path remains the fallback every beat.
-        self.control_plane = ControlPlaneClient(
-            self.transport, self.dht, self.cfg.peer_id
-        )
-        if self.cfg.host_replica:
-            # This volunteer is an election candidate for the replicated
-            # control plane: it serves status/exchange traffic and owns a
-            # key range when elected into the active set.
-            self.replica = ControlPlaneReplica(
-                self.transport, self.dht, telemetry=self.telemetry
-            )
-            await self.replica.start()
-        self._build_resilience_layer()
-        extra_info = {
-            "model": self.cfg.model,
-            # Full averaging namespace (model/average_what): gossip picks
-            # partners from membership records (no rendezvous key), so the
-            # record must carry the same string the averagers namespace
-            # their rounds by — a params-mode peer must never gossip with
-            # a grads-mode peer on the same model.
-            "avg_ns": f"{self.cfg.model}/{self.cfg.average_what}",
-        }
-        if self.cfg.zone:
-            # Locality advertisement for the hierarchical schedule; absent
-            # on unzoned volunteers so mixed-version swarms degrade to
-            # flat scheduling instead of treating "" as a real zone name.
-            extra_info["zone"] = self.cfg.zone
-        self.membership = SwarmMembership(
-            self.dht, self.cfg.peer_id, ttl=self.cfg.heartbeat_ttl,
-            failure_detector=self.failure_detector,
-            extra_info=extra_info,
-            # Measured up/down bandwidth rides every heartbeat (refreshed
-            # from the transport's bulk-transfer throughput EWMAs; stale
-            # estimates age out to absent fields): the input to
-            # bandwidth-weighted leader election.
-            bandwidth_source=self.transport.bandwidth_advertisement,
-            # Batched control plane: announce + metrics report + peers
-            # snapshot coalesce into one cp.exchange per heartbeat interval
-            # while any replica is reachable (direct DHT fallback per beat).
-            control_plane=self.control_plane,
-            report_source=self._build_report,
-            telemetry=self.telemetry,
-        )
-        await self.membership.join()
-        if self.cfg.average_interval_s > 0:
-            # Wall-cadence rendezvous no longer assumes NTP: peer-to-peer
-            # clock-offset estimation corrects this volunteer's boundary
-            # clock onto swarm-consensus time (swarm/clocksync.py).
-            # DVC_CLOCK_SKEW_S injects artificial skew so the e2e suite can
-            # prove rendezvous under multi-second skew.
-            from distributedvolunteercomputing_tpu.swarm.clocksync import ClockSync
-
-            skew = float(os.environ.get("DVC_CLOCK_SKEW_S", "0") or "0")
-            clock = (lambda: time.time() + skew) if skew else time.time
-            self.clocksync = ClockSync(self.transport, self.membership, clock=clock)
-            # First estimate immediately: the first boundary this volunteer
-            # arms must already be on swarm time.
-            await self.clocksync.estimate()
-            self.clocksync.start(interval_s=max(self.cfg.heartbeat_ttl, 15.0))
-            # Span timestamps align to swarm-consensus time: cross-volunteer
-            # traces stitch even when volunteer clocks are skewed.
-            self.telemetry.set_clock(self.clocksync.now)
-        if self.cfg.averaging != "none":
-            kw = dict(
-                min_group=self.cfg.min_group,
-                max_group=self.cfg.max_group,
-                join_timeout=self.cfg.join_timeout,
-                gather_timeout=self.cfg.gather_timeout,
-                wire=self.cfg.wire,
-                topk_frac=self.cfg.topk_frac,
-                topk_warmup_rounds=self.cfg.topk_warmup_rounds,
-                powersgd_rank=self.cfg.powersgd_rank,
-                adaptive_timeout=self.cfg.adaptive_timeout,
-                # Deadline-bounded rounds: leaders stamp clock()+budget into
-                # the begin on the consensus clock when one exists (wall-
-                # cadence swarms), else local wall time — the same clock the
-                # whole group's members compare the deadline against.
-                clock=self.clocksync.now if self.clocksync is not None else None,
-                round_deadline_s=self.cfg.round_deadline_s or None,
-                resilience=self.resilience_policy,
+            if self.cfg.host_replica:
+                # This volunteer is an election candidate for the replicated
+                # control plane: it serves status/exchange traffic and owns a
+                # key range when elected into the active set.
+                self.replica = ControlPlaneReplica(
+                    self.transport, self.dht, telemetry=self.telemetry
+                )
+                await self.replica.start()
+            self._build_resilience_layer()
+            extra_info = {
+                "model": self.cfg.model,
+                # Full averaging namespace (model/average_what): gossip picks
+                # partners from membership records (no rendezvous key), so the
+                # record must carry the same string the averagers namespace
+                # their rounds by — a params-mode peer must never gossip with
+                # a grads-mode peer on the same model.
+                "avg_ns": f"{self.cfg.model}/{self.cfg.average_what}",
+            }
+            if self.cfg.zone:
+                # Locality advertisement for the hierarchical schedule; absent
+                # on unzoned volunteers so mixed-version swarms degrade to
+                # flat scheduling instead of treating "" as a real zone name.
+                extra_info["zone"] = self.cfg.zone
+            self.membership = SwarmMembership(
+                self.dht, self.cfg.peer_id, ttl=self.cfg.heartbeat_ttl,
                 failure_detector=self.failure_detector,
-                # Closed-loop controller (None under --no-adapt / without
-                # --resilience): the averager is both its evidence feed
-                # and its actuator.
-                controller=self.controller,
-                # Matchmaking rendezvous reads ride the replicated control
-                # plane's micro-cache when a replica answers (direct DHT
-                # fallback otherwise).
+                extra_info=extra_info,
+                # Measured up/down bandwidth rides every heartbeat (refreshed
+                # from the transport's bulk-transfer throughput EWMAs; stale
+                # estimates age out to absent fields): the input to
+                # bandwidth-weighted leader election.
+                bandwidth_source=self.transport.bandwidth_advertisement,
+                # Batched control plane: announce + metrics report + peers
+                # snapshot coalesce into one cp.exchange per heartbeat interval
+                # while any replica is reachable (direct DHT fallback per beat).
                 control_plane=self.control_plane,
-                # Shared telemetry bundle: round spans, the unified metrics
-                # registry, and the flight recorder all live here.
+                report_source=self._build_report,
                 telemetry=self.telemetry,
-                # Tail-optimal hedged recovery (docs/PERFORMANCE.md):
-                # soft-deadline re-requests for predicted-late tile ranges
-                # when this node leads a streaming round, plus the optional
-                # last-k% summand redundancy ring.
-                hedge=self.cfg.hedge,
-                tail_redundancy_frac=self.cfg.tail_redundancy_frac,
             )
-            if self.cfg.group_size:
-                from distributedvolunteercomputing_tpu.swarm.matchmaking import (
-                    GroupSchedule,
+            await self.membership.join()
+            if self.cfg.average_interval_s > 0:
+                # Wall-cadence rendezvous no longer assumes NTP: peer-to-peer
+                # clock-offset estimation corrects this volunteer's boundary
+                # clock onto swarm-consensus time (swarm/clocksync.py).
+                # DVC_CLOCK_SKEW_S injects artificial skew so the e2e suite can
+                # prove rendezvous under multi-second skew.
+                from distributedvolunteercomputing_tpu.swarm.clocksync import ClockSync
+
+                skew = float(os.environ.get("DVC_CLOCK_SKEW_S", "0") or "0")
+                clock = (lambda: time.time() + skew) if skew else time.time
+                self.clocksync = ClockSync(self.transport, self.membership, clock=clock)
+                # First estimate immediately: the first boundary this volunteer
+                # arms must already be on swarm time.
+                await self.clocksync.estimate()
+                self.clocksync.start(interval_s=max(self.cfg.heartbeat_ttl, 15.0))
+                # Span timestamps align to swarm-consensus time: cross-volunteer
+                # traces stitch even when volunteer clocks are skewed.
+                self.telemetry.set_clock(self.clocksync.now)
+            if net is not None:
+                # Who was there at the join (the view a join exchange left, or
+                # one DHT read). Advisory: a failed read must not fail a start.
+                try:
+                    peers = await self.membership.alive_peers(
+                        include_self=False, max_age=self.cfg.heartbeat_ttl
+                    )
+                    net.attrs["peers"] = len(peers)
+                except Exception as e:  # noqa: BLE001
+                    log.debug("peers at join not read: %s", errstr(e))
+        with tracer.child(self._lifecycle, "lifecycle.model"):
+            if self.cfg.averaging != "none":
+                kw = dict(
+                    min_group=self.cfg.min_group,
+                    max_group=self.cfg.max_group,
+                    join_timeout=self.cfg.join_timeout,
+                    gather_timeout=self.cfg.gather_timeout,
+                    wire=self.cfg.wire,
+                    topk_frac=self.cfg.topk_frac,
+                    topk_warmup_rounds=self.cfg.topk_warmup_rounds,
+                    powersgd_rank=self.cfg.powersgd_rank,
+                    adaptive_timeout=self.cfg.adaptive_timeout,
+                    # Deadline-bounded rounds: leaders stamp clock()+budget into
+                    # the begin on the consensus clock when one exists (wall-
+                    # cadence swarms), else local wall time — the same clock the
+                    # whole group's members compare the deadline against.
+                    clock=self.clocksync.now if self.clocksync is not None else None,
+                    round_deadline_s=self.cfg.round_deadline_s or None,
+                    resilience=self.resilience_policy,
+                    failure_detector=self.failure_detector,
+                    # Closed-loop controller (None under --no-adapt / without
+                    # --resilience): the averager is both its evidence feed
+                    # and its actuator.
+                    controller=self.controller,
+                    # Matchmaking rendezvous reads ride the replicated control
+                    # plane's micro-cache when a replica answers (direct DHT
+                    # fallback otherwise).
+                    control_plane=self.control_plane,
+                    # Shared telemetry bundle: round spans, the unified metrics
+                    # registry, and the flight recorder all live here.
+                    telemetry=self.telemetry,
+                    # Tail-optimal hedged recovery (docs/PERFORMANCE.md):
+                    # soft-deadline re-requests for predicted-late tile ranges
+                    # when this node leads a streaming round, plus the optional
+                    # last-k% summand redundancy ring.
+                    hedge=self.cfg.hedge,
+                    tail_redundancy_frac=self.cfg.tail_redundancy_frac,
+                )
+                if self.cfg.group_size:
+                    from distributedvolunteercomputing_tpu.swarm.matchmaking import (
+                        GroupSchedule,
+                    )
+
+                    # Rotation rides the consensus clock when one exists: every
+                    # member of a prospective group must land in the same
+                    # window or they rendezvous under different keys.
+                    kw["group_schedule"] = GroupSchedule(
+                        target_size=self.cfg.group_size,
+                        rotation_s=self.cfg.group_rotation_s
+                        or (self.cfg.average_interval_s or 15.0),
+                        clock=self.clocksync.now
+                        if self.clocksync is not None
+                        else time.time,
+                        min_size=self.cfg.min_group,
+                        cross_zone_every_k=self.cfg.cross_zone_every_k,
+                    )
+                if self.cfg.averaging == "byzantine" and (
+                    self.cfg.method != "mean" or self.cfg.wire == "topk"
+                ):
+                    # Passing "mean" explicitly matters for topk: without it the
+                    # ByzantineAverager defaults to trimmed_mean, which the topk
+                    # wire (validated in __post_init__) must not run under.
+                    kw["method"] = self.cfg.method
+                if self.cfg.method_kw:
+                    kw["method_kw"] = dict(self.cfg.method_kw)
+                # Namespace rounds by model AND by what is averaged: a grads-mode
+                # peer must never rendezvous with a params-mode peer on the same
+                # model — averaging a gradient tree against a parameter tree
+                # would silently destroy both.
+                kw["namespace"] = f"{self.cfg.model}/{self.cfg.average_what}"
+                self.averager = make_averager(
+                    self.cfg.averaging, self.transport, self.dht, self.membership, **kw
+                )
+            bundle = get_model(self.cfg.model, **self.cfg.model_overrides)
+            on_step = None
+            if self.cfg.checkpoint_dir and self.cfg.checkpoint_every > 0:
+                from distributedvolunteercomputing_tpu.training.checkpoint import save_async
+
+                ckpt_dir, every = self.cfg.checkpoint_dir, self.cfg.checkpoint_every
+
+                def on_step(trainer, step_no):
+                    # Periodic snapshot: a kill -9 between saves loses at most
+                    # checkpoint_every steps, not the whole run. Async: the D2H
+                    # copy happens here, the file write on a background thread —
+                    # the device never idles on disk I/O.
+                    if step_no % every == 0:
+                        save_async(trainer, ckpt_dir)
+
+            # Heterogeneity injection (test/experiment hook, like
+            # DVC_CHAOS_CONTRIB_SCALE below): DVC_STEP_DELAY_MS=<x> slows THIS
+            # volunteer's step rate by x ms/step — on a shared localhost core,
+            # batch-size spreads don't produce real step-rate skew (per-step
+            # overhead dominates), so heterogeneous-cadence experiments need an
+            # explicit clock. Unset in production.
+            delay_ms = float(os.environ.get("DVC_STEP_DELAY_MS", "0") or 0.0)
+            if delay_ms > 0:
+                prev_on_step = on_step
+
+                def on_step(trainer, step_no, _prev=prev_on_step):  # noqa: F811
+                    time.sleep(delay_ms / 1e3)
+                    if _prev is not None:
+                        _prev(trainer, step_no)
+
+            data = None
+            eval_data = None
+            if self.cfg.data_path:
+                import zlib
+
+                from distributedvolunteercomputing_tpu.training.data import npz_batch_iter
+
+                # Seeded per-peer so volunteers shard the shuffle order, not the
+                # data: every volunteer sees the full file in a different order.
+                # crc32, not hash(): PYTHONHASHSEED randomization would make the
+                # per-peer order non-reproducible across restarts.
+                data_seed = zlib.crc32(self.cfg.peer_id.encode()) & 0x7FFFFFFF
+                data = npz_batch_iter(self.cfg.data_path, self.cfg.batch_size, seed=data_seed)
+                if self.cfg.eval_every:
+                    # Independent shuffled stream over the same file: eval draws
+                    # never perturb the training order (matches the synthetic
+                    # path's separate-rng held-out semantics).
+                    eval_data = npz_batch_iter(
+                        self.cfg.data_path, self.cfg.batch_size, seed=data_seed ^ 0x5EED
+                    )
+            mesh = None
+            if self.cfg.mesh:
+                from distributedvolunteercomputing_tpu.parallel.mesh import (
+                    make_mesh,
+                    parse_mesh_spec,
                 )
 
-                # Rotation rides the consensus clock when one exists: every
-                # member of a prospective group must land in the same
-                # window or they rendezvous under different keys.
-                kw["group_schedule"] = GroupSchedule(
-                    target_size=self.cfg.group_size,
-                    rotation_s=self.cfg.group_rotation_s
-                    or (self.cfg.average_interval_s or 15.0),
-                    clock=self.clocksync.now
-                    if self.clocksync is not None
-                    else time.time,
-                    min_size=self.cfg.min_group,
-                    cross_zone_every_k=self.cfg.cross_zone_every_k,
-                )
-            if self.cfg.averaging == "byzantine" and (
-                self.cfg.method != "mean" or self.cfg.wire == "topk"
-            ):
-                # Passing "mean" explicitly matters for topk: without it the
-                # ByzantineAverager defaults to trimmed_mean, which the topk
-                # wire (validated in __post_init__) must not run under.
-                kw["method"] = self.cfg.method
-            if self.cfg.method_kw:
-                kw["method_kw"] = dict(self.cfg.method_kw)
-            # Namespace rounds by model AND by what is averaged: a grads-mode
-            # peer must never rendezvous with a params-mode peer on the same
-            # model — averaging a gradient tree against a parameter tree
-            # would silently destroy both.
-            kw["namespace"] = f"{self.cfg.model}/{self.cfg.average_what}"
-            self.averager = make_averager(
-                self.cfg.averaging, self.transport, self.dht, self.membership, **kw
+                mesh = make_mesh(**parse_mesh_spec(self.cfg.mesh))
+            # Build THIS volunteer's swarm data path (ops.mesh_codec: the bf16
+            # wire codec, PowerSGD matmuls and the leader's tile folds on the
+            # local device mesh where that is TPU silicon, host numpy elsewhere)
+            # now that the local mesh exists. The averager resolves the process
+            # default lazily, so configuring here covers the averager built
+            # earlier. Surfaced in stats()["mesh_codec"]; degrades to host on
+            # slice failure.
+            from distributedvolunteercomputing_tpu.ops import mesh_codec as mesh_codec_mod
+
+            codec = mesh_codec_mod.configure(mesh=mesh)
+            # Slice-loss degrades land in this volunteer's flight recorder, the
+            # codec's device ops in its span ring.
+            codec.recorder = self.telemetry.recorder
+            codec.tracer = self.telemetry.tracer
+            chosen = codec.stats()
+            log.info(
+                "swarm data path: %s backend, pallas %s, collective %s (mesh=%s)",
+                codec.backend, chosen["pallas"], chosen["collective"],
+                self.cfg.mesh or "single-device",
             )
-        bundle = get_model(self.cfg.model, **self.cfg.model_overrides)
-        on_step = None
-        if self.cfg.checkpoint_dir and self.cfg.checkpoint_every > 0:
-            from distributedvolunteercomputing_tpu.training.checkpoint import save_async
-
-            ckpt_dir, every = self.cfg.checkpoint_dir, self.cfg.checkpoint_every
-
-            def on_step(trainer, step_no):
-                # Periodic snapshot: a kill -9 between saves loses at most
-                # checkpoint_every steps, not the whole run. Async: the D2H
-                # copy happens here, the file write on a background thread —
-                # the device never idles on disk I/O.
-                if step_no % every == 0:
-                    save_async(trainer, ckpt_dir)
-
-        # Heterogeneity injection (test/experiment hook, like
-        # DVC_CHAOS_CONTRIB_SCALE below): DVC_STEP_DELAY_MS=<x> slows THIS
-        # volunteer's step rate by x ms/step — on a shared localhost core,
-        # batch-size spreads don't produce real step-rate skew (per-step
-        # overhead dominates), so heterogeneous-cadence experiments need an
-        # explicit clock. Unset in production.
-        delay_ms = float(os.environ.get("DVC_STEP_DELAY_MS", "0") or 0.0)
-        if delay_ms > 0:
-            prev_on_step = on_step
-
-            def on_step(trainer, step_no, _prev=prev_on_step):  # noqa: F811
-                time.sleep(delay_ms / 1e3)
-                if _prev is not None:
-                    _prev(trainer, step_no)
-
-        data = None
-        eval_data = None
-        if self.cfg.data_path:
-            import zlib
-
-            from distributedvolunteercomputing_tpu.training.data import npz_batch_iter
-
-            # Seeded per-peer so volunteers shard the shuffle order, not the
-            # data: every volunteer sees the full file in a different order.
-            # crc32, not hash(): PYTHONHASHSEED randomization would make the
-            # per-peer order non-reproducible across restarts.
-            data_seed = zlib.crc32(self.cfg.peer_id.encode()) & 0x7FFFFFFF
-            data = npz_batch_iter(self.cfg.data_path, self.cfg.batch_size, seed=data_seed)
-            if self.cfg.eval_every:
-                # Independent shuffled stream over the same file: eval draws
-                # never perturb the training order (matches the synthetic
-                # path's separate-rng held-out semantics).
-                eval_data = npz_batch_iter(
-                    self.cfg.data_path, self.cfg.batch_size, seed=data_seed ^ 0x5EED
-                )
-        mesh = None
-        if self.cfg.mesh:
-            from distributedvolunteercomputing_tpu.parallel.mesh import (
-                make_mesh,
-                parse_mesh_spec,
-            )
-
-            mesh = make_mesh(**parse_mesh_spec(self.cfg.mesh))
-        # Build THIS volunteer's swarm data path (ops.mesh_codec: the bf16
-        # wire codec, PowerSGD matmuls and the leader's tile folds on the
-        # local device mesh where that is TPU silicon, host numpy elsewhere)
-        # now that the local mesh exists. The averager resolves the process
-        # default lazily, so configuring here covers the averager built
-        # earlier. Surfaced in stats()["mesh_codec"]; degrades to host on
-        # slice failure.
-        from distributedvolunteercomputing_tpu.ops import mesh_codec as mesh_codec_mod
-
-        codec = mesh_codec_mod.configure(mesh=mesh)
-        # Slice-loss degrades land in this volunteer's flight recorder, the
-        # codec's device ops in its span ring.
-        codec.recorder = self.telemetry.recorder
-        codec.tracer = self.telemetry.tracer
-        chosen = codec.stats()
-        log.info(
-            "swarm data path: %s backend, pallas %s, collective %s (mesh=%s)",
-            codec.backend, chosen["pallas"], chosen["collective"],
-            self.cfg.mesh or "single-device",
-        )
         self.trainer = Trainer(
             bundle,
             data=data,
@@ -897,7 +934,8 @@ class Volunteer:
             outer_optimizer=self.cfg.outer_optimizer,
             outer_lr=self.cfg.outer_lr,
             outer_momentum=self.cfg.outer_momentum,
-            tracer=self.telemetry.tracer,
+            tracer=tracer,
+            lifecycle=self._lifecycle,
         )
         if self.averager is not None:
             # Checkpoint sidecars persist the averager's compressor state
@@ -907,7 +945,14 @@ class Volunteer:
         if self.cfg.checkpoint_dir:
             from distributedvolunteercomputing_tpu.training.checkpoint import maybe_restore
 
-            maybe_restore(self.trainer, self.cfg.checkpoint_dir)
+            with tracer.child(self._lifecycle, "lifecycle.restore") as sp:
+                restored = maybe_restore(self.trainer, self.cfg.checkpoint_dir)
+                if sp is not None:
+                    sp.attrs.update(
+                        restored=restored, step=int(self.trainer.state.step),
+                        # of the host snapshot the restore has just published
+                        bytes=tree_size_bytes(self.trainer.host_snapshot()[1]) if restored else 0,
+                    )
         if self.cfg.averaging != "none":
             # Peer-pull state sync: catch up to the swarm BEFORE the first
             # step, so a (re)joining volunteer's first averaging round
@@ -954,15 +999,21 @@ class Volunteer:
                 return step, tree
 
             self.state_sync.set_provider(provider)
-            pulled = await self.state_sync.pull(
-                bundle.avg_select(self.trainer.state.params),
-                int(self.trainer.state.step),
-            )
-            if pulled is not None:
-                step, subtree = pulled
-                self.trainer.adopt_params(
-                    bundle.avg_merge(self.trainer.state.params, subtree), step=step
+            with tracer.child(self._lifecycle, "lifecycle.state_sync") as sp:
+                pulled = await self.state_sync.pull(
+                    bundle.avg_select(self.trainer.state.params),
+                    int(self.trainer.state.step),
                 )
+                if pulled is not None:
+                    step, subtree = pulled
+                    self.trainer.adopt_params(
+                        bundle.avg_merge(self.trainer.state.params, subtree), step=step
+                    )
+                if sp is not None:
+                    sp.attrs.update(
+                        adopted=pulled is not None, step=int(self.trainer.state.step),
+                        bytes=tree_size_bytes(pulled[1]) if pulled is not None else 0,
+                    )
             await self.state_sync.announce()
             if self.cfg.averaging == "gossip" and self.cfg.average_what == "params":
                 # Publish the post-state-sync params so exchanges from
@@ -1273,6 +1324,10 @@ class Volunteer:
             # and the device allocator's high-water mark where reported.
             self.summary["device"] = self.trainer.device
             self.summary["compile"] = self.trainer.compile_summary()
+            # How long this start took to be useful (``ready_s``: Volunteer()
+            # to the first finished step) and where it went, phase by phase;
+            # {} with telemetry off.
+            self.summary["lifecycle"] = self.telemetry.lifecycle
             # Traced attention calls by core ({"flash": n} or {"xla": n};
             # empty with telemetry off).
             self.summary["attention_core"] = self.telemetry.attention_cores()
@@ -1367,7 +1422,9 @@ class Volunteer:
         signal.signal(signal.SIGINT, _on_signal)
 
 
-def run_volunteer(cfg: VolunteerConfig) -> Dict[str, float]:
-    vol = Volunteer(cfg)
+def run_volunteer(
+    cfg: VolunteerConfig, process_phases: Sequence[Tuple[str, float, float]] = ()
+) -> Dict[str, float]:
+    vol = Volunteer(cfg, process_phases)
     vol.install_signal_handlers()
     return asyncio.run(vol.run())

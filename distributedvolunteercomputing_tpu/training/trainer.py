@@ -32,6 +32,7 @@ import collections
 import concurrent.futures
 import contextlib
 import os
+import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
@@ -68,6 +69,10 @@ COPIES_IN_FLIGHT = 2
 ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
                 "moe_rows_moved", "moe_act_zero_share", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
+
+# Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
+# ``LIFECYCLE``, which this module does not import.
+LIFECYCLE = "lifecycle"
 
 
 def _tree_bytes(tree: Any) -> int:
@@ -203,6 +208,9 @@ class Trainer:
         # sync) become spans and profiler annotations. None: every site is a
         # no-op.
         tracer: Optional[Any] = None,
+        # The start-up tree's root span, where the tracer's owner has opened
+        # one (``tracer.start("lifecycle", "lifecycle")``); see ``_lifecycle``.
+        lifecycle: Optional[Any] = None,
     ):
         if eval_every and eval_batches < 1:
             raise ValueError(f"eval_batches must be >= 1, got {eval_batches}")
@@ -228,231 +236,259 @@ class Trainer:
             raise ValueError(
                 f"accum_steps={accum_steps} must be >=1 and divide batch_size={batch_size}"
             )
-        # Persistent XLA compilation cache: volunteers churn (rejoin =
-        # re-trace + re-compile); the cache turns every rejoin after the
-        # first into a disk hit.
-        from distributedvolunteercomputing_tpu.utils.jaxenv import (
-            compile_log,
-            device_record,
-            enable_compile_cache,
-        )
-
-        self.compile_cache_dir = enable_compile_cache()
-        self._compile_log = compile_log()  # listening before the step compiles
-        self.device = device_record()
-        self.bundle = bundle
-        self.batch_size = batch_size
-        self.accum_steps = accum_steps
-        self.average_every = average_every
-        self.average_interval_s = float(average_interval_s)
-        self._wall_clock = wall_clock or time.time
-        # Next wall-clock boundary (multiple of the interval) a round is due
-        # at; None until run() arms it.
-        self._next_avg_t: Optional[float] = None
-        # Steps of local progress behind the NEXT params-mode contribution —
-        # read by the volunteer's averager callback to weight it in samples.
-        # Under the step cadence this is average_every except after failed
-        # rounds (progress accumulates); under the interval cadence it is
-        # whatever this volunteer managed in the window, which is exactly
-        # what makes heterogeneous contributions weigh correctly.
-        self.steps_since_merge: int = average_every
-        self._last_merge_step: Optional[int] = None
         self.tracer = tracer
+        # The start-up tree's root span (trace ``lifecycle``): opened by
+        # whoever built the tracer (the volunteer, in its constructor, so that
+        # the join and the model's construction are under it) or, for a
+        # trainer handed a tracer alone, here. It outlives this constructor:
+        # the first call of a step function (``_call``) takes it, and a waiter
+        # ends it when that step's outputs are ready. None: tracing is off.
+        if tracer is not None and lifecycle is None:
+            lifecycle = tracer.start(LIFECYCLE, LIFECYCLE, model=bundle.name)
+        self._lifecycle = lifecycle if tracer is not None else None
+        # ``lifecycle.first_batch``: from ``run``'s entry to that first call.
+        self._first_batch: Optional[Any] = None
         # Trace id of the phases opened now: "loop" for those that belong to
         # no round, a round's key (or the tracer's PENDING) inside
-        # _round_phase.
-        self._phase_trace = "loop"
-        # Written by the averager callback before it returns: the trace id
-        # (round key) of the round it just ran, None when no group formed.
-        # Read after the call, or after the future that carried it resolved.
-        self.round_trace: Optional[str] = None
-        self.averager = averager
-        self.average_what = average_what
-        # ``seed`` is PER-VOLUNTEER: it drives the data order and the step
-        # rng, so volunteers see different batches. ``init_seed`` is
-        # TASK-CONSTANT: every volunteer training the same task must build
-        # the same initial params — for LoRA models this is load-bearing
-        # (the frozen base is NEVER averaged, so adapters averaged across
-        # volunteers are deltas against one shared base; with per-volunteer
-        # bases the average would be semantically meaningless), and for full
-        # models it makes round 1 start contracted instead of spending early
-        # rounds averaging away init noise.
-        rng = jax.random.PRNGKey(seed)
-        _, data_rng, state_rng = jax.random.split(rng, 3)
-        self.tx = make_optimizer(optimizer, lr=lr, total_steps=total_steps)
-        params = bundle.init(jax.random.PRNGKey(init_seed))
-        self.param_dtype = param_dtype
-        if param_dtype:
-            # bf16 training (params + optimizer moments + every matmul in
-            # the dtype): halves param/optimizer HBM and runs the MXU at
-            # native rate. Floating leaves only — integer tables and the
-            # step counter keep their dtypes. The swarm tier is
-            # dtype-agnostic by construction (flatten_to_buffer ships f32
-            # and restores per-leaf dtypes), and init stays bit-identical
-            # across volunteers BEFORE the cast, so the task-constant
-            # init_seed contract above still holds.
-            from distributedvolunteercomputing_tpu.utils.pytree import cast_floating
-
-            params = cast_floating(params, param_dtype)
-        self.state = TrainState.create(params, self.tx, state_rng)
-        # Gradient-averaging mode splits the step so grads can cross the WAN
-        # between bwd and the optimizer (reference GradientAverager
-        # semantics); the fused donate-everything step covers the rest.
-        self._grads_mode = averager is not None and average_what == "grads"
-        self.overlap = bool(overlap) and averager is not None and not self._grads_mode
-        self.max_staleness = max_staleness
-        # One worker: rounds never overlap each other, only local compute.
-        self._avg_pool = (
-            concurrent.futures.ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="avg-round"
-            )
-            if self.overlap
-            else None
-        )
-        # (launch_step, launched, future): ``launched`` is the device-side
-        # copy of the payload, kept for the flight as the merge's third term.
-        self._inflight: Optional[tuple] = None
-        self._routing_pending: Optional[tuple] = None  # (step, its routing scalars still on the device)
-        # The in-flight launch's spans, which wait for their round's key.
-        self._launch_spans: tuple = ()
-        if mesh is None and (fsdp or seq_sharded):
-            raise ValueError("fsdp/seq_sharded require a mesh (--mesh dp=...,tp=...)")
-        if outer_optimizer not in ("none", "nesterov"):
-            raise ValueError(f"unknown outer_optimizer {outer_optimizer!r}")
-        if outer_optimizer != "none" and averager is not None and average_what != "params":
-            # The outer step operates on PARAMETER deltas between rounds;
-            # grads mode has no per-round parameter anchor to difference
-            # against (each step's gradients are averaged individually).
-            raise ValueError("outer_optimizer requires average_what='params'")
-        self.outer_optimizer = outer_optimizer
-        self.outer_lr = float(outer_lr)
-        self.outer_momentum = float(outer_momentum)
-        # Host-side outer state: the anchor is the global params the current
-        # inner phase STARTED from (payload/avg_select space); the momentum
-        # tree accumulates per-round aggregate deltas.
-        self._outer_anchor: Any = None
-        self._outer_m: Any = None
-        if fsdp and average_what == "grads":
-            # The split grad/apply steps have no in-step constraint keeping
-            # params at 1/dp, so ZeRO-3 would silently re-replicate — and
-            # per-step host grad averaging defeats its purpose anyway.
-            # Independent of whether an averager is attached NOW: the config
-            # asked for grads-mode semantics, and accepting it only when the
-            # wiring happens to be absent would make the same flag set pass
-            # or fail on an unrelated condition.
-            raise ValueError("fsdp is a params-mode feature; use average_what='params'")
-        self.mesh = mesh
-        self.fsdp = fsdp
-        self._param_shardings = None
-        self._put_batch: Optional[Callable[[Batch], Batch]] = None
-        if mesh is not None:
-            from distributedvolunteercomputing_tpu.parallel.train_step import (
-                put_batch,
-                shard_train_state,
+        # _round_phase, the start-up tree's for this constructor's.
+        self._phase_trace = "loop" if self._lifecycle is None else LIFECYCLE
+        with self._lifecycle_child("lifecycle.init", sync=True):
+            # Persistent XLA compilation cache: volunteers churn (rejoin =
+            # re-trace + re-compile); the cache turns every rejoin after the
+            # first into a disk hit.
+            from distributedvolunteercomputing_tpu.utils.jaxenv import (
+                compile_log,
+                device_record,
+                enable_compile_cache,
             )
 
-            self.state, self._param_shardings = shard_train_state(
-                self.state, mesh, self.tx, fsdp=fsdp
-            )
-            self._put_batch = lambda b: put_batch(b, mesh, seq_sharded=seq_sharded)
-        if self._grads_mode:
-            # The split steps are plain jits: with mesh-sharded inputs GSPMD
-            # partitions them like the fused sharded step for replicated-dp
-            # layouts (tp/pp rules propagate from the input shardings). The
-            # fsdp layout needs the fused step's in-step constraints and is
-            # rejected above.
-            self._grad_fn = make_grad_step(bundle.loss_fn, accum_steps=accum_steps)
-            self._apply_fn = make_apply_step(self.tx)
-            self._step_fn = None
-        elif mesh is not None:
-            from distributedvolunteercomputing_tpu.parallel.train_step import (
-                make_sharded_train_step,
-            )
+            self.compile_cache_dir = enable_compile_cache()
+            self._compile_log = compile_log()  # listening before the step compiles
+            self.device = device_record()
+            self.bundle = bundle
+            self.batch_size = batch_size
+            self.accum_steps = accum_steps
+            self.average_every = average_every
+            self.average_interval_s = float(average_interval_s)
+            self._wall_clock = wall_clock or time.time
+            # Next wall-clock boundary (multiple of the interval) a round is due
+            # at; None until run() arms it.
+            self._next_avg_t: Optional[float] = None
+            # Steps of local progress behind the NEXT params-mode contribution —
+            # read by the volunteer's averager callback to weight it in samples.
+            # Under the step cadence this is average_every except after failed
+            # rounds (progress accumulates); under the interval cadence it is
+            # whatever this volunteer managed in the window, which is exactly
+            # what makes heterogeneous contributions weigh correctly.
+            self.steps_since_merge: int = average_every
+            self._last_merge_step: Optional[int] = None
+            # Written by the averager callback before it returns: the trace id
+            # (round key) of the round it just ran, None when no group formed.
+            # Read after the call, or after the future that carried it resolved.
+            self.round_trace: Optional[str] = None
+            self.averager = averager
+            self.average_what = average_what
+            # ``seed`` is PER-VOLUNTEER: it drives the data order and the step
+            # rng, so volunteers see different batches. ``init_seed`` is
+            # TASK-CONSTANT: every volunteer training the same task must build
+            # the same initial params — for LoRA models this is load-bearing
+            # (the frozen base is NEVER averaged, so adapters averaged across
+            # volunteers are deltas against one shared base; with per-volunteer
+            # bases the average would be semantically meaningless), and for full
+            # models it makes round 1 start contracted instead of spending early
+            # rounds averaging away init noise.
+            rng = jax.random.PRNGKey(seed)
+            _, data_rng, state_rng = jax.random.split(rng, 3)
+            self.tx = make_optimizer(optimizer, lr=lr, total_steps=total_steps)
+            self.param_dtype = param_dtype
+            with self._phase("lifecycle.init.params") as init_params:
+                began = time.time()
+                params = bundle.init(jax.random.PRNGKey(init_seed))
+                if param_dtype:
+                    # bf16 training (params + optimizer moments + every matmul
+                    # in the dtype): halves param/optimizer HBM and runs the
+                    # MXU at native rate. Floating leaves only — integer
+                    # tables and the step counter keep their dtypes. The swarm
+                    # tier is dtype-agnostic by construction
+                    # (flatten_to_buffer ships f32 and restores per-leaf
+                    # dtypes), and init stays bit-identical across volunteers
+                    # BEFORE the cast, so the task-constant init_seed contract
+                    # above still holds.
+                    from distributedvolunteercomputing_tpu.utils.pytree import cast_floating
 
-            self._step_fn = make_sharded_train_step(
-                bundle.loss_fn, self.tx, mesh, accum_steps=accum_steps,
-                seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl,
+                    params = cast_floating(params, param_dtype)
+                self.state = TrainState.create(params, self.tx, state_rng)
+                if init_params is not None:
+                    # What this thread compiled on the way (a model's init is
+                    # tens of one-op programs unless it is jitted whole). The
+                    # arrays are dispatched, not ready: the device's part of
+                    # the init shows in the constructor's snapshot below.
+                    built = self._compile_log.summary(since=began, thread=threading.get_ident())
+                    init_params.attrs.update(
+                        programs=built["programs"], backend_s=built["seconds"],
+                        cache_hits=built["cache_hits"], cache_misses=built["cache_misses"],
+                        bytes=_tree_bytes(self.state.params),
+                    )
+            # Gradient-averaging mode splits the step so grads can cross the WAN
+            # between bwd and the optimizer (reference GradientAverager
+            # semantics); the fused donate-everything step covers the rest.
+            self._grads_mode = averager is not None and average_what == "grads"
+            self.overlap = bool(overlap) and averager is not None and not self._grads_mode
+            self.max_staleness = max_staleness
+            # One worker: rounds never overlap each other, only local compute.
+            self._avg_pool = (
+                concurrent.futures.ThreadPoolExecutor(
+                    max_workers=1, thread_name_prefix="avg-round"
+                )
+                if self.overlap
+                else None
             )
-        else:
-            self._step_fn = make_train_step(
-                bundle.loss_fn, self.tx, accum_steps=accum_steps
-            )
-        self.steps_per_call = int(steps_per_call)
-        self.chunk_cadences = tuple(int(c) for c in chunk_cadences if c)
-        # EMA of seconds per step, measured at chunk granularity — only
-        # maintained (and only needed) under the wall-clock averaging
-        # cadence, where chunk sizing must anticipate the next boundary.
-        self._ema_step_s: Optional[float] = None
-        self._multi_fn = None
-        if self.steps_per_call > 1 and self._step_fn is not None:
+            # (launch_step, launched, future): ``launched`` is the device-side
+            # copy of the payload, kept for the flight as the merge's third term.
+            self._inflight: Optional[tuple] = None
+            self._routing_pending: Optional[tuple] = None  # (step, its routing scalars still on the device)
+            # The in-flight launch's spans, which wait for their round's key.
+            self._launch_spans: tuple = ()
+            if mesh is None and (fsdp or seq_sharded):
+                raise ValueError("fsdp/seq_sharded require a mesh (--mesh dp=...,tp=...)")
+            if outer_optimizer not in ("none", "nesterov"):
+                raise ValueError(f"unknown outer_optimizer {outer_optimizer!r}")
+            if outer_optimizer != "none" and averager is not None and average_what != "params":
+                # The outer step operates on PARAMETER deltas between rounds;
+                # grads mode has no per-round parameter anchor to difference
+                # against (each step's gradients are averaged individually).
+                raise ValueError("outer_optimizer requires average_what='params'")
+            self.outer_optimizer = outer_optimizer
+            self.outer_lr = float(outer_lr)
+            self.outer_momentum = float(outer_momentum)
+            # Host-side outer state: the anchor is the global params the current
+            # inner phase STARTED from (payload/avg_select space); the momentum
+            # tree accumulates per-round aggregate deltas.
+            self._outer_anchor: Any = None
+            self._outer_m: Any = None
+            if fsdp and average_what == "grads":
+                # The split grad/apply steps have no in-step constraint keeping
+                # params at 1/dp, so ZeRO-3 would silently re-replicate — and
+                # per-step host grad averaging defeats its purpose anyway.
+                # Independent of whether an averager is attached NOW: the config
+                # asked for grads-mode semantics, and accepting it only when the
+                # wiring happens to be absent would make the same flag set pass
+                # or fail on an unrelated condition.
+                raise ValueError("fsdp is a params-mode feature; use average_what='params'")
+            self.mesh = mesh
+            self.fsdp = fsdp
+            self._param_shardings = None
+            self._put_batch: Optional[Callable[[Batch], Batch]] = None
             if mesh is not None:
-                # The mesh twin scans the SAME sharded body (incl. the
-                # ZeRO in-step re-constraints) — r4 VERDICT missing #5.
                 from distributedvolunteercomputing_tpu.parallel.train_step import (
-                    make_sharded_multi_step,
+                    put_batch,
+                    shard_train_state,
                 )
 
-                self._multi_fn = make_sharded_multi_step(
+                with self._phase("lifecycle.init.shard"):
+                    self.state, self._param_shardings = shard_train_state(
+                        self.state, mesh, self.tx, fsdp=fsdp
+                    )
+                self._put_batch = lambda b: put_batch(b, mesh, seq_sharded=seq_sharded)
+            if self._grads_mode:
+                # The split steps are plain jits: with mesh-sharded inputs GSPMD
+                # partitions them like the fused sharded step for replicated-dp
+                # layouts (tp/pp rules propagate from the input shardings). The
+                # fsdp layout needs the fused step's in-step constraints and is
+                # rejected above.
+                self._grad_fn = make_grad_step(bundle.loss_fn, accum_steps=accum_steps)
+                self._apply_fn = make_apply_step(self.tx)
+                self._step_fn = None
+            elif mesh is not None:
+                from distributedvolunteercomputing_tpu.parallel.train_step import (
+                    make_sharded_train_step,
+                )
+
+                self._step_fn = make_sharded_train_step(
                     bundle.loss_fn, self.tx, mesh, accum_steps=accum_steps,
                     seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl,
                 )
             else:
-                from distributedvolunteercomputing_tpu.training.steps import make_multi_step
-
-                self._multi_fn = make_multi_step(
+                self._step_fn = make_train_step(
                     bundle.loss_fn, self.tx, accum_steps=accum_steps
                 )
-        self._data_rng = data_rng
-        self._data = data
-        self.eval_every = eval_every
-        self.eval_batches = eval_batches
-        self._eval_fn = None
-        self._it: Optional[Any] = None
-        self._eval_data = eval_data
-        self._eval_it: Optional[Any] = None
-        # Held-out stream: a distinct fold of the volunteer seed, so eval
-        # batches never collide with any training batch at any seed.
-        self._eval_rng = jax.random.fold_in(data_rng, 0x5EED)
-        self.metrics = MetricsWriter(metrics_path, volunteer_id)
-        # Header: every later record in this stream names what it ran on.
-        self.metrics.record_event(
-            0, "header",
-            {**self.device, "compile_cache_dir": self.compile_cache_dir},
-        )
-        self.on_step = on_step
-        # Host-side (step, params) snapshot for concurrent readers (the
-        # state-sync provider serves fetches from the asyncio thread while
-        # the train step DONATES the live state's buffers — reading
-        # self.state.params cross-thread would hit deleted arrays). Replaced
-        # whole, by the landing thread or (``wait=True``) by the caller;
-        # tuple assignment keeps readers consistent.
-        self._snapshot: Any = None
-        # Device-side copies of the parameters (_enqueue_copy): one program
-        # each, and one worker that waits for their host copies in order.
-        self._copy_fn = jax.jit(_param_copy, out_shardings=self._param_shardings)
-        self._land_pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="snapshot-land"
-        )
-        self._landing: "collections.deque[concurrent.futures.Future]" = collections.deque()
-        # (step, mutation_counter) of the newest copy: a boundary whose
-        # launch has just copied the parameters snapshots nothing again.
-        self._copied: Optional[tuple] = None
-        self._merge_fn = (
-            jax.jit(
-                _make_round_merge(bundle.avg_select, bundle.avg_merge),
-                donate_argnums=(0, 2),
-                out_shardings=(self._param_shardings, self._param_shardings),
+            self.steps_per_call = int(steps_per_call)
+            self.chunk_cadences = tuple(int(c) for c in chunk_cadences if c)
+            # EMA of seconds per step, measured at chunk granularity — only
+            # maintained (and only needed) under the wall-clock averaging
+            # cadence, where chunk sizing must anticipate the next boundary.
+            self._ema_step_s: Optional[float] = None
+            self._multi_fn = None
+            if self.steps_per_call > 1 and self._step_fn is not None:
+                if mesh is not None:
+                    # The mesh twin scans the SAME sharded body (incl. the
+                    # ZeRO in-step re-constraints) — r4 VERDICT missing #5.
+                    from distributedvolunteercomputing_tpu.parallel.train_step import (
+                        make_sharded_multi_step,
+                    )
+
+                    self._multi_fn = make_sharded_multi_step(
+                        bundle.loss_fn, self.tx, mesh, accum_steps=accum_steps,
+                        seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl,
+                    )
+                else:
+                    from distributedvolunteercomputing_tpu.training.steps import make_multi_step
+
+                    self._multi_fn = make_multi_step(
+                        bundle.loss_fn, self.tx, accum_steps=accum_steps
+                    )
+            self._data_rng = data_rng
+            self._data = data
+            self.eval_every = eval_every
+            self.eval_batches = eval_batches
+            self._eval_fn = None
+            self._it: Optional[Any] = None
+            self._eval_data = eval_data
+            self._eval_it: Optional[Any] = None
+            # Held-out stream: a distinct fold of the volunteer seed, so eval
+            # batches never collide with any training batch at any seed.
+            self._eval_rng = jax.random.fold_in(data_rng, 0x5EED)
+            self.metrics = MetricsWriter(metrics_path, volunteer_id)
+            # Header: every later record in this stream names what it ran on.
+            self.metrics.record_event(
+                0, "header",
+                {**self.device, "compile_cache_dir": self.compile_cache_dir},
             )
-            if self.overlap
-            else None
-        )
-        # Bumped on every out-of-band params mutation (averaging merge,
-        # peer-pull adoption). Lets the checkpoint layer tell whether state
-        # at the SAME step number still matches its last snapshot — the step
-        # counter alone can't (the end-of-run overlap drain merges without
-        # advancing it).
-        self.mutation_counter = 0
-        self._take_snapshot(0, wait=True)
+            self.on_step = on_step
+            # Host-side (step, params) snapshot for concurrent readers (the
+            # state-sync provider serves fetches from the asyncio thread while
+            # the train step DONATES the live state's buffers — reading
+            # self.state.params cross-thread would hit deleted arrays). Replaced
+            # whole, by the landing thread or (``wait=True``) by the caller;
+            # tuple assignment keeps readers consistent.
+            self._snapshot: Any = None
+            # Device-side copies of the parameters (_enqueue_copy): one program
+            # each, and one worker that waits for their host copies in order.
+            self._copy_fn = jax.jit(_param_copy, out_shardings=self._param_shardings)
+            self._land_pool = concurrent.futures.ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="snapshot-land"
+            )
+            self._landing: "collections.deque[concurrent.futures.Future]" = collections.deque()
+            # (step, mutation_counter) of the newest copy: a boundary whose
+            # launch has just copied the parameters snapshots nothing again.
+            self._copied: Optional[tuple] = None
+            self._merge_fn = (
+                jax.jit(
+                    _make_round_merge(bundle.avg_select, bundle.avg_merge),
+                    donate_argnums=(0, 2),
+                    out_shardings=(self._param_shardings, self._param_shardings),
+                )
+                if self.overlap
+                else None
+            )
+            # Bumped on every out-of-band params mutation (averaging merge,
+            # peer-pull adoption). Lets the checkpoint layer tell whether state
+            # at the SAME step number still matches its last snapshot — the step
+            # counter alone can't (the end-of-run overlap drain merges without
+            # advancing it).
+            self.mutation_counter = 0
+            self._take_snapshot(0, wait=True)
+        self._phase_trace = "loop"
 
     def compile_summary(self) -> dict:
         """What this process compiled so far (``CompileLog.summary``) with
@@ -492,6 +528,60 @@ class Trainer:
         if self.tracer is None:
             return contextlib.nullcontext()
         return self.tracer.phase(name, self._phase_trace, **attrs)
+
+    def _lifecycle_child(self, name: str, sync: bool = False, **attrs: Any):
+        """A phase directly under the start-up tree's root."""
+        if self._lifecycle is None:
+            return contextlib.nullcontext()
+        return self.tracer.child(self._lifecycle, name, sync=sync, **attrs)
+
+    def _call(self, fn: Callable, step_no: int, *args: Any) -> Any:
+        """``fn(*args)`` for a step function (``_step_fn``, ``_grad_fn``,
+        ``_multi_fn``). The first such call of this trainer is the phase
+        ``lifecycle.step_build``: Python trace, lowering, backend compile or
+        cache load, until the call returns with the step dispatched. From
+        there ``lifecycle.first_step`` runs until the step's metrics (every
+        step function's second output, which no later call donates) are
+        ready; it and the root are ended by a waiter thread, as
+        ``loop.snapshot.land`` is by the landing thread, so this thread goes
+        on dispatching as far ahead of the chip as it did."""
+        root = self._lifecycle
+        if root is None:
+            return fn(*args)
+        self._lifecycle = None
+        if self._first_batch is not None:
+            self._first_batch.end()
+        began = time.time()
+        with self.tracer.child(
+            root, "lifecycle.step_build", sync=True, program=f"jit({fn.__name__})"
+        ) as sp:
+            out = fn(*args)
+            if sp is not None:
+                built = self._compile_log.summary(since=began, thread=threading.get_ident())
+                sp.attrs.update(
+                    trace_s=built["trace_seconds"], lower_s=built["lower_seconds"],
+                    backend_s=built["seconds"], cache_load_s=built["cache_load_seconds"],
+                    cache="miss" if built["cache_misses"] else "hit" if built["cache_hits"] else "off",
+                )
+        first = self.tracer.start("lifecycle.first_step", root.trace, step=step_no)
+        if first is not None:
+            first.parent = root.name  # the root is never the ambient span
+        metrics = out[1]  # all the waiter holds of the step's outputs
+
+        def wait() -> None:
+            try:
+                with self._mark("lifecycle.first_step"):
+                    jax.block_until_ready(metrics)
+            finally:
+                if first is not None:
+                    first.end()
+                root.end(
+                    chips=1 if self.mesh is None else int(self.mesh.devices.size),
+                    cold=self._compile_log.summary()["cache_misses"] > 0,
+                )
+
+        threading.Thread(target=wait, name="lifecycle-first-step", daemon=True).start()
+        return out
 
     def _note_routing(self, step_no: int, m: Dict[str, Any], at_log_point: bool) -> None:
         """Between log points, every ``ROUTE_EVERY`` steps: keep a sparse-expert
@@ -1019,6 +1109,13 @@ class Trainer:
         measured without giving up the fixed-steps throughput row."""
         if target_mode not in ("stop", "record"):
             raise ValueError(f"unknown target_mode {target_mode!r}")
+        if self._lifecycle is not None:
+            # Until the first call of a step function (``_call`` ends it): the
+            # data stream's first batches (a file's load, the synthetic
+            # stream's programs) and their placement.
+            self._first_batch = self.tracer.start("lifecycle.first_batch", LIFECYCLE)
+            if self._first_batch is not None:
+                self._first_batch.parent = self._lifecycle.name
         it = iter(self._data) if self._data is not None else iter(self.data_iter())
         self._it = it  # evaluate() draws from the same iterator for custom data
         # Tracing hook (SURVEY.md §5): DVC_PROFILE_DIR=<dir> captures a
@@ -1068,7 +1165,9 @@ class Trainer:
                     )
                     t_chunk = time.perf_counter()
                     with self._mark("dispatch"):
-                        self.state, losses = self._multi_fn(self.state, stacked)
+                        self.state, losses = self._call(
+                            self._multi_fn, start_step + ran_steps + n - 1, self.state, stacked
+                        )
                     ran_steps += n - 1
                     if self.averager is not None and self.average_interval_s > 0:
                         # One sync per chunk: the real chunk duration feeds
@@ -1128,7 +1227,7 @@ class Trainer:
                 # gradient is averaged before any optimizer sees it (skipping
                 # steps would let replica params drift with nothing ever
                 # re-contracting them — that's what params mode is for).
-                grads, m, next_rng = self._grad_fn(self.state, batch)
+                grads, m, next_rng = self._call(self._grad_fn, step_no, self.state, batch)
                 if step_no >= avg_skip_until:
                     merged = self._run_average_round(grads, step_no, "grads")
                     if merged is not None:
@@ -1140,7 +1239,7 @@ class Trainer:
                     self._take_snapshot(step_no)
             else:
                 with self._mark("dispatch"):
-                    self.state, m = self._step_fn(self.state, batch)
+                    self.state, m = self._call(self._step_fn, step_no, self.state, batch)
                 if self._inflight is not None and m_done is not None:
                     # A round is in flight: stay ONE step ahead of the chip,
                     # not a cadence. The round's codec programs and the

@@ -10,9 +10,11 @@ every result carries so it names what it ran on: the device
 
 from __future__ import annotations
 
+import collections
 import os
 import re
 import threading
+import time
 from typing import Dict, List, Optional
 
 # The in-checkout cache directory (git-ignored). The path is part of the
@@ -101,49 +103,144 @@ def device_record() -> dict:
 
 class CompileLog:
     """What jax compiled in this process, heard from ``jax.monitoring``: per
-    program name the count and the seconds spent in the backend compiler (or
-    fetching the executable from the persistent cache), and the persistent
-    cache's hits and misses."""
+    program name (``jit(step)``) the count and the seconds of each stage a
+    first call goes through, and the persistent cache's hits and misses.
+
+    The stages: ``trace`` (the Python function to a jaxpr; nested ``jit``
+    calls are part of the program that called them and are not counted
+    again), ``lower`` (jaxpr to an MLIR module), ``backend`` (the backend
+    compiler, or fetching the executable from the persistent cache) and
+    ``cache_load`` (the fetch alone: the part of ``backend`` that a cache hit
+    costs). Each event is kept with the wall time at which it ended and the
+    thread it ended on, so that ``summary`` can count what happened before or
+    after a moment, or on one thread."""
+
+    # Events kept one by one; older ones are folded into totals that every
+    # unfiltered summary (and every ``until``) still counts.
+    MAX_EVENTS = 4096
+    _TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    _BACKEND = "/jax/core/compile/backend_compile_duration"
+    _STAGES = {
+        _TRACE: "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        _BACKEND: "backend",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+        "/jax/compilation_cache/compile_time_saved_sec": "cache_saved",
+    }
+    _COUNTS = {
+        "/jax/compilation_cache/cache_hits": "hit",
+        "/jax/compilation_cache/cache_misses": "miss",
+    }
 
     def __init__(self) -> None:
         import jax
 
         self._lock = threading.Lock()
-        self._programs: Dict[str, List[float]] = {}  # name -> [count, seconds]
-        self._cache_hits = 0
-        self._cache_misses = 0
+        # (wall time, thread, stage, program, seconds), oldest first
+        self._events: "collections.deque[tuple]" = collections.deque()
+        self._folded: Dict[tuple, List[float]] = {}  # (stage, program) -> [count, seconds]
+        # Per thread: how deep in nested traces it is, and the program whose
+        # backend compile it is in (the cache's events carry no name).
+        self._here = threading.local()
+        jax.monitoring.register_scalar_listener(self._on_begin)
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
+    def _on_begin(self, event: str, value: float, **kw) -> None:
+        # jax records a scalar (the start time) as a timed stage begins.
+        if event == self._TRACE:
+            self._here.depth = getattr(self._here, "depth", 0) + 1
+        elif event == self._BACKEND:
+            self._here.program = kw.get("fun_name", "?")
+
     def _on_duration(self, event: str, duration: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            with self._lock:
-                rec = self._programs.setdefault(kw.get("fun_name", "?"), [0, 0.0])
-                rec[0] += 1
-                rec[1] += duration
+        stage = self._STAGES.get(event)
+        if stage is None:
+            return
+        if stage == "trace":
+            self._here.depth = max(getattr(self._here, "depth", 1) - 1, 0)
+            if self._here.depth:
+                return  # a jit called while another is traced: inside that one's seconds
+            name = f"jit({kw.get('fun_name', '?')})"  # as the later stages name it
+        elif stage in ("cache_load", "cache_saved"):
+            name = getattr(self._here, "program", "?")
+        else:
+            name = kw.get("fun_name", "?")
+        self._keep(stage, name, duration)
+        if stage == "backend":
+            self._here.program = "?"
 
     def _on_event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            with self._lock:
-                self._cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            with self._lock:
-                self._cache_misses += 1
+        kind = self._COUNTS.get(event)
+        if kind is not None:
+            self._keep(kind, getattr(self._here, "program", "?"), 0.0)
 
-    def summary(self, program: str) -> dict:
-        """Totals, and ``program``'s own count and seconds (``jit(step)``
-        compiled once means no recompilation after warm-up)."""
+    def _keep(self, stage: str, program: str, seconds: float) -> None:
         with self._lock:
-            count, seconds = self._programs.get(program, (0, 0.0))
-            return {
-                "programs": int(sum(c for c, _ in self._programs.values())),
-                "seconds": round(sum(t for _, t in self._programs.values()), 3),
-                "cache_hits": self._cache_hits,
-                "cache_misses": self._cache_misses,
-                "program": program,
-                "program_compiles": int(count),
-                "program_seconds": round(seconds, 3),
-            }
+            self._events.append((time.time(), threading.get_ident(), stage, program, seconds))
+            if len(self._events) > self.MAX_EVENTS:
+                _, _, old_stage, old_program, old_seconds = self._events.popleft()
+                rec = self._folded.setdefault((old_stage, old_program), [0, 0.0])
+                rec[0] += 1
+                rec[1] += old_seconds
+
+    def summary(
+        self,
+        program: str = "",
+        until: Optional[float] = None,
+        since: Optional[float] = None,
+        thread: Optional[int] = None,
+    ) -> dict:
+        """Totals, and ``program``'s own count and seconds (``jit(step)``
+        compiled once means no recompilation after warm-up). ``until`` /
+        ``since`` (wall times) and ``thread`` (an ident) count only the
+        events that ended by then, from then on, on that thread."""
+        with self._lock:
+            acc: Dict[tuple, List[float]] = (
+                {k: list(v) for k, v in self._folded.items()}
+                if since is None and thread is None else {}
+            )
+            for wall, ident, stage, name, seconds in self._events:
+                if (
+                    (until is None or wall <= until)
+                    and (since is None or wall >= since)
+                    and (thread is None or ident == thread)
+                ):
+                    rec = acc.setdefault((stage, name), [0, 0.0])
+                    rec[0] += 1
+                    rec[1] += seconds
+
+        def count(stage: str) -> int:
+            return int(sum(c for (s, _), (c, _) in acc.items() if s == stage))
+
+        def seconds(stage: str) -> float:
+            return round(sum((t for (s, _), (_, t) in acc.items() if s == stage), 0.0), 3)
+
+        # A program's seconds over all stages (the cache's are inside ``backend``).
+        whole: Dict[str, Dict[str, float]] = {}
+        for (stage, name), (_, t) in acc.items():
+            if stage in ("trace", "lower", "backend"):
+                whole.setdefault(name, {"trace": 0.0, "lower": 0.0, "backend": 0.0})[stage] += t
+        slowest = sorted(whole.items(), key=lambda kv: -sum(kv[1].values()))[:5]
+        compiles, compile_seconds = acc.get(("backend", program), (0, 0.0))
+        return {
+            "programs": count("backend"),
+            "seconds": seconds("backend"),
+            "cache_hits": count("hit"),
+            "cache_misses": count("miss"),
+            "program": program,
+            "program_compiles": int(compiles),
+            "program_seconds": round(compile_seconds, 3),
+            "trace_seconds": seconds("trace"),
+            "lower_seconds": seconds("lower"),
+            "cache_load_seconds": seconds("cache_load"),
+            "cache_saved_seconds": seconds("cache_saved"),
+            "slowest": [
+                {"program": name, "seconds": round(sum(st.values()), 3),
+                 **{f"{stage}_s": round(t, 3) for stage, t in st.items()}}
+                for name, st in slowest
+            ],
+        }
 
 
 _compile_log: Optional[CompileLog] = None

@@ -21,10 +21,19 @@ On TPU-VM preemption (SIGTERM) the volunteer checkpoints, tombstones its
 membership record, and exits cleanly.
 """
 
-import argparse
-import json
+import time
 
-from distributedvolunteercomputing_tpu.swarm.volunteer import VolunteerConfig, run_volunteer
+_T_PROCESS = time.time()  # before any other import: start-up is timed from here
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+
+from distributedvolunteercomputing_tpu.swarm.volunteer import (  # noqa: E402
+    VolunteerConfig,
+    run_volunteer,
+)
+
+_T_IMPORTED = time.time()
 
 
 def main() -> None:
@@ -380,14 +389,25 @@ def main() -> None:
         tail_redundancy_frac=args.tail_redundancy_frac,
         metrics_port=args.metrics_port,
     )
+    # What comes before any telemetry exists, for the volunteer to record
+    # under ``lifecycle.process``: the imports above, the accelerator
+    # runtime's bring-up (jax's first look at its devices) and the native core.
+    phases = [("imports", _T_PROCESS, _T_IMPORTED)]
+    import jax
+
+    began = time.time()
+    jax.devices()
+    phases.append(("backend", began, time.time()))
     if cfg.averaging != "none":
         # Build/load the native host core BEFORE the event loop exists: the
         # lazy path builds on a background thread, but a volunteer should
         # start its first round with the library already warm.
         from distributedvolunteercomputing_tpu import native
 
+        began = time.time()
         native.ensure_built()
-    summary = run_volunteer(cfg)
+        phases.append(("native", began, time.time()))
+    summary = run_volunteer(cfg, phases)
     print("VOLUNTEER_DONE " + json.dumps(summary), flush=True)
 
 

@@ -176,7 +176,11 @@ def pytest_collection_modifyitems(config, items):
 # in turn (and to six of Laguna's lists), so that file's manifest test and the
 # new configuration's case of the first are marked too, and
 # tests/yardstick/test_yardstick_smallthinker.py asserts what they asserted,
-# one place up. Strict and AssertionError only: each case fails loudly once it
+# one place up. PR 37 (tracing) appended seven lifecycle.* metrics to per_layer
+# and no cell: test_yardstick_smallthinker.py's manifest test, which asserts
+# that SmallThinker's three END that list, is marked, and
+# tests/yardstick/test_yardstick_lifecycle.py asserts what it asserted, seven
+# places up. Strict and AssertionError only: each case fails loudly once it
 # passes (the next benchmark PR relaxes the assertions and deletes this).
 _YARDSTICK_PINS = (
     ("test_configuration_file_is_what_the_program_runs", "[laguna-xs2]",
@@ -189,6 +193,9 @@ _YARDSTICK_PINS = (
     ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_laguna.py",
      "asserts that laguna-solo-8k ends the manifest and is alone in Laguna's lists; smallthinker-solo-16k "
      "was appended after it (checked in test_yardstick_smallthinker.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_smallthinker.py",
+     "asserts that SmallThinker's three metrics end per_layer; PR 37 appended the seven lifecycle.* metrics "
+     "after them (checked, seven places up, in test_yardstick_lifecycle.py)"),
 )
 
 

@@ -177,13 +177,15 @@ class TestTrainLoopSpans:
         assert merge["attrs"]["where"] == ("device" if overlap else "host")
         assert merge["attrs"]["bytes"] == merged_bytes(tr)
         assert launch["t0"] + launch["dur_s"] <= merge["t0"] + 1e-3
-        # one snapshot per cadence boundary under `loop`, the one at
-        # construction included; the merge's own carries the round's key
+        # one snapshot per cadence boundary under `loop`; the merge's own
+        # carries the round's key, the constructor's belongs to start-up
         loose = [s for s in by_name["loop.snapshot"] if s["trace"] == "loop"]
         assert all("parent" not in s for s in loose)
-        # overlap: boundary 4 (the launch's) and the constructor's; blocking:
-        # the boundary's snapshot IS the merge's
-        assert sorted(s["attrs"]["step"] for s in loose) == ([0, 4] if overlap else [0])
+        # overlap: boundary 4 (the launch's); blocking: the boundary's
+        # snapshot IS the merge's
+        assert sorted(s["attrs"]["step"] for s in loose) == ([4] if overlap else [])
+        (built,) = [s for s in by_name["loop.snapshot"] if s["trace"] == "lifecycle"]
+        assert built["attrs"]["step"] == 0 and built["parent"] == "lifecycle.init"
         merged = [s for s in by_name["loop.snapshot"] if s["trace"] == "round-a"]
         assert len(merged) == 1 and merged[0]["attrs"]["bytes"] == merged_bytes(tr)
         # every device-side copy lands once, under `loop`, with no parent:
@@ -239,8 +241,9 @@ class TestTrainLoopSpans:
         assert opened.count("data") == opened.count("dispatch") == 7
         names = {s["name"] for s in tracer.spans()}
         assert "data" not in names and "dispatch" not in names
-        # every span's annotation was opened under the same name
-        assert names <= set(opened)
+        # every span's annotation was opened under the same name, but for the
+        # two that `Tracer.start` opens and another function or thread ends
+        assert names - {"lifecycle", "lifecycle.first_batch"} <= set(opened)
 
 
 # -- the codec under the round ---------------------------------------------------
