@@ -25,7 +25,13 @@ many chunks as the step's routing needs: one, unless the router sends this
 share more than three times its part; then more, and still none dropped. Rows
 return to their tokens by gathers too: within a chunk the rows are put in token
 order, a token's (at most k) rows summed along their run, and each token takes
-its run's total. A chunk is two grouped matmuls forward and five backward
+its run's total. The run's sum is one pass over the ``[rows, d]`` buffer
+(``_combine``): in tiles of 128 rows, a 0/1 matrix made from the sorted tokens
+("same token, not later") times the tile on the MXU, accumulated in float32 and
+rounded once; what a run left behind a tile's edge is summed from the tiles'
+last 8 rows and added in the same product's epilogue. It is the transpose of
+the rows' gather too, so it runs forward and backward. A chunk is two grouped
+matmuls forward and five backward
 (``_share_rows``, ``_share_experts``): gate and up are one product against the
 two stacks side by side, and a row's gate scales ``hidden`` [rows, f] in front
 of the down product, not its result [rows, d]: passes over [rows, d] buffers
@@ -271,25 +277,60 @@ def _spread(x: jax.Array, where, k: int) -> jax.Array:
     return jnp.where(valid[:, None], x[tok], jnp.zeros((), x.dtype))
 
 
+# Rows of one tile of the product that sums a token's run (``_combine``), where
+# they divide the chunk: a chunk is whole megablox row tiles of 512 at a cell's
+# sizes and whole sublane tiles of 8 at a test's, which then is the tile. 128 is
+# the v5e's MXU width. MEASURED (PR 38, TPU v5e, bf16, experiments/combine_sweep.py;
+# PERF.md, Findings of PR 38): over a ``[104448, 2560]`` chunk the product is one
+# fusion of 1.628 ms at 128, 1.637 at 256 and 1.670 at 512, the time of the
+# buffer's one read and one write (1.62); over ``[49152, 2048]`` 0.614, 0.614 and
+# 0.621. The tile decides nothing a step feels, so it is the smallest 0/1 matrix.
+# The three shifted adds it replaced were 12.0 ms (3 x (1.63 + 2.37)) and 4.5.
+_RUN_TILE = 128
+# Rows at a tile's end that a run which crosses the edge can have left behind:
+# at most k - 1, and one sublane tile of them is read (0.10 ms and 0.04).
+_RUN_CARRY = 8
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _combine(rows: jax.Array, where, k: int) -> jax.Array:
-    """``[R, d] -> [S, d]``: every token's sum over its held rows, by gathers:
-    rows into token order, a token's at most ``k`` rows summed along their run
-    (shifted adds at distances 1, 2, 4, ..: after them a run's last row holds
-    the whole run's sum), and each token takes its run's last row, zero where
-    it has no run (a select on the [S, d] result: no zero row is appended to
-    the ``[R, d]`` buffer, which would copy it)."""
+    """``[R, d] -> [S, d]``: every token's sum over its held rows, by a gather
+    into token order and ONE batched product over the buffer: its ``[R / T, T,
+    d]`` tiles times the 0/1 matrices ``A[b, i, j] = tok[b, i] == tok[b, j] and
+    j <= i`` (made from the sorted tokens alone, inside the product's fusion;
+    exact in bfloat16), accumulated in float32, so row ``i`` holds its run's
+    sum up to ``i`` inside its tile, rounded once. A run that crosses a tile's
+    edge (at most one a tile, at most ``k - 1`` rows behind the edge) is closed
+    from the small side: the ``_RUN_CARRY`` trailing rows of every tile that
+    share a token with the next tile's first row are summed (a reduction over
+    a sixteenth of the buffer, ``[R / T, d]`` float32) and added to the next
+    tile's rows of that token in the product's float32 epilogue, which costs
+    no pass (as a second gather and add on the ``[S, d]`` result it would be a
+    1.4 ms gather; as an update of the ``[R, d]`` rows a second pass). Then
+    each token takes its run's last row, zero where it has no run (a select on
+    the ``[S, d]`` result: no zero row is appended to the ``[R, d]`` buffer,
+    which would copy it). One read and one write of the buffer where the three
+    rounds of shifted adds before PR 38 made six of each and three slices."""
     perm, tok_sorted, last_pos = where[2:]
-    total = rows[perm]
-    shift = 1
-    while shift < min(k, total.shape[0]):
-        same = tok_sorted[shift:] == tok_sorted[:-shift]
-        earlier = jnp.where(same[:, None], total[:-shift], jnp.zeros((), total.dtype))
-        total = total + jnp.pad(earlier, ((shift, 0), (0, 0)))
-        shift *= 2
-    r = total.shape[0]
-    last = total[jnp.minimum(last_pos, r - 1)]
-    return jnp.where((last_pos < r)[:, None], last, jnp.zeros((), total.dtype))
+    r, d = rows.shape
+    t = _RUN_TILE if r % _RUN_TILE == 0 else 8
+    if r % t or not k - 1 <= _RUN_CARRY <= t:
+        raise ValueError(f"a run of {k} rows in {r} rows: tiles of {t} with {_RUN_CARRY} carried do not cover it")
+    dtype = rows.dtype
+    tiles = rows[perm].reshape(r // t, t, d)
+    tok = tok_sorted.reshape(r // t, t)
+    first = tok[:, 0]
+    # what a tile's trailing rows leave to the next tile's first run: [R / T, d], float32, none to the first
+    behind = tok[:-1, t - _RUN_CARRY:] == first[1:, None]
+    carry = jnp.einsum("bj,bjd->bd", behind.astype(dtype), tiles[:-1, t - _RUN_CARRY:],
+                       preferred_element_type=jnp.float32)
+    carry = jnp.pad(carry, ((1, 0), (0, 0)))
+    at = jnp.arange(t, dtype=jnp.int32)
+    same = (tok[:, :, None] == tok[:, None, :]) & (at[None, :] <= at[:, None])
+    run = jnp.einsum("bij,bjd->bid", same.astype(dtype), tiles, preferred_element_type=jnp.float32)
+    run = (run + jnp.where((tok == first[:, None])[:, :, None], carry[:, None, :], 0.0)).astype(dtype).reshape(r, d)
+    last = run[jnp.minimum(last_pos, r - 1)]
+    return jnp.where((last_pos < r)[:, None], last, jnp.zeros((), dtype))
 
 
 # The two are each other's transpose: neither differentiates into a scatter-add.

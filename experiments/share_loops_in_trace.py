@@ -12,8 +12,14 @@ second argument: 196,608 in smallthinker-solo-16k, 262,144 in laguna-solo-8k);
 for each, its runs on chip 0 with their median length, and every operation
 inside the median run with its own milliseconds (the grouped matmuls are
 ``gmm`` / ``jvp_jit_gmm__`` / ``transpose_jvp_jit_[t]gmm___``, the row
-gathers and shifted adds nameless fusions told by their result's type). PERF.md
-section 5 quotes it for the two share cells (PR 36).
+gathers nameless ``fusion.N`` told by their result's type, ``bf16[rows,d]``).
+Since PR 38 a token's run is summed by the one ``fusion.N`` whose result is
+``bf16[rows/128,128,d]`` (the batched 0/1 product of ``_combine``, a
+convolution fusion: 1.62 ms over ``[104448,2560]``), with
+``multiply_reduce_fusion.N f32[rows/128-1,d]`` (the carry over a tile's edge, 0.10
+ms) in front of it; before it they were three ``slice_select_fusion`` of
+``bf16[rows-1|2|4,d]`` and three ``pad_add_fusion`` of ``bf16[rows,d]`` a loop.
+PERF.md section 5 quotes the listing for the two share cells (PR 36, PR 38).
 """
 
 import collections

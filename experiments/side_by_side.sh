@@ -2,7 +2,7 @@
 # One cell, parent against change, on the same chip in one call: p c c p with a
 # seed a pair, then a traced run of the sides named. The parent is unpacked in
 # .bench_parent, the change (git archive of the tree to commit) in .bench_change.
-#   chiprun -- bash experiments/side_by_side.sh <outdir> <cell> <seedA> <seedB> ["p c"]
+#   chiprun -- bash experiments/side_by_side.sh <outdir> <cell> <seedA> <seedB> ["p c"] [order]
 out=$1; cell=$2; a=$3; b=$4; traced=$5
 mkdir -p chiprun_out/$out
 run() { # side seed trace tag
@@ -16,5 +16,14 @@ try:
     d=json.loads(sys.stdin.read()); print('correct',d['correct'],'failed',d['failed'],{k:v['value'] for k,v in d['metrics'].items()})
 except Exception as e: print('no result line',e)")"
 }
-if [ "$a" != "-" ]; then run p $a 0 1p; run c $a 0 2c; run c $b 0 3c; run p $b 0 4p; fi
-for side in $traced; do run $side $((b+7)) 1 t$side; done
+# A sixth argument gives another order, e.g. "pa pb tp ca cb tc" for a cell whose
+# two steps do not fit the machine's compile cache together (each side cold once).
+n=0
+for step in ${6:-$([ "$a" != "-" ] && echo pa ca cb pb) $(for side in $traced; do echo t$side; done)}; do
+  n=$((n+1))
+  case $step in
+    t?) run ${step#t} $((b+7)) 1 $step ;;
+    ?a) run ${step%a} $a 0 $n${step%a} ;;
+    ?b) run ${step%b} $b 0 $n${step%b} ;;
+  esac
+done

@@ -383,14 +383,18 @@ def test_no_held_assignment_is_dropped_when_every_token_picks_the_same_expert():
 
 @pytest.mark.parametrize("k", [3, 8])
 def test_rows_return_to_their_tokens_as_a_segment_sum_and_its_transpose(k):
-    """``_combine`` (sort by token, shifted adds at doubling distances, one
-    gather) against a plain segment sum, with runs of every length up to k;
-    ``_spread`` is its transpose."""
+    """``_combine`` (sort by token, the runs summed by one 0/1 product a tile
+    of rows since PR 38, one gather) against a plain segment sum, with runs of
+    every length up to k; ``_spread`` is its transpose. The rows are whole
+    sublane tiles, as a chunk's always are (``share_rows_bound``): the last
+    few are not held."""
     rng = np.random.default_rng(k)
     n_tokens = 20  # eight tokens with k rows, twelve with 0 .. k: a token has at most k choices
     tok = np.concatenate([np.repeat(np.arange(8), k), np.repeat(np.arange(8, n_tokens), rng.integers(0, k + 1, 12))])
-    tok, r = jnp.asarray(rng.permutation(tok), jnp.int32), tok.shape[0]
-    valid = jnp.asarray(rng.random(r) < 0.8)
+    filler = -tok.shape[0] % 8
+    tok = np.concatenate([rng.permutation(tok), rng.integers(8, n_tokens, filler)])
+    tok, r = jnp.asarray(tok, jnp.int32), tok.shape[0]
+    valid = jnp.asarray((rng.random(r) < 0.8) & (np.arange(r) < r - filler))
     valid = valid.at[jnp.nonzero(tok < 8)[0]].set(True)  # eight whole runs of k rows
     rows = jnp.asarray(rng.normal(size=(r, 5)), jnp.float32)
     where = (tok, valid, *moe_dispatch._token_runs(tok, valid, n_tokens))

@@ -23,14 +23,14 @@ ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 SLACKS = {"several": 0.5, "one": 3.0}
 
 
-def inputs():
+def inputs(f=F):
     """Tokens, routes and held stacks: token 0 chooses no held expert, token 1
     all four of them (a run of k rows), the others as the draw has it (52 held
     assignments of 192)."""
     ks = jax.random.split(jax.random.PRNGKey(36), 6)
     x = jax.random.normal(ks[0], (S, D))
     stacks = [jax.random.normal(kk, shape) * 0.3
-              for kk, shape in zip(ks[1:4], ((HELD, D, F), (HELD, D, F), (HELD, F, D)))]
+              for kk, shape in zip(ks[1:4], ((HELD, D, f), (HELD, D, f), (HELD, f, D)))]
     idx = jnp.argsort(jax.random.uniform(ks[4], (S, E)), axis=1)[:, :K].astype(jnp.int32)
     idx = idx.at[0].set(jnp.asarray([0, 1, 9, 13])).at[1].set(jnp.asarray([7, 4, 6, 5]))
     gates = jax.nn.softmax(jax.random.normal(ks[5], (S, K)), axis=1)
@@ -129,3 +129,159 @@ def test_every_gradient_is_the_per_token_sums(act, chunks):
                        for i in range(K)], axis=1)
     np.testing.assert_allclose(d_gates, np.asarray(alone), rtol=2e-4, atol=2e-5)
     assert not np.asarray(got[0][0]).any()  # a token with no held row takes no gradient from this share
+
+
+# -- a token's run summed by one block-local 0/1 product (PR 38) ------------------------
+
+# rows R, k, and the runs the case is about as (first position in token order,
+# rows); the positions before each are filled with other tokens' runs of 0 .. k
+# rows, and at least one row at the end is not held
+RUNS = {
+    "one_tile_of_8": (8, 4, [(1, 4)]),
+    "ends_on_the_second_tiles_first_row": (16, 4, [(1, 4), (5, 4)]),         # k - 1 = 3 rows behind the edge
+    "one_row_behind_the_edge": (16, 4, [(7, 4), (11, 2)]),
+    "k_of_8_over_the_edge": (16, 8, [(1, 8)]),                               # 7 rows behind, ends on row 8
+    "three_tiles_of_8": (24, 4, [(6, 3), (9, 4), (13, 4)]),                  # both edges crossed
+    "a_run_ahead_of_each_edge": (24, 3, [(5, 3), (8, 1), (14, 2), (16, 3)]),  # runs that end and begin at an edge
+    "one_tile_of_128": (128, 6, [(0, 6), (120, 6)]),
+    "two_tiles_of_128": (256, 6, [(100, 6), (123, 6), (245, 5)]),            # 123 .. 128: k - 1 behind, ends on row 128
+    "two_tiles_of_128_k_of_8": (256, 8, [(121, 8)]),
+    "three_tiles_of_128": (384, 6, [(127, 2), (252, 6), (300, 1)]),
+}
+
+
+def chunk_of(case, seed=38):
+    """(tok [R], valid [R], n_tokens, k) for ``RUNS[case]``: held
+    rows of tokens in the counts the case asks for, every third token with no
+    row at all, in a random order among rows that are not held, whose token
+    numbers are drawn from the same range (a sum that took them would show)."""
+    r, k, forced = RUNS[case]
+    rng = np.random.default_rng(seed)
+    counts, pos, fill = [], 0, 0
+    for start, length in forced:
+        while pos < start:
+            n = min((k, 0, 1, 2, 0, k - 1)[fill % 6], start - pos)
+            counts.append(n)
+            pos, fill = pos + n, fill + 1
+        counts.append(length)
+        pos += length
+    counts += [0, 1, 0]
+    assert sum(counts) < r and max(counts) <= k
+    held = np.repeat(np.arange(len(counts)), counts)
+    tok = np.concatenate([held, rng.integers(0, len(counts), size=r - len(held))])
+    valid = np.arange(r) < len(held)
+    order = rng.permutation(r)
+    return jnp.asarray(tok[order], jnp.int32), jnp.asarray(valid[order]), len(counts), k
+
+
+def plain_sum(rows, tok, valid, n_tokens):
+    """Every token's float32 sum over its held rows, row by row."""
+    out = np.zeros((n_tokens, rows.shape[1]), np.float32)
+    for row, t, v in zip(np.asarray(rows, np.float32), np.asarray(tok), np.asarray(valid)):
+        if v:
+            out[t] += row
+    return out
+
+
+@pytest.mark.parametrize("case", list(RUNS))
+def test_a_tokens_run_is_summed_whole_across_tile_edges(case):
+    """``_combine`` against the plain sum: a run that ends on a tile's first
+    row with k - 1 rows behind the edge, one with a single row behind it, runs
+    of exactly k, tokens with no run, rows that are not held between the held
+    ones, with the product's tile equal to the test's sublane tile (8: one,
+    two and three tiles) and to the chip's (128). The case says where its runs
+    lie; that they do is checked on the sorted order itself."""
+    tok, valid, n_tokens, k = chunk_of(case)
+    r, _, forced = RUNS[case]
+    where = (tok, valid, *moe_dispatch._token_runs(tok, valid, n_tokens))
+    tok_sorted = np.asarray(where[3])
+    for start, length in forced:
+        assert len(set(tok_sorted[start:start + length])) == 1 and tok_sorted[start] < n_tokens
+        assert tok_sorted[start - 1] != tok_sorted[start] != tok_sorted[start + length] or start == 0
+    rows = jax.random.normal(jax.random.PRNGKey(38), (r, D))
+    got = moe_dispatch._combine(rows, where, k)
+    want = plain_sum(rows, tok, valid, n_tokens)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    empty = np.bincount(np.asarray(tok)[np.asarray(valid)], minlength=n_tokens) == 0
+    assert empty.any() and not np.asarray(got)[empty].any()
+    # in the compute dtype the run is accumulated in float32 and rounded once
+    low = moe_dispatch._combine(rows.astype(jnp.bfloat16), where, k)
+    want_low = plain_sum(rows.astype(jnp.bfloat16), tok, valid, n_tokens)
+    np.testing.assert_allclose(np.asarray(low, np.float32), want_low, rtol=2 ** -8, atol=1e-6)
+
+
+def test_a_run_longer_than_the_carry_is_refused():
+    tok, valid, n_tokens, _ = chunk_of("one_tile_of_8")
+    where = (tok, valid, *moe_dispatch._token_runs(tok, valid, n_tokens))
+    with pytest.raises(ValueError, match="carried"):
+        moe_dispatch._combine(jnp.zeros((8, D)), where, 10)
+
+
+@pytest.mark.parametrize("case", ["ends_on_the_second_tiles_first_row", "three_tiles_of_8", "two_tiles_of_128"])
+def test_spread_and_combine_are_each_others_transpose_without_a_scatter_add(case):
+    """``jax.vjp`` of ``_spread`` is ``_combine`` on the cotangent and the
+    reverse (held to each function itself and, as an adjoint pair, to the
+    inner products), and neither derivative's jaxpr holds a scatter-add: the
+    product that sums a run is its own transpose's partner as the shifted adds
+    were."""
+    tok, valid, n_tokens, k = chunk_of(case)
+    r = tok.shape[0]
+    where = (tok, valid, *moe_dispatch._token_runs(tok, valid, n_tokens))
+    kx, kg = jax.random.split(jax.random.PRNGKey(3))
+    x, g = jax.random.normal(kx, (n_tokens, D)), jax.random.normal(kg, (r, D))
+
+    def pull_spread(x, g):
+        return jax.vjp(lambda x: moe_dispatch._spread(x, where, k), x)[1](g)[0]
+
+    def pull_combine(g, x):
+        return jax.vjp(lambda g: moe_dispatch._combine(g, where, k), g)[1](x)[0]
+
+    np.testing.assert_allclose(np.asarray(pull_spread(x, g)), np.asarray(moe_dispatch._combine(g, where, k)),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pull_combine(g, x)), np.asarray(moe_dispatch._spread(x, where, k)),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(float(jnp.sum(moe_dispatch._spread(x, where, k) * g)),
+                               float(jnp.sum(x * moe_dispatch._combine(g, where, k))), rtol=1e-5)
+    for pull, args in ((pull_spread, (x, g)), (pull_combine, (g, x))):
+        names = {eqn.primitive.name for _, eqn in live_equations(jax.make_jaxpr(pull)(*args).jaxpr)}
+        assert not any("scatter" in name for name in names), names
+        assert "gather" in names
+
+
+@pytest.mark.parametrize("chunks", list(SLACKS))
+@pytest.mark.parametrize("act", list(ACTS))
+def test_a_chunk_sums_its_runs_in_one_product_forward_and_one_backward(act, chunks):
+    """In the gradient's jaxpr with dead code removed, each chunk loop holds
+    ONE ``dot_general`` whose result is the ``[rows, d]`` buffer (as its
+    ``[rows / T, T, d]`` tiles): ``_combine`` forward on the down product's
+    result, and backward on the rows' cotangent, where it is ``_spread``'s
+    transpose. No ``pad``, no slice and no add of a buffer that size: the
+    three rounds of shifted slice, pad and add before PR 38 are gone, and the
+    carry over a tile's edge is added inside the product's own expression."""
+    x, idx, gates, stacks = inputs(f=12)  # so that [rows, 2 f] is not taken for [rows, d]
+    slack = SLACKS[chunks]
+    rows = moe_dispatch.share_rows_bound(S, K, HELD, E, slack)
+
+    def loss(x, gates, *stacks):
+        return jnp.sum(jnp.sin(moe_dispatch.share_glu_experts(
+            x, idx, gates, *stacks, OFFSET, E, act=act, slack=slack)[0]))
+
+    closed = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(x, gates, *stacks)
+    in_loops = [(path, eqn) for path, eqn in live_equations(closed.jaxpr) if "while" in path]
+
+    def tiles_of_the_buffer(eqn):
+        shape = eqn.outvars[0].aval.shape
+        return len(shape) == 3 and shape[-1] == D and shape[0] * shape[1] == rows
+
+    def shifted_rows(eqn):
+        shape = eqn.outvars[0].aval.shape
+        return len(shape) == 2 and shape[1] == D and rows - 8 <= shape[0] <= rows
+
+    products = collections.Counter(
+        "forward" if "custom_vjp_call" in path[:path.index("while")] else "backward"
+        for path, eqn in in_loops if eqn.primitive.name == "dot_general" and tiles_of_the_buffer(eqn))
+    assert products == {"forward": 1, "backward": 1}, products
+    moved = collections.Counter(eqn.primitive.name for _, eqn in in_loops if shifted_rows(eqn))
+    assert not {"pad", "slice", "concatenate", "add", "add_any", "dynamic_update_slice"} & set(moved), moved
+    # the one add of that size is the carry's, on the product's float32 tiles, once a loop
+    assert sum(eqn.primitive.name == "add" and tiles_of_the_buffer(eqn) for _, eqn in in_loops) == 2
