@@ -1,7 +1,8 @@
 """Model registry: one bundle per reference workload (BASELINE.json:7-11),
 and the public architectures run at their published sizes beyond them
-(``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``: each takes the
-overrides that cut it to one chip's share without touching a width).
+(``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``, ``lfm2_24b_a2b``:
+each takes the overrides that cut it to one chip's share without touching a
+width).
 
 Bundles are built lazily so importing the registry never pays for the whole
 zoo. Each bundle closes over its config and exposes:
@@ -14,7 +15,7 @@ zoo. Each bundle closes over its config and exposes:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
@@ -31,6 +32,31 @@ def _identity_merge(params: Any, averaged: Any) -> Any:
 
 
 @dataclasses.dataclass(frozen=True)
+class SteppedLeaves:
+    """Leaves of the parameters that the STEP moves, by the model's own rule
+    and from the step's own metrics, and the optimizer does not: a state that
+    no gradient reaches (a router's selection bias, which load balancing
+    without an auxiliary loss raises and lowers by the experts' loads). A
+    model's bundle names them (``ModelBundle.stepped``); every builder of a
+    step takes them (``stepped=``) and hands them to ``train_step_body``, the
+    one place they act. A bundle that names none compiles to the program it
+    compiled to before there was such a thing.
+
+    ``signal``: the key of the loss function's metrics that the rule reads. It
+    need not be a scalar; the step takes it out of the metrics it returns.
+    ``owns(params)``: a tree of bools shaped like ``params``, True on the
+    leaves the rule owns. Their gradient is zeroed before the optimizer sees
+    it (no share of a global-norm clip) and whatever the optimizer makes of
+    them is discarded: an owned leaf after the step is ``rule``'s.
+    ``rule(params, signal)``: a tree shaped like ``params`` whose owned leaves
+    are the new values, from the parameters as they were BEFORE the update."""
+
+    signal: str
+    owns: Callable[[Any], Any]
+    rule: Callable[[Any, Any], Any]
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelBundle:
     name: str
     config: Any
@@ -42,6 +68,9 @@ class ModelBundle:
     # the WAN round ships ~1000x less) and merge the averaged result back.
     avg_select: Callable[[Any], Any] = _identity_select
     avg_merge: Callable[[Any, Any], Any] = _identity_merge
+    # Leaves the train step moves by the model's own rule and keeps the
+    # optimizer off; None for every model whose parameters all follow a gradient.
+    stepped: Optional[SteppedLeaves] = None
 
 
 def _mlp(**overrides: Any) -> ModelBundle:
@@ -202,6 +231,27 @@ def _smallthinker(**overrides: Any) -> ModelBundle:
     )
 
 
+def _lfm2(**overrides: Any) -> ModelBundle:
+    """LFM2-24B-A2B at its published sizes (models/lfm2.py): 40 layers of 64
+    experts are many chips' work; ``layer_types`` with ``dense_layers``,
+    ``experts_held`` / ``expert_offset`` and ``vocab`` cut it to one chip's
+    share. Its routers' selection biases are the step's to move."""
+    from distributedvolunteercomputing_tpu.models import lfm2
+    from distributedvolunteercomputing_tpu.training import data
+
+    cfg = dataclasses.replace(lfm2.LFM2Config(), **overrides)
+    return ModelBundle(
+        name="lfm2_24b_a2b",
+        config=cfg,
+        init=lambda rng: lfm2.init(rng, cfg),
+        loss_fn=lambda p, b, rng: lfm2.loss_fn(p, b, rng, cfg),
+        make_batch=lambda rng, bs: data.synthetic_lm_batch(
+            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
+        ),
+        stepped=lfm2.stepped(cfg),
+    )
+
+
 def _vit(**overrides: Any) -> ModelBundle:
     from distributedvolunteercomputing_tpu.models import vit
     from distributedvolunteercomputing_tpu.training import data
@@ -251,6 +301,7 @@ _REGISTRY: Dict[str, Callable[..., ModelBundle]] = {
     "olmoe_1b_7b": _olmoe,
     "laguna_xs2": _laguna,
     "smallthinker_21b_a3b": _smallthinker,
+    "lfm2_24b_a2b": _lfm2,
     "llama_lora": _llama_lora,
 }
 

@@ -139,6 +139,14 @@ def keeping_kernel_results(layers: int):
         _kept_observer(layers, layers * sum(kept))
 
 
+def chips_in_step() -> int:
+    """How many chips the program being traced is laid out over: the size of
+    the traced step's mesh, or, where no step has announced one, every device
+    there is (a bare ``jit`` may be handed arrays sharded over all of them).
+    A kernel with no per-shard form runs where this is 1."""
+    return jax.device_count() if _mesh_ctx is None else _mesh_ctx.size
+
+
 def heads_tp() -> int:
     """Over how many chips the traced step's mesh can divide a projection's
     heads: the size of its ``tp`` axis, or 1 with no step mesh or where an

@@ -130,6 +130,7 @@ def make_sharded_train_step(
     zero1: bool = False,
     fsdp: bool = False,
     sp_impl: str = "ring",
+    stepped: Any = None,  # models/registry.SteppedLeaves: leaves the step moves by the model's rule
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, Metrics]]:
     """Build the jitted sharded ``(state, batch) -> (state, metrics)`` step.
 
@@ -160,7 +161,7 @@ def make_sharded_train_step(
     def step(state: TrainState, batch: Batch) -> Tuple[TrainState, Metrics]:
         batch = jax.lax.with_sharding_constraint(batch, bspec)
         with _attention_ctx(mesh, use_ring, sp_impl):
-            new_state, metrics = train_step_body(loss_fn, tx, state, batch, accum_steps)
+            new_state, metrics = train_step_body(loss_fn, tx, state, batch, accum_steps, stepped)
         return constrain_opt(new_state), metrics
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
@@ -223,6 +224,7 @@ def make_sharded_multi_step(
     zero1: bool = False,
     fsdp: bool = False,
     sp_impl: str = "ring",
+    stepped: Any = None,  # models/registry.SteppedLeaves: leaves the step moves by the model's rule
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, jax.Array]]:
     """N sharded train steps in ONE compiled call: ``(state,
     stacked_batches) -> (state, per_step_losses)``.
@@ -242,7 +244,7 @@ def make_sharded_multi_step(
     def multi(state: TrainState, batches: Batch) -> Tuple[TrainState, jax.Array]:
         def body(s: TrainState, b: Batch):
             b = jax.lax.with_sharding_constraint(b, bspec)
-            s2, metrics = train_step_body(loss_fn, tx, s, b, accum_steps)
+            s2, metrics = train_step_body(loss_fn, tx, s, b, accum_steps, stepped)
             return constrain_opt(s2), metrics["loss"]
 
         with _attention_ctx(mesh, use_ring, sp_impl):

@@ -135,6 +135,7 @@ class VolunteerConfig:
     init_seed: int = 0  # TASK-constant: shared initial params (see Trainer)
     param_dtype: Optional[str] = None  # e.g. "bfloat16" for bf16 training
     steps: int = 1000
+    warmup_steps: int = 0  # linear LR warm-up from 0, before the cosine decay over `steps`
     target_loss: Optional[float] = None
     # "stop" ends the run at the target; "record" trains the full --steps
     # and reports when the target was first crossed (time-to-target-loss).
@@ -927,6 +928,7 @@ class Volunteer:
             metrics_path=self.cfg.metrics_path,
             volunteer_id=self.cfg.peer_id,
             total_steps=self.cfg.steps,
+            warmup_steps=self.cfg.warmup_steps,
             on_step=on_step,
             eval_every=self.cfg.eval_every,
             eval_batches=self.cfg.eval_batches,

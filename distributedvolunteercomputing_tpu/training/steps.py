@@ -11,11 +11,13 @@ and the params never round-trip to host between steps. The multi-chip variant
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
+
+from distributedvolunteercomputing_tpu.models.registry import SteppedLeaves
 
 Batch = Dict[str, jax.Array]
 Metrics = Dict[str, jax.Array]
@@ -102,10 +104,22 @@ def apply_half(
     state: TrainState,
     grads: Any,
     rng: jax.Array,
+    stepped: Optional[SteppedLeaves] = None,
+    signal: Any = None,
 ) -> TrainState:
-    """Optimizer-update half of the step."""
+    """Optimizer-update half of the step. With ``stepped``, the leaves it owns
+    are its rule's (from ``signal``, the step's metric of that name) and the
+    optimizer's update of every other leaf is what it would be without them."""
+    if stepped is not None:
+        owned = stepped.owns(state.params)
+        grads = jax.tree_util.tree_map(
+            lambda own, g: jnp.zeros_like(g) if own else g, owned, grads)
     updates, opt_state = tx.update(grads, state.opt_state, state.params)
     params = optax.apply_updates(state.params, updates)
+    if stepped is not None:
+        params = jax.tree_util.tree_map(
+            lambda own, ruled, updated: ruled if own else updated,
+            owned, stepped.rule(state.params, signal), params)
     return TrainState(params=params, opt_state=opt_state, step=state.step + 1, rng=rng)
 
 
@@ -115,12 +129,14 @@ def train_step_body(
     state: TrainState,
     batch: Batch,
     accum_steps: int = 1,
+    stepped: Optional[SteppedLeaves] = None,
 ) -> Tuple[TrainState, Metrics]:
     """The traced step math, shared by the single-device step, the sharded
     step (parallel/train_step.py), and — via its two halves — the split
     grad/apply steps of gradient-averaging mode, so no path can diverge."""
     grads, metrics, rng = grad_half(loss_fn, state, batch, accum_steps)
-    return apply_half(tx, state, grads, rng), metrics
+    signal = metrics.pop(stepped.signal) if stepped is not None else None
+    return apply_half(tx, state, grads, rng, stepped, signal), metrics
 
 
 def make_train_step(
@@ -128,11 +144,12 @@ def make_train_step(
     tx: optax.GradientTransformation,
     donate: bool = True,
     accum_steps: int = 1,
+    stepped: Optional[SteppedLeaves] = None,
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, Metrics]]:
     """Build the jitted ``(state, batch) -> (state, metrics)`` step."""
 
     def step(state: TrainState, batch: Batch) -> Tuple[TrainState, Metrics]:
-        return train_step_body(loss_fn, tx, state, batch, accum_steps)
+        return train_step_body(loss_fn, tx, state, batch, accum_steps, stepped)
 
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
@@ -141,6 +158,7 @@ def make_multi_step(
     loss_fn: Callable[[Any, Batch, jax.Array], Tuple[jax.Array, Metrics]],
     tx: optax.GradientTransformation,
     accum_steps: int = 1,
+    stepped: Optional[SteppedLeaves] = None,
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, jax.Array]]:
     """N train steps in ONE compiled call: ``(state, stacked_batches) ->
     (state, per_step_losses)``.
@@ -155,7 +173,7 @@ def make_multi_step(
 
     def multi(state: TrainState, batches: Batch) -> Tuple[TrainState, jax.Array]:
         def body(s: TrainState, b: Batch):
-            s2, metrics = train_step_body(loss_fn, tx, s, b, accum_steps)
+            s2, metrics = train_step_body(loss_fn, tx, s, b, accum_steps, stepped)
             return s2, metrics["loss"]
 
         return jax.lax.scan(body, state, batches)
@@ -180,11 +198,14 @@ def make_grad_step(
 def make_apply_step(
     tx: optax.GradientTransformation,
     donate: bool = True,
-) -> Callable[[TrainState, Any, jax.Array], TrainState]:
+    stepped: Optional[SteppedLeaves] = None,
+) -> Callable[..., TrainState]:
     """Gradient-averaging mode, half 2: optimizer update from (possibly
-    swarm-averaged) grads."""
+    swarm-averaged) grads. With ``stepped`` the caller takes the rule's signal
+    out of the grad step's metrics (its own, not averaged) and passes it as
+    the fourth argument."""
     return jax.jit(
-        lambda state, grads, rng: apply_half(tx, state, grads, rng),
+        lambda state, grads, rng, signal=None: apply_half(tx, state, grads, rng, stepped, signal),
         donate_argnums=(0,) if donate else (),
     )
 

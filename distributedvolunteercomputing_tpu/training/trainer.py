@@ -67,7 +67,8 @@ COPIES_IN_FLIGHT = 2
 # ``moe.route`` span), and how often, in steps, the loop notes them between
 # its log points.
 ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
-                "moe_rows_moved", "moe_act_zero_share", "aux_loss", "lm_loss")
+                "moe_rows_moved", "moe_act_zero_share", "moe_bias_max", "moe_bias_min",
+                "moe_bias_moved", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
@@ -162,6 +163,9 @@ class Trainer:
         metrics_path: Optional[str] = None,
         volunteer_id: str = "local",
         total_steps: Optional[int] = None,
+        # The learning rate rises linearly from 0 over this many steps before
+        # the cosine decay over ``total_steps`` (0: the schedule starts at ``lr``).
+        warmup_steps: int = 0,
         # Called after each HOST-VISIBLE step. With steps_per_call > 1 the
         # scan prefix runs whole chunks on-device, so on_step fires only on
         # chunk-final steps: any per-step or modular cadence inside the
@@ -299,7 +303,7 @@ class Trainer:
             # rounds averaging away init noise.
             rng = jax.random.PRNGKey(seed)
             _, data_rng, state_rng = jax.random.split(rng, 3)
-            self.tx = make_optimizer(optimizer, lr=lr, total_steps=total_steps)
+            self.tx = make_optimizer(optimizer, lr=lr, warmup_steps=warmup_steps, total_steps=total_steps)
             self.param_dtype = param_dtype
             with self._phase("lifecycle.init.params") as init_params:
                 began = time.time()
@@ -390,6 +394,8 @@ class Trainer:
                         self.state, mesh, self.tx, fsdp=fsdp
                     )
                 self._put_batch = lambda b: put_batch(b, mesh, seq_sharded=seq_sharded)
+            # leaves the step moves by the model's own rule (models/registry.SteppedLeaves)
+            self._stepped = stepped = bundle.stepped
             if self._grads_mode:
                 # The split steps are plain jits: with mesh-sharded inputs GSPMD
                 # partitions them like the fused sharded step for replicated-dp
@@ -397,7 +403,7 @@ class Trainer:
                 # fsdp layout needs the fused step's in-step constraints and is
                 # rejected above.
                 self._grad_fn = make_grad_step(bundle.loss_fn, accum_steps=accum_steps)
-                self._apply_fn = make_apply_step(self.tx)
+                self._apply_fn = make_apply_step(self.tx, stepped=stepped)
                 self._step_fn = None
             elif mesh is not None:
                 from distributedvolunteercomputing_tpu.parallel.train_step import (
@@ -406,11 +412,11 @@ class Trainer:
 
                 self._step_fn = make_sharded_train_step(
                     bundle.loss_fn, self.tx, mesh, accum_steps=accum_steps,
-                    seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl,
+                    seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl, stepped=stepped,
                 )
             else:
                 self._step_fn = make_train_step(
-                    bundle.loss_fn, self.tx, accum_steps=accum_steps
+                    bundle.loss_fn, self.tx, accum_steps=accum_steps, stepped=stepped
                 )
             self.steps_per_call = int(steps_per_call)
             self.chunk_cadences = tuple(int(c) for c in chunk_cadences if c)
@@ -430,12 +436,13 @@ class Trainer:
                     self._multi_fn = make_sharded_multi_step(
                         bundle.loss_fn, self.tx, mesh, accum_steps=accum_steps,
                         seq_sharded_batch=seq_sharded, fsdp=fsdp, sp_impl=sp_impl,
+                        stepped=stepped,
                     )
                 else:
                     from distributedvolunteercomputing_tpu.training.steps import make_multi_step
 
                     self._multi_fn = make_multi_step(
-                        bundle.loss_fn, self.tx, accum_steps=accum_steps
+                        bundle.loss_fn, self.tx, accum_steps=accum_steps, stepped=stepped
                     )
             self._data_rng = data_rng
             self._data = data
@@ -612,6 +619,9 @@ class Trainer:
         # which stream the layer's router reads: its input (before attention) or
         # what attention made of it
         attrs["router_site"] = getattr(self.bundle.config, "router_site", "post_attention")
+        # a model that lists its layers' token mixers says how many of each kind
+        for kind in sorted(set(getattr(self.bundle.config, "layer_types", ()))):
+            attrs[f"mixers_{kind}"] = self.bundle.config.layer_types.count(kind)
         with self._phase("moe.route", step=step_no, **attrs):
             pass
 
@@ -1234,7 +1244,9 @@ class Trainer:
                         grads = merged
                     else:
                         avg_skip_until = step_no + self.average_every
-                self.state = self._apply_fn(self.state, grads, next_rng)
+                # the model's rule reads this volunteer's own step, not the group's
+                signal = () if self._stepped is None else (m.pop(self._stepped.signal),)
+                self.state = self._apply_fn(self.state, grads, next_rng, *signal)
                 if step_no % self.average_every == 0:
                     self._take_snapshot(step_no)
             else:

@@ -7,6 +7,7 @@ ops/moe_dispatch.py rests on.
 
     chiprun -- python experiments/laguna_routing_trace.py --seeds 2
     chiprun -- python experiments/laguna_routing_trace.py --config smallthinker-21b-a3b --seeds 3 --steps 60
+    chiprun -- python experiments/laguna_routing_trace.py --config lfm2-24b-a2b --seeds 2 --steps 80
     python experiments/laguna_routing_trace.py --config tiny-rehearsal-laguna --steps 12 --seeds 1
 
 One JSON line per seed in ``chiprun_out/laguna_routing_trace.json``; with
@@ -41,6 +42,8 @@ def main() -> int:
     ap.add_argument("--steps", type=int, default=45)
     ap.add_argument("--settled-from", type=int, default=100,
                     help="summarise the steps from this one on (a run of a few hundred steps)")
+    ap.add_argument("--warmup-steps", type=int, default=None,
+                    help="LR warm-up other than the configuration's (volunteer.warmup_steps, else 0)")
     ap.add_argument("--out", default="chiprun_out/laguna_routing_trace.json")
     args = ap.parse_args()
     cfg = Manifest().load_config(args.config)
@@ -63,13 +66,15 @@ def one_seed(args, cfg, sizes, dev, workdir, seed):
     path = datagen.write_token_file(
         os.path.join(workdir, f"tokens_{seed}.npz"), seed, 256, sizes["seq_len"], sizes["vocab"])
     bundle = get_model(cfg["registry_model"], **cfg["model_overrides"])
+    warm = vol.get("warmup_steps", 0) if args.warmup_steps is None else args.warmup_steps
     tr = Trainer(bundle, batch_size=vol["batch_size"], optimizer=vol["optimizer"], lr=vol["lr"],
-                 total_steps=vol["steps"], data=npz_batch_iter(path, vol["batch_size"], seed=seed),
+                 total_steps=vol["steps"], warmup_steps=warm, data=npz_batch_iter(path, vol["batch_size"], seed=seed),
                  seed=seed, init_seed=seed)
     steps = []
     inner = tr.metrics.record
     tr.metrics.record = lambda step, m, n_samples=0: (
-        steps.append({k: float(m[k]) for k in ("loss", "moe_rows_held", "moe_rows_moved", "moe_load_max")}),
+        steps.append({k: float(m[k]) for k in ("loss", "moe_rows_held", "moe_rows_moved", "moe_load_max",
+                                                 "moe_bias_max", "moe_bias_min", "moe_bias_moved") if k in m}),
         inner(step, m, n_samples=n_samples))
     tr.run(steps=args.steps, log_every=1)
     c = bundle.config
@@ -77,13 +82,17 @@ def one_seed(args, cfg, sizes, dev, workdir, seed):
     slack = getattr(sys.modules[type(c).__module__], "SHARE_ROWS_SLACK", None)  # the model's own, if it brings one
     bound = moe_dispatch.share_rows_bound(
         vol["batch_size"] * c.max_len, c.top_k, c.experts_held, c.n_experts, slack)
-    rec = {"seed": seed, "device": {"platform": dev.platform, "kind": dev.device_kind},
+    rec = {"seed": seed, "warmup_steps": warm, "device": {"platform": dev.platform, "kind": dev.device_kind},
            "chunk_rows": bound, "expert_layers": n_sparse,
            "even_share_a_layer": vol["batch_size"] * c.max_len * c.top_k * c.experts_held / c.n_experts,
            "held_a_layer": [round(s["moe_rows_held"] / n_sparse) for s in steps],
            "chunks": [round(s["moe_rows_moved"] / bound) for s in steps],
            "load_max": [s["moe_load_max"] for s in steps],
            "loss": [round(s["loss"], 3) for s in steps]}
+    if "moe_bias_max" in steps[0]:  # a router with a selection bias: what the step chose with, and how many it moved
+        rec["bias_max"] = [round(s["moe_bias_max"], 4) for s in steps]
+        rec["bias_min"] = [round(s["moe_bias_min"], 4) for s in steps]
+        rec["bias_moved"] = [round(s["moe_bias_moved"]) for s in steps]
     settled = rec["held_a_layer"][args.settled_from:]
     if settled:  # the state a donor lives in, past the router's first steps
         rec["settled"] = {"from_step": args.settled_from, "steps": len(settled),

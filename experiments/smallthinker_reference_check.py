@@ -1,15 +1,19 @@
-"""How close the program comes to the plain reference at
-SmallThinker-21BA3B-Instruct's published widths (the cell's cut: one period of
-four layers, eight of 64 experts, an eighth of the vocabulary, one sequence of
-16,384 tokens): the readings that set ``reference_check`` in
-``benchmark/configs/smallthinker-21b-a3b.json``.
+"""How close the program comes to its family's plain reference at the published
+widths of a share configuration (SmallThinker-21BA3B-Instruct by default: one
+period of four layers, eight of 64 experts, an eighth of the vocabulary, one
+sequence of 16,384 tokens; ``--config lfm2-24b-a2b``: published layers 0 and
+2-5, eight of 64 experts, an eighth of the vocabulary, 8,192 tokens): the
+readings that set ``reference_check`` in ``benchmark/configs/<config>.json``.
 
     chiprun -- python experiments/smallthinker_reference_check.py --seeds 3 --left-out
-    python experiments/smallthinker_reference_check.py --config tiny-rehearsal-smallthinker --seeds 1 --left-out
+    chiprun -- python experiments/smallthinker_reference_check.py --config lfm2-24b-a2b --seeds 3 --left-out
+    chiprun -- python experiments/smallthinker_reference_check.py --config lfm2-24b-a2b --seeds 2 --left-out \
+        --bias 0.5 --norm-scale 0.3 --qk-scale 4 --variants bias_in_weights,no_qk_norm
+    python experiments/smallthinker_reference_check.py --config tiny-rehearsal-lfm2 --seeds 1 --left-out
 
 Per seed (the benchmark's own seeded sequence and seeded initial parameters):
 the program's loss and gradients (bf16 compute on a TPU) against
-``benchmark/references/smallthinker.py`` (float32, highest precision)
+``benchmark/references/<family>.py`` (float32, highest precision)
 
 - as the harness calls it, without routes: the error ``correct`` sees, and the
   share of the L x S x k assignments on which the two picked another expert;
@@ -18,13 +22,24 @@ the program's loss and gradients (bf16 compute on a TPU) against
   same routes, against itself: what lower precisions read, which the limits
   must refuse;
 - ``--left-out`` (first seed): the reference with one term of the layer
-  equations computed wrongly (``references/smallthinker.VARIANTS``: the router
-  after attention, SiLU for ReLU, rotary on the global layer, no window, top-6
-  weights not renormalised), against itself with the same routes (the router
-  after attention picks its own): each must land outside a limit.
+  equations computed wrongly (the reference's ``VARIANTS``, or ``--variants``
+  of them), against itself with the same routes (a variant that scores the
+  experts otherwise picks its own): each must land outside a limit.
+
+``--bias``, ``--norm-scale``, ``--qk-scale`` make the parameters of EVERY
+reading seeded non-initial ones (a state a few thousand steps in, not a trained
+one): each router's selection bias ``normal(0, --bias)``, each per-head q / k
+norm's scales ``normal(1, --norm-scale)``, the q and k projections times
+``--qk-scale``. At the initial parameters the bias is zero (adding it to the
+weights is then no mistake) and q and k have RMS 0.9 under scales of 1 (the
+norm is nearly the identity), so neither ``bias_in_weights`` nor ``no_qk_norm``
+can show there. With any of the three, every reading also comes BY GROUP of
+leaves (``by_group``: attention, experts, router, ...): the whole-gradient
+norm is the dense layer's and the head's, and a mistake in one mixer or in the
+experts' weights moves its own group's leaves.
 
 One JSON line per seed and a summary; all in
-``chiprun_out/smallthinker_reference_check.json``. A CPU run compares float32
+``chiprun_out/<family>_reference_check.json``. A CPU run compares float32
 with float32 and checks the paths only.
 """
 
@@ -42,11 +57,44 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import datagen
+from benchmark import datagen, references
 from benchmark.manifest import Manifest
-from benchmark.references import smallthinker as ref
-from distributedvolunteercomputing_tpu.models import get_model, smallthinker
+from distributedvolunteercomputing_tpu.models import get_model
 from experiments.olmoe_reference_check import _diff2, _norm2, rel_err
+
+# variants that score the experts otherwise, so they pick their own routes
+OWN_ROUTES = ("router_after_attention", "softmax_for_sigmoid")
+# a leaf's group, by the first of these keys its path holds
+GROUPS = {"router": "router", "experts": "experts", "conv": "conv", "mlp": "mlp",
+          **dict.fromkeys(("wq", "wk", "wv", "wo", "q_norm", "k_norm"), "attention")}
+
+
+def by_group(got, want):
+    """Relative error of ``got`` against ``want`` over each group of leaves."""
+    num, den = {}, {}
+    for (path, n), d in zip(jax.tree_util.tree_leaves_with_path(_diff2(got, want)),
+                            jax.tree_util.tree_leaves(_norm2(want))):
+        keys = [getattr(k, "key", None) for k in path]
+        group = next((name for key, name in GROUPS.items() if key in keys), "other")
+        num[group] = num.get(group, 0.0) + float(n)
+        den[group] = den.get(group, 0.0) + float(d)
+    return {g: math.sqrt(num[g] / den[g]) for g in sorted(num) if den[g] > 0}
+
+
+def seeded_state(params, seed, bias, norm_scale, qk_scale):
+    """``params`` a few thousand steps in, by the three options (module docstring)."""
+    def leaf(path, a):
+        key = getattr(path[-1], "key", None)
+        inside = [getattr(k, "key", None) for k in path]
+        if key == "bias" and bias:
+            return bias * jax.random.normal(jax.random.PRNGKey(seed), a.shape)
+        if ("q_norm" in inside or "k_norm" in inside) and norm_scale:
+            return a + norm_scale * jax.random.normal(jax.random.PRNGKey(seed + 1), a.shape)
+        if key in ("wq", "wk") and qk_scale != 1.0:
+            return a * qk_scale
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, params)
 
 
 def main() -> int:
@@ -56,10 +104,18 @@ def main() -> int:
     ap.add_argument("--first-seed", type=int, default=3500003301)
     ap.add_argument("--seq-len", type=int, default=None)
     ap.add_argument("--left-out", action="store_true")
-    ap.add_argument("--out", default="chiprun_out/smallthinker_reference_check.json")
+    ap.add_argument("--variants", default="", help="--left-out: these of the reference's VARIANTS (comma-separated)")
+    ap.add_argument("--bias", type=float, default=0.0, help="scale of a seeded selection bias (0: as initialised)")
+    ap.add_argument("--norm-scale", type=float, default=0.0,
+                    help="spread of the q and k norms' seeded scales about 1 (0: as initialised)")
+    ap.add_argument("--qk-scale", type=float, default=1.0, help="the q and k projections times this")
+    ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
     cfg = Manifest().load_config(args.config)
+    ref = references.load(cfg["family"])
+    args.out = args.out or f"chiprun_out/{cfg['family']}_reference_check.json"
+    seeded = bool(args.bias or args.norm_scale or args.qk_scale != 1.0)
     rc = dict(cfg["reference_check"])
     if args.seq_len:
         rc["seq_len"] = args.seq_len
@@ -70,11 +126,12 @@ def main() -> int:
     device = {"platform": dev.platform, "kind": dev.device_kind}
     hp = ref.hyper(cfg)
     n_routed = bundle.config.n_experts
+    model = sys.modules[type(bundle.config).__module__]  # the program's module of this family
 
     @jax.jit
     def program(params, tokens, targets):
         def f(p):
-            loss, _, routes = smallthinker.loss_and_routes(
+            loss, _, routes = model.loss_and_routes(
                 p, {"tokens": tokens, "targets": targets}, bundle.config)
             return loss, routes
 
@@ -100,6 +157,8 @@ def main() -> int:
     rows = []
     for seed in range(args.first_seed, args.first_seed + args.seeds):
         params = jax.jit(bundle.init)(jax.random.PRNGKey(seed))
+        if seeded:
+            params = seeded_state(params, seed, args.bias, args.norm_scale, args.qk_scale)
         arrays = datagen.lm_arrays(seed + 0x5EED, 1, rc["seq_len"], sizes["vocab"])
         tok, tgt = arrays["tokens"][:1], arrays["targets"][:1]
         lp, gp, mine = program(params, tok, tgt)
@@ -109,6 +168,9 @@ def main() -> int:
         rec = {"seed": seed, "seq_len": rc["seq_len"], "loss_program": float(lp),
                "flipped_share": float(1.0 - (oh(mine) & oh(np.asarray(theirs))).sum() / mine.size)}
         rec["loss_reference"] = float(lr)
+        if seeded:
+            rec["seeded"] = {"bias": args.bias, "norm_scale": args.norm_scale, "qk_scale": args.qk_scale}
+            rec["by_group_no_routes"] = by_group(gp, gr)
         rec["grad_rel_err_no_routes"] = rel_err(gp, gr)
         rec["loss_abs_err_no_routes"] = abs(float(lp) - float(lr))
         per_leaf = sorted(
@@ -122,6 +184,8 @@ def main() -> int:
         lr2, gr2 = reference(params, tok, tgt, routes)
         rec["grad_rel_err_with_routes"] = rel_err(gp, gr2)
         rec["loss_abs_err_with_routes"] = abs(float(lp) - float(lr2))
+        if seeded:
+            rec["by_group_with_routes"] = by_group(gp, gr2)
         del gp
         for name, to in rounded.items():
             lq, gq = reference(to(params), tok, tgt, routes)
@@ -129,11 +193,12 @@ def main() -> int:
             rec[f"{name}_params_loss_abs_err"] = abs(float(lq) - float(lr2))
             del gq
         if args.left_out and seed == args.first_seed:
-            for variant in ref.VARIANTS:
-                own_routes = variant == "router_after_attention"  # another input picks other experts
-                lo, go = reference_with(variant)(params, tok, tgt, None if own_routes else routes)
+            for variant in (args.variants.split(",") if args.variants else ref.VARIANTS):
+                lo, go = reference_with(variant)(params, tok, tgt, None if variant in OWN_ROUTES else routes)
                 rec[f"{variant}_grad_rel_err"] = rel_err(go, gr2)
                 rec[f"{variant}_loss_abs_err"] = abs(float(lo) - float(lr2))
+                if seeded:
+                    rec[f"{variant}_by_group"] = by_group(go, gr2)
                 del go
         del gr2
         rec["device"] = device

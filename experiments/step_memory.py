@@ -3,8 +3,9 @@
 Compiles the step of every cell at its real depth and batch for a v5e that is
 described and not attached (no chip: `tests/test_tpu_compile.py` has the
 method) and prints one JSON line a cell with ``memory_analysis()``'s bytes:
-the arguments (state and batch), the temporaries, and their sum with the
-outputs that do not alias an argument. ``memory_stats()`` on the chip counts
+the arguments (state and batch), the temporaries, their sum with the
+outputs that do not alias an argument, and the size of the step's entry in the
+compile cache (the serialized executable under zstd). ``memory_stats()`` on the chip counts
 live buffers and not a program's temporaries (PERF.md section 4), so this is
 where a change to what the step keeps (``models/common.remat_layer``) shows.
 
@@ -26,24 +27,31 @@ CELLS = {
     "laguna-solo-8k": ("laguna_xs2", 1, 1, 4, {"n_layers": 5, "experts_held": 16, "vocab": 12544}),
     "smallthinker-solo-16k": ("smallthinker_21b_a3b", 1, 1, 2,
                               {"n_layers": 4, "experts_held": 8, "vocab": 18992}),
+    "lfm2-solo-8k": ("lfm2_24b_a2b", 1, 1, 4,
+                     {"n_layers": None, "layer_types": "conv,full_attention,conv,conv,conv", "dense_layers": 1,
+                      "experts_held": 8, "vocab": 8192}),
 }
 
 
 def main(cells) -> None:
+    import zstandard
     from jax.experimental import topologies
+    from jax.experimental.serialize_executable import serialize
 
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention, short_conv
     from distributedvolunteercomputing_tpu.utils import jaxenv
-    from tests.test_tpu_compile import _kernel_calls, _lowered_step
+    from tests.test_tpu_compile import _kernel_calls, _kernel_names, _lowered_step
 
     # the program's backend checks answer as they do on the chip
     jaxenv.tpu_backend = pallas_attention.tpu_backend = moe_dispatch.tpu_backend = lambda: True
+    short_conv.tpu_backend = jaxenv.tpu_backend
     moe_dispatch.grouped_matmul_impl = lambda m, k, n: "megablox"
     v5e = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
     for cell in cells:
         model, dp, tp, batch, overrides = CELLS[cell]
         compiled = _lowered_step(v5e, model, dp, tp, batch, **overrides).compile()
         mem = compiled.memory_analysis()
+        calls = _kernel_calls(compiled.as_text())
         print(json.dumps({
             "cell": cell,
             "argument_bytes": mem.argument_size_in_bytes,
@@ -51,7 +59,10 @@ def main(cells) -> None:
             "total_bytes": mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes,
             "code_bytes": mem.generated_code_size_in_bytes,
-            "kernel_calls": len(_kernel_calls(compiled.as_text())),
+            # what the step's entry of the compile cache weighs: the chip's own listing read the same (PR 39)
+            "cache_entry_MB": round(len(zstandard.ZstdCompressor().compress(serialize(compiled)[0])) / 1e6, 2),
+            "kernel_calls": len(calls),
+            "kernel_names": sorted({n.split(".")[0] for n in _kernel_names(calls)}),
         }), flush=True)
 
 

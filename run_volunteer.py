@@ -242,6 +242,9 @@ def main() -> None:
                     help="TASK-constant seed for the initial params; must match "
                          "across the swarm (for LoRA it pins the shared frozen base)")
     ap.add_argument("--steps", type=int, default=1000)
+    ap.add_argument("--warmup-steps", type=int, default=0,
+                    help="the learning rate rises linearly from 0 over this many steps, "
+                         "then follows the cosine decay over --steps (0: it starts at --lr)")
     ap.add_argument("--target-loss", type=float, default=None)
     ap.add_argument("--target-mode", default="stop", choices=("stop", "record"),
                     help="stop: end the run at --target-loss; record: train "
@@ -365,6 +368,7 @@ def main() -> None:
         init_seed=args.init_seed,
         param_dtype=args.param_dtype,
         steps=args.steps,
+        warmup_steps=args.warmup_steps,
         target_loss=args.target_loss,
         target_mode=args.target_mode,
         eval_every=args.eval_every,

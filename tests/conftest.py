@@ -196,6 +196,20 @@ _YARDSTICK_PINS = (
     ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_smallthinker.py",
      "asserts that SmallThinker's three metrics end per_layer; PR 37 appended the seven lifecycle.* metrics "
      "after them (checked, seven places up, in test_yardstick_lifecycle.py)"),
+    # PR 39 (lfm2-24b-a2b, lfm2-solo-8k, conv.device_ms / conv.roofline / moe.bias_spread; the cell appended to
+    # attention.device_ms's list): tests/yardstick/test_yardstick_lfm2.py asserts what each of these asserted,
+    # three metrics, a cell and a configuration up.
+    ("test_configuration_file_is_what_the_program_runs", "[lfm2-24b-a2b]",
+     "asserts reduced == []; lfm2-24b-a2b lists its cut (checked in test_yardstick_lfm2.py)"),
+    ("test_manifest_holds_the_seven_start_up_metrics_at_its_end", "test_yardstick_lifecycle.py",
+     "asserts that the seven lifecycle.* metrics end per_layer, over six cells and five configurations; PR 39 "
+     "appended three metrics, a cell and a configuration (checked in test_yardstick_lfm2.py)"),
+    ("test_manifest_tail_as_the_smallthinker_test_asserted_it_seven_places_up", "test_yardstick_lifecycle.py",
+     "asserts that smallthinker-solo-16k ends the cells and every appended list; lfm2-solo-8k was appended "
+     "after it (checked in test_yardstick_lfm2.py)"),
+    ("test_manifest_lists_the_metric_in_the_solo_cells", "test_yardstick_attention_metric.py",
+     "asserts that attention.device_ms lists the two gpt2 cells alone; lfm2-solo-8k, whose only attention "
+     "kernels are the full-causal ones it reads, was appended (checked in test_yardstick_lfm2.py)"),
 )
 
 

@@ -92,17 +92,18 @@ def test_flash_fwd_bwd_compiles(v5e, shape, blocks):
 def as_on_the_chip(monkeypatch):
     """The program's backend checks answer as they do on the chip: bf16
     compute, auto routing to the kernel, compiled (not interpreted) kernels."""
-    from distributedvolunteercomputing_tpu.ops import pallas_attention
+    from distributedvolunteercomputing_tpu.ops import pallas_attention, short_conv
     from distributedvolunteercomputing_tpu.utils import jaxenv
 
     monkeypatch.setattr(jaxenv, "tpu_backend", lambda: True)
     monkeypatch.setattr(pallas_attention, "tpu_backend", lambda: True)
+    monkeypatch.setattr(short_conv, "tpu_backend", lambda: True)
 
 
 def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides):
     """The sharded train step of ``model`` (two layers: the scanned block
-    appears once whatever the depth), lowered for a dp x tp mesh of described
-    chips."""
+    appears once whatever the depth; None for a model whose depth is its list
+    of layers), lowered for a dp x tp mesh of described chips."""
     from distributedvolunteercomputing_tpu.models import get_model
     from distributedvolunteercomputing_tpu.parallel import sharding
     from distributedvolunteercomputing_tpu.parallel.mesh import AXES
@@ -114,7 +115,9 @@ def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int =
     from distributedvolunteercomputing_tpu.training.steps import TrainState
 
     mesh = Mesh(np.asarray(v5e[: dp * tp]).reshape(dp, 1, 1, 1, tp), AXES)
-    bundle = get_model(model, n_layers=n_layers, **overrides)
+    if n_layers is not None:
+        overrides = dict(overrides, n_layers=n_layers)
+    bundle = get_model(model, **overrides)
     tx = make_optimizer("adam", lr=1e-3)
     abstract = jax.eval_shape(
         lambda: TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(1))
@@ -139,7 +142,7 @@ def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int =
     batch_shape = jax.tree_util.tree_map(
         lambda x: placed(x, sharding.batch_sharding(mesh)), batch_shape
     )
-    step = make_sharded_train_step(bundle.loss_fn, tx, mesh)
+    step = make_sharded_train_step(bundle.loss_fn, tx, mesh, stepped=bundle.stepped)
     with mesh:
         return step.lower(state, batch_shape)
 
@@ -490,3 +493,95 @@ def test_ring_all_gather_kernel(ring_folder):
     )
     text = f._build_gather().lower(acc).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("t,d,head_dim,group", [(8192, 2048, 64, 4)])
+def test_lfm2_kernel_blocks_at_head_dim_64_group_4_and_8k(t, d, head_dim, group):
+    """lfm2-solo-8k's attention shape (32 query heads over 8 key/value heads of
+    64, T=8,192): a head of 64 pads to 128 lanes in VMEM, so the whole-head
+    kernels hold what Laguna's full layers hold at 128, 1,024 x 1,024 blocks,
+    two thirds of the budget, and the next doubling still fits; the
+    convolution's kernel takes blocks of 256 positions over all 2,048 channels."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention, short_conv
+
+    assert pallas_attention.choose_blocks(t, t, head_dim, jnp.bfloat16) == (1024, 1024)
+    used = pallas_attention.vmem_bytes(t, t, head_dim, jnp.bfloat16, 1024, 1024)
+    assert used == pallas_attention.vmem_bytes(t, t, 128, jnp.bfloat16, 1024, 1024)  # 64 pads to a lane tile
+    assert 0.6 * pallas_attention.VMEM_BUDGET_BYTES < used <= 0.7 * pallas_attention.VMEM_BUDGET_BYTES
+    assert pallas_attention.choose_blocks(2 * t, 2 * t, head_dim, jnp.bfloat16) == (1024, 1024)
+    assert pallas_attention.choose_blocks(4 * t, 4 * t, head_dim, jnp.bfloat16) is None
+    assert short_conv.choose_block(t, d, 3) == short_conv.BLOCK_T == 256
+    # a block's buffers, double: the streams in and their cotangent out, the output's cotangent, two edges each
+    block = 2 * (2 * 256 * 3 * d + 256 * d + 3 * 16 * 3 * d + 16 * d) * 2
+    assert block < 0.25 * short_conv._VMEM_LIMIT
+
+
+def test_short_conv_fwd_bwd_compiles_without_a_copy(v5e):
+    """The convolution's two kernels at the cell's shape ([4, 8192, 6144]
+    bfloat16 streams, float32 taps), by the chip's own compiler, under the
+    names a trace shows; the program around them holds no temporary (the
+    streams are read where the projection left them, the cotangent is written
+    once)."""
+    from distributedvolunteercomputing_tpu.ops import short_conv
+
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((4, 8192, 3 * 2048), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one)
+    dy = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=one)
+
+    def fwd_bwd(x, w, dy):
+        y, vjp = jax.vjp(lambda a, b: short_conv.short_conv_kernel(a, b, 256, False), x, w)
+        return y, vjp(dy)
+
+    compiled = jax.jit(fwd_bwd).lower(x, w, dy).compile()
+    names = _kernel_names(_kernel_calls(compiled.as_text()))
+    # differentiated alone the names carry the transform's (``jvp_dvc_short_conv_fwd_``); inside the step
+    # they are bare, which the step's test and the benchmark's readers match
+    assert len(names) == 2 and sum("dvc_short_conv_fwd" in n for n in names) == 1
+    assert sum("dvc_short_conv_bwd" in n for n in names) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
+    """lfm2-solo-8k's step (published layers 0 and 2-5 of LFM2-24B-A2B at its
+    published widths, eight of 64 experts held, an eighth of the vocabulary,
+    4 x 8,192 tokens): three traced layer shapes, the dense conv layer, the
+    attention expert layer and ONE scanned conv expert layer for the three.
+    The attention layer takes the flash kernel at head dim 64 with four query
+    heads a key/value head, forward and backward only (its recomputed forward
+    holds none: ``remat_layer`` kept the output and row statistics); each conv
+    layer shape runs the convolution's kernel forward, again in the recomputed
+    forward (it keeps nothing of its mixer) and backward: two shapes, six
+    calls; the share's grouped matmuls see the bounded chunk of 49,152 rows,
+    seven a traced expert layer. That it compiles says it fits the chip."""
+    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    seen = []
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
+        (impl, t, d, window, kv_heads)))
+    try:
+        compiled = _lowered_step(
+            v5e, "lfm2_24b_a2b", 1, 1, 4, n_layers=None, layer_types="conv,full_attention,conv,conv,conv",
+            dense_layers=1, experts_held=8, vocab=8192).compile()
+    finally:
+        attention.set_core_observer(None)
+    assert set(seen) == {("flash", 8192, 64, None, 8)}, seen
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    names = _kernel_names(calls)
+    flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
+    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd"], flash
+    assert all("bf16[4,32,8192,64]" in ln and "bf16[4,8,8192,64]" in ln for ln in calls if "dvc_flash_" in ln)
+    conv = sorted(n.split(".")[0] for n in names if n.startswith("dvc_short_conv"))
+    assert conv == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4, conv
+    assert all("bf16[4,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
+    rows = moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64)
+    assert rows == 49152  # three times the even share of 16,384
+    assert f"[{rows},2048]" in text and "[131072,2048]" not in text
+    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=2, rows=rows, d=2048, f=1536)
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert total < 13.5e9, total
+    assert mem.temp_size_in_bytes <= 7.40e9, mem.temp_size_in_bytes  # PR 39: 7.359e9
