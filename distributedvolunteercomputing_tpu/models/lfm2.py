@@ -54,7 +54,13 @@ The cut a chip makes without touching a width, as ``models/laguna.py``:
 ``layer_types`` with ``dense_layers`` (which of the published layers run),
 ``experts_held`` with ``expert_offset`` (``ops/moe_dispatch.share_glu_experts``
 computes the held experts' part of the sum; what the others would add is left
-out), ``vocab`` (a slice: embedding, tied head and loss over the slice).
+out), ``vocab`` (a slice: embedding, tied head and loss over the slice). The
+share's dispatch takes a chunk of the even share and a quarter
+(``SHARE_ROWS_SLACK``: ``ops/moe_dispatch.SHARE_ROWS_SLACK_LEVELLED``, not the
+dispatch's default of three even shares), because every expert layer built
+here carries the stepped bias, which keeps the held experts near their even
+share; a layer that is sent more runs another chunk, and the step's metrics
+count those (``moe_chunks_extra``: 0 on a levelled step).
 
 Layers of one kind that follow each other are one RUN: ``params["blocks"]`` is
 a list of runs, each a tree stacked on a leading layer axis, and a run of
@@ -82,7 +88,9 @@ from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads,
 )
-from distributedvolunteercomputing_tpu.ops.moe_dispatch import share_glu_experts
+from distributedvolunteercomputing_tpu.ops.moe_dispatch import (
+    SHARE_ROWS_SLACK_LEVELLED, share_glu_experts, share_rows_bound,
+)
 from distributedvolunteercomputing_tpu.ops.short_conv import short_conv
 from distributedvolunteercomputing_tpu.models.registry import SteppedLeaves
 
@@ -91,6 +99,10 @@ DENSE, SPARSE = "dense", "sparse"
 # the step's metric that ``stepped`` reads: per expert layer, how many of the
 # step's assignments chose each expert ``[L_sparse, E]``
 COUNTS = "moe_expert_counts"
+
+# Rows a chunk of the share's dispatch holds over the share's even part: this
+# model's routers are levelled by the bias the step moves, every one of them.
+SHARE_ROWS_SLACK = SHARE_ROWS_SLACK_LEVELLED
 
 PUBLISHED_LAYER_TYPES = (CONV, CONV) + (FULL, CONV, CONV, CONV) * 9 + (FULL, CONV)
 
@@ -246,6 +258,7 @@ def _zero_stats() -> Dict[str, jax.Array]:
         "rows_held": zero,   # assignments on held experts, all layers
         "rows_moved": zero,  # rows the dispatch gathered to its grouped matmuls, all layers
         "dropped": zero,     # held assignments no grouped matmul computed
+        "chunks_extra": zero,  # chunks the dispatch ran beyond one a layer, all layers
     }
 
 
@@ -291,9 +304,10 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: LFM
         ex = p["experts"]
         y, group_sizes, dropped, moved, _ = share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
-            cfg.expert_offset, cfg.n_experts,
+            cfg.expert_offset, cfg.n_experts, slack=SHARE_ROWS_SLACK,
         )
         x = x + y.reshape(b, t, d)
+        cap = share_rows_bound(b * t, cfg.top_k, cfg.experts_held, cfg.n_experts, SHARE_ROWS_SLACK)
         chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1))
         load = group_sizes.astype(jnp.float32)
         stats = {
@@ -301,6 +315,8 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: LFM
             "rows_held": stats["rows_held"] + jnp.sum(load),
             "rows_moved": stats["rows_moved"] + moved.astype(jnp.float32),
             "dropped": stats["dropped"] + dropped.astype(jnp.float32),
+            # ``moved`` is whole chunks of ``cap`` rows (all S k, at most one chunk's, with every expert held)
+            "chunks_extra": stats["chunks_extra"] + ((moved + cap - 1) // cap - 1).astype(jnp.float32),
         }
     return x, stats, (top_idx, chosen)
 
@@ -361,6 +377,8 @@ def loss_and_routes(
         "moe_rows_held": stats["rows_held"],
         "moe_rows_moved": stats["rows_moved"],
         "moe_dropped": stats["dropped"],
+        # how many chunks beyond one a layer the levelled bound cost this step
+        "moe_chunks_extra": stats["chunks_extra"],
         # the selection biases this step chose with, over layers and experts,
         # and how many of them the step's rule then moves
         "moe_bias_max": jnp.max(bias),

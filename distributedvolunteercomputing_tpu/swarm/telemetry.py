@@ -948,6 +948,11 @@ class Telemetry:
                 self.registry.gauge(
                     "swarm.moe_experts_held", "experts this chip holds of those routed over",
                 ).set(float(attrs.get("experts_held", 0.0)))
+            if "moe_chunks_extra" in attrs:  # a model whose chunk is sized for a levelled router
+                self.registry.gauge(
+                    "swarm.moe_chunks_extra",
+                    "chunks the share's dispatch ran beyond one a layer, at the last log point",
+                ).set(float(attrs["moe_chunks_extra"]))
             if "moe_act_zero_share" in attrs:  # a model with ReLU-gated experts
                 self.registry.gauge(
                     "swarm.moe_act_zero_share",
@@ -962,8 +967,8 @@ class Telemetry:
         dispatch = self._counts_by("swarm.moe_dispatch", "impl")
         if dispatch:
             out["dispatch"] = dispatch
-        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held", "experts_held",
-                    "act_zero_share"):
+        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held", "chunks_extra",
+                    "experts_held", "act_zero_share"):
             v = self.registry.gauge(f"swarm.moe_{key}").value()
             if v is not None:
                 out[key] = v

@@ -67,8 +67,8 @@ COPIES_IN_FLIGHT = 2
 # ``moe.route`` span), and how often, in steps, the loop notes them between
 # its log points.
 ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
-                "moe_rows_moved", "moe_act_zero_share", "moe_bias_max", "moe_bias_min",
-                "moe_bias_moved", "aux_loss", "lm_loss")
+                "moe_rows_moved", "moe_chunks_extra", "moe_act_zero_share", "moe_bias_max",
+                "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's

@@ -32,9 +32,11 @@ KINDS = ["conv", "full_attention", "conv", "conv", "conv"]
 
 @pytest.fixture(autouse=True)
 def tight_chunks(monkeypatch):
-    """A chunk a quarter over the even share (the program's is three times
-    it): at these sizes several chunks run."""
-    monkeypatch.setattr(moe_dispatch, "SHARE_ROWS_SLACK", 1.25)
+    """A chunk a quarter over the even share, whatever the model's own bound
+    becomes (it is the levelled router's, ``models/lfm2.SHARE_ROWS_SLACK``, and
+    no longer the dispatch's default): under matrices scaled up and a seeded
+    bias several chunks run at these sizes."""
+    monkeypatch.setattr(lfm2, "SHARE_ROWS_SLACK", 1.25)
 
 
 def seeded(scale: float = 3.0, bias: float = 0.0, **overrides):
@@ -456,6 +458,46 @@ def test_the_shares_add_up_to_the_uncut_layer():
         assert float(jnp.max(jnp.abs(y - whole))) > 1e-2  # one share is not the whole
 
 
+def lifted(params, cfg, n):
+    """``params`` with the selection bias of the first ``n`` held experts of
+    every expert layer at 2 (over any sigmoid score: every token chooses them)."""
+    first = cfg.expert_offset
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x.at[..., first:first + n].set(2.0) if lfm2.is_bias(path) else x, params)
+
+
+@pytest.mark.parametrize("n_lifted,chunks_a_layer", [(0, 1), (2, 2), (3, 3)])
+def test_the_levelled_bound_computes_what_three_even_shares_compute(monkeypatch, n_lifted, chunks_a_layer):
+    """The chunk of a levelled router (1.25 even shares: ``moe_dispatch.
+    SHARE_ROWS_SLACK_LEVELLED``, which the model passes) against the dispatch's
+    default of three: the loss, every leaf's gradient, the experts' counts and
+    ``moe_dropped`` = 0 agree, under an even load (one chunk a layer,
+    ``moe_chunks_extra`` 0) and under a load that a seeded selection bias
+    pushes past the smaller bound, so that every layer runs two, then three
+    chunks where three even shares hold it in one, then two."""
+    assert lfm2.SHARE_ROWS_SLACK == moe_dispatch.SHARE_ROWS_SLACK_LEVELLED == 1.25  # the fixture's is the program's
+    bundle, params, batch = seeded()
+    c = bundle.config
+    params = lifted(params, c, n_lifted)
+    read = {}
+    for slack in (moe_dispatch.SHARE_ROWS_SLACK_LEVELLED, moe_dispatch.SHARE_ROWS_SLACK):
+        monkeypatch.setattr(lfm2, "SHARE_ROWS_SLACK", slack)
+        (loss, m), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(params, batch, None)
+        cap = moe_dispatch.share_rows_bound(batch["tokens"].size, c.top_k, c.experts_held, c.n_experts, slack)
+        held = np.asarray(m[lfm2.COUNTS])[:, c.expert_offset:c.expert_offset + c.experts_held].sum(axis=1)
+        chunks = np.ceil(held / cap)
+        assert float(m["moe_dropped"]) == 0.0 and float(m["moe_rows_held"]) == held.sum()
+        assert float(m["moe_chunks_extra"]) == (chunks - 1).sum()  # what the load says
+        assert float(m["moe_rows_moved"]) == chunks.sum() * cap
+        read[slack] = (float(loss), grads, np.asarray(m[lfm2.COUNTS]), (cap, list(chunks)))
+    (loss_a, grads_a, counts_a, ran_a), (loss_b, grads_b, counts_b, ran_b) = read.values()
+    assert ran_a == (160, [chunks_a_layer] * 4)           # the levelled bound over an even 128 rows
+    assert ran_b == (384, [max(1, chunks_a_layer - 1)] * 4)  # three even shares
+    assert loss_a == pytest.approx(loss_b, rel=1e-6)
+    np.testing.assert_array_equal(counts_a, counts_b)
+    assert max(leaf_errors(grads_a, grads_b).values()) < 1e-5
+
+
 # -- attention at head dim 64, four query heads a key/value head, each head normed ---------
 
 
@@ -556,7 +598,7 @@ def test_train_loop_records_the_bias_and_the_mixers_on_the_route_span():
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
     from distributedvolunteercomputing_tpu.training.trainer import ROUTING_KEYS, Trainer
 
-    assert {"moe_bias_max", "moe_bias_min", "moe_bias_moved"} <= set(ROUTING_KEYS)
+    assert {"moe_bias_max", "moe_bias_min", "moe_bias_moved", "moe_chunks_extra"} <= set(ROUTING_KEYS)
     tel = Telemetry(peer_id="v", enabled=True)
     tr = Trainer(get_model("lfm2_24b_a2b", **OVERRIDES), batch_size=2, optimizer="adam", lr=1e-3, tracer=tel.tracer)
     summary = tr.run(steps=11, log_every=5)
@@ -570,7 +612,40 @@ def test_train_loop_records_the_bias_and_the_mixers_on_the_route_span():
         assert a["aux_loss"] == 0.0 and a["lm_loss"] > 0
         assert a["moe_load_mean"] == 2 * 64 * 4 / 16 and 0 < a["moe_rows_held"] <= 4 * 2 * 64 * 4
         assert 0 < a["moe_bias_moved"] <= 4 * 16
+        # an untrained router under the rule is level: one chunk of 1.25 even shares a layer
+        assert a["moe_chunks_extra"] == 0.0 and a["moe_rows_moved"] == 4 * 160
         # the biases the step CHOSE with: after n - 1 steps none is further than (n - 1) gamma from zero
         reach = (a["step"] - 1) * 0.001
         assert -reach - 1e-7 <= a["moe_bias_min"] < 0 < a["moe_bias_max"] <= reach + 1e-7
     assert tel.summary()["moe"]["dropped_total"] == 0.0
+    assert tel.summary()["moe"]["chunks_extra"] == 0.0
+    assert tel.registry.gauge("swarm.moe_chunks_extra").value() == 0.0
+
+
+def test_a_load_over_the_bound_shows_on_the_route_span_and_the_gauge():
+    """The same loop with three held experts' biases lifted over every score:
+    each of the four expert layers runs three chunks, and the span's
+    ``moe_chunks_extra`` and the volunteer's gauge say 8."""
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+    from distributedvolunteercomputing_tpu.training.trainer import Trainer
+
+    tel = Telemetry(peer_id="v", enabled=True)
+    tr = Trainer(get_model("lfm2_24b_a2b", **OVERRIDES), batch_size=2, optimizer="adam", lr=1e-3, tracer=tel.tracer)
+    tr.state = dataclasses.replace(tr.state, params=lifted(tr.state.params, tr.bundle.config, 3))
+    tr.run(steps=5, log_every=5)
+    (route,) = [s for s in tel.tracer.spans() if s["name"] == "moe.route"]
+    assert route["attrs"]["moe_chunks_extra"] == 8.0 and route["attrs"]["moe_dropped"] == 0.0
+    assert route["attrs"]["moe_rows_moved"] == 12 * 160
+    assert tel.summary()["moe"]["chunks_extra"] == 8.0
+
+
+@pytest.mark.parametrize("family,carries", [("laguna", False), ("smallthinker", False), ("lfm2", True)])
+def test_only_a_levelled_routers_metrics_count_extra_chunks(family, carries):
+    """Laguna's and SmallThinker's steps are the programs they were: their
+    chunk is sized for a router that collapses, and their metrics do not carry
+    the levelled bound's counter. LFM2's do."""
+    cfg = Manifest().load_config(f"tiny-rehearsal-{family}")
+    bundle = get_model(cfg["registry_model"], **cfg["model_overrides"])
+    batch = bundle.make_batch(jax.random.PRNGKey(0), 2)
+    _, metrics = jax.eval_shape(bundle.loss_fn, bundle.init(jax.random.PRNGKey(1)), batch, None)
+    assert ("moe_chunks_extra" in metrics) == carries and "moe_rows_moved" in metrics

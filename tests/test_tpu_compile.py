@@ -552,8 +552,12 @@ def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip
     holds none: ``remat_layer`` kept the output and row statistics); each conv
     layer shape runs the convolution's kernel forward, again in the recomputed
     forward (it keeps nothing of its mixer) and backward: two shapes, six
-    calls; the share's grouped matmuls see the bounded chunk of 49,152 rows,
-    seven a traced expert layer. That it compiles says it fits the chip."""
+    calls; the share's grouped matmuls see the levelled router's chunk of 20,480
+    rows (the even share of 16,384 and a quarter: the model passes
+    ``SHARE_ROWS_SLACK_LEVELLED``), never the dispatch's default of 49,152 nor
+    the S x k = 131,072, seven a traced expert layer. That it compiles says it
+    fits the chip; its temporaries are 6.186e9 (7.359e9 at 49,152 rows, PR 39)."""
+    from distributedvolunteercomputing_tpu.models import lfm2
     from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -577,11 +581,12 @@ def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip
     conv = sorted(n.split(".")[0] for n in names if n.startswith("dvc_short_conv"))
     assert conv == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4, conv
     assert all("bf16[4,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
-    rows = moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64)
-    assert rows == 49152  # three times the even share of 16,384
-    assert f"[{rows},2048]" in text and "[131072,2048]" not in text
+    assert moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64) == 49152  # the dispatch's default, three even shares
+    rows = moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64, lfm2.SHARE_ROWS_SLACK)
+    assert rows == 20480  # the even share of 16,384 and a quarter: forty megablox row tiles
+    assert f"[{rows},2048]" in text and "[131072,2048]" not in text and "[49152,2048]" not in text
     _share_chunks_hold_seven_grouped_matmuls(names, text, layers=2, rows=rows, d=2048, f=1536)
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert total < 13.5e9, total
-    assert mem.temp_size_in_bytes <= 7.40e9, mem.temp_size_in_bytes  # PR 39: 7.359e9
+    assert mem.temp_size_in_bytes <= 6.22e9, mem.temp_size_in_bytes  # 6.186e9; at 49,152 rows (PR 39) 7.359e9
