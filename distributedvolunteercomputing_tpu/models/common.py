@@ -138,15 +138,26 @@ def stacked_init(layer_init, rng: jax.Array, n_layers: int) -> Params:
 def remat_layer(body, layers: int = 1):
     """``body`` (one layer) rematerialised in the backward pass: the one place
     that decides what a rematerialised layer keeps. Beside the layer's inputs
-    that is what the flash kernel produced in it, its output and log-sum-exp
-    rows (``pallas_attention.KEPT_NAMES``: a layer input's worth of memory for
-    a kernel call's worth of time), so the recomputed forward runs no kernel.
-    A layer on the XLA core names nothing and its program is a bare
-    ``jax.checkpoint``'s. ``layers``: how many layers run this one trace (a
-    scan's length), for ``swarm.remat_kept``'s bytes."""
+    that is what costs a layer input's worth of memory and a long wait to make
+    again: what the flash kernel produced in the layer, its output and
+    log-sum-exp rows (``pallas_attention.KEPT_NAMES``: a kernel call's worth of
+    time), so the recomputed forward runs no kernel; and, where the traced step's
+    mesh divides the layer over ``tp``, the row-parallel attention product's
+    result after its sum over ``tp`` (``attention_ops.keep_tp_reduced``, named
+    in ``gpt2._block``: an all-reduce over the link that nothing hides), so
+    the recomputed forward holds no collective. On one chip that second keep
+    would save a product of 0.14 ms for the same memory and is not made: a
+    layer there keeps what it kept, and one on the XLA core names nothing and
+    its program is a bare ``jax.checkpoint``'s. The expert models' ``wo`` /
+    ``w_down`` results are not named: no cell runs them over ``tp`` and their
+    room at 8,192-16,384 tokens a row is smaller than this stack. ``layers``:
+    how many layers run this one trace (a scan's length), for
+    ``swarm.remat_kept``'s bytes."""
     from distributedvolunteercomputing_tpu.ops.pallas_attention import KEPT_NAMES
 
-    fn = jax.checkpoint(body, policy=jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES))
+    # a name that no value of the trace carries (TP_REDUCED where tp is 1) keeps nothing
+    policy = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES, attention_ops.TP_REDUCED)
+    fn = jax.checkpoint(body, policy=policy)
 
     def layer(*args):
         with attention_ops.keeping_kernel_results(layers):

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
+from distributedvolunteercomputing_tpu.ops.attention import attention_core, keep_tp_reduced, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,7 +85,7 @@ def _block(p: common.Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
         h = common.layernorm(p["ln1"], x)
         q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
         attn = merge_heads(attention_core(q, k, v, causal=True))
-        x = x + common.dense(p["attn_out"], attn)
+        x = x + keep_tp_reduced(common.dense(p["attn_out"], attn))
     with jax.named_scope("mlp"):
         h = common.layernorm(p["ln2"], x)
         h = common.dense(p["mlp_out"], jax.nn.gelu(common.dense(p["mlp_in"], h)))

@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # Active sequence-parallel context: (mesh, axis_name, impl) or None. When
 # set, the attention core routes to the chosen SP implementation so the
@@ -109,14 +110,18 @@ def observe_qkv(layout: str, tp: int) -> None:
         _qkv_observer(layout, tp)
 
 
-# Called once per TRACED rematerialised layer whose checkpoint kept a kernel's
-# results (models/common.remat_layer) with (layers, bytes): how many layers run
-# that one trace (a scan's length) and the bytes one chip keeps of them a step
+# Called once per TRACED rematerialised layer whose checkpoint kept something
+# (models/common.remat_layer) with (layers, bytes): how many layers run that
+# one trace (a scan's length) and the bytes one chip keeps of them a step
 # (swarm.remat_kept, beside swarm.attention_core). A layer that ran the XLA
-# core names nothing, keeps nothing and is not reported.
+# core on one chip names nothing, keeps nothing and is not reported.
 _kept_observer = None
-# While remat_layer traces a body: the bytes kept of each kernel call in it.
+# While remat_layer traces a body: the bytes kept of each kernel call in it
+# and of each value named by ``keep_tp_reduced``.
 _kept_ctx = None
+# The name of a row-parallel product's result after its sum over ``tp``, kept
+# by a rematerialised layer where the traced step's mesh divides the layer.
+TP_REDUCED = "tp_reduced"
 
 
 def set_kept_observer(fn) -> None:
@@ -128,7 +133,8 @@ def set_kept_observer(fn) -> None:
 def keeping_kernel_results(layers: int):
     """Around the trace of one rematerialised layer body that ``layers``
     layers run: gathers what ``_flash_per_shard`` says a chip keeps of each
-    kernel call in it and reports the sum."""
+    kernel call in it, and ``keep_tp_reduced`` of each value it named, and
+    reports the sum."""
     global _kept_ctx
     prev, _kept_ctx = _kept_ctx, []
     kept = _kept_ctx
@@ -156,6 +162,21 @@ def heads_tp() -> int:
     if _mesh_ctx is None or "tp" in jax.sharding.get_abstract_mesh().manual_axes:
         return 1
     return _mesh_ctx.shape.get("tp", 1)
+
+
+def keep_tp_reduced(x: jax.Array) -> jax.Array:
+    """``x``, a row-parallel product's [B, T, d] result after its sum over
+    ``tp``, named so that the layer's checkpoint keeps it: rebuilding it in the
+    backward costs the product and an all-reduce over the link that no compute
+    hides. With ``tp`` 1 the same keep would buy a product alone for a layer
+    input's worth of memory, so ``x`` comes back unnamed and the program is
+    the text it was."""
+    if heads_tp() == 1:
+        return x
+    if _kept_ctx is not None:  # a chip's share: the rows of its dp (and sp) part, whole over tp
+        rows_over = _mesh_ctx.shape.get("dp", 1) * _mesh_ctx.shape.get("sp", 1)
+        _kept_ctx.append(x.size * jnp.dtype(x.dtype).itemsize // rows_over)
+    return checkpoint_name(x, TP_REDUCED)
 
 
 def constrain_in_step(x: jax.Array, spec) -> jax.Array:

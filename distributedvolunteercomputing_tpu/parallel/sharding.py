@@ -14,11 +14,15 @@ generic rule table that covers every transformer in the zoo:
 
 What a transformer layer costs over ``tp`` in a rematerialised train step
 (read off the compiled ``dp=2,tp=2`` gpt2_large step, tests/test_tpu_compile.py,
-and its trace, PERF.md section 5): FIVE all-reduces of [B/dp, T, d]
-activations — after attn_out and mlp_out in the forward, after attn_out again
-in the recomputed forward, and for the input cotangent of each column-parallel
-product (mlp_in, qkv) in the backward — none of them hidden behind compute.
-That is the layout's price. The fused ``qkv`` leaf adds no activation traffic
+and its trace, PERF.md section 5): FOUR all-reduces of [B/dp, T, d]
+activations — after attn_out and mlp_out in the forward, and for the input
+cotangent of each column-parallel product (mlp_in, qkv) in the backward —
+none of them hidden behind compute. That is the layout's price. A fifth, after
+attn_out again in the backward's recomputed forward, was ours and is gone:
+where ``tp`` > 1 the layer's checkpoint keeps attn_out's reduced result
+(``models/common.remat_layer``, ``ops/attention.keep_tp_reduced``; mlp_out's
+needs nothing, the recomputed forward never wants the layer's output). The
+fused ``qkv`` leaf adds no activation traffic
 to it, though its stored sharding alone would: [d, 3d] cut over ``tp`` on 3d
 in contiguous parts gives chip 0 of a pair all of q and half of k, while
 attention runs heads 0..H/tp-1 of each of q, k and v there. The stored leaf

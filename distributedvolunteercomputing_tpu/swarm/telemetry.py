@@ -887,15 +887,17 @@ class Telemetry:
             ).inc(layout=layout, tp=str(tp))
 
     def count_remat_kept(self, layers: int, nbytes: int) -> None:
-        """One TRACED rematerialised layer kept the attention kernel's output
-        and row statistics (models/common.remat_layer through ops.attention's
-        ``set_kept_observer``): ``layers`` layers run that trace, and
-        ``nbytes`` is what one chip keeps of them a step. A layer on the XLA
-        core keeps nothing and never comes here."""
+        """One TRACED rematerialised layer kept something beside its input
+        (models/common.remat_layer through ops.attention's
+        ``set_kept_observer``): the attention kernel's output and row
+        statistics and, where the step's mesh divides the layer over ``tp``,
+        the reduced attention output product. ``layers`` layers run that
+        trace, and ``nbytes`` is what one chip keeps of them a step. A layer
+        on one chip's XLA core keeps nothing and never comes here."""
         if self.enabled:
             self.registry.counter(
                 "swarm.remat_kept",
-                "traced rematerialised layers that kept the attention kernel's results",
+                "traced rematerialised layers whose checkpoint kept a kernel's or a tp sum's result",
             ).inc(layers=str(layers), bytes=str(nbytes))
 
     def count_moe_dispatch(
@@ -986,9 +988,10 @@ class Telemetry:
         return self._counts_by("swarm.attention_core", "impl")
 
     def remat_kept(self) -> Dict[str, int]:
-        """Traced rematerialised layers whose checkpoint kept the attention
-        kernel's results, and the bytes one chip keeps of them a step; empty
-        where every layer ran the XLA core."""
+        """Traced rematerialised layers whose checkpoint kept something (the
+        attention kernel's results; over ``tp`` the reduced attention output
+        product), and the bytes one chip keeps of them a step; empty where
+        every layer ran the XLA core on one chip."""
         recs = self.registry.counter("swarm.remat_kept")._scrape()["values"]
         if not recs:
             return {}

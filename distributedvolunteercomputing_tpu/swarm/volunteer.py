@@ -1337,8 +1337,9 @@ class Volunteer:
             # whose tp divides the heads, {"fused": n} elsewhere).
             self.summary["qkv_projection"] = self.telemetry.qkv_projections()
             # Traced rematerialised layers that kept the attention kernel's
-            # output and row statistics, and the bytes a chip keeps of them a
-            # step ({} where every layer ran the XLA core).
+            # output and row statistics (over tp also the reduced attention
+            # output product), and the bytes a chip keeps of them a step ({}
+            # where every layer ran the XLA core on one chip).
             self.summary["remat_kept"] = self.telemetry.remat_kept()
             moe = self.telemetry.moe()
             if moe:

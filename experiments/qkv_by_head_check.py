@@ -15,8 +15,13 @@ error of the whole gradient and of its worst leaf). With ``--reference
 benchmark/configs/gpt2-large.json`` (and ``--batch 2 --seq-len 512``, the
 reference check's size: the float32 reference keeps every T x T tensor) both
 are also held to the benchmark's plain float32 reference, which says how much
-of their distance from each other is bf16 rounding met in another order. One
-JSON line, also in ``chiprun_out/qkv_by_head_check.json``.
+of their distance from each other is bf16 rounding met in another order. Since
+PR 43 the step's trace also keeps ``attn_out``'s result after its sum over
+``tp`` (``ops/attention.keep_tp_reduced``; ``heads_tp`` 1 turns that off with
+the by-head form), which the reference check sees as little: a third trace,
+by head under a bare ``jax.checkpoint`` a layer (nothing kept), says under
+``kept_against_bare`` what the keep alone moves. One JSON line, also in
+``chiprun_out/qkv_by_head_check.json``.
 
 On the CPU (``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``
 with ``--override n_layers=2 --override d_model=64 --override n_heads=4
@@ -35,7 +40,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-from distributedvolunteercomputing_tpu.models import get_model
+from distributedvolunteercomputing_tpu.models import common, get_model
 from distributedvolunteercomputing_tpu.ops import attention
 from distributedvolunteercomputing_tpu.parallel import make_mesh, make_param_shardings
 from distributedvolunteercomputing_tpu.parallel.mesh import parse_mesh_spec
@@ -68,8 +73,10 @@ def main() -> int:
     )
     layouts = []
     attention.set_qkv_observer(lambda layout, tp: layouts.append(layout))
+    kept = []  # a chip's bytes kept a step, one entry a trace that kept something
+    attention.set_kept_observer(lambda layers, nbytes: kept.append(nbytes))
 
-    def make_loss_and_grads():  # a function of its own each time: jit caches traces by function
+    def make_loss_and_grads(bundle=bundle):  # a new function each time: jit caches traces by function
         def loss_and_grads(params, batch):
             with attention.step_mesh(mesh):  # what parallel/train_step.py announces
                 return jax.value_and_grad(
@@ -92,6 +99,7 @@ def main() -> int:
         loss_fused, grads_fused = make_loss_and_grads()(params, batch)
     finally:
         attention.heads_tp = heads_tp
+    traced_fused = layouts[len(traced_by_head):]
 
     def rel_errs(got, want):
         num, den = compare(got, want)
@@ -101,14 +109,28 @@ def main() -> int:
                 max(math.sqrt(n / d) for n, d in zip(num, den) if d > 0))
 
     grad_rel_err, worst_leaf_rel_err = rel_errs(grads_head, grads_fused)
+    if not args.reference:
+        del grads_fused  # room for the third trace's gradients beside the step's temporaries
+    # by head again with nothing kept (a new bundle: a traced loss is cached)
+    remat_layer, common.remat_layer = common.remat_layer, lambda body, layers=1: jax.checkpoint(body)
+    try:
+        loss_bare, grads_bare = make_loss_and_grads(get_model(args.model, **overrides))(params, batch)
+    finally:
+        common.remat_layer = remat_layer
+    kept_grad_rel_err, kept_worst_leaf_rel_err = rel_errs(grads_head, grads_bare)
+    del grads_bare
     dev = jax.devices()[0]
     result = {
         "model": args.model, "mesh": args.mesh, "batch": args.batch, "seed": args.seed,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count()},
-        "traced": {"by_head": traced_by_head, "fused": layouts[len(traced_by_head):]},
+        "traced": {"by_head": traced_by_head, "fused": traced_fused, "kept_bytes": kept},
         "loss_by_head": float(loss_head), "loss_fused": float(loss_fused),
         "loss_abs_err": abs(float(loss_head) - float(loss_fused)),
         "grad_rel_err": grad_rel_err, "worst_leaf_rel_err": worst_leaf_rel_err,
+        "kept_against_bare": {
+            "loss_abs_err": abs(float(loss_head) - float(loss_bare)),
+            "grad_rel_err": kept_grad_rel_err, "worst_leaf_rel_err": kept_worst_leaf_rel_err,
+        },
     }
     if args.reference:
         from benchmark import references
@@ -133,6 +155,8 @@ def main() -> int:
     ok = (
         set(traced_by_head) == {"by_head"} and set(result["traced"]["fused"]) == {"fused"}
         and result["loss_abs_err"] <= 0.005 and result["grad_rel_err"] <= 0.04
+        and result["kept_against_bare"]["loss_abs_err"] <= 0.005
+        and result["kept_against_bare"]["grad_rel_err"] <= 0.04
     )
     return 0 if ok else 1
 

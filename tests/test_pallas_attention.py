@@ -489,7 +489,8 @@ def test_remat_layer_runs_the_forward_kernel_once(model, kept, bare, bare_checkp
 def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkpoint):
     """dp=2, tp=2: the kept names pass through ``_flash_per_shard``'s
     ``shard_map``; one forward kernel call a layer, the bare checkpoint's
-    gradients bit for bit, and the bytes counted are one chip's share."""
+    gradients bit for bit, and the bytes counted are one chip's share of the
+    kernel's results and of the reduced attention product."""
     from jax.sharding import Mesh
 
     from distributedvolunteercomputing_tpu.ops import attention
@@ -515,9 +516,11 @@ def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkp
     assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]
     assert sorted(bare) == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_fwd"]
     _assert_bit_equal(got, want)
-    # a chip's share, [2, 2, 32, 16] of [4, 4, 32, 16], for two layers; by the one
-    # trace of the helper's layer, never by the bare checkpoint's
-    assert seen == [(2, 2 * _kept(2, 2, 32))]
+    # a chip's share, [2, 2, 32, 16] of [4, 4, 32, 16], for two layers, and, tp
+    # dividing the layer, the [2, 32, 64] f32 rows of the reduced attention
+    # product (``keep_tp_reduced``); by the one trace of the helper's layer,
+    # never by the bare checkpoint's
+    assert seen == [(2, 2 * (_kept(2, 2, 32) + 2 * 32 * 64 * 4))]
 
 
 def test_remat_layer_on_the_xla_core_is_the_bare_checkpoint(bare_checkpoint):

@@ -172,6 +172,67 @@ def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, m
     )
 
 
+@pytest.mark.parametrize("dp,tp", [(2, 2), (4, 1)], ids=["dp2-tp2", "dp4-tp1"])
+def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, monkeypatch, dp, tp):
+    """A rematerialised gpt2 step on a mesh: where ``tp`` divides the layer the
+    block's checkpoint keeps the row-parallel attention product's result after
+    its sum over ``tp`` (so the backward repeats no all-reduce) and
+    ``swarm.remat_kept`` counts a chip's share of it; where ``tp`` is 1 nothing
+    is named or counted (the CPU's layers run the XLA core). Either way the
+    loss and every gradient leaf (plain SGD at lr 1) are those of the same
+    step under a bare ``jax.checkpoint`` and of the single-device step."""
+    import optax
+
+    from distributedvolunteercomputing_tpu.models import common
+    from distributedvolunteercomputing_tpu.ops import attention
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+
+    NAMED = f"name={attention.TP_REDUCED}]"
+    cfg = dict(_LM, n_heads=4, remat=True)
+    tx = optax.sgd(1.0)
+    bundle = get_model("gpt2_small", **cfg)
+    params, batch = bundle.init(jax.random.PRNGKey(0)), bundle.make_batch(jax.random.PRNGKey(1), 8)
+
+    def step_on(mesh):  # a new bundle a step: a traced loss is cached
+        bundle = get_model("gpt2_small", **cfg)
+        state = TrainState.create(params, tx, jax.random.PRNGKey(2))
+        if mesh is None:
+            step, put = make_train_step(bundle.loss_fn, tx, donate=False), batch
+        else:
+            state, _ = shard_train_state(state, mesh, tx)
+            step, put = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False), put_batch(batch, mesh)
+        text = str(step.trace(state, put).jaxpr)  # the name is in the jaxpr, not in what it lowers to
+        state, metrics = step(state, put)
+        grads = jax.tree_util.tree_map(lambda a, b: np.asarray(a) - np.asarray(b), params, state.params)
+        return float(metrics["loss"]), grads, text
+
+    tel = Telemetry(peer_id="t")
+    attention.set_kept_observer(tel.count_remat_kept)
+    try:
+        ref_loss, ref_grads, ref_text = step_on(None)
+        assert tel.remat_kept() == {} and NAMED not in ref_text
+        loss, grads, text = step_on(make_mesh(dp=dp, tp=tp))
+        kept = tel.remat_kept()
+    finally:
+        attention.set_kept_observer(None)
+    if tp > 1:
+        # one scanned block traced for both layers: a chip's [8 / dp, 32, 96] f32 each
+        assert kept == {"traced_layers": 1, "bytes_a_step": 2 * (8 // dp) * 32 * 96 * 4}
+        assert text.count(NAMED) == 1
+    else:
+        assert kept == {} and NAMED not in text
+    monkeypatch.setattr(common, "remat_layer", lambda body, layers=1: jax.checkpoint(body))
+    bare_loss, bare_grads, bare_text = step_on(make_mesh(dp=dp, tp=tp))
+    # what the name buys: the backward's recomputed forward runs no attention output product
+    assert bare_text.count("dot_general") - text.count("dot_general") == (1 if tp > 1 else 0)
+
+    assert float(np.abs(ref_grads["blocks"]["attn_out"]["w"]).max()) > 1e-4  # not vacuous
+    for want_loss, want in ((bare_loss, bare_grads), (ref_loss, ref_grads)):
+        np.testing.assert_allclose(loss, want_loss, rtol=2e-4)
+        jax.tree_util.tree_map(
+            lambda got, ref: np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-5), grads, want)
+
+
 def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):
     """Inside a ``shard_map`` that has made ``tp`` manual the trace sees one
     chip's share: nothing is left to divide, the projection keeps its fused

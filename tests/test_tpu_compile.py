@@ -100,10 +100,10 @@ def as_on_the_chip(monkeypatch):
     monkeypatch.setattr(short_conv, "tpu_backend", lambda: True)
 
 
-def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides):
+def _traced_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides):
     """The sharded train step of ``model`` (two layers: the scanned block
     appears once whatever the depth; None for a model whose depth is its list
-    of layers), lowered for a dp x tp mesh of described chips."""
+    of layers), traced for a dp x tp mesh of described chips."""
     from distributedvolunteercomputing_tpu.models import get_model
     from distributedvolunteercomputing_tpu.parallel import sharding
     from distributedvolunteercomputing_tpu.parallel.mesh import AXES
@@ -144,7 +144,12 @@ def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int =
     )
     step = make_sharded_train_step(bundle.loss_fn, tx, mesh, stepped=bundle.stepped)
     with mesh:
-        return step.lower(state, batch_shape)
+        return step.trace(state, batch_shape)
+
+
+def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides):
+    """That step, lowered."""
+    return _traced_step(v5e, model, dp, tp, batch, n_layers, **overrides).lower()
 
 
 def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides) -> str:
@@ -357,35 +362,74 @@ def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
 
 
 def _collectives(text: str):
-    """(kind, result type) of every collective in a compiled program."""
+    """(result type, kind, line) of every collective in a compiled program."""
     import re
 
-    return re.findall(
-        r"= (.+?) (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
-        r"(?:-start)?\(", text,
-    )
+    kinds = r"(all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)(?:-start)?\("
+    return [(m.group(1), m.group(2), ln) for ln in text.splitlines()
+            if (m := re.search(r"= (.+?) " + kinds, ln))]
 
 
 def test_four_chip_step_moves_no_activation_for_qkv(v5e, as_on_the_chip):
     """large-solo-4chip's step: q, k and v are born on the chip that runs their
     heads (``common.qkv_heads`` divides the projection by head over tp), so no
     all-to-all and no collective-permute carries them or their cotangents. What
-    crosses a link at an activation's size is Megatron's price alone: the
-    all-reduce after each row-parallel product (attn_out and mlp_out forward,
-    attn_out in the recomputed forward) and before each column-parallel one in
-    the backward (mlp_in, qkv). The kernel still sees its own 10 heads, forward
-    and backward."""
+    crosses a link at an activation's size is Megatron's price alone, FOUR
+    all-reduces a layer: after each row-parallel product in the forward
+    (attn_out, mlp_out) and before each column-parallel one in the backward
+    (mlp_in, qkv). The backward's recomputed forward moves no activation: the
+    layer's checkpoint kept attn_out's reduced result (``common.remat_layer``,
+    ``attention.keep_tp_reduced``). The kernel still sees its own 10 heads,
+    forward and backward."""
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
     found = _collectives(text)
-    kinds = {kind for _, kind in found}
+    kinds = {kind for _, kind, _ in found}
     assert "all-to-all" not in kinds and "collective-permute" not in kinds, kinds
-    activation_sized = [kind for result, kind in found if re.search(r"\[16,1024,\d+\]", result)]
-    assert activation_sized and set(activation_sized) == {"all-reduce"}, activation_sized
-    assert len(activation_sized) <= 5, activation_sized
+    activation_sized = [(kind, ln) for result, kind, ln in found if re.search(r"\[16,1024,\d+\]", result)]
+    assert [kind for kind, _ in activation_sized] == ["all-reduce"] * 4, activation_sized
+    # two in the forward scan's body, two in the backward's, by the scopes their products carry
+    where = sorted(re.search(r'op_name="jit\(step\)/(.*?)/while/.*/(attention|mlp)/', ln).groups()
+                   for _, ln in activation_sized)
+    assert where == [("jvp()", "attention"), ("jvp()", "mlp"),
+                     ("transpose(jvp())", "attention"), ("transpose(jvp())", "mlp")], where
+    # the recomputed forward, by the name its operations carry: no all-reduce and nothing of an
+    # activation's size; what it still gathers is the qkv leaf's head-aligned view (weight and bias)
+    recomputed = [(result, kind) for result, kind, ln in found if "rematted_computation" in ln]
+    assert recomputed and {kind for _, kind in recomputed} == {"all-gather"}, recomputed
+    assert all(re.match(r"bf16\[(1280,3840|\d+,1,1920)\]", result) for result, _ in recomputed), recomputed
     calls = _kernel_calls(text)
     assert len(calls) == 2 and all("bf16[16,10,1024,64]" in ln for ln in calls)
+    assert not [ln for ln in calls if "rematted_computation" in ln]
+
+
+def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
+    """medium-solo's step traces with no ``tp`` to divide a layer: the block's
+    checkpoint names the kernel's two results and nothing else (on one chip
+    attn_out's result costs a product to make again, not an all-reduce), and
+    ``swarm.remat_kept`` reads the kernel's bytes. The four-chip step names
+    attn_out's reduced result once a traced block and counts a chip's
+    ``bf16[16,1024,1280]`` of it a layer beside the kernel's."""
+    import re
+
+    from distributedvolunteercomputing_tpu.ops import attention
+
+    def kept(*model_mesh_batch):
+        seen = []
+        attention.set_kept_observer(lambda layers, nbytes: seen.append((layers, nbytes)))
+        try:
+            jaxpr = str(_traced_step(v5e, *model_mesh_batch).jaxpr)
+        finally:
+            attention.set_kept_observer(None)
+        return sorted(re.findall(r"name\[name=(\w+)\]", jaxpr)), seen
+
+    def kernel(b, h, t):  # the output's rows at 128 lanes of bf16 and a float32 log-sum-exp a row
+        return b * h * t * (128 * 2 + 4)
+
+    assert kept("gpt2_medium", 1, 1, 16) == (["attention_lse", "attention_out"], [(2, 2 * kernel(16, 16, 1024))])
+    assert kept("gpt2_large", 2, 2, 32) == (
+        ["attention_lse", "attention_out", "tp_reduced"], [(2, 2 * (kernel(16, 10, 1024) + 41_943_040))])
 
 
 def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):
