@@ -29,7 +29,11 @@ three even shares and more where a plain router may collapse onto a few held
 experts (``SHARE_ROWS_SLACK``; the model passes its ``slack``). Rows
 return to their tokens by gathers too: within a chunk the rows are put in token
 order, a token's (at most k) rows summed along their run, and each token takes
-its run's total. The run's sum is one pass over the ``[rows, d]`` buffer
+its run's total. Of a chunk's rows the grouped matmuls are handed the held
+ones only (``_handed_sizes``): the rest are moved with the chunk, multiplied by
+nobody and, on the megablox path, never written, so whatever they hold is
+selected away where a sum could meet it (``_combine``'s gather, the router
+weights' cotangent). The run's sum is one pass over the ``[rows, d]`` buffer
 (``_combine``): in tiles of 128 rows, a 0/1 matrix made from the sorted tokens
 ("same token, not later") times the tile on the MXU, accumulated in float32 and
 rounded once; what a run left behind a tile's edge is summed from the tiles'
@@ -118,7 +122,14 @@ def grouped_matmul_impl(m: int, k: int, n: int) -> str:
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> jax.Array:
     """``[M, K] x [E, K, N] -> [M, N]``: rows ``sum(group_sizes[:e]) ..`` of
-    ``lhs`` times ``rhs[e]``. ``group_sizes`` (int32 ``[E]``) sums to M."""
+    ``lhs`` times ``rhs[e]``. ``group_sizes`` (int32 ``[E]``) sums to at most
+    M; rows past the sum are not computed and, on the megablox path, not
+    written: the kernel's grid ends with the last group's row tile, so those
+    rows of the result hold whatever the buffer held (NaN and Inf included;
+    ``ragged_dot`` returns zeros there). Both gradients follow the same sizes:
+    the rows' cotangent is unwritten past the sum, and the stack's sums no row
+    outside its group (``tgmm`` selects them away). A caller that hands fewer
+    than M rows selects the others out of whatever reads the result."""
     m, k, n = lhs.shape[0], rhs.shape[1], rhs.shape[2]
     if grouped_matmul_impl(m, k, n) == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
@@ -235,8 +246,14 @@ def dropless_glu_experts(
 # an expert that takes EVERY token still fits one chunk beside the other held
 # experts' even share (S + (held - 1) S k / E <= 3 S k held / E wherever
 # k (2 held + 1) >= E: 8 x 33 against 256), the collapse a router in training
-# shows, so a step runs one chunk a layer and costs the same whatever the router
-# does short of that; past it, more chunks. MEASURED (PR 33, TPU v5e,
+# shows, so a step runs one chunk a layer whatever the router does short of
+# that; past it, more chunks. What a chunk costs is in two parts since PR 46:
+# the rows are MOVED whole (gathers, elementwise passes and the run's sum
+# cover all its rows: that part does not follow the router), and
+# MULTIPLIED only as far as they are held (the grouped products end with the
+# held rows, ``_handed_sizes``: that part follows what the router sends, so a
+# step's time moves with its routing by the products' share of the padding:
+# PERF.md, Findings of PR 46). MEASURED (PR 33, TPU v5e,
 # laguna-solo-8k, Adam at 1e-3 from scratch; experiments/laguna_routing_trace.py;
 # PERF.md, Findings of PR 33): over a layer's first 45 steps sixteen of 256
 # experts are sent between a tenth and twice the even share (33,456 rows at
@@ -297,11 +314,14 @@ def _token_runs(tok: jax.Array, valid: jax.Array, n_tokens: int):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _spread(x: jax.Array, where, k: int) -> jax.Array:
-    """``[S, d] -> [R, d]``: row ``i`` is token ``tok[i]``'s where the row is a
-    held assignment and zero elsewhere (zero in, zero out of a gated expert).
+    """``[S, d] -> [R, d]``: row ``i`` is token ``tok[i]``'s, held assignment
+    or not. Nothing zeroes the rows that are not held (a select here is a pass
+    over the buffer of its own on the chip): they lie past the sizes' sum, and
+    all that reads this result is a grouped product (forward the gate-up
+    product, backward the down product's two transposes), which neither
+    multiplies nor sums a row outside every group.
     ``where`` = (tok, valid, *_token_runs)."""
-    tok, valid = where[:2]
-    return jnp.where(valid[:, None], x[tok], jnp.zeros((), x.dtype))
+    return x[where[0]]
 
 
 # Rows of one tile of the product that sums a token's run (``_combine``), where
@@ -344,7 +364,18 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
     if r % t or not k - 1 <= _RUN_CARRY <= t:
         raise ValueError(f"a run of {k} rows in {r} rows: tiles of {t} with {_RUN_CARRY} carried do not cover it")
     dtype = rows.dtype
-    tiles = rows[perm].reshape(r // t, t, d)
+    # Rows that are not held may hold anything (``grouped_matmul`` does not
+    # write them), and the 0/1 product below would turn one NaN into a tile of
+    # them. They sort last, and are selected away INSIDE the gather, on its
+    # index: their positions take the first sorted row, a held one wherever
+    # the chunk holds any, whose numbers meet zeros of the 0/1 matrix like any
+    # other token's. (A select on the gathered ``[R, d]`` rows is a pass of
+    # its own on the chip, 1.6 ms over ``[104448, 2560]``: PERF.md, Findings of
+    # PR 46.) What those positions sum to is read by nobody: no token's last
+    # row is among them, and in a chunk that holds nothing, where the first
+    # row is no better than the rest, no token has a last row at all.
+    held = tok_sorted < last_pos.shape[0]
+    tiles = rows[jnp.where(held, perm, perm[0])].reshape(r // t, t, d)
     tok = tok_sorted.reshape(r // t, t)
     first = tok[:, 0]
     # what a tile's trailing rows leave to the next tile's first run: [R / T, d], float32, none to the first
@@ -360,7 +391,8 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
     return jnp.where((last_pos < r)[:, None], last, jnp.zeros((), dtype))
 
 
-# The two are each other's transpose: neither differentiates into a scatter-add.
+# The two are each other's transpose on the held rows, which are all that the
+# grouped products between them read: neither differentiates into a scatter-add.
 _spread.defvjp(lambda x, where, k: (_spread(x, where, k), where),
                lambda k, where, g: (_combine(g, where, k), None))
 _combine.defvjp(lambda rows, where, k: (_combine(rows, where, k), where),
@@ -369,12 +401,11 @@ _combine.defvjp(lambda rows, where, k: (_combine(rows, where, k), where),
 
 def _handed_sizes(group_sizes: jax.Array, lo, rows: int) -> jax.Array:
     """What the grouped matmuls over sorted assignments ``lo .. lo + rows`` are
-    handed: each held expert's rows among them, and the rows past the held
-    ones (zeros) on the last expert's account, so that the sizes sum to
-    ``rows`` and every row is written."""
+    handed: each held expert's rows among them and nothing more. The sizes sum
+    to the held rows of the chunk, which come first in it; the rows past them
+    are moved with the chunk and multiplied by nobody (``grouped_matmul``)."""
     ends = jnp.cumsum(group_sizes)
-    sizes = jnp.clip(ends, lo, lo + rows) - jnp.clip(ends - group_sizes, lo, lo + rows)
-    return sizes.at[-1].add(rows - jnp.sum(sizes))
+    return jnp.clip(ends, lo, lo + rows) - jnp.clip(ends - group_sizes, lo, lo + rows)
 
 
 def _share_rows(x, gates, w_gate_up, w_down, plan, lo, rows: int, k: int, act: str):
@@ -403,7 +434,10 @@ def _share_rows(x, gates, w_gate_up, w_down, plan, lo, rows: int, k: int, act: s
     where = (tok, valid, *_token_runs(tok, valid, x.shape[0]))
     gate_up = grouped_matmul(_spread(x, where, k), w_gate_up, sizes)
     hidden, zeros = _gated(gate_up[:, :f], gate_up[:, f:], valid, act)
-    hidden = hidden * gates[order][:, None].astype(hidden.dtype)
+    # a select, not a product: its transpose hands an assignment that is not
+    # this share's a cotangent of exactly zero, whatever its unwritten row holds
+    weight = jnp.where(valid, gates[order], 0.0)
+    hidden = hidden * weight[:, None].astype(hidden.dtype)
     y = _combine(grouped_matmul(hidden, w_down, sizes), where, k)
     row = jnp.arange(rows, dtype=jnp.int32)
     computed_by = jnp.sum((row[:, None] >= jnp.cumsum(sizes)[None, :]).astype(jnp.int32), axis=1)
@@ -534,8 +568,8 @@ def share_glu_experts(
                          "it was made with another slack")
     # At least one chunk, also for a layer that sends this share nothing: a
     # router in its first steps does that in whole layers (all tokens on the
-    # same eight experts, none of them held), and a step's cost should not
-    # follow it. A settled router never does.
+    # same eight experts, none of them held). That chunk costs its moves only:
+    # its sizes are all zero, and the grouped products visit no row tile.
     n_chunks = jnp.maximum((plan.n_held + cap - 1) // cap, 1)
     # gate and up side by side, once a layer; their gradients come back apart
     # through the concatenate, so the parameter tree keeps the published two

@@ -942,11 +942,18 @@ class Telemetry:
             dropped.set((dropped.value() or 0.0) + float(attrs.get("moe_dropped", 0.0)))
             held_rows = float(attrs.get("moe_rows_held") or 0.0)
             if held_rows > 0:  # a chip that holds a share of the experts
+                moved = float(attrs.get("moe_rows_moved", 0.0))
                 self.registry.gauge(
                     "swarm.moe_rows_moved_over_held",
                     "rows the dispatch gathered over the assignments on held experts, "
                     "at the last log point",
-                ).set(float(attrs.get("moe_rows_moved", 0.0)) / held_rows)
+                ).set(moved / held_rows)
+                if moved > 0:
+                    self.registry.gauge(
+                        "swarm.moe_rows_multiplied_over_moved",
+                        "share of the rows the dispatch gathered that its grouped products "
+                        "ran (the held assignments handed to their expert), at the last log point",
+                    ).set((held_rows - float(attrs.get("moe_dropped", 0.0))) / moved)
                 self.registry.gauge(
                     "swarm.moe_experts_held", "experts this chip holds of those routed over",
                 ).set(float(attrs.get("experts_held", 0.0)))
@@ -964,13 +971,13 @@ class Telemetry:
 
     def moe(self) -> dict:
         """Routing of a sparse-expert model: traced dispatches per grouped
-        matmul, and the two gauges; empty for a dense model."""
+        matmul, and the routing gauges; empty for a dense model."""
         out: Dict[str, Any] = {}
         dispatch = self._counts_by("swarm.moe_dispatch", "impl")
         if dispatch:
             out["dispatch"] = dispatch
-        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held", "chunks_extra",
-                    "experts_held", "act_zero_share"):
+        for key in ("load_max_over_mean", "dropped_total", "rows_moved_over_held",
+                    "rows_multiplied_over_moved", "chunks_extra", "experts_held", "act_zero_share"):
             v = self.registry.gauge(f"swarm.moe_{key}").value()
             if v is not None:
                 out[key] = v

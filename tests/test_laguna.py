@@ -554,4 +554,7 @@ def test_train_loop_records_the_share_on_the_route_span_and_as_gauges():
     moe = tel.summary()["moe"]
     last = routes[-1]["attrs"]
     assert moe["rows_moved_over_held"] == pytest.approx(last["moe_rows_moved"] / last["moe_rows_held"])
+    # what the grouped products ran of it: the held rows, not the chunk (1.0 before PR 46)
+    assert moe["rows_multiplied_over_moved"] == pytest.approx(last["moe_rows_held"] / last["moe_rows_moved"])
+    assert 0 < moe["rows_multiplied_over_moved"] <= 1
     assert moe["experts_held"] == 4.0 and moe["dropped_total"] == 0.0

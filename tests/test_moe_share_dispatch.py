@@ -131,6 +131,155 @@ def test_every_gradient_is_the_per_token_sums(act, chunks):
     assert not np.asarray(got[0][0]).any()  # a token with no held row takes no gradient from this share
 
 
+# -- the grouped products end at the held rows (PR 46) ----------------------------------
+
+
+def dense_groups(lhs, rhs, sizes):
+    """Group by group, ``lhs[start:end] @ rhs[e]`` in float32; the rows past
+    the sizes' sum are left zero."""
+    out = np.zeros((lhs.shape[0], rhs.shape[2]), np.float32)
+    start = 0
+    for e, n in enumerate(np.asarray(sizes)):
+        out[start:start + n] = np.asarray(lhs[start:start + n], np.float32) @ np.asarray(rhs[e], np.float32)
+        start += n
+    return out
+
+
+@pytest.mark.parametrize("sizes", [(300, 0, 200, 100), (0, 0, 0, 0), (512, 512, 500, 12)],
+                         ids=["ends_inside_the_second_tile", "holds_nothing", "sums_to_every_row"])
+@pytest.mark.parametrize("impl", ["ragged_dot", "megablox"])
+def test_rows_past_the_sizes_sum_are_neither_read_nor_summed(impl, sizes, monkeypatch):
+    """``grouped_matmul`` with sizes that sum to less than M, the rows of
+    ``lhs`` past the sum (and of the result's cotangent) set to NaN and Inf:
+    the rows inside the groups, the rows' cotangent inside the groups and the
+    whole stack's cotangent equal the dense product group by group, on
+    ``ragged_dot`` and on megablox (interpreted here: three row tiles of 512,
+    of which the sizes end inside the second, visit none, or fill all)."""
+    m, k, n = 1536, 128, 128
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda *a: impl)
+    ks = jax.random.split(jax.random.PRNGKey(46), 3)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    total = int(sizes.sum())
+    inside = (jnp.arange(m) < total)[:, None]
+    poison = jnp.where(jnp.arange(m)[:, None] % 2 == 0, jnp.nan, jnp.inf)
+    lhs = jax.random.normal(ks[0], (m, k))
+    rhs = jax.random.normal(ks[1], (len(sizes), k, n)) * 0.1
+    cot = jax.random.normal(ks[2], (m, n))
+    out, pull = jax.vjp(lambda a, b: moe_dispatch.grouped_matmul(a, b, sizes),
+                        jnp.where(inside, lhs, poison), rhs)
+    d_lhs, d_rhs = pull(jnp.where(inside, cot, poison))
+    np.testing.assert_allclose(np.asarray(out)[:total], dense_groups(lhs, rhs, sizes)[:total], rtol=1e-4, atol=1e-4)
+    want_lhs = dense_groups(cot, jnp.swapaxes(rhs, 1, 2), sizes)
+    np.testing.assert_allclose(np.asarray(d_lhs)[:total], want_lhs[:total], rtol=1e-4, atol=1e-4)
+    ends = np.concatenate([[0], np.cumsum(np.asarray(sizes))])
+    want_rhs = np.stack([np.asarray(lhs[a:b], np.float32).T @ np.asarray(cot[a:b], np.float32)
+                         for a, b in zip(ends[:-1], ends[1:])])
+    np.testing.assert_allclose(np.asarray(d_rhs), want_rhs, rtol=1e-4, atol=1e-3)
+
+
+@pytest.mark.parametrize("lo,want", [(0, (5, 0, 3, 0)), (8, (0, 0, 6, 2)), (16, (0, 0, 0, 1)), (24, (0, 0, 0, 0))],
+                         ids=["first_chunk", "a_group_over_both_edges", "last_chunk", "past_the_held_rows"])
+def test_the_sizes_handed_are_the_held_rows_of_the_window(lo, want):
+    """``_handed_sizes`` over sorted assignments ``lo .. lo + 8`` of held
+    groups of 5, 0, 9 and 3: each held expert's rows inside the window (the
+    third group lies over the first chunk's end and the second's), summing to
+    the held rows there (8, 8, 1, 0), never topped up to the window's 8."""
+    group_sizes = jnp.asarray([5, 0, 9, 3], jnp.int32)
+    got = np.asarray(moe_dispatch._handed_sizes(group_sizes, jnp.int32(lo), 8))
+    keys = np.repeat(np.arange(5), [5, 0, 9, 3, 15])  # the sorted keys, 4 for those not held
+    assert tuple(got) == want == tuple(np.bincount(keys[lo:lo + 8], minlength=5)[:4])
+    assert got.sum() == max(0, min(17 - lo, 8))
+
+
+def grouped_matmul_that_leaves_rows_unwritten(lhs, rhs, sizes):
+    """Megablox as this dispatch must take it, on ``ragged_dot``: it reads no
+    row outside the groups, forward or backward, and every row past the
+    sizes' sum of what it returns (the result, and the rows' cotangent) holds
+    NaN, as memory nobody wrote may."""
+
+    def inside(a):
+        return (jnp.arange(a.shape[0]) < jnp.sum(sizes))[:, None]
+
+    def product(a, b):
+        return jax.lax.ragged_dot(jnp.where(inside(a), a, 0.0), b, sizes)
+
+    @jax.custom_vjp
+    def unwritten(a, b):
+        return jnp.where(inside(a), product(a, b), jnp.nan)
+
+    def backward(res, cot):
+        d_a, d_b = jax.vjp(product, *res)[1](jnp.where(inside(cot), cot, 0.0))
+        return jnp.where(inside(d_a), d_a, jnp.nan), d_b
+
+    unwritten.defvjp(lambda a, b: (unwritten(a, b), (a, b)), backward)
+    return unwritten(lhs, rhs)
+
+
+def routes_of(fill):
+    """(routes [S, K], slack) for a chunk filled as ``fill`` says."""
+    _, idx, _, _ = inputs()
+    if fill == "nothing_held":       # every choice on an expert this share does not hold
+        return jnp.where((idx >= OFFSET) & (idx < OFFSET + HELD), idx + HELD, idx) % E, 3.0
+    if fill == "to_the_last_row":    # one held choice a token: 48 held rows in one chunk of 48
+        others = jnp.asarray([0, 1, 9], jnp.int32)
+        return jnp.concatenate([OFFSET + jnp.arange(S, dtype=jnp.int32)[:, None] % HELD,
+                                jnp.tile(others, (S, 1))], axis=1), 1.0
+    return idx, SLACKS[fill]
+
+
+FILLS = ["one", "several", "nothing_held", "to_the_last_row"]
+
+
+@pytest.mark.parametrize("fill", FILLS)
+@pytest.mark.parametrize("act", list(ACTS))
+def test_rows_the_products_leave_unwritten_reach_no_result(act, fill, monkeypatch):
+    """``share_glu_experts`` over grouped products that write NaN into every
+    row past the sizes' sum: ``y`` and the gradients of the tokens, the
+    router's weights and the three stacks are finite and the per-token sums',
+    with one chunk a third full, three chunks of which the last holds 4 rows
+    of 24, a share that is sent nothing, and a chunk filled to its last row."""
+    x, _, gates, stacks = inputs()
+    idx, slack = routes_of(fill)
+    held = np.asarray((idx >= OFFSET) & (idx < OFFSET + HELD))
+    rows = moe_dispatch.share_rows_bound(S, K, HELD, E, slack)
+    assert held.sum() == {"one": 52, "several": 52, "nothing_held": 0, "to_the_last_row": rows}[fill]
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", grouped_matmul_that_leaves_rows_unwritten)
+    probe = jax.random.normal(jax.random.PRNGKey(7), (S, D))
+
+    def share(x, gates, *stacks):
+        return moe_dispatch.share_glu_experts(x, idx, gates, *stacks, OFFSET, E, act=act, slack=slack)
+
+    y, _, dropped, moved, zeros = share(x, gates, *stacks)
+    assert int(dropped) == 0 and int(moved) == rows * max(1, -(-int(held.sum()) // rows))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(per_token_sum(x, idx, gates, *stacks, act)),
+                               rtol=2e-5, atol=2e-5)
+    if act == "relu":  # counted over the held rows only, whatever the others hold
+        gate = jnp.einsum("sd,skdf->skf", x, stacks[0][jnp.clip(idx - OFFSET, 0, HELD - 1)])
+        assert int(zeros) == int(jnp.sum((gate <= 0) & held[:, :, None]))
+    got = jax.grad(lambda *a: jnp.sum(share(*a)[0] * probe), argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
+    want = jax.grad(lambda x, gates, *w: jnp.sum(per_token_sum(x, idx, gates, *w, act) * probe),
+                    argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
+    for name, a, b in zip(("x", "top_gates", "w_gate", "w_up", "w_down"), got, want):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("fill", FILLS)
+def test_an_assignment_that_is_not_this_shares_takes_a_weight_gradient_of_exactly_zero(fill, monkeypatch):
+    """The router's weight of a choice on an expert held elsewhere: its row is
+    past the sizes' sum, so ``sum(hidden * d hidden)`` over it is NaN here;
+    the cotangent that leaves the dispatch for it is 0.0 to the bit (a select
+    on ``valid``, not a product with it), and non-zero for every held one."""
+    x, _, gates, stacks = inputs()
+    idx, slack = routes_of(fill)
+    held = np.asarray((idx >= OFFSET) & (idx < OFFSET + HELD))
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", grouped_matmul_that_leaves_rows_unwritten)
+    d_gates = np.asarray(jax.grad(lambda g: jnp.sum(jnp.sin(moe_dispatch.share_glu_experts(
+        x, idx, g, *stacks, OFFSET, E, slack=slack)[0])))(gates))
+    assert np.array_equal(d_gates[~held], np.zeros_like(d_gates[~held]))
+    assert np.all(np.isfinite(d_gates)) and np.all(d_gates[held] != 0)
+
+
 # -- a token's run summed by one block-local 0/1 product (PR 38) ------------------------
 
 # rows R, k, and the runs the case is about as (first position in token order,
@@ -220,10 +369,11 @@ def test_a_run_longer_than_the_carry_is_refused():
 @pytest.mark.parametrize("case", ["ends_on_the_second_tiles_first_row", "three_tiles_of_8", "two_tiles_of_128"])
 def test_spread_and_combine_are_each_others_transpose_without_a_scatter_add(case):
     """``jax.vjp`` of ``_spread`` is ``_combine`` on the cotangent and the
-    reverse (held to each function itself and, as an adjoint pair, to the
-    inner products), and neither derivative's jaxpr holds a scatter-add: the
-    product that sums a run is its own transpose's partner as the shifted adds
-    were."""
+    reverse (held to each function itself and, as an adjoint pair on the held
+    rows, to the inner products: ``_spread`` zeroes no row since PR 46, and a
+    row that is not held is in no group of the products between the two), and
+    neither derivative's jaxpr holds a scatter-add: the product that sums a
+    run is its own transpose's partner as the shifted adds were."""
     tok, valid, n_tokens, k = chunk_of(case)
     r = tok.shape[0]
     where = (tok, valid, *moe_dispatch._token_runs(tok, valid, n_tokens))
@@ -240,8 +390,12 @@ def test_spread_and_combine_are_each_others_transpose_without_a_scatter_add(case
                                rtol=1e-6, atol=1e-6)
     np.testing.assert_allclose(np.asarray(pull_combine(g, x)), np.asarray(moe_dispatch._spread(x, where, k)),
                                rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(float(jnp.sum(moe_dispatch._spread(x, where, k) * g)),
+    np.testing.assert_allclose(float(jnp.sum(moe_dispatch._spread(x, where, k) * jnp.where(valid[:, None], g, 0.0))),
                                float(jnp.sum(x * moe_dispatch._combine(g, where, k))), rtol=1e-5)
+    # rows that are not held may hold anything: no sum takes them
+    poisoned = jnp.where(valid[:, None], g, jnp.where(jnp.arange(r)[:, None] % 2 == 0, jnp.nan, jnp.inf))
+    np.testing.assert_array_equal(np.asarray(moe_dispatch._combine(poisoned, where, k)),
+                                  np.asarray(moe_dispatch._combine(g, where, k)))
     for pull, args in ((pull_spread, (x, g)), (pull_combine, (g, x))):
         names = {eqn.primitive.name for _, eqn in live_equations(jax.make_jaxpr(pull)(*args).jaxpr)}
         assert not any("scatter" in name for name in names), names

@@ -364,7 +364,7 @@ OLMOE_TINY = dict(n_layers=2, d_model=64, n_heads=4, n_experts=8, top_k=2, d_exp
 
 
 @pytest.mark.parametrize("model,overrides,lines,ops", [
-    ("laguna_xs2", LAGUNA_TINY, 7566, 7156), ("olmoe_1b_7b", OLMOE_TINY, 1654, 1601)])
+    ("laguna_xs2", LAGUNA_TINY, 7553, 7122), ("olmoe_1b_7b", OLMOE_TINY, 1654, 1601)])
 def test_a_silu_models_lowered_step_is_unchanged_and_gains_no_output(monkeypatch, model, overrides, lines, ops):
     """The loss and gradient program of Laguna and of OLMoE, lowered at a tiny
     size: OLMoE's as many lines and operations as at the parent of PR 35 (where
@@ -379,7 +379,12 @@ def test_a_silu_models_lowered_step_is_unchanged_and_gains_no_output(monkeypatch
     0/1 product a call of ``_combine`` (with the 0/1 matrices, the carry over
     a tile's edge and its select written out: 36 more operations a call in
     the text, eight calls) where three rounds of slice, select, pad and add
-    were; the compiled step holds one fusion for them (tests/test_tpu_compile.py)."""
+    were; the compiled step holds one fusion for them (tests/test_tpu_compile.py).
+    And at PR 46, (7,566, 7,156) -> (7,553, 7,122): the sizes handed to the
+    grouped matmuls are no longer topped up to the chunk's rows, ``_spread``
+    zeroes nothing (three selects a layer gone), and ``_combine``'s gather
+    index and the router weights take a select each, over ``[rows]``, not
+    ``[rows, d]`` (tests/test_moe_share_dispatch.py)."""
     monkeypatch.setattr(moe_dispatch, "SHARE_ROWS_SLACK", 3.0)  # the program's own
     bundle = get_model(model, **overrides)
     params = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))

@@ -241,8 +241,8 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     the names a device trace tells them by; the expert layers' grouped matmuls over the bounded
     chunk of rows, never the S x k = 262,144, seven a layer. That it compiles
     says the step fits the chip beside its state; its temporaries are what
-    they were before PR 38 (8.1079e9 then, 8.1090e9 now: the float32 carry of a
-    run over a tile's edge, ``[rows / 128, d]`` a call)."""
+    they were before PR 38 (8.1079e9 then, 8.1090e9 after it: the float32 carry
+    of a run over a tile's edge, ``[rows / 128, d]`` a call; 8.1105e9 since PR 46)."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -266,7 +266,9 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert total < 15.75e9, total
-    assert mem.temp_size_in_bytes <= 8.110e9, mem.temp_size_in_bytes  # the parent of PR 36: 8.1125e9; of PR 38: 8.1079e9
+    # the parent of PR 36: 8.1125e9; of PR 38: 8.1079e9; of PR 46: 8.1090e9, and 8.1105e9 since (three select passes
+    # a layer fewer and the same buffers alive: the heap packs 1.5 MB worse)
+    assert mem.temp_size_in_bytes <= 8.112e9, mem.temp_size_in_bytes
 
 
 def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_on_the_chip, monkeypatch):
