@@ -13,7 +13,8 @@ AMP the same way (SURVEY.md L1/L5).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+import dataclasses
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,32 @@ from jax.sharding import PartitionSpec as P
 from distributedvolunteercomputing_tpu.ops import attention as attention_ops
 
 Params = Dict[str, Any]
+
+
+@dataclasses.dataclass(frozen=True)
+class SteppedLeaves:
+    """Leaves of the parameters that the STEP moves, by the model's own rule
+    and from the step's own metrics, and the optimizer does not: a state that
+    no gradient reaches (a router's selection bias, which load balancing
+    without an auxiliary loss raises and lowers by the experts' loads). A
+    model's bundle names them (``ModelBundle.stepped``); every builder of a
+    step takes them (``stepped=``) and hands them to ``train_step_body``, the
+    one place they act. A bundle that names none compiles to the program it
+    compiled to before there was such a thing.
+
+    ``signal``: the key of the loss function's metrics that the rule reads. It
+    need not be a scalar; the step takes it out of the metrics it returns.
+    ``owns(params)``: a tree of bools shaped like ``params``, True on the
+    leaves the rule owns. Their gradient is zeroed before the optimizer sees
+    it (no share of a global-norm clip) and whatever the optimizer makes of
+    them is discarded: an owned leaf after the step is ``rule``'s.
+    ``rule(params, signal)``: a tree shaped like ``params`` whose owned leaves
+    are the new values, from the parameters as they were BEFORE the update."""
+
+    signal: str
+    owns: Callable[[Any], Any]
+    rule: Callable[[Any, Any], Any]
+
 
 # bf16 on TPU keeps the MXU at full rate; f32 on CPU keeps tests exact enough
 # to compare against numpy references.
@@ -86,8 +113,27 @@ def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[jax.Array, jax.Arr
     return q, k, v
 
 
+def matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
+    return jax.random.normal(rng, shape, jnp.float32) * scale
+
+
+def swiglu_init(keys: Sequence[jax.Array], d: int, f: int, lead: Tuple[int, ...] = (),
+                first: int = 0) -> Params:
+    """A gated FFN ``d -> f -> d`` from ``keys[first]``, ``[first + 1]``,
+    ``[first + 2]``, each taken as its leaf is drawn; ``lead``: the leading
+    axes of a stack of them (a chip's held experts)."""
+    return {"w_gate": matrix(keys[first], (*lead, d, f)), "w_up": matrix(keys[first + 1], (*lead, d, f)),
+            "w_down": matrix(keys[first + 2], (*lead, f, d))}
+
+
+def swiglu(p: Params, h: jax.Array) -> jax.Array:
+    dtype = h.dtype
+    act = jax.nn.silu(h @ p["w_gate"].astype(dtype)) * (h @ p["w_up"].astype(dtype))
+    return act @ p["w_down"].astype(dtype)
+
+
 def embed_init(rng: jax.Array, vocab: int, d: int, scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, (vocab, d), jnp.float32) * scale
+    return matrix(rng, (vocab, d), scale)
 
 
 def layernorm_init(d: int) -> Params:

@@ -44,7 +44,7 @@ its query/key head is refused at construction (no core here takes two).
 **The selection bias** and the router are ``models/moe.py``'s (shared with
 ``models/lfm2.py``: a float32 leaf ``bias`` a layer, zeros at initialisation,
 no gradient, moved by the STEP: ``b_e <- b_e + 0.001 sign(mean(c) - c_e)``,
-``models/registry.SteppedLeaves``); here with the divisor's 1e-20 and the
+``models/common.SteppedLeaves``); here with the divisor's 1e-20 and the
 scaling factor 1.8. The cut a chip makes without touching a width, as
 ``models/lfm2.py``: ``n_layers`` (the leading ones), ``experts_held`` with
 ``expert_offset``, ``vocab`` (a slice: embedding, head and loss over it). Every
@@ -66,12 +66,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common, moe
+from distributedvolunteercomputing_tpu.models.common import matrix, swiglu, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads, rope
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
@@ -133,13 +134,7 @@ class Glm4MoeLiteConfig:
     xent_chunk: int = 512
 
     def __post_init__(self):
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(f"top_k={self.top_k} must be in [1, n_experts={self.n_experts}]")
-        if not (0 <= self.expert_offset and 1 <= self.experts_held
-                and self.expert_offset + self.experts_held <= self.n_experts):
-            raise ValueError(
-                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
-                f"are not a slice of the {self.n_experts}")
+        moe.check_share(self)
         if self.head_dim != self.v_head_dim:
             raise ValueError(
                 f"query/key head {self.qk_nope_dim} + {self.qk_rope_dim} and value head {self.v_head_dim} "
@@ -164,35 +159,26 @@ class Glm4MoeLiteConfig:
         return tuple((kind, n) for kind, n in ((DENSE, dense), (SPARSE, sparse)) if n)
 
 
-def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, shape, jnp.float32) * scale
-
-
-def _swiglu_init(keys, d: int, f: int, lead: Tuple[int, ...] = ()) -> common.Params:
-    return {"w_gate": _matrix(keys[0], (*lead, d, f)), "w_up": _matrix(keys[1], (*lead, d, f)),
-            "w_down": _matrix(keys[2], (*lead, f, d))}
-
-
 def _layer_init(rng: jax.Array, cfg: Glm4MoeLiteConfig, ffn: str) -> common.Params:
     k = jax.random.split(rng, 15)
     d, h = cfg.d_model, cfg.n_heads
     p: common.Params = {
         "ln_mixer": common.rmsnorm_init(d), "ln_ffn": common.rmsnorm_init(d),
-        "wq_a": _matrix(k[0], (d, cfg.q_lora_rank)), "q_a_norm": common.rmsnorm_init(cfg.q_lora_rank),
-        "wq_b": _matrix(k[1], (cfg.q_lora_rank, h * cfg.head_dim)),
-        "wkv_a": _matrix(k[2], (d, cfg.kv_lora_rank + cfg.qk_rope_dim)),
+        "wq_a": matrix(k[0], (d, cfg.q_lora_rank)), "q_a_norm": common.rmsnorm_init(cfg.q_lora_rank),
+        "wq_b": matrix(k[1], (cfg.q_lora_rank, h * cfg.head_dim)),
+        "wkv_a": matrix(k[2], (d, cfg.kv_lora_rank + cfg.qk_rope_dim)),
         "kv_a_norm": common.rmsnorm_init(cfg.kv_lora_rank),
-        "wkv_b": _matrix(k[3], (cfg.kv_lora_rank, h * (cfg.qk_nope_dim + cfg.v_head_dim))),
-        "wo": _matrix(k[4], (h * cfg.v_head_dim, d)),
+        "wkv_b": matrix(k[3], (cfg.kv_lora_rank, h * (cfg.qk_nope_dim + cfg.v_head_dim))),
+        "wo": matrix(k[4], (h * cfg.v_head_dim, d)),
     }
     if ffn == DENSE:
-        p["mlp"] = _swiglu_init(k[5:8], d, cfg.d_ff)
+        p["mlp"] = swiglu_init(k[5:8], d, cfg.d_ff)
     else:
-        p["router"] = _matrix(k[8], (d, cfg.n_experts))
+        p["router"] = matrix(k[8], (d, cfg.n_experts))
         p["bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)  # the step's, not the optimizer's
-        p["shared"] = _swiglu_init(k[9:12], d, cfg.n_shared * cfg.d_expert)
+        p["shared"] = swiglu_init(k[9:12], d, cfg.n_shared * cfg.d_expert)
         # the held experts stacked on a leading axis -> sharded over ep (parallel/sharding.py)
-        p["experts"] = _swiglu_init(k[12:15], d, cfg.d_expert, (cfg.experts_held,))
+        p["experts"] = swiglu_init(k[12:15], d, cfg.d_expert, (cfg.experts_held,))
     return p
 
 
@@ -211,14 +197,8 @@ def init(rng: jax.Array, cfg: Glm4MoeLiteConfig) -> common.Params:
         "wte": common.embed_init(keys[0], cfg.vocab, cfg.d_model),
         "blocks": blocks,
         "ln_f": common.rmsnorm_init(cfg.d_model),
-        "lm_head": _matrix(keys[2], (cfg.d_model, cfg.vocab)),
+        "lm_head": matrix(keys[2], (cfg.d_model, cfg.vocab)),
     }
-
-
-def _swiglu(p: common.Params, h: jax.Array) -> jax.Array:
-    dtype = h.dtype
-    act = jax.nn.silu(h @ p["w_gate"].astype(dtype)) * (h @ p["w_up"].astype(dtype))
-    return act @ p["w_down"].astype(dtype)
 
 
 def qkv(p: common.Params, n: jax.Array, cfg: Glm4MoeLiteConfig):
@@ -260,17 +240,17 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Glm
     h = common.rmsnorm(p["ln_ffn"], x, cfg.rms_eps)
     if ffn == DENSE:
         with jax.named_scope("mlp"):
-            return x + _swiglu(p["mlp"], h), stats, None
+            return x + swiglu(p["mlp"], h), stats, None
     with jax.named_scope("moe"):
         h = h.reshape(b * t, d)
-        top_idx, weights = moe.route(p["router"], p["bias"], h, cfg.top_k, cfg.routed_scale, ROUTE_EPS)
+        top_idx, weights, _ = moe.route(p["router"], h, cfg.top_k, cfg.routed_scale, p["bias"], ROUTE_EPS)
         ex = p["experts"]
-        y, group_sizes, dropped, moved, _ = moe_dispatch.share_glu_experts(
+        y, *dispatch = moe_dispatch.share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
             cfg.expert_offset, cfg.n_experts, slack=SHARE_ROWS_SLACK,
         )
-        x = x + (_swiglu(p["shared"], h) + y).reshape(b, t, d)   # the shared expert: every token, unweighted
-        stats, chosen = moe.note_share(stats, top_idx, group_sizes, dropped, moved, cfg, SHARE_ROWS_SLACK)
+        x = x + (swiglu(p["shared"], h) + y).reshape(b, t, d)   # the shared expert: every token, unweighted
+        stats, chosen = moe.note_share(stats, top_idx, dispatch, cfg, SHARE_ROWS_SLACK)
     return x, stats, (top_idx, chosen)
 
 
@@ -283,18 +263,14 @@ def loss_and_routes(
     x = params["wte"][tokens].astype(common.compute_dtype())
     runs = [(functools.partial(_layer, cfg=cfg, ffn=ffn), n, ffn == SPARSE) for ffn, n in cfg.runs]
     x, stats, routes, counts = moe.run_layers(
-        runs, params["blocks"], x, moe.zero_share_stats(), cfg.remat, tokens.size, cfg)
+        runs, params["blocks"], x, moe.zero_share_stats(chunks_extra=True), cfg.remat, tokens.size, cfg)
     x = common.rmsnorm(params["ln_f"], x, cfg.rms_eps)
     loss = common.lm_xent_chunked(
         x, params["lm_head"], batch["targets"], chunk=cfg.xent_chunk, head_layout="dv"
     )
-    return loss, moe.share_metrics(loss, stats, params, counts, tokens.size, cfg), routes
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: Optional[jax.Array], cfg: Glm4MoeLiteConfig
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return loss_and_routes(params, batch, cfg)[:2]
+    metrics = moe.share_metrics(
+        loss, loss, jnp.zeros((), jnp.float32), stats, tokens.size, cfg, params, counts)
+    return loss, metrics, routes
 
 
 def stepped(cfg: Glm4MoeLiteConfig):

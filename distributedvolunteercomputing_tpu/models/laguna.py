@@ -59,12 +59,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributedvolunteercomputing_tpu.models import common
+from distributedvolunteercomputing_tpu.models import common, moe
+from distributedvolunteercomputing_tpu.models.common import matrix, swiglu, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads, yarn_inv_freq,
 )
@@ -111,13 +112,7 @@ class LagunaConfig:
     xent_chunk: int = 512
 
     def __post_init__(self):
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(f"top_k={self.top_k} must be in [1, n_experts={self.n_experts}]")
-        if not (0 <= self.expert_offset and 1 <= self.experts_held
-                and self.expert_offset + self.experts_held <= self.n_experts):
-            raise ValueError(
-                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
-                f"are not a slice of the {self.n_experts}")
+        moe.check_share(self)
         for h in (self.heads_full, self.heads_sliding):
             if h % self.n_kv_heads:
                 raise ValueError(f"{self.n_kv_heads} key/value heads do not divide {h} query heads")
@@ -132,34 +127,25 @@ class LagunaConfig:
         return self.heads_full if self.attention_kind(layer) == FULL else self.heads_sliding
 
 
-def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, shape, jnp.float32) * scale
-
-
-def _swiglu_init(keys, d: int, f: int, lead: Tuple[int, ...] = ()) -> common.Params:
-    return {"w_gate": _matrix(keys[0], (*lead, d, f)), "w_up": _matrix(keys[1], (*lead, d, f)),
-            "w_down": _matrix(keys[2], (*lead, f, d))}
-
-
 def _layer_init(rng: jax.Array, cfg: LagunaConfig, layer: int) -> common.Params:
     k = jax.random.split(rng, 15)
     d, hd, h = cfg.d_model, cfg.head_dim, cfg.heads(layer)
     p = {
         "ln_attn": common.rmsnorm_init(d),
-        "wq": _matrix(k[0], (d, h * hd)),
-        "wk": _matrix(k[1], (d, cfg.n_kv_heads * hd)),
-        "wv": _matrix(k[2], (d, cfg.n_kv_heads * hd)),
-        "wg": _matrix(k[3], (d, h)),
-        "wo": _matrix(k[4], (h * hd, d)),
+        "wq": matrix(k[0], (d, h * hd)),
+        "wk": matrix(k[1], (d, cfg.n_kv_heads * hd)),
+        "wv": matrix(k[2], (d, cfg.n_kv_heads * hd)),
+        "wg": matrix(k[3], (d, h)),
+        "wo": matrix(k[4], (h * hd, d)),
         "ln_mlp": common.rmsnorm_init(d),
     }
     if cfg.ffn_kind(layer) == DENSE:
-        p["mlp"] = _swiglu_init(k[5:8], d, cfg.d_ff)
+        p["mlp"] = swiglu_init(k[5:8], d, cfg.d_ff)
     else:
-        p["router"] = _matrix(k[8], (d, cfg.n_experts))
-        p["shared"] = _swiglu_init(k[9:12], d, cfg.d_shared)
+        p["router"] = matrix(k[8], (d, cfg.n_experts))
+        p["shared"] = swiglu_init(k[9:12], d, cfg.d_shared)
         # the held experts stacked on a leading axis -> sharded over ep (parallel/sharding.py)
-        p["experts"] = _swiglu_init(k[12:15], d, cfg.d_expert, (cfg.experts_held,))
+        p["experts"] = swiglu_init(k[12:15], d, cfg.d_expert, (cfg.experts_held,))
     return p
 
 
@@ -173,7 +159,7 @@ def init(rng: jax.Array, cfg: LagunaConfig) -> common.Params:
         "wte": common.embed_init(keys[0], cfg.vocab, cfg.d_model),
         "blocks": [_layer_init(layer_keys[l], cfg, l) for l in range(cfg.n_layers)],
         "ln_f": common.rmsnorm_init(cfg.d_model),
-        "lm_head": _matrix(keys[2], (cfg.d_model, cfg.vocab)),
+        "lm_head": matrix(keys[2], (cfg.d_model, cfg.vocab)),
     }
 
 
@@ -186,39 +172,6 @@ def rotary(x: jax.Array, cfg: LagunaConfig, kind: str) -> jax.Array:
         cfg.yarn_beta_fast, cfg.yarn_beta_slow)
     return rope(x, layout="half", rotary_dim=cfg.rotary_dim_full, inv_freq=inv_freq,
                 scale=cfg.yarn_attention_factor)
-
-
-def route(p_router: jax.Array, h: jax.Array, top_k: int, routed_scale: float):
-    """Router of one layer: ``h`` [S, d] -> (top_idx [S, k], weights [S, k]
-    float32, scores [S, E] float32). Sigmoid scores from a float32 product at
-    the highest precision; the chosen scores normalised to sum to 1, times
-    ``routed_scale``."""
-    logits = jnp.dot(
-        h.astype(jnp.float32), p_router, precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )
-    scores = jax.nn.sigmoid(logits)
-    top_scores, top_idx = jax.lax.top_k(scores, top_k)
-    weights = routed_scale * top_scores / jnp.sum(top_scores, axis=-1, keepdims=True)
-    return top_idx, weights, scores
-
-
-def _swiglu(p: common.Params, h: jax.Array) -> jax.Array:
-    dtype = h.dtype
-    act = jax.nn.silu(h @ p["w_gate"].astype(dtype)) * (h @ p["w_up"].astype(dtype))
-    return act @ p["w_down"].astype(dtype)
-
-
-def _zero_stats(cfg: LagunaConfig) -> Dict[str, jax.Array]:
-    zero = jnp.zeros((), jnp.float32)
-    return {
-        "choices": jnp.zeros((cfg.n_experts,), jnp.float32),  # sum over layers of f_e
-        "probs": jnp.zeros((cfg.n_experts,), jnp.float32),    # sum over layers of P_e
-        "load_max": zero,    # fullest held expert of any layer, rows
-        "rows_held": zero,   # assignments on held experts, all layers
-        "rows_moved": zero,  # rows the dispatch gathered to its grouped matmuls, all layers
-        "dropped": zero,     # held assignments no grouped matmul computed
-    }
 
 
 def _attention(p: common.Params, x: jax.Array, cfg: LagunaConfig, layer: int) -> jax.Array:
@@ -244,26 +197,17 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Lag
     h = common.rmsnorm(p["ln_mlp"], x, cfg.rms_eps)
     if cfg.ffn_kind(layer) == DENSE:
         with jax.named_scope("mlp"):
-            return x + _swiglu(p["mlp"], h), stats, None
+            return x + swiglu(p["mlp"], h), stats, None
     with jax.named_scope("moe"):
         h = h.reshape(b * t, d)
-        top_idx, weights, scores = route(p["router"], h, cfg.top_k, cfg.routed_scale)
+        top_idx, weights, scores = moe.route(p["router"], h, cfg.top_k, cfg.routed_scale)
         ex = p["experts"]
-        y, group_sizes, dropped, moved, _ = share_glu_experts(
+        y, *dispatch = share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
             cfg.expert_offset, cfg.n_experts,
         )
-        x = x + (_swiglu(p["shared"], h) + y).reshape(b, t, d)
-        chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1))
-        load = group_sizes.astype(jnp.float32)
-        stats = {
-            "choices": stats["choices"] + chosen / (b * t),
-            "probs": stats["probs"] + jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=0),
-            "load_max": jnp.maximum(stats["load_max"], jnp.max(load)),
-            "rows_held": stats["rows_held"] + jnp.sum(load),
-            "rows_moved": stats["rows_moved"] + moved.astype(jnp.float32),
-            "dropped": stats["dropped"] + dropped.astype(jnp.float32),
-        }
+        x = x + (swiglu(p["shared"], h) + y).reshape(b, t, d)
+        stats, _ = moe.note_share(stats, top_idx, dispatch, cfg, scores=scores)
     return x, stats, top_idx
 
 
@@ -271,7 +215,7 @@ def _trunk(params: common.Params, tokens: jax.Array, cfg: LagunaConfig):
     """Final hidden states [B, T, d], the routing statistics summed over the
     expert layers, and those layers' routes ``[L_sparse, S, k]``."""
     x = params["wte"][tokens].astype(common.compute_dtype())
-    stats, routes = _zero_stats(cfg), []
+    stats, routes = moe.zero_share_stats(balanced=cfg.n_experts), []
     for layer, p in enumerate(params["blocks"]):
         def block(p, x, stats, layer=layer):
             return _layer(p, x, stats, cfg, layer)
@@ -293,25 +237,6 @@ def loss_and_routes(
     lm = common.lm_xent_chunked(
         x, params["lm_head"], batch["targets"], chunk=cfg.xent_chunk, head_layout="dv"
     )
-    n = max(cfg.n_layers - cfg.dense_layers, 1)  # expert layers
-    aux = cfg.n_experts * jnp.sum((stats["choices"] / n) * (stats["probs"] / n))
+    aux = moe.balance_loss(stats, max(cfg.n_layers - cfg.dense_layers, 1), cfg.n_experts)
     loss = lm + cfg.aux_coef * aux
-    metrics = {
-        "loss": loss, "lm_loss": lm, "aux_loss": aux,
-        # over the held experts: the fullest of any layer and the even share of
-        # a layer's S k assignments; the assignments on held experts and the
-        # rows the dispatch gathered for them, summed over the expert layers;
-        # held assignments no grouped matmul computed
-        "moe_load_max": stats["load_max"],
-        "moe_load_mean": jnp.asarray(tokens.size * cfg.top_k / cfg.n_experts, jnp.float32),
-        "moe_rows_held": stats["rows_held"],
-        "moe_rows_moved": stats["rows_moved"],
-        "moe_dropped": stats["dropped"],
-    }
-    return loss, metrics, routes
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: Optional[jax.Array], cfg: LagunaConfig
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return loss_and_routes(params, batch, cfg)[:2]
+    return loss, moe.share_metrics(loss, lm, aux, stats, tokens.size, cfg), routes

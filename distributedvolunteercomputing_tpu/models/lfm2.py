@@ -47,7 +47,7 @@ with ``c_e`` the number of the step's ``S x 4`` assignments that chose expert
 ``e`` in the layer, ``b_e <- b_e + gamma sign(mean(c) - c_e)``, gamma 0.001.
 ``config.json`` carries the switch only; the rule and gamma are the family's
 convention (the benchmark's configuration file says so under ``assumed``).
-``stepped(cfg)`` hands the rule to the train step (``models/registry.SteppedLeaves``),
+``stepped(cfg)`` hands the rule to the train step (``models/common.SteppedLeaves``),
 which keeps the optimizer off these leaves.
 
 The cut a chip makes without touching a width, as ``models/laguna.py``:
@@ -79,12 +79,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common, moe
+from distributedvolunteercomputing_tpu.models.common import matrix, swiglu, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads,
 )
@@ -138,13 +139,7 @@ class LFM2Config:
         unknown = sorted(set(self.layer_types) - {CONV, FULL})
         if unknown or not self.layer_types:
             raise ValueError(f"layer_types holds {unknown or 'nothing'}; known: {CONV}, {FULL}")
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(f"top_k={self.top_k} must be in [1, n_experts={self.n_experts}]")
-        if not (0 <= self.expert_offset and 1 <= self.experts_held
-                and self.expert_offset + self.experts_held <= self.n_experts):
-            raise ValueError(
-                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
-                f"are not a slice of the {self.n_experts}")
+        moe.check_share(self)
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"{self.n_kv_heads} key/value heads do not divide {self.n_heads} query heads")
@@ -171,37 +166,28 @@ class LFM2Config:
         return tuple((m, f, n) for m, f, n in out)
 
 
-def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, shape, jnp.float32) * scale
-
-
-def _swiglu_init(keys, d: int, f: int, lead: Tuple[int, ...] = ()) -> common.Params:
-    return {"w_gate": _matrix(keys[0], (*lead, d, f)), "w_up": _matrix(keys[1], (*lead, d, f)),
-            "w_down": _matrix(keys[2], (*lead, f, d))}
-
-
 def _layer_init(rng: jax.Array, cfg: LFM2Config, mixer: str, ffn: str) -> common.Params:
     k = jax.random.split(rng, 11)
     d, hd = cfg.d_model, cfg.head_dim
     p: common.Params = {"ln_mixer": common.rmsnorm_init(d), "ln_ffn": common.rmsnorm_init(d)}
     if mixer == CONV:
-        p["conv"] = {"w_in": _matrix(k[0], (d, 3 * d)), "taps": _matrix(k[1], (cfg.conv_taps, d)),
-                     "w_out": _matrix(k[2], (d, d))}
+        p["conv"] = {"w_in": matrix(k[0], (d, 3 * d)), "taps": matrix(k[1], (cfg.conv_taps, d)),
+                     "w_out": matrix(k[2], (d, d))}
     else:
         p.update({
-            "wq": _matrix(k[0], (d, cfg.n_heads * hd)),
-            "wk": _matrix(k[1], (d, cfg.n_kv_heads * hd)),
-            "wv": _matrix(k[2], (d, cfg.n_kv_heads * hd)),
-            "wo": _matrix(k[3], (cfg.n_heads * hd, d)),
+            "wq": matrix(k[0], (d, cfg.n_heads * hd)),
+            "wk": matrix(k[1], (d, cfg.n_kv_heads * hd)),
+            "wv": matrix(k[2], (d, cfg.n_kv_heads * hd)),
+            "wo": matrix(k[3], (cfg.n_heads * hd, d)),
             "q_norm": common.rmsnorm_init(hd), "k_norm": common.rmsnorm_init(hd),
         })
     if ffn == DENSE:
-        p["mlp"] = _swiglu_init(k[4:7], d, cfg.d_ff)
+        p["mlp"] = swiglu_init(k[4:7], d, cfg.d_ff)
     else:
-        p["router"] = _matrix(k[7], (d, cfg.n_experts))
+        p["router"] = matrix(k[7], (d, cfg.n_experts))
         p["bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)  # the step's, not the optimizer's
         # the held experts stacked on a leading axis -> sharded over ep (parallel/sharding.py)
-        p["experts"] = _swiglu_init(k[8:11], d, cfg.d_expert, (cfg.experts_held,))
+        p["experts"] = swiglu_init(k[8:11], d, cfg.d_expert, (cfg.experts_held,))
     return p
 
 
@@ -221,12 +207,6 @@ def init(rng: jax.Array, cfg: LFM2Config) -> common.Params:
         "blocks": blocks,
         "ln_f": common.rmsnorm_init(cfg.d_model),
     }
-
-
-def _swiglu(p: common.Params, h: jax.Array) -> jax.Array:
-    dtype = h.dtype
-    act = jax.nn.silu(h @ p["w_gate"].astype(dtype)) * (h @ p["w_up"].astype(dtype))
-    return act @ p["w_down"].astype(dtype)
 
 
 def _conv_mixer(p: common.Params, x: jax.Array, cfg: LFM2Config) -> jax.Array:
@@ -264,17 +244,17 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: LFM
     h = common.rmsnorm(p["ln_ffn"], x, cfg.rms_eps)
     if ffn == DENSE:
         with jax.named_scope("mlp"):
-            return x + _swiglu(p["mlp"], h), stats, None
+            return x + swiglu(p["mlp"], h), stats, None
     with jax.named_scope("moe"):
         h = h.reshape(b * t, d)
-        top_idx, weights = moe.route(p["router"], p["bias"], h, cfg.top_k, cfg.routed_scale, ROUTE_EPS)
+        top_idx, weights, _ = moe.route(p["router"], h, cfg.top_k, cfg.routed_scale, p["bias"], ROUTE_EPS)
         ex = p["experts"]
-        y, group_sizes, dropped, moved, _ = share_glu_experts(
+        y, *dispatch = share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
             cfg.expert_offset, cfg.n_experts, slack=SHARE_ROWS_SLACK,
         )
         x = x + y.reshape(b, t, d)
-        stats, chosen = moe.note_share(stats, top_idx, group_sizes, dropped, moved, cfg, SHARE_ROWS_SLACK)
+        stats, chosen = moe.note_share(stats, top_idx, dispatch, cfg, SHARE_ROWS_SLACK)
     return x, stats, (top_idx, chosen)
 
 
@@ -286,7 +266,7 @@ def _trunk(params: common.Params, tokens: jax.Array, cfg: LFM2Config):
     runs = [(functools.partial(_layer, cfg=cfg, mixer=mixer, ffn=ffn), n, ffn == SPARSE)
             for mixer, ffn, n in cfg.runs]
     x, stats, routes, counts = moe.run_layers(
-        runs, params["blocks"], x, moe.zero_share_stats(), cfg.remat, tokens.size, cfg)
+        runs, params["blocks"], x, moe.zero_share_stats(chunks_extra=True), cfg.remat, tokens.size, cfg)
     return common.rmsnorm(params["ln_f"], x, cfg.rms_eps), stats, routes, counts
 
 
@@ -300,14 +280,9 @@ def loss_and_routes(
     loss = common.lm_xent_chunked(
         x, params["wte"], batch["targets"], chunk=cfg.xent_chunk, head_layout="vd"
     )
-    metrics = moe.share_metrics(loss, stats, params, counts, tokens.size, cfg)
+    metrics = moe.share_metrics(
+        loss, loss, jnp.zeros((), jnp.float32), stats, tokens.size, cfg, params, counts)
     return loss, metrics, routes
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: Optional[jax.Array], cfg: LFM2Config
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return loss_and_routes(params, batch, cfg)[:2]
 
 
 def stepped(cfg: LFM2Config):

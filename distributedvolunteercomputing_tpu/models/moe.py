@@ -1,249 +1,136 @@
-"""Mixture-of-Experts FFN (Switch top-1 / GShard top-2 routing) + GPT-2-MoE.
+"""What the expert families share (``models/laguna.py``, ``smallthinker.py``,
+``lfm2.py``, ``glm4_moe_lite.py``; ``models/olmoe.py`` holds every expert and
+takes the balancing term only): a config brings ``top_k``, ``n_experts``,
+``experts_held`` and ``expert_offset``, its expert layers call
+``ops/moe_dispatch.share_glu_experts`` for the held experts' part of the sum,
+and this module keeps
 
-Build-side extension beyond reference parity (SURVEY.md §2 lists the
-reference as dense volunteer-DP only), completing the parallelism set with
-EXPERT parallelism: expert weights are stacked on a leading E axis and
-sharded over the mesh's ``ep`` axis (parallel/sharding.py rules), so the
-dispatch/combine einsums below compile to GSPMD all-to-alls over ICI — the
-canonical GShard/Switch TPU formulation, where routing is expressed as
-dense one-hot einsums the MXU eats, never as data-dependent gathers.
+- the check that the held experts are a slice of the router's (``check_share``);
+- the sigmoid router (``route``), with the selection bias that the STEP moves
+  where a layer carries the leaf ``bias`` [E] beside ``router`` [d, E];
+- the share's running statistics over the expert layers (``zero_share_stats``,
+  ``note_share``), the balancing term of a router trained by an auxiliary loss
+  (``balance_loss``) and the step's metrics (``share_metrics``: what the loop
+  puts on a ``moe.route`` span, ``training/trainer.ROUTING_KEYS``);
+- the runs of equal layers (``run_layers``) and the stepped bias's rule
+  (``balance``, ``stepped``) for the families that have them.
 
-Routing (``router_top_k``; 1 = Switch Transformer, 2 = GShard top-2):
-- router logits [S, E] -> softmax gates; each token goes to its top-k
-  experts, output scaled by the gate(s) (renormalized over the chosen
-  experts for k > 1; the raw argmax gate for k = 1, as in Switch);
-- static capacity C = ceil(capacity_factor * router_top_k * S / E) per
-  expert (capacity scales with k — 2S assignments need 2x the slots);
-  tokens beyond an expert's capacity are DROPPED for the FFN (their
-  residual stream passes through unchanged) — the standard fixed-shape
-  trade that keeps the whole layer jit-compatible;
-- load-balancing aux loss (Switch eq. 4): E * sum_e(frac_tokens_e *
-  mean_gate_e), minimized at uniform routing; returned in metrics and
-  added to the objective with ``aux_coef``.
+What a family has is read from the statistics' own keys and from the arguments
+it passes, never from which family is calling.
 """
 
 from __future__ import annotations
 
-import dataclasses
-import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.models.gpt2 import GPT2Config
-from distributedvolunteercomputing_tpu.models.registry import SteppedLeaves
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 from distributedvolunteercomputing_tpu.ops.moe_dispatch import share_rows_bound
-
-
-@dataclasses.dataclass(frozen=True)
-class GPT2MoEConfig(GPT2Config):
-    n_experts: int = 8
-    capacity_factor: float = 1.25
-    aux_coef: float = 0.01
-    # Experts each token is routed to: 1 = Switch, 2 = GShard-style top-2
-    # (gates renormalized over the chosen experts; the second choice queues
-    # for capacity AFTER all first choices).
-    router_top_k: int = 1
-    # MoE replaces the dense FFN in EVERY block (Switch layout); d_ff is the
-    # per-expert hidden width.
-
-    def __post_init__(self):
-        if not 1 <= self.router_top_k <= self.n_experts:
-            raise ValueError(
-                f"router_top_k={self.router_top_k} must be in [1, n_experts={self.n_experts}]"
-            )
-
-
-def moe_init(rng: jax.Array, cfg: GPT2MoEConfig) -> common.Params:
-    kr, ki, ko = jax.random.split(rng, 3)
-    e, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
-    res_scale = 1.0 / ((2 * cfg.n_layers) ** 0.5 * d**0.5)
-    return {
-        "router": jax.random.normal(kr, (d, e), jnp.float32) * 0.02,
-        # experts stacked on the leading E axis -> sharded over ep
-        "moe_in": jax.random.normal(ki, (e, d, f), jnp.float32) * 0.02,
-        "moe_out": jax.random.normal(ko, (e, f, d), jnp.float32) * res_scale,
-    }
-
-
-def moe_ffn(p: common.Params, x: jax.Array, cfg: GPT2MoEConfig) -> Tuple[jax.Array, jax.Array]:
-    """x: [B, T, d] -> (y [B, T, d], aux_loss scalar)."""
-    b, t, d = x.shape
-    s = b * t
-    e = cfg.n_experts
-    # ceil, not truncation: capacity_factor=1.25 must mean >= 25% headroom
-    # over the uniform share, never less. Capacity scales with router_top_k
-    # (GShard): top-2 makes 2S total assignments, so per-expert slots must
-    # double for the same factor or ~a third of assignments drop even under
-    # perfectly uniform routing.
-    cap = max(math.ceil(cfg.capacity_factor * cfg.router_top_k * s / e), 1)
-    xs = x.reshape(s, d)
-
-    # Router in f32 (softmax statistics), gates carry the gradient.
-    logits = jnp.einsum("sd,de->se", xs.astype(jnp.float32), p["router"])
-    gates = jax.nn.softmax(logits, axis=-1)  # [S, E]
-    k_router = cfg.router_top_k
-    top_gates, top_idx = jax.lax.top_k(gates, k_router)  # [S, K]
-    if k_router > 1:
-        # GShard: renormalize over the chosen experts so the combined output
-        # is a convex mixture. (Deliberately NOT applied at K=1, matching
-        # Switch — the raw gate carries the router gradient.)
-        top_gates = top_gates / jnp.sum(top_gates, axis=-1, keepdims=True)
-
-    # Per-choice dispatch: choice i's tokens queue for expert capacity AFTER
-    # every earlier choice's assignments (count_prev), the standard GShard
-    # ordering — a token's second choice never displaces a first choice.
-    dispatch = jnp.zeros((s, e, cap), x.dtype)
-    combine = jnp.zeros((s, e, cap), x.dtype)
-    count_prev = jnp.zeros((e,), jnp.float32)
-    onehot1 = None
-    for i in range(k_router):
-        oh = jax.nn.one_hot(top_idx[:, i], e, dtype=jnp.float32)  # [S, E]
-        if i == 0:
-            onehot1 = oh
-        # Position within the expert queue; -1 where unrouted, >= cap drops.
-        pos = (jnp.cumsum(oh, axis=0) + count_prev[None, :]) * oh - 1.0
-        kept = (pos >= 0) & (pos < cap)
-        pos_oh = jax.nn.one_hot(
-            jnp.clip(pos, 0, cap - 1).astype(jnp.int32), cap, dtype=x.dtype
-        )  # [S, E, C]
-        disp = pos_oh * kept.astype(x.dtype)[..., None]
-        dispatch = dispatch + disp
-        combine = combine + disp * top_gates[:, i].astype(x.dtype)[:, None, None]
-        count_prev = count_prev + jnp.sum(oh, axis=0)
-
-    # dispatch/combine einsums: with moe_in/out sharded over ep, GSPMD emits
-    # the all-to-alls here.
-    ein = jnp.einsum("sec,sd->ecd", dispatch, xs)  # [E, C, d]
-    dtype = x.dtype
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", ein, p["moe_in"].astype(dtype)))
-    eout = jnp.einsum("ecf,efd->ecd", h, p["moe_out"].astype(dtype))  # [E, C, d]
-    y = jnp.einsum("sec,ecd->sd", combine, eout)
-
-    # Load-balance loss (Switch eq. 4 / GShard): E * sum_e(frac of tokens
-    # whose FIRST choice is e * mean_gate_e).
-    frac = jnp.mean(onehot1, axis=0)  # [E]
-    mean_gate = jnp.mean(gates, axis=0)  # [E]
-    aux = e * jnp.sum(frac * mean_gate)
-    return y.reshape(b, t, d), aux.astype(jnp.float32)
-
-
-def _layer_init(rng: jax.Array, cfg: GPT2MoEConfig) -> common.Params:
-    k = jax.random.split(rng, 3)
-    res_scale = 1.0 / ((2 * cfg.n_layers) ** 0.5 * cfg.d_model**0.5)
-    return {
-        "ln1": common.layernorm_init(cfg.d_model),
-        "qkv": common.dense_init(k[0], cfg.d_model, 3 * cfg.d_model, scale=0.02),
-        "attn_out": common.dense_init(k[1], cfg.d_model, cfg.d_model, scale=res_scale),
-        "ln2": common.layernorm_init(cfg.d_model),
-        "moe": moe_init(k[2], cfg),
-    }
-
-
-def init(rng: jax.Array, cfg: GPT2MoEConfig) -> common.Params:
-    keys = jax.random.split(rng, 3)
-    return {
-        "wte": common.embed_init(keys[0], cfg.vocab, cfg.d_model),
-        "wpe": common.embed_init(keys[1], cfg.max_len, cfg.d_model, scale=0.01),
-        "blocks": common.stacked_init(
-            lambda k: _layer_init(k, cfg), keys[2], cfg.n_layers
-        ),
-        "ln_f": common.layernorm_init(cfg.d_model),
-    }
-
-
-def _block(p: common.Params, x_aux, cfg: GPT2MoEConfig):
-    x, aux = x_aux
-    h = common.layernorm(p["ln1"], x)
-    q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
-    attn = merge_heads(attention_core(q, k, v, causal=True))
-    x = x + common.dense(p["attn_out"], attn)
-    h = common.layernorm(p["ln2"], x)
-    y, layer_aux = moe_ffn(p["moe"], h, cfg)
-    return x + y, aux + layer_aux
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: jax.Array, cfg: GPT2MoEConfig
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    from distributedvolunteercomputing_tpu.models import gpt2
-
-    x = gpt2.embed(params, batch["tokens"], cfg)
-    aux0 = jnp.zeros((), jnp.float32)
-    (x, aux) = common.scan_blocks(
-        lambda p, xa: _block(p, xa, cfg), params["blocks"], (x, aux0), remat=cfg.remat
-    )
-    x = common.layernorm(params["ln_f"], x)
-    lm = common.lm_xent_chunked(
-        x, params["wte"], batch["targets"], chunk=cfg.xent_chunk, head_layout="vd"
-    )
-    aux = aux / cfg.n_layers
-    loss = lm + cfg.aux_coef * aux
-    return loss, {"loss": loss, "lm_loss": lm, "aux_loss": aux}
-
-
-# ---------------------------------------------------------------------------
-# The sigmoid router with a selection bias that the STEP moves, over a chip's
-# share of the experts: what models/lfm2.py and models/glm4_moe_lite.py have in
-# common. A model's config brings ``top_k``, ``n_experts``, ``experts_held``;
-# its expert layers carry the leaf ``bias`` [E] beside ``router`` [d, E].
-# ---------------------------------------------------------------------------
 
 # the step's metric that ``stepped`` reads: per expert layer, how many of the
 # step's assignments chose each expert ``[L_sparse, E]``
 COUNTS = "moe_expert_counts"
 
 
-def route(p_router: jax.Array, bias: jax.Array, h: jax.Array, top_k: int, routed_scale: float,
-          eps: float):
+def check_share(cfg) -> None:
+    """``top_k`` of the router's outputs, and the held experts a slice of them."""
+    if not 1 <= cfg.top_k <= cfg.n_experts:
+        raise ValueError(f"top_k={cfg.top_k} must be in [1, n_experts={cfg.n_experts}]")
+    if not (0 <= cfg.expert_offset and 1 <= cfg.experts_held
+            and cfg.expert_offset + cfg.experts_held <= cfg.n_experts):
+        raise ValueError(
+            f"experts {cfg.expert_offset}..{cfg.expert_offset + cfg.experts_held} "
+            f"are not a slice of the {cfg.n_experts}")
+
+
+def route(p_router: jax.Array, h: jax.Array, top_k: int, routed_scale: float,
+          bias: Optional[jax.Array] = None, eps: float = 0.0):
     """Router of one layer: ``h`` [S, d] -> (top_idx [S, k], weights [S, k]
-    float32). Sigmoid scores from a float32 product at the highest precision;
-    the selection ``bias`` [E] is added for the CHOICE of the k and for
-    nothing else: the weights are the chosen experts' own scores, normalised
-    to sum to 1 (+``eps`` in the divisor, as the family's public code has it:
-    1e-6 LFM2, 1e-20 GLM-4.7-Flash), times ``routed_scale``."""
+    float32, scores [S, E] float32). Sigmoid scores from a float32 product at
+    the highest precision; the weights are the chosen experts' own scores,
+    normalised to sum to 1 (+``eps`` in the divisor, as the family's public
+    code has it: 1e-6 LFM2, 1e-20 GLM-4.7-Flash, none Laguna), times
+    ``routed_scale``. A selection ``bias`` [E] is added for the CHOICE of the
+    k and for nothing else."""
     logits = jnp.dot(
         h.astype(jnp.float32), p_router, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     scores = jax.nn.sigmoid(logits)
-    _, top_idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
-    top_scores = jnp.take_along_axis(scores, top_idx, axis=-1)
-    weights = routed_scale * top_scores / (jnp.sum(top_scores, axis=-1, keepdims=True) + eps)
-    return top_idx, weights
+    if bias is None:
+        top_scores, top_idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+        top_scores = jnp.take_along_axis(scores, top_idx, axis=-1)
+    scaled = routed_scale * top_scores
+    total = jnp.sum(top_scores, axis=-1, keepdims=True)
+    weights = scaled / ((total + eps) if eps else total)
+    return top_idx, weights, scores
 
 
-def zero_share_stats() -> Dict[str, jax.Array]:
+def zero_share_stats(balanced: int = 0, act_zeros: bool = False,
+                     chunks_extra: bool = False) -> Dict[str, jax.Array]:
+    """The share's statistics before the first expert layer. ``balanced``: the
+    router's outputs E where an auxiliary loss balances it; ``act_zeros``:
+    ReLU-gated experts; ``chunks_extra``: a chunk sized by the family's own
+    ``slack``, whose second chunks are worth counting."""
     zero = jnp.zeros((), jnp.float32)
-    return {
+    stats = {}
+    if balanced:
+        stats["choices"] = jnp.zeros((balanced,), jnp.float32)  # sum over layers of f_e
+        stats["probs"] = jnp.zeros((balanced,), jnp.float32)    # sum over layers of P_e
+    stats.update({
         "load_max": zero,    # fullest held expert of any layer, rows
         "rows_held": zero,   # assignments on held experts, all layers
         "rows_moved": zero,  # rows the dispatch gathered to its grouped matmuls, all layers
         "dropped": zero,     # held assignments no grouped matmul computed
-        "chunks_extra": zero,  # chunks the dispatch ran beyond one a layer, all layers
-    }
+    })
+    if act_zeros:
+        stats["act_zeros"] = zero     # entries of the held rows' gate that the ReLU set to zero
+    if chunks_extra:
+        stats["chunks_extra"] = zero  # chunks the dispatch ran beyond one a layer, all layers
+    return stats
 
 
-def note_share(stats: Dict[str, jax.Array], top_idx: jax.Array, group_sizes: jax.Array,
-               dropped: jax.Array, moved: jax.Array, cfg, slack: float):
-    """The running statistics with one expert layer's dispatch added
-    (``ops/moe_dispatch.share_glu_experts``'s ``group_sizes``, ``dropped``,
-    ``moved``), and how many of its assignments chose each expert ``[E]``."""
-    cap = share_rows_bound(top_idx.shape[0], cfg.top_k, cfg.experts_held, cfg.n_experts, slack)
+def note_share(stats: Dict[str, jax.Array], top_idx: jax.Array, dispatch, cfg,
+               slack: Optional[float] = None, probs: Optional[jax.Array] = None,
+               scores: Optional[jax.Array] = None):
+    """The running statistics with one expert layer added, and how many of its
+    assignments chose each expert ``[E]``. ``dispatch``: what
+    ``ops/moe_dispatch.share_glu_experts`` returned beside ``y``
+    (``group_sizes``, ``dropped``, ``moved``, ``act_zeros``), at ``slack``.
+    Statistics with ``choices`` / ``probs`` take the router's distribution
+    over all E ``[S, E]``: ``probs`` as it is, or ``scores`` normalised here."""
+    group_sizes, dropped, moved, act_zeros = dispatch
     chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1))
     load = group_sizes.astype(jnp.float32)
-    stats = {
+    new = {}
+    if "choices" in stats:
+        new["choices"] = stats["choices"] + chosen / top_idx.shape[0]
+        if scores is not None:
+            probs = scores / jnp.sum(scores, -1, keepdims=True)
+        new["probs"] = stats["probs"] + jnp.mean(probs, axis=0)
+    new.update({
         "load_max": jnp.maximum(stats["load_max"], jnp.max(load)),
         "rows_held": stats["rows_held"] + jnp.sum(load),
         "rows_moved": stats["rows_moved"] + moved.astype(jnp.float32),
         "dropped": stats["dropped"] + dropped.astype(jnp.float32),
+    })
+    if "act_zeros" in stats:
+        new["act_zeros"] = stats["act_zeros"] + act_zeros.astype(jnp.float32)
+    if "chunks_extra" in stats:
         # ``moved`` is whole chunks of ``cap`` rows (all S k, at most one chunk's, with every expert held)
-        "chunks_extra": stats["chunks_extra"] + ((moved + cap - 1) // cap - 1).astype(jnp.float32),
-    }
-    return stats, chosen
+        cap = share_rows_bound(top_idx.shape[0], cfg.top_k, cfg.experts_held, cfg.n_experts, slack)
+        new["chunks_extra"] = stats["chunks_extra"] + ((moved + cap - 1) // cap - 1).astype(jnp.float32)
+    return new, chosen
+
+
+def balance_loss(stats: Dict[str, jax.Array], n_layers: int, n_experts: int) -> jax.Array:
+    """``E sum_e f_e P_e`` of the ``n_layers`` expert layers the statistics
+    hold: means over layers and tokens first, product after."""
+    return n_experts * jnp.sum((stats["choices"] / n_layers) * (stats["probs"] / n_layers))
 
 
 def run_layers(runs, blocks, x: jax.Array, stats: Dict[str, jax.Array], remat: bool,
@@ -291,27 +178,42 @@ def bias_steps(counts: jax.Array) -> jax.Array:
     return jnp.sign(jnp.mean(counts, axis=-1, keepdims=True) - counts)
 
 
-def share_metrics(loss: jax.Array, stats: Dict[str, jax.Array], params: common.Params,
-                  counts: jax.Array, n_tokens: int, cfg) -> Dict[str, jax.Array]:
-    """The step's metrics of a model whose routers carry the stepped bias."""
-    bias = biases(params)
-    return {
-        "loss": loss, "lm_loss": loss, "aux_loss": jnp.zeros((), jnp.float32),
-        # as models/laguna.py: over the held experts, summed over the expert layers
+def share_metrics(loss: jax.Array, lm: jax.Array, aux: jax.Array, stats: Dict[str, jax.Array],
+                  n_tokens: int, cfg, params: Optional[common.Params] = None,
+                  counts: Optional[jax.Array] = None) -> Dict[str, jax.Array]:
+    """The step's metrics of a model that holds a share of its experts; with
+    ``counts`` (``run_layers``'s, and the ``params`` they were counted under),
+    those of routers that carry the stepped bias as well."""
+    metrics = {
+        "loss": loss, "lm_loss": lm, "aux_loss": aux,
+        # over the held experts: the fullest of any layer and the even share of
+        # a layer's S k assignments; the assignments on held experts and the
+        # rows the dispatch gathered for them, summed over the expert layers;
+        # held assignments no grouped matmul computed
         "moe_load_max": stats["load_max"],
         "moe_load_mean": jnp.asarray(n_tokens * cfg.top_k / cfg.n_experts, jnp.float32),
         "moe_rows_held": stats["rows_held"],
         "moe_rows_moved": stats["rows_moved"],
         "moe_dropped": stats["dropped"],
-        # how many chunks beyond one a layer the levelled bound cost this step
-        "moe_chunks_extra": stats["chunks_extra"],
+    }
+    if "act_zeros" in stats:
+        # of the held assignments' d_expert hidden activations each, the share
+        # whose gate the ReLU set to exactly zero (what a sparse down-projection
+        # could skip); the mask is the activation's own
+        metrics["moe_act_zero_share"] = stats["act_zeros"] / jnp.maximum(
+            stats["rows_held"] * cfg.d_expert, 1.0)
+    if "chunks_extra" in stats:
+        # how many chunks beyond one a layer the family's bound cost this step
+        metrics["moe_chunks_extra"] = stats["chunks_extra"]
+    if counts is not None:
+        bias = biases(params)
         # the selection biases this step chose with, over layers and experts,
         # and how many of them the step's rule then moves
-        "moe_bias_max": jnp.max(bias),
-        "moe_bias_min": jnp.min(bias),
-        "moe_bias_moved": jnp.sum(bias_steps(counts) != 0).astype(jnp.float32),
-        COUNTS: counts,  # the step's own: ``stepped`` reads it, the loop never sees it
-    }
+        metrics["moe_bias_max"] = jnp.max(bias)
+        metrics["moe_bias_min"] = jnp.min(bias)
+        metrics["moe_bias_moved"] = jnp.sum(bias_steps(counts) != 0).astype(jnp.float32)
+        metrics[COUNTS] = counts  # the step's own: ``stepped`` reads it, the loop never sees it
+    return metrics
 
 
 def is_bias(path: Tuple) -> bool:
@@ -335,8 +237,8 @@ def balance(params: common.Params, counts: jax.Array, gamma: float) -> common.Pa
 
 def stepped(gamma: float):
     """What the train step needs to move the selection biases itself
-    (``models/registry.SteppedLeaves``)."""
-    return SteppedLeaves(
+    (``models/common.SteppedLeaves``)."""
+    return common.SteppedLeaves(
         signal=COUNTS,
         owns=lambda params: jax.tree_util.tree_map_with_path(lambda path, _: is_bias(path), params),
         rule=lambda params, counts: balance(params, counts, gamma),

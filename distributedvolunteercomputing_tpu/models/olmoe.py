@@ -41,7 +41,7 @@ which the reference check's routing-flip tolerance is glad of); the rotary
 angles are computed in float32.
 
 The expert layer is ``ops/moe_dispatch.py``'s sorted dropless dispatch;
-``gpt2_moe`` (``models/moe.py``) keeps its dense one-hot dispatch with a
+``gpt2_moe`` (``models/gpt2_moe.py``) keeps its dense one-hot dispatch with a
 capacity, top-1/2 and renormalised gates.
 """
 
@@ -53,7 +53,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from distributedvolunteercomputing_tpu.models import common
+from distributedvolunteercomputing_tpu.models import common, moe
+from distributedvolunteercomputing_tpu.models.common import matrix, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads,
 )
@@ -86,29 +87,21 @@ class OlmoeConfig:
             raise ValueError(f"d_model={self.d_model} is not a multiple of n_heads={self.n_heads}")
 
 
-def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, shape, jnp.float32) * scale
-
-
 def _layer_init(rng: jax.Array, cfg: OlmoeConfig) -> common.Params:
     k = jax.random.split(rng, 8)
     d, f, e = cfg.d_model, cfg.d_expert, cfg.n_experts
     return {
         "ln_attn": common.rmsnorm_init(d),
-        "wq": _matrix(k[0], (d, d)),
-        "wk": _matrix(k[1], (d, d)),
-        "wv": _matrix(k[2], (d, d)),
-        "wo": _matrix(k[3], (d, d)),
+        "wq": matrix(k[0], (d, d)),
+        "wk": matrix(k[1], (d, d)),
+        "wv": matrix(k[2], (d, d)),
+        "wo": matrix(k[3], (d, d)),
         "q_norm": common.rmsnorm_init(d),
         "k_norm": common.rmsnorm_init(d),
         "ln_mlp": common.rmsnorm_init(d),
-        "router": _matrix(k[4], (d, e)),
+        "router": matrix(k[4], (d, e)),
         # experts stacked on a leading E axis -> sharded over ep (parallel/sharding.py)
-        "experts": {
-            "w_gate": _matrix(k[5], (e, d, f)),
-            "w_up": _matrix(k[6], (e, d, f)),
-            "w_down": _matrix(k[7], (e, f, d)),
-        },
+        "experts": swiglu_init(k, d, f, (e,), first=5),
     }
 
 
@@ -118,7 +111,7 @@ def init(rng: jax.Array, cfg: OlmoeConfig) -> common.Params:
         "wte": common.embed_init(keys[0], cfg.vocab, cfg.d_model),
         "blocks": common.stacked_init(lambda k: _layer_init(k, cfg), keys[1], cfg.n_layers),
         "ln_f": common.rmsnorm_init(cfg.d_model),
-        "lm_head": _matrix(keys[2], (cfg.d_model, cfg.vocab)),
+        "lm_head": matrix(keys[2], (cfg.d_model, cfg.vocab)),
     }
 
 
@@ -208,9 +201,8 @@ def loss_and_routes(
     lm = common.lm_xent_chunked(
         x, params["lm_head"], batch["targets"], chunk=cfg.xent_chunk, head_layout="dv"
     )
-    n = cfg.n_layers
-    aux = cfg.n_experts * jnp.sum((stats["choices"] / n) * (stats["probs"] / n))
-    z = stats["z"] / n
+    aux = moe.balance_loss(stats, cfg.n_layers, cfg.n_experts)
+    z = stats["z"] / cfg.n_layers
     loss = lm + cfg.aux_coef * aux + cfg.z_coef * z
     rows = tokens.size * cfg.top_k  # assignments a layer routes
     metrics = {
@@ -223,9 +215,3 @@ def loss_and_routes(
         "moe_dropped": stats["dropped"],
     }
     return loss, metrics, routes
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: jax.Array, cfg: OlmoeConfig
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return loss_and_routes(params, batch, cfg)[:2]

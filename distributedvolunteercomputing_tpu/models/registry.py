@@ -15,9 +15,13 @@ zoo. Each bundle closes over its config and exposes:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+
+from distributedvolunteercomputing_tpu.models.common import SteppedLeaves
 
 Batch = Dict[str, jax.Array]
 Metrics = Dict[str, jax.Array]
@@ -29,31 +33,6 @@ def _identity_select(params: Any) -> Any:
 
 def _identity_merge(params: Any, averaged: Any) -> Any:
     return averaged
-
-
-@dataclasses.dataclass(frozen=True)
-class SteppedLeaves:
-    """Leaves of the parameters that the STEP moves, by the model's own rule
-    and from the step's own metrics, and the optimizer does not: a state that
-    no gradient reaches (a router's selection bias, which load balancing
-    without an auxiliary loss raises and lowers by the experts' loads). A
-    model's bundle names them (``ModelBundle.stepped``); every builder of a
-    step takes them (``stepped=``) and hands them to ``train_step_body``, the
-    one place they act. A bundle that names none compiles to the program it
-    compiled to before there was such a thing.
-
-    ``signal``: the key of the loss function's metrics that the rule reads. It
-    need not be a scalar; the step takes it out of the metrics it returns.
-    ``owns(params)``: a tree of bools shaped like ``params``, True on the
-    leaves the rule owns. Their gradient is zeroed before the optimizer sees
-    it (no share of a global-norm clip) and whatever the optimizer makes of
-    them is discarded: an owned leaf after the step is ``rule``'s.
-    ``rule(params, signal)``: a tree shaped like ``params`` whose owned leaves
-    are the new values, from the parameters as they were BEFORE the update."""
-
-    signal: str
-    owns: Callable[[Any], Any]
-    rule: Callable[[Any, Any], Any]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,158 +100,6 @@ def _bert(**overrides: Any) -> ModelBundle:
     )
 
 
-def _gpt2(**overrides: Any) -> ModelBundle:
-    from distributedvolunteercomputing_tpu.models import gpt2
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(gpt2.GPT2Config(), **overrides)
-    return ModelBundle(
-        name="gpt2_small",
-        config=cfg,
-        init=lambda rng: gpt2.init(rng, cfg),
-        loss_fn=lambda p, b, rng: gpt2.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _gpt2_preset(preset: str, **overrides: Any) -> ModelBundle:
-    """gpt2_medium / gpt2_large as first-class registry names: the scale
-    rungs above the flagship (GPT2Config.medium/.large presets), nameable
-    from the CLI (--model) and a benchmark configuration without a
-    config-override incantation. Overrides still apply on top."""
-    from distributedvolunteercomputing_tpu.models import gpt2
-    from distributedvolunteercomputing_tpu.training import data
-
-    base = getattr(gpt2.GPT2Config, preset)()
-    cfg = dataclasses.replace(base, **overrides)
-    return ModelBundle(
-        name=f"gpt2_{preset}",
-        config=cfg,
-        init=lambda rng: gpt2.init(rng, cfg),
-        loss_fn=lambda p, b, rng: gpt2.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _gpt2_moe(**overrides: Any) -> ModelBundle:
-    from distributedvolunteercomputing_tpu.models import moe
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(moe.GPT2MoEConfig(), **overrides)
-    return ModelBundle(
-        name="gpt2_moe",
-        config=cfg,
-        init=lambda rng: moe.init(rng, cfg),
-        loss_fn=lambda p, b, rng: moe.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _olmoe(**overrides: Any) -> ModelBundle:
-    """OLMoE-1B-7B at its published sizes (models/olmoe.py): 16 layers need a
-    four-chip host; ``n_layers`` cuts the depth to what a chip holds."""
-    from distributedvolunteercomputing_tpu.models import olmoe
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(olmoe.OlmoeConfig(), **overrides)
-    return ModelBundle(
-        name="olmoe_1b_7b",
-        config=cfg,
-        init=lambda rng: olmoe.init(rng, cfg),
-        loss_fn=lambda p, b, rng: olmoe.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _laguna(**overrides: Any) -> ModelBundle:
-    """Laguna-XS.2 at its published sizes (models/laguna.py): 40 layers of 256
-    experts are many chips' work; ``n_layers``, ``experts_held`` /
-    ``expert_offset`` and ``vocab`` cut it to one chip's share."""
-    from distributedvolunteercomputing_tpu.models import laguna
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(laguna.LagunaConfig(), **overrides)
-    return ModelBundle(
-        name="laguna_xs2",
-        config=cfg,
-        init=lambda rng: laguna.init(rng, cfg),
-        loss_fn=lambda p, b, rng: laguna.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _smallthinker(**overrides: Any) -> ModelBundle:
-    """SmallThinker-21BA3B-Instruct at its published sizes
-    (models/smallthinker.py): 52 layers of 64 experts are many chips' work;
-    ``n_layers`` (whole periods of four), ``experts_held`` / ``expert_offset``
-    and ``vocab`` cut it to one chip's share."""
-    from distributedvolunteercomputing_tpu.models import smallthinker
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(smallthinker.SmallThinkerConfig(), **overrides)
-    return ModelBundle(
-        name="smallthinker_21b_a3b",
-        config=cfg,
-        init=lambda rng: smallthinker.init(rng, cfg),
-        loss_fn=lambda p, b, rng: smallthinker.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-    )
-
-
-def _lfm2(**overrides: Any) -> ModelBundle:
-    """LFM2-24B-A2B at its published sizes (models/lfm2.py): 40 layers of 64
-    experts are many chips' work; ``layer_types`` with ``dense_layers``,
-    ``experts_held`` / ``expert_offset`` and ``vocab`` cut it to one chip's
-    share. Its routers' selection biases are the step's to move."""
-    from distributedvolunteercomputing_tpu.models import lfm2
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(lfm2.LFM2Config(), **overrides)
-    return ModelBundle(
-        name="lfm2_24b_a2b",
-        config=cfg,
-        init=lambda rng: lfm2.init(rng, cfg),
-        loss_fn=lambda p, b, rng: lfm2.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-        stepped=lfm2.stepped(cfg),
-    )
-
-
-def _glm4_7_flash(**overrides: Any) -> ModelBundle:
-    """GLM-4.7-Flash at its published sizes (models/glm4_moe_lite.py): 47
-    layers of latent attention and 64 experts are many chips' work;
-    ``n_layers``, ``experts_held`` / ``expert_offset`` and ``vocab`` cut it to
-    one chip's share. Its routers' selection biases are the step's to move."""
-    from distributedvolunteercomputing_tpu.models import glm4_moe_lite
-    from distributedvolunteercomputing_tpu.training import data
-
-    cfg = dataclasses.replace(glm4_moe_lite.Glm4MoeLiteConfig(), **overrides)
-    return ModelBundle(
-        name="glm4_7_flash",
-        config=cfg,
-        init=lambda rng: glm4_moe_lite.init(rng, cfg),
-        loss_fn=lambda p, b, rng: glm4_moe_lite.loss_fn(p, b, rng, cfg),
-        make_batch=lambda rng, bs: data.synthetic_lm_batch(
-            rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
-        ),
-        stepped=glm4_moe_lite.stepped(cfg),
-    )
-
-
 def _vit(**overrides: Any) -> ModelBundle:
     from distributedvolunteercomputing_tpu.models import vit
     from distributedvolunteercomputing_tpu.training import data
@@ -291,22 +118,65 @@ def _vit(**overrides: Any) -> ModelBundle:
     )
 
 
-def _llama_lora(**overrides: Any) -> ModelBundle:
-    from distributedvolunteercomputing_tpu.models import llama
+# The language models: name -> (its module under models/, what makes its
+# config there: a class, or one of a class's presets). The expert families run at
+# their published sizes; each takes the overrides that cut it to one chip's
+# share without touching a width (the module's docstring says what the cut
+# leaves out).
+_LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
+    "gpt2_small": ("gpt2", "GPT2Config"),
+    # the scale rungs above the flagship, nameable from the CLI (--model) and
+    # a benchmark configuration without a config-override incantation
+    "gpt2_medium": ("gpt2", "GPT2Config.medium"),
+    "gpt2_large": ("gpt2", "GPT2Config.large"),
+    "gpt2_moe": ("gpt2_moe", "GPT2MoEConfig"),
+    # 16 layers need a four-chip host; ``n_layers`` cuts the depth to what a chip holds
+    "olmoe_1b_7b": ("olmoe", "OlmoeConfig"),
+    # 40 layers of 256 experts: ``n_layers``, ``experts_held`` / ``expert_offset``, ``vocab``
+    "laguna_xs2": ("laguna", "LagunaConfig"),
+    # 52 layers of 64 experts: ``n_layers`` (whole periods of four), ``experts_held`` /
+    # ``expert_offset``, ``vocab``
+    "smallthinker_21b_a3b": ("smallthinker", "SmallThinkerConfig"),
+    # 40 layers of 64 experts: ``layer_types`` with ``dense_layers``, ``experts_held`` /
+    # ``expert_offset``, ``vocab``; its routers' selection biases are the step's to move
+    "lfm2_24b_a2b": ("lfm2", "LFM2Config"),
+    # 47 layers of latent attention and 64 experts: ``n_layers``, ``experts_held`` /
+    # ``expert_offset``, ``vocab``; its routers' selection biases are the step's to move
+    "glm4_7_flash": ("glm4_moe_lite", "Glm4MoeLiteConfig"),
+    "llama_lora": ("llama", "LlamaConfig"),
+}
+
+
+def _language_model(name: str, **overrides: Any) -> ModelBundle:
+    """The bundle of one of ``_LANGUAGE_MODELS``. A module brings ``init(rng,
+    cfg)`` and either ``loss_and_routes(params, batch, cfg)`` (an expert
+    family: the loss is its first two results) or ``loss_fn(params, batch,
+    rng, cfg)``; ``stepped(cfg)`` where the step moves leaves of its own; and,
+    where its config has a ``lora_rank`` above 0, the subtree the swarm
+    averages (``lora_subtree`` / ``with_lora_subtree``)."""
     from distributedvolunteercomputing_tpu.training import data
 
-    cfg = dataclasses.replace(llama.LlamaConfig(), **overrides)
-    lora_on = cfg.lora_rank > 0
+    module_name, make_config = _LANGUAGE_MODELS[name]
+    module = importlib.import_module(f"{__package__}.{module_name}")
+    cfg = dataclasses.replace(functools.reduce(getattr, make_config.split("."), module)(), **overrides)
+    if hasattr(module, "loss_and_routes"):
+        def loss_fn(params, batch, rng):
+            return module.loss_and_routes(params, batch, cfg)[:2]
+    else:
+        def loss_fn(params, batch, rng):
+            return module.loss_fn(params, batch, rng, cfg)
+    lora_on = getattr(cfg, "lora_rank", 0) > 0
     return ModelBundle(
-        name="llama_lora",
+        name=name,
         config=cfg,
-        init=lambda rng: llama.init(rng, cfg),
-        loss_fn=lambda p, b, rng: llama.loss_fn(p, b, rng, cfg),
+        init=lambda rng: module.init(rng, cfg),
+        loss_fn=loss_fn,
         make_batch=lambda rng, bs: data.synthetic_lm_batch(
             rng, bs, seq_len=cfg.max_len, vocab=cfg.vocab
         ),
-        avg_select=llama.lora_subtree if lora_on else _identity_select,
-        avg_merge=llama.with_lora_subtree if lora_on else _identity_merge,
+        avg_select=module.lora_subtree if lora_on else _identity_select,
+        avg_merge=module.with_lora_subtree if lora_on else _identity_merge,
+        stepped=module.stepped(cfg) if hasattr(module, "stepped") else None,
     )
 
 
@@ -315,16 +185,7 @@ _REGISTRY: Dict[str, Callable[..., ModelBundle]] = {
     "cifar10_resnet18": _resnet18,
     "cifar10_vit": _vit,
     "bert_mlm": _bert,
-    "gpt2_small": _gpt2,
-    "gpt2_medium": lambda **kw: _gpt2_preset("medium", **kw),
-    "gpt2_large": lambda **kw: _gpt2_preset("large", **kw),
-    "gpt2_moe": _gpt2_moe,
-    "olmoe_1b_7b": _olmoe,
-    "laguna_xs2": _laguna,
-    "smallthinker_21b_a3b": _smallthinker,
-    "lfm2_24b_a2b": _lfm2,
-    "glm4_7_flash": _glm4_7_flash,
-    "llama_lora": _llama_lora,
+    **{name: functools.partial(_language_model, name) for name in _LANGUAGE_MODELS},
 }
 
 

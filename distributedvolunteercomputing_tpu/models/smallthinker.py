@@ -61,12 +61,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from distributedvolunteercomputing_tpu.models import common
+from distributedvolunteercomputing_tpu.models import common, moe
+from distributedvolunteercomputing_tpu.models.common import matrix, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import (
     attention_core, merge_heads, rope, split_heads,
 )
@@ -118,13 +119,7 @@ class SmallThinkerConfig:
     router_site = "layer_input"
 
     def __post_init__(self):
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(f"top_k={self.top_k} must be in [1, n_experts={self.n_experts}]")
-        if not (0 <= self.expert_offset and 1 <= self.experts_held
-                and self.expert_offset + self.experts_held <= self.n_experts):
-            raise ValueError(
-                f"experts {self.expert_offset}..{self.expert_offset + self.experts_held} "
-                f"are not a slice of the {self.n_experts}")
+        moe.check_share(self)
         if self.n_heads % self.n_kv_heads:
             raise ValueError(
                 f"{self.n_kv_heads} key/value heads do not divide {self.n_heads} query heads")
@@ -140,25 +135,19 @@ class SmallThinkerConfig:
         return GLOBAL if layer % self.period == 0 else SLIDING
 
 
-def _matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:
-    return jax.random.normal(rng, shape, jnp.float32) * scale
-
-
 def _layer_init(rng: jax.Array, cfg: SmallThinkerConfig) -> common.Params:
     k = jax.random.split(rng, 8)
     d, hd, f = cfg.d_model, cfg.head_dim, cfg.d_expert
     return {
         "ln_attn": common.rmsnorm_init(d),
-        "wq": _matrix(k[0], (d, cfg.n_heads * hd)),
-        "wk": _matrix(k[1], (d, cfg.n_kv_heads * hd)),
-        "wv": _matrix(k[2], (d, cfg.n_kv_heads * hd)),
-        "wo": _matrix(k[3], (cfg.n_heads * hd, d)),
+        "wq": matrix(k[0], (d, cfg.n_heads * hd)),
+        "wk": matrix(k[1], (d, cfg.n_kv_heads * hd)),
+        "wv": matrix(k[2], (d, cfg.n_kv_heads * hd)),
+        "wo": matrix(k[3], (cfg.n_heads * hd, d)),
         "ln_mlp": common.rmsnorm_init(d),
-        "router": _matrix(k[4], (d, cfg.n_experts)),
+        "router": matrix(k[4], (d, cfg.n_experts)),
         # the held experts stacked on a leading axis -> sharded over ep (parallel/sharding.py)
-        "experts": {"w_gate": _matrix(k[5], (cfg.experts_held, d, f)),
-                    "w_up": _matrix(k[6], (cfg.experts_held, d, f)),
-                    "w_down": _matrix(k[7], (cfg.experts_held, f, d))},
+        "experts": swiglu_init(k, d, f, (cfg.experts_held,), first=5),
     }
 
 
@@ -174,7 +163,7 @@ def init(rng: jax.Array, cfg: SmallThinkerConfig) -> common.Params:
         "blocks": {GLOBAL: jax.vmap(one)(layer_keys[:, 0]),
                    SLIDING: jax.vmap(jax.vmap(one))(layer_keys[:, 1:])},
         "ln_f": common.rmsnorm_init(cfg.d_model),
-        "lm_head": _matrix(keys[2], (cfg.d_model, cfg.vocab)),
+        "lm_head": matrix(keys[2], (cfg.d_model, cfg.vocab)),
     }
 
 
@@ -190,19 +179,6 @@ def route(p_router: jax.Array, x: jax.Array, top_k: int):
     )
     top_logits, top_idx = jax.lax.top_k(logits, top_k)
     return top_idx, jax.nn.softmax(top_logits, axis=-1), jax.nn.softmax(logits, axis=-1)
-
-
-def _zero_stats(cfg: SmallThinkerConfig) -> Dict[str, jax.Array]:
-    zero = jnp.zeros((), jnp.float32)
-    return {
-        "choices": jnp.zeros((cfg.n_experts,), jnp.float32),  # sum over layers of f_e
-        "probs": jnp.zeros((cfg.n_experts,), jnp.float32),    # sum over layers of P_e
-        "load_max": zero,    # fullest held expert of any layer, rows
-        "rows_held": zero,   # assignments on held experts, all layers
-        "rows_moved": zero,  # rows the dispatch gathered to its grouped matmuls, all layers
-        "dropped": zero,     # held assignments no grouped matmul computed
-        "act_zeros": zero,   # entries of the held rows' gate that the ReLU set to zero
-    }
 
 
 def _attention(p: common.Params, x: jax.Array, cfg: SmallThinkerConfig, kind: str) -> jax.Array:
@@ -233,22 +209,12 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Sma
     with jax.named_scope("moe"):
         h = common.rmsnorm(p["ln_mlp"], x, cfg.rms_eps).reshape(b * t, d)
         ex = p["experts"]
-        y, group_sizes, dropped, moved, zeros = share_glu_experts(
+        y, *dispatch = share_glu_experts(
             h, top_idx, weights, ex["w_gate"], ex["w_up"], ex["w_down"],
             cfg.expert_offset, cfg.n_experts, act="relu", plan=plan, slack=SHARE_ROWS_SLACK,
         )
         x = x + y.reshape(b, t, d)
-        chosen = jnp.sum(jax.nn.one_hot(top_idx, cfg.n_experts, dtype=jnp.float32), axis=(0, 1))
-        load = group_sizes.astype(jnp.float32)
-        stats = {
-            "choices": stats["choices"] + chosen / (b * t),
-            "probs": stats["probs"] + jnp.mean(probs, axis=0),
-            "load_max": jnp.maximum(stats["load_max"], jnp.max(load)),
-            "rows_held": stats["rows_held"] + jnp.sum(load),
-            "rows_moved": stats["rows_moved"] + moved.astype(jnp.float32),
-            "dropped": stats["dropped"] + dropped.astype(jnp.float32),
-            "act_zeros": stats["act_zeros"] + zeros.astype(jnp.float32),
-        }
+        stats, _ = moe.note_share(stats, top_idx, dispatch, cfg, probs=probs)
     return x, stats, top_idx
 
 
@@ -275,7 +241,8 @@ def _trunk(params: common.Params, tokens: jax.Array, cfg: SmallThinkerConfig):
         carry, rest = jax.lax.scan(sliding_step, (x, stats), p[SLIDING])
         return carry, jnp.concatenate([first[None], rest])
 
-    (x, stats), routes = jax.lax.scan(period_step, (x, _zero_stats(cfg)), params["blocks"])
+    stats = moe.zero_share_stats(balanced=cfg.n_experts, act_zeros=True)
+    (x, stats), routes = jax.lax.scan(period_step, (x, stats), params["blocks"])
     routes = routes.reshape(cfg.n_layers, tokens.size, cfg.top_k)
     return common.rmsnorm(params["ln_f"], x, cfg.rms_eps), stats, routes
 
@@ -290,28 +257,6 @@ def loss_and_routes(
     lm = common.lm_xent_chunked(
         x, params["lm_head"], batch["targets"], chunk=cfg.xent_chunk, head_layout="dv"
     )
-    n = cfg.n_layers
-    aux = cfg.n_experts * jnp.sum((stats["choices"] / n) * (stats["probs"] / n))
+    aux = moe.balance_loss(stats, cfg.n_layers, cfg.n_experts)
     loss = lm + cfg.aux_coef * aux
-    metrics = {
-        "loss": loss, "lm_loss": lm, "aux_loss": aux,
-        # as models/laguna.py: over the held experts, summed over the layers
-        "moe_load_max": stats["load_max"],
-        "moe_load_mean": jnp.asarray(tokens.size * cfg.top_k / cfg.n_experts, jnp.float32),
-        "moe_rows_held": stats["rows_held"],
-        "moe_rows_moved": stats["rows_moved"],
-        "moe_dropped": stats["dropped"],
-        # of the held assignments' d_expert hidden activations each, the share
-        # whose gate the ReLU set to exactly zero (what a sparse down-projection
-        # could skip); the mask is the activation's own
-        "moe_act_zero_share": stats["act_zeros"] / jnp.maximum(
-            stats["rows_held"] * cfg.d_expert, 1.0),
-    }
-    return loss, metrics, routes
-
-
-def loss_fn(
-    params: common.Params, batch: Dict[str, jax.Array], rng: Optional[jax.Array],
-    cfg: SmallThinkerConfig
-) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    return loss_and_routes(params, batch, cfg)[:2]
+    return loss, moe.share_metrics(loss, lm, aux, stats, tokens.size, cfg), routes

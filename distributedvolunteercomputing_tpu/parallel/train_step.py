@@ -130,7 +130,7 @@ def make_sharded_train_step(
     zero1: bool = False,
     fsdp: bool = False,
     sp_impl: str = "ring",
-    stepped: Any = None,  # models/registry.SteppedLeaves: leaves the step moves by the model's rule
+    stepped: Any = None,  # models/common.SteppedLeaves: leaves the step moves by the model's rule
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, Metrics]]:
     """Build the jitted sharded ``(state, batch) -> (state, metrics)`` step.
 
@@ -224,7 +224,7 @@ def make_sharded_multi_step(
     zero1: bool = False,
     fsdp: bool = False,
     sp_impl: str = "ring",
-    stepped: Any = None,  # models/registry.SteppedLeaves: leaves the step moves by the model's rule
+    stepped: Any = None,  # models/common.SteppedLeaves: leaves the step moves by the model's rule
 ) -> Callable[[TrainState, Batch], Tuple[TrainState, jax.Array]]:
     """N sharded train steps in ONE compiled call: ``(state,
     stacked_batches) -> (state, per_step_losses)``.

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from distributedvolunteercomputing_tpu.models.registry import SteppedLeaves
+from distributedvolunteercomputing_tpu.models.common import SteppedLeaves
 
 Batch = Dict[str, jax.Array]
 Metrics = Dict[str, jax.Array]

@@ -394,7 +394,7 @@ class Trainer:
                         self.state, mesh, self.tx, fsdp=fsdp
                     )
                 self._put_batch = lambda b: put_batch(b, mesh, seq_sharded=seq_sharded)
-            # leaves the step moves by the model's own rule (models/registry.SteppedLeaves)
+            # leaves the step moves by the model's own rule (models/common.SteppedLeaves)
             self._stepped = stepped = bundle.stepped
             if self._grads_mode:
                 # The split steps are plain jits: with mesh-sharded inputs GSPMD

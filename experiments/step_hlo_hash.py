@@ -1,12 +1,18 @@
-"""sha256 of each benchmark configuration's lowered train step (StableHLO text, one chip, no mesh):
-run at two commits, equal hashes say a change left a configuration's program as it was.
+"""sha256 of each benchmark configuration's lowered train step and of its lowered ``bundle.init``
+(StableHLO text, one chip, no mesh): run at two commits, equal hashes say a change left a
+configuration's program, and the program that makes its initial parameters, as they were.
 
     JAX_PLATFORMS=cpu python experiments/step_hlo_hash.py [config ...]
+
+On the chip the lowering holds the Pallas kernels, each a serialised module WITH its operations' locations: with
+Python frames in them (JAX's default, ten a location) the hash would follow the line numbers of every file on the
+way to the kernel and the checkout's path, so locations here are the name stacks alone.
 
 A configuration the checkout's registry does not know is skipped with a line that says so."""
 import hashlib, json, sys, os
 sys.path.insert(0, os.getcwd())
 import jax
+jax.config.update("jax_traceback_in_locations_limit", 0)
 from distributedvolunteercomputing_tpu.models import get_model
 from distributedvolunteercomputing_tpu.training.optim import make_optimizer
 from distributedvolunteercomputing_tpu.training.steps import TrainState, make_train_step
@@ -30,4 +36,7 @@ for name in sys.argv[1:] or CELLS:
     batch = jax.eval_shape(lambda: bundle.make_batch(jax.random.PRNGKey(2), bs))
     kw = {"stepped": bundle.stepped} if hasattr(bundle, "stepped") else {}
     text = make_train_step(bundle.loss_fn, tx, **kw).lower(state, batch).as_text()
-    print(json.dumps({"config": name, "lines": len(text.splitlines()), "sha256": hashlib.sha256(text.encode()).hexdigest()}))
+    init = jax.jit(bundle.init).lower(jax.random.PRNGKey(0)).as_text()
+    print(json.dumps({"config": name, "device": jax.devices()[0].platform, "lines": len(text.splitlines()),
+                      "sha256": hashlib.sha256(text.encode()).hexdigest(), "init_lines": len(init.splitlines()),
+                      "init_sha256": hashlib.sha256(init.encode()).hexdigest()}))

@@ -212,8 +212,8 @@ def test_a_token_changes_nothing_before_it(run):
     ffn = "dense" if run == 0 else "sparse"
     at = 40
     other = x.at[0, at].set(x[0, at] + 1.0)
-    a, _, _ = glm._layer(p, x, moe.zero_share_stats(), cfg, ffn)
-    b, _, _ = glm._layer(p, other, moe.zero_share_stats(), cfg, ffn)
+    a, _, _ = glm._layer(p, x, moe.zero_share_stats(chunks_extra=True), cfg, ffn)
+    b, _, _ = glm._layer(p, other, moe.zero_share_stats(chunks_extra=True), cfg, ffn)
     diff = np.abs(np.asarray(a - b)).max(axis=-1)[0]
     assert diff[:at].max() == 0.0 and diff[at] > 0 and np.nonzero(diff)[0].max() == cfg.max_len - 1
 
@@ -280,25 +280,7 @@ def test_latent_attention_goes_through_the_core_at_one_head_dim():
     assert 256 in attention._AUTO_FLASH_HEAD_DIMS  # the published head takes the kernel on a chip
 
 
-# -- the router and its selection bias -----------------------------------------------------
-
-
-def test_the_bias_changes_the_choice_and_not_the_weights_which_sum_to_the_scaling_factor():
-    x = jax.random.normal(jax.random.PRNGKey(0), (40, 16))
-    w = jax.random.normal(jax.random.PRNGKey(1), (16, 12))
-    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (12,))
-    scores = np.asarray(jax.nn.sigmoid(x @ w), np.float64)
-    idx0, w0 = moe.route(w, jnp.zeros(12), x, 4, 1.8, glm.ROUTE_EPS)
-    idx1, w1 = moe.route(w, bias, x, 4, 1.8, glm.ROUTE_EPS)
-    biased = np.argsort(-(scores + np.asarray(bias)), -1)[:, :4]
-    assert np.array_equal(np.sort(np.asarray(idx1), -1), np.sort(biased, -1))
-    assert not np.array_equal(np.sort(np.asarray(idx0), -1), np.sort(np.asarray(idx1), -1))
-    chosen = np.take_along_axis(scores, np.asarray(idx1), axis=-1)
-    np.testing.assert_allclose(np.asarray(w1), 1.8 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(w1).sum(-1), 1.8, rtol=1e-5)   # routed_scaling_factor, not 1
-    g = jax.grad(lambda b: jnp.sum(moe.route(w, b, x, 4, 1.8, glm.ROUTE_EPS)[1] ** 2))(bias)
-    assert not np.any(np.asarray(g))
-    assert glm.ROUTE_EPS == 1e-20 and glm.Glm4MoeLiteConfig().routed_scale == 1.8
+# -- the selection bias (the router itself: tests/test_expert_families.py) -----------------------------------------------------
 
 
 @pytest.mark.parametrize("optimizer", ["adam", "adamw"])
@@ -363,7 +345,7 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_onc
     for offset in range(0, 16, 4):
         cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
         held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-        y, stats, _ = glm._layer(dict(p, experts=held), x, moe.zero_share_stats(), cfg, "sparse")
+        y, stats, _ = glm._layer(dict(p, experts=held), x, moe.zero_share_stats(chunks_extra=True), cfg, "sparse")
         assert float(stats["dropped"]) == 0.0
         total = total + (y - alike)  # this share's routed experts' part alone
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)

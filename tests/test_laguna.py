@@ -19,7 +19,7 @@ import pytest
 
 from benchmark.manifest import Manifest
 from benchmark.references import laguna as ref
-from distributedvolunteercomputing_tpu.models import get_model, laguna
+from distributedvolunteercomputing_tpu.models import get_model, laguna, moe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.ops.pallas_attention import choose_blocks, flash_attention
 
@@ -274,7 +274,7 @@ def test_the_gate_scales_each_head_before_the_output_projection():
 def test_router_is_sigmoid_top_k_normalised_and_scaled():
     h = jax.random.normal(jax.random.PRNGKey(7), (12, 64))
     w = jax.random.normal(jax.random.PRNGKey(8), (64, 16))
-    idx, weights, scores = laguna.route(w, h, 4, 2.5)
+    idx, weights, scores = moe.route(w, h, 4, 2.5)
     s = 1.0 / (1.0 + np.exp(-np.asarray(h @ w, np.float64)))
     np.testing.assert_allclose(np.asarray(scores), s, rtol=1e-5)
     want_idx = np.argsort(-s, axis=1)[:, :4]
@@ -291,7 +291,7 @@ def expert_layer_inputs(s=48, d=16, f=8, e=16, k=4, key=0):
     ks = jax.random.split(jax.random.PRNGKey(key), 6)
     x = jax.random.normal(ks[0], (s, d))
     stacks = [jax.random.normal(kk, shape) * 0.3 for kk, shape in zip(ks[1:4], ((e, d, f), (e, d, f), (e, f, d)))]
-    idx, weights, _ = laguna.route(jax.random.normal(ks[4], (d, e)), x, k, 2.5)
+    idx, weights, _ = moe.route(jax.random.normal(ks[4], (d, e)), x, k, 2.5)
     return x, idx, weights, stacks
 
 
@@ -322,7 +322,7 @@ def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     for offset in range(0, 16, 4):
         cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
         held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-        y, stats, _ = laguna._layer(dict(p, experts=held), x, laguna._zero_stats(cfg), cfg, 1)
+        y, stats, _ = laguna._layer(dict(p, experts=held), x, moe.zero_share_stats(balanced=cfg.n_experts), cfg, 1)
         assert float(stats["dropped"]) == 0.0
         total = total + (y - alike)  # this share's experts' part alone
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)

@@ -268,8 +268,8 @@ def test_a_token_changes_nothing_before_it(run, kind):
     ffn = "dense" if run == 0 else "sparse"
     at = 40
     other = x.at[0, at].set(x[0, at] + 1.0)
-    a, _, _ = lfm2._layer(p, x, lfm2.moe.zero_share_stats(), cfg, kind, ffn)
-    b, _, _ = lfm2._layer(p, other, lfm2.moe.zero_share_stats(), cfg, kind, ffn)
+    a, _, _ = lfm2._layer(p, x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, ffn)
+    b, _, _ = lfm2._layer(p, other, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, ffn)
     diff = np.abs(np.asarray(a - b)).max(axis=-1)[0]
     assert diff[:at].max() == 0.0 and diff[at] > 0
     # a conv layer reaches two positions on (three taps), an attention layer to the end
@@ -277,31 +277,7 @@ def test_a_token_changes_nothing_before_it(run, kind):
     assert reach == (at + 2 if kind == "conv" else cfg.max_len - 1)
 
 
-# -- the router and its selection bias -----------------------------------------------------
-
-
-def test_the_bias_changes_the_choice_and_not_the_weights():
-    x = jax.random.normal(jax.random.PRNGKey(0), (40, 16))
-    w = jax.random.normal(jax.random.PRNGKey(1), (16, 12))
-    bias = 0.3 * jax.random.normal(jax.random.PRNGKey(2), (12,))
-    scores = np.asarray(jax.nn.sigmoid(x @ w), np.float64)
-    idx0, w0 = lfm2.moe.route(w, jnp.zeros(12), x, 4, 1.0, lfm2.ROUTE_EPS)
-    idx1, w1 = lfm2.moe.route(w, bias, x, 4, 1.0, lfm2.ROUTE_EPS)
-    assert np.array_equal(np.sort(np.asarray(idx0), -1), np.sort(np.argsort(-scores, -1)[:, :4], -1))
-    biased = np.argsort(-(scores + np.asarray(bias)), -1)[:, :4]
-    assert np.array_equal(np.sort(np.asarray(idx1), -1), np.sort(biased, -1))
-    assert not np.array_equal(np.sort(np.asarray(idx0), -1), np.sort(np.asarray(idx1), -1))
-    # the weights are the chosen experts' own scores over their sum: the bias is not in them
-    chosen = np.take_along_axis(scores, np.asarray(idx1), axis=-1)
-    np.testing.assert_allclose(np.asarray(w1), chosen / (chosen.sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(w1).sum(-1), 1.0, rtol=1e-4)
-    # where both pick the same four, the weights are the same numbers
-    same = np.all(np.sort(np.asarray(idx0), -1) == np.sort(np.asarray(idx1), -1), axis=-1)
-    assert same.any() and not same.all()
-    np.testing.assert_allclose(np.sort(np.asarray(w0)[same], -1), np.sort(np.asarray(w1)[same], -1), rtol=1e-6)
-    # and no gradient reaches it
-    g = jax.grad(lambda b: jnp.sum(lfm2.moe.route(w, b, x, 4, 1.0, lfm2.ROUTE_EPS)[1] ** 2))(bias)
-    assert not np.any(np.asarray(g))
+# -- the selection bias (the router itself: tests/test_expert_families.py) -----------------------------------------------------
 
 
 def own_update(tx, state, grads):
@@ -451,7 +427,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         for offset in range(0, 16, 4):
             cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
             held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-            y, stats, _ = lfm2._layer(dict(p, experts=held), x, lfm2.moe.zero_share_stats(), cfg, kind, "sparse")
+            y, stats, _ = lfm2._layer(dict(p, experts=held), x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, "sparse")
             assert float(stats["dropped"]) == 0.0
             total = total + (y - alike)  # this share's experts' part alone
         np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)

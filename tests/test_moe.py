@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from distributedvolunteercomputing_tpu.models import get_model
-from distributedvolunteercomputing_tpu.models.moe import GPT2MoEConfig, moe_ffn, moe_init
+from distributedvolunteercomputing_tpu.models.gpt2_moe import GPT2MoEConfig, moe_ffn, moe_init
 from distributedvolunteercomputing_tpu.parallel import make_mesh
 from distributedvolunteercomputing_tpu.parallel.sharding import make_param_shardings
 from distributedvolunteercomputing_tpu.parallel.train_step import (

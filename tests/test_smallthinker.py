@@ -21,11 +21,15 @@ import pytest
 
 from benchmark.manifest import Manifest
 from benchmark.references import smallthinker as ref
-from distributedvolunteercomputing_tpu.models import common, get_model, smallthinker
+from distributedvolunteercomputing_tpu.models import common, get_model, moe as share, smallthinker
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 
 TINY = Manifest().load_config("tiny-rehearsal-smallthinker")
 OVERRIDES = TINY["model_overrides"]
+
+
+def zero_stats(cfg):
+    return share.zero_share_stats(balanced=cfg.n_experts, act_zeros=True)
 
 
 @pytest.fixture(autouse=True)
@@ -190,9 +194,9 @@ def test_router_reads_the_layers_input_and_weighs_by_the_softmax_over_the_chosen
     bundle, params, batch = seeded()
     cfg, p = bundle.config, one_layer(params, 1)
     xin = params["wte"][batch["tokens"]][:1]
-    _, _, routes = smallthinker._layer(p, xin, smallthinker._zero_stats(cfg), cfg, "sliding")
+    _, _, routes = smallthinker._layer(p, xin, zero_stats(cfg), cfg, "sliding")
     _, _, again = smallthinker._layer(dict(p, wo=p["wo"] * 7.0, wv=-p["wv"]), xin,
-                                      smallthinker._zero_stats(cfg), cfg, "sliding")
+                                      zero_stats(cfg), cfg, "sliding")
     direct, _, _ = smallthinker.route(p["router"], xin.reshape(-1, 64), cfg.top_k)
     assert np.array_equal(np.asarray(routes), np.asarray(again))
     assert np.array_equal(np.asarray(routes), np.asarray(direct))
@@ -312,7 +316,7 @@ def test_a_plan_made_before_attention_is_the_plan_made_in_place():
     bundle, params, batch = seeded()
     cfg, p = bundle.config, one_layer(params, 1)
     xin = params["wte"][batch["tokens"]][:1]
-    text = str(jax.make_jaxpr(lambda p, x: smallthinker._layer(p, x, smallthinker._zero_stats(cfg), cfg, "sliding")[0])(p, xin))
+    text = str(jax.make_jaxpr(lambda p, x: smallthinker._layer(p, x, zero_stats(cfg), cfg, "sliding")[0])(p, xin))
     assert 0 < text.index("top_k") < text.index(" sort[") < text.index("rsqrt") < text.index("while[")
 
 
@@ -337,7 +341,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         for offset in range(0, 16, 4):
             cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
             held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-            y, stats, _ = smallthinker._layer(dict(p, experts=held), x, smallthinker._zero_stats(cfg), cfg, kind)
+            y, stats, _ = smallthinker._layer(dict(p, experts=held), x, zero_stats(cfg), cfg, kind)
             assert float(stats["dropped"]) == 0.0
             total = total + (y - alike)  # this share's experts' part alone
         np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)
