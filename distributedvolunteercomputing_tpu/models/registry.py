@@ -1,7 +1,7 @@
 """Model registry: one bundle per reference workload (BASELINE.json:7-11),
 and the public architectures run at their published sizes beyond them
 (``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``, ``lfm2_24b_a2b``,
-``glm4_7_flash``: each takes the overrides that cut it to one chip's share without touching a
+``glm4_7_flash``, ``nemotron3_nano_30b_a3b``: each takes the overrides that cut it to one chip's share without touching a
 width).
 
 Bundles are built lazily so importing the registry never pays for the whole
@@ -143,6 +143,10 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # 47 layers of latent attention and 64 experts: ``n_layers``, ``experts_held`` /
     # ``expert_offset``, ``vocab``; its routers' selection biases are the step's to move
     "glm4_7_flash": ("glm4_moe_lite", "Glm4MoeLiteConfig"),
+    # 52 one-mixer blocks (state-space, experts, attention) of 128 experts: ``n_layers`` (the
+    # pattern's first blocks), ``experts_held`` / ``expert_offset``, ``vocab``; its routers'
+    # selection biases are the step's to move
+    "nemotron3_nano_30b_a3b": ("nemotron_h", "NemotronHConfig"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 

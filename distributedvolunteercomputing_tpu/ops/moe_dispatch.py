@@ -68,6 +68,15 @@ from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 # in 5.06 and 18.0 (55%, 46%); megablox at its default 128^3 tiles takes 50.5 ms.
 _MEGABLOX_TILE_M = 512
 _MEGABLOX_TILES_KN = (1024, 512, 256, 128)
+# A k or n that none of these tiles divides (Nemotron-H's expert width of 1,856:
+# 14.5 tiles of 128) is padded with zeros up to whole tiles of this size, and so
+# is its partner (2,688 = 21 x 128 would else run in tiles of 128), so that the
+# product runs at the tiles measured above. MEASURED (PR 48, TPU v5e,
+# nemotron3-nano-solo-8k, [7680, 2688] x [8, 2688, 1856] and its transposes;
+# PERF.md, Findings of PR 48): as ``ragged_dot`` the step's 21 grouped products
+# took 61.4 ms a step, 2.75 ms for the 0.39 that one's FLOPs need, and the
+# step's time followed the router by 2% from seed to seed.
+_MEGABLOX_PAD = 1024
 
 # Called once per TRACED dispatch with (impl, E, k, rows, held, act): the
 # volunteer counts them (swarm.moe_dispatch), as ops/attention.py's observer
@@ -80,6 +89,19 @@ _dispatch_observer = None
 # the held rows' hidden activations that are exactly zero: what a sparse
 # kernel could skip); a SiLU gate has none and its program no such count.
 _GATES = {"silu": (jax.nn.silu, "swiglu"), "relu": (jax.nn.relu, "reglu")}  # name -> (activation, kind)
+# An expert WITHOUT a gate, ``down(act(up x))``, by the same static name: one
+# stack in front of the activation where a gated expert has two side by side.
+# A chunk of the share is the same seven grouped matmuls (two forward, five
+# backward: up again, the cotangent of ``hidden``, the two stacks', the rows'),
+# the first over f columns where gate and up side by side are 2 f. ``relu2`` is the squared ReLU (Nemotron-H's experts);
+# what it zeroes is what a ReLU zeroes, and is counted the same way.
+_UNGATED = {"relu2": (lambda z: jnp.square(jax.nn.relu(z)), "relu2")}
+_COUNTS_ZEROS = ("relu", "relu2")
+
+
+def expert_kind(act: str) -> str:
+    """What the dispatch observer is told of the experts ("swiglu" | "reglu" | "relu2")."""
+    return (_GATES.get(act) or _UNGATED[act])[1]
 
 
 def _gated(gate: jax.Array, up: jax.Array, valid, act: str):
@@ -88,10 +110,24 @@ def _gated(gate: jax.Array, up: jax.Array, valid, act: str):
     hidden = _GATES[act][0](gate) * up
     if act != "relu":
         return hidden, None
-    zero = gate <= 0
+    return hidden, _zeros(gate, valid)
+
+
+def _zeros(pre: jax.Array, valid) -> jax.Array:
+    """How many entries of the ``valid`` rows' (all rows': None) pre-activation a ReLU sets to zero."""
+    zero = pre <= 0
     if valid is not None:
         zero = zero & valid[:, None]
-    return hidden, jnp.sum(zero.astype(jnp.int32))
+    return jnp.sum(zero.astype(jnp.int32))
+
+
+def _hidden(product: jax.Array, f: int, valid, act: str):
+    """The experts' hidden activation ``[rows, f]`` and its zero count (None
+    where the activation makes none) from the first grouped product: gate and
+    up side by side ``[rows, 2 f]``, or a gate-less expert's up alone."""
+    if act in _GATES:
+        return _gated(product[:, :f], product[:, f:], valid, act)
+    return _UNGATED[act][0](product), _zeros(product, valid)
 
 
 def set_dispatch_observer(fn) -> None:
@@ -109,13 +145,19 @@ def _megablox_tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
     return (_MEGABLOX_TILE_M, tk, tn)
 
 
+def _padded(dim: int) -> int:
+    """``dim`` in whole tiles of ``_MEGABLOX_PAD`` (of 128 under half of one)."""
+    tile = _MEGABLOX_PAD if dim >= _MEGABLOX_PAD // 2 else 128
+    return -(-dim // tile) * tile
+
+
 def grouped_matmul_impl(m: int, k: int, n: int) -> str:
     """The grouped matmul a ``[m, k] x [E, k, n]`` product traced now takes.
     The one seam a test or an experiment patches to take the other."""
     # On several chips the expert stacks are sharded over ``ep``: ragged_dot
     # is an XLA op that GSPMD partitions, a Mosaic kernel is not
     # (ops/attention.py has the same rule for its kernel).
-    if not tpu_backend() or jax.device_count() > 1 or _megablox_tiling(m, k, n) is None:
+    if not tpu_backend() or jax.device_count() > 1 or m < _MEGABLOX_TILE_M:
         return "ragged_dot"
     return "megablox"
 
@@ -134,11 +176,18 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array) -> ja
     if grouped_matmul_impl(m, k, n) == "megablox":
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
+        tiling = _megablox_tiling(m, k, n)
+        if tiling is None:
+            # no tile divides k or n: zeros up to whole tiles (they add nothing to a sum over k, and the
+            # columns past n are cut away; the pads' transposes are slices, so the gradients come back unpadded)
+            kp, np_ = _padded(k), _padded(n)
+            lhs = jnp.pad(lhs, ((0, 0), (0, kp - k)))
+            rhs = jnp.pad(rhs, ((0, 0), (0, kp - k), (0, np_ - n)))
+            tiling = _megablox_tiling(m, kp, np_)
         # interpreted only where a test has patched the choice off a TPU
-        return gmm(
-            lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-            tiling=_megablox_tiling(m, k, n), interpret=not tpu_backend(),
-        )
+        out = gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype, tiling=tiling,
+                  interpret=not tpu_backend())
+        return out if out.shape[1] == n else out[:, :n]
     return jax.lax.ragged_dot(lhs, rhs, group_sizes)
 
 
@@ -212,7 +261,7 @@ def dropless_glu_experts(
     x: jax.Array,          # [S, d] tokens, compute dtype
     top_idx: jax.Array,    # [S, k] int32 expert of each choice
     top_gates: jax.Array,  # [S, k] float32 weight of each choice
-    w_gate: jax.Array,     # [E, d, f]
+    w_gate: Optional[jax.Array],  # [E, d, f]; None for an expert without a gate (``act`` of ``_UNGATED``)
     w_up: jax.Array,       # [E, d, f]
     w_down: jax.Array,     # [E, f, d]
     act: str = "silu",
@@ -223,15 +272,19 @@ def dropless_glu_experts(
     set to zero: None for SiLU)."""
     s, d = x.shape
     k = top_idx.shape[1]
-    e = w_gate.shape[0]
+    e = w_up.shape[0]
     dtype = x.dtype
     if _dispatch_observer is not None:
-        _dispatch_observer(grouped_matmul_impl(s * k, d, w_gate.shape[2]), e, k, s * k, e, _GATES[act][1])
+        _dispatch_observer(grouped_matmul_impl(s * k, d, w_up.shape[2]), e, k, s * k, e, expert_kind(act))
     order, inv, group_sizes, experts = sort_by_expert(top_idx, e)
     rows = _rows_of_tokens(x, order, inv, k)                       # [S k, d]
-    gate = grouped_matmul(rows, w_gate.astype(dtype), group_sizes)
-    up = grouped_matmul(rows, w_up.astype(dtype), group_sizes)
-    hidden, zeros = _gated(gate, up, None, act)
+    if w_gate is None:
+        up = grouped_matmul(rows, w_up.astype(dtype), group_sizes)
+        hidden, zeros = _hidden(up, up.shape[1], None, act)
+    else:
+        gate = grouped_matmul(rows, w_gate.astype(dtype), group_sizes)
+        up = grouped_matmul(rows, w_up.astype(dtype), group_sizes)
+        hidden, zeros = _gated(gate, up, None, act)
     out = grouped_matmul(hidden, w_down.astype(dtype), group_sizes)
     out = _permute_rows(out, inv, order).reshape(s, k, d)          # back to token-major
     y = jnp.einsum("skd,sk->sd", out, top_gates.astype(dtype))
@@ -433,7 +486,7 @@ def _share_rows(x, gates, w_gate_up, w_down, plan, lo, rows: int, k: int, act: s
     tok = order // k
     where = (tok, valid, *_token_runs(tok, valid, x.shape[0]))
     gate_up = grouped_matmul(_spread(x, where, k), w_gate_up, sizes)
-    hidden, zeros = _gated(gate_up[:, :f], gate_up[:, f:], valid, act)
+    hidden, zeros = _hidden(gate_up, f, valid, act)
     # a select, not a product: its transpose hands an assignment that is not
     # this share's a cotangent of exactly zero, whatever its unwritten row holds
     weight = jnp.where(valid, gates[order], 0.0)
@@ -466,7 +519,7 @@ def _share_experts(x, gates, w_gate_up, w_down, plan, n_chunks, k, cap, act):
         part, ns = _share_rows(x, gates, w_gate_up, w_down, plan, c * cap, cap, k, act)
         return c + 1, y + part, tuple(a + n for a, n in zip(counts, ns))
 
-    start = (jnp.int32(0), jnp.zeros_like(x), (jnp.int32(0),) * (2 if act == "relu" else 1))
+    start = (jnp.int32(0), jnp.zeros_like(x), (jnp.int32(0),) * (2 if act in _COUNTS_ZEROS else 1))
     return jax.lax.while_loop(lambda st: st[0] < n_chunks, body, start)[1:]
 
 
@@ -528,7 +581,7 @@ def share_glu_experts(
     x: jax.Array,          # [S, d] tokens, compute dtype
     top_idx: jax.Array,    # [S, k] int32 expert of each choice, among all n_experts
     top_gates: jax.Array,  # [S, k] float32 weight of each choice
-    w_gate: jax.Array,     # [held, d, f]: experts offset .. offset + held
+    w_gate: Optional[jax.Array],  # [held, d, f]: experts offset .. offset + held; None: no gate
     w_up: jax.Array,       # [held, d, f]
     w_down: jax.Array,     # [held, f, d]
     expert_offset: int,
@@ -540,7 +593,8 @@ def share_glu_experts(
     """This chip's part of the expert layer: ``y[s]`` is the sum over the
     choices ``i`` of token ``s`` whose expert ``top_idx[s, i]`` is one of the
     ``held`` experts from ``expert_offset`` on, of ``top_gates[s, i] *
-    expert(x[s])``, an expert being ``down(act(gate x) * up x)``; what the other
+    expert(x[s])``, an expert being ``down(act(gate x) * up x)`` or, without a
+    gate (``w_gate`` None, ``act`` "relu2"), ``down(act(up x))``; what the other
     experts would add is left out. ``slack``: the chunk's rows over the even
     share, as ``share_rows_bound`` takes it; ``plan``: ``plan_share`` of these
     routes at that ``slack``, where the caller made it earlier. Returns
@@ -551,7 +605,7 @@ def share_glu_experts(
     ``dropless_glu_experts``."""
     s, d = x.shape
     k = top_idx.shape[1]
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     if held == n_experts:
         y, group_sizes, dropped, zeros = dropless_glu_experts(
             x, top_idx, top_gates, w_gate, w_up, w_down, act)
@@ -560,7 +614,7 @@ def share_glu_experts(
     cap = share_rows_bound(s, k, held, n_experts, slack)
     if _dispatch_observer is not None:
         _dispatch_observer(
-            grouped_matmul_impl(cap, d, w_gate.shape[2]), n_experts, k, cap, held, _GATES[act][1])
+            grouped_matmul_impl(cap, d, w_up.shape[2]), n_experts, k, cap, held, expert_kind(act))
     if plan is None:
         plan = plan_share(top_idx, expert_offset, held, n_experts, slack)
     elif plan.order.shape[0] % cap:
@@ -573,9 +627,13 @@ def share_glu_experts(
     n_chunks = jnp.maximum((plan.n_held + cap - 1) // cap, 1)
     # gate and up side by side, once a layer; their gradients come back apart
     # through the concatenate, so the parameter tree keeps the published two
-    w_gate_up = jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], axis=2)
+    # (an expert without a gate hands its one stack: ``_hidden`` reads it whole)
+    if w_gate is None:
+        w_gate_up = w_up.astype(dtype)
+    else:
+        w_gate_up = jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], axis=2)
     y, counts = _share_experts(
         x, top_gates.reshape(-1).astype(jnp.float32), w_gate_up, w_down.astype(dtype),
         tuple(plan[:3]), n_chunks, k, cap, act)
     return (y, plan.group_sizes, plan.n_held - counts[0], n_chunks * cap,
-            counts[1] if act == "relu" else None)
+            counts[1] if act in _COUNTS_ZEROS else None)

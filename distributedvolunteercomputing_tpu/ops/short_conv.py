@@ -27,6 +27,19 @@ Two forms of the same arithmetic:
 A block is ``block_t`` positions of one sequence over all ``d`` channels; the
 K - 1 positions before it (after it, for the cotangent in the backward) come
 from the neighbouring block's edge, read as a block of ``_EDGE`` rows.
+
+The second convolution of the repo (``causal_conv``: a Mamba-2 mixer's,
+``models/nemotron_h.py``) is the same depthwise causal sum over ONE stream, with
+a bias and SiLU and no gates: ``y = silu(conv(u) + b)``, K = 4, 6,144
+channels. The taps were static already (any K up to ``_EDGE``); what the blocks
+read of their neighbours, the shifts, the grid and the kernels' NAMES
+(``dvc_short_conv_fwd`` / ``_bwd``: what ``conv.device_ms`` reads) are shared,
+and the two kernel BODIES are its own (``_fwd_kernel_stream`` /
+``_bwd_kernel_stream``): a gate-less body inside the gated one would be a flag on
+every line of it, and the gated bodies stay the text LFM2's step compiled from.
+Its backward needs the activation's slope on the K - 1 rows AFTER the block
+too, so it rebuilds the convolution there from the next block's edge and its
+own last rows.
 """
 
 from __future__ import annotations
@@ -208,6 +221,123 @@ def _vjp_bwd(block, interpret, res, dy):
 
 
 short_conv_kernel.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
+# one stream, a bias, an activation, no gates
+# ---------------------------------------------------------------------------
+
+def _silu_slope(z: jax.Array) -> jax.Array:
+    """SiLU's derivative from the pre-activation."""
+    s = jax.nn.sigmoid(z)
+    return s * (1.0 + z * (1.0 - s))
+
+
+def causal_conv_xla(u: jax.Array, taps: jax.Array, bias: jax.Array) -> jax.Array:
+    """The plain form, in ``u``'s dtype: ``silu(sum_j taps[j] u_{t-(K-1-j)} + bias)``."""
+    k, t = taps.shape[0], u.shape[1]
+    w = taps.astype(u.dtype)
+    padded = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+    conv = sum(padded[:, j:j + t] * w[j] for j in range(k))
+    return jax.nn.silu(conv + bias.astype(u.dtype))
+
+
+def _fwd_kernel_stream(x_ref, prev_ref, w_ref, b_ref, o_ref, *, taps: int):
+    f32 = jnp.float32
+    prev = jnp.where(pl.program_id(1) > 0, prev_ref[...].astype(f32), 0.0)
+    conv, _ = _conv_of_block(x_ref[...].astype(f32), prev, w_ref[...], taps)
+    o_ref[...] = jax.nn.silu(conv + b_ref[...]).astype(o_ref.dtype)
+
+
+def _bwd_kernel_stream(x_ref, prev_ref, next_ref, dy_ref, dy_next_ref, w_ref, b_ref,
+                      dx_ref, dw_ref, db_ref, *, taps: int, n_blocks: int):
+    first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+
+    @pl.when(first)
+    def _():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    f32 = jnp.float32
+    w, bias = w_ref[...], b_ref[...]
+    u = x_ref[...].astype(f32)
+    n = u.shape[0]
+    prev = jnp.where(pl.program_id(1) > 0, prev_ref[...].astype(f32), 0.0)
+    conv, shifted = _conv_of_block(u, prev, w, taps)
+    da = dy_ref[...].astype(f32) * _silu_slope(conv + bias)              # the convolution's cotangent
+    # the same on the next block's first rows, whose convolution reaches back into this block
+    conv_next, _ = _conv_of_block(next_ref[...].astype(f32), u[n - _EDGE:], w, taps)
+    next_da = jnp.where(pl.program_id(1) < n_blocks - 1,
+                        dy_next_ref[...].astype(f32) * _silu_slope(conv_next + bias), 0.0)
+    du = da * w[taps - 1:taps]
+    for by in range(1, taps):
+        later = _shifted(da, [next_da[i:i + 1] for i in range(by)], by, True)
+        du = du + later * w[taps - 1 - by:taps - by]
+    dx_ref[...] = du.astype(dx_ref.dtype)
+    for by, s in enumerate([u] + shifted):
+        dw_ref[taps - 1 - by:taps - by, :] += jnp.sum(da * s, axis=0, keepdims=True)
+    db_ref[...] += jnp.sum(da, axis=0, keepdims=True)
+
+
+def _stream_fwd(u, taps, bias, block: int, interpret: bool) -> jax.Array:
+    batch, t, d = u.shape
+    k = taps.shape[0]
+    here, prev, _ = _specs(block, d, t // block)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel_stream, taps=k),
+        grid=(batch, t // block),
+        in_specs=[here, prev, pl.BlockSpec((k, d), lambda i, j: (0, 0)),
+                  pl.BlockSpec((1, d), lambda i, j: (0, 0))],
+        out_specs=here,
+        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
+        compiler_params=_params(interpret), interpret=interpret, name="dvc_short_conv_fwd",
+    )(u, u, taps.astype(jnp.float32), bias.astype(jnp.float32)[None])
+
+
+def _stream_bwd(u, taps, bias, dy, block: int, interpret: bool):
+    batch, t, d = u.shape
+    k = taps.shape[0]
+    n_blocks = t // block
+    here, prev, nxt = _specs(block, d, n_blocks)
+    whole = pl.BlockSpec((k, d), lambda i, j: (0, 0))
+    row = pl.BlockSpec((1, d), lambda i, j: (0, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel_stream, taps=k, n_blocks=n_blocks),
+        grid=(batch, n_blocks),
+        in_specs=[here, prev, nxt, here, nxt, whole, row],
+        out_specs=[here, whole, row],
+        out_shape=[jax.ShapeDtypeStruct(u.shape, u.dtype),
+                   jax.ShapeDtypeStruct((k, d), jnp.float32),
+                   jax.ShapeDtypeStruct((1, d), jnp.float32)],
+        compiler_params=_params(interpret), interpret=interpret, name="dvc_short_conv_bwd",
+    )(u, u, u, dy, dy, taps.astype(jnp.float32), bias.astype(jnp.float32)[None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def causal_conv_kernel(u: jax.Array, taps: jax.Array, bias: jax.Array, block: int, interpret: bool) -> jax.Array:
+    return _stream_fwd(u, taps, bias, block, interpret)
+
+
+def _stream_vjp_fwd(u, taps, bias, block, interpret):
+    return _stream_fwd(u, taps, bias, block, interpret), (u, taps, bias)
+
+
+def _stream_vjp_bwd(block, interpret, res, dy):
+    u, taps, bias = res
+    du, dw, db = _stream_bwd(u, taps, bias, dy, block, interpret)
+    return du, dw.astype(taps.dtype), db[0].astype(bias.dtype)
+
+
+causal_conv_kernel.defvjp(_stream_vjp_fwd, _stream_vjp_bwd)
+
+
+def causal_conv(u: jax.Array, taps: jax.Array, bias: jax.Array) -> jax.Array:
+    """``silu(conv(u) + bias)`` over ``u`` [batch, T, d]: the kernels on one TPU
+    chip where they take the shape, the plain form elsewhere, as ``short_conv``."""
+    block = choose_block(u.shape[1], u.shape[2], taps.shape[0])
+    if block is None or not tpu_backend() or chips_in_step() > 1:
+        return causal_conv_xla(u, taps, bias)
+    return causal_conv_kernel(u, taps, bias, block, False)
 
 
 def short_conv(bcu: jax.Array, taps: jax.Array) -> jax.Array:

@@ -908,7 +908,7 @@ class Telemetry:
         ("megablox" | "ragged_dot"): ops.moe_dispatch's observer. ``rows`` are
         the rows one grouped matmul is handed, ``held`` the experts this chip
         holds of the ``n_experts`` routed over (all of them by default),
-        ``act`` the experts' kind ("swiglu" | "reglu")."""
+        ``act`` the experts' kind ("swiglu" | "reglu" | "relu2": no gate)."""
         if self.enabled:
             self.registry.counter(
                 "swarm.moe_dispatch",

@@ -70,6 +70,9 @@ ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
                 "moe_rows_moved", "moe_chunks_extra", "moe_act_zero_share", "moe_bias_max",
                 "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
+# What a step with state-space mixers says of its chunked scans (the attributes
+# of an ``ssm.scan`` span, noted when and as the routing is).
+SCAN_KEYS = ("ssm_carry_share",)
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
 # ``LIFECYCLE``, which this module does not import.
@@ -602,7 +605,8 @@ class Trainer:
             self._record_routing(*held)
         if not at_log_point and step_no % ROUTE_EVERY == 0 and self._routing_pending is None:
             self._routing_pending = (
-                step_no, {k: v for k, v in m.items() if k in ROUTING_KEYS and hasattr(v, "is_ready")})
+                step_no, {k: v for k, v in m.items()
+                          if k in ROUTING_KEYS + SCAN_KEYS and hasattr(v, "is_ready")})
 
     def _record_routing(self, step_no: int, m: Dict[str, Any]) -> None:
         """A sparse-expert step's routing statistics as the attributes of a
@@ -624,6 +628,10 @@ class Trainer:
             attrs[f"mixers_{kind}"] = self.bundle.config.layer_types.count(kind)
         with self._phase("moe.route", step=step_no, **attrs):
             pass
+        scan = {k: float(m[k]) for k in SCAN_KEYS if k in m}
+        if scan:  # a model with state-space mixers: how much its scans carry from chunk to chunk
+            with self._phase("ssm.scan", step=step_no, **scan):
+                pass
 
     @contextlib.contextmanager
     def _round_phase(self, name: str, trace: Optional[str] = None):

@@ -123,7 +123,8 @@ def one_seed(args, cfg, sizes, dev, workdir, seed):
         inner(step, m, n_samples=n_samples))
     tr.run(steps=args.steps, log_every=1)
     c = bundle.config
-    n_sparse = c.n_layers - getattr(c, "dense_layers", 0)
+    # the expert layers: as the step counted them by layer, else all but the leading dense ones
+    n_sparse = sum(k.startswith(HELD_IN_LAYER) for k in steps[0]) or c.n_layers - getattr(c, "dense_layers", 0)
     slack = getattr(sys.modules[type(c).__module__], "SHARE_ROWS_SLACK", None)  # the model's own, if it brings one
     bound = moe_dispatch.share_rows_bound(
         vol["batch_size"] * c.max_len, c.top_k, c.experts_held, c.n_experts, slack)

@@ -23,7 +23,8 @@ CELLS = {"gpt2-medium": ("gpt2_medium", {}, 16),
          "laguna-xs2": ("laguna_xs2", {"n_layers": 5, "experts_held": 16, "vocab": 12544}, 4),
          "lfm2-24b-a2b": ("lfm2_24b_a2b", {"layer_types": "conv,full_attention,conv,conv,conv", "dense_layers": 1,
                                            "experts_held": 8, "expert_offset": 0, "vocab": 8192}, 4),
-         "glm-4.7-flash": ("glm4_7_flash", {"n_layers": 5, "experts_held": 8, "expert_offset": 0, "vocab": 19360}, 2)}
+         "glm-4.7-flash": ("glm4_7_flash", {"n_layers": 5, "experts_held": 8, "expert_offset": 0, "vocab": 19360}, 2),
+         "nemotron-3-nano-30b-a3b": ("nemotron3_nano_30b_a3b", {"n_layers": 7, "experts_held": 8, "expert_offset": 0, "vocab": 16384}, 2)}
 for name in sys.argv[1:] or CELLS:
     model, ov, bs = CELLS[name]
     try:

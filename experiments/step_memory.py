@@ -33,6 +33,9 @@ CELLS = {
     "glm47-flash-solo-8k": ("glm4_7_flash", 1, 1, 2, {"n_layers": 5, "experts_held": 8, "vocab": 19360}),
     # what the cell's batch of 2 was chosen against (PERF.md section 4)
     "glm47-flash-batch4": ("glm4_7_flash", 1, 1, 4, {"n_layers": 5, "experts_held": 8, "vocab": 19360}),
+    "nemotron3-nano-solo-8k": ("nemotron3_nano_30b_a3b", 1, 1, 2, {"n_layers": 7, "experts_held": 8, "vocab": 16384}),
+    # what the cell's batch of 2 was chosen against (PERF.md section 4): 17.21e9, over the chip
+    "nemotron3-nano-batch4": ("nemotron3_nano_30b_a3b", 1, 1, 4, {"n_layers": 7, "experts_held": 8, "vocab": 16384}),
 }
 
 
@@ -41,11 +44,11 @@ def described_v5e():
     program's backend checks answering as they do on the chip."""
     from jax.experimental import topologies
 
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention, short_conv
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention, short_conv, ssd
     from distributedvolunteercomputing_tpu.utils import jaxenv
 
     jaxenv.tpu_backend = pallas_attention.tpu_backend = moe_dispatch.tpu_backend = lambda: True
-    short_conv.tpu_backend = jaxenv.tpu_backend
+    short_conv.tpu_backend = ssd.tpu_backend = jaxenv.tpu_backend
     moe_dispatch.grouped_matmul_impl = lambda m, k, n: "megablox"
     return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
 

@@ -221,6 +221,18 @@ _YARDSTICK_PINS = (
     ("test_manifest_tail_as_the_lifecycle_tests_asserted_it_three_metrics_and_a_cell_up", "test_yardstick_lfm2.py",
      "asserts the manifest's tail three metrics and a cell up from the lifecycle metrics; glm47-flash-solo-8k and "
      "moe.chunks_extra were appended after them (checked in test_yardstick_glm4_moe_lite.py)"),
+    # PR 48 (nemotron-3-nano-30b-a3b, nemotron3-nano-solo-8k, ssm.device_ms / ssm.roofline / ssm.carry_share; the cell
+    # appended to the lists GLM's cell ended and to conv.* and moe.act_zero_share):
+    # tests/yardstick/test_yardstick_nemotron_h.py asserts what each of these asserted, three metrics, a cell and a
+    # configuration up.
+    ("test_configuration_file_is_what_the_program_runs", "[nemotron-3-nano-30b-a3b]",
+     "asserts reduced == []; nemotron-3-nano-30b-a3b lists its cut (checked in test_yardstick_nemotron_h.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metric", "test_yardstick_glm4_moe_lite.py",
+     "asserts that glm47-flash-solo-8k ends the manifest, moe.chunks_extra per_layer and its lists; PR 48 appended three "
+     "metrics, a cell and a configuration (checked in test_yardstick_nemotron_h.py)"),
+    ("test_manifest_tail_as_the_lfm2_tests_asserted_it_one_metric_and_a_cell_up", "test_yardstick_glm4_moe_lite.py",
+     "asserts the manifest's tail one metric and a cell up from LFM2's; nemotron3-nano-solo-8k and the three ssm.* metrics "
+     "were appended after them, and the cell to conv.* and moe.act_zero_share (checked in test_yardstick_nemotron_h.py)"),
 )
 
 

@@ -9,6 +9,7 @@ registry names hand the step leaves of its own."""
 import ast
 import dataclasses
 import pathlib
+import types
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ import pytest
 from benchmark.manifest import Manifest
 from distributedvolunteercomputing_tpu import models as models_package
 from distributedvolunteercomputing_tpu.models import (
-    get_model, glm4_moe_lite, laguna, lfm2, list_models, moe, smallthinker,
+    get_model, glm4_moe_lite, laguna, lfm2, list_models, moe, nemotron_h, smallthinker,
 )
 from distributedvolunteercomputing_tpu.models.registry import _LANGUAGE_MODELS
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
@@ -59,6 +60,13 @@ FAMILIES = {
              ("full_attention", "sparse"), dict(chunks_extra=True), None, STEPPED_KEYS),
     "glm": (glm4_moe_lite, "glm4_7_flash", "tiny-rehearsal-glm", lambda b: first(b[1]), ("sparse",),
             dict(chunks_extra=True), None, STEPPED_KEYS),
+    # a block is one mixer there: the expert BLOCK stands for the layer (its leaves at the top of a unit's tree)
+    "nemotron": (types.SimpleNamespace(_layer=nemotron_h._experts, moe_dispatch=nemotron_h.moe_dispatch,
+                                       SHARE_ROWS_SLACK=nemotron_h.SHARE_ROWS_SLACK),
+                 "nemotron3_nano_30b_a3b", "tiny-rehearsal-nemotron",
+                 lambda b: {k: v for k, v in first(b[0]).items() if k != "before"}, (),
+                 dict(act_zeros=True, chunks_extra=True), None,
+                 STEPPED_KEYS | {"moe_act_zero_share", "ssm_carry_share"}),
 }
 
 
@@ -227,7 +235,7 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
     for key, value in overrides.items():
         got = getattr(bundle.config, key)
         assert (list(got) if isinstance(got, tuple) else got) == value
-    assert (bundle.stepped is not None) == (name in ("lfm2_24b_a2b", "glm4_7_flash"))
+    assert (bundle.stepped is not None) == (name in ("lfm2_24b_a2b", "glm4_7_flash", "nemotron3_nano_30b_a3b"))
     if bundle.stepped is not None:
         assert bundle.stepped.signal == moe.COUNTS
     # the swarm averages the whole tree of every model but the one with adapters

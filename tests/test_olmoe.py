@@ -303,7 +303,9 @@ def test_the_choice_of_grouped_matmul_follows_the_platform(monkeypatch):
     assert moe_dispatch.grouped_matmul_impl(131072, 2048, 1024) == "ragged_dot"  # GSPMD partitions it
     monkeypatch.setattr(jax, "device_count", lambda: 1)
     assert moe_dispatch.grouped_matmul_impl(131072, 2048, 1024) == "megablox"
-    assert moe_dispatch.grouped_matmul_impl(131072, 2048, 96) == "ragged_dot"  # no tile divides 96
+    # no tile divides 96: since PR 48 padded to whole tiles (ops/moe_dispatch._padded), not handed to ragged_dot
+    assert moe_dispatch.grouped_matmul_impl(131072, 2048, 96) == "megablox"
+    assert moe_dispatch.grouped_matmul_impl(256, 2048, 1024) == "ragged_dot"  # fewer rows than one row tile
 
 
 def test_the_step_holds_no_token_by_expert_by_capacity_tensor():

@@ -697,3 +697,101 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < 16.25e9, total                        # 16.176e9: the chip takes about 16.9e9 and ran it
     assert mem.temp_size_in_bytes <= 9.12e9, mem.temp_size_in_bytes   # 9.080e9 (9.219e9 at the levelled chunk)
+
+
+def test_ssd_and_causal_conv_kernels_compile_at_the_published_mixer(v5e):
+    """nemotron3-nano-solo-8k's state-space kernels by the chip's own compiler,
+    under the names a trace shows: the chunked scan forward and backward at 64
+    heads of 64 in 8 groups, state 128, chunks of 128, two sequences of 8,192
+    (a group's eight heads a grid step, each head's [64, 128] float32 state
+    resident), and the one-stream convolution at 6,144 channels and 4 taps."""
+    from distributedvolunteercomputing_tpu.ops import short_conv, ssd
+
+    one = SingleDeviceSharding(v5e[0])
+    z, h, t, p, g, n, q = 2, 64, 8192, 64, 8, 128, 128
+    assert ssd.kernel_takes(h, g, p, n, q) and short_conv.choose_block(t, 6144, 4) == 256
+    rows = jax.ShapeDtypeStruct((z, h, t, p), jnp.bfloat16, sharding=one)
+    cum = jax.ShapeDtypeStruct((z, t // q, h, q), jnp.float32, sharding=one)
+    group = jax.ShapeDtypeStruct((z, g, t, n), jnp.bfloat16, sharding=one)
+
+    def scan_fwd_bwd(xd, cum, b, c, dy):
+        y, vjp = jax.vjp(lambda *a: ssd.ssd_core(*a, ssd.KERNEL), xd, cum, b, c)
+        return y, vjp(dy)
+
+    compiled = jax.jit(scan_fwd_bwd).lower(rows, cum, group, group, rows).compile()
+    names = _kernel_names(_kernel_calls(compiled.as_text()))
+    assert len(names) == 2 and sum("dvc_ssd_fwd" in n for n in names) == 1 and sum("dvc_ssd_bwd" in n for n in names) == 1
+    # between the two kernels: the chunk-boundary states, float32 [2, 64, 64, 64, 128] = 0.27e9, and the streams at a
+    # head of 64 in tiles of 128 lanes (half of each tile is padding: ROADMAP R5 (b))
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1e9
+
+    u = jax.ShapeDtypeStruct((z, t, 6144), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one)
+    bias = jax.ShapeDtypeStruct((6144,), jnp.float32, sharding=one)
+
+    def conv_fwd_bwd(u, w, bias, dy):
+        y, vjp = jax.vjp(lambda *a: short_conv.causal_conv_kernel(*a, 256, False), u, w, bias)
+        return y, vjp(dy)
+
+    compiled = jax.jit(conv_fwd_bwd).lower(u, w, bias, u).compile()
+    names = _kernel_names(_kernel_calls(compiled.as_text()))
+    assert len(names) == 2 and sum("dvc_short_conv_fwd" in n for n in names) == 1
+    assert sum("dvc_short_conv_bwd" in n for n in names) == 1
+
+
+def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_chip, monkeypatch):
+    """nemotron3-nano-solo-8k's step (published blocks 0-6, MEMEM*E, of
+    Nemotron-3-Nano-30B-A3B at its published widths, eight of 128 experts held,
+    an eighth of the vocabulary, 2 x 8,192 tokens): two traced unit shapes, a
+    scan over the two ``ME`` and one ``M*E``, every block rematerialised by
+    itself. Each traced state-space block runs the scan's kernel forward, again
+    in its recomputed forward (it keeps nothing) and backward, and the
+    convolution's likewise: two traces, six calls each. The attention block
+    takes the flash kernel at a head of 128 with SIXTEEN query heads a key/value
+    head, forward and backward only. The experts' width of 1,856 is no whole
+    number of megablox's 128-column tiles (14.5), so the share's grouped
+    products run padded: ``[7680, 3072] x [8, 3072, 2048]`` in tiles of 512 x
+    1,024 x 1,024, seven a traced expert block, over the levelled router's
+    chunk of 7,680 rows (the even share of 6,144 and a quarter), never the
+    S x k = 98,304. That it compiles says it fits the chip."""
+    from distributedvolunteercomputing_tpu.models import nemotron_h
+    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, ssd
+
+    monkeypatch.setattr(ssd, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    assert moe_dispatch._megablox_tiling(7680, 2688, 1856) is None and moe_dispatch._megablox_tiling(7680, 1856, 2688) is None
+    assert (moe_dispatch._padded(2688), moe_dispatch._padded(1856)) == (3072, 2048)
+    assert moe_dispatch._megablox_tiling(7680, 3072, 2048) == (512, 1024, 1024)
+    seen, kept = [], []
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
+        (impl, t, d, window, kv_heads)))
+    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
+    try:
+        compiled = _lowered_step(v5e, "nemotron3_nano_30b_a3b", 1, 1, 2, n_layers=7, experts_held=8, vocab=16384).compile()
+    finally:
+        attention.set_core_observer(None)
+        attention.set_kept_observer(None)
+    assert seen == [("flash", 8192, 128, None, 2)], seen
+    # what the blocks keep: the attention block's output and row statistics; a state-space block nothing
+    assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    names = [n.split(".")[0] for n in _kernel_names(calls)]
+    assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
+    assert all("bf16[2,32,8192,128]" in ln and "bf16[2,2,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)
+    assert sorted(n for n in names if n.startswith("dvc_ssd")) == ["dvc_ssd_bwd"] * 2 + ["dvc_ssd_fwd"] * 4
+    assert all("bf16[2,64,8192,64]" in ln for ln in calls if "dvc_ssd_" in ln)
+    assert sorted(n for n in names if n.startswith("dvc_short_conv")) == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4
+    assert all("bf16[2,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
+    rows = moe_dispatch.share_rows_bound(2 * 8192, 6, 8, 128, nemotron_h.SHARE_ROWS_SLACK)
+    assert rows == 7680  # the even share of 6,144 and a quarter: fifteen row tiles
+    assert f"[{rows},2688]" in text and f"[{rows},1856]" in text and "[98304,2688]" not in text
+    from benchmark import moe_trace
+
+    gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
+    assert len(gmm) == 7 * 2 and "ragged-dot" not in text, gmm      # two traced expert blocks, seven products each
+    assert f"bf16[{rows},3072]" in text and "bf16[8,3072,2048]" in text and "bf16[8,2048,3072]" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(6.3373e9, rel=1e-3)  # float32 parameters and two Adam moments
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.3e9     # at 4 x 8,192: 17.21e9, over the chip
