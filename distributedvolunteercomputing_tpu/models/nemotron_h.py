@@ -28,7 +28,8 @@ Final RMSNorm, an untied head. Loss = mean cross-entropy over the vocabulary
 (slice); no auxiliary term (``aux_loss`` reads 0).
 
 The recurrence runs as a chunked scan with its own backward
-(``ops/ssd.py``, chunk 128), the convolution through ``ops/short_conv.causal_conv``,
+(``ops/ssd.py``, chunk 128; it takes x', B and C where the convolution leaves
+them, side by side in one array), the convolution through ``ops/short_conv.causal_conv``,
 the held experts through ``ops/moe_dispatch.share_glu_experts`` in its gate-less
 kind (``act="relu2"``, no ``w_gate``: two grouped products a chunk forward and
 five backward, as with a gate, the first of them over f columns and not 2 f), attention through ``ops/attention.attention_core`` in groups of
@@ -285,17 +286,14 @@ def group_rmsnorm(g: jax.Array, y: jax.Array, groups: int, eps: float) -> jax.Ar
 def _mamba(p: common.Params, x: jax.Array, cfg: NemotronHConfig):
     """``x + mixer(norm(x))`` and the scan's carry share (``ops/ssd.ssd``)."""
     dtype = x.dtype
-    b, t, _ = x.shape
-    h, hp, g, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups, cfg.d_state
     normed = common.rmsnorm(p["ln"], x, cfg.rms_eps)
     zxd = normed @ p["w_in"].astype(dtype)
     z, xbc, dt = jnp.split(zxd, [cfg.d_inner, cfg.d_inner + cfg.conv_dim], axis=-1)
     xbc = causal_conv(xbc, p["conv_w"], p["conv_b"])
-    xs, bm, cm = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + g * n], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
-    y, carried = ssd(xs.reshape(b, t, h, hp), dt, p["a_log"], bm.reshape(b, t, g, n),
-                     cm.reshape(b, t, g, n), p["d_skip"], cfg.chunk)
-    y = group_rmsnorm(p["norm"]["g"], y.reshape(b, t, cfg.d_inner) * jax.nn.silu(z), g, cfg.rms_eps)
+    # the scan takes x', B and C where the convolution leaves them, side by side in ``xbc``
+    y, carried = ssd(xbc, dt, p["a_log"], p["d_skip"], cfg.n_groups, cfg.d_state, cfg.chunk)
+    y = group_rmsnorm(p["norm"]["g"], y * jax.nn.silu(z), cfg.n_groups, cfg.rms_eps)
     return x + y @ p["w_out"].astype(dtype), carried
 
 

@@ -32,6 +32,7 @@ import collections
 import concurrent.futures
 import contextlib
 import os
+import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
@@ -71,7 +72,8 @@ ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
                 "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
 # What a step with state-space mixers says of its chunked scans (the attributes
-# of an ``ssm.scan`` span, noted when and as the routing is).
+# of an ``ssm.scan`` span, noted when and as the routing is; beside them the
+# span carries ``ssm_form``, the form ``ops/ssd.ssd`` took when the step was traced).
 SCAN_KEYS = ("ssm_carry_share",)
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
@@ -354,6 +356,14 @@ class Trainer:
             # copy of the payload, kept for the flight as the merge's third term.
             self._inflight: Optional[tuple] = None
             self._routing_pending: Optional[tuple] = None  # (step, its routing scalars still on the device)
+            # Which form each state-space scan took when a step was traced (ops/ssd.py's
+            # observer: the kernels on one chip, the plain scan elsewhere): the ``ssm.scan`` span's
+            # ``ssm_form``. A model with such mixers has loaded the module by now; nobody else pays
+            # for its import (it brings Pallas in: 0.9 s of a start-up).
+            forms = self._scan_forms = set()  # the observer outlives this trainer: it holds the set, not ``self``
+            scans = sys.modules.get("distributedvolunteercomputing_tpu.ops.ssd")
+            if scans is not None:
+                scans.set_form_observer(lambda form, *shape: forms.add(form))
             # The in-flight launch's spans, which wait for their round's key.
             self._launch_spans: tuple = ()
             if mesh is None and (fsdp or seq_sharded):
@@ -629,7 +639,9 @@ class Trainer:
         with self._phase("moe.route", step=step_no, **attrs):
             pass
         scan = {k: float(m[k]) for k in SCAN_KEYS if k in m}
-        if scan:  # a model with state-space mixers: how much its scans carry from chunk to chunk
+        if scan:  # a model with state-space mixers: how much its scans carry from chunk to chunk, and in which form
+            if self._scan_forms:
+                scan["ssm_form"] = "+".join(sorted(self._scan_forms))
             with self._phase("ssm.scan", step=step_no, **scan):
                 pass
 

@@ -20,7 +20,7 @@ from benchmark import datagen
 from benchmark.manifest import Manifest
 from benchmark.references import nemotron_h as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, moe, nemotron_h
-from distributedvolunteercomputing_tpu.ops import moe_dispatch
+from distributedvolunteercomputing_tpu.ops import moe_dispatch, ssd
 
 M = Manifest()
 TINY = M.load_config("tiny-rehearsal-nemotron")
@@ -434,11 +434,34 @@ def test_train_loop_records_the_scan_span_beside_the_route_span():
     scans = [s for s in tel.tracer.spans() if s["name"] == "ssm.scan"]
     assert len(routes) == len(scans) >= 2
     for s in scans:
-        assert 0.0 < s["attrs"]["ssm_carry_share"] <= 1.0 and set(s["attrs"]) == {"step", "ssm_carry_share"}
+        assert 0.0 < s["attrs"]["ssm_carry_share"] <= 1.0 and set(s["attrs"]) == {"step", "ssm_carry_share", "ssm_form"}
+        assert s["attrs"]["ssm_form"] == ssd.PLAIN        # no TPU here: what a traced scan told ops/ssd's observer
     attrs = routes[-1]["attrs"]
     assert attrs["mixers_mamba"] == 3 and attrs["mixers_experts"] == 3 and attrs["mixers_attention"] == 1
     assert attrs["experts_held"] == 4 and "moe_act_zero_share" in attrs and "moe_chunks_extra" in attrs
     assert "ssm_carry_share" not in attrs
+
+
+def test_a_traced_scan_tells_the_observer_its_form_and_shape():
+    """``ops/ssd.set_form_observer``: one call a TRACED scan with (form, heads,
+    groups, head_dim, state, chunk), as attention's core tells its observer;
+    the tiny model's three state-space blocks are two traces (``ME`` scanned
+    twice and ``M*E``), each traced again by its checkpoint's backward."""
+    bundle, params, batch = tiny()
+    seen = []
+    ssd.set_form_observer(lambda *a: seen.append(a))
+    try:
+        jax.make_jaxpr(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
+        forward = len(seen)
+        jax.make_jaxpr(jax.grad(lambda p: bundle.loss_fn(p, batch, None)[0]))(params)
+    finally:
+        ssd.set_form_observer(None)
+    cfg = bundle.config
+    assert forward == 2 and len(seen) > 2 * forward - 1
+    assert set(seen) == {(ssd.PLAIN, cfg.mamba_heads, cfg.n_groups, cfg.mamba_head_dim, cfg.d_state, cfg.chunk)}
+    told = len(seen)
+    jax.make_jaxpr(lambda p: bundle.loss_fn(p, batch, None)[0])(params)      # no observer: nothing is told, nothing fails
+    assert len(seen) == told
 
 
 def test_run_volunteer_knows_the_model_and_no_training_code_names_it():

@@ -31,6 +31,19 @@ def ssd_sequential(x, dt, a_log, b, c, d):
     return jnp.moveaxis(y, 0, 1)
 
 
+def side_by_side(x, b, c):
+    """``xbc`` [Z, T, H P + 2 G N]: x' by head, then B and C by group, in one array's lanes."""
+    z, t = x.shape[:2]
+    return jnp.concatenate([a.reshape(z, t, -1) for a in (x, b, c)], axis=-1)
+
+
+def scan(x, dt, a_log, b, c, d, **kw):
+    """``ssd.ssd`` as the mixer calls it: x', B and C side by side in ONE array
+    (what the convolution leaves), ``y`` back in ``x``'s shape."""
+    y, share = ssd.ssd(side_by_side(x, b, c), dt, a_log, d, b.shape[2], b.shape[3], **kw)
+    return y.reshape(x.shape), share
+
+
 @pytest.fixture(autouse=True)
 def _highest_precision():
     with jax.default_matmul_precision("highest"):
@@ -60,10 +73,10 @@ def test_the_chunked_scan_is_the_recurrence_position_by_position(form, chunk):
     differentiation of the recurrence one position at a time: 1e-5."""
     args, probe = scan_inputs()
     want = ssd_sequential(*args)
-    got, _ = ssd.ssd(*args, chunk=chunk, form=form)
+    got, _ = scan(*args, chunk=chunk, form=form)
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5 * scale, rtol=1e-5)
-    g_got = jax.grad(lambda *a: jnp.sum(ssd.ssd(*a, chunk=chunk, form=form)[0] * probe), argnums=tuple(range(6)))(*args)
+    g_got = jax.grad(lambda *a: jnp.sum(scan(*a, chunk=chunk, form=form)[0] * probe), argnums=tuple(range(6)))(*args)
     g_want = jax.grad(lambda *a: jnp.sum(ssd_sequential(*a) * probe), argnums=tuple(range(6)))(*args)
     for name, a, b in zip(ARGS, g_got, g_want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=1e-5,
@@ -91,8 +104,8 @@ def test_the_kernels_in_the_compute_dtype_are_the_plain_form_in_it():
     """bfloat16 streams: the interpreted kernels and the plain form round in
     the same places (operands in the compute dtype, decays and state float32)."""
     args, probe = scan_inputs(dtype=jnp.bfloat16)
-    plain, kernel = (ssd.ssd(*args, chunk=16, form=f)[0].astype(jnp.float32) for f in (ssd.PLAIN, ssd.INTERPRET))
-    assert plain.dtype == jnp.float32 and ssd.ssd(*args, chunk=16, form=ssd.PLAIN)[0].dtype == jnp.bfloat16
+    plain, kernel = (scan(*args, chunk=16, form=f)[0].astype(jnp.float32) for f in (ssd.PLAIN, ssd.INTERPRET))
+    assert plain.dtype == jnp.float32 and scan(*args, chunk=16, form=ssd.PLAIN)[0].dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(kernel), np.asarray(plain), rtol=2e-2, atol=2e-2)
     exact = ssd_sequential(*args)
     assert float(jnp.max(jnp.abs(plain - exact))) < 0.05 * float(jnp.max(jnp.abs(exact)))
@@ -103,22 +116,109 @@ def test_carry_share_counts_the_boundaries_a_state_survives():
     after the first: a hand count from dt and A."""
     (x, dt, a_log, b, c, d), _ = scan_inputs(t=48)
     dt = jnp.full_like(dt, 0.1)
-    _, share = ssd.ssd(x, dt, a_log, b, c, d, chunk=16)
+    _, share = scan(x, dt, a_log, b, c, d, chunk=16)
     # exp(-16 x 0.1 x A): A = 0.05, 0.5, 2 survive (0.92, 0.45, 0.041), A = 8 does not (2.8e-6)
     assert float(share) == pytest.approx(0.75)
-    assert float(ssd.ssd(x, dt, a_log, b, c, d, chunk=64)[1]) == 0.0        # one chunk: no boundary
-    padded = ssd.ssd(x[:, :40], dt[:, :40], a_log, b[:, :40], c[:, :40], d, chunk=16)[1]
+    assert float(scan(x, dt, a_log, b, c, d, chunk=64)[1]) == 0.0        # one chunk: no boundary
+    padded = scan(x[:, :40], dt[:, :40], a_log, b[:, :40], c[:, :40], d, chunk=16)[1]
     # the half chunk at the end decays half as far: A = 8 survives it (exp(-6.4) = 1.7e-3): 7 of 8
     assert float(padded) == pytest.approx(7 / 8)
-    assert jax.grad(lambda v: ssd.ssd(x, v, a_log, b, c, d, chunk=16)[1])(dt).max() == 0.0
+    assert jax.grad(lambda v: scan(x, v, a_log, b, c, d, chunk=16)[1])(dt).max() == 0.0
 
 
-def test_which_shapes_the_kernels_take():
-    assert ssd.kernel_takes(64, 8, 64, 128, 128)                    # the published mixer
-    assert not ssd.kernel_takes(4, 2, 8, 16, 16)                    # the tests' size: the plain form
-    assert not ssd.kernel_takes(64, 16, 64, 128, 128) and not ssd.kernel_takes(64, 8, 64, 64, 128)
-    assert ssd.choose_form(64, 8, 64, 128, 128) == ssd.PLAIN        # no TPU here
+def packed(seed=0, groups=2, dtype=jnp.float32, t=40):
+    """The mixer's own operands: ``xbc`` [Z, T, H P + 2 G N] as the convolution
+    leaves it (x', B, C side by side), dt, A_log, D; and a probe for ``y``."""
+    (x, dt, a_log, b, c, d), probe = scan_inputs(seed, t=t, g=groups, dtype=dtype)
+    return (side_by_side(x, b, c), dt, a_log, d), probe.reshape(*x.shape[:2], -1).astype(dtype), (groups, b.shape[3])
+
+
+# four heads of 8 in one group (four heads a lane tile) and in two (two a tile); 40 positions = 2.5 chunks of 16
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", [1, 2])
+def test_the_token_major_kernels_are_the_plain_form(groups, dtype):
+    """The kernels (interpreted) read x', B and C out of ONE ``xbc`` array, scale
+    by dt and add the skip themselves; the plain form splits it and works by
+    head. Values and the gradients of ``xbc`` (x', B and C in their lanes), dt,
+    A_log and D, the same arithmetic: 1e-5 in float32; in bfloat16 the kernels
+    round ``y`` once, after the skip, and ``dx'`` once, after both its terms."""
+    args, probe, (g, n) = packed(groups=groups, dtype=dtype)
+    assert ssd.heads_a_tile(4 // groups, 8) == 4 // groups
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+
+    def run(form):
+        y, vjp = jax.vjp(lambda *a: ssd.ssd(*a, g, n, chunk=16, form=form)[0], *args)
+        return (y, *vjp(probe))
+
+    got, want = run(ssd.INTERPRET), run(ssd.PLAIN)
+    assert got[0].dtype == dtype and got[0].shape == probe.shape and got[1].shape == args[0].shape
+    lanes = {"x'": slice(0, 32), "B": slice(32, 32 + g * n), "C": slice(32 + g * n, None)}
+    for name, a, b in zip(("y", "d_xbc", "d_dt", "d_a_log", "d_D"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        for part, at in (lanes.items() if name == "d_xbc" else [("", slice(None))]):
+            np.testing.assert_allclose(a[..., at], b[..., at], atol=tol * float(np.max(np.abs(b[..., at]))), rtol=tol,
+                                       err_msg=f"{name} {part}")
+
+
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, a ``pallas_call`` taken whole."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns_outside_kernels(sub)
+
+
+def test_nothing_of_a_streams_size_runs_outside_the_kernels():
+    """The pin that keeps the passes from coming back: in the kernel form the
+    jaxpr of ``ssd`` holds, outside its ``pallas_call``, no operation at all over
+    an array of x's size (no transpose, no multiply by dt, no skip, no split or
+    reshape of ``xbc``); its gradient's holds two, the updates in place that
+    put dB and dC into their lanes of the array the kernel wrote dx' into (G N
+    lanes each: no concatenation moves the H P lanes of dx' again)."""
+    args, probe, (g, n) = packed(t=32)
+    stream = probe.size
+
+    def big(jaxpr):
+        found = [e for e in _eqns_outside_kernels(jaxpr.jaxpr)
+                 if any(getattr(v.aval, "size", 0) >= stream for v in (*e.invars, *e.outvars))]
+        return sorted(e.primitive.name for e in found)
+
+    def scan(*a):
+        return ssd.ssd(*a, g, n, chunk=16, form=ssd.INTERPRET)[0]
+
+    assert big(jax.make_jaxpr(scan)(*args)) == ["custom_vjp_call", "pallas_call"]
+    backward = big(jax.make_jaxpr(lambda dy, *a: jax.vjp(scan, *a)[1](dy))(probe, *args))
+    assert backward == ["dynamic_update_slice"] * 2 + ["pallas_call"] * 2, backward
+    # the plain form, the same entry: there the passes are (what the kernels took over)
+    plain = big(jax.make_jaxpr(lambda *a: ssd.ssd(*a, g, n, chunk=16, form=ssd.PLAIN)[0])(*args))
+    assert "transpose" in plain and "mul" in plain and "split" in plain
+
+
+@pytest.mark.parametrize("shape, takes", [
+    ((64, 8, 64, 128, 128), True),      # the published mixer: eight heads a group, two a lane tile
+    ((64, 8, 128, 128, 128), True),     # a head a tile
+    ((64, 8, 256, 128, 128), True),     # a head two tiles
+    ((32, 2, 32, 128, 256), True),      # four heads a tile, sixteen a group; chunks of 256
+    ((64, 8, 64, 256, 128), True),      # a state of two lane tiles: x' is sixteen of B's blocks into xbc
+    ((4, 2, 8, 16, 16), False),         # the tests' size: the plain form
+    ((64, 16, 64, 128, 128), False),    # four heads a group: half a sublane tile of cum
+    ((64, 8, 64, 64, 128), False),      # a state of half a lane tile
+    ((64, 8, 64, 128, 64), False),      # a chunk of half a lane tile
+    ((64, 8, 96, 128, 128), False),     # a head of 96 lanes: neither a whole number of tiles nor of heads a tile
+    ((64, 8, 24, 128, 128), False),     # 192 lanes a group: a tile and a half
+    ((24, 3, 16, 256, 128), False),     # 384 lanes of x': B's first block is no whole number of blocks of 256 into xbc
+    ((60, 8, 64, 128, 128), False),     # heads that do not divide into the groups
+])
+def test_which_shapes_the_kernels_take(shape, takes):
+    assert ssd.kernel_takes(*shape) == takes
+    assert ssd.choose_form(*shape) == ssd.PLAIN                     # no TPU here
+
+
+def test_the_scans_constants():
     assert ssd.CHUNK == 128 and ssd.CARRY_FLOOR == 1e-3
+    assert ssd.heads_a_tile(8, 64) == 2 and ssd.heads_a_tile(8, 128) == 1 and ssd.heads_a_tile(2, 8) == 2
 
 
 # -- the convolution -----------------------------------------------------------------

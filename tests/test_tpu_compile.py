@@ -703,27 +703,37 @@ def test_ssd_and_causal_conv_kernels_compile_at_the_published_mixer(v5e):
     """nemotron3-nano-solo-8k's state-space kernels by the chip's own compiler,
     under the names a trace shows: the chunked scan forward and backward at 64
     heads of 64 in 8 groups, state 128, chunks of 128, two sequences of 8,192
-    (a group's eight heads a grid step, each head's [64, 128] float32 state
-    resident), and the one-stream convolution at 6,144 channels and 4 taps."""
+    (a group's eight heads a grid step, two heads a lane tile, each head's
+    [64, 128] float32 state resident), reading x', B and C out of the
+    convolution's ONE [2, 8192, 6144] array and writing y token-major, and the
+    one-stream convolution at 6,144 channels and 4 taps."""
     from distributedvolunteercomputing_tpu.ops import short_conv, ssd
 
     one = SingleDeviceSharding(v5e[0])
     z, h, t, p, g, n, q = 2, 64, 8192, 64, 8, 128, 128
     assert ssd.kernel_takes(h, g, p, n, q) and short_conv.choose_block(t, 6144, 4) == 256
-    rows = jax.ShapeDtypeStruct((z, h, t, p), jnp.bfloat16, sharding=one)
-    cum = jax.ShapeDtypeStruct((z, t // q, h, q), jnp.float32, sharding=one)
-    group = jax.ShapeDtypeStruct((z, g, t, n), jnp.bfloat16, sharding=one)
+    assert ssd.heads_a_tile(h // g, p) == 2
+    xbc = jax.ShapeDtypeStruct((z, t, h * p + 2 * g * n), jnp.bfloat16, sharding=one)
+    dt = jax.ShapeDtypeStruct((z, t, h), jnp.float32, sharding=one)
+    by_head = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one)
+    rows = jax.ShapeDtypeStruct((z, t, h * p), jnp.bfloat16, sharding=one)
 
-    def scan_fwd_bwd(xd, cum, b, c, dy):
-        y, vjp = jax.vjp(lambda *a: ssd.ssd_core(*a, ssd.KERNEL), xd, cum, b, c)
+    def scan_fwd_bwd(xbc, dt, a_log, d, dy):
+        y, vjp = jax.vjp(lambda *a: ssd.ssd(*a, g, n, q, ssd.KERNEL)[0], xbc, dt, a_log, d)
         return y, vjp(dy)
 
-    compiled = jax.jit(scan_fwd_bwd).lower(rows, cum, group, group, rows).compile()
-    names = _kernel_names(_kernel_calls(compiled.as_text()))
+    compiled = jax.jit(scan_fwd_bwd).lower(xbc, dt, by_head, by_head, rows).compile()
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    names = _kernel_names(calls)
     assert len(names) == 2 and sum("dvc_ssd_fwd" in n for n in names) == 1 and sum("dvc_ssd_bwd" in n for n in names) == 1
-    # between the two kernels: the chunk-boundary states, float32 [2, 64, 64, 64, 128] = 0.27e9, and the streams at a
-    # head of 64 in tiles of 128 lanes (half of each tile is padding: ROADMAP R5 (b))
-    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1e9
+    # the kernels' streams are the mixer's own: xbc three times over, y / dy / dx' [2, 8192, 4096], nothing by head
+    assert all(ln.count("bf16[2,8192,6144]") >= 3 and "bf16[2,8192,4096]" in ln for ln in calls), calls
+    assert "[2,64,8192,64]" not in text and "[2,8192,64,64]" not in text
+    # between the two kernels: the chunk-boundary states, float32 [2, 64, 128, 64 x 64] = 0.27e9, and dB, dC before
+    # they are put into d xbc in place (1.1e9 with the streams by head at a head of 64 in tiles of 128 lanes)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.32e9
+    assert "concatenate" not in text and text.count("dynamic-update-slice(") == 2
 
     u = jax.ShapeDtypeStruct((z, t, 6144), jnp.bfloat16, sharding=one)
     w = jax.ShapeDtypeStruct((4, 6144), jnp.float32, sharding=one)
@@ -781,7 +791,8 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
     assert all("bf16[2,32,8192,128]" in ln and "bf16[2,2,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)
     assert sorted(n for n in names if n.startswith("dvc_ssd")) == ["dvc_ssd_bwd"] * 2 + ["dvc_ssd_fwd"] * 4
-    assert all("bf16[2,64,8192,64]" in ln for ln in calls if "dvc_ssd_" in ln)
+    assert all("bf16[2,8192,6144]" in ln and "bf16[2,8192,4096]" in ln for ln in calls if "dvc_ssd_" in ln)
+    assert "[2,64,8192,64]" not in text and "[2,8192,64,64]" not in text   # no stream by head: nothing to transpose
     assert sorted(n for n in names if n.startswith("dvc_short_conv")) == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4
     assert all("bf16[2,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
     rows = moe_dispatch.share_rows_bound(2 * 8192, 6, 8, 128, nemotron_h.SHARE_ROWS_SLACK)
