@@ -1,7 +1,7 @@
 """Model registry: one bundle per reference workload (BASELINE.json:7-11),
 and the public architectures run at their published sizes beyond them
 (``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``, ``lfm2_24b_a2b``,
-``glm4_7_flash``, ``nemotron3_nano_30b_a3b``: each takes the overrides that cut it to one chip's share without touching a
+``glm4_7_flash``, ``nemotron3_nano_30b_a3b``, ``kimi_linear_48b_a3b``: each takes the overrides that cut it to one chip's share without touching a
 width).
 
 Bundles are built lazily so importing the registry never pays for the whole
@@ -147,6 +147,10 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # pattern's first blocks), ``experts_held`` / ``expert_offset``, ``vocab``; its routers'
     # selection biases are the step's to move
     "nemotron3_nano_30b_a3b": ("nemotron_h", "NemotronHConfig"),
+    # 27 layers (delta-rule linear attention, every fourth latent attention without positions) of 256
+    # experts: ``n_layers`` (the first so many), ``experts_held`` / ``expert_offset``, ``vocab``; its
+    # routers' selection biases are the step's to move
+    "kimi_linear_48b_a3b": ("kimi_linear", "KimiLinearConfig"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 

@@ -77,9 +77,10 @@ _impl = "auto"
 # and 1,024 (6.3 / 12.4), not measured below. Other dtypes keep the XLA core.
 _AUTO_FLASH_MIN_T = {"bfloat16": 512, "float32": 512}
 # Head dims the kernel was measured at (128: 3.2 against 6.6 ms a layer at
-# T=1,024; 256, latent attention's head: PERF.md, Findings of PR 42); others
-# keep the XLA core.
-_AUTO_FLASH_HEAD_DIMS = (64, 128, 256)
+# T=1,024; 256, latent attention's head: PERF.md, Findings of PR 42; 192, a
+# latent key of 128 + 64 over a value head of 128: PERF.md, Findings of PR 52);
+# others keep the XLA core.
+_AUTO_FLASH_HEAD_DIMS = (64, 128, 192, 256)
 
 # Called once per TRACED attention call with (impl, T, D, dtype name, window,
 # key/value heads): the volunteer counts them (swarm.attention_core), so its
@@ -268,7 +269,7 @@ def _flash_per_shard(
 
     def core(q, k, v):  # traced once, at one chip's shapes
         if _kept_ctx is not None:
-            _kept_ctx.append(kept_bytes(q, k, window))
+            _kept_ctx.append(kept_bytes(q, k, window, v))
         # blocks: pallas_attention.choose_blocks of the (shard's) shape
         return flash_attention(q, k, v, causal=causal, window=window)
 
@@ -288,7 +289,7 @@ def _flash_per_shard(
 def attention_core(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D]: Hkv divides H, query head h reads h // (H / Hkv)
-    v: jax.Array,  # [B, Hkv, Tk, D]
+    v: jax.Array,  # [B, Hkv, Tk, Dv]: the value head's own width, which is the output's
     causal: bool = False,
     mask: Optional[jax.Array] = None,  # [B, 1|H, Tq, Tk] additive-able bool
     window: Optional[int] = None,  # causal, square: query i sees keys i - window < j <= i
@@ -352,7 +353,7 @@ def attention_core_local(
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
     if h != h_kv:
-        return jnp.einsum("bngqk,bnkd->bngqd", probs, v).reshape(q.shape)
+        return jnp.einsum("bngqk,bnkd->bngqd", probs, v).reshape(*q.shape[:-1], v.shape[-1])
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 

@@ -38,6 +38,10 @@ Design (speeds: PERF.md, Findings of PR 27, measured on a TPU v5e):
   unmasked body, and only the blocks an edge crosses pay for the mask. The
   windowed calls carry their own kernel names (``dvc_flash_win_fwd`` /
   ``dvc_flash_win_bwd``) so that a device trace tells them from the full ones.
+- **The value head has its own width** (``v.shape[-1]``, latent attention's 128
+  under keys of 128 + 64): the v, o, dO and dv blocks and the output's
+  accumulator take it, the scores and dq / dk the key width. With equal widths
+  the program is what it was.
 - **Matmuls run in the INPUT dtype** with f32 accumulation, so bf16 inputs
   hit the MXU at its bf16 rate. Scores, statistics and accumulators are
   f32; the probabilities (and ds) are rounded to the input dtype before
@@ -261,7 +265,7 @@ def _flash_forward(
 ) -> Tuple[jax.Array, jax.Array]:
     """(out [B, H, Tq, D], lse [B, H, nq, 1, bq] over the padded rows)."""
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    tk, dv = k.shape[2], v.shape[3]  # the value head's own width: o's, and the accumulator's
     group = h // k.shape[1]  # query heads per key/value head
     scale = 1.0 / (d ** 0.5)
     qp, kp, vp = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
@@ -273,25 +277,29 @@ def _flash_forward(
         kvspec = pl.BlockSpec((None, None, tk_p, d), lambda i, j, iq: (i, j, 0, 0))
     else:
         kvspec = pl.BlockSpec((None, None, tk_p, d), lambda i, j, iq: (i, j // group, 0, 0))
+    ospec, vspec = qspec, kvspec
+    if dv != d:  # a value head narrower (or wider) than the key head: v and o in blocks of its width
+        ospec = pl.BlockSpec((None, None, bq, dv), qspec.index_map)
+        vspec = pl.BlockSpec((None, None, tk_p, dv), kvspec.index_map)
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_k=n_k, window=window,
         ),
         grid=(b, h, n_q),
-        in_specs=[qspec, kvspec, kvspec],
+        in_specs=[qspec, kvspec, vspec],
         out_specs=[
-            qspec,
+            ospec,
             pl.BlockSpec((None, None, None, 1, bq), lambda i, j, iq: (i, j, iq, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, n_q, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),      # un-normalized output
+            pltpu.VMEM((bq, dv), jnp.float32),     # un-normalized output
         ],
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel"),
@@ -383,7 +391,7 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
     q, k, v, out, lse = residuals
     do = g
     b, h, tq, d = q.shape
-    h_kv, tk = k.shape[1], k.shape[2]
+    h_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // h_kv
     scale = 1.0 / (d ** 0.5)
 
@@ -401,23 +409,28 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
     # A group's query heads read one K/V head and each writes its own dk, dv.
     kv_in = kblock if group == 1 else pl.BlockSpec(
         (None, None, bk, d), lambda i, j, ik: (i, j // group, ik, 0))
-    dq, dk, dv = pl.pallas_call(
+    vhead, vblock, v_in = head, kblock, kv_in
+    if dv != d:  # do, v and dv in blocks of the value head's width
+        vhead = pl.BlockSpec((None, None, tq_p, dv), head.index_map)
+        vblock = pl.BlockSpec((None, None, bk, dv), kblock.index_map)
+        v_in = pl.BlockSpec((None, None, bk, dv), kv_in.index_map)
+    dq, dk, dv_ = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_q=n_q, n_k=n_k, window=window,
         ),
         grid=(b, h, n_k),
-        in_specs=[head, kv_in, kv_in, head, rows, rows],
-        out_specs=[head, kblock, kblock],
+        in_specs=[head, kv_in, v_in, vhead, rows, rows],
+        out_specs=[head, kblock, vblock],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk_p, d), v.dtype),
+            jax.ShapeDtypeStruct((b, h, tk_p, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq_p, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, dv), jnp.float32),
         ],
         compiler_params=_compiler_params(
             # dq accumulates across the k-blocks of a head: that dim is
@@ -428,13 +441,13 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         interpret=interpret,
         name="dvc_flash_bwd" if window is None else "dvc_flash_win_bwd",
     )(qp, kp, vp, dop, lse, delta)
-    dk, dv = dk[:, :, :tk], dv[:, :, :tk]
+    dk, dv_ = dk[:, :, :tk], dv_[:, :, :tk]
     if group > 1:
-        dk, dv = (
-            jnp.sum(a.reshape(b, h_kv, group, tk, d), axis=2, dtype=jnp.float32).astype(a.dtype)
-            for a in (dk, dv)
+        dk, dv_ = (
+            jnp.sum(a.reshape(b, h_kv, group, tk, a.shape[-1]), axis=2, dtype=jnp.float32).astype(a.dtype)
+            for a in (dk, dv_)
         )
-    return dq[:, :, :tq], dk, dv
+    return dq[:, :, :tq], dk, dv_
 
 
 # ---------------------------------------------------------------------------
@@ -464,7 +477,8 @@ def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None) -> Tup
     return bq, bk, _interpret_default() if interpret is None else interpret
 
 
-def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None) -> int:
+def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None,
+               v: Optional[jax.Array] = None) -> int:
     """Bytes of the two residuals named by ``KEPT_NAMES`` for one call at
     ``choose_blocks``' blocks, as the chip lays them out: the output with its
     head dim padded to whole lanes (a D=64 output takes a D=128 one's room:
@@ -472,6 +486,8 @@ def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None) -> int:
     and the f32 log-sum-exp rows of whole q-blocks."""
     bq, _, _ = _resolve(q, k, None, None, False, True, window)
     b, h, tq, d = q.shape
+    if v is not None:  # the output is as wide as the value head
+        d = v.shape[3]
     return b * h * (tq * _round_up(d, LANES) * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
 
 
@@ -479,7 +495,7 @@ def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None) -> int:
 def flash_attention(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D], Hkv dividing H
-    v: jax.Array,  # [B, Hkv, Tk, D]
+    v: jax.Array,  # [B, Hkv, Tk, Dv]: the value head may be another width than the key head; o is as wide
     causal: bool = False,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,

@@ -71,10 +71,13 @@ ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
                 "moe_rows_moved", "moe_chunks_extra", "moe_act_zero_share", "moe_bias_max",
                 "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss")
 ROUTE_EVERY = 10
-# What a step with state-space mixers says of its chunked scans (the attributes
-# of an ``ssm.scan`` span, noted when and as the routing is; beside them the
-# span carries ``ssm_form``, the form ``ops/ssd.ssd`` took when the step was traced).
-SCAN_KEYS = ("ssm_carry_share",)
+# What a step with recurrent mixers says of its chunked scans, noted when and as
+# the routing is: a key's prefix names its span (``ssm_*`` the attributes of an
+# ``ssm.scan`` span, ``kda_*`` of a ``kda.scan`` span). Where the prefix's module
+# under ``ops/`` has more than one form (``SCAN_FORMS``), the span also carries
+# ``<prefix>_form``, the form that module took when the step was traced.
+SCAN_KEYS = ("ssm_carry_share", "kda_carry_share", "kda_decay_min", "kda_beta_mean")
+SCAN_FORMS = {"ssm": "ssd"}
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
 # ``LIFECYCLE``, which this module does not import.
@@ -356,14 +359,16 @@ class Trainer:
             # copy of the payload, kept for the flight as the merge's third term.
             self._inflight: Optional[tuple] = None
             self._routing_pending: Optional[tuple] = None  # (step, its routing scalars still on the device)
-            # Which form each state-space scan took when a step was traced (ops/ssd.py's
-            # observer: the kernels on one chip, the plain scan elsewhere): the ``ssm.scan`` span's
-            # ``ssm_form``. A model with such mixers has loaded the module by now; nobody else pays
-            # for its import (it brings Pallas in: 0.9 s of a start-up).
-            forms = self._scan_forms = set()  # the observer outlives this trainer: it holds the set, not ``self``
-            scans = sys.modules.get("distributedvolunteercomputing_tpu.ops.ssd")
-            if scans is not None:
-                scans.set_form_observer(lambda form, *shape: forms.add(form))
+            # Which form each chunked scan took when a step was traced (the observer of its module
+            # under ops/: the kernels on one chip, the plain scan elsewhere): the ``<prefix>.scan``
+            # span's ``<prefix>_form``. A model with such mixers has loaded the module by now; nobody
+            # else pays for its import (it brings Pallas in: 0.9 s of a start-up).
+            self._scan_forms = {}  # prefix -> forms; the observers outlive this trainer: they hold the sets, not ``self``
+            for prefix, module in SCAN_FORMS.items():
+                scans = sys.modules.get(f"distributedvolunteercomputing_tpu.ops.{module}")
+                if scans is not None:
+                    forms = self._scan_forms[prefix] = set()
+                    scans.set_form_observer(lambda form, *shape, forms=forms: forms.add(form))
             # The in-flight launch's spans, which wait for their round's key.
             self._launch_spans: tuple = ()
             if mesh is None and (fsdp or seq_sharded):
@@ -638,12 +643,14 @@ class Trainer:
             attrs[f"mixers_{kind}"] = self.bundle.config.layer_types.count(kind)
         with self._phase("moe.route", step=step_no, **attrs):
             pass
-        scan = {k: float(m[k]) for k in SCAN_KEYS if k in m}
-        if scan:  # a model with state-space mixers: how much its scans carry from chunk to chunk, and in which form
-            if self._scan_forms:
-                scan["ssm_form"] = "+".join(sorted(self._scan_forms))
-            with self._phase("ssm.scan", step=step_no, **scan):
-                pass
+        # a model with recurrent mixers: how much its scans carry from chunk to chunk, and in which form
+        for prefix in dict.fromkeys(k.split("_", 1)[0] for k in SCAN_KEYS):
+            scan = {k: float(m[k]) for k in SCAN_KEYS if k in m and k.startswith(prefix + "_")}
+            if scan:
+                if self._scan_forms.get(prefix):
+                    scan[f"{prefix}_form"] = "+".join(sorted(self._scan_forms[prefix]))
+                with self._phase(f"{prefix}.scan", step=step_no, **scan):
+                    pass
 
     @contextlib.contextmanager
     def _round_phase(self, name: str, trace: Optional[str] = None):

@@ -24,7 +24,8 @@ CELLS = {"gpt2-medium": ("gpt2_medium", {}, 16),
          "lfm2-24b-a2b": ("lfm2_24b_a2b", {"layer_types": "conv,full_attention,conv,conv,conv", "dense_layers": 1,
                                            "experts_held": 8, "expert_offset": 0, "vocab": 8192}, 4),
          "glm-4.7-flash": ("glm4_7_flash", {"n_layers": 5, "experts_held": 8, "expert_offset": 0, "vocab": 19360}, 2),
-         "nemotron-3-nano-30b-a3b": ("nemotron3_nano_30b_a3b", {"n_layers": 7, "experts_held": 8, "expert_offset": 0, "vocab": 16384}, 2)}
+         "nemotron-3-nano-30b-a3b": ("nemotron3_nano_30b_a3b", {"n_layers": 7, "experts_held": 8, "expert_offset": 0, "vocab": 16384}, 2),
+         "kimi-linear-48b-a3b": ("kimi_linear_48b_a3b", {"n_layers": 5, "experts_held": 8, "expert_offset": 0, "vocab": 20480}, 2)}
 for name in sys.argv[1:] or CELLS:
     model, ov, bs = CELLS[name]
     try:

@@ -36,6 +36,7 @@ CELLS = {
     "nemotron3-nano-solo-8k": ("nemotron3_nano_30b_a3b", 1, 1, 2, {"n_layers": 7, "experts_held": 8, "vocab": 16384}),
     # what the cell's batch of 2 was chosen against (PERF.md section 4): 17.21e9, over the chip
     "nemotron3-nano-batch4": ("nemotron3_nano_30b_a3b", 1, 1, 4, {"n_layers": 7, "experts_held": 8, "vocab": 16384}),
+    "kimi-linear-solo-8k": ("kimi_linear_48b_a3b", 1, 1, 2, {"n_layers": 5, "experts_held": 8, "vocab": 20480}),
 }
 
 
@@ -44,11 +45,11 @@ def described_v5e():
     program's backend checks answering as they do on the chip."""
     from jax.experimental import topologies
 
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention, short_conv, ssd
+    from distributedvolunteercomputing_tpu.ops import kda, moe_dispatch, pallas_attention, short_conv, ssd
     from distributedvolunteercomputing_tpu.utils import jaxenv
 
     jaxenv.tpu_backend = pallas_attention.tpu_backend = moe_dispatch.tpu_backend = lambda: True
-    short_conv.tpu_backend = ssd.tpu_backend = jaxenv.tpu_backend
+    short_conv.tpu_backend = ssd.tpu_backend = kda.tpu_backend = jaxenv.tpu_backend
     moe_dispatch.grouped_matmul_impl = lambda m, k, n: "megablox"
     return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices
 

@@ -233,6 +233,18 @@ _YARDSTICK_PINS = (
     ("test_manifest_tail_as_the_lfm2_tests_asserted_it_one_metric_and_a_cell_up", "test_yardstick_glm4_moe_lite.py",
      "asserts the manifest's tail one metric and a cell up from LFM2's; nemotron3-nano-solo-8k and the three ssm.* metrics "
      "were appended after them, and the cell to conv.* and moe.act_zero_share (checked in test_yardstick_nemotron_h.py)"),
+    # PR 52 (kimi-linear-48b-a3b, kimi-linear-solo-8k, kda.device_ms / kda.roofline / kda.carry_share; the cell appended
+    # to the lists Nemotron's cell ended but moe.act_zero_share and ssm.*):
+    # tests/yardstick/test_yardstick_kimi_linear.py asserts what each of these asserted, three metrics, a cell and a
+    # configuration up.
+    ("test_configuration_file_is_what_the_program_runs", "[kimi-linear-48b-a3b]",
+     "asserts reduced == []; kimi-linear-48b-a3b lists its cut (checked in test_yardstick_kimi_linear.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_nemotron_h.py",
+     "asserts that nemotron3-nano-solo-8k ends the manifest, the three ssm.* metrics per_layer and its lists; PR 52 appended "
+     "three metrics, a cell and a configuration (checked in test_yardstick_kimi_linear.py)"),
+    ("test_manifest_tail_as_the_glm_tests_asserted_it_three_metrics_and_a_cell_up", "test_yardstick_nemotron_h.py",
+     "asserts the manifest's tail three metrics and a cell up from GLM's; kimi-linear-solo-8k and the three kda.* metrics "
+     "were appended after them (checked in test_yardstick_kimi_linear.py)"),
 )
 
 
@@ -241,6 +253,27 @@ def _mark_yardstick_pins_a_new_cell_moves(items):
         for test, where, reason in _YARDSTICK_PINS:
             if getattr(item, "originalname", None) == test and where in item.nodeid:
                 item.add_marker(pytest.mark.xfail(reason=reason, raises=AssertionError, strict=True))
+
+
+# Every program jax compiles holds memory maps of its own until its cache drops it, and a test that runs a model
+# eagerly compiles hundreds (tests/test_kda.py: 600 maps a test). The kernel gives a process 65,530
+# (vm.max_map_count); a worker that had run enough such files ended in the compiler with a segmentation fault or an
+# abort, in whichever test came next (PR 52: three whole runs of four). Dropping the caches gives the maps back.
+_MAPS_HIGH = 40_000
+
+
+@pytest.fixture(autouse=True)
+def _memory_maps_stay_under_the_kernels_limit():
+    yield
+    try:
+        with open("/proc/self/maps") as fh:
+            held = sum(1 for _ in fh)
+    except OSError:
+        return
+    if held > _MAPS_HIGH:
+        import jax
+
+        jax.clear_caches()
 
 
 @pytest.fixture(scope="session")

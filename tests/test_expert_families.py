@@ -19,7 +19,7 @@ import pytest
 from benchmark.manifest import Manifest
 from distributedvolunteercomputing_tpu import models as models_package
 from distributedvolunteercomputing_tpu.models import (
-    get_model, glm4_moe_lite, laguna, lfm2, list_models, moe, nemotron_h, smallthinker,
+    get_model, glm4_moe_lite, kimi_linear, laguna, lfm2, list_models, moe, nemotron_h, smallthinker,
 )
 from distributedvolunteercomputing_tpu.models.registry import _LANGUAGE_MODELS
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
@@ -67,6 +67,10 @@ FAMILIES = {
                  lambda b: {k: v for k, v in first(b[0]).items() if k != "before"}, (),
                  dict(act_zeros=True, chunks_extra=True), None,
                  STEPPED_KEYS | {"moe_act_zero_share", "ssm_carry_share"}),
+    # its latent-attention layer: a KDA layer's statistics hold its scan's counters beside the share's
+    "kimi": (kimi_linear, "kimi_linear_48b_a3b", "tiny-rehearsal-kimi", lambda b: first(b[2]),
+             ("latent_attention", "sparse"), dict(chunks_extra=True), None,
+             STEPPED_KEYS | {"kda_carry_share", "kda_decay_min", "kda_beta_mean"}),
 }
 
 
@@ -235,7 +239,8 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
     for key, value in overrides.items():
         got = getattr(bundle.config, key)
         assert (list(got) if isinstance(got, tuple) else got) == value
-    assert (bundle.stepped is not None) == (name in ("lfm2_24b_a2b", "glm4_7_flash", "nemotron3_nano_30b_a3b"))
+    assert (bundle.stepped is not None) == (name in ("lfm2_24b_a2b", "glm4_7_flash", "nemotron3_nano_30b_a3b",
+                                                     "kimi_linear_48b_a3b"))
     if bundle.stepped is not None:
         assert bundle.stepped.signal == moe.COUNTS
     # the swarm averages the whole tree of every model but the one with adapters

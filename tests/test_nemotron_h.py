@@ -10,6 +10,7 @@ import json
 import os
 import subprocess
 import sys
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -43,7 +44,7 @@ def tiny(seed=3, scale=3.0, bias=0.05, **overrides):
 
     def moved(path, x):
         name = jax.tree_util.keystr(path)
-        key = jax.random.fold_in(jax.random.PRNGKey(11), abs(hash(name)) % (2 ** 31))
+        key = jax.random.fold_in(jax.random.PRNGKey(11), zlib.crc32(name.encode()) % (2 ** 31))  # no PYTHONHASHSEED moves it
         if name.endswith("['bias']"):
             return bias * jax.random.normal(key, x.shape)
         if name.endswith("['g']") or name.endswith("['d_skip']"):

@@ -806,3 +806,99 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(6.3373e9, rel=1e-3)  # float32 parameters and two Adam moments
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.3e9     # at 4 x 8,192: 17.21e9, over the chip
+
+
+def test_the_delta_rule_scan_and_a_value_head_of_128_under_keys_of_192_compile_at_the_published_mixers(v5e):
+    """kimi-linear-solo-8k's new mixers by the chip's own compiler: the
+    delta-rule scan forward and backward at 32 heads of 128 keys and 128 values,
+    chunks of 64, two sequences of 8,192 (XLA's loops over the 128 chunks, each
+    carrying the heads' [2, 32, 128, 128] float32 states: what
+    ``benchmark/kda_trace.py`` tells them by in a trace), and the flash kernels
+    with q and k at a head of 192 and v, o, dO and dv at 128."""
+    from benchmark import kda_trace
+    from distributedvolunteercomputing_tpu.ops import kda, pallas_attention
+
+    one = SingleDeviceSharding(v5e[0])
+    z, t, h, d, chunk = 2, 8192, 32, 128, 64
+    stream = jax.ShapeDtypeStruct((z, t, h, d), jnp.bfloat16, sharding=one)
+    decay = jax.ShapeDtypeStruct((z, t, h, d), jnp.float32, sharding=one)
+    beta = jax.ShapeDtypeStruct((z, t, h), jnp.float32, sharding=one)
+
+    def scan_fwd_bwd(q, k, v, g, beta, do):
+        o, vjp = jax.vjp(lambda *a: kda.kda(*a, chunk)[0], q, k, v, g, beta)
+        return o, vjp(do)
+
+    compiled = jax.jit(scan_fwd_bwd).lower(stream, stream, stream, decay, beta, stream).compile()
+    loops = [kda_trace.carried(ln.strip()) for ln in compiled.as_text().splitlines() if " while(" in ln]
+    scans = [shapes for shapes in loops if (z, h, d, d) in shapes]
+    held = sorted(sum(s[:2] == (t // chunk, z) for s in shapes) for shapes in scans)
+    # one loop forward (the streams, o, the states) and one backward (those, dO and the five cotangents)
+    assert len(scans) == 2 and held[0] <= kda_trace.FORWARD_HOLDS_AT_MOST < held[1], (len(loops), held)
+    assert held == [7, 12] and compiled.memory_analysis().temp_size_in_bytes <= 2.3e9      # 2.16e9: the states, the streams by chunk
+
+    assert pallas_attention.choose_blocks(t, t, 192, jnp.bfloat16) == (1024, 1024)
+    q = jax.ShapeDtypeStruct((z, h, t, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((z, h, t, 128), jnp.bfloat16, sharding=one)
+
+    def attention_fwd_bwd(q, k, v, do):
+        o, vjp = jax.vjp(lambda *a: flash_attention(*a, causal=True, interpret=False), q, k, v)
+        return o, vjp(do)
+
+    calls = _kernel_calls(jax.jit(attention_fwd_bwd).lower(q, q, v, v).compile().as_text())
+    fwd, bwd = (next(ln for ln in calls if name in ln) for name in ("dvc_flash_fwd", "dvc_flash_bwd"))
+    assert len(calls) == 2 and fwd.split(" custom-call(")[0].count("bf16[2,32,8192,128]") == 1    # o at the value width
+    assert bwd.split(" custom-call(")[0].count("bf16[2,32,8192,192]") == 2 and "bf16[2,32,8192,128]" in bwd.split(" custom-call(")[0]
+
+
+def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
+    """kimi-linear-solo-8k's step (published layers 1-5 of
+    Kimi-Linear-48B-A3B-Instruct at its published widths, eight of 256 experts
+    held, an eighth of the vocabulary, 2 x 8,192 tokens): four traced layer
+    shapes (layer 1, layers 2-3 as one scanned body, layer 4, layer 5), every
+    layer rematerialised. Each traced KDA layer runs the scan's loop forward,
+    again in its recomputed forward (it keeps nothing) and backward, and each of
+    its three convolutions' kernels likewise; the latent layer takes the flash kernel at
+    keys of 192 over values of 128, forward and backward only. The share's
+    grouped products see the dispatch's default chunk of 12,288 rows (three even
+    shares of 4,096), seven a traced expert layer. That it compiles says it fits
+    the chip."""
+    from benchmark import kda_trace
+    from distributedvolunteercomputing_tpu.models import kimi_linear
+    from distributedvolunteercomputing_tpu.ops import attention, kda, moe_dispatch
+
+    monkeypatch.setattr(kda, "tpu_backend", lambda: True)     # bfloat16 products as the chip takes them
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    seen, kept = [], []
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
+        (impl, t, d, window, kv_heads)))
+    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
+    try:
+        compiled = _lowered_step(v5e, "kimi_linear_48b_a3b", 1, 1, 2, n_layers=5, experts_held=8, vocab=20480).compile()
+    finally:
+        attention.set_core_observer(None)
+        attention.set_kept_observer(None)
+    assert seen == [("flash", 8192, 192, None, 32)], seen
+    # what the layers keep: the latent layer's output at 32 x 128 a token and its row statistics; a KDA layer nothing
+    assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    names = [n.split(".")[0] for n in _kernel_names(calls)]
+    assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
+    # the scan's loops, told as benchmark/kda_trace.py tells them in a trace: three traced KDA layers, each forward twice and backward
+    scans = [shapes for shapes in (kda_trace.carried(ln.strip()) for ln in text.splitlines() if " while(" in ln)
+             if (2, 32, 128, 128) in shapes]
+    assert sorted(sum(s[:2] == (128, 2) for s in shapes) > kda_trace.FORWARD_HOLDS_AT_MOST for shapes in scans) == [False] * 6 + [True] * 3
+    assert sorted(n for n in names if n.startswith("dvc_short_conv")) == ["dvc_short_conv_bwd"] * 9 + ["dvc_short_conv_fwd"] * 18
+    assert all("bf16[2,8192,4096]" in ln for ln in calls if "dvc_short_conv" in ln)
+    rows = moe_dispatch.share_rows_bound(2 * 8192, 8, 8, 256, kimi_linear.SHARE_ROWS_SLACK)
+    assert rows == 12288 and f"[{rows},2304]" in text and "[131072,2304]" not in text   # never the S x k assignments
+    from benchmark import moe_trace
+
+    gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
+    assert len(gmm) == 7 * 3 and "ragged-dot" not in text, gmm      # three traced expert layers, seven products each
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(7.2296e9, rel=1e-3)  # float32 parameters and two Adam moments
+    # 17.16e9 by this analysis (9.93e9 of temporaries): the chip's own compile loaded it and ran it beside the
+    # check, memory_peak_bytes 15.04e9 of 16.9e9 (my chip runs, PR 52, calls 9-10)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 17.3e9
