@@ -6,9 +6,11 @@ Layer: compiled step. Moves tok_s_chip.
 
 1.0 is a dispatch that moves only what it computes; E / held (16 in
 laguna-solo-8k) one that moves every one of the S x k assignments. The
-program's bounded chunk is three times the even share (ops/moe_dispatch.py:
-``share_rows_bound``), so an even load reads 3 and a share the router has
-trained away from reads more.
+program's bounded chunk is the model's slack times the even share
+(ops/moe_dispatch.py: ``share_rows_bound``), so an even load reads that slack:
+3.0 in Laguna, GLM-4.7-Flash and Kimi-Linear (the default), 4.25 in
+SmallThinker (its own), 1.25 in LFM2 and Nemotron (the levelled routers'). A
+share the router has trained away from reads more, one it crowds reads less.
 
 A program that records no such attributes (a dense model, one that holds every
 expert, the parent of PR 33) gives nothing."""

@@ -47,6 +47,24 @@ def test_at_most_a_quarter_of_the_cells_take_four_chips():
     assert len(set(pairs)) == len(pairs)
 
 
+@pytest.mark.parametrize("cell", ["smallthinker-solo-16k", "lfm2-solo-8k", "glm47-flash-solo-8k",
+                                  "nemotron3-nano-solo-8k", "kimi-linear-solo-8k"])
+def test_a_cell_that_times_inside_an_lr_warmup_says_so(cell):
+    """The five share cells whose configuration carries ``volunteer.warmup_steps``:
+    the window (steps 5 to under 100) lies in the warm-up's first twentieth, so the
+    cell's ``why`` says what schedule its number belongs to."""
+    entry = M.cell(cell)
+    cfg = M.load_config(entry["config"])
+    assert cfg["volunteer"]["warmup_steps"] == cfg["assumed"]["lr_warmup"]["warmup_steps"] == 2000
+    assert "in LR warm-up" in entry["why"] and len(entry["why"]) <= 200
+
+
+def test_only_a_cell_whose_configuration_carries_a_warmup_says_it_times_inside_one():
+    for w in DOC["workloads"]:
+        carries = "warmup_steps" in M.load_config(w["config"])["volunteer"]
+        assert ("in LR warm-up" in w["why"]) == carries, w["name"]
+
+
 @pytest.mark.parametrize("metric", ALL_METRICS, ids=lambda m: m["name"])
 def test_metric_names_units_and_bounds(metric):
     assert NAME_RE.match(metric["name"]) and UNIT_RE.match(metric["unit"])

@@ -157,6 +157,18 @@ def test_configuration_file_is_what_the_program_runs_with_its_cut_listed():
             assert CFG[key] == value, key  # every width and both layout lists whole
 
 
+def test_the_window_lies_inside_an_lr_warmup_stated_with_its_routing_trace():
+    """PR 54: at 1e-3 from the first step the softmax router collapses inside
+    the five warm-up steps and the step's time follows the seed; the cell times
+    the schedule the four other warm-up share cells run in."""
+    warm = CFG["assumed"]["lr_warmup"]
+    assert CFG["volunteer"]["warmup_steps"] == warm["warmup_steps"] == 2000
+    assert TINY["volunteer"]["warmup_steps"] == 2000
+    assert "linear warm-up from 0 over warmup_steps" in CFG["assumed"]["optimizer"]
+    for word in ("2412.19437", "WITHOUT", "WITH it", "24,576", "3,072", "--warmup-steps 0", "PR 54"):
+        assert word in warm["why"], word
+
+
 # -- FLOPs, pairs and bytes ----------------------------------------------------------
 
 
