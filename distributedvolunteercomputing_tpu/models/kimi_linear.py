@@ -43,7 +43,9 @@ choice. Final RMSNorm, an untied head. Loss = mean next-token cross-entropy over
 the vocabulary (slice); no auxiliary loss.
 
 The delta rule runs as a chunked scan with its own backward (``ops/kda.py``,
-chunks of 64, token-major streams), the three convolutions each through
+chunks of 64; it takes q, k, v and the log decay as the convolution and the
+products leave them, the heads side by side, and norms q and k, folds beta and
+sums the decay on the chunk its step holds), the three convolutions each through
 ``ops/short_conv.causal_conv`` over its 4,096 channels (which wants a bias: it
 is handed constant zeros that are no leaf), latent attention through
 ``attention_core`` with keys of 192 over values of 128 (the flash kernels take
@@ -95,8 +97,6 @@ PUBLISHED_KDA_LAYERS = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21,
 PUBLISHED_FULL_LAYERS = (4, 8, 12, 16, 20, 24, 27)
 # what the weights' divisor adds to the chosen scores' sum (the family's public code's)
 ROUTE_EPS = 1e-20
-# what the l2 norm of a head's query and key adds under its root (the family's public kernels')
-L2_EPS = 1e-6
 # the decay's initialisation (not in the published config; the configuration file's ``assumed.kda_init``):
 # ``exp(A_log)`` uniform in [1, A_MAX], ``dt_bias`` the inverse softplus of a dt log-uniform in [DT_MIN, DT_MAX]
 A_MAX, DT_MIN, DT_MAX = 16.0, 1e-3, 1e-1
@@ -274,12 +274,6 @@ def init(rng: jax.Array, cfg: KimiLinearConfig) -> common.Params:
 # ---------------------------------------------------------------------------
 
 
-def _l2norm(x: jax.Array, scale: float = 1.0) -> jax.Array:
-    """Each head's vector (the last axis) at length ``scale``."""
-    xf = x.astype(jnp.float32)
-    return (xf * (scale * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + L2_EPS))).astype(x.dtype)
-
-
 def _kda(p: common.Params, n: jax.Array, cfg: KimiLinearConfig):
     """The mixer on the normed stream ``n`` [B, T, d]: (its output [B, T, d],
     what its scan says of itself: ``ops/kda.scan_counters`` and the mean ``beta``)."""
@@ -290,13 +284,15 @@ def _kda(p: common.Params, n: jax.Array, cfg: KimiLinearConfig):
     # each stream from its own columns of W_qkv through its own convolution (one stream of 12,288 channels is
     # 73 MB of the kernel's 64 MB of VMEM at its block of 256 positions); the bias is constant zeros, no leaf
     w_qkv, no_bias = p["w_qkv"].astype(dtype), jnp.zeros((h * hd,), jnp.float32)
-    q, k, v = (by_head(causal_conv(n @ w_qkv[:, at:at + h * hd], p["conv_w"][:, at:at + h * hd], no_bias))
+    q, k, v = (causal_conv(n @ w_qkv[:, at:at + h * hd], p["conv_w"][:, at:at + h * hd], no_bias)
                for at in range(0, 3 * h * hd, h * hd))
-    q, k = _l2norm(q, hd ** -0.5), _l2norm(k)
+    # the log decay with the heads side by side as the product leaves them: a head's rate on each of its channels
     f = (n @ p["w_fa"].astype(dtype)) @ p["w_fb"].astype(dtype)
-    g = -jnp.exp(p["a_log"])[:, None] * jax.nn.softplus(by_head(f.astype(jnp.float32) + p["dt_bias"]))
+    g = -jnp.repeat(jnp.exp(p["a_log"]), hd) * jax.nn.softplus(f.astype(jnp.float32) + p["dt_bias"])
     beta = jax.nn.sigmoid((n @ p["w_beta"].astype(dtype)).astype(jnp.float32))
-    o, sums = kda_ops.kda_with_sums(q, k, v, g, beta, cfg.chunk)
+    # q and k un-normed: the scan norms a head's vectors, folds beta and sums the decay on the chunk it holds
+    # (ops/kda.py), and splitting the heads here is a reshape it takes back: nothing of a stream's size by head
+    o, sums = kda_ops.kda_with_sums(by_head(q), by_head(k), by_head(v), by_head(g), beta, cfg.chunk)
     gate = jax.nn.sigmoid(((n @ p["w_ga"].astype(dtype)) @ p["w_gb"].astype(dtype)).astype(jnp.float32)
                           + p["gate_b"])
     y = common.rmsnorm(p["o_norm"], o, cfg.rms_eps).astype(jnp.float32) * by_head(gate)

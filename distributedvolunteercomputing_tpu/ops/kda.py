@@ -31,23 +31,39 @@ levels, from the diagonal outwards: forward substitution in blocks, two small
 products a level (``_inverse``, which says why not the shorter product of
 ``I + (-L)^(2^k)``).
 
-``kda`` takes the streams token-major as the projections and the convolution
-leave them, ``q``, ``k`` [batch, T, H, K], ``v`` [batch, T, H, V], ``g``
-[batch, T, H, K] float32, ``beta`` [batch, T, H]. One chunk of one head is plain
-matrices (``_chunk_fwd`` / ``_chunk_bwd``); ``core`` is a ``lax.scan`` over the
-chunks with those functions mapped over sequences and heads, and its backward
-the reverse scan that carries the state's cotangent and recomputes a chunk from
-the state that entered it (float32, kept by the forward: [batch, T / C, H, V,
-K]). XLA compiles it for the CPU, one chip and a step over several chips alike;
-there is no kernel, because the same chunk functions as Pallas kernels (a head's
-state resident in VMEM) were slower on a v5e than XLA's loops, alone and in the
-step (``PERF.md``, PR 52). A device trace shows the scan as ``while`` loops that
-carry the heads' states (``benchmark/kda_trace.py``).
+``kda`` takes the mixer's streams token-major as the projections and the
+convolution leave them: ``q``, ``k`` [batch, T, H, K] UN-NORMED, ``v`` [batch,
+T, H, V], ``g`` [batch, T, H, K] float32, ``beta`` [batch, T, H]. It sees them
+with the heads side by side again ([batch, T, H K]: where the caller split the
+heads by a reshape, none is left) and nothing of a stream's size is laid out
+again, widened or worked by head outside the loops. ``core`` is a ``lax.scan``
+over the chunks' numbers whose step takes its chunk [batch, C, H, ..] out of the
+streams IN PLACE (``lax.dynamic_slice`` along T: a chunk of a sequence is one
+block of C x H K values) and does on the chunk it holds what the equations'
+operands are made of (``_prepare``): a head's ``q`` at length K^-1/2 and ``k``
+at length 1 (float32, back in the streams' dtype), ``kb = beta k`` and ``vb =
+beta v`` (float32 products, rounded once), ``G`` the running sum of ``g`` down
+the chunk's rows (a lower-triangular product at float32's own precision). One
+chunk of one head is then plain matrices (``_chunk_fwd`` / ``_chunk_bwd``),
+mapped over sequences and heads, and ``o``'s chunk is written into [batch, T, H
+V] the same way. The backward is the reverse scan that carries the state's
+cotangent, recomputes a chunk from the raw streams and the state that entered
+it (float32, kept by the forward: [T / C, batch, H, V, K]), runs ``_chunk_bwd``
+and hands its cotangents to JAX's transpose of the chunk's preparation: the
+streams' cotangents come out raw, in place, and no formula of the preparation's
+is derived by hand. XLA compiles it for the CPU, one chip and a step over
+several chips alike (a slice along T of streams sharded over sequences and heads
+stays on its device); there is no kernel, because the same chunk functions as
+Pallas kernels (a head's state resident in VMEM) were slower on a v5e than XLA's
+loops, alone and in the step (``PERF.md``, PR 52). A device trace shows the scan
+as ``while`` loops that carry the heads' states (``benchmark/kda_trace.py``).
 
-``beta`` is folded into ``kb`` and ``vb`` and ``g`` summed by chunk outside the
-core, in passes that JAX differentiates. A sequence that is no whole number of
-chunks is padded with positions that neither decay nor write (``g`` 0, ``beta``
-0) and cut again.
+Until PR 55 the norms, beta's fold and the decay's running sum were float32
+passes over whole streams by head around the core, and the core was handed its
+chunks stacked ``[T / C, batch, C, H, ..]``: on the chip those passes and copies
+took as long as the loops (``PERF.md``, PR 55; ``experiments/kda_sweep.py``
+times both). A sequence that is no whole number of chunks is padded with
+positions that neither decay nor write (``g`` 0, ``beta`` 0) and cut again.
 """
 
 from __future__ import annotations
@@ -63,6 +79,8 @@ from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 CHUNK = 64
 # a chunk's whole decay, at the channel that decays least, under which the state carried into it counts as forgotten
 CARRY_FLOOR = 1e-3
+# what the l2 norm of a head's query and key adds under its root (the family's public kernels')
+L2_EPS = 1e-6
 _F32 = jnp.float32
 _BF16 = jnp.bfloat16
 
@@ -237,72 +255,127 @@ def _chunk_bwd(dst, st, q, k, kb, vb, gc, do):
 
 
 # ---------------------------------------------------------------------------
+# a chunk as the mixer's streams hold it: what the chunk functions' operands are made of
+# ---------------------------------------------------------------------------
+
+
+def _l2norm(x: jax.Array, scale: float = 1.0) -> jax.Array:
+    """A head's vector (the last axis) at length ``scale``: float32, back in ``x``'s dtype."""
+    xf = x.astype(_F32)
+    return (xf * (scale * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + L2_EPS))).astype(x.dtype)
+
+
+def _prepare(q, k, v, g, beta):
+    """One chunk of one head as the streams hold it (``q``, ``k`` [C, K]
+    un-normed, ``v`` [C, V], the log decay ``g`` [C, K], ``beta`` [C, 1]) -> the
+    chunk functions' operands (q, k, kb [C, K], vb [C, V] in the streams' dtype,
+    gc [C, K] float32): every product and sum float32, each result rounded once."""
+    q, k = _l2norm(q, q.shape[-1] ** -0.5), _l2norm(k)
+    b = beta.astype(_F32)
+    kb = (k.astype(_F32) * b).astype(k.dtype)
+    vb = (v.astype(_F32) * b).astype(v.dtype)
+    # the running sum down the chunk's rows: a lower-triangular product at float32's own precision
+    row, col = _grid(q.shape[0])
+    gc = _dot32(jnp.where(row >= col, 1.0, 0.0), g.astype(_F32))
+    return q, k, kb, vb, gc
+
+
+def _head_fwd(st, q, k, v, g, beta):
+    """(the state that leaves [V, K], the chunk's summed log decay [K], o [C, V]) of one raw chunk of one head."""
+    q, k, kb, vb, gc = _prepare(q, k, v, g, beta)
+    o, st_new = _chunk_fwd(st, q, k, kb, vb, gc)
+    return st_new, gc[-1], o
+
+
+def _head_bwd(dst, st, q, k, v, g, beta, do):
+    """(The cotangent of the state that entered, the raw chunk's: dq, dk [C, K],
+    dv [C, V], dg [C, K], dbeta [C, 1], each in its stream's dtype): the
+    preparation computed again and differentiated by JAX around ``_chunk_bwd``,
+    whose cotangents leave it in the operands' dtypes."""
+    prepared, back = jax.vjp(_prepare, q, k, v, g, beta)
+    *d, dst_prev = _chunk_bwd(dst, st, *prepared, do)
+    return (dst_prev, *back(tuple(a.astype(p.dtype) for a, p in zip(d, prepared))))
+
+
+# ---------------------------------------------------------------------------
 # a scan over the chunks, the chunk's functions over sequences and heads
 # ---------------------------------------------------------------------------
-# streams [Z, T, H, K or V], T a whole number of chunks; states [Z, nc, H, V, K]
+# streams [Z, T, H K or H V] as the projections and the convolution leave them, beta
+# [Z, T, H], T a whole number of chunks: a step takes its chunk [Z, C, ..] out of them
+# in place and writes its results' chunk the same way; states [nc, Z, H, V, K]
 
 
-def _over_heads(fn, n_states: int, n_streams: int):
-    """``fn`` of ``n_states`` states [Z, H, V, K] then ``n_streams`` streams [Z, C, H, *]: over Z and H."""
-    def axes(stream_axis):
-        return (0,) * n_states + (stream_axis,) * n_streams
-    return jax.vmap(jax.vmap(fn, in_axes=axes(1)), in_axes=axes(0))
+def _over_heads(fn, values: Tuple[int, int], streams: Tuple[int, int]):
+    """``fn`` over Z and H. Its arguments are ``values[0]`` values a head [Z, H,
+    ..] then ``streams[0]`` streams' chunks [Z, C, H, ..]; its results
+    ``values[1]`` and ``streams[1]`` of the same, in that order."""
+    def axes(at: int, stream_axis: int):
+        return (0,) * values[at] + (stream_axis,) * streams[at]
+    return jax.vmap(jax.vmap(fn, in_axes=axes(0, 1), out_axes=axes(1, 1)), in_axes=axes(0, 0), out_axes=0)
 
 
-def _by_chunk(a, chunk: int):
-    """[Z, T, H, D] -> [nc, Z, C, H, D]."""
-    z, t, h, d = a.shape
-    return jnp.moveaxis(a.reshape(z, t // chunk, chunk, h, d), 1, 0)
+def _chunk_indices(nc: int):
+    """The chunks' numbers, UNSIGNED: a signed index is first tested for counting from the end, and behind that
+    test the TPU compiler no longer sees that a chunk starts at a multiple of its rows (an update in place of
+    rows that may straddle tiles took 11 us a chunk of a stream where an aligned one takes under 4: my chip runs, PR 55)."""
+    return jnp.arange(nc, dtype=jnp.uint32)
 
 
-def _from_chunks(a):
-    """[nc, Z, H, C, D] (the mapped functions' results) -> [Z, T, H, D]."""
-    nc, z, h, c, d = a.shape
-    return jnp.moveaxis(a, (0, 3), (1, 2)).reshape(z, nc * c, h, d)
+def _chunk_of(a, i, chunk: int, heads: int):
+    """Chunk ``i`` of a stream [Z, T, H D] by head: [Z, C, H, D] (``beta``, [Z, T, H]: D = 1)."""
+    return jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=1).reshape(a.shape[0], chunk, heads, -1)
 
 
-def _scan_fwd(q, k, kb, vb, gc, chunk: int):
-    """(o [Z, T, H, V], the state entering each chunk [Z, nc, H, V, K] float32)."""
-    z, t, h, dk = q.shape
-    step_fn = _over_heads(_chunk_fwd, 1, 5)
-
-    def step(st, xs):
-        o, st_new = step_fn(st, *xs)
-        return st_new, (o, st)
-
-    xs = tuple(_by_chunk(a, chunk) for a in (q, k, kb, vb, gc))
-    _, (o, states) = jax.lax.scan(step, jnp.zeros((z, h, vb.shape[-1], dk), _F32), xs)
-    return _from_chunks(o), jnp.moveaxis(states, 0, 1)
+def _put_chunk(a, block, i, chunk: int):
+    """The stream ``a`` [Z, T, H D] with ``block`` [Z, C, H, D] as its chunk ``i``."""
+    return jax.lax.dynamic_update_slice_in_dim(a, block.reshape(a.shape[0], chunk, -1), i * chunk, axis=1)
 
 
-def _scan_bwd(q, k, kb, vb, gc, states, do, chunk: int):
-    z, t, h, dk = q.shape
-    step_fn = _over_heads(_chunk_bwd, 2, 6)
+def _scan_fwd(q, k, v, g, beta, heads: int, chunk: int):
+    """(o [Z, T, H V], every chunk's summed log decay [nc, Z, H, K] float32, the
+    state entering each chunk [nc, Z, H, V, K] float32)."""
+    z, t, _ = q.shape
+    step_fn = _over_heads(_head_fwd, (1, 2), (5, 1))
 
-    def step(dst, xs):
-        *out, dg, dst_prev = step_fn(dst, *xs)
-        # the streams' cotangents leave a chunk in the streams' dtype: what the scan stacks is half as wide
-        return dst_prev, (*(d.astype(a.dtype) for d, a in zip(out, (q, k, kb, vb))), dg)
+    def step(carry, i):
+        st, o = carry
+        st_new, total, o_i = step_fn(st, *(_chunk_of(a, i, chunk, heads) for a in (q, k, v, g, beta)))
+        return (st_new, _put_chunk(o, o_i, i, chunk)), (total, st)
 
-    xs = (jnp.moveaxis(states, 1, 0), *(_by_chunk(a, chunk) for a in (q, k, kb, vb, gc, do)))
-    _, outs = jax.lax.scan(step, jnp.zeros((z, h, vb.shape[-1], dk), _F32), xs, reverse=True)
-    return tuple(_from_chunks(a) for a in outs)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def core(q, k, kb, vb, gc, chunk: int) -> jax.Array:
-    """``o`` [Z, T, H, V] from ``q``, ``k``, ``kb = beta k`` [Z, T, H, K], ``vb =
-    beta v`` [Z, T, H, V] and the chunks' running sums ``gc`` [Z, T, H, K]
-    float32."""
-    return _scan_fwd(q, k, kb, vb, gc, chunk)[0]
+    start = (jnp.zeros((z, heads, v.shape[-1] // heads, q.shape[-1] // heads), _F32), jnp.zeros_like(v))
+    (_, o), (sums, states) = jax.lax.scan(step, start, _chunk_indices(t // chunk))
+    return o, sums, states
 
 
-def _core_fwd(q, k, kb, vb, gc, chunk):
-    o, states = _scan_fwd(q, k, kb, vb, gc, chunk)
-    return o, (q, k, kb, vb, gc, states)
+def _scan_bwd(q, k, v, g, beta, states, do, heads: int, chunk: int):
+    step_fn = _over_heads(_head_bwd, (2, 1), (6, 5))
+    streams = (q, k, v, g, beta)
+
+    def step(carry, xs):
+        dst, grads = carry
+        i, st = xs
+        dst_prev, *d = step_fn(dst, st, *(_chunk_of(a, i, chunk, heads) for a in (*streams, do)))
+        return (dst_prev, tuple(_put_chunk(a, d_i, i, chunk) for a, d_i in zip(grads, d))), None
+
+    start = (jnp.zeros(states.shape[1:], _F32), tuple(jnp.zeros_like(a) for a in streams))
+    (_, grads), _ = jax.lax.scan(step, start, (_chunk_indices(states.shape[0]), states), reverse=True)
+    return grads
 
 
-core.defvjp(_core_fwd, lambda chunk, res, do: _scan_bwd(*res, do, chunk))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def core(q, k, v, g, beta, heads: int, chunk: int) -> Tuple[jax.Array, jax.Array]:
+    """(``o`` [Z, T, H V], the chunks' summed log decays [nc, Z, H, K] float32:
+    no gradient) from the mixer's streams with the heads side by side: ``q``,
+    ``k`` un-normed and ``g`` [Z, T, H K], ``v`` [Z, T, H V], ``beta`` [Z, T, H]."""
+    return _scan_fwd(q, k, v, g, beta, heads, chunk)[:2]
+
+
+def _core_fwd(q, k, v, g, beta, heads, chunk):
+    o, sums, states = _scan_fwd(q, k, v, g, beta, heads, chunk)
+    return (o, sums), (q, k, v, g, beta, states)
+
+
+core.defvjp(_core_fwd, lambda heads, chunk, res, d: _scan_bwd(*res, d[0], heads, chunk))
 
 
 # ---------------------------------------------------------------------------
@@ -314,22 +387,16 @@ def kda_with_sums(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: 
                   chunk: int = CHUNK) -> Tuple[jax.Array, jax.Array]:
     """``kda``'s ``o`` [batch, T, H, V] in ``q``'s dtype and every chunk's summed
     log decay [batch, T / C, H, K] float32 (no gradient: what the counters read)."""
-    z, t, h, dk = q.shape
+    z, t, h, _ = q.shape
     if chunk < 2 or chunk & (chunk - 1):
         raise ValueError(f"chunk={chunk} is no power of two: the pairs of a chunk are taken by level")
+    # the heads side by side again, as the projections hold them: where the caller split them by a reshape, no copy
+    streams = tuple(a.reshape(z, t, -1) for a in (q, k, v, g, beta))
     pad = (-t) % chunk
     if pad:
-        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0))) for a in (q, k, v, g))
-        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
-    tp = t + pad
-    nc = tp // chunk
-    b = beta.astype(_F32)[..., None]
-    kb = (k.astype(_F32) * b).astype(k.dtype)
-    vb = (v.astype(_F32) * b).astype(v.dtype)
-    gc = jnp.cumsum(g.astype(_F32).reshape(z, nc, chunk, h, dk), axis=2)
-    sums = jax.lax.stop_gradient(gc[:, :, -1])
-    o = core(q, k, kb, vb, gc.reshape(z, tp, h, dk), chunk)
-    return (o[:, :t] if pad else o), sums
+        streams = tuple(jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in streams)
+    o, sums = core(*streams, h, chunk)
+    return o[:, :t].reshape(z, t, h, -1), jnp.moveaxis(jax.lax.stop_gradient(sums), 0, 1)
 
 
 def carry_share(sums: jax.Array) -> jax.Array:
@@ -345,9 +412,11 @@ def carry_share(sums: jax.Array) -> jax.Array:
 def kda(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
         chunk: int = CHUNK) -> Tuple[jax.Array, jax.Array]:
     """The recurrence at the top of this module over the mixer's streams,
-    token-major: ``q`` (scaled), ``k`` [batch, T, H, K], ``v`` [batch, T, H, V],
-    the log decay ``g`` [batch, T, H, K] (at most 0) and ``beta`` [batch, T, H]:
-    (``o`` [batch, T, H, V] in ``q``'s dtype, ``carry_share`` of the chunks' sums)."""
+    token-major: ``q``, ``k`` [batch, T, H, K] as the convolution leaves them
+    (UN-NORMED: the scan takes a head's ``q`` at length K^-1/2 and its ``k`` at
+    length 1), ``v`` [batch, T, H, V], the log decay ``g`` [batch, T, H, K] (at
+    most 0) and ``beta`` [batch, T, H]: (``o`` [batch, T, H, V] in ``q``'s dtype,
+    ``carry_share`` of the chunks' sums)."""
     o, sums = kda_with_sums(q, k, v, g, beta, chunk)
     return o, carry_share(sums)
 

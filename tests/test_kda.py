@@ -1,6 +1,8 @@
 """ops/kda.py (Kimi Delta Attention's recurrence, a delta rule with a decay by
 channel, as a chunked scan with its own backward) at a tiny size on the CPU:
-the scan against the recurrence TOKEN BY TOKEN, values and every gradient."""
+the scan against the recurrence TOKEN BY TOKEN, values and every gradient, and
+against the formulation it had until PR 55 (the streams normed, folded, summed
+and laid out by chunk as whole passes around the scan)."""
 
 import jax
 import jax.numpy as jnp
@@ -12,10 +14,17 @@ from distributedvolunteercomputing_tpu.ops import kda
 NAMES = ("q", "k", "v", "g", "beta")
 
 
+def unit(x):
+    """Each head's vector at length 1, as ``kda`` norms the q and k it is handed."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + kda.L2_EPS)
+
+
 def recurrence(q, k, v, g, beta):
-    """The three steps a token, float32: decay the state by channel, the delta
-    along the new key, the rank-one update; ``o_t = S_t^T q_t``."""
+    """A head's q at length 1 / sqrt(K) and k at length 1, then the three steps
+    a token, float32: decay the state by channel, the delta along the new key,
+    the rank-one update; ``o_t = S_t^T q_t``."""
     z, t, h, dk = q.shape
+    q, k = unit(q.astype(jnp.float32)) * dk ** -0.5, unit(k.astype(jnp.float32))
 
     def token(s, now):
         q_t, k_t, v_t, g_t, b_t = now
@@ -36,12 +45,12 @@ def _highest_precision():
 
 
 def scan_inputs(seed=0, z=2, t=40, h=3, dk=8, dv=16, decay=0.3, dtype=jnp.float32):
-    """Seeded streams and a probe for the output: unit keys and queries, a log
-    decay a channel from nearly none (1e-3 a token) to ``decay`` a token."""
+    """Seeded streams and a probe for the output: keys and queries of no
+    particular length (the scan norms them), a log decay a channel from nearly
+    none (1e-3 a token) to ``decay`` a token."""
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = (unit(jax.random.normal(k[0], (z, t, h, dk))) * dk ** -0.5).astype(dtype)
-    key = unit(jax.random.normal(k[1], (z, t, h, dk))).astype(dtype)
+    q = (1.7 * jax.random.normal(k[0], (z, t, h, dk))).astype(dtype)
+    key = (0.6 * jax.random.normal(k[1], (z, t, h, dk))).astype(dtype)
     v = jax.random.normal(k[2], (z, t, h, dv)).astype(dtype)
     g = -jnp.exp(jax.random.uniform(k[3], (z, t, h, dk), jnp.float32, jnp.log(1e-3), jnp.log(decay)))
     beta = jax.random.uniform(k[4], (z, t, h), jnp.float32, 0.05, 0.95)
@@ -124,6 +133,40 @@ def test_in_bfloat16_the_scan_stays_within_rounding_of_the_float32_recurrence():
         close(a, b, 5e-2, f"d {name}")
 
 
+@pytest.mark.parametrize("tokens", [48, 40], ids=["whole_chunks", "a_padded_tail"])
+@pytest.mark.parametrize("dtype, o_tol, grad_tol", [(jnp.float32, 1e-5, 1e-5), (jnp.bfloat16, 3e-2, 5e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_scan_on_raw_streams_is_the_formulation_it_replaced(dtype, o_tol, grad_tol, tokens):
+    """``kda`` takes its chunks out of the raw streams in place and norms, folds
+    and sums on the chunk it holds. Held to PR 52's formulation
+    (``experiments/kda_sweep.parent_kda``: whole-stream l2 norms, beta's fold and
+    the running sum by chunk as passes that JAX differentiates, then a scan over
+    chunks stacked ``[nc, Z, ..]``): ``o``, the chunks' sums and the gradient of
+    EVERY input, at two sequences (the second one's slices) and with a tail that
+    is no whole chunk (which the parent's entry padded as this one does)."""
+    from experiments.kda_sweep import parent_kda
+
+    chunk = 16
+    args, probe = scan_inputs(t=tokens, dtype=dtype)
+    pad = (-tokens) % chunk
+
+    def parent(*streams):
+        padded = tuple(jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in streams)
+        return parent_kda(*padded, chunk)[:, :tokens]
+
+    want, want_grads = value_and_grads(parent, args, probe)
+    got, grads = value_and_grads(lambda *a: kda.kda(*a, chunk=chunk)[0], args, probe)
+    assert got.dtype == want.dtype == dtype and [a.dtype for a in grads] == [b.dtype for b in want_grads]
+    close(got, want, o_tol, "o")
+    for name, a, b in zip(NAMES, grads, want_grads):
+        close(a, b, grad_tol, f"d {name}")
+    sums = kda.kda_with_sums(*args, chunk=chunk)[1]
+    g = jnp.pad(args[3], ((0, 0), (0, pad), (0, 0), (0, 0)))
+    assert sums.shape == (2, (tokens + pad) // chunk, 3, 8) and sums.dtype == jnp.float32
+    close(sums, jnp.sum(g.reshape(2, -1, chunk, 3, 8), axis=2), 1e-6, "the chunks' sums")
+    assert not np.any(np.asarray(jax.grad(lambda g: jnp.sum(kda.kda_with_sums(*args[:3], g, args[4], chunk=chunk)[1]))(args[3])))
+
+
 def test_a_sequence_that_is_no_whole_number_of_chunks_is_padded_with_tokens_that_do_nothing():
     """40 tokens in chunks of 16 are the first 40 of 48: the padding neither
     decays nor writes, and a token changes nothing before it."""
@@ -167,7 +210,6 @@ def test_a_chunk_of_keys_that_resemble_each_other_is_solved_without_cancellation
     blocks is forward substitution and keeps float32's precision."""
     k = jax.random.split(jax.random.PRNGKey(5), 4)
     z, t, h, dk, dv = 1, 64, 2, 8, 8
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     key = unit(jnp.ones((z, t, h, dk)) + 0.2 * jax.random.normal(k[0], (z, t, h, dk)))
     q = unit(jax.random.normal(k[1], (z, t, h, dk))) * dk ** -0.5
     v = jax.random.normal(k[2], (z, t, h, dv))

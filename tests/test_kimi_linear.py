@@ -350,6 +350,62 @@ def test_a_step_moves_each_bias_by_gamma_by_the_counts_and_the_decay_leaves_by_t
         assert np.any(np.asarray(m1[leaf]) != m0[leaf]), leaf
 
 
+def _outside_the_loops(jaxpr):
+    """Every equation of a jaxpr and of what it calls, but for the bodies of its ``scan`` and ``while`` loops."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name not in ("scan", "while"):
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _outside_the_loops(sub)
+
+
+def _passes_around_the_scan(fn, *args, shape, nc):
+    """What the gradient's program holds outside its loops of the two kinds of
+    pass PR 55 took out: (arrays of a stream's size laid out by chunk, leading
+    ``[nc, Z]``; ``transpose`` / ``cumsum`` / ``reduce_sum`` equations that read a
+    float32 stream by head ``[B, T, H, hd]`` or ``[B, nc, C, H, hd]``; the loops met)."""
+    z, size = shape[0], int(np.prod(shape))
+    by_chunk, by_head, loops = set(), [], 0
+    for eqn in _outside_the_loops(jax.make_jaxpr(fn)(*args).jaxpr):
+        loops += eqn.primitive.name in ("scan", "while")
+        avals = [v.aval for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape")]
+        by_chunk |= {a.shape for a in avals if a.shape[:2] == (nc, z) and int(np.prod(a.shape)) == size}
+        if eqn.primitive.name in ("transpose", "cumsum", "reduce_sum") and any(
+                a.shape[-2:] == shape[-2:] and int(np.prod(a.shape)) == size and a.dtype == jnp.float32
+                for a in (v.aval for v in eqn.invars if hasattr(v.aval, "shape"))):
+            by_head.append(eqn.primitive.name)
+    return by_chunk, sorted(by_head), loops
+
+
+def test_the_mixers_gradient_holds_no_stream_by_chunk_and_no_float32_pass_by_head_around_the_scan():
+    """What the trace of PR 52's step showed as 173 ms around the scan's loops
+    (`PERF.md` section 5) were passes over whole streams: float32 by head
+    ``[B, T, H, hd]`` (the l2 norms' sums, the decay's running sum, their
+    cotangents' transposes) and copies by chunk ``[nc, Z, C, H, hd]``. A jaxpr at
+    a tiny size cannot time them; it can say they are gone from the gradient of
+    ``_kda`` outside the loops' bodies. What stays by head outside is the head
+    norm of ``o`` with its gate (one ``reduce_sum`` forward, two backward:
+    ``common.rmsnorm``, left where it was). The same walk over PR 52's
+    formulation (``experiments/kda_sweep.parent_kda``) finds both kinds, so it
+    can see what it looks for."""
+    from experiments.kda_sweep import parent_kda
+
+    cfg = kimi_linear.KimiLinearConfig(**{**kimi_linear.TINY, "chunk": 8})   # states [nc, Z, H, 16, 16]: twice a stream
+    b, t, h, hd = 2, 32, cfg.kda_heads, cfg.kda_head_dim
+    p = kimi_linear._kda_init(jax.random.split(jax.random.PRNGKey(0), 10), cfg)
+    n = jax.random.normal(jax.random.PRNGKey(1), (b, t, cfg.d_model))
+    loss = lambda p, n: jnp.sum(kimi_linear._kda(p, n, cfg)[0] ** 2)
+    by_chunk, by_head, loops = _passes_around_the_scan(jax.grad(loss, argnums=(0, 1)), p, n, shape=(b, t, h, hd), nc=t // 8)
+    assert loops == 2 and not by_chunk, by_chunk
+    assert by_head == ["reduce_sum"] * 3, by_head                           # the head norm of o alone
+
+    args = tuple(jax.random.normal(jax.random.PRNGKey(i), (b, t, h, hd)) for i in range(4)) + (jnp.full((b, t, h), 0.5),)
+    parent = jax.grad(lambda *a: jnp.sum(parent_kda(*a, 8) ** 2), argnums=(0, 1, 2, 3, 4))
+    by_chunk, by_head, loops = _passes_around_the_scan(parent, *args, shape=(b, t, h, hd), nc=t // 8)
+    assert loops == 2 and (t // 8, b, 8, h, hd) in by_chunk and (t // 8, b, h, 8, hd) in by_chunk
+    assert "cumsum" in by_head and by_head.count("reduce_sum") >= 4 and "transpose" in by_head, by_head
+
+
 def test_stacked_runs_take_the_sharding_rules(eight_devices):
     from jax.sharding import Mesh
 
@@ -363,6 +419,37 @@ def test_stacked_runs_take_the_sharding_rules(eight_devices):
     run = specs["blocks"][1]
     assert "ep" in tuple(run["experts"]["w_up"])
     assert "ep" not in tuple(run["mixer"]["w_qkv"]) and "ep" not in tuple(run["bias"])
+
+
+def test_the_scan_under_a_mesh_takes_its_chunks_out_of_each_devices_own_shard(eight_devices):
+    """Sequences over ``dp`` and heads over ``tp``, as a step's mesh lays the
+    mixer's streams: a chunk is a slice along T, which no device divides, so the
+    scan's loops and their backward hold no collective, and ``o`` and every
+    gradient are what one device computes."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributedvolunteercomputing_tpu.ops import kda
+
+    z, t, h, d, chunk = 2, 40, 4, 8, 16
+    keys = jax.random.split(jax.random.PRNGKey(0), 6)
+    q, k, v, probe = (jax.random.normal(keys[i], (z, t, h * d)) for i in range(4))
+    g = -jax.random.uniform(keys[4], (z, t, h * d), jnp.float32, 1e-3, 0.3)
+    beta = jax.random.uniform(keys[5], (z, t, h), jnp.float32, 0.05, 0.95)
+
+    def fwd_bwd(q, k, v, g, beta, probe):
+        by_head = lambda a: a.reshape(z, t, h, d)
+        o, vjp = jax.vjp(lambda q, k, v, g, beta: kda.kda(by_head(q), by_head(k), by_head(v), by_head(g), beta, chunk)[0]
+                         .reshape(z, t, h * d), q, k, v, g, beta)
+        return (o, *vjp(probe))
+
+    mesh = Mesh(np.asarray(eight_devices[:4]).reshape(2, 2), ("dp", "tp"))
+    laid = NamedSharding(mesh, P("dp", None, "tp"))
+    args = (q, k, v, g, beta, probe)
+    compiled = jax.jit(fwd_bwd, in_shardings=(laid,) * 6, out_shardings=(laid,) * 6).lower(*args).compile()
+    text = compiled.as_text()
+    assert not [w for w in ("all-gather", "all-reduce", "all-to-all", "collective-permute", "reduce-scatter") if w in text]
+    for got, want in zip(compiled(*(jax.device_put(a, laid) for a in args)), jax.jit(fwd_bwd)(*args)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
 
 
 def test_train_loop_records_the_scan_span_beside_the_route_span():
