@@ -31,6 +31,7 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import contextlib
+import json
 import os
 import sys
 import threading
@@ -50,6 +51,7 @@ from distributedvolunteercomputing_tpu.training.steps import (
     make_grad_step,
     make_train_step,
 )
+from distributedvolunteercomputing_tpu.utils import step_scopes
 from distributedvolunteercomputing_tpu.utils.logging import errstr, get_logger
 
 log = get_logger(__name__)
@@ -276,6 +278,7 @@ class Trainer:
 
             self.compile_cache_dir = enable_compile_cache()
             self._compile_log = compile_log()  # listening before the step compiles
+            self._scoped: set = set()  # step functions whose abstract arguments are remembered
             self.device = device_record()
             self.bundle = bundle
             self.batch_size = batch_size
@@ -569,7 +572,15 @@ class Trainer:
         step function's second output, which no later call donates) are
         ready; it and the root are ended by a waiter thread, as
         ``loop.snapshot.land`` is by the landing thread, so this thread goes
-        on dispatching as far ahead of the chip as it did."""
+        on dispatching as far ahead of the chip as it did.
+
+        With or without a tracer, the first call of each step function leaves
+        its arguments' shapes, dtypes and shardings with ``utils.step_scopes``
+        (before the call: it donates the state), which builds the program's
+        scope map from them if somebody asks."""
+        if fn.__name__ not in self._scoped:
+            self._scoped.add(fn.__name__)
+            step_scopes.remember(fn, args)
         root = self._lifecycle
         if root is None:
             return fn(*args)
@@ -607,6 +618,31 @@ class Trainer:
 
         threading.Thread(target=wait, name="lifecycle-first-step", daemon=True).start()
         return out
+
+    def _write_step_scopes(self, profile_dir: str) -> threading.Thread:
+        """``<profile_dir>/step_scopes.json`` beside a profile that has just
+        stopped: the step program's name, the scope vocabulary and the map
+        from instruction to scope and pass (``utils.step_scopes``), which
+        ``experiments/step_ops_in_trace.py`` joins to the trace's events. jax's
+        own caches answer the lowering and the compile in milliseconds; should
+        they have dropped the step, it compiles again: so a daemon thread makes
+        the map, as ``lifecycle-first-step`` ends its span."""
+        fn = self._step_fn if self._step_fn is not None else self._grad_fn
+        program = f"jit({fn.__name__})"
+
+        def write() -> None:
+            try:
+                doc = step_scopes.step_scopes(program)
+                if doc is not None:
+                    with open(os.path.join(profile_dir, "step_scopes.json"), "w") as fh:
+                        json.dump(doc, fh)
+                    log.info("step scopes of %s written to %s (%s s)", program, profile_dir, doc["seconds"])
+            except Exception as e:  # noqa: BLE001 - an operator's aid must not end a run
+                log.warning("no step scopes for %s: %s", program, errstr(e))
+
+        writer = threading.Thread(target=write, name="step-scopes", daemon=True)
+        writer.start()
+        return writer
 
     def _note_routing(self, step_no: int, m: Dict[str, Any], at_log_point: bool) -> None:
         """Between log points, every ``ROUTE_EVERY`` steps: keep a sparse-expert
@@ -1163,6 +1199,7 @@ class Trainer:
         profile_start = int(os.environ.get("DVC_PROFILE_START", "10"))
         profile_steps = int(os.environ.get("DVC_PROFILE_STEPS", "10"))
         profiling = False
+        scopes_writer: Optional[threading.Thread] = None
         # Grads mode averages every step; after a FAILED round (no group —
         # e.g. the only partner died) skip averaging for average_every steps
         # instead of paying a full matchmaking timeout per step.
@@ -1352,6 +1389,7 @@ class Trainer:
                 jax.profiler.stop_trace()
                 profiling = False
                 log.info("profiler trace written to %s", profile_dir)
+                scopes_writer = self._write_step_scopes(profile_dir)
 
             if self.on_step is not None:
                 self.on_step(self, step_no)
@@ -1372,6 +1410,9 @@ class Trainer:
                     break
         if profiling:  # loop ended inside the trace window
             jax.profiler.stop_trace()
+            scopes_writer = self._write_step_scopes(profile_dir)
+        if scopes_writer is not None:
+            scopes_writer.join()  # the steps are done: the file is there when this returns
         # Drain an in-flight round so the returned params are contracted and
         # a partner mid-round isn't abandoned by our exit.
         if self.overlap:

@@ -245,6 +245,14 @@ _YARDSTICK_PINS = (
     ("test_manifest_tail_as_the_glm_tests_asserted_it_three_metrics_and_a_cell_up", "test_yardstick_nemotron_h.py",
      "asserts the manifest's tail three metrics and a cell up from GLM's; kimi-linear-solo-8k and the three kda.* metrics "
      "were appended after them (checked in test_yardstick_kimi_linear.py)"),
+    # PR 56 (the nine scope.* metrics appended to per_layer; no cell, no configuration, no list touched):
+    # tests/yardstick/test_yardstick_scopes.py runs both of these as they stand against the manifest nine places up.
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_kimi_linear.py",
+     "asserts that the three kda.* metrics end per_layer; PR 56 appended the nine scope.* metrics after them "
+     "(checked, nine places up, in test_yardstick_scopes.py)"),
+    ("test_manifest_tail_as_the_nemotron_tests_asserted_it_three_metrics_and_a_cell_up", "test_yardstick_kimi_linear.py",
+     "asserts the manifest's tail three metrics up from Kimi's; the nine scope.* metrics were appended after them "
+     "(checked, nine places up, in test_yardstick_scopes.py)"),
 )
 
 

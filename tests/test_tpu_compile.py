@@ -152,22 +152,59 @@ def _lowered_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int =
     return _traced_step(v5e, model, dp, tp, batch, n_layers, **overrides).lower()
 
 
+_STEP_TEXTS: dict = {}  # three of the steps are read by two tests each (15-22 s a compile): compiled once
+
+
 def _step_text(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides) -> str:
     """The compiled HLO of that step."""
-    return _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).compile().as_text()
+    key = (model, dp, tp, batch, n_layers, tuple(sorted(overrides.items())))
+    if key not in _STEP_TEXTS:
+        _STEP_TEXTS[key] = _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).compile().as_text()
+    return _STEP_TEXTS[key]
 
 
 def _kernel_calls(text: str):
     return [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "custom-call(" in ln]
 
 
+# By COUNT most of a module is parameters and tuple plumbing, which carry no scope (by time `other` is 4-12%
+# of a step: the chip's reading, `PERF.md` section 5); a scope word that stopped reaching the HLO would pass this.
+SCOPE_OTHER_AT_MOST = 0.80
+
+
+def _step_holds_the_groups_its_cell_lists(text: str, cell: str) -> None:
+    """The step's scope map, from the text the chip's own compiler gives at the
+    cell's size (``utils/step_scopes.scope_map``: what a traced run of ``cell``
+    joins to its device events), holds every group whose ``scope.<group>_ms``
+    metric lists ``cell`` in ``BENCHMARK.json`` and no other; every
+    checkpointed block scope is there forward, recomputed and backward; and
+    ``other`` holds under a stated share of the instructions."""
+    import collections
+
+    from benchmark.manifest import Manifest
+    from distributedvolunteercomputing_tpu.utils import step_scopes
+
+    listed = {m["name"][len("scope."):-len("_ms")] for m in Manifest().doc["per_layer"]
+              if m["name"].startswith("scope.") and m["name"].endswith("_ms") and cell in m["workloads"]}
+    assert listed >= {"attention", "loss_head", "optimizer", "other"}, listed
+    got = step_scopes.scope_map(text)
+    seen = collections.Counter((step_scopes.group_of(r["scope"]), r["pass"]) for r in got.values())
+    assert {group for group, _ in seen} == listed, (sorted(seen), listed)
+    for group in listed - {"loss_head", "optimizer", "other"}:
+        assert all(seen[(group, which)] for which in step_scopes.PASSES), (group, seen)
+    other = sum(n for (group, _), n in seen.items() if group == "other")
+    assert other / len(got) <= SCOPE_OTHER_AT_MOST, (other, len(got))
+
+
 def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     """medium-solo's step, auto routing: T=1,024 bf16 takes the fused core,
     forward and backward; the recomputed forward holds no kernel (the layer's
     checkpoint kept its output and row statistics: ``common.remat_layer``)."""
-    calls = _kernel_calls(_step_text(v5e, "gpt2_medium", 1, 1, 16))
+    text = _step_text(v5e, "gpt2_medium", 1, 1, 16)
+    calls = _kernel_calls(text)
     assert len(calls) == 2
     assert all("bf16[16,16,1024,64]" in ln for ln in calls)
+    _step_holds_the_groups_its_cell_lists(text, "medium-solo")
 
 
 def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
@@ -185,7 +222,9 @@ def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     # the choice itself counts this host's 8 CPUs as chips
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    calls = _kernel_calls(_step_text(v5e, "olmoe_1b_7b", 1, 1, 4, n_layers=1))
+    text = _step_text(v5e, "olmoe_1b_7b", 1, 1, 4, n_layers=1)
+    _step_holds_the_groups_its_cell_lists(text, "olmoe-solo")
+    calls = _kernel_calls(text)
     names = [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
     flash = [n for n in names if n.startswith("dvc_flash_")]
     gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
@@ -250,6 +289,7 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     compiled = _lowered_step(
         v5e, "laguna_xs2", 1, 1, 4, n_layers=5, experts_held=16, vocab=12544).compile()
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "laguna-solo-8k")
     calls = _kernel_calls(text)
     names = _kernel_names(calls)
     full = [n for n in names if n.startswith(("dvc_flash_fwd", "dvc_flash_bwd"))]
@@ -311,6 +351,7 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
         attention.set_core_observer(None)
     assert sorted(set(seen), key=str) == [("flash", t, 4096, 4), ("flash", t, None, 4)], seen
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "smallthinker-solo-16k")
     calls = _kernel_calls(text)
     flash = sorted(n.split(".")[0] for n in _kernel_names(calls) if n.startswith("dvc_flash"))
     assert flash == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_win_bwd", "dvc_flash_win_fwd"], flash
@@ -353,6 +394,7 @@ def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
+    _step_holds_the_groups_its_cell_lists(text, "large-solo-4chip")
     calls = _kernel_calls(text)
     assert len(calls) == 2
     assert all("bf16[16,10,1024,64]" in ln for ln in calls)
@@ -619,6 +661,7 @@ def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip
         attention.set_core_observer(None)
     assert set(seen) == {("flash", 8192, 64, None, 8)}, seen
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "lfm2-solo-8k")
     calls = _kernel_calls(text)
     names = _kernel_names(calls)
     flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
@@ -682,6 +725,7 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     # the output at 20 x 256 a token and the f32 row statistics: 168.8 MB a layer, 845.4 MB a step
     assert kept == [(1, 169_082_880), (4, 4 * 169_082_880)], kept
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "glm47-flash-solo-8k")
     calls = _kernel_calls(text)
     names = _kernel_names(calls)
     flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
@@ -786,6 +830,7 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     # what the blocks keep: the attention block's output and row statistics; a state-space block nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "nemotron3-nano-solo-8k")
     calls = _kernel_calls(text)
     names = [n.split(".")[0] for n in _kernel_names(calls)]
     assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
@@ -898,6 +943,7 @@ def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_t
     # what the layers keep: the latent layer's output at 32 x 128 a token and its row statistics; a KDA layer nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "kimi-linear-solo-8k")
     calls = _kernel_calls(text)
     names = [n.split(".")[0] for n in _kernel_names(calls)]
     assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
