@@ -181,7 +181,7 @@ def stacked_init(layer_init, rng: jax.Array, n_layers: int) -> Params:
     return jax.vmap(layer_init)(keys)
 
 
-def remat_layer(body, layers: int = 1):
+def remat_layer(body, layers: int = 1, calls: int = 1):
     """``body`` (one layer) rematerialised in the backward pass: the one place
     that decides what a rematerialised layer keeps. Beside the layer's inputs
     that is what costs a layer input's worth of memory and a long wait to make
@@ -197,7 +197,8 @@ def remat_layer(body, layers: int = 1):
     its program is a bare ``jax.checkpoint``'s. The expert models' ``wo`` /
     ``w_down`` results are not named: no cell runs them over ``tp`` and their
     room at 8,192-16,384 tokens a row is smaller than this stack. ``layers``:
-    how many layers run this one trace (a scan's length), for
+    how many layers run this one trace (a scan's length), and ``calls``: how
+    often ``body`` runs what it traced once (``scan_blocks``' row streams), for
     ``swarm.remat_kept``'s bytes."""
     from distributedvolunteercomputing_tpu.ops.pallas_attention import KEPT_NAMES
 
@@ -206,25 +207,55 @@ def remat_layer(body, layers: int = 1):
     fn = jax.checkpoint(body, policy=policy)
 
     def layer(*args):
-        with attention_ops.keeping_kernel_results(layers):
+        with attention_ops.keeping_kernel_results(layers, calls):
             return fn(*args)
 
     return layer
 
 
-def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_outputs: bool = False):
+def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_outputs: bool = False,
+                rows_independent: bool = False):
     """Run ``x`` through stacked ``blocks`` with ``lax.scan``; ``body`` is
     ``(layer_params, x) -> x``. With ``remat`` each layer's activations are
     rematerialized in backward (``remat_layer`` per scan step), the standard
     O(sqrt)-free layerwise remat that keeps HBM at one layer's activations.
     ``with_outputs``: ``body`` returns ``(x, y)`` and the layers' ``y`` come
-    back stacked beside the final ``x``."""
-    fn = remat_layer(body, jax.tree_util.tree_leaves(blocks)[0].shape[0]) if remat else body
+    back stacked beside the final ``x``.
+
+    ``rows_independent``: the caller's word that ``body`` couples no two rows
+    of ``x`` (a dense block; not a layer whose dispatch chunks or statistics
+    run over the batch). Where the traced step's mesh divides a layer over
+    ``tp`` and each replica's rows are even (``attention_ops.tp_streams``) the
+    scan then carries ``x`` as a PAIR of row halves, split once here and
+    merged once after the last layer, and ONE body, under one checkpoint,
+    applies ``body`` to each half in turn: two chains that share only the
+    weights, so the compiler can run one's all-reduce over ``tp`` beside the
+    other's products and kernel (parallel/train_step.py compiles such a step
+    with asynchronous collectives), forward and backward. The checkpoint keeps
+    of each half what it kept of the whole. Elsewhere the jaxpr is the one it
+    was (the step over a ``tp`` axis is compiled with that axis's options
+    whether or not a model splits)."""
+    streams = 1
+    if rows_independent:
+        streams = attention_ops.tp_streams(x.shape[0])
+        attention_ops.observe_streams(streams)
+    if streams > 1:
+        if with_outputs:
+            raise ValueError("row streams carry no layer outputs")
+        one = jax.jit(body)  # the halves have one shape: traced once, called for each
+        x = attention_ops.split_rows(x, streams)
+
+        def body(p, hs):
+            return tuple(one(p, h) for h in hs)
+
+    fn = remat_layer(body, jax.tree_util.tree_leaves(blocks)[0].shape[0], streams) if remat else body
 
     def step(h, p):
         return fn(p, h) if with_outputs else (fn(p, h), None)
 
     x, ys = jax.lax.scan(step, x, blocks)
+    if streams > 1:
+        x = attention_ops.merge_rows(x)
     return (x, ys) if with_outputs else x
 
 

@@ -121,7 +121,8 @@ def _trunk(params: common.Params, tokens: jax.Array, cfg: GPT2Config) -> jax.Arr
     the training and inference trunks can never drift apart."""
     x = embed(params, tokens, cfg)
     return common.scan_blocks(
-        lambda p, h: _block(p, h, cfg), params["blocks"], x, remat=cfg.remat
+        lambda p, h: _block(p, h, cfg), params["blocks"], x, remat=cfg.remat,
+        rows_independent=True,  # nothing in a dense block couples two rows
     )
 
 

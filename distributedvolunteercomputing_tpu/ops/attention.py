@@ -131,11 +131,12 @@ def set_kept_observer(fn) -> None:
 
 
 @contextlib.contextmanager
-def keeping_kernel_results(layers: int):
+def keeping_kernel_results(layers: int, calls: int = 1):
     """Around the trace of one rematerialised layer body that ``layers``
     layers run: gathers what ``_flash_per_shard`` says a chip keeps of each
     kernel call in it, and ``keep_tp_reduced`` of each value it named, and
-    reports the sum."""
+    reports the sum; ``calls`` times over where the body runs ``calls`` times
+    what it traced once (a row stream each)."""
     global _kept_ctx
     prev, _kept_ctx = _kept_ctx, []
     kept = _kept_ctx
@@ -144,7 +145,7 @@ def keeping_kernel_results(layers: int):
     finally:
         _kept_ctx = prev
     if kept and _kept_observer is not None:
-        _kept_observer(layers, layers * sum(kept))
+        _kept_observer(layers, layers * calls * sum(kept))
 
 
 def chips_in_step() -> int:
@@ -163,6 +164,59 @@ def heads_tp() -> int:
     if _mesh_ctx is None or "tp" in jax.sharding.get_abstract_mesh().manual_axes:
         return 1
     return _mesh_ctx.shape.get("tp", 1)
+
+
+# Called once per TRACED layer scan of a model whose rows a layer does not
+# couple (models/common.scan_blocks with ``rows_independent``) with how many row
+# streams the scanned body runs: 2 where ``tp_streams`` gave two, 1 where it
+# fell back (swarm.tp_streams, beside swarm.qkv_projection).
+_streams_observer = None
+
+
+def set_streams_observer(fn) -> None:
+    global _streams_observer
+    _streams_observer = fn
+
+
+def observe_streams(streams: int) -> None:
+    if _streams_observer is not None:
+        _streams_observer(streams)
+
+
+def tp_streams(rows: int) -> int:
+    """As how many independent row streams a layer should run a batch of
+    ``rows`` rows: 2 where the traced step's mesh divides the layer over ``tp``
+    (each row-parallel product then ends in an all-reduce over the link, which
+    only OTHER rows' work can run beside) and every ``dp`` replica holds an
+    even number of rows; 1 elsewhere, and the jaxpr is the one it was (a step
+    over ``tp`` is still compiled with ``parallel/train_step``'s options for
+    that axis, one stream or two)."""
+    if heads_tp() == 1:
+        return 1
+    return 2 if rows % (2 * _mesh_ctx.shape.get("dp", 1)) == 0 else 1
+
+
+def split_rows(x: jax.Array, streams: int):
+    """``x`` [B, ...] as ``streams`` arrays [B / streams, ...], each holding
+    the same share of EVERY ``dp`` replica's rows and laid out over ``dp``
+    itself: ``x[:B / 2]`` of a dp-sharded batch would put one stream on each
+    replica and move activations across ``dp``. ``merge_rows`` undoes it."""
+    dp = _mesh_ctx.shape.get("dp", 1)
+    parts = x.reshape(dp, streams, x.shape[0] // (dp * streams), *x.shape[1:])
+    return tuple(_rows_over_dp(parts[:, s].reshape(-1, *x.shape[1:])) for s in range(streams))
+
+
+def merge_rows(parts) -> jax.Array:
+    """The rows ``split_rows`` took apart, in the order they had."""
+    dp = _mesh_ctx.shape.get("dp", 1)
+    rest = parts[0].shape[1:]
+    stacked = jnp.stack([p.reshape(dp, -1, *rest) for p in parts], axis=1)
+    return _rows_over_dp(stacked.reshape(-1, *rest))
+
+
+def _rows_over_dp(x: jax.Array) -> jax.Array:
+    free = jax.sharding.PartitionSpec.UNCONSTRAINED
+    return constrain_in_step(x, jax.sharding.PartitionSpec("dp", *[free] * (x.ndim - 1)))
 
 
 def keep_tp_reduced(x: jax.Array) -> jax.Array:

@@ -14,10 +14,25 @@ generic rule table that covers every transformer in the zoo:
 
 What a transformer layer costs over ``tp`` in a rematerialised train step
 (read off the compiled ``dp=2,tp=2`` gpt2_large step, tests/test_tpu_compile.py,
-and its trace, PERF.md section 5): FOUR all-reduces of [B/dp, T, d]
+and its trace, PERF.md section 5): FOUR all-reduces of a replica's [B/dp, T, d]
 activations — after attn_out and mlp_out in the forward, and for the input
-cotangent of each column-parallel product (mlp_in, qkv) in the backward —
-none of them hidden behind compute. That is the layout's price. A fifth, after
+cotangent of each column-parallel product (mlp_in, qkv) in the backward. That
+is the layout's price, and since PR 57 about half of it is paid beside other
+work: a model whose block couples no two rows (gpt2) runs each replica's rows
+as TWO independent streams inside the one scanned, rematerialised layer body
+(``models/common.scan_blocks``, ``ops/attention.tp_streams``), so each of the
+four is two all-reduces of [B/2dp, T, d], and the step is compiled with
+asynchronous collectives where the mesh has a ``tp`` axis
+(``parallel/train_step.step_compiler_options``): at each of the four sites the
+stream that comes first has its all-reduce running as a start / done pair
+beside the other stream's products and attention kernel; the stream that comes
+second has nothing of the layer left to hide behind and stays on the
+instruction stream. With one row a replica, an odd count, or ``tp`` 1 there is
+one stream and the jaxpr is the one it was; with ``tp`` 1 the compiled program
+too, while a one-stream step over ``tp`` > 1 (those counts, and every model
+``scan_blocks`` is not told to split) is still compiled with the ``tp``
+options: its all-reduces become pairs with nothing of their own rows to run
+beside (what that costs: PERF.md section 6, PR 57). A fifth, after
 attn_out again in the backward's recomputed forward, was ours and is gone:
 where ``tp`` > 1 the layer's checkpoint keeps attn_out's reduced result
 (``models/common.remat_layer``, ``ops/attention.keep_tp_reduced``; mlp_out's

@@ -886,6 +886,20 @@ class Telemetry:
                 "traced fused qkv projections by how they were divided over tp",
             ).inc(layout=layout, tp=str(tp))
 
+    def count_tp_streams(self, streams: int) -> None:
+        """One TRACED layer scan of a model whose block couples no two rows
+        (gpt2) runs each replica's rows as ``streams`` independent streams:
+        2 where the step's mesh divides a layer over ``tp`` and a replica's
+        rows are even, so that one stream's all-reduce runs beside the
+        other's products, 1 where it fell back to the program it was
+        (models/common.scan_blocks through ops.attention's
+        ``set_streams_observer``)."""
+        if self.enabled:
+            self.registry.counter(
+                "swarm.tp_streams",
+                "traced layer scans by the independent row streams their body runs",
+            ).inc(streams=str(streams))
+
     def count_remat_kept(self, layers: int, nbytes: int) -> None:
         """One TRACED rematerialised layer kept something beside its input
         (models/common.remat_layer through ops.attention's
@@ -1012,6 +1026,12 @@ class Telemetry:
         separate q, k and v leaves."""
         return self._counts_by("swarm.qkv_projection", "layout")
 
+    def tp_streams(self) -> Dict[str, int]:
+        """Traced layer scans by the row streams their body runs (``{"2": n}``
+        over ``tp``, ``{"1": n}`` elsewhere); empty for a model whose layers
+        couple rows and are never split."""
+        return self._counts_by("swarm.tp_streams", "streams")
+
     # -- RPC surface ---------------------------------------------------------
 
     def register_rpcs(self, transport) -> None:
@@ -1118,6 +1138,8 @@ class Telemetry:
             "attention_core": self.attention_cores(),
             # how often the fused qkv projection was divided by head over tp
             "qkv_projection": self.qkv_projections(),
+            # as how many independent row streams a layer over tp ran ({} for a model never split)
+            "tp_streams": self.tp_streams(),
             # what rematerialised layers kept of the attention kernel ({} on the XLA core)
             "remat_kept": self.remat_kept(),
             # a sparse-expert model's dispatches and routing gauges ({} if dense)

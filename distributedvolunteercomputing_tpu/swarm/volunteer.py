@@ -537,11 +537,13 @@ class Volunteer:
             set_core_observer,
             set_kept_observer,
             set_qkv_observer,
+            set_streams_observer,
         )
 
         set_core_observer(self.telemetry.count_attention_core if cfg.telemetry else None)
         set_qkv_observer(self.telemetry.count_qkv_projection if cfg.telemetry else None)
         set_kept_observer(self.telemetry.count_remat_kept if cfg.telemetry else None)
+        set_streams_observer(self.telemetry.count_tp_streams if cfg.telemetry else None)
         from distributedvolunteercomputing_tpu.ops.moe_dispatch import set_dispatch_observer
 
         set_dispatch_observer(self.telemetry.count_moe_dispatch if cfg.telemetry else None)
@@ -1336,6 +1338,10 @@ class Volunteer:
             # Traced fused qkv projections by layout ({"by_head": n} on a mesh
             # whose tp divides the heads, {"fused": n} elsewhere).
             self.summary["qkv_projection"] = self.telemetry.qkv_projections()
+            # Traced layer scans by the independent row streams their body
+            # runs ({"2": n} where the mesh divides a layer over tp and a
+            # replica's rows are even, {"1": n} elsewhere).
+            self.summary["tp_streams"] = self.telemetry.tp_streams()
             # Traced rematerialised layers that kept the attention kernel's
             # output and row statistics (over tp also the reduced attention
             # output product), and the bytes a chip keeps of them a step ({}

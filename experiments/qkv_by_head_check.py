@@ -20,8 +20,15 @@ PR 43 the step's trace also keeps ``attn_out``'s result after its sum over
 ``tp`` (``ops/attention.keep_tp_reduced``; ``heads_tp`` 1 turns that off with
 the by-head form), which the reference check sees as little: a third trace,
 by head under a bare ``jax.checkpoint`` a layer (nothing kept), says under
-``kept_against_bare`` what the keep alone moves. One JSON line, also in
-``chiprun_out/qkv_by_head_check.json``.
+``kept_against_bare`` what the keep alone moves. Since PR 57 the step's trace
+runs each replica's rows as two independent streams where ``tp`` divides the
+layer (``models/common.scan_blocks``, ``ops/attention.tp_streams``) and is
+compiled with the sharded step's own options
+(``parallel/train_step.step_compiler_options``: asynchronous collectives), which
+the reference check cannot see either: a fourth trace, by head with
+``tp_streams`` held to 1, says under ``two_streams_against_one`` what the split
+moves (the order in which a weight gradient sums its rows). One JSON line, also
+in ``chiprun_out/qkv_by_head_check.json``.
 
 On the CPU (``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``
 with ``--override n_layers=2 --override d_model=64 --override n_heads=4
@@ -45,6 +52,7 @@ from distributedvolunteercomputing_tpu.ops import attention
 from distributedvolunteercomputing_tpu.parallel import make_mesh, make_param_shardings
 from distributedvolunteercomputing_tpu.parallel.mesh import parse_mesh_spec
 from distributedvolunteercomputing_tpu.parallel.sharding import batch_sharding
+from distributedvolunteercomputing_tpu.parallel.train_step import step_compiler_options
 
 
 def main() -> int:
@@ -75,6 +83,8 @@ def main() -> int:
     attention.set_qkv_observer(lambda layout, tp: layouts.append(layout))
     kept = []  # a chip's bytes kept a step, one entry a trace that kept something
     attention.set_kept_observer(lambda layers, nbytes: kept.append(nbytes))
+    streams = []  # row streams a traced layer scan ran, one entry a trace
+    attention.set_streams_observer(streams.append)
 
     def make_loss_and_grads(bundle=bundle):  # a new function each time: jit caches traces by function
         def loss_and_grads(params, batch):
@@ -83,7 +93,7 @@ def main() -> int:
                     lambda p: bundle.loss_fn(p, batch, jax.random.PRNGKey(0))[0]
                 )(params)
 
-        return jax.jit(loss_and_grads)
+        return jax.jit(loss_and_grads, compiler_options=step_compiler_options(mesh))
 
     @jax.jit
     def compare(got, want):
@@ -111,8 +121,16 @@ def main() -> int:
     grad_rel_err, worst_leaf_rel_err = rel_errs(grads_head, grads_fused)
     if not args.reference:
         del grads_fused  # room for the third trace's gradients beside the step's temporaries
-    # by head again with nothing kept (a new bundle: a traced loss is cached)
-    remat_layer, common.remat_layer = common.remat_layer, lambda body, layers=1: jax.checkpoint(body)
+    # by head again as ONE row stream (a new bundle: a traced loss is cached)
+    tp_streams, attention.tp_streams = attention.tp_streams, lambda rows: 1
+    try:
+        loss_one, grads_one = make_loss_and_grads(get_model(args.model, **overrides))(params, batch)
+    finally:
+        attention.tp_streams = tp_streams
+    one_grad_rel_err, one_worst_leaf_rel_err = rel_errs(grads_head, grads_one)
+    del grads_one
+    # by head again with nothing kept
+    remat_layer, common.remat_layer = common.remat_layer, lambda body, *layers_and_calls: jax.checkpoint(body)
     try:
         loss_bare, grads_bare = make_loss_and_grads(get_model(args.model, **overrides))(params, batch)
     finally:
@@ -123,10 +141,15 @@ def main() -> int:
     result = {
         "model": args.model, "mesh": args.mesh, "batch": args.batch, "seed": args.seed,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count()},
-        "traced": {"by_head": traced_by_head, "fused": traced_fused, "kept_bytes": kept},
+        "traced": {"by_head": traced_by_head, "fused": traced_fused, "kept_bytes": kept, "streams": streams},
+        "compiler_options": step_compiler_options(mesh),
         "loss_by_head": float(loss_head), "loss_fused": float(loss_fused),
         "loss_abs_err": abs(float(loss_head) - float(loss_fused)),
         "grad_rel_err": grad_rel_err, "worst_leaf_rel_err": worst_leaf_rel_err,
+        "two_streams_against_one": {
+            "loss_abs_err": abs(float(loss_head) - float(loss_one)),
+            "grad_rel_err": one_grad_rel_err, "worst_leaf_rel_err": one_worst_leaf_rel_err,
+        },
         "kept_against_bare": {
             "loss_abs_err": abs(float(loss_head) - float(loss_bare)),
             "grad_rel_err": kept_grad_rel_err, "worst_leaf_rel_err": kept_worst_leaf_rel_err,
@@ -155,6 +178,8 @@ def main() -> int:
     ok = (
         set(traced_by_head) == {"by_head"} and set(result["traced"]["fused"]) == {"fused"}
         and result["loss_abs_err"] <= 0.005 and result["grad_rel_err"] <= 0.04
+        and result["two_streams_against_one"]["loss_abs_err"] <= 0.005
+        and result["two_streams_against_one"]["grad_rel_err"] <= 0.04
         and result["kept_against_bare"]["loss_abs_err"] <= 0.005
         and result["kept_against_bare"]["grad_rel_err"] <= 0.04
     )

@@ -253,6 +253,14 @@ _YARDSTICK_PINS = (
     ("test_manifest_tail_as_the_nemotron_tests_asserted_it_three_metrics_and_a_cell_up", "test_yardstick_kimi_linear.py",
      "asserts the manifest's tail three metrics up from Kimi's; the nine scope.* metrics were appended after them "
      "(checked, nine places up, in test_yardstick_scopes.py)"),
+    # PR 57 (two device.collective_all_* metrics appended to per_layer; no cell, no configuration, no list touched):
+    # tests/yardstick/test_yardstick_collective_pairs.py runs these as they stand against the manifest two places up.
+    ("test_manifest_holds_the_nine_scope_metrics_at_its_end", "test_yardstick_scopes.py",
+     "asserts that the nine scope.* metrics end per_layer, 66 entries; PR 57 appended device.collective_all_share and "
+     "_exposed after them (checked, two places up, in test_yardstick_collective_pairs.py)"),
+    ("test_manifest_as_the_kimi_tests_asserted_it_nine_places_up", "test_yardstick_scopes.py",
+     "asserts Kimi's tail nine places up from the manifest's end; it is eleven now "
+     "(checked, two places up, in test_yardstick_collective_pairs.py)"),
 )
 
 

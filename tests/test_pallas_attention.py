@@ -445,7 +445,7 @@ def bare_checkpoint(monkeypatch):
     from distributedvolunteercomputing_tpu.models import common
 
     def switch():
-        monkeypatch.setattr(common, "remat_layer", lambda body, layers=1: jax.checkpoint(body))
+        monkeypatch.setattr(common, "remat_layer", lambda body, *layers_and_calls: jax.checkpoint(body))
 
     return switch
 
@@ -488,9 +488,11 @@ def test_remat_layer_runs_the_forward_kernel_once(model, kept, bare, bare_checkp
 
 def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkpoint):
     """dp=2, tp=2: the kept names pass through ``_flash_per_shard``'s
-    ``shard_map``; one forward kernel call a layer, the bare checkpoint's
-    gradients bit for bit, and the bytes counted are one chip's share of the
-    kernel's results and of the reduced attention product."""
+    ``shard_map``; one forward kernel call a layer and ROW STREAM (a replica's
+    two rows run as two streams of one over ``tp``: ``common.scan_blocks``),
+    the bare checkpoint's gradients bit for bit, and the bytes counted are one
+    chip's share of the kernel's results and of the reduced attention product,
+    what they were as one stream."""
     from jax.sharding import Mesh
 
     from distributedvolunteercomputing_tpu.ops import attention
@@ -513,8 +515,8 @@ def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkp
         set_attention_impl("auto")
         attention.set_kept_observer(None)
     assert "shard_map" in str(jaxpr)
-    assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]
-    assert sorted(bare) == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_fwd"]
+    assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 2
+    assert sorted(bare) == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 4
     _assert_bit_equal(got, want)
     # a chip's share, [2, 2, 32, 16] of [4, 4, 32, 16], for two layers, and, tp
     # dividing the layer, the [2, 32, 64] f32 rows of the reduced attention

@@ -221,7 +221,7 @@ def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, 
         assert text.count(NAMED) == 1
     else:
         assert kept == {} and NAMED not in text
-    monkeypatch.setattr(common, "remat_layer", lambda body, layers=1: jax.checkpoint(body))
+    monkeypatch.setattr(common, "remat_layer", lambda body, *layers_and_calls: jax.checkpoint(body))
     bare_loss, bare_grads, bare_text = step_on(make_mesh(dp=dp, tp=tp))
     # what the name buys: the backward's recomputed forward runs no attention output product
     assert bare_text.count("dot_general") - text.count("dot_general") == (1 if tp > 1 else 0)
@@ -231,6 +231,139 @@ def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, 
         np.testing.assert_allclose(loss, want_loss, rtol=2e-4)
         jax.tree_util.tree_map(
             lambda got, ref: np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-5), grads, want)
+
+
+@pytest.fixture
+def tp_streams():
+    """Counts of ``swarm.tp_streams`` as a volunteer's telemetry takes them."""
+    from distributedvolunteercomputing_tpu.ops import attention
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+
+    tel = Telemetry(peer_id="t")
+    attention.set_streams_observer(tel.count_tp_streams)
+    yield lambda: tel.summary()["tp_streams"]  # what coord.status shows per peer
+    attention.set_streams_observer(None)
+
+
+def _without_row_streams(monkeypatch):
+    """``common.scan_blocks`` as it was before it knew of row streams."""
+    from distributedvolunteercomputing_tpu.models import common
+
+    scan_blocks = common.scan_blocks
+    monkeypatch.setattr(common, "scan_blocks", lambda *a, rows_independent=False, **kw: scan_blocks(*a, **kw))
+
+
+@pytest.mark.parametrize("rows,dp,tp,streams", [
+    (16, 2, 2, 2),  # large-solo-4chip's layout: 8 rows a replica, two streams of 4
+    (8, 2, 4, 2),
+    (2, 2, 2, 1),   # one row a replica
+    (6, 2, 2, 1),   # an odd count a replica
+    (16, 4, 1, 1),  # no tp to sum over
+], ids=["dp2-tp2", "dp2-tp4", "one-row", "odd-rows", "dp4-tp1"])
+def test_two_row_streams_over_tp_match_one_stream_and_single_device(
+        eight_devices, monkeypatch, tp_streams, rows, dp, tp, streams):
+    """A rematerialised gpt2 step on a mesh: where ``tp`` divides the layer and
+    each replica's rows are even the scanned body runs them as two independent
+    streams (``swarm.tp_streams`` reads 2) and the loss and every gradient leaf
+    (plain SGD at lr 1) are those of the one-stream body on the same mesh and
+    of the single-device step; where a replica holds one row or an odd count,
+    or ``tp`` is 1, the count reads 1 and the step's jaxpr IS the one-stream
+    body's."""
+    import re
+
+    import optax
+
+    cfg = dict(_LM, n_heads=4, remat=True)
+    tx = optax.sgd(1.0)
+    params = get_model("gpt2_small", **cfg).init(jax.random.PRNGKey(0))
+    batch = get_model("gpt2_small", **cfg).make_batch(jax.random.PRNGKey(1), rows)
+
+    def step_on(mesh, run=True):  # a new bundle a step: a traced loss is cached
+        bundle = get_model("gpt2_small", **cfg)
+        state = TrainState.create(params, tx, jax.random.PRNGKey(2))
+        if mesh is None:
+            step, put = make_train_step(bundle.loss_fn, tx, donate=False), batch
+        else:
+            state, _ = shard_train_state(state, mesh, tx)
+            step, put = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False), put_batch(batch, mesh)
+        text = re.sub(r"0x[0-9a-f]+", "0x", str(step.trace(state, put).jaxpr))  # a function's address
+        if not run:
+            return None, None, text
+        state, metrics = step(state, put)
+        grads = jax.tree_util.tree_map(lambda a, b: np.asarray(a) - np.asarray(b), params, state.params)
+        return float(metrics["loss"]), grads, text
+
+    ref_loss, ref_grads, _ = step_on(None)
+    assert tp_streams() == {"1": 1}  # no step mesh: one stream, one trace
+    loss, grads, text = step_on(make_mesh(dp=dp, tp=tp))
+    assert tp_streams() == ({"1": 1, "2": 1} if streams == 2 else {"1": 2})
+    _without_row_streams(monkeypatch)
+    # where the jaxprs are one the one-stream step is this step: traced for its text, run only where they differ
+    one_loss, one_grads, one_text = step_on(make_mesh(dp=dp, tp=tp), run=streams == 2)
+    assert (text == one_text) == (streams == 1)
+
+    assert float(np.abs(ref_grads["blocks"]["attn_out"]["w"]).max()) > 1e-4  # not vacuous
+    for want_loss, want in ((one_loss, one_grads), (ref_loss, ref_grads))[streams == 1:]:
+        np.testing.assert_allclose(loss, want_loss, rtol=2e-4)
+        jax.tree_util.tree_map(
+            lambda got, ref: np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-5), grads, want)
+
+
+def test_row_streams_keep_each_replica_s_rows_on_its_replica(eight_devices):
+    """The streams are halves of each ``dp`` replica's rows, not of the global
+    batch: ``split_rows`` of rows 0..15 over dp=2 gives rows 0-3 + 8-11 and
+    4-7 + 12-15, each stream laid out over ``dp`` with no row leaving its
+    replica, and ``merge_rows`` puts them back in order."""
+    from distributedvolunteercomputing_tpu.ops import attention
+
+    mesh = make_mesh(dp=2, tp=2)
+    x = jnp.arange(16 * 3, dtype=jnp.float32).reshape(16, 3)
+
+    @jax.jit
+    def there_and_back(x):
+        with attention.step_mesh(mesh):
+            parts = attention.split_rows(x, 2)
+            return parts, attention.merge_rows(parts)
+
+    x = jax.device_put(x, jax.sharding.NamedSharding(mesh, P("dp")))
+    (a, b), back = there_and_back(x)
+    np.testing.assert_array_equal(np.asarray(a)[:, 0] // 3, [0, 1, 2, 3, 8, 9, 10, 11])
+    np.testing.assert_array_equal(np.asarray(b)[:, 0] // 3, [4, 5, 6, 7, 12, 13, 14, 15])
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+    for part in (a, b, back):
+        assert part.sharding.spec[0] == "dp", part.sharding
+    text = there_and_back.lower(x).compile().as_text()
+    assert "all-to-all" not in text and "collective-permute" not in text and "all-gather" not in text
+
+
+def test_sharded_multi_step_runs_the_two_stream_body(eight_devices, tp_streams):
+    """``make_sharded_multi_step`` scans the SAME traced body as the single
+    step: over dp=2,tp=2 its layer scan runs two row streams too, and N
+    scanned steps give the N per-step calls' losses and parameters."""
+    from distributedvolunteercomputing_tpu.parallel.train_step import make_sharded_multi_step
+
+    bundle = get_model("gpt2_small", **dict(TINY_GPT2, remat=True))
+    tx = make_optimizer("adam", lr=1e-3)
+    batches = [bundle.make_batch(jax.random.PRNGKey(10 + i), 8) for i in range(2)]
+    mesh = make_mesh(dp=2, tp=2)
+    ref_state, _ = shard_train_state(
+        TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(2)), mesh, tx)
+    step = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False)
+    losses_ref = []
+    for b in batches:
+        ref_state, m = step(ref_state, put_batch(b, mesh))
+        losses_ref.append(float(m["loss"]))
+    assert tp_streams() == {"2": 1}
+
+    state, _ = shard_train_state(
+        TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(2)), mesh, tx)
+    multi = make_sharded_multi_step(bundle.loss_fn, tx, mesh)
+    state, losses = multi(state, jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *batches))
+    assert tp_streams() == {"2": 2}
+    np.testing.assert_allclose(np.asarray(losses), np.asarray(losses_ref), rtol=2e-4)
+    jax.tree_util.tree_map(
+        lambda got, ref: np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-3, atol=1e-5),
+        state.params, ref_state.params)
 
 
 def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):

@@ -12,6 +12,11 @@ takes the kernel, not that its results are right (chip_smoke.py does that).
 
 The program's backend checks see the CPU here, so each test steers
 compiled-vs-interpret itself (``interpret=False``, ``pallas="compiled"``).
+
+The whole steps of the six share-model cells (laguna, smallthinker, lfm2,
+glm47-flash, nemotron, kimi-linear) are compiled in a file each,
+``test_tpu_compile_<family>.py``, on this file's helpers and fixtures: each
+takes one and a half to two minutes and shares nothing with another test.
 """
 
 import os
@@ -271,103 +276,6 @@ def _share_chunks_hold_seven_grouped_matmuls(names, text: str, layers: int, rows
     assert not re.search(rf"pad_add_fusion[\w.]* = bf16\[{rows},{d}\]", text)
 
 
-def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip, monkeypatch):
-    """laguna-solo-8k's step (five layers of Laguna-XS.2 at its published
-    widths, sixteen of 256 experts held, an eighth of the vocabulary,
-    4 x 8,192 tokens): the full-causal kernel at 48 query heads over 8
-    key/value heads (layers 0 and 4) and the windowed one at 64 (layers 1-3),
-    each forward and backward (the recomputed forward holds no kernel), under
-    the names a device trace tells them by; the expert layers' grouped matmuls over the bounded
-    chunk of rows, never the S x k = 262,144, seven a layer. That it compiles
-    says the step fits the chip beside its state; its temporaries are what
-    they were before PR 38 (8.1079e9 then, 8.1090e9 after it: the float32 carry
-    of a run over a tile's edge, ``[rows / 128, d]`` a call; 8.1105e9 since PR 46)."""
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch
-
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    compiled = _lowered_step(
-        v5e, "laguna_xs2", 1, 1, 4, n_layers=5, experts_held=16, vocab=12544).compile()
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "laguna-solo-8k")
-    calls = _kernel_calls(text)
-    names = _kernel_names(calls)
-    full = [n for n in names if n.startswith(("dvc_flash_fwd", "dvc_flash_bwd"))]
-    win = [n for n in names if n.startswith("dvc_flash_win_")]
-    assert len(full) == 4 and sum(n.startswith("dvc_flash_bwd") for n in full) == 2, names
-    assert len(win) == 6 and sum(n.startswith("dvc_flash_win_bwd") for n in win) == 3, names
-    assert all("bf16[4,48,8192,128]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
-    assert all("bf16[4,64,8192,128]" in ln for ln in calls if "dvc_flash_win_" in ln)
-    assert all("bf16[4,8,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
-    rows = moe_dispatch.share_rows_bound(4 * 8192, 8, 16, 256)
-    assert rows == 49152  # three times the even share of 16,384: one chunk a layer on the chip
-    assert f"[{rows},2048]" in text and "[262144,2048]" not in text
-    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=4, rows=rows, d=2048, f=512)
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert total < 15.75e9, total
-    # the parent of PR 36: 8.1125e9; of PR 38: 8.1079e9; of PR 46: 8.1090e9, and 8.1105e9 since (three select passes
-    # a layer fewer and the same buffers alive: the heap packs 1.5 MB worse)
-    assert mem.temp_size_in_bytes <= 8.112e9, mem.temp_size_in_bytes
-
-
-def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_on_the_chip, monkeypatch):
-    """smallthinker-solo-16k's step (one period of SmallThinker-21BA3B at its
-    published widths, eight of 64 experts held, an eighth of the vocabulary,
-    2 x 16,384 tokens): at T=16,384 the whole-head-resident kernels still fit
-    their VMEM budget (1,024 x 1,024 blocks with or without the 4,096 window),
-    so both layer kinds take them, 28 query heads over 4 key/value heads, and
-    no layer falls to the XLA core's [28, 16384, 16384] scores. The model is
-    scanned by period with an inner scan over the three sliding layers: ONE
-    windowed and ONE full kernel, each forward and backward, whatever the
-    depth. The share's grouped matmuls see the bounded chunk of 104,448 rows
-    (the model's own slack, 4.25 times the even share: models/smallthinker.py),
-    never the S x k = 196,608, seven a traced layer; arguments and temporaries
-    stay under 15.0e9. The temporaries: 9.5213e9 before PR 36, 9.3057e9 with
-    it, 9.6039e9 since PR 38, whose step needs LESS at once (XLA's live-range
-    peak 10.598e9 against 10.781e9 with the arguments; two ``[rows, d]``
-    buffers in a run's sum where the shifted adds held three) and whose heap
-    packs worse: the scheduler now runs the down stack's ``tgmm`` after the
-    run's product, the heap simulator lays 0.24e9 more out, and a tile of 256
-    rows compiles to the same (PERF.md, Findings of PR 38)."""
-    from distributedvolunteercomputing_tpu.models import smallthinker
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, pallas_attention
-
-    t, d = 16384, 128
-    for window in (None, 4096):
-        assert pallas_attention.choose_blocks(t, t, d, jnp.bfloat16, window) == (1024, 1024)
-    used = pallas_attention.vmem_bytes(t, t, d, jnp.bfloat16, 1024, 1024)
-    assert 0.9 * pallas_attention.VMEM_BUDGET_BYTES < used <= pallas_attention.VMEM_BUDGET_BYTES
-    assert pallas_attention.choose_blocks(2 * t, 2 * t, d, jnp.bfloat16) is None  # the next doubling does not fit
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, window, kv_heads)))
-    try:
-        compiled = _lowered_step(
-            v5e, "smallthinker_21b_a3b", 1, 1, 2, n_layers=4, experts_held=8, vocab=18992).compile()
-    finally:
-        attention.set_core_observer(None)
-    assert sorted(set(seen), key=str) == [("flash", t, 4096, 4), ("flash", t, None, 4)], seen
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "smallthinker-solo-16k")
-    calls = _kernel_calls(text)
-    flash = sorted(n.split(".")[0] for n in _kernel_names(calls) if n.startswith("dvc_flash"))
-    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_win_bwd", "dvc_flash_win_fwd"], flash
-    assert all("bf16[2,28,16384,128]" in ln and "bf16[2,4,16384,128]" in ln for ln in calls if "dvc_flash_" in ln)
-    assert moe_dispatch.share_rows_bound(2 * t, 6, 8, 64) == 73728  # the dispatch's default, three even shares
-    rows = moe_dispatch.share_rows_bound(2 * t, 6, 8, 64, smallthinker.SHARE_ROWS_SLACK)
-    assert rows == 104448  # 3.19 S: three held experts that each take every token fit one chunk
-    assert f"[{rows},2560]" in text and "[196608,2560]" not in text and "[73728,2560]" not in text
-    # one trace a layer kind: the scan's body holds each kind's loops once
-    _share_chunks_hold_seven_grouped_matmuls(_kernel_names(calls), text, layers=2, rows=rows, d=2560, f=768)
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (
-        mem.argument_size_in_bytes, mem.temp_size_in_bytes)
-    assert mem.temp_size_in_bytes <= 9.61e9, mem.temp_size_in_bytes
-
-
 @pytest.mark.parametrize("model,batch,layers,shape", [
     ("gpt2_medium", 16, 2, "bf16[16,16,1024,64]"), ("olmoe_1b_7b", 4, 1, "bf16[4,16,4096,128]")])
 def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, model, batch, layers, shape):
@@ -388,17 +296,19 @@ def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, m
 
 def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
     """large-solo-4chip's step (dp=2, tp=2, batch 32, 20 heads): each chip's
-    kernel sees its own 16 rows and 10 heads, forward and backward; the
-    recomputed forward holds no kernel (the kept names pass through the
-    per-shard ``shard_map``); and nothing gathered feeds it."""
+    kernel sees its own 10 heads of ONE row stream, 8 of the replica's 16 rows
+    (``common.scan_blocks`` runs the rows as two streams over ``tp``), forward
+    and backward a stream; the recomputed forward holds no kernel (the kept
+    names pass through the per-shard ``shard_map``); and nothing gathered
+    feeds it."""
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
     _step_holds_the_groups_its_cell_lists(text, "large-solo-4chip")
     calls = _kernel_calls(text)
-    assert len(calls) == 2
-    assert all("bf16[16,10,1024,64]" in ln for ln in calls)
-    assert not any("[32," in ln.split("custom-call(")[1].split(")")[0] for ln in calls)
+    assert sorted(n.split(".")[0] for n in _kernel_names(calls)) == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 2
+    assert all("bf16[8,10,1024,64]" in ln for ln in calls)
+    assert not any(re.search(r"\[(16|32),", ln.split("custom-call(")[1].split(")")[0]) for ln in calls)
     gathered = set(re.findall(r"(%all-gather[\w.\-]*) =", text))
     for ln in calls:
         operands = set(re.findall(r"%[\w.\-]+", ln.split("custom-call(")[1]))
@@ -414,38 +324,151 @@ def _collectives(text: str):
             if (m := re.search(r"= (.+?) " + kinds, ln))]
 
 
+STREAM = r"bf16\[8,1024,1280\]"  # one row stream's activations on a chip: 8 of the replica's 16 rows
+
+
+def _computations(text: str):
+    """{name: its instruction lines, in the order the compiler scheduled them}."""
+    import re
+
+    out, name = {}, None
+    for ln in text.splitlines():
+        if m := re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", ln):
+            name = m.group(1)
+            out[name] = []
+        elif name is not None and " = " in ln:
+            out[name].append(ln)
+    return out
+
+
+def _stream_all_reduces(text: str):
+    """The all-reduces of one row stream's activations in a compiled step, as
+    (pass, scope, asynchronous?, the lines scheduled between start and done).
+    A synchronous one is an ``all-reduce`` of the loop body itself; an
+    asynchronous one is this compiler's pair of fusions ``async-collective-start``
+    / ``-done`` (the ``all-reduce`` instructions inside fused computations are
+    the pair's parts, told by their ``chain_id``)."""
+    import re
+
+    def where(ln):
+        m = re.search(r'op_name="jit\(step\)/(.*?)/while/.*/(attention|mlp)/', ln)
+        return ("bwd" if m.group(1).startswith("transpose") else "fwd", m.group(2))
+
+    found = []
+    for lines in _computations(text).values():
+        for i, ln in enumerate(lines):
+            if re.search(r"= " + STREAM + r"\S* all-reduce\(", ln) and "chain_id" not in ln:
+                found.append((*where(ln), False, []))
+            elif m := re.match(r"\s*(%async-collective-start[\w.]*) = \(" + STREAM, ln):
+                done = next(j for j in range(i + 1, len(lines))
+                            if re.search(re.escape(m.group(1).replace("start", "done")) + r" = ", lines[j]))
+                found.append((*where(lines[done]), True, lines[i + 1:done]))
+    return found
+
+
 def test_four_chip_step_moves_no_activation_for_qkv(v5e, as_on_the_chip):
     """large-solo-4chip's step: q, k and v are born on the chip that runs their
     heads (``common.qkv_heads`` divides the projection by head over tp), so no
-    all-to-all and no collective-permute carries them or their cotangents. What
-    crosses a link at an activation's size is Megatron's price alone, FOUR
-    all-reduces a layer: after each row-parallel product in the forward
-    (attn_out, mlp_out) and before each column-parallel one in the backward
-    (mlp_in, qkv). The backward's recomputed forward moves no activation: the
-    layer's checkpoint kept attn_out's reduced result (``common.remat_layer``,
-    ``attention.keep_tp_reduced``). The kernel still sees its own 10 heads,
-    forward and backward."""
+    all-to-all and no collective-permute carries them or their cotangents
+    (the two row streams are each laid out over dp: no activation crosses dp
+    either). What crosses a link at an activation's size is Megatron's price
+    alone, four all-reduces a layer and STREAM (``common.scan_blocks`` runs a
+    replica's 16 rows as two streams of 8): after each row-parallel product in
+    the forward (attn_out, mlp_out) and before each column-parallel one in the
+    backward (mlp_in, qkv), eight of ``bf16[8,1024,1280]`` and none of the
+    whole ``[16,1024,1280]``. The backward's recomputed forward moves no
+    activation: the layer's ONE checkpoint around both streams kept attn_out's
+    reduced result of each (``common.remat_layer``, ``attention.keep_tp_reduced``).
+    The kernel still sees its own 10 heads, forward and backward a stream."""
+    import collections
     import re
 
     text = _step_text(v5e, "gpt2_large", 2, 2, 32)
     found = _collectives(text)
     kinds = {kind for _, kind, _ in found}
     assert "all-to-all" not in kinds and "collective-permute" not in kinds, kinds
-    activation_sized = [(kind, ln) for result, kind, ln in found if re.search(r"\[16,1024,\d+\]", result)]
-    assert [kind for kind, _ in activation_sized] == ["all-reduce"] * 4, activation_sized
-    # two in the forward scan's body, two in the backward's, by the scopes their products carry
-    where = sorted(re.search(r'op_name="jit\(step\)/(.*?)/while/.*/(attention|mlp)/', ln).groups()
-                   for _, ln in activation_sized)
-    assert where == [("jvp()", "attention"), ("jvp()", "mlp"),
-                     ("transpose(jvp())", "attention"), ("transpose(jvp())", "mlp")], where
+    assert not [ln for result, _, ln in found if re.search(r"\[16,1024,\d+\]", result)]
+    sites = collections.Counter((which, scope) for which, scope, _, _ in _stream_all_reduces(text))
+    # two streams at each of the four sites, by the scopes their products carry
+    assert sites == {("fwd", "attention"): 2, ("fwd", "mlp"): 2, ("bwd", "attention"): 2, ("bwd", "mlp"): 2}, sites
     # the recomputed forward, by the name its operations carry: no all-reduce and nothing of an
     # activation's size; what it still gathers is the qkv leaf's head-aligned view (weight and bias)
     recomputed = [(result, kind) for result, kind, ln in found if "rematted_computation" in ln]
     assert recomputed and {kind for _, kind in recomputed} == {"all-gather"}, recomputed
     assert all(re.match(r"bf16\[(1280,3840|\d+,1,1920)\]", result) for result, _ in recomputed), recomputed
     calls = _kernel_calls(text)
-    assert len(calls) == 2 and all("bf16[16,10,1024,64]" in ln for ln in calls)
+    assert len(calls) == 4 and all("bf16[8,10,1024,64]" in ln for ln in calls)
     assert not [ln for ln in calls if "rematted_computation" in ln]
+
+
+def test_four_chip_step_hides_a_stream_s_all_reduce_behind_the_other_s_products(v5e, as_on_the_chip):
+    """large-solo-4chip's step is compiled with asynchronous collectives
+    (``train_step.step_compiler_options``: its mesh has a ``tp`` axis) and the
+    scanned body holds two independent row streams, so at each of the four
+    sites where a layer sums over ``tp`` one stream's all-reduce is a start /
+    done pair with a product or a kernel call scheduled between them, the
+    other stream's work; never the two streams' results combined into one
+    all-reduce, which would wait for both. What the compiler leaves on the
+    instruction stream is the stream that comes second at a site: nothing of
+    the layer is left to run beside it (three of the eight)."""
+    import re
+
+    text = _step_text(v5e, "gpt2_large", 2, 2, 32)
+    comps = _computations(text)
+
+    def is_work(ln):  # a kernel call, or a fusion that holds a product
+        if "tpu_custom_call" in ln:
+            return True
+        called = re.search(r"calls=(%[\w.\-]+)", ln)
+        return bool(called) and any(" convolution(" in inner for inner in comps.get(called.group(1), []))
+
+    reduces = _stream_all_reduces(text)
+    hidden = [(which, scope) for which, scope, asynchronous, _ in reduces if asynchronous]
+    assert set(hidden) == {("fwd", "attention"), ("fwd", "mlp"), ("bwd", "attention"), ("bwd", "mlp")}, hidden
+    assert len(hidden) >= 5, hidden
+    for which, scope, asynchronous, between in reduces:
+        assert not asynchronous or any(is_work(ln) for ln in between), (which, scope, between)
+    # one stream's result each: a combined all-reduce would carry two
+    assert not re.search(r"= \(" + STREAM + r"\S*, " + STREAM + r"\S*\) all-reduce\(", text)
+
+
+@pytest.mark.parametrize("model,dp,tp,batch,asked,overrides", [
+    ("gpt2_medium", 1, 1, 16, [1], {}),         # medium-solo, medium-round: one chip
+    ("gpt2_large", 4, 1, 32, [1], {}),          # a dp-only mesh of the four chips
+    ("smallthinker_21b_a3b", 1, 1, 2, [], dict(n_layers=4, experts_held=8, vocab=18992)),  # a share model: never asks
+], ids=["gpt2_medium-1x1", "gpt2_large-dp4", "smallthinker-1x1"])
+def test_steps_without_tp_are_the_programs_they_were(v5e, as_on_the_chip, monkeypatch, model, dp, tp, batch, asked, overrides):
+    """Where the step's mesh has no ``tp`` to divide a layer the rows are not
+    split and no compiler option is passed: the step lowers to the text it
+    lowers to with the split taken out of ``scan_blocks`` altogether, so the
+    one-chip cells' programs and cache keys are what they were. A model whose
+    layers couple rows (a share of experts) never asks for streams."""
+    from distributedvolunteercomputing_tpu.models import common
+    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+    from distributedvolunteercomputing_tpu.parallel.mesh import AXES
+    from distributedvolunteercomputing_tpu.parallel.train_step import step_compiler_options
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    overrides = dict(overrides)
+    n_layers = overrides.pop("n_layers", 2)
+    mesh = Mesh(np.asarray(v5e[: dp * tp]).reshape(dp, 1, 1, 1, tp), AXES)
+    assert step_compiler_options(mesh) == {}
+    assert step_compiler_options(Mesh(np.asarray(v5e).reshape(2, 1, 1, 1, 2), AXES))  # and some with one
+    seen = []
+    attention.set_streams_observer(seen.append)
+    # name stacks only: with Python frames a kernel's serialised module follows every line on the way to it
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text()
+        assert seen == asked
+        scan_blocks = common.scan_blocks
+        monkeypatch.setattr(common, "scan_blocks", lambda *a, rows_independent=False, **kw: scan_blocks(*a, **kw))
+        assert _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text() == text
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+        attention.set_streams_observer(None)
 
 
 def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
@@ -453,8 +476,10 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
     checkpoint names the kernel's two results and nothing else (on one chip
     attn_out's result costs a product to make again, not an all-reduce), and
     ``swarm.remat_kept`` reads the kernel's bytes. The four-chip step names
-    attn_out's reduced result once a traced block and counts a chip's
-    ``bf16[16,1024,1280]`` of it a layer beside the kernel's."""
+    attn_out's reduced result in its traced block and counts a chip's
+    ``bf16[16,1024,1280]`` of it a layer beside the kernel's: the bytes it
+    counted as one stream, now two halves of the same stacks (the block is
+    traced once, at one stream's 8 rows, and runs twice)."""
     import re
 
     from distributedvolunteercomputing_tpu.ops import attention
@@ -466,7 +491,7 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
             jaxpr = str(_traced_step(v5e, *model_mesh_batch).jaxpr)
         finally:
             attention.set_kept_observer(None)
-        return sorted(re.findall(r"name\[name=(\w+)\]", jaxpr)), seen
+        return sorted(set(re.findall(r"name\[name=(\w+)\]", jaxpr))), seen  # the names, however often printed
 
     def kernel(b, h, t):  # the output's rows at 128 lanes of bf16 and a float32 log-sum-exp a row
         return b * h * t * (128 * 2 + 4)
@@ -630,119 +655,6 @@ def test_short_conv_fwd_bwd_compiles_without_a_copy(v5e):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
-    """lfm2-solo-8k's step (published layers 0 and 2-5 of LFM2-24B-A2B at its
-    published widths, eight of 64 experts held, an eighth of the vocabulary,
-    4 x 8,192 tokens): three traced layer shapes, the dense conv layer, the
-    attention expert layer and ONE scanned conv expert layer for the three.
-    The attention layer takes the flash kernel at head dim 64 with four query
-    heads a key/value head, forward and backward only (its recomputed forward
-    holds none: ``remat_layer`` kept the output and row statistics); each conv
-    layer shape runs the convolution's kernel forward, again in the recomputed
-    forward (it keeps nothing of its mixer) and backward: two shapes, six
-    calls; the share's grouped matmuls see the levelled router's chunk of 20,480
-    rows (the even share of 16,384 and a quarter: the model passes
-    ``SHARE_ROWS_SLACK_LEVELLED``), never the dispatch's default of 49,152 nor
-    the S x k = 131,072, seven a traced expert layer. That it compiles says it
-    fits the chip; its temporaries are 6.186e9 (7.359e9 at 49,152 rows, PR 39)."""
-    from distributedvolunteercomputing_tpu.models import lfm2
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
-
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
-    try:
-        compiled = _lowered_step(
-            v5e, "lfm2_24b_a2b", 1, 1, 4, n_layers=None, layer_types="conv,full_attention,conv,conv,conv",
-            dense_layers=1, experts_held=8, vocab=8192).compile()
-    finally:
-        attention.set_core_observer(None)
-    assert set(seen) == {("flash", 8192, 64, None, 8)}, seen
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "lfm2-solo-8k")
-    calls = _kernel_calls(text)
-    names = _kernel_names(calls)
-    flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
-    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd"], flash
-    assert all("bf16[4,32,8192,64]" in ln and "bf16[4,8,8192,64]" in ln for ln in calls if "dvc_flash_" in ln)
-    conv = sorted(n.split(".")[0] for n in names if n.startswith("dvc_short_conv"))
-    assert conv == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4, conv
-    assert all("bf16[4,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
-    assert moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64) == 49152  # the dispatch's default, three even shares
-    rows = moe_dispatch.share_rows_bound(4 * 8192, 4, 8, 64, lfm2.SHARE_ROWS_SLACK)
-    assert rows == 20480  # the even share of 16,384 and a quarter: forty megablox row tiles
-    assert f"[{rows},2048]" in text and "[131072,2048]" not in text and "[49152,2048]" not in text
-    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=2, rows=rows, d=2048, f=1536)
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert total < 13.5e9, total
-    assert mem.temp_size_in_bytes <= 6.22e9, mem.temp_size_in_bytes  # 6.186e9; at 49,152 rows (PR 39) 7.359e9
-
-
-def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_of_256(v5e, as_on_the_chip, monkeypatch):
-    """glm47-flash-solo-8k's step (published layers 0-4 of GLM-4.7-Flash at its
-    published widths, eight of 64 experts held, an eighth of the vocabulary,
-    2 x 8,192 tokens). A head of 256 at T=8,192 is the edge of what the
-    whole-head-resident kernels hold: 1,024 x 1,024 blocks are 66.06e6 of the
-    67.1e6-byte budget (the same resident bytes as D=128 at T=16,384), and the
-    next doubling of either does not fit. The model builds q, k and v at
-    ``[2, 20, 8192, 256]`` (the one rotary key broadcast to the 20 heads) and
-    both traced layer shapes, the dense layer and ONE scanned expert layer for
-    the four, take the kernel forward and backward only (``remat_layer`` kept
-    the output and row statistics). The share's grouped matmuls see the
-    dispatch's default chunk of 24,576 rows (three even shares of 8,192: the
-    model's own reading refuted the levelled quarter), seven a traced expert
-    layer. Arguments and temporaries are
-    16.18e9 (7.096 + 9.080): OVER the 15.0e9 line of the other share cells'
-    tests, and what the chip still loads and runs (PERF.md, Findings of PR 42);
-    the line here says that nothing more fits."""
-    from distributedvolunteercomputing_tpu.models import glm4_moe_lite
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, pallas_attention
-
-    t, d = 8192, 256
-    assert 256 in attention._AUTO_FLASH_HEAD_DIMS
-    assert pallas_attention.choose_blocks(t, t, d, jnp.bfloat16) == (1024, 1024)
-    used = pallas_attention.vmem_bytes(t, t, d, jnp.bfloat16, 1024, 1024)
-    assert used == 66_060_288 and 0.98 * pallas_attention.VMEM_BUDGET_BYTES < used <= pallas_attention.VMEM_BUDGET_BYTES
-    # D=128 at T=16,384 (smallthinker-solo-16k) holds the same resident bytes and smaller streamed blocks: 61.0 MiB
-    assert 63.9e6 < pallas_attention.vmem_bytes(2 * t, 2 * t, 128, jnp.bfloat16, 1024, 1024) < used
-    assert pallas_attention.choose_blocks(2 * t, 2 * t, d, jnp.bfloat16) is None   # a head of 256 beyond 8,192: none
-    assert pallas_attention.choose_blocks(t, t, 2 * d, jnp.bfloat16) is None
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
-        compiled = _lowered_step(v5e, "glm4_7_flash", 1, 1, 2, n_layers=5, experts_held=8, vocab=19360).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", t, d, None, 20)] * 2, seen          # one traced dense layer, one traced scan body
-    # the output at 20 x 256 a token and the f32 row statistics: 168.8 MB a layer, 845.4 MB a step
-    assert kept == [(1, 169_082_880), (4, 4 * 169_082_880)], kept
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "glm47-flash-solo-8k")
-    calls = _kernel_calls(text)
-    names = _kernel_names(calls)
-    flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
-    assert flash == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 2, flash
-    assert all("bf16[2,20,8192,256]" in ln for ln in calls if "dvc_flash_" in ln)
-    assert moe_dispatch.share_rows_bound(2 * t, 4, 8, 64, moe_dispatch.SHARE_ROWS_SLACK_LEVELLED) == 10240
-    rows = moe_dispatch.share_rows_bound(2 * t, 4, 8, 64, glm4_moe_lite.SHARE_ROWS_SLACK)
-    assert rows == 24576  # three even shares of 8,192: forty-eight megablox row tiles
-    assert f"[{rows},2048]" in text and "[65536,2048]" not in text   # never the S x k assignments
-    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=1, rows=rows, d=2048, f=1536)
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes == pytest.approx(7.0957e9, rel=1e-3)  # float32 parameters and two Adam moments
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert total < 16.25e9, total                        # 16.176e9: the chip takes about 16.9e9 and ran it
-    assert mem.temp_size_in_bytes <= 9.12e9, mem.temp_size_in_bytes   # 9.080e9 (9.219e9 at the levelled chunk)
-
-
 def test_ssd_and_causal_conv_kernels_compile_at_the_published_mixer(v5e):
     """nemotron3-nano-solo-8k's state-space kernels by the chip's own compiler,
     under the names a trace shows: the chunked scan forward and backward at 64
@@ -791,66 +703,6 @@ def test_ssd_and_causal_conv_kernels_compile_at_the_published_mixer(v5e):
     names = _kernel_names(_kernel_calls(compiled.as_text()))
     assert len(names) == 2 and sum("dvc_short_conv_fwd" in n for n in names) == 1
     assert sum("dvc_short_conv_bwd" in n for n in names) == 1
-
-
-def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_chip, monkeypatch):
-    """nemotron3-nano-solo-8k's step (published blocks 0-6, MEMEM*E, of
-    Nemotron-3-Nano-30B-A3B at its published widths, eight of 128 experts held,
-    an eighth of the vocabulary, 2 x 8,192 tokens): two traced unit shapes, a
-    scan over the two ``ME`` and one ``M*E``, every block rematerialised by
-    itself. Each traced state-space block runs the scan's kernel forward, again
-    in its recomputed forward (it keeps nothing) and backward, and the
-    convolution's likewise: two traces, six calls each. The attention block
-    takes the flash kernel at a head of 128 with SIXTEEN query heads a key/value
-    head, forward and backward only. The experts' width of 1,856 is no whole
-    number of megablox's 128-column tiles (14.5), so the share's grouped
-    products run padded: ``[7680, 3072] x [8, 3072, 2048]`` in tiles of 512 x
-    1,024 x 1,024, seven a traced expert block, over the levelled router's
-    chunk of 7,680 rows (the even share of 6,144 and a quarter), never the
-    S x k = 98,304. That it compiles says it fits the chip."""
-    from distributedvolunteercomputing_tpu.models import nemotron_h
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, ssd
-
-    monkeypatch.setattr(ssd, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    assert moe_dispatch._megablox_tiling(7680, 2688, 1856) is None and moe_dispatch._megablox_tiling(7680, 1856, 2688) is None
-    assert (moe_dispatch._padded(2688), moe_dispatch._padded(1856)) == (3072, 2048)
-    assert moe_dispatch._megablox_tiling(7680, 3072, 2048) == (512, 1024, 1024)
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
-        compiled = _lowered_step(v5e, "nemotron3_nano_30b_a3b", 1, 1, 2, n_layers=7, experts_held=8, vocab=16384).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 128, None, 2)], seen
-    # what the blocks keep: the attention block's output and row statistics; a state-space block nothing
-    assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "nemotron3-nano-solo-8k")
-    calls = _kernel_calls(text)
-    names = [n.split(".")[0] for n in _kernel_names(calls)]
-    assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
-    assert all("bf16[2,32,8192,128]" in ln and "bf16[2,2,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)
-    assert sorted(n for n in names if n.startswith("dvc_ssd")) == ["dvc_ssd_bwd"] * 2 + ["dvc_ssd_fwd"] * 4
-    assert all("bf16[2,8192,6144]" in ln and "bf16[2,8192,4096]" in ln for ln in calls if "dvc_ssd_" in ln)
-    assert "[2,64,8192,64]" not in text and "[2,8192,64,64]" not in text   # no stream by head: nothing to transpose
-    assert sorted(n for n in names if n.startswith("dvc_short_conv")) == ["dvc_short_conv_bwd"] * 2 + ["dvc_short_conv_fwd"] * 4
-    assert all("bf16[2,8192,6144]" in ln for ln in calls if "dvc_short_conv" in ln)
-    rows = moe_dispatch.share_rows_bound(2 * 8192, 6, 8, 128, nemotron_h.SHARE_ROWS_SLACK)
-    assert rows == 7680  # the even share of 6,144 and a quarter: fifteen row tiles
-    assert f"[{rows},2688]" in text and f"[{rows},1856]" in text and "[98304,2688]" not in text
-    from benchmark import moe_trace
-
-    gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
-    assert len(gmm) == 7 * 2 and "ragged-dot" not in text, gmm      # two traced expert blocks, seven products each
-    assert f"bf16[{rows},3072]" in text and "bf16[8,3072,2048]" in text and "bf16[8,2048,3072]" in text
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes == pytest.approx(6.3373e9, rel=1e-3)  # float32 parameters and two Adam moments
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.3e9     # at 4 x 8,192: 17.21e9, over the chip
 
 
 def test_the_delta_rule_scan_and_a_value_head_of_128_under_keys_of_192_compile_at_the_published_mixers(v5e):
@@ -909,65 +761,3 @@ def test_the_delta_rule_scan_and_a_value_head_of_128_under_keys_of_192_compile_a
     fwd, bwd = (next(ln for ln in calls if name in ln) for name in ("dvc_flash_fwd", "dvc_flash_bwd"))
     assert len(calls) == 2 and fwd.split(" custom-call(")[0].count("bf16[2,32,8192,128]") == 1    # o at the value width
     assert bwd.split(" custom-call(")[0].count("bf16[2,32,8192,192]") == 2 and "bf16[2,32,8192,128]" in bwd.split(" custom-call(")[0]
-
-
-def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
-    """kimi-linear-solo-8k's step (published layers 1-5 of
-    Kimi-Linear-48B-A3B-Instruct at its published widths, eight of 256 experts
-    held, an eighth of the vocabulary, 2 x 8,192 tokens): four traced layer
-    shapes (layer 1, layers 2-3 as one scanned body, layer 4, layer 5), every
-    layer rematerialised. Each traced KDA layer runs the scan's loop forward,
-    again in its recomputed forward (it keeps nothing) and backward, and each of
-    its three convolutions' kernels likewise; the latent layer takes the flash kernel at
-    keys of 192 over values of 128, forward and backward only. The share's
-    grouped products see the dispatch's default chunk of 12,288 rows (three even
-    shares of 4,096), seven a traced expert layer. That it compiles says it fits
-    the chip."""
-    from benchmark import kda_trace
-    from distributedvolunteercomputing_tpu.models import kimi_linear
-    from distributedvolunteercomputing_tpu.ops import attention, kda, moe_dispatch
-
-    monkeypatch.setattr(kda, "tpu_backend", lambda: True)     # bfloat16 products as the chip takes them
-    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
-    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
-        compiled = _lowered_step(v5e, "kimi_linear_48b_a3b", 1, 1, 2, n_layers=5, experts_held=8, vocab=20480).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 192, None, 32)], seen
-    # what the layers keep: the latent layer's output at 32 x 128 a token and its row statistics; a KDA layer nothing
-    assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
-    text = compiled.as_text()
-    _step_holds_the_groups_its_cell_lists(text, "kimi-linear-solo-8k")
-    calls = _kernel_calls(text)
-    names = [n.split(".")[0] for n in _kernel_names(calls)]
-    assert sorted(n for n in names if n.startswith("dvc_flash")) == ["dvc_flash_bwd", "dvc_flash_fwd"]
-    # the scan's loops, told as benchmark/kda_trace.py tells them in a trace: three traced KDA layers, each forward twice and backward
-    scans = [shapes for shapes in (kda_trace.carried(ln.strip()) for ln in text.splitlines() if " while(" in ln)
-             if (2, 32, 128, 128) in shapes]
-    # nine loops carry the heads' states. Since PR 55 each takes its chunks out of the whole streams it carries
-    # ([2, 8192, 4096]) and holds by chunk the states alone, so the reader's count (more than FORWARD_HOLDS_AT_MOST
-    # arrays by chunk: a backward loop) calls none of the nine backward: kda.roofline's least time is nine forward
-    # loops' where three are backward ones (PERF.md section 7). By the whole streams they carry the three are plain:
-    # q, k, v, g and o forward; q, k, v, g, dO and the four cotangents backward
-    assert [sum(s[:2] == (128, 2) for s in shapes) for shapes in scans] == [1] * 9 and kda_trace.FORWARD_HOLDS_AT_MOST == 8
-    assert sorted(sum(s == (2, 8192, 4096) for s in shapes) for shapes in scans) == [5] * 6 + [9] * 3
-    assert sorted(n for n in names if n.startswith("dvc_short_conv")) == ["dvc_short_conv_bwd"] * 9 + ["dvc_short_conv_fwd"] * 18
-    assert all("bf16[2,8192,4096]" in ln for ln in calls if "dvc_short_conv" in ln)
-    rows = moe_dispatch.share_rows_bound(2 * 8192, 8, 8, 256, kimi_linear.SHARE_ROWS_SLACK)
-    assert rows == 12288 and f"[{rows},2304]" in text and "[131072,2304]" not in text   # never the S x k assignments
-    from benchmark import moe_trace
-
-    gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
-    assert len(gmm) == 7 * 3 and "ragged-dot" not in text, gmm      # three traced expert layers, seven products each
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes == pytest.approx(7.2296e9, rel=1e-3)  # float32 parameters and two Adam moments
-    # 15.37e9 by this analysis (8.14e9 of temporaries; 17.16e9 and 9.93e9 until PR 55 took the streams' by-chunk and
-    # by-head copies out): under the 17.16e9 that the chip's own compile loaded and ran beside the reference check
-    # (memory_peak_bytes 15.04e9 of 16.9e9 then, 15.02e9 now: my chip runs, PR 52 calls 9-10, PR 55 call 1)
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9

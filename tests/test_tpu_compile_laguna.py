@@ -1,0 +1,60 @@
+"""laguna-solo-8k's whole train step, compiled for the described v5e of
+``test_tpu_compile.py``.
+
+A file of its own, as each of the six cells' steps that take one and a half to
+two minutes to compile and share nothing with another test: under
+``--dist loadfile`` the workers compile them side by side (3.5 to 6 GB of
+host memory a compile) instead of one worker all six, and, being the files
+with the fewest tests, after the files of many short tests.
+"""
+
+from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
+    as_on_the_chip,
+    _kernel_calls,
+    _kernel_names,
+    _lowered_step,
+    no_persistent_cache,
+    _share_chunks_hold_seven_grouped_matmuls,
+    _step_holds_the_groups_its_cell_lists,
+    v5e,
+)
+
+
+def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip, monkeypatch):
+    """laguna-solo-8k's step (five layers of Laguna-XS.2 at its published
+    widths, sixteen of 256 experts held, an eighth of the vocabulary,
+    4 x 8,192 tokens): the full-causal kernel at 48 query heads over 8
+    key/value heads (layers 0 and 4) and the windowed one at 64 (layers 1-3),
+    each forward and backward (the recomputed forward holds no kernel), under
+    the names a device trace tells them by; the expert layers' grouped matmuls over the bounded
+    chunk of rows, never the S x k = 262,144, seven a layer. That it compiles
+    says the step fits the chip beside its state; its temporaries are what
+    they were before PR 38 (8.1079e9 then, 8.1090e9 after it: the float32 carry
+    of a run over a tile's edge, ``[rows / 128, d]`` a call; 8.1105e9 since PR 46)."""
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    compiled = _lowered_step(
+        v5e, "laguna_xs2", 1, 1, 4, n_layers=5, experts_held=16, vocab=12544).compile()
+    text = compiled.as_text()
+    _step_holds_the_groups_its_cell_lists(text, "laguna-solo-8k")
+    calls = _kernel_calls(text)
+    names = _kernel_names(calls)
+    full = [n for n in names if n.startswith(("dvc_flash_fwd", "dvc_flash_bwd"))]
+    win = [n for n in names if n.startswith("dvc_flash_win_")]
+    assert len(full) == 4 and sum(n.startswith("dvc_flash_bwd") for n in full) == 2, names
+    assert len(win) == 6 and sum(n.startswith("dvc_flash_win_bwd") for n in win) == 3, names
+    assert all("bf16[4,48,8192,128]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
+    assert all("bf16[4,64,8192,128]" in ln for ln in calls if "dvc_flash_win_" in ln)
+    assert all("bf16[4,8,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
+    rows = moe_dispatch.share_rows_bound(4 * 8192, 8, 16, 256)
+    assert rows == 49152  # three times the even share of 16,384: one chunk a layer on the chip
+    assert f"[{rows},2048]" in text and "[262144,2048]" not in text
+    _share_chunks_hold_seven_grouped_matmuls(names, text, layers=4, rows=rows, d=2048, f=512)
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert total < 15.75e9, total
+    # the parent of PR 36: 8.1125e9; of PR 38: 8.1079e9; of PR 46: 8.1090e9, and 8.1105e9 since (three select passes
+    # a layer fewer and the same buffers alive: the heap packs 1.5 MB worse)
+    assert mem.temp_size_in_bytes <= 8.112e9, mem.temp_size_in_bytes
