@@ -1,10 +1,25 @@
-"""End-to-end swarm tests: real processes, real entrypoints, real churn.
+"""The swarm's base and its end-to-end tests.
 
-This is the reference's own test shape (SURVEY.md §4): N volunteer PROCESSES
-on localhost, a coordinator process, kill -9 mid-run — the whole L6-L2 stack
-through the actual CLI entrypoints.
+First, end to end: real processes, real entrypoints, real churn. This is the
+reference's own test shape (SURVEY.md §4): N volunteer PROCESSES on localhost,
+a coordinator process, kill -9 mid-run — the whole L6-L2 stack through the
+actual CLI entrypoints.
+
+Then the layers under the entrypoints (transport / DHT / membership /
+coordinator), in-process over real localhost sockets: the
+"multi-node-without-a-cluster" strategy (SURVEY.md §4): every node is a real
+asyncio TCP server on 127.0.0.1, so the wire protocol, timeouts, and churn
+behavior are exercised for real; only process isolation is elided (the
+end-to-end class above has it). They were ``tests/test_swarm_base.py`` until
+PR 58 and share this file for the tier-1 run's sake: ``--dist loadfile`` hands
+files to its six workers MOST TESTS FIRST, the end-to-end class holds a worker
+for eight to ten minutes of protocol waits on 5 s of its own CPU, and as a
+file of 19 tests it started 400 s into the run and ended it 170 s after every
+other worker had finished (ROADMAP D3). With these 18 quick tests the file is
+handed out among the first dozen.
 """
 
+import asyncio
 import json
 import os
 import re
@@ -13,7 +28,13 @@ import subprocess
 import sys
 import time
 
+import numpy as np
 import pytest
+
+from distributedvolunteercomputing_tpu.swarm.coordinator import Coordinator
+from distributedvolunteercomputing_tpu.swarm.dht import DHTNode
+from distributedvolunteercomputing_tpu.swarm.membership import SwarmMembership
+from distributedvolunteercomputing_tpu.swarm.transport import RPCError, Transport
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -93,6 +114,23 @@ def wait_swarm_alive(coord_addr, n, timeout=180):
             await t.close()
 
     return asyncio.run(poll())
+
+
+def wait_trained(metrics_path, records=3, timeout=90):
+    """Wait until a volunteer started with ``--metrics metrics_path`` has
+    demonstrably TRAINED (its file holds ``records`` lines): a wall-clock
+    sleep lands during the JAX compile on a loaded machine and after the run
+    has ended on a quiet one."""
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        try:
+            with open(metrics_path) as fh:
+                if sum(1 for _ in fh) >= records:
+                    return
+        except OSError:
+            pass
+        time.sleep(0.5)
+    raise AssertionError(f"no {records} metrics records in {metrics_path} after {timeout} s: never started training")
 
 
 def wait_done(proc, timeout=180):
@@ -345,19 +383,9 @@ class TestSwarmE2E:
                 )
                 for i in range(3)
             ]
-            # Kill only once the victim has demonstrably TRAINED (metrics
-            # records exist): a wall-clock sleep can land the kill during
-            # JAX compile, quietly degrading this to a 2-node test.
-            deadline = time.time() + 90
-            while time.time() < deadline:
-                try:
-                    if sum(1 for _ in open(victim_metrics)) >= 3:
-                        break
-                except OSError:
-                    pass
-                time.sleep(1.0)
-            else:
-                raise AssertionError("victim volunteer never started training")
+            # Kill only once the victim has demonstrably TRAINED: a kill
+            # during JAX compile quietly degrades this to a 2-node test.
+            wait_trained(victim_metrics)
             vols[2].send_signal(signal.SIGKILL)
             s0, out0 = wait_done(vols[0])
             s1, out1 = wait_done(vols[1])
@@ -625,16 +653,16 @@ class TestSwarmE2E:
 
     def test_sigterm_preemption_graceful(self, tmp_path):
         """SIGTERM (TPU-VM preemption notice) -> checkpoint + clean exit."""
-        ckpt = str(tmp_path / "ckpt")
+        ckpt, metrics = str(tmp_path / "ckpt"), str(tmp_path / "preempt.jsonl")
         v = start_volunteer_standalone = subprocess.Popen(
             [
                 sys.executable, os.path.join(REPO, "run_volunteer.py"),
                 "--peer-id", "preempt-me", "--steps", "100000", "--batch-size", "16",
-                *TINY_MLP, "--checkpoint-dir", ckpt,
+                *TINY_MLP, "--checkpoint-dir", ckpt, "--metrics", metrics,
             ],
             stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True, env=_env(),
         )
-        time.sleep(15)  # well into training
+        wait_trained(metrics, records=10)  # well into training
         v.send_signal(signal.SIGTERM)
         summary, out = wait_done(v, timeout=60)
         assert v.returncode == 0, out
@@ -683,3 +711,475 @@ def test_async_checkpoint_roundtrip(tmp_path):
         jax.tree_util.tree_leaves(t2.state.params),
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the layers under the entrypoints, in-process over real localhost sockets --------------
+
+
+def run(coro):
+    return asyncio.run(coro)
+
+
+@pytest.mark.transport
+class TestTransport:
+    def test_echo_roundtrip(self):
+        async def main():
+            server = Transport()
+
+            async def echo(args, payload):
+                return {"got": args["x"]}, payload[::-1]
+
+            server.register("echo", echo)
+            addr = await server.start()
+            client = Transport()
+            ret, payload = await client.call(addr, "echo", {"x": 42}, b"abc")
+            await server.close()
+            return ret, payload
+
+        ret, payload = run(main())
+        assert ret == {"got": 42}
+        assert payload == b"cba"
+
+    def test_survives_garbage_frames(self):
+        """Frame-parser fuzz: raw TCP garbage — bad magic, truncated
+        headers, oversize lengths, invalid JSON meta, non-dict JSON meta —
+        must each produce a clean drop (no task crash), and the server must
+        keep serving legitimate RPCs afterwards."""
+        import json as _json
+        import zlib
+
+        from distributedvolunteercomputing_tpu.swarm.transport import (
+            _HEADER, MAGIC, VERSION,
+        )
+
+        def frame(meta_b: bytes, payload: bytes = b"", magic=MAGIC, version=VERSION):
+            crc = zlib.crc32(payload) & 0xFFFFFFFF
+            return (
+                _HEADER.pack(magic, version, 1, len(meta_b), len(payload), crc)
+                + meta_b + payload
+            )
+
+        garbage = [
+            b"\x00" * 64,                                  # not a frame at all
+            frame(b"{}", magic=b"XX"),                     # bad magic
+            frame(b"{}", version=99),                      # bad version
+            frame(b"not json at all"),                     # invalid JSON meta
+            frame(_json.dumps([1, 2, 3]).encode()),        # JSON, not an object
+            frame(_json.dumps("str").encode()),            # JSON scalar meta
+            _HEADER.pack(MAGIC, VERSION, 1, 10, 0, 0),     # truncated: no meta
+            _HEADER.pack(MAGIC, VERSION, 1, 0, 1 << 62, 0),  # absurd payload len
+            frame(b"[" * 100_000 + b"1" + b"]" * 100_000),  # parser stack bomb
+        ]
+
+        async def main():
+            server = Transport()
+
+            async def echo(args, payload):
+                return {"ok": True}, payload
+
+            server.register("echo", echo)
+            addr = await server.start()
+            for g in garbage:
+                reader, writer = await asyncio.open_connection(*addr)
+                writer.write(g)
+                try:
+                    await writer.drain()
+                    # EOF makes a server blocked on readexactly for bytes
+                    # that will never come fail fast (IncompleteReadError)
+                    # instead of stalling this test for the full timeout.
+                    writer.write_eof()
+                    # Server replies with an error frame or just drops us;
+                    # either way the connection ends without wedging.
+                    await asyncio.wait_for(reader.read(1 << 16), timeout=5)
+                except (ConnectionResetError, BrokenPipeError, asyncio.TimeoutError):
+                    pass
+                finally:
+                    writer.close()
+            # The real client still works after every garbage volley.
+            client = Transport()
+            ret, payload = await client.call(addr, "echo", {"x": 1}, b"ok")
+            await server.close()
+            return ret, payload
+
+        ret, payload = run(main())
+        assert ret == {"ok": True}
+        assert payload == b"ok"
+
+    def test_large_binary_payload(self):
+        async def main():
+            server = Transport()
+
+            async def double(args, payload):
+                arr = np.frombuffer(payload, np.float32) * 2
+                return {}, arr.tobytes()
+
+            server.register("double", double)
+            addr = await server.start()
+            client = Transport()
+            data = np.arange(300_000, dtype=np.float32)
+            _, resp = await client.call(addr, "double", payload=data.tobytes())
+            await server.close()
+            return data, np.frombuffer(resp, np.float32)
+
+        data, resp = run(main())
+        np.testing.assert_allclose(resp, data * 2)
+
+    def test_auth_roundtrip_and_rejection(self):
+        """Shared-secret HMAC frame auth: matching secrets work end-to-end;
+        a client with the wrong secret (or none) is rejected — the whole
+        swarm tier crosses this transport, so this one gate is what keeps
+        identity spoofing out of the Byzantine first-write-wins rule."""
+
+        async def main():
+            server = Transport(secret=b"s3kr1t")
+
+            async def echo(args, payload):
+                return {"got": args["x"]}, payload
+
+            server.register("echo", echo)
+            addr = await server.start()
+
+            ok_client = Transport(secret=b"s3kr1t")
+            ret, payload = await ok_client.call(addr, "echo", {"x": 1}, b"hi")
+            assert ret == {"got": 1} and payload == b"hi"
+
+            outcomes = {}
+            for name, client in (
+                ("wrong", Transport(secret=b"wrong")),
+                ("none", Transport()),
+            ):
+                try:
+                    # The server drops unauthenticated frames; from the
+                    # client side that surfaces as an error or a dead
+                    # connection — never a successful call.
+                    await client.call(addr, "echo", {"x": 2}, b"x", timeout=5.0)
+                    outcomes[name] = "accepted"
+                except (
+                    RPCError, OSError, asyncio.IncompleteReadError,
+                    asyncio.TimeoutError, TimeoutError,
+                ):
+                    outcomes[name] = "rejected"
+            await server.close()
+            return outcomes
+
+        assert run(main()) == {"wrong": "rejected", "none": "rejected"}
+
+    def test_auth_client_rejects_unauthenticated_server(self):
+        """Auth is mutual: a secret-holding client refuses responses from a
+        server that can't sign them (e.g. a man-in-the-middle without the
+        secret)."""
+
+        async def main():
+            server = Transport()  # no secret: cannot sign responses
+
+            async def echo(args, payload):
+                return {}, payload
+
+            server.register("echo", echo)
+            addr = await server.start()
+            client = Transport(secret=b"s3kr1t")
+            try:
+                await client.call(addr, "echo", {}, b"x", timeout=5.0)
+                outcome = "accepted"
+            except (RPCError, OSError, asyncio.TimeoutError, TimeoutError):
+                outcome = "rejected"
+            await server.close()
+            return outcome
+
+        assert run(main()) == "rejected"
+
+    def test_auth_timestamp_window(self):
+        """Frames outside the auth window are rejected (bounds replay)."""
+
+        async def main():
+            server = Transport(secret=b"k", auth_window=0.0)  # everything stale
+
+            async def echo(args, payload):
+                return {}, payload
+
+            server.register("echo", echo)
+            addr = await server.start()
+            client = Transport(secret=b"k")
+            try:
+                await client.call(addr, "echo", {}, b"", timeout=5.0)
+                outcome = "accepted"
+            except (RPCError, OSError, asyncio.TimeoutError, TimeoutError):
+                outcome = "rejected"
+            await server.close()
+            return outcome
+
+        assert run(main()) == "rejected"
+
+    def test_auth_rejects_replayed_request_frame(self):
+        """A captured request frame (e.g. a membership heartbeat) re-sent
+        within the auth window must be refused: every legitimate request
+        carries a fresh rid inside the MAC'd meta, so the server treats an
+        already-accepted MAC as a replay."""
+        import json as _json
+        import time as _time
+        import zlib as _zlib
+
+        from distributedvolunteercomputing_tpu.swarm.transport import (
+            _HEADER, MAGIC, TYPE_ERR, TYPE_REQ, TYPE_RESP, VERSION,
+        )
+
+        async def main():
+            server = Transport(secret=b"s3kr1t")
+            calls = []
+
+            async def ping(args, payload):
+                calls.append(args)
+                return {"ok": True}, b""
+
+            server.register("ping", ping)
+            addr = await server.start()
+            # A second node in the same swarm (same secret): the captured
+            # frame must be unusable there too (cross-node replay).
+            other = Transport(secret=b"s3kr1t")
+
+            async def ping2(args, payload):
+                calls.append(("other", args))
+                return {"ok": True}, b""
+
+            other.register("ping", ping2)
+            other_addr = await other.start()
+            # Craft ONE authenticated request frame (what an eavesdropper
+            # inside the window holds), then send the identical bytes twice
+            # on two fresh connections.
+            signer = Transport(secret=b"s3kr1t")
+            meta = {
+                "rid": "feedfacefeedface", "method": "ping", "args": {"n": 1},
+                "dst": [addr[0], addr[1]], "ts": round(_time.time(), 3),
+            }
+            meta["auth"] = signer._mac(TYPE_REQ, meta, b"")
+            meta_b = _json.dumps(meta).encode()
+            frame = _HEADER.pack(
+                MAGIC, VERSION, TYPE_REQ, len(meta_b), 0,
+                _zlib.crc32(b"") & 0xFFFFFFFF,
+            ) + meta_b
+
+            async def send_raw(to):
+                reader, writer = await asyncio.open_connection(*to)
+                try:
+                    writer.write(frame)
+                    await writer.drain()
+                    return await signer._read_frame(reader)
+                finally:
+                    writer.close()
+
+            ftype1, meta1, _ = await send_raw(addr)
+            ftype2, meta2, _ = await send_raw(addr)
+            ftype3, meta3, _ = await send_raw(other_addr)
+            await server.close()
+            await other.close()
+            assert ftype1 == TYPE_RESP and meta1["ret"] == {"ok": True}
+            # same-node replay: rejected by the seen-MAC cache
+            assert ftype2 == TYPE_ERR and "replay" in meta2.get("error", "")
+            # cross-node replay: rejected by the MAC'd dst binding
+            assert ftype3 == TYPE_ERR and "different node" in meta3.get("error", "")
+            assert len(calls) == 1  # the handler ran exactly once, on one node
+
+        run(main())
+
+    def test_dst_alias_matching(self):
+        """The MAC'd destination must match this node: port exactly, host
+        by legitimate alias (advertised, bound, loopback). Distinct nodes'
+        alias sets can't collide — same machine implies distinct ports."""
+        t = Transport(host="0.0.0.0", advertise_host="10.1.2.3")
+        t._port = 7000
+        assert t._dst_is_me(["10.1.2.3", 7000])   # advertised
+        assert t._dst_is_me(["0.0.0.0", 7000])    # bound
+        assert t._dst_is_me(["127.0.0.1", 7000])  # loopback dial
+        assert t._dst_is_me(["localhost", 7000])
+        assert not t._dst_is_me(["10.9.9.9", 7000])   # another machine
+        assert not t._dst_is_me(["10.1.2.3", 7001])   # another node, same host
+        assert not t._dst_is_me(None)                 # frame without dst
+        assert not t._dst_is_me(["10.1.2.3"])         # malformed
+
+    def test_unknown_method_raises(self):
+        async def main():
+            server = Transport()
+            addr = await server.start()
+            client = Transport()
+            try:
+                with pytest.raises(RPCError, match="no such method"):
+                    await client.call(addr, "nope")
+            finally:
+                await server.close()
+
+        run(main())
+
+    def test_handler_exception_propagates(self):
+        async def main():
+            server = Transport()
+
+            async def boom(args, payload):
+                raise ValueError("kaboom")
+
+            server.register("boom", boom)
+            addr = await server.start()
+            client = Transport()
+            try:
+                with pytest.raises(RPCError, match="kaboom"):
+                    await client.call(addr, "boom")
+            finally:
+                await server.close()
+
+        run(main())
+
+    def test_dead_peer_times_out(self):
+        async def main():
+            client = Transport()
+            with pytest.raises((OSError, asyncio.TimeoutError)):
+                await client.call(("127.0.0.1", 1), "ping", timeout=2.0)
+
+        run(main())
+
+
+async def _spawn_swarm(n, bootstrap_first=True):
+    nodes = []
+    for i in range(n):
+        node = DHTNode(Transport())
+        boot = [nodes[0].transport.addr] if (nodes and bootstrap_first) else []
+        await node.start(bootstrap=boot)
+        nodes.append(node)
+    return nodes
+
+
+async def _teardown(nodes):
+    for n in nodes:
+        await n.transport.close()
+
+
+class TestDHT:
+    def test_store_get_across_nodes(self):
+        async def main():
+            nodes = await _spawn_swarm(5)
+            try:
+                await nodes[1].store("model_version", {"step": 120}, ttl=30)
+                seen = await nodes[4].get_value("model_version")
+                return seen
+            finally:
+                await _teardown(nodes)
+
+        assert run(main()) == {"step": 120}
+
+    def test_subkey_merge_from_different_writers(self):
+        async def main():
+            nodes = await _spawn_swarm(4)
+            try:
+                for i, node in enumerate(nodes):
+                    await node.store("peers", {"rank": i}, subkey=f"peer{i}", ttl=30)
+                views = [await n.get("peers") for n in nodes]
+                return views
+            finally:
+                await _teardown(nodes)
+
+        views = run(main())
+        for view in views:
+            assert set(view) == {"peer0", "peer1", "peer2", "peer3"}
+            assert view["peer2"] == {"rank": 2}
+
+    def test_expiry(self):
+        async def main():
+            nodes = await _spawn_swarm(3)
+            try:
+                await nodes[0].store("ephemeral", "x", ttl=0.5)
+                now = await nodes[2].get_value("ephemeral")
+                await asyncio.sleep(0.8)
+                later = await nodes[2].get_value("ephemeral", default="GONE")
+                return now, later
+            finally:
+                await _teardown(nodes)
+
+        now, later = run(main())
+        assert now == "x"
+        assert later == "GONE"
+
+    def test_survives_node_death(self):
+        async def main():
+            nodes = await _spawn_swarm(6)
+            try:
+                await nodes[1].store("k", "v", ttl=30)
+                # kill half the swarm, including the bootstrap node
+                for victim in nodes[:3]:
+                    await victim.transport.close()
+                return await nodes[4].get_value("k", default="LOST")
+            finally:
+                await _teardown(nodes[3:])
+
+        # replication factor K=8 > swarm size, so every node holds a replica
+        assert run(main()) == "v"
+
+
+class TestMembership:
+    def test_join_heartbeat_leave(self):
+        async def main():
+            nodes = await _spawn_swarm(3)
+            try:
+                members = [
+                    SwarmMembership(node, f"vol{i}", ttl=2.0) for i, node in enumerate(nodes)
+                ]
+                for m in members:
+                    await m.join()
+                alive = await members[0].alive_peers()
+                await members[2].leave()
+                after_leave = await members[0].alive_peers()
+                return alive, after_leave
+            finally:
+                await _teardown(nodes)
+
+        alive, after_leave = run(main())
+        assert set(alive) == {"vol0", "vol1", "vol2"}
+        assert set(after_leave) == {"vol0", "vol1"}
+
+    def test_crashed_peer_expires(self):
+        async def main():
+            nodes = await _spawn_swarm(3)
+            try:
+                members = [
+                    SwarmMembership(node, f"vol{i}", ttl=1.2) for i, node in enumerate(nodes)
+                ]
+                for m in members:
+                    await m.join()
+                # simulate kill -9: no leave(), just stop heartbeats + socket
+                members[1]._heartbeat_task.cancel()
+                await nodes[1].transport.close()
+                await asyncio.sleep(1.6)
+                alive = await members[0].alive_peers()
+                return alive
+            finally:
+                await _teardown([nodes[0], nodes[2]])
+
+        alive = run(main())
+        assert "vol1" not in alive
+        assert {"vol0", "vol2"} <= set(alive)
+
+
+class TestCoordinator:
+    def test_status_aggregates(self):
+        async def main():
+            coord = Coordinator()
+            caddr = await coord.start()
+            try:
+                nodes = []
+                for i in range(3):
+                    node = DHTNode(Transport())
+                    await node.start(bootstrap=[caddr])
+                    nodes.append(node)
+                    m = SwarmMembership(node, f"vol{i}", ttl=10.0)
+                    await m.join()
+                    await node.transport.call(
+                        caddr,
+                        "coord.report",
+                        {"peer": f"vol{i}", "step": 10 * i, "samples_per_sec": 100.0},
+                    )
+                status, _ = await coord._rpc_status({}, b"")
+                await _teardown(nodes)
+                return status
+            finally:
+                await coord.close()
+
+        status = run(main())
+        assert status["n_alive"] == 3
+        assert status["swarm_samples_per_sec"] == pytest.approx(300.0)

@@ -23,8 +23,8 @@ from distributedvolunteercomputing_tpu.models import (
 )
 from distributedvolunteercomputing_tpu.models.registry import _LANGUAGE_MODELS
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
-from distributedvolunteercomputing_tpu.training.optim import make_optimizer
-from distributedvolunteercomputing_tpu.training.steps import TrainState, make_train_step
+from distributedvolunteercomputing_tpu.training.steps import TrainState
+from tests import tiny_models
 
 SHARED_KEYS = {"loss", "lm_loss", "aux_loss", "moe_load_max", "moe_load_mean", "moe_rows_held",
                "moe_rows_moved", "moe_dropped"}
@@ -75,8 +75,12 @@ FAMILIES = {
 
 
 def tiny(family):
+    """The family's module and its tiny bundle: the one ``tests/tiny_models.py`` keeps for every file."""
     module, name, rehearsal = FAMILIES[family][:3]
-    return module, get_model(name, **Manifest().load_config(rehearsal)["model_overrides"])
+    assert rehearsal == f"tiny-rehearsal-{family}"
+    bundle = tiny_models.bundle(family)
+    assert bundle.name == name
+    return module, bundle
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -94,11 +98,19 @@ def test_a_layers_statistics_are_a_plain_count_of_its_routes_and_the_dispatchs_r
         return out
 
     monkeypatch.setattr(holder, "share_glu_experts", recorded)
-    params = jax.tree_util.tree_map(lambda a: a * 3.0, bundle.init(jax.random.PRNGKey(3)))
+    params = jax.tree_util.tree_map(lambda a: a * 3.0, jax.jit(bundle.init)(jax.random.PRNGKey(3)))
     p = layer_of(params["blocks"])
     x = jax.random.normal(jax.random.PRNGKey(4), (2, cfg.max_len, cfg.d_model))
     start = moe.zero_share_stats(**zero)
-    _, stats, out = module._layer(p, x, start, cfg, *args)
+
+    @jax.jit
+    def layer(stats):
+        """The layer's statistics and routes, and what the dispatch was handed and returned while it was traced."""
+        _, new, out = module._layer(p, x, stats, cfg, *args)
+        return new, out, {k: seen[k] for k in ("h", "dispatch")}
+
+    stats, out, told = layer(start)
+    seen.update(told)   # the values, where the trace left tracers
     assert set(stats) == set(start)
     top_idx = np.asarray(out[0] if isinstance(out, tuple) else out)
     s = top_idx.shape[0]
@@ -123,7 +135,7 @@ def test_a_layers_statistics_are_a_plain_count_of_its_routes_and_the_dispatchs_r
     for key, value in want.items():
         np.testing.assert_allclose(np.asarray(stats[key]), value, rtol=1e-5, err_msg=key)
     # a second layer adds to the sums and keeps the fullest
-    _, twice, _ = module._layer(p, x, stats, cfg, *args)
+    twice, _, _ = layer(stats)
     for key in want:
         both = want[key] if key == "load_max" else 2 * np.asarray(want[key])
         np.testing.assert_allclose(np.asarray(twice[key]), both, rtol=1e-5, err_msg=key)
@@ -133,13 +145,13 @@ def test_a_layers_statistics_are_a_plain_count_of_its_routes_and_the_dispatchs_r
 def test_a_familys_step_returns_the_metric_keys_it_returned(family):
     module, bundle = tiny(family)
     own = FAMILIES[family][7]
-    tx = make_optimizer("adam", lr=1e-3, total_steps=100)
-    state = TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(1))
+    tx, step = tiny_models.train_step(bundle, "adam", lr=1e-3)   # the step the family's own file takes
+    state = TrainState.create(jax.jit(bundle.init)(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(1))
     batch = bundle.make_batch(jax.random.PRNGKey(2), 2)
-    _, metrics = bundle.loss_fn(state.params, batch, None)
+    _, metrics, _ = tiny_models.programs(bundle).loss_and_routes(state.params, batch)   # bundle.loss_fn's two results
     counts = {moe.COUNTS} if bundle.stepped else set()
     assert set(metrics) == SHARED_KEYS | own | counts
-    _, metrics = make_train_step(bundle.loss_fn, tx, stepped=bundle.stepped)(state, batch)
+    _, metrics = step(state, batch)
     assert set(metrics) == SHARED_KEYS | own | {"grad_norm"}
     cfg = bundle.config
     assert float(metrics["moe_load_mean"]) == 2 * cfg.max_len * cfg.top_k / cfg.n_experts

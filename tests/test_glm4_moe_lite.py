@@ -23,10 +23,12 @@ from benchmark.references import glm4_moe_lite as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, glm4_moe_lite as glm, moe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.training import steps
+from tests import tiny_models
 
-TINY = Manifest().load_config("tiny-rehearsal-glm")
+TINY = tiny_models.rehearsal("glm")
 OVERRIDES = TINY["model_overrides"]
 MODEL = "glm4_7_flash"
+HP = ref.hyper(TINY)
 # the variants that the initial parameters hide (a bias of zero; norms, scale and rotary turn under a
 # softmax that is nearly flat): held here at scaled weights and a seeded bias
 INIT_BLIND = ("bias_in_weights", "no_latent_norm", "no_query_norm", "scale_by_192", "rope_on_whole_head",
@@ -39,8 +41,8 @@ def seeded(scale: float = 3.0, bias: float = 0.0, **overrides):
     top-4, a shared expert) with matrices scaled up so that every term matters,
     a seeded selection bias of that size where asked, norm scales seeded about
     1, and two seeded sequences."""
-    bundle = get_model(MODEL, **{**OVERRIDES, **overrides})
-    params = bundle.init(jax.random.PRNGKey(3))
+    bundle = tiny_models.bundle("glm", **overrides)
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(3))
 
     def scaled(path, x):
         name = jax.tree_util.keystr(path)
@@ -75,6 +77,14 @@ def one_layer(params, run, i=0):
     return jax.tree_util.tree_map(lambda a: a[i], params["blocks"][run])
 
 
+# ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
+reference = tiny_models.reference_programs(ref, HP)
+
+
+# the reference's own loss-and-gradient as the harness calls it, under one jit
+REFERENCE = jax.jit(ref.make_loss_and_grad(TINY))
+
+
 # -- the program against the reference ---------------------------------------------
 
 
@@ -86,8 +96,8 @@ def test_float32_program_equals_the_reference_on_loss_and_every_leaf(remat):
     # what the comparison covers: the dense layer, a stacked run, a share, every head its own non-rotary key
     assert cfg.runs == (("dense", 1), ("sparse", 2)) and cfg.layer_types == ("latent_attention",) * 3
     assert (cfg.experts_held, cfg.expert_offset, cfg.n_experts, cfg.n_shared) == (4, 4, 16, 1)
-    lp, gp = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    lr, gr = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"])
+    lp, gp = tiny_models.programs(bundle).loss_and_grad(params, batch)
+    lr, gr = REFERENCE(params, batch["tokens"], batch["targets"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     errors = leaf_errors(gp, gr)
     # 12 leaves of the dense layer, 17 of the expert run, 3 outside (embedding, final norm, head)
@@ -106,10 +116,9 @@ def test_bf16_program_equals_the_reference_given_its_routes(monkeypatch):
     the limits are about three times that."""
     monkeypatch.setattr(common, "compute_dtype", lambda: jnp.bfloat16)
     bundle, params, batch = seeded(scale=1.0)
-    (got_l, routes), got_g = jax.value_and_grad(
-        lambda p: glm.loss_and_routes(p, batch, bundle.config)[::2], has_aux=True)(params)
+    (got_l, routes), got_g = tiny_models.programs(bundle).loss_routes_and_grad(params, batch)
     assert routes.shape == (2, 128, 4)
-    want_l, want_g = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"], routes)
+    want_l, want_g = REFERENCE(params, batch["tokens"], batch["targets"], routes)
     assert abs(float(got_l) - float(want_l)) < 0.002
     assert whole_error(got_g, want_g) < 0.03
     errors = leaf_errors(got_g, want_g)
@@ -149,11 +158,11 @@ def _as_written():
     (its arguments, the program's loss, the routes, the reference's gradient)."""
     if not _AS_WRITTEN:
         bundle, params, batch = seeded(bias=0.1)
-        args = (params, batch["tokens"], batch["targets"], ref.hyper(TINY))
-        program = float(bundle.loss_fn(params, batch, None)[0])
-        right, routes = ref.loss(*args, with_routes=True)
+        args = (params, batch["tokens"], batch["targets"])
+        program = float(tiny_models.programs(bundle).loss(params, batch))
+        right, routes = reference(with_routes=True)(*args)
         assert program == pytest.approx(float(right), rel=1e-5)
-        _AS_WRITTEN.append((args, program, routes, jax.grad(ref.loss)(*args, routes)))
+        _AS_WRITTEN.append((args, program, routes, reference(grad=True)(*args, routes)[1]))
     return _AS_WRITTEN[0]
 
 
@@ -166,37 +175,37 @@ def test_reference_notices_a_term_left_out(variant):
     program agrees with the reference as written."""
     args, program, routes, g_right = _as_written()
     if variant == "softmax_for_sigmoid":  # other scores pick other experts: its own routes
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args)
     else:
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, routes, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args, routes)
     assert abs(float(wrong) - program) > 1e-4, variant
     assert whole_error(g_wrong, g_right) > 0.01, variant
     with pytest.raises(ValueError, match="unknown variant"):
-        ref.loss(*args, variant="nothing")
+        ref.loss(*args, HP, variant="nothing")
 
 
 def test_the_init_blind_variants_are_variants_and_the_bias_one_is_blind_at_zero():
     assert set(INIT_BLIND) <= set(ref.VARIANTS)
     bundle, params, batch = seeded(bias=0.0)
-    args = (params, batch["tokens"], batch["targets"], ref.hyper(TINY))
-    right, routes = ref.loss(*args, with_routes=True)
-    assert float(ref.loss(*args, routes, variant="bias_in_weights")) == pytest.approx(float(right), rel=1e-6)
+    args = (params, batch["tokens"], batch["targets"])
+    right, routes = reference(with_routes=True)(*args)
+    assert float(reference(variant="bias_in_weights")(*args, routes)) == pytest.approx(float(right), rel=1e-6)
 
 
 def test_routes_given_equal_routes_computed_and_another_share_is_noticed():
     bundle, params, batch = seeded(bias=0.05)
-    hp = ref.hyper(TINY)
-    loss, routes = ref.loss(params, batch["tokens"], batch["targets"], hp, with_routes=True)
+    loss, routes = reference(with_routes=True)(params, batch["tokens"], batch["targets"])
     assert routes.shape == (2, batch["tokens"].size, 4)  # the two expert layers, in layer order
-    _, _, mine = glm.loss_and_routes(params, batch, bundle.config)
+    _, _, mine = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.sort(np.asarray(mine), -1), np.sort(np.asarray(routes), -1))
-    fn = ref.make_loss_and_grad(TINY)
+    fn = REFERENCE
     l0, g0 = fn(params, batch["tokens"], batch["targets"])
     l1, g1 = fn(params, batch["tokens"], batch["targets"], routes)
     assert float(l0) == pytest.approx(float(l1), rel=1e-6) == pytest.approx(float(loss), rel=1e-6)
     errors = leaf_errors(g1, g0)
     assert max(v for k, v in errors.items() if not k.endswith("['bias']")) < 1e-5
-    other = float(ref.loss(params, batch["tokens"], batch["targets"], dict(hp, offset=0)))
+    other = float(jax.jit(lambda p, tok, tgt: ref.loss(p, tok, tgt, dict(HP, offset=0)))(
+        params, batch["tokens"], batch["targets"]))
     assert abs(float(loss) - other) > 1e-4
 
 
@@ -212,8 +221,8 @@ def test_a_token_changes_nothing_before_it(run):
     ffn = "dense" if run == 0 else "sparse"
     at = 40
     other = x.at[0, at].set(x[0, at] + 1.0)
-    a, _, _ = glm._layer(p, x, moe.zero_share_stats(chunks_extra=True), cfg, ffn)
-    b, _, _ = glm._layer(p, other, moe.zero_share_stats(chunks_extra=True), cfg, ffn)
+    layer = jax.jit(lambda x: glm._layer(p, x, moe.zero_share_stats(chunks_extra=True), cfg, ffn)[0])
+    a, b = layer(x), layer(other)
     diff = np.abs(np.asarray(a - b)).max(axis=-1)[0]
     assert diff[:at].max() == 0.0 and diff[at] > 0 and np.nonzero(diff)[0].max() == cfg.max_len - 1
 
@@ -296,7 +305,7 @@ def test_a_step_moves_each_bias_by_gamma_by_the_counts_and_nothing_else_differs(
     step = steps.make_train_step(bundle.loss_fn, tx, donate=False, stepped=bundle.stepped)
     new, metrics = step(state, batch)
     assert moe.COUNTS not in metrics and float(metrics["aux_loss"]) == 0.0
-    _, m, _ = steps.grad_half(bundle.loss_fn, state, batch)
+    _, m = tiny_models.programs(bundle).loss_and_routes(params, batch)[:2]
     counts = np.asarray(m[moe.COUNTS])
     assert counts.shape == (2, 16) and np.all(counts.sum(-1) == 2 * 64 * 4)
     plain = steps.make_train_step(
@@ -335,17 +344,18 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_onc
     x = params["wte"][batch["tokens"]][:1]
     p = one_layer(params, 1)
     with jax.default_matmul_precision("highest"):
-        whole, _ = ref._block(p, x, None, hp)
+        block = jax.jit(lambda p: ref._block(p, x, None, hp)[0])
+        whole = block(p)
         no_experts = jax.tree_util.tree_map(jnp.zeros_like, p["experts"])
-        alike, _ = ref._block(dict(p, experts=no_experts), x, None, hp)   # mixer, residual, shared expert
-        no_shared, _ = ref._block(dict(p, experts=no_experts, shared=jax.tree_util.tree_map(jnp.zeros_like, p["shared"])),
-                                  x, None, hp)
+        alike = block(dict(p, experts=no_experts))   # mixer, residual, shared expert
+        no_shared = block(dict(p, experts=no_experts, shared=jax.tree_util.tree_map(jnp.zeros_like, p["shared"])))
     assert float(jnp.max(jnp.abs(alike - no_shared))) > 1e-2   # the shared expert is in what is counted once
     total = alike
     for offset in range(0, 16, 4):
         cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
         held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-        y, stats, _ = glm._layer(dict(p, experts=held), x, moe.zero_share_stats(chunks_extra=True), cfg, "sparse")
+        y, stats, _ = jax.jit(lambda p: glm._layer(  # a program a share: the offset is the trace's
+            p, x, moe.zero_share_stats(chunks_extra=True), cfg, "sparse"))(dict(p, experts=held))
         assert float(stats["dropped"]) == 0.0
         total = total + (y - alike)  # this share's routed experts' part alone
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)
@@ -365,7 +375,7 @@ def test_the_model_takes_the_default_chunk_and_counts_what_a_smaller_one_would_c
     read = {}
     for slack in (moe_dispatch.SHARE_ROWS_SLACK_LEVELLED, moe_dispatch.SHARE_ROWS_SLACK):
         monkeypatch.setattr(glm, "SHARE_ROWS_SLACK", slack)
-        (loss, m), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(lifted, batch, None)
+        (loss, m), grads = tiny_models.programs(bundle).loss_metrics_and_grad(lifted, batch)  # keyed by the slack in force
         cap = moe_dispatch.share_rows_bound(batch["tokens"].size, c.top_k, c.experts_held, c.n_experts, slack)
         held = np.asarray(m[moe.COUNTS])[:, first:first + c.experts_held].sum(axis=1)
         assert float(m["moe_dropped"]) == 0.0 and float(m["moe_chunks_extra"]) == (np.ceil(held / cap) - 1).sum()

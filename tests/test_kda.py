@@ -4,6 +4,8 @@ the scan against the recurrence TOKEN BY TOKEN, values and every gradient, and
 against the formulation it had until PR 55 (the streams normed, folded, summed
 and laid out by chunk as whole passes around the scan)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,9 +59,20 @@ def scan_inputs(seed=0, z=2, t=40, h=3, dk=8, dv=16, decay=0.3, dtype=jnp.float3
     return (q, key, v, g, beta), jax.random.normal(k[5], (z, t, h, dv)).astype(dtype)
 
 
+@functools.lru_cache(maxsize=4)   # ``recurrence``, asked for by every test, stays; a test's own lambda goes with it
+def _value_and_grads(fn):
+    def both(args, probe):
+        return fn(*args), jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * probe.astype(jnp.float32)),
+                                   argnums=tuple(range(len(args))))(*args)
+
+    return jax.jit(both)
+
+
 def value_and_grads(fn, args, probe):
-    return fn(*args), jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * probe.astype(jnp.float32)),
-                               argnums=tuple(range(len(args))))(*args)
+    """``fn``'s value and its gradient against ``probe``, as ONE program a
+    function (the token-by-token ``recurrence`` is compiled once a shape for
+    the whole file; evaluated eagerly it is hundreds of small programs a test)."""
+    return _value_and_grads(fn)(args, probe)
 
 
 def close(got, want, tol, what):

@@ -7,6 +7,7 @@ parameter counts, the share's sum, the value head's own width, the step's bias
 rule and the loop's spans."""
 
 import dataclasses
+import functools
 import json
 import os
 import subprocess
@@ -23,10 +24,10 @@ from benchmark.manifest import Manifest
 from benchmark.references import kimi_linear as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, kimi_linear, moe
 from distributedvolunteercomputing_tpu.ops import attention, pallas_attention
+from tests import tiny_models
 
-M = Manifest()
-TINY = M.load_config("tiny-rehearsal-kimi")
-CFG = M.load_config("kimi-linear-48b-a3b")
+TINY = tiny_models.rehearsal("kimi")
+CFG = Manifest().load_config("kimi-linear-48b-a3b")
 HP = ref.hyper(TINY)
 
 
@@ -41,9 +42,15 @@ def tiny(seed=3, scale=3.0, bias=0.05, **overrides):
     matrix times ``scale``, seeded selection biases and output-gate biases, the
     norms' scales spread out; the decay's leaves and the taps as initialised) by
     a hash of the leaf's name that no ``PYTHONHASHSEED`` moves, and two seeded
-    sequences of 40."""
-    bundle = get_model(TINY["registry_model"], **{**TINY["model_overrides"], **overrides})
-    params = bundle.init(jax.random.PRNGKey(seed))
+    sequences of 40. One tree a set of arguments: no test writes into it or
+    donates it."""
+    return _tiny(seed, scale, bias, tuple(sorted(overrides.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def _tiny(seed, scale, bias, overrides):
+    bundle = tiny_models.bundle("kimi", **dict(overrides))
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(seed))
 
     def moved(path, x):
         name = jax.tree_util.keystr(path)
@@ -62,11 +69,14 @@ def tiny(seed=3, scale=3.0, bias=0.05, **overrides):
     return bundle, params, datagen.lm_arrays(5, 2, 40, TINY["vocab_size"])
 
 
+# ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
+reference = tiny_models.reference_programs(ref, HP)
+
+
 def both_sides(bundle, params, batch, variant=None, routes=None):
     tokens, targets = batch["tokens"], batch["targets"]
-    program = jax.value_and_grad(lambda p: bundle.loss_fn(p, {"tokens": tokens, "targets": targets}, None)[0])(params)
-    reference = jax.value_and_grad(lambda p: ref.loss(p, tokens, targets, HP, routes, variant=variant))(params)
-    return program, reference
+    program = tiny_models.programs(bundle).loss_and_grad(params, {"tokens": tokens, "targets": targets})
+    return program, reference(grad=True, variant=variant)(params, tokens, targets, routes)
 
 
 def rel(a, b):
@@ -108,12 +118,12 @@ def test_float32_program_equals_the_reference_on_loss_and_every_leaf(state):
 def test_routes_given_equal_routes_computed_and_another_share_is_noticed():
     bundle, params, batch = tiny()
     tokens, targets = batch["tokens"], batch["targets"]
-    own, routes = ref.loss(params, tokens, targets, HP, with_routes=True)
-    assert routes.shape == (4, 80, 4) and float(ref.loss(params, tokens, targets, HP, routes)) == float(own)
-    _, _, program_routes = kimi_linear.loss_and_routes(params, batch, bundle.config)
+    own, routes = reference(with_routes=True)(params, tokens, targets)
+    assert routes.shape == (4, 80, 4) and float(reference()(params, tokens, targets, routes)) == float(own)
+    _, _, program_routes = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.asarray(program_routes), np.asarray(routes))
-    other = dict(HP, offset=8)
-    assert abs(float(ref.loss(params, tokens, targets, other, routes)) - float(own)) > 1e-4
+    other = jax.jit(lambda p, r: ref.loss(p, tokens, targets, dict(HP, offset=8), r))
+    assert abs(float(other(params, routes)) - float(own)) > 1e-4
 
 
 def test_a_token_changes_nothing_before_it():
@@ -121,6 +131,7 @@ def test_a_token_changes_nothing_before_it():
     tokens = jnp.asarray(batch["tokens"][:1])
     targets = jnp.asarray(batch["targets"][:1])
 
+    @jax.jit
     def per_token_loss_inputs(tok):
         # the final hidden states, through the program's own trunk: the loss's head is position-wise
         cfg = dataclasses.replace(bundle.config, remat=False)
@@ -194,7 +205,7 @@ def test_the_decay_leaves_are_initialised_as_the_family_does():
     again = bundle.init(jax.random.PRNGKey(3))
     assert np.array_equal(np.asarray(again["blocks"][1]["mixer"]["dt_bias"]), np.asarray(m["dt_bias"]))
     # the counters: with this initialisation every head carries a state across a chunk of 16
-    _, metrics, _ = kimi_linear.loss_and_routes(params, batch, bundle.config)
+    _, metrics, _ = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert 0.0 < float(metrics["kda_carry_share"]) <= 1.0 and float(metrics["kda_decay_min"]) < 0.0
     assert 0.3 < float(metrics["kda_beta_mean"]) < 0.7
 
@@ -214,7 +225,8 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_onc
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 40, 64))
     zero = jnp.zeros(())
     stats = {**moe.zero_share_stats(chunks_extra=True), "kda_carried": zero, "kda_decay_min": zero, "kda_beta": zero}
-    layer = lambda p, c: kimi_linear._layer(p, x, stats, c, kimi_linear.LATENT, kimi_linear.SPARSE)  # noqa: E731
+    layer = lambda p, c: jax.jit(  # noqa: E731 — a program a share: the offset is the trace's
+        lambda p: kimi_linear._layer(p, x, stats, c, kimi_linear.LATENT, kimi_linear.SPARSE))(p)
     whole, _, (routes, chosen) = layer(p, cfg)
     mixed = x + kimi_linear._latent(p["mixer"], common.rmsnorm(p["ln_mixer"], x, cfg.rms_eps), cfg)
     h = common.rmsnorm(p["ln_ffn"], mixed, cfg.rms_eps).reshape(80, 64)
@@ -330,17 +342,16 @@ def test_latent_attention_builds_keys_of_192_from_one_shared_part_and_rotates_no
 
 
 def test_a_step_moves_each_bias_by_gamma_by_the_counts_and_the_decay_leaves_by_the_optimizer():
-    from distributedvolunteercomputing_tpu.training.optim import make_optimizer
-    from distributedvolunteercomputing_tpu.training.steps import TrainState, make_train_step
+    from distributedvolunteercomputing_tpu.training.steps import TrainState
 
     bundle, params, batch = tiny(scale=0.0)
-    tx = make_optimizer("adam", lr=1e-3)
+    tx, step = tiny_models.train_step(bundle, "adam", lr=1e-3)
     state = TrainState.create(params, tx, jax.random.PRNGKey(1))
-    before = jax.tree_util.tree_map(lambda a: np.array(a, copy=True), params)   # the step donates its state: no view of it
-    _, metrics, _ = kimi_linear.loss_and_routes(params, batch, bundle.config)
+    before = jax.tree_util.tree_map(lambda a: np.array(a, copy=True), params)
+    _, metrics, _ = tiny_models.programs(bundle).loss_and_routes(params, batch)
     counts = np.asarray(metrics[moe.COUNTS])
     assert counts.shape == (4, 16) and counts.sum() == 4 * 80 * 4
-    state, out = make_train_step(bundle.loss_fn, tx, stepped=bundle.stepped)(state, batch)
+    state, out = step(state, batch)
     assert moe.COUNTS not in out and {"kda_carry_share", "kda_decay_min", "kda_beta_mean", "moe_chunks_extra"} <= set(out)
     want = 0.001 * np.sign(counts.mean(-1, keepdims=True) - counts)
     got = np.concatenate([np.asarray(p["bias"]) for p in state.params["blocks"] if "bias" in p])

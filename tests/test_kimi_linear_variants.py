@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from benchmark.references import kimi_linear as ref
-from tests.test_kimi_linear import HP, flat, rel, tiny
+from tests.test_kimi_linear import flat, reference, rel, tiny
 
 
 @pytest.fixture(autouse=True)
@@ -29,14 +29,13 @@ def state(scale):
     """(parameters, tokens, targets, the routes the reference chose) at the tiny size."""
     _, params, batch = tiny(scale=scale)
     tokens, targets = batch["tokens"], batch["targets"]
-    _, routes = jax.jit(lambda p: ref.loss(p, tokens, targets, HP, with_routes=True))(params)
+    _, routes = reference(with_routes=True)(params, tokens, targets)
     return params, tokens, targets, routes
 
 
 def loss_and_grad(variant, params, tokens, targets, routes):
     """The reference's loss and its gradient as one flat vector, ``variant`` in place of one term."""
-    fn = jax.jit(jax.value_and_grad(lambda p: ref.loss(p, tokens, targets, HP, routes, variant=variant)))
-    loss, grads = fn(params)
+    loss, grads = reference(grad=True, variant=variant)(params, tokens, targets, routes)
     return float(loss), flat(grads)
 
 

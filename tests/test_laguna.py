@@ -22,9 +22,18 @@ from benchmark.references import laguna as ref
 from distributedvolunteercomputing_tpu.models import get_model, laguna, moe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.ops.pallas_attention import choose_blocks, flash_attention
+from tests import tiny_models
 
-TINY = Manifest().load_config("tiny-rehearsal-laguna")
+TINY = tiny_models.rehearsal("laguna")
 OVERRIDES = TINY["model_overrides"]
+HP = ref.hyper(TINY)
+# the reference's own loss-and-gradient as the harness calls it, under one jit
+REFERENCE = jax.jit(ref.make_loss_and_grad(TINY))
+
+
+def reference_loss(hp=HP, **static):
+    """``ref.loss`` of ``(params, tokens, targets)`` at ``hp`` as one program."""
+    return jax.jit(lambda params, tokens, targets: ref.loss(params, tokens, targets, hp, **static))
 
 
 @pytest.fixture(autouse=True)
@@ -37,8 +46,8 @@ def tight_chunks(monkeypatch):
 def seeded(scale: float = 3.0, **overrides):
     """The tiny model with weights scaled up so that every term matters, and
     two seeded sequences."""
-    bundle = get_model("laguna_xs2", **{**OVERRIDES, **overrides})
-    params = bundle.init(jax.random.PRNGKey(3))
+    bundle = tiny_models.bundle("laguna", **overrides)
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(3))
     params = jax.tree_util.tree_map(lambda x: x * scale if x.ndim > 1 else x, params)
     rng = np.random.default_rng(0)
     t, v = bundle.config.max_len, bundle.config.vocab
@@ -60,8 +69,8 @@ def leaf_errors(got, want):
 def test_float32_program_equals_the_reference_on_loss_and_every_leaf(remat):
     bundle, params, batch = seeded(remat=remat)
     ref.check_config(dataclasses.replace(bundle.config, remat=True), TINY)
-    lp, gp = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    lr, gr = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"])
+    lp, gp = tiny_models.programs(bundle).loss_and_grad(params, batch)
+    lr, gr = REFERENCE(params, batch["tokens"], batch["targets"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     errors = leaf_errors(gp, gr)
     assert len(errors) == 69  # five layers' leaves, embedding, head, final norm
@@ -81,6 +90,9 @@ def test_the_layers_follow_the_published_pattern():
     assert shapes["blocks"][1]["router"].shape == (64, 16)                # the router keeps its width
 
 
+AS_WRITTEN = reference_loss()
+
+
 @pytest.mark.parametrize("name,change", [
     ("the routed scale", lambda hp: dict(hp, routed_scale=1.0)),
     ("the window", lambda hp: dict(hp, window=1 << 20)),
@@ -90,29 +102,27 @@ def test_the_layers_follow_the_published_pattern():
 ])
 def test_reference_notices_a_term_left_out(name, change):
     bundle, params, batch = seeded()
-    hp = ref.hyper(TINY)
-    program = float(bundle.loss_fn(params, batch, None)[0])
-    assert program == pytest.approx(float(ref.loss(params, batch["tokens"], batch["targets"], hp)), rel=1e-5)
-    other = float(ref.loss(params, batch["tokens"], batch["targets"], change(hp)))
+    program = float(tiny_models.programs(bundle).loss(params, batch))
+    assert program == pytest.approx(float(AS_WRITTEN(params, batch["tokens"], batch["targets"])), rel=1e-5)
+    other = float(reference_loss(change(HP))(params, batch["tokens"], batch["targets"]))
     assert abs(other - program) > 1e-4, name
 
 
 def test_reference_notices_a_missing_gate():
     bundle, params, batch = seeded()
     ungated = dict(params, blocks=[dict(b, wg=b["wg"] + 1.0) for b in params["blocks"]])
-    a = float(ref.loss(params, batch["tokens"], batch["targets"], ref.hyper(TINY)))
-    b = float(ref.loss(ungated, batch["tokens"], batch["targets"], ref.hyper(TINY)))
-    assert abs(a - b) > 1e-4 and b == pytest.approx(float(bundle.loss_fn(ungated, batch, None)[0]), rel=1e-5)
+    a = float(AS_WRITTEN(params, batch["tokens"], batch["targets"]))
+    b = float(AS_WRITTEN(ungated, batch["tokens"], batch["targets"]))
+    assert abs(a - b) > 1e-4 and b == pytest.approx(float(tiny_models.programs(bundle).loss(ungated, batch)), rel=1e-5)
 
 
 def test_routes_given_equal_routes_computed():
     bundle, params, batch = seeded()
-    hp = ref.hyper(TINY)
-    loss, routes = ref.loss(params, batch["tokens"], batch["targets"], hp, with_routes=True)
+    loss, routes = reference_loss(with_routes=True)(params, batch["tokens"], batch["targets"])
     assert routes.shape == (4, batch["tokens"].size, 4)  # four expert layers of five
-    _, _, mine = laguna.loss_and_routes(params, batch, bundle.config)
+    _, _, mine = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.sort(np.asarray(mine), -1), np.sort(np.asarray(routes), -1))
-    fn = ref.make_loss_and_grad(TINY)
+    fn = REFERENCE
     l0, g0 = fn(params, batch["tokens"], batch["targets"])
     l1, g1 = fn(params, batch["tokens"], batch["targets"], routes)
     assert float(l0) == pytest.approx(float(l1), rel=1e-6) == pytest.approx(float(loss), rel=1e-6)
@@ -144,8 +154,8 @@ def test_xla_core_with_grouped_heads_and_a_window(heads, kv_heads, window):
     q, k, v, cot = qkv(jax.random.PRNGKey(1), heads, kv_heads, t=20)
     attention.set_attention_impl("xla")
     try:
-        f = lambda core: jax.value_and_grad(  # noqa: E731
-            lambda q, k, v: jnp.sum(core(q, k, v) * cot), argnums=(0, 1, 2))(q, k, v)
+        f = lambda core: jax.jit(jax.value_and_grad(  # noqa: E731
+            lambda q, k, v: jnp.sum(core(q, k, v) * cot), argnums=(0, 1, 2)))(q, k, v)
         got = f(lambda q, k, v: attention.attention_core(q, k, v, causal=True, window=window))
         want = f(lambda q, k, v: plain_attention(q, k, v, window))
     finally:
@@ -167,8 +177,8 @@ def test_kernel_with_grouped_heads_and_a_window_forward_and_backward(t, window, 
     start at the window's first block and end at the diagonal, the masked and
     unmasked bodies, dk and dv summed over a group's query heads."""
     q, k, v, cot = qkv(jax.random.PRNGKey(t), heads, kv_heads, t)
-    f = lambda core: jax.value_and_grad(  # noqa: E731
-        lambda q, k, v: jnp.sum(core(q, k, v) * cot), argnums=(0, 1, 2))(q, k, v)
+    f = lambda core: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda q, k, v: jnp.sum(core(q, k, v) * cot), argnums=(0, 1, 2)))(q, k, v)
     got = f(lambda q, k, v: flash_attention(q, k, v, True, bq, bk, None, window))
     want = f(lambda q, k, v: plain_attention(q, k, v, window))
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
@@ -314,15 +324,17 @@ def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer():
     hp, p = ref.hyper(uncut), params["blocks"][1]
     x = params["wte"][batch["tokens"]][:1]
     with jax.default_matmul_precision("highest"):
-        whole, _, _ = ref._block(p, x, 1, None, hp)
+        block = jax.jit(lambda p: ref._block(p, x, 1, None, hp)[0])
+        whole = block(p)
         # what every chip computes alike: attention, the residual, the shared expert
         no_experts = jax.tree_util.tree_map(jnp.zeros_like, p["experts"])
-        alike, _, _ = ref._block(dict(p, experts=no_experts), x, 1, None, hp)
+        alike = block(dict(p, experts=no_experts))
     total = alike
     for offset in range(0, 16, 4):
         cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
         held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-        y, stats, _ = laguna._layer(dict(p, experts=held), x, moe.zero_share_stats(balanced=cfg.n_experts), cfg, 1)
+        y, stats, _ = jax.jit(lambda p: laguna._layer(  # a program a share: the offset is the trace's
+            p, x, moe.zero_share_stats(balanced=cfg.n_experts), cfg, 1))(dict(p, experts=held))
         assert float(stats["dropped"]) == 0.0
         total = total + (y - alike)  # this share's experts' part alone
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)
@@ -343,9 +355,9 @@ def test_a_share_is_its_experts_part_with_gradients(offset):
         full = [w.at[offset:offset + 4].set(h) for w, h in zip(stacks, held)]
         return jnp.sum(jnp.sin(dense_experts(x, idx, weights, full, range(offset, offset + 4))))
 
-    (_, (y, sizes, dropped, moved)), got = jax.value_and_grad(share, (0, 1, 2, 3, 4), has_aux=True)(
+    (_, (y, sizes, dropped, moved)), got = jax.jit(jax.value_and_grad(share, (0, 1, 2, 3, 4), has_aux=True))(
         x, weights, *held)
-    want = jax.grad(dense, (0, 1, 2, 3, 4))(x, weights, *held)
+    want = jax.jit(jax.grad(dense, (0, 1, 2, 3, 4)))(x, weights, *held)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
     counts = np.bincount(np.asarray(idx).ravel(), minlength=16)[offset:offset + 4]

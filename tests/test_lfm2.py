@@ -24,9 +24,11 @@ from benchmark.references import lfm2 as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, lfm2
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, short_conv
 from distributedvolunteercomputing_tpu.training import steps
+from tests import tiny_models
 
-TINY = Manifest().load_config("tiny-rehearsal-lfm2")
+TINY = tiny_models.rehearsal("lfm2")
 OVERRIDES = TINY["model_overrides"]
+HP = ref.hyper(TINY)
 KINDS = ["conv", "full_attention", "conv", "conv", "conv"]
 
 
@@ -44,8 +46,8 @@ def seeded(scale: float = 3.0, bias: float = 0.0, **overrides):
     three conv expert layers; 8 query heads over 2 key/value heads; experts
     4..7 of 16 held, top-4) with matrices scaled up so that every term matters,
     a seeded selection bias of that size where asked, and two seeded sequences."""
-    bundle = get_model("lfm2_24b_a2b", **{**OVERRIDES, **overrides})
-    params = bundle.init(jax.random.PRNGKey(3))
+    bundle = tiny_models.bundle("lfm2", **overrides)
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(3))
 
     def scaled(path, x):
         name = jax.tree_util.keystr(path)
@@ -78,6 +80,14 @@ def one_layer(params, run, i=0):
     return jax.tree_util.tree_map(lambda a: a[i], params["blocks"][run])
 
 
+# ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
+reference = tiny_models.reference_programs(ref, HP)
+
+
+# the reference's own loss-and-gradient as the harness calls it, under one jit
+REFERENCE = jax.jit(ref.make_loss_and_grad(TINY))
+
+
 # -- the program against the reference ---------------------------------------------
 
 
@@ -89,8 +99,8 @@ def test_float32_program_equals_the_reference_on_loss_and_every_leaf(remat):
     # what the comparison covers: both mixers, the dense layer, a stacked run, group 4, a share
     assert cfg.runs == (("conv", "dense", 1), ("full_attention", "sparse", 1), ("conv", "sparse", 3))
     assert cfg.n_heads // cfg.n_kv_heads == 4 and (cfg.experts_held, cfg.expert_offset, cfg.n_experts) == (4, 4, 16)
-    lp, gp = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    lr, gr = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"])
+    lp, gp = tiny_models.programs(bundle).loss_and_grad(params, batch)
+    lr, gr = REFERENCE(params, batch["tokens"], batch["targets"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     errors = leaf_errors(gp, gr)
     # 8 leaves of the dense conv layer, 13 of the attention expert layer, 10 of the conv expert run, 2 outside
@@ -112,10 +122,9 @@ def test_bf16_program_equals_the_reference_given_its_routes(monkeypatch):
     reads tenths on every leaf, which measures the test and not the model."""
     monkeypatch.setattr(common, "compute_dtype", lambda: jnp.bfloat16)
     bundle, params, batch = seeded(scale=1.0)
-    (got_l, routes), got_g = jax.value_and_grad(
-        lambda p: lfm2.loss_and_routes(p, batch, bundle.config)[::2], has_aux=True)(params)
+    (got_l, routes), got_g = tiny_models.programs(bundle).loss_routes_and_grad(params, batch)
     assert routes.shape == (4, 128, 4)
-    want_l, want_g = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"], routes)
+    want_l, want_g = REFERENCE(params, batch["tokens"], batch["targets"], routes)
     assert abs(float(got_l) - float(want_l)) < 0.002
     assert whole_error(got_g, want_g) < 0.03
     errors = leaf_errors(got_g, want_g)
@@ -153,11 +162,11 @@ def _as_written():
     (its arguments, the program's loss, the routes, the reference's gradient)."""
     if not _AS_WRITTEN:
         bundle, params, batch = seeded(bias=0.1)
-        args = (params, batch["tokens"], batch["targets"], ref.hyper(TINY))
-        program = float(bundle.loss_fn(params, batch, None)[0])
-        right, routes = ref.loss(*args, with_routes=True)
+        args = (params, batch["tokens"], batch["targets"])
+        program = float(tiny_models.programs(bundle).loss(params, batch))
+        right, routes = reference(with_routes=True)(*args)
         assert program == pytest.approx(float(right), rel=1e-5)
-        _AS_WRITTEN.append((args, program, routes, jax.grad(ref.loss)(*args, routes)))
+        _AS_WRITTEN.append((args, program, routes, reference(grad=True)(*args, routes)[1]))
     return _AS_WRITTEN[0]
 
 
@@ -169,29 +178,29 @@ def test_reference_notices_a_term_left_out(variant):
     ``bias_in_weights`` is no mistake at all."""
     args, program, routes, g_right = _as_written()
     if variant == "softmax_for_sigmoid":  # other scores pick other experts: its own routes
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args)
     else:
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, routes, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args, routes)
     assert abs(float(wrong) - program) > 1e-4, variant
     assert whole_error(g_wrong, g_right) > 0.01, variant
     with pytest.raises(ValueError, match="unknown variant"):
-        ref.loss(*args, variant="nothing")
+        ref.loss(*args, HP, variant="nothing")
 
 
 def test_routes_given_equal_routes_computed_and_another_share_is_noticed():
     bundle, params, batch = seeded(bias=0.05)
-    hp = ref.hyper(TINY)
-    loss, routes = ref.loss(params, batch["tokens"], batch["targets"], hp, with_routes=True)
+    loss, routes = reference(with_routes=True)(params, batch["tokens"], batch["targets"])
     assert routes.shape == (4, batch["tokens"].size, 4)  # the four expert layers, in layer order
-    _, _, mine = lfm2.loss_and_routes(params, batch, bundle.config)
+    _, _, mine = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.sort(np.asarray(mine), -1), np.sort(np.asarray(routes), -1))
-    fn = ref.make_loss_and_grad(TINY)
+    fn = REFERENCE
     l0, g0 = fn(params, batch["tokens"], batch["targets"])
     l1, g1 = fn(params, batch["tokens"], batch["targets"], routes)
     assert float(l0) == pytest.approx(float(l1), rel=1e-6) == pytest.approx(float(loss), rel=1e-6)
     errors = leaf_errors(g1, g0)
     assert max(v for k, v in errors.items() if not k.endswith("['bias']")) < 1e-5
-    other = float(ref.loss(params, batch["tokens"], batch["targets"], dict(hp, offset=0)))
+    other = float(jax.jit(lambda p, tok, tgt: ref.loss(p, tok, tgt, dict(HP, offset=0)))(
+        params, batch["tokens"], batch["targets"]))
     assert abs(float(loss) - other) > 1e-4
 
 
@@ -268,8 +277,8 @@ def test_a_token_changes_nothing_before_it(run, kind):
     ffn = "dense" if run == 0 else "sparse"
     at = 40
     other = x.at[0, at].set(x[0, at] + 1.0)
-    a, _, _ = lfm2._layer(p, x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, ffn)
-    b, _, _ = lfm2._layer(p, other, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, ffn)
+    layer = jax.jit(lambda x: lfm2._layer(p, x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, ffn)[0])
+    a, b = layer(x), layer(other)
     diff = np.abs(np.asarray(a - b)).max(axis=-1)[0]
     assert diff[:at].max() == 0.0 and diff[at] > 0
     # a conv layer reaches two positions on (three taps), an attention layer to the end
@@ -300,7 +309,7 @@ def test_a_step_moves_each_bias_by_gamma_by_the_counts_and_nothing_else_differs(
     step = steps.make_train_step(bundle.loss_fn, tx, donate=False, stepped=bundle.stepped)
     new, metrics = step(state, batch)
     assert lfm2.moe.COUNTS not in metrics and float(metrics["aux_loss"]) == 0.0
-    grads, m, _ = steps.grad_half(bundle.loss_fn, state, batch)
+    (_, m), grads = tiny_models.programs(bundle).loss_metrics_and_grad(params, batch)
     counts = np.asarray(m[lfm2.moe.COUNTS])
     assert counts.shape == (4, 16) and np.all(counts.sum(-1) == 2 * 64 * 4)
     # the rule switched off: the same step over a loss that keeps its counts to itself
@@ -420,14 +429,16 @@ def test_the_shares_add_up_to_the_uncut_layer():
     for run, kind in ((1, "full_attention"), (2, "conv")):
         p = one_layer(params, run)
         with jax.default_matmul_precision("highest"):
-            whole, _ = ref._block(p, x, None, hp)
+            block = jax.jit(lambda p: ref._block(p, x, None, hp)[0])
+            whole = block(p)
             no_experts = jax.tree_util.tree_map(jnp.zeros_like, p["experts"])
-            alike, _ = ref._block(dict(p, experts=no_experts), x, None, hp)
+            alike = block(dict(p, experts=no_experts))
         total = alike
         for offset in range(0, 16, 4):
             cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
             held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-            y, stats, _ = lfm2._layer(dict(p, experts=held), x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, "sparse")
+            y, stats, _ = jax.jit(lambda p: lfm2._layer(  # a program a share: the offset is the trace's
+                p, x, lfm2.moe.zero_share_stats(chunks_extra=True), cfg, kind, "sparse"))(dict(p, experts=held))
             assert float(stats["dropped"]) == 0.0
             total = total + (y - alike)  # this share's experts' part alone
         np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)
@@ -458,7 +469,7 @@ def test_the_levelled_bound_computes_what_three_even_shares_compute(monkeypatch,
     read = {}
     for slack in (moe_dispatch.SHARE_ROWS_SLACK_LEVELLED, moe_dispatch.SHARE_ROWS_SLACK):
         monkeypatch.setattr(lfm2, "SHARE_ROWS_SLACK", slack)
-        (loss, m), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(params, batch, None)
+        (loss, m), grads = tiny_models.programs(bundle).loss_metrics_and_grad(params, batch)  # keyed by the slack in force
         cap = moe_dispatch.share_rows_bound(batch["tokens"].size, c.top_k, c.experts_held, c.n_experts, slack)
         held = np.asarray(m[lfm2.moe.COUNTS])[:, c.expert_offset:c.expert_offset + c.experts_held].sum(axis=1)
         chunks = np.ceil(held / cap)

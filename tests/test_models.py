@@ -29,7 +29,7 @@ def test_loss_finite_and_grads_flow(name):
     bundle = get_model(name, **TINY[name])
     params = bundle.init(jax.random.PRNGKey(0))
     batch = bundle.make_batch(jax.random.PRNGKey(1), 4)
-    (loss, metrics), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(bundle.loss_fn, has_aux=True))(
         params, batch, jax.random.PRNGKey(2)
     )
     assert np.isfinite(float(loss))
@@ -57,7 +57,7 @@ class TestLoRA:
         params = bundle.init(jax.random.PRNGKey(0))
         assert set(params) == {"base", "lora"}
         batch = bundle.make_batch(jax.random.PRNGKey(1), 2)
-        grads = jax.grad(lambda p, b, r: bundle.loss_fn(p, b, r)[0])(
+        grads = jax.jit(jax.grad(lambda p, b, r: bundle.loss_fn(p, b, r)[0]))(
             params, batch, jax.random.PRNGKey(2)
         )
         base_gnorm = sum(float(jnp.sum(jnp.abs(g))) for g in jax.tree_util.tree_leaves(grads["base"]))
@@ -131,9 +131,9 @@ class TestGQA:
         params = bundle.init(jax.random.PRNGKey(0))
         assert params["base"]["blocks"]["wk"].shape == (2, 32, 16)  # d_kv = 2*8
         batch = bundle.make_batch(jax.random.PRNGKey(1), 4)
-        (loss, _), grads = jax.value_and_grad(
+        (loss, _), grads = jax.jit(jax.value_and_grad(
             lambda p: bundle.loss_fn(p, batch, jax.random.PRNGKey(2)), has_aux=True
-        )(params)
+        ))(params)
         assert np.isfinite(float(loss))
         # LoRA contract: the base stays FROZEN (zero grads) while the
         # adapters — including the d_kv-shaped v adapter — receive gradient.

@@ -146,7 +146,7 @@ def test_gpt2_moe_grads_reach_experts_and_router():
     bundle = get_model("gpt2_moe", **TINY)
     params = bundle.init(jax.random.PRNGKey(0))
     batch = bundle.make_batch(jax.random.PRNGKey(1), 4)
-    (loss, metrics), grads = jax.value_and_grad(bundle.loss_fn, has_aux=True)(
+    (loss, metrics), grads = jax.jit(jax.value_and_grad(bundle.loss_fn, has_aux=True))(
         params, batch, jax.random.PRNGKey(2)
     )
     assert np.isfinite(float(loss))

@@ -115,9 +115,9 @@ def test_every_gradient_is_the_per_token_sums(act, chunks):
     want_y = per_token_sum(x, idx, gates, *stacks, act)
     np.testing.assert_allclose(np.asarray(share(x, gates, *stacks)), np.asarray(want_y), rtol=2e-5, atol=2e-5)
     assert not np.asarray(want_y[0]).any() and np.asarray(want_y[1]).any()
-    got = jax.grad(lambda *a: jnp.sum(share(*a) * probe), argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
-    want = jax.grad(lambda x, gates, *w: jnp.sum(per_token_sum(x, idx, gates, *w, act) * probe),
-                    argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(share(*a) * probe), argnums=(0, 1, 2, 3, 4)))(x, gates, *stacks)
+    want = jax.jit(jax.grad(lambda x, gates, *w: jnp.sum(per_token_sum(x, idx, gates, *w, act) * probe),
+                            argnums=(0, 1, 2, 3, 4)))(x, gates, *stacks)
     for name, a, b in zip(("x", "top_gates", "w_gate", "w_up", "w_down"), got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5, err_msg=name)
     held = np.asarray((idx >= OFFSET) & (idx < OFFSET + HELD))
@@ -256,9 +256,9 @@ def test_rows_the_products_leave_unwritten_reach_no_result(act, fill, monkeypatc
     if act == "relu":  # counted over the held rows only, whatever the others hold
         gate = jnp.einsum("sd,skdf->skf", x, stacks[0][jnp.clip(idx - OFFSET, 0, HELD - 1)])
         assert int(zeros) == int(jnp.sum((gate <= 0) & held[:, :, None]))
-    got = jax.grad(lambda *a: jnp.sum(share(*a)[0] * probe), argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
-    want = jax.grad(lambda x, gates, *w: jnp.sum(per_token_sum(x, idx, gates, *w, act) * probe),
-                    argnums=(0, 1, 2, 3, 4))(x, gates, *stacks)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(share(*a)[0] * probe), argnums=(0, 1, 2, 3, 4)))(x, gates, *stacks)
+    want = jax.jit(jax.grad(lambda x, gates, *w: jnp.sum(per_token_sum(x, idx, gates, *w, act) * probe),
+                            argnums=(0, 1, 2, 3, 4)))(x, gates, *stacks)
     for name, a, b in zip(("x", "top_gates", "w_gate", "w_up", "w_down"), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5, err_msg=name)

@@ -6,6 +6,7 @@ would compute, the parameter counts, the share's sum, the gate-less share path
 as a dataflow, the step's bias rule and the loop's spans."""
 
 import dataclasses
+import functools
 import json
 import os
 import subprocess
@@ -22,10 +23,10 @@ from benchmark.manifest import Manifest
 from benchmark.references import nemotron_h as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, moe, nemotron_h
 from distributedvolunteercomputing_tpu.ops import moe_dispatch, ssd
+from tests import tiny_models
 
-M = Manifest()
-TINY = M.load_config("tiny-rehearsal-nemotron")
-CFG = M.load_config("nemotron-3-nano-30b-a3b")
+TINY = tiny_models.rehearsal("nemotron")
+CFG = Manifest().load_config("nemotron-3-nano-30b-a3b")
 HP = ref.hyper(TINY)
 
 
@@ -38,9 +39,15 @@ def _highest_precision():
 def tiny(seed=3, scale=3.0, bias=0.05, **overrides):
     """The tiny bundle, its parameters moved off their initial values (every
     matrix times ``scale``, seeded selection biases, the convolution's bias and
-    the norms' scales and D spread out) and two seeded sequences of 40."""
-    bundle = get_model(TINY["registry_model"], **{**TINY["model_overrides"], **overrides})
-    params = bundle.init(jax.random.PRNGKey(seed))
+    the norms' scales and D spread out) and two seeded sequences of 40. One
+    tree a set of arguments: no test writes into it or donates it."""
+    return _tiny(seed, scale, bias, tuple(sorted(overrides.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def _tiny(seed, scale, bias, overrides):
+    bundle = tiny_models.bundle("nemotron", **dict(overrides))
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(seed))
 
     def moved(path, x):
         name = jax.tree_util.keystr(path)
@@ -59,11 +66,29 @@ def tiny(seed=3, scale=3.0, bias=0.05, **overrides):
     return bundle, params, datagen.lm_arrays(5, 2, 40, TINY["vocab_size"])
 
 
+# ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
+reference = tiny_models.reference_programs(ref, HP)
+
+
 def both_sides(bundle, params, batch, variant=None, routes=None):
     tokens, targets = batch["tokens"], batch["targets"]
-    program = jax.value_and_grad(lambda p: bundle.loss_fn(p, {"tokens": tokens, "targets": targets}, None)[0])(params)
-    reference = jax.value_and_grad(lambda p: ref.loss(p, tokens, targets, HP, routes, variant=variant))(params)
-    return program, reference
+    program = tiny_models.programs(bundle).loss_and_grad(params, {"tokens": tokens, "targets": targets})
+    return program, reference(grad=True, variant=variant)(params, tokens, targets, routes)
+
+
+def flat(g):
+    return jnp.concatenate([x.ravel() for x in jax.tree_util.tree_leaves(g)])
+
+
+@functools.lru_cache(maxsize=None)
+def as_written(scale):
+    """(parameters, tokens, targets, the routes the reference chose, its loss
+    and its gradient as one vector given them) at the tiny size, once for all variants."""
+    _, params, batch = tiny(scale=scale)
+    tokens, targets = batch["tokens"], batch["targets"]
+    _, routes = reference(with_routes=True)(params, tokens, targets)
+    loss, grads = reference(grad=True, variant=None)(params, tokens, targets, routes)
+    return params, tokens, targets, routes, float(loss), flat(grads)
 
 
 def rel(a, b):
@@ -97,48 +122,39 @@ def test_float32_program_equals_the_reference_on_loss_and_every_leaf(state):
 def test_reference_notices_a_term_left_out(variant):
     """Every mistaken term changes the loss and the gradient at seeded
     non-initial parameters, against the reference itself with the same routes."""
-    bundle, params, batch = tiny()
-    tokens, targets = batch["tokens"], batch["targets"]
-    _, routes = ref.loss(params, tokens, targets, HP, with_routes=True)
+    params, tokens, targets, routes, lr, gr = as_written(3.0)
     given = None if variant == "softmax_for_sigmoid" else routes
-    lr, gr = jax.value_and_grad(lambda p: ref.loss(p, tokens, targets, HP, routes))(params)
-    lv, gv = jax.value_and_grad(lambda p: ref.loss(p, tokens, targets, HP, given, variant=variant))(params)
-    flat = lambda g: jnp.concatenate([x.ravel() for x in jax.tree_util.tree_leaves(g)])  # noqa: E731
+    lv, gv = reference(grad=True, variant=variant)(params, tokens, targets, given)
     # the bias is small beside a score: 8e-5 on the loss, and the gradient reads it
-    assert abs(float(lv) - float(lr)) > (5e-5 if variant == "bias_in_weights" else 1e-4), (variant, float(lv), float(lr))
-    assert rel(flat(gv), flat(gr)) > 1e-2, (variant, rel(flat(gv), flat(gr)))
+    assert abs(float(lv) - lr) > (5e-5 if variant == "bias_in_weights" else 1e-4), (variant, float(lv), lr)
+    assert rel(flat(gv), gr) > 1e-2, (variant, rel(flat(gv), gr))
 
 
 def test_what_the_check_on_the_initial_parameters_can_and_cannot_see():
     """On ``init``'s own parameters the carried state shows (that is what the
     state-space leaves' initialisation is for), and a selection bias of zero
     hides ``bias_in_weights``, as the configuration file's ``left_out`` says."""
-    bundle, params, batch = tiny(scale=0.0)
-    tokens, targets = batch["tokens"], batch["targets"]
-    _, routes = ref.loss(params, tokens, targets, HP, with_routes=True)
-    flat = lambda g: jnp.concatenate([x.ravel() for x in jax.tree_util.tree_leaves(g)])  # noqa: E731
-    base = flat(jax.grad(lambda p: ref.loss(p, tokens, targets, HP, routes))(params))
-    grad_of = lambda v: flat(jax.grad(lambda p: ref.loss(p, tokens, targets, HP, routes, variant=v))(params))  # noqa: E731
+    params, tokens, targets, routes, _, base = as_written(0.0)
+    grad_of = lambda v, p=params: flat(reference(grad=True, variant=v)(p, tokens, targets, routes)[1])  # noqa: E731
     # 0.0076 here (40 positions, chunks of 16, d 64); with taps at normal(0, 0.02) it read 4e-5
     assert rel(grad_of("no_state_between_chunks"), base) > 2e-3
     assert rel(grad_of("bias_in_weights"), base) == 0.0
     # with the repo's normal(0, 0.02) for A_log and dt_bias the state is gone within a chunk and the same check is blind
     blind = jax.tree_util.tree_map_with_path(
         lambda path, x: jnp.zeros_like(x) + 4.0 if jax.tree_util.keystr(path).endswith("['dt_bias']") else x, params)
-    base_blind = flat(jax.grad(lambda p: ref.loss(p, tokens, targets, HP, routes))(blind))
-    gone = flat(jax.grad(lambda p: ref.loss(p, tokens, targets, HP, routes, variant="no_state_between_chunks"))(blind))
+    base_blind, gone = grad_of(None, blind), grad_of("no_state_between_chunks", blind)
     assert rel(gone, base_blind) < 0.5 * rel(grad_of("no_state_between_chunks"), base)
 
 
 def test_routes_given_equal_routes_computed_and_another_share_is_noticed():
     bundle, params, batch = tiny()
     tokens, targets = batch["tokens"], batch["targets"]
-    own, routes = ref.loss(params, tokens, targets, HP, with_routes=True)
-    assert routes.shape == (3, 80, 3) and float(ref.loss(params, tokens, targets, HP, routes)) == float(own)
-    _, _, program_routes = nemotron_h.loss_and_routes(params, batch, bundle.config)
+    own, routes = reference(with_routes=True)(params, tokens, targets)
+    assert routes.shape == (3, 80, 3) and float(reference()(params, tokens, targets, routes)) == float(own)
+    _, _, program_routes = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.asarray(program_routes), np.asarray(routes))
-    other = dict(HP, offset=8)
-    assert abs(float(ref.loss(params, tokens, targets, other, routes)) - float(own)) > 1e-4
+    other = jax.jit(lambda p, r: ref.loss(p, tokens, targets, dict(HP, offset=8), r))
+    assert abs(float(other(params, routes)) - float(own)) > 1e-4
 
 
 def test_a_token_changes_nothing_before_it():
@@ -146,6 +162,7 @@ def test_a_token_changes_nothing_before_it():
     cfg = bundle.config
     tokens = jnp.asarray(batch["tokens"][:1])
 
+    @jax.jit
     def hidden(tok):
         x = params["wte"][tok]
         runs = [(lambda p, x, s, u=unit, n=n: nemotron_h._unit(p, x, s, cfg, u, n), n, unit[-1] == "E")
@@ -192,7 +209,7 @@ def test_published_sizes_parameter_counts_and_units():
 
 def test_a_cut_that_ends_in_mixers_trains_and_routes_less():
     bundle, params, batch = tiny(scale=0.0, n_layers=5)
-    loss, metrics, routes = nemotron_h.loss_and_routes(params, batch, bundle.config)
+    loss, metrics, routes = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert routes.shape == (2, 80, 3) and np.isfinite(float(loss)) and 0.0 <= float(metrics["ssm_carry_share"]) <= 1.0
 
 
@@ -210,7 +227,7 @@ def test_the_state_space_leaves_are_initialised_as_the_family_does():
     assert np.array_equal(np.asarray(again["blocks"][0]["before"][0]["dt_bias"]), np.asarray(m["dt_bias"]))
     assert not np.any(np.asarray(params["blocks"][0]["bias"]))
     # the metric: with this initialisation some head carries a state across a chunk of 16
-    _, metrics, _ = nemotron_h.loss_and_routes(params, datagen.lm_arrays(5, 2, 40, 512), bundle.config)
+    _, metrics, _ = tiny_models.programs(bundle).loss_and_routes(params, datagen.lm_arrays(5, 2, 40, 512))
     assert 0.0 < float(metrics["ssm_carry_share"]) <= 1.0
 
 
@@ -237,14 +254,15 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_onc
     p["bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(6), (16,))
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 40, 64))
     stats = {**moe.zero_share_stats(act_zeros=True, chunks_extra=True), "ssm_carried": jnp.zeros(())}
-    whole, _, (routes, chosen) = nemotron_h._experts(p, x, stats, cfg)
+    experts = lambda p, c: jax.jit(lambda p: nemotron_h._experts(p, x, stats, c))(p)  # noqa: E731 — a program a share
+    whole, _, (routes, chosen) = experts(p, cfg)
     h = common.rmsnorm(p["ln"], x, cfg.rms_eps).reshape(80, 64)
     shared = nemotron_h._relu2_mlp(p["shared"], h).reshape(2, 40, 64)
     routed = jnp.zeros_like(x)
     for offset in range(0, 16, 4):
         part_cfg = dataclasses.replace(cfg, experts_held=4, expert_offset=offset)
         part = {**p, "experts": jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])}
-        out, part_stats, (part_routes, _) = nemotron_h._experts(part, x, stats, part_cfg)
+        out, part_stats, (part_routes, _) = experts(part, part_cfg)
         assert np.array_equal(np.asarray(part_routes), np.asarray(routes)) and float(part_stats["dropped"]) == 0.0
         routed = routed + (out - x - shared)
     np.testing.assert_allclose(np.asarray(x + shared + routed), np.asarray(whole), rtol=1e-4, atol=1e-5)
@@ -316,8 +334,9 @@ def test_the_gate_less_share_path_is_the_per_token_sum_and_its_products_end_at_t
     up = jnp.einsum("sd,skdf->skf", x, w_up[jnp.clip(idx - OFFSET, 0, HELD - 1)])
     assert int(zeros) == int(jnp.sum((up <= 0) & mine[:, :, None]))     # what a ReLU zeroes, over the held rows
     probe = jax.random.normal(jax.random.PRNGKey(7), (S, D))
-    got = jax.grad(lambda *a: jnp.sum(share(*a)[0] * probe), argnums=(0, 1, 2, 3))(x, gates, w_up, w_down)
-    want = jax.grad(lambda *a: jnp.sum(per_token_sum(a[0], idx, *a[1:]) * probe), argnums=(0, 1, 2, 3))(x, gates, w_up, w_down)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(share(*a)[0] * probe), argnums=(0, 1, 2, 3)))(x, gates, w_up, w_down)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(per_token_sum(a[0], idx, *a[1:]) * probe), argnums=(0, 1, 2, 3)))(
+        x, gates, w_up, w_down)
     for name, a, b in zip(("x", "top_gates", "w_up", "w_down"), got, want):
         assert np.isfinite(np.asarray(a)).all(), name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5, err_msg=name)
@@ -378,7 +397,7 @@ def test_a_width_no_tile_divides_runs_on_megablox_padded_and_is_the_ragged_produ
     want = lambda a, b: jnp.sum(jnp.where(inside, jax.lax.ragged_dot(a, b, sizes), 0.0) * probe)  # noqa: E731
     assert moe_dispatch.grouped_matmul(lhs, rhs, sizes).shape == (512, n)
     assert float(got(lhs, rhs)) == pytest.approx(float(want(lhs, rhs)), rel=1e-5)
-    for a, b in zip(jax.grad(got, (0, 1))(lhs, rhs), jax.grad(want, (0, 1))(lhs, rhs)):
+    for a, b in zip(jax.jit(jax.grad(got, (0, 1)))(lhs, rhs), jax.jit(jax.grad(want, (0, 1)))(lhs, rhs)):
         assert a.shape == b.shape
         np.testing.assert_allclose(np.where(np.isfinite(np.asarray(a)), np.asarray(a), 0.0), np.asarray(b), rtol=1e-4, atol=1e-4)
 
@@ -387,17 +406,16 @@ def test_a_width_no_tile_divides_runs_on_megablox_padded_and_is_the_ragged_produ
 
 
 def test_a_step_moves_each_bias_by_gamma_by_the_counts_and_the_state_space_leaves_by_the_optimizer():
-    from distributedvolunteercomputing_tpu.training.optim import make_optimizer
-    from distributedvolunteercomputing_tpu.training.steps import TrainState, make_train_step
+    from distributedvolunteercomputing_tpu.training.steps import TrainState
 
     bundle, params, batch = tiny(scale=0.0)
-    tx = make_optimizer("adam", lr=1e-3)
+    tx, step = tiny_models.train_step(bundle, "adam", lr=1e-3)
     state = TrainState.create(params, tx, jax.random.PRNGKey(1))
     before = jax.tree_util.tree_map(np.asarray, params)
-    _, metrics, _ = nemotron_h.loss_and_routes(params, batch, bundle.config)
+    _, metrics, _ = tiny_models.programs(bundle).loss_and_routes(params, batch)
     counts = np.asarray(metrics[moe.COUNTS])
     assert counts.shape == (3, 16) and counts.sum() == 3 * 80 * 3
-    state, out = make_train_step(bundle.loss_fn, tx, stepped=bundle.stepped)(state, batch)
+    state, out = step(state, batch)
     assert moe.COUNTS not in out and "ssm_carry_share" in out and "moe_act_zero_share" in out
     want = 0.001 * np.sign(counts.mean(-1, keepdims=True) - counts)
     got = np.concatenate([np.asarray(p["bias"]) for p in state.params["blocks"]])

@@ -45,8 +45,8 @@ def test_grads_match_xla(causal):
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal, 16, 16) * cot)
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
 
@@ -262,8 +262,7 @@ def test_kernel_matches_xla_across_blocks(dtype, tol, t, bq, bk):
         def loss(q, k, v):
             return jnp.sum((core(q, k, v) * cot).astype(jnp.float32))
 
-        out = core(q, k, v)
-        return (out, *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+        return jax.jit(lambda q, k, v: (core(q, k, v), *jax.grad(loss, argnums=(0, 1, 2))(q, k, v)))(q, k, v)
 
     ref = run(lambda q, k, v: attention_core(q, k, v, causal=True))
     got = run(lambda q, k, v: flash_attention(q, k, v, True, bq, bk))
@@ -285,10 +284,10 @@ def test_kernel_at_the_chosen_geometry(causal):
 def test_noncausal_rectangular_grads():
     q, k, v = _qkv(jax.random.PRNGKey(8), b=1, h=2, tq=48, tk=80, d=16)
     cot = jax.random.normal(jax.random.PRNGKey(9), q.shape)
-    g_ref = jax.grad(lambda *a: jnp.sum(attention_core(*a) * cot), argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(attention_core(*a) * cot), argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(
         lambda *a: jnp.sum(flash_attention(*a, False, 32, 32) * cot), argnums=(0, 1, 2)
-    )(q, k, v)
+    ))(q, k, v)
     for a, b in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
 

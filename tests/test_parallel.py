@@ -5,6 +5,8 @@ fallback, and that a dp x tp sharded step computes the SAME numbers as the
 single-device step — sharding must be a pure performance annotation.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,6 +28,17 @@ from distributedvolunteercomputing_tpu.training.optim import make_optimizer
 from distributedvolunteercomputing_tpu.training.steps import TrainState, make_train_step
 
 TINY_GPT2 = dict(vocab=128, max_len=32, d_model=64, n_heads=4, n_layers=2, d_ff=128, remat=False)
+
+
+@functools.lru_cache(maxsize=None)
+def single_device_step():
+    """``(state, metrics)`` after ONE step of the tiny gpt2 on one device
+    (Adam at 1e-3, parameters from key 0, 16 rows from key 1, the state's key
+    2): what five tests hold a sharded step to, compiled and run once."""
+    bundle = get_model("gpt2_small", **TINY_GPT2)
+    tx = make_optimizer("adam", lr=1e-3)
+    state = TrainState.create(bundle.init(jax.random.PRNGKey(0)), tx, jax.random.PRNGKey(2))
+    return make_train_step(bundle.loss_fn, tx, donate=False)(state, bundle.make_batch(jax.random.PRNGKey(1), 16))
 
 
 def test_make_mesh_shapes(eight_devices):
@@ -81,10 +94,7 @@ def test_sharded_step_matches_single_device(eight_devices, dp, tp):
     params = bundle.init(rng)
     batch = bundle.make_batch(jax.random.PRNGKey(1), 16)
 
-    # single-device reference
-    ref_state = TrainState.create(params, tx, jax.random.PRNGKey(2))
-    ref_step = make_train_step(bundle.loss_fn, tx, donate=False)
-    ref_state, ref_metrics = ref_step(ref_state, batch)
+    ref_state, ref_metrics = single_device_step()
 
     mesh = make_mesh(dp=dp, tp=tp)
     state = TrainState.create(params, tx, jax.random.PRNGKey(2))
@@ -397,9 +407,7 @@ def test_sharded_step_with_accum_matches_single_device(eight_devices):
     params = bundle.init(jax.random.PRNGKey(0))
     batch = bundle.make_batch(jax.random.PRNGKey(1), 16)
 
-    ref_state = TrainState.create(params, tx, jax.random.PRNGKey(2))
-    ref_step = make_train_step(bundle.loss_fn, tx, donate=False)
-    ref_state, ref_metrics = ref_step(ref_state, batch)
+    ref_state, ref_metrics = single_device_step()
 
     mesh = make_mesh(dp=2, tp=4)
     state = TrainState.create(params, tx, jax.random.PRNGKey(2))
@@ -458,9 +466,7 @@ class TestZero1:
         params = bundle.init(jax.random.PRNGKey(0))
         batch = bundle.make_batch(jax.random.PRNGKey(1), 16)
 
-        ref_state = TrainState.create(params, tx, jax.random.PRNGKey(2))
-        ref_step = make_train_step(bundle.loss_fn, tx, donate=False)
-        ref_state, ref_metrics = ref_step(ref_state, batch)
+        ref_state, ref_metrics = single_device_step()
 
         mesh = make_mesh(dp=2, tp=4)
         state = TrainState.create(params, tx, jax.random.PRNGKey(2))
@@ -529,9 +535,7 @@ class TestFSDP:
         params = bundle.init(jax.random.PRNGKey(0))
         batch = bundle.make_batch(jax.random.PRNGKey(1), 16)
 
-        ref_state = TrainState.create(params, tx, jax.random.PRNGKey(2))
-        ref_step = make_train_step(bundle.loss_fn, tx, donate=False)
-        ref_state, ref_metrics = ref_step(ref_state, batch)
+        ref_state, ref_metrics = single_device_step()
 
         mesh = make_mesh(dp=2, tp=4)
         state = TrainState.create(params, tx, jax.random.PRNGKey(2))

@@ -1,6 +1,6 @@
 """RPC-argument fuzz: junk args through every registered swarm method.
 
-The transport-level fuzz (test_swarm_base) proves malformed FRAMES can't
+The transport-level fuzz (test_e2e_swarm.py, TestTransport) proves malformed FRAMES can't
 kill a node; this layer proves malformed ARGUMENTS can't either. Handler
 exceptions are contained by the serve loop (they come back as error
 frames), so the property under test is: after a volley of junk calls to

@@ -23,9 +23,17 @@ from benchmark.manifest import Manifest
 from benchmark.references import smallthinker as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, moe as share, smallthinker
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+from tests import tiny_models
 
-TINY = Manifest().load_config("tiny-rehearsal-smallthinker")
+TINY = tiny_models.rehearsal("smallthinker")
 OVERRIDES = TINY["model_overrides"]
+HP = ref.hyper(TINY)
+# the reference's own loss-and-gradient as the harness calls it, under one jit
+REFERENCE = jax.jit(ref.make_loss_and_grad(TINY))
+
+
+# ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
+reference = tiny_models.reference_programs(ref, HP)
 
 
 def zero_stats(cfg):
@@ -45,8 +53,8 @@ def seeded(scale: float = 3.0, **overrides):
     query heads over 2 key/value heads, window 8 under 64 positions, experts
     4..7 of 16 held) with weights scaled up so that every term matters, and
     two seeded sequences."""
-    bundle = get_model("smallthinker_21b_a3b", **{**OVERRIDES, **overrides})
-    params = bundle.init(jax.random.PRNGKey(3))
+    bundle = tiny_models.bundle("smallthinker", **overrides)
+    params = jax.jit(bundle.init)(jax.random.PRNGKey(3))
     params = jax.tree_util.tree_map_with_path(  # every matrix; the norms' gains stay at 1
         lambda path, x: x if "ln_" in jax.tree_util.keystr(path) else x * scale, params)
     rng = np.random.default_rng(0)
@@ -81,8 +89,8 @@ def test_float32_program_equals_the_reference_on_loss_and_every_leaf(remat):
     assert cfg.max_len > cfg.window and cfg.n_heads // cfg.n_kv_heads == 7
     assert [cfg.attention_kind(l) for l in range(4)] == ["global", "sliding", "sliding", "sliding"]
     assert (cfg.experts_held, cfg.expert_offset, cfg.n_experts) == (4, 4, 16)
-    lp, gp = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    lr, gr = ref.make_loss_and_grad(TINY)(params, batch["tokens"], batch["targets"])
+    lp, gp = tiny_models.programs(bundle).loss_and_grad(params, batch)
+    lr, gr = REFERENCE(params, batch["tokens"], batch["targets"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     errors = leaf_errors(gp, gr)
     assert len(errors) == 23  # ten leaves a layer kind, embedding, head, final norm
@@ -112,40 +120,38 @@ def test_reference_notices_a_term_left_out(variant):
     layer, no window, top-6 weights not renormalised) changes the loss and the
     gradient; the program agrees with the reference as written."""
     bundle, params, batch = seeded()
-    hp = ref.hyper(TINY)
-    args = (params, batch["tokens"], batch["targets"], hp)
-    program = float(bundle.loss_fn(params, batch, None)[0])
-    right, routes = ref.loss(*args, with_routes=True)
+    args = (params, batch["tokens"], batch["targets"])
+    program = float(tiny_models.programs(bundle).loss(params, batch))
+    right, routes = reference(with_routes=True)(*args)
     assert program == pytest.approx(float(right), rel=1e-5)
-    g_right = jax.grad(ref.loss)(*args, routes)
+    g_right = reference(grad=True)(*args, routes)[1]
     if variant == "router_after_attention":  # another router input picks other experts: its own routes
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args)
     else:
-        wrong, g_wrong = jax.value_and_grad(ref.loss)(*args, routes, variant=variant)
+        wrong, g_wrong = reference(grad=True, variant=variant)(*args, routes)
     assert abs(float(wrong) - program) > 1e-4, variant
     num = sum(float(jnp.sum((a - b) ** 2)) for a, b in zip(*map(jax.tree_util.tree_leaves, (g_wrong, g_right))))
     den = sum(float(jnp.sum(b ** 2)) for b in jax.tree_util.tree_leaves(g_right))
     assert math.sqrt(num / den) > 0.05, variant
     with pytest.raises(ValueError, match="unknown variant"):
-        ref.loss(*args, variant="nothing")
+        ref.loss(*args, HP, variant="nothing")
 
 
 def test_reference_notices_another_share():
     bundle, params, batch = seeded()
-    hp = ref.hyper(TINY)
-    mine = float(ref.loss(params, batch["tokens"], batch["targets"], hp))
-    other = float(ref.loss(params, batch["tokens"], batch["targets"], dict(hp, offset=0)))
+    mine = float(reference()(params, batch["tokens"], batch["targets"]))
+    other = float(jax.jit(lambda p, tok, tgt: ref.loss(p, tok, tgt, dict(HP, offset=0)))(
+        params, batch["tokens"], batch["targets"]))
     assert abs(mine - other) > 1e-4
 
 
 def test_routes_given_equal_routes_computed():
     bundle, params, batch = seeded()
-    hp = ref.hyper(TINY)
-    loss, routes = ref.loss(params, batch["tokens"], batch["targets"], hp, with_routes=True)
+    loss, routes = reference(with_routes=True)(params, batch["tokens"], batch["targets"])
     assert routes.shape == (4, batch["tokens"].size, 3)  # every layer routes; layer order
-    _, _, mine = smallthinker.loss_and_routes(params, batch, bundle.config)
+    _, _, mine = tiny_models.programs(bundle).loss_and_routes(params, batch)
     assert np.array_equal(np.sort(np.asarray(mine), -1), np.sort(np.asarray(routes), -1))
-    fn = ref.make_loss_and_grad(TINY)
+    fn = REFERENCE
     l0, g0 = fn(params, batch["tokens"], batch["targets"])
     l1, g1 = fn(params, batch["tokens"], batch["targets"], routes)
     assert float(l0) == pytest.approx(float(l1), rel=1e-6) == pytest.approx(float(loss), rel=1e-6)
@@ -159,12 +165,12 @@ def test_two_periods_scan_in_layer_order():
     two = dict(TINY, num_hidden_layers=8, rope_layout=[0, 1, 1, 1] * 2, sliding_window_layout=[0, 1, 1, 1] * 2)
     bundle, params, batch = seeded(n_layers=8)
     ref.check_config(bundle.config, two)
-    lp, gp = jax.value_and_grad(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    lr, gr = ref.make_loss_and_grad(two)(params, batch["tokens"], batch["targets"])
+    (lp, mine), gp = tiny_models.programs(bundle).loss_routes_and_grad(params, batch)
+    lr, gr = jax.jit(ref.make_loss_and_grad(two))(params, batch["tokens"], batch["targets"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     assert max(leaf_errors(gp, gr).values()) < 1e-4
-    _, routes = ref.loss(params, batch["tokens"], batch["targets"], ref.hyper(two), with_routes=True)
-    _, _, mine = smallthinker.loss_and_routes(params, batch, bundle.config)
+    _, routes = jax.jit(lambda p, tok, tgt: ref.loss(p, tok, tgt, ref.hyper(two), with_routes=True))(
+        params, batch["tokens"], batch["targets"])
     assert mine.shape == routes.shape == (8, 128, 3)
     assert np.array_equal(np.sort(np.asarray(mine), -1), np.sort(np.asarray(routes), -1))
     # layer l's tree: blocks["global"][l // 4] or blocks["sliding"][l // 4, l % 4 - 1], on both sides
@@ -280,8 +286,8 @@ def test_a_reglu_share_is_its_experts_part_with_gradients_and_counts_its_zeros(o
     assert int(dropped) == 0 and int(zeros) == want_zeros and 0 < want_zeros < int(jnp.sum(sizes)) * 8
     assert [int(n) for n in sizes] == [int(jnp.sum(idx == e)) for e in range(offset, offset + held)]
     probe = jax.random.normal(jax.random.PRNGKey(9), x.shape)
-    g1 = jax.grad(lambda *a: jnp.sum(share(*a) * probe), argnums=(0, 1, 2, 3, 4))(x, weights, *mine)
-    g2 = jax.grad(lambda *a: jnp.sum(dense(*a) * probe), argnums=(0, 1, 2, 3, 4))(x, weights, *mine)
+    g1 = jax.jit(jax.grad(lambda *a: jnp.sum(share(*a) * probe), argnums=(0, 1, 2, 3, 4)))(x, weights, *mine)
+    g2 = jax.grad(lambda *a: jnp.sum(dense(*a) * probe), argnums=(0, 1, 2, 3, 4))(x, weights, *mine)  # counts in Python
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5)
     # SiLU experts: the same function, no zero count
@@ -334,14 +340,16 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         p = one_layer(params, layer)
         with jax.default_matmul_precision("highest"):
             sliding = kind == "sliding"  # both published lists' entry for the layer
-            whole, _, _ = ref._block(p, x, sliding, sliding, None, hp)
+            block = jax.jit(lambda p: ref._block(p, x, sliding, sliding, None, hp)[0])
+            whole = block(p)
             no_experts = jax.tree_util.tree_map(jnp.zeros_like, p["experts"])
-            alike, _, _ = ref._block(dict(p, experts=no_experts), x, sliding, sliding, None, hp)
+            alike = block(dict(p, experts=no_experts))
         total = alike
         for offset in range(0, 16, 4):
             cfg = dataclasses.replace(bundle.config, experts_held=4, expert_offset=offset)
             held = jax.tree_util.tree_map(lambda a: a[offset:offset + 4], p["experts"])
-            y, stats, _ = smallthinker._layer(dict(p, experts=held), x, zero_stats(cfg), cfg, kind)
+            y, stats, _ = jax.jit(lambda p: smallthinker._layer(  # a program a share: the offset is the trace's
+                p, x, zero_stats(cfg), cfg, kind))(dict(p, experts=held))
             assert float(stats["dropped"]) == 0.0
             total = total + (y - alike)  # this share's experts' part alone
         np.testing.assert_allclose(np.asarray(total), np.asarray(whole), rtol=2e-4, atol=2e-4)

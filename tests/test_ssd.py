@@ -3,6 +3,8 @@ and ops/short_conv.causal_conv (the one-stream convolution with a bias and an
 activation), at a tiny size on the CPU: both forms of each against the plain
 arithmetic they must equal, values and every gradient, float32."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,12 @@ def ssd_sequential(x, dt, a_log, b, c, d):
     xs = tuple(jnp.moveaxis(v.astype(jnp.float32), 1, 0) for v in (x, dt, bh, ch))
     _, y = jax.lax.scan(step, jnp.zeros((z, h, p, b.shape[3]), jnp.float32), xs)
     return jnp.moveaxis(y, 0, 1)
+
+
+@jax.jit
+def sequential_grads(probe, *args):
+    """The recurrence's six gradients against ``probe``, one program for every case that shares a shape."""
+    return jax.grad(lambda *a: jnp.sum(ssd_sequential(*a) * probe), argnums=tuple(range(6)))(*args)
 
 
 def side_by_side(x, b, c):
@@ -72,12 +80,12 @@ def test_the_chunked_scan_is_the_recurrence_position_by_position(form, chunk):
     hand-written backward that carries dS among them, against JAX's
     differentiation of the recurrence one position at a time: 1e-5."""
     args, probe = scan_inputs()
-    want = ssd_sequential(*args)
+    want = jax.jit(ssd_sequential)(*args)
     got, _ = scan(*args, chunk=chunk, form=form)
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5 * scale, rtol=1e-5)
-    g_got = jax.grad(lambda *a: jnp.sum(scan(*a, chunk=chunk, form=form)[0] * probe), argnums=tuple(range(6)))(*args)
-    g_want = jax.grad(lambda *a: jnp.sum(ssd_sequential(*a) * probe), argnums=tuple(range(6)))(*args)
+    g_got = jax.jit(jax.grad(lambda *a: jnp.sum(scan(*a, chunk=chunk, form=form)[0] * probe), argnums=tuple(range(6))))(*args)
+    g_want = sequential_grads(probe, *args)
     for name, a, b in zip(ARGS, g_got, g_want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5 * float(jnp.max(jnp.abs(b))), rtol=1e-5,
                                    err_msg=name)
@@ -93,9 +101,8 @@ def test_one_chunks_backward_is_the_transpose_of_its_forward():
     cum = jnp.cumsum(-jax.nn.softplus(jax.random.normal(k[2], (z, g, r, q))), axis=-1)
     b, c = jax.random.normal(k[3], (z, g, q, n)), jax.random.normal(k[4], (z, g, q, n))
     dy, ds = jax.random.normal(k[5], (z, g, r, q, p)), jax.random.normal(k[6], (z, g, r, p, n))
-    _, pull = jax.vjp(ssd._chunk_fwd, s, xd, cum, b, c)
-    ds_prev, dxd, dcum, db, dc = pull((dy, ds))
-    got = ssd._chunk_bwd(ds, s, xd, cum, b, c, dy)
+    ds_prev, dxd, dcum, db, dc = jax.jit(lambda *a: jax.vjp(ssd._chunk_fwd, *a)[1]((dy, ds)))(s, xd, cum, b, c)
+    got = jax.jit(ssd._chunk_bwd)(ds, s, xd, cum, b, c, dy)
     for name, a, w in zip(("dxd", "dcum", "db", "dc", "ds_prev"), got, (dxd, dcum, db, dc, ds_prev)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=2e-5, atol=2e-5, err_msg=name)
 
@@ -146,6 +153,7 @@ def test_the_token_major_kernels_are_the_plain_form(groups, dtype):
     assert ssd.heads_a_tile(4 // groups, 8) == 4 // groups
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
 
+    @functools.partial(jax.jit, static_argnums=0)
     def run(form):
         y, vjp = jax.vjp(lambda *a: ssd.ssd(*a, g, n, chunk=16, form=form)[0], *args)
         return (y, *vjp(probe))
@@ -251,9 +259,9 @@ def test_causal_conv_both_forms_are_the_sum_over_taps(taps):
     kernel = short_conv.causal_conv_kernel(u, w, bias, 32, True)       # three blocks: both edges
     np.testing.assert_allclose(np.asarray(plain), want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(kernel), want, rtol=1e-5, atol=1e-5)
-    g_plain = jax.grad(lambda *a: jnp.sum(short_conv.causal_conv_xla(*a) * probe), argnums=(0, 1, 2))(u, w, bias)
-    g_kernel = jax.grad(lambda *a: jnp.sum(short_conv.causal_conv_kernel(*a, 32, True) * probe),
-                        argnums=(0, 1, 2))(u, w, bias)
+    g_plain = jax.jit(jax.grad(lambda *a: jnp.sum(short_conv.causal_conv_xla(*a) * probe), argnums=(0, 1, 2)))(u, w, bias)
+    g_kernel = jax.jit(jax.grad(lambda *a: jnp.sum(short_conv.causal_conv_kernel(*a, 32, True) * probe),
+                                argnums=(0, 1, 2)))(u, w, bias)
     for name, a, b in zip(("u", "taps", "bias"), g_kernel, g_plain):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5 * float(jnp.max(jnp.abs(b))),
                                    err_msg=name)

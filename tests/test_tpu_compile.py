@@ -212,6 +212,11 @@ def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     _step_holds_the_groups_its_cell_lists(text, "medium-solo")
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 42 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     """olmoe-solo's step (one layer of OLMoE-1B-7B at its published widths,
     4 x 4,096 tokens): the fused attention core at head dim 128 and T=4,096,
@@ -294,6 +299,11 @@ def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, m
     assert all(ln.count(shape) >= 3 for ln in calls if "dvc_flash_" in ln)  # q, k and v alike
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 59 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_four_chip_step_calls_the_kernel_per_shard(v5e, as_on_the_chip):
     """large-solo-4chip's step (dp=2, tp=2, batch 32, 20 heads): each chip's
     kernel sees its own 10 heads of ONE row stream, 8 of the replica's 16 rows

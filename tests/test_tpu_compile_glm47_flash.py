@@ -23,6 +23,11 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
 )
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 133 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_of_256(v5e, as_on_the_chip, monkeypatch):
     """glm47-flash-solo-8k's step (published layers 0-4 of GLM-4.7-Flash at its
     published widths, eight of 64 experts held, an eighth of the vocabulary,

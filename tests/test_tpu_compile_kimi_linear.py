@@ -21,6 +21,11 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
 )
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 135 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
     """kimi-linear-solo-8k's step (published layers 1-5 of
     Kimi-Linear-48B-A3B-Instruct at its published widths, eight of 256 experts

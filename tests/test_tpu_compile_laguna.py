@@ -8,6 +8,8 @@ host memory a compile) instead of one worker all six, and, being the files
 with the fewest tests, after the files of many short tests.
 """
 
+import pytest
+
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
     _kernel_calls,
@@ -20,6 +22,11 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
 )
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 158 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip, monkeypatch):
     """laguna-solo-8k's step (five layers of Laguna-XS.2 at its published
     widths, sixteen of 256 experts held, an eighth of the vocabulary,

@@ -8,6 +8,8 @@ host memory a compile) instead of one worker all six, and, being the files
 with the fewest tests, after the files of many short tests.
 """
 
+import pytest
+
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
     _kernel_calls,
@@ -20,6 +22,11 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
 )
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 72 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip, monkeypatch):
     """lfm2-solo-8k's step (published layers 0 and 2-5 of LFM2-24B-A2B at its
     published widths, eight of 64 experts held, an eighth of the vocabulary,

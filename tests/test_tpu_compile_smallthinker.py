@@ -10,6 +10,8 @@ with the fewest tests, after the files of many short tests.
 
 import jax.numpy as jnp
 
+import pytest
+
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
     _kernel_calls,
@@ -22,6 +24,11 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
 )
 
 
+# ``slow`` since PR 58: one cell-size compile for a described v5e, 135 s of the tier-1 run's six
+# workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
+# the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
+# model's step: ``python -m pytest -m slow tests/test_tpu_compile*.py`` (the verify skill).
+@pytest.mark.slow
 def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_on_the_chip, monkeypatch):
     """smallthinker-solo-16k's step (one period of SmallThinker-21BA3B at its
     published widths, eight of 64 experts held, an eighth of the vocabulary,
