@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from distributedvolunteercomputing_tpu.models import common, moe
 from distributedvolunteercomputing_tpu.models.common import matrix, swiglu, swiglu_init
 from distributedvolunteercomputing_tpu.ops.attention import (
-    attention_core, merge_heads, rope, split_heads, yarn_inv_freq,
+    Rotary, attention_merged, rope, yarn_inv_freq,
 )
 from distributedvolunteercomputing_tpu.ops.moe_dispatch import share_glu_experts
 
@@ -163,28 +163,39 @@ def init(rng: jax.Array, cfg: LagunaConfig) -> common.Params:
     }
 
 
-def rotary(x: jax.Array, cfg: LagunaConfig, kind: str) -> jax.Array:
-    """The layer kind's rotary embedding of ``x`` [B, H, T, 128]."""
+def rotary_of(cfg: LagunaConfig, kind: str) -> Rotary:
+    """The layer kind's rotary embedding as ``rope`` is told of it."""
     if kind == SLIDING:
-        return rope(x, base=cfg.rope_theta_sliding, layout="half")
+        return Rotary(base=cfg.rope_theta_sliding, layout="half")
     inv_freq = yarn_inv_freq(
         cfg.rotary_dim_full, cfg.rope_theta_full, cfg.yarn_factor, cfg.yarn_original_len,
         cfg.yarn_beta_fast, cfg.yarn_beta_slow)
-    return rope(x, layout="half", rotary_dim=cfg.rotary_dim_full, inv_freq=inv_freq,
-                scale=cfg.yarn_attention_factor)
+    return Rotary(layout="half", rotary_dim=cfg.rotary_dim_full, inv_freq=inv_freq,
+                  scale=cfg.yarn_attention_factor)
+
+
+def rotary(x: jax.Array, cfg: LagunaConfig, kind: str) -> jax.Array:
+    """The layer kind's rotary embedding of ``x`` [B, H, T, 128]."""
+    return rope(x, **rotary_of(cfg, kind)._asdict())
 
 
 def _attention(p: common.Params, x: jax.Array, cfg: LagunaConfig, layer: int) -> jax.Array:
     dtype = x.dtype
     kind, heads = cfg.attention_kind(layer), cfg.heads(layer)
     n = common.rmsnorm(p["ln_attn"], x, cfg.rms_eps)
-    q = rotary(split_heads(n @ p["wq"].astype(dtype), heads), cfg, kind)
-    k = rotary(split_heads(n @ p["wk"].astype(dtype), cfg.n_kv_heads), cfg, kind)
-    v = split_heads(n @ p["wv"].astype(dtype), cfg.n_kv_heads)
-    a = attention_core(q, k, v, causal=True, window=cfg.window if kind == SLIDING else None)
+    a = attention_merged(  # q, k and v as the projections leave them; [B, T, H * 128] back
+        n @ p["wq"].astype(dtype), n @ p["wk"].astype(dtype), n @ p["wv"].astype(dtype),
+        heads, cfg.n_kv_heads, causal=True, window=cfg.window if kind == SLIDING else None,
+        rotary=rotary_of(cfg, kind),
+    )
     gate = jax.nn.sigmoid((n @ p["wg"].astype(dtype)).astype(jnp.float32)).astype(dtype)  # [B, T, H]
-    a = a * gate.transpose(0, 2, 1)[..., None]
-    return x + merge_heads(a) @ p["wo"].astype(dtype)
+    # The gate reaches its head's 128 lanes of the merged array through a 0/1 product
+    # [H, H * 128] (exact: one term a sum), and its gradient comes back through the same
+    # product: on the chip a reshape to [B, T, H, 128] re-tiles the whole array, forward
+    # (a broadcast written out) and backward (a copy before the heads' sums).
+    spread = jnp.repeat(jnp.eye(heads, dtype=dtype), cfg.head_dim, axis=1)
+    a = a * jnp.dot(gate, spread, precision=jax.lax.Precision.HIGHEST)
+    return x + a @ p["wo"].astype(dtype)
 
 
 def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: LagunaConfig,

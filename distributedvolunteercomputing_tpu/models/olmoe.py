@@ -55,9 +55,7 @@ import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common, moe
 from distributedvolunteercomputing_tpu.models.common import matrix, swiglu_init
-from distributedvolunteercomputing_tpu.ops.attention import (
-    attention_core, merge_heads, rope, split_heads,
-)
+from distributedvolunteercomputing_tpu.ops.attention import Rotary, attention_merged
 from distributedvolunteercomputing_tpu.ops.moe_dispatch import dropless_glu_experts
 
 
@@ -149,10 +147,11 @@ def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Olm
         q = common.rmsnorm(p["q_norm"], h @ p["wq"].astype(dtype), cfg.rms_eps)
         k = common.rmsnorm(p["k_norm"], h @ p["wk"].astype(dtype), cfg.rms_eps)
         v = h @ p["wv"].astype(dtype)
-        qh = rope(split_heads(q, cfg.n_heads), base=cfg.rope_theta, layout="half")
-        kh = rope(split_heads(k, cfg.n_heads), base=cfg.rope_theta, layout="half")
-        attn = attention_core(qh, kh, split_heads(v, cfg.n_heads), causal=True)
-        x = x + merge_heads(attn) @ p["wo"].astype(dtype)
+        attn = attention_merged(  # [B, T, H * D] in and out: the projections' own layout
+            q, k, v, cfg.n_heads, cfg.n_heads, causal=True,
+            rotary=Rotary(base=cfg.rope_theta, layout="half"),
+        )
+        x = x + attn @ p["wo"].astype(dtype)
     with jax.named_scope("moe"):
         h = common.rmsnorm(p["ln_mlp"], x, cfg.rms_eps).reshape(b * t, d)
         top_idx, top_gates, probs, logits = route(p["router"], h, cfg.top_k)

@@ -68,9 +68,7 @@ import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common, moe
 from distributedvolunteercomputing_tpu.models.common import matrix, swiglu_init
-from distributedvolunteercomputing_tpu.ops.attention import (
-    attention_core, merge_heads, rope, split_heads,
-)
+from distributedvolunteercomputing_tpu.ops.attention import Rotary, attention_merged
 from distributedvolunteercomputing_tpu.ops.moe_dispatch import plan_share, share_glu_experts
 
 GLOBAL, SLIDING = "global", "sliding"
@@ -184,14 +182,13 @@ def route(p_router: jax.Array, x: jax.Array, top_k: int):
 def _attention(p: common.Params, x: jax.Array, cfg: SmallThinkerConfig, kind: str) -> jax.Array:
     dtype = x.dtype
     n = common.rmsnorm(p["ln_attn"], x, cfg.rms_eps)
-    q = split_heads(n @ p["wq"].astype(dtype), cfg.n_heads)
-    k = split_heads(n @ p["wk"].astype(dtype), cfg.n_kv_heads)
-    v = split_heads(n @ p["wv"].astype(dtype), cfg.n_kv_heads)
-    if kind == SLIDING:  # a global layer has no position encoding at all
-        q = rope(q, base=cfg.rope_theta, layout="half")
-        k = rope(k, base=cfg.rope_theta, layout="half")
-    a = attention_core(q, k, v, causal=True, window=cfg.window if kind == SLIDING else None)
-    return x + merge_heads(a) @ p["wo"].astype(dtype)
+    a = attention_merged(  # q, k and v as the projections leave them; [B, T, H * D] back
+        n @ p["wq"].astype(dtype), n @ p["wk"].astype(dtype), n @ p["wv"].astype(dtype),
+        cfg.n_heads, cfg.n_kv_heads, causal=True, window=cfg.window if kind == SLIDING else None,
+        # a global layer has no position encoding at all
+        rotary=Rotary(base=cfg.rope_theta, layout="half") if kind == SLIDING else None,
+    )
+    return x + a @ p["wo"].astype(dtype)
 
 
 def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: SmallThinkerConfig,

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -83,10 +83,20 @@ _AUTO_FLASH_MIN_T = {"bfloat16": 512, "float32": 512}
 _AUTO_FLASH_HEAD_DIMS = (64, 128, 192, 256)
 
 # Called once per TRACED attention call with (impl, T, D, dtype name, window,
-# key/value heads): the volunteer counts them (swarm.attention_core), so its
-# summary says how many of the step's attention calls took the fused core.
+# key/value heads, layout, rotary): the volunteer counts them
+# (swarm.attention_core), so its summary says how many of the step's attention
+# calls took the fused core, how many of those were handed the projections'
+# own [B, T, H * D] arrays (``layout`` "merged"; "heads" is [B, H, T, D]) and
+# where the call's rotary turn ran (``rotary``): "kernel" where the forward
+# kernel turns each q block on the tile (k, and the backward's resident q and
+# its dq, by one merged-layout pass each beside the kernels), "outside" where
+# ``attention_merged`` ran ``rope`` on [B, H, T, D] before the core, "none"
+# where the call was given no rotary description (a model that turns its own
+# parts before ``attention_core``, GLM's and Kimi's latent keys, reads "none").
 # Trace time only: a compiled step never reaches it.
 _core_observer = None
+# What ``attention_merged`` says of the call it hands to ``attention_core``.
+_observed_rotary = "none"
 
 
 def set_core_observer(fn) -> None:
@@ -260,7 +270,7 @@ def get_attention_impl() -> str:
     return _impl
 
 
-def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None) -> bool:
+def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None, turned: bool = False) -> bool:
     if mask is not None:  # flash path has no additive-mask support
         return False
     tq, tk, d = q.shape[-2], k.shape[-2], q.shape[-1]
@@ -288,7 +298,7 @@ def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None)
             return False
     from distributedvolunteercomputing_tpu.ops.pallas_attention import choose_blocks
 
-    if choose_blocks(tq, tk, d, q.dtype, window) is None:  # one head does not fit VMEM
+    if choose_blocks(tq, tk, d, q.dtype, window, turned) is None:  # one head (and a turned call's tables) does not fit VMEM
         return False
     return _shard_axes(q, k) is not None
 
@@ -327,17 +337,114 @@ def _flash_per_shard(
         # blocks: pallas_attention.choose_blocks of the (shard's) shape
         return flash_attention(q, k, v, causal=causal, window=window)
 
-    if _mesh_ctx is None or _mesh_ctx.size == 1:
-        return core(q, k, v)
     spec = P(*_shard_axes(q, k), None, None)
+    return _per_shard(core, (q, k, v), (spec, spec, spec), spec)
+
+
+def _per_shard(core, args, in_specs, out_spec):
+    """``core(*args)`` on each chip's own part under the traced step's mesh
+    (the specs are not read where the step has one chip)."""
+    if _mesh_ctx is None or _mesh_ctx.size == 1:
+        return core(*args)
     # Inside an enclosing shard_map the mesh to name is the context's own
     # (the same axes, some already manual).
     ctx = jax.sharding.get_abstract_mesh()
     outer = set(ctx.manual_axes)
     return jax.shard_map(
-        core, mesh=ctx if outer else _mesh_ctx, in_specs=(spec, spec, spec),
-        out_specs=spec, axis_names=set(_mesh_ctx.axis_names) - outer, check_vma=False,
-    )(q, k, v)
+        core, mesh=ctx if outer else _mesh_ctx, in_specs=in_specs,
+        out_specs=out_spec, axis_names=set(_mesh_ctx.axis_names) - outer, check_vma=False,
+    )(*args)
+
+
+class Rotary(NamedTuple):
+    """A model's rotary embedding as ``rope`` is told of it."""
+
+    base: float = 10000.0
+    layout: str = "interleaved"
+    rotary_dim: Optional[int] = None
+    inv_freq: Optional[jax.Array] = None
+    scale: float = 1.0
+
+
+def attention_merged(
+    q: jax.Array,  # [B, T, H * D] as the query projection made it
+    k: jax.Array,  # [B, T, Hkv * D]
+    v: jax.Array,  # [B, T, Hkv * Dv]
+    heads: int,
+    kv_heads: int,
+    causal: bool = False,
+    window: Optional[int] = None,
+    rotary: Optional[Rotary] = None,  # turns q and k (both by the same positions 0..T-1)
+) -> jax.Array:
+    """Attention from the projections' arrays to the output projection's,
+    [B, T, H * Dv]: ``merge_heads(attention_core(rope(split_heads(q)),
+    rope(split_heads(k)), split_heads(v)))``, which is what runs wherever the
+    shapes do not allow better. Where the call would take the flash kernel
+    (``_route_to_flash``), both head widths are whole 128-lane tiles and the
+    rotary layout is "half" (or there is none), the kernels read q, k and v and
+    write the output where they lie, a head being a block of the last axis, and
+    the rotary pairs are turned by a roll along a head's own lanes (q on the
+    forward kernel's tile; k, and the backward's q and dq, by one pass each in
+    the same layout): no transpose, no array D/2 wide and no float32 copy of a
+    head-shaped array between a projection and its kernel. The shapes decide;
+    nothing else does."""
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import LANES
+
+    global _observed_rotary
+    b, t, _ = q.shape
+    d, dv = q.shape[-1] // heads, v.shape[-1] // kv_heads
+
+    # what ``_route_to_flash`` and ``_shard_axes`` read of q and k by head, [B, n, T, D]
+    qs, ks = (jax.ShapeDtypeStruct((b, n, x.shape[1], d), x.dtype) for n, x in ((heads, q), (kv_heads, k)))
+    in_place = (
+        _seq_ctx is None and d % LANES == 0 and dv % LANES == 0
+        and (rotary is None or rotary.layout == "half")
+        and (window is None or (causal and window >= 1))
+        and _route_to_flash(qs, ks, causal, None, window, turned=rotary is not None)
+    )
+    if not in_place:
+        qh, kh, vh = split_heads(q, heads), split_heads(k, kv_heads), split_heads(v, kv_heads)
+        if rotary is not None:
+            qh, kh = rope(qh, **rotary._asdict()), rope(kh, **rotary._asdict())
+        prev, _observed_rotary = _observed_rotary, "none" if rotary is None else "outside"
+        try:
+            return merge_heads(attention_core(qh, kh, vh, causal=causal, window=window))
+        finally:
+            _observed_rotary = prev
+    if _core_observer is not None:
+        _core_observer(
+            "flash", t, d, jnp.dtype(q.dtype).name, window, kv_heads,
+            "merged", "none" if rotary is None else "kernel",
+        )
+    return _flash_merged_per_shard(q, k, v, d, _shard_axes(qs, ks), causal, window, rotary)
+
+
+def _flash_merged_per_shard(q, k, v, d: int, axes, causal, window, rotary: Optional[Rotary]):
+    """``_flash_per_shard`` for the merged layout, heads of ``d`` lanes: a
+    chip's heads are a contiguous part of the last axis (``tp`` of ``axes`` =
+    ``_shard_axes`` cuts whole heads, the key/value heads with their query
+    heads), the tables are whole on every chip."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    tables, rotary_dim = (), None
+    if rotary is not None:
+        rotary_dim = d if rotary.rotary_dim is None else rotary.rotary_dim
+        tables = pa.rotary_tables(
+            q.shape[1], d, rotary.base, rotary_dim, rotary.inv_freq, rotary.scale)
+
+    def core(q, k, v, *tables):  # traced once, at one chip's shapes
+        shard = (q.shape[-1] // d, k.shape[-1] // d)  # this chip's query and key/value heads
+        if _kept_ctx is not None:
+            _kept_ctx.append(pa.kept_bytes(q, k, window, v, shard))
+        cos, sin = tables if tables else (None, None)
+        if tables:
+            k = pa.rotary_merged(k, cos, sin, rotary_dim)
+        return pa.flash_attention_merged(q, k, v, cos, sin, shard, causal, window, rotary_dim)
+
+    spec = P(axes[0], None, axes[1])
+    return _per_shard(core, (q, k, v, *tables), (spec, spec, spec) + (P(None, None),) * len(tables), spec)
 
 
 def attention_core(
@@ -385,7 +492,7 @@ def attention_core_local(
     if _core_observer is not None:
         _core_observer(
             "flash" if flash else "xla", q.shape[-2], q.shape[-1], jnp.dtype(q.dtype).name,
-            window, h_kv,
+            window, h_kv, "heads", _observed_rotary,
         )
     if flash:
         return _flash_per_shard(q, k, v, causal, window)

@@ -42,6 +42,28 @@ Design (speeds: PERF.md, Findings of PR 27, measured on a TPU v5e):
   under keys of 128 + 64): the v, o, dO and dv blocks and the output's
   accumulator take it, the scores and dq / dk the key width. With equal widths
   the program is what it was.
+- **The projections' own layout** (``flash_attention_merged``, PR 59): where a
+  head is whole 128-lane tiles, block ``h`` of the last axis of ``[B, T, H * D]``
+  IS head ``h``, so the same two kernels read q, k, v (and dO) where the
+  projections leave them and write o, dq, dk, dv where the next product reads
+  them: ``_head_spec`` gives either index, the kernel bodies see ``[rows, D]``
+  both ways. No transpose exists around the call. What stood around it by
+  head is done without leaving the layout: delta by a small kernel that sums
+  each head along its own lanes (``dvc_attn_delta``; XLA's reduce would first
+  re-tile both arrays by head), a group's dk and dv written as ``[B, group, T,
+  Hkv * D]`` so that the group's sum is over a leading axis.
+- **The rotary turn by a lane roll** ("half" pairs, ``_turn``): ``x * cos +
+  roll(x) * sin`` in float32 on a ``[rows, D]`` tile, tables ``[T, D]`` made
+  once a call (``rotary_tables``: ones and zeros on the lanes a partial rotary
+  passes through, the scale folded in), rounded to the input dtype where
+  ``ops/attention.rope`` rounds. Each element is turned once a pass: the
+  forward kernel turns the q block it has taken up (its grid visits a block
+  once); k, and in the backward the resident q and its dq, take ONE
+  merged-layout pass each beside the kernels (``dvc_rotary`` /
+  ``dvc_rotary_back``: bfloat16 in and out, never an array D/2 wide, never
+  float32 in HBM), because the kernel that holds them resident would turn them
+  at every visit, or hold whole float32 tables: 16 MiB at T=16,384, where the
+  backward already stands at 61.0 of its 64 MiB (PERF.md, Findings of PR 59).
 - **Matmuls run in the INPUT dtype** with f32 accumulation, so bf16 inputs
   hit the MXU at its bf16 rate. Scores, statistics and accumulators are
   f32; the probabilities (and ds) are rounded to the input dtype before
@@ -91,13 +113,15 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def vmem_bytes(tq: int, tk: int, d: int, dtype, block_q: int, block_k: int) -> int:
+def vmem_bytes(tq: int, tk: int, d: int, dtype, block_q: int, block_k: int, turned: bool = False) -> int:
     """Upper estimate of the backward kernel's VMEM (the larger of the two):
     the resident head (q, dO, dq, double-buffered; the f32 dq accumulator),
     the streamed k/v/dk/dv blocks and their accumulators, the statistics'
     rows (a [1, bq] row fills 8 sublanes) and the score-shaped f32
     temporaries (s, p, dp, ds and two rounded copies). The head dim pads to
-    128 lanes in VMEM."""
+    128 lanes in VMEM. A call that turns its q (``turned``) adds the forward's
+    two float32 table blocks, double-buffered, on top: the backward is handed
+    a turned q and takes no table, so this bounds both kernels."""
     item = jnp.dtype(dtype).itemsize
     dl = _round_up(d, LANES)
     tq_p, bk = _round_up(tq, block_q), min(block_k, _round_up(tk, 8))
@@ -105,14 +129,16 @@ def vmem_bytes(tq: int, tk: int, d: int, dtype, block_q: int, block_k: int) -> i
     streamed = 4 * 2 * bk * dl * item + 2 * bk * dl * 4
     stats = 2 * 2 * 8 * tq_p * 4
     tiles = 6 * block_q * bk * 4
-    return resident + streamed + stats + tiles
+    tables = 2 * 2 * block_q * dl * 4 if turned else 0
+    return resident + streamed + stats + tiles + tables
 
 
 def choose_blocks(
-    tq: int, tk: int, d: int, dtype, window: Optional[int] = None
+    tq: int, tk: int, d: int, dtype, window: Optional[int] = None, turned: bool = False
 ) -> Optional[Tuple[int, int]]:
     """(block_q, block_k) for a shape, or None where the kernel cannot hold
-    one head in VMEM. With a ``window`` the blocks are no larger than the
+    one head in VMEM (``turned``: with the rotary tables' blocks of a call that
+    turns its q). With a ``window`` the blocks are no larger than the
     window where the sequence allows it: every block a query block visits is
     then crossed by an edge (two blocks visited for one block's worth of pairs
     inside the band), and still smaller blocks lost to the per-block cost.
@@ -138,17 +164,42 @@ def choose_blocks(
         return _round_up(t, 16) if t <= PREFERRED_BLOCK else PREFERRED_BLOCK
 
     bq, bk = pick(tq), pick(tk)
-    if vmem_bytes(tq, tk, d, dtype, bq, bk) > VMEM_BUDGET_BYTES:
+    if vmem_bytes(tq, tk, d, dtype, bq, bk, turned) > VMEM_BUDGET_BYTES:
         return None
     return bq, bk
 
 
-def _pad_seq(x: jax.Array, block: int) -> jax.Array:
-    t = x.shape[2]
-    pad = (-t) % block
+def _pad_seq(x: jax.Array, block: int, axis: int = 2) -> jax.Array:
+    pad = (-x.shape[axis]) % block
     if pad == 0:
         return x
-    return jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    return jnp.pad(x, [(0, pad if a == axis else 0) for a in range(x.ndim)])
+
+
+def _dims(q: jax.Array, k: jax.Array, v: jax.Array, heads: Optional[Tuple[int, int]]):
+    """(B, H, Hkv, Tq, Tk, D, Dv) of a call by head, ``[B, H, T, D]`` (``heads``
+    None), or merged, ``[B, T, H * D]`` with ``heads`` = (H, Hkv)."""
+    if heads is None:
+        b, h, tq, d = q.shape
+        return b, h, k.shape[1], tq, k.shape[2], d, v.shape[3]
+    b, tq, _ = q.shape
+    h, h_kv = heads
+    return b, h, h_kv, tq, k.shape[1], q.shape[2] // h, v.shape[2] // h_kv
+
+
+def _head_spec(merged: bool, rows: int, width: int, where) -> pl.BlockSpec:
+    """``rows`` x ``width`` of one head as a block of an array by head,
+    ``[B, H, T, D]``, or merged, ``[B, T, H * D]``: a head whose width is
+    whole lanes is block ``head`` of the merged array's last axis, so the
+    kernels read a projection's result, and write what the output projection
+    reads, where they lie. ``where(i, j, g)`` gives (batch, head, row block)."""
+    if merged:
+        def index(i, j, g):
+            b, h, r = where(i, j, g)
+            return b, r, h
+
+        return pl.BlockSpec((None, rows, width), index)
+    return pl.BlockSpec((None, None, rows, width), lambda i, j, g: (*where(i, j, g), 0))
 
 
 def _dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
@@ -194,19 +245,143 @@ def _mask_scores(s, q0, kpos: jax.Array, q_axis: int, *, causal: bool, tk_valid:
 
 
 # ---------------------------------------------------------------------------
+# the rotary turn ("half" layout: a frequency pairs lanes i and i + R / 2)
+# ---------------------------------------------------------------------------
+
+
+def rotary_tables(
+    t: int, d: int, base: float = 10000.0, rotary_dim: Optional[int] = None,
+    inv_freq: Optional[jax.Array] = None, scale: float = 1.0,
+) -> Tuple[jax.Array, jax.Array]:
+    """(cos, sin), each ``[T, D]`` float32, of ``ops/attention.rope``'s "half"
+    layout with the same ``base``, ``rotary_dim``, ``inv_freq`` and ``scale``,
+    laid out for ``_turn``: a lane's cosine, and its partner's sine with the
+    sign the lane takes it with (minus on the first half of the rotated lanes);
+    ones and zeros on the lanes a partial rotary passes through. The values are
+    ``rope``'s own (same float32 products), made once a call by XLA."""
+    r = d if rotary_dim is None else rotary_dim
+    freqs = base ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r) if inv_freq is None else inv_freq
+    angles = jnp.arange(t)[:, None].astype(jnp.float32) * freqs[None, :]  # [T, R/2]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    rest = jnp.ones((t, d - r), jnp.float32)
+    return (
+        jnp.concatenate([cos, cos, rest], axis=-1),
+        jnp.concatenate([-sin, sin, 0.0 * rest], axis=-1),
+    )
+
+
+def _turn(x: jax.Array, cos: jax.Array, sin: jax.Array, rotary_dim: int, back: bool = False):
+    """One head's rows ``x`` [rows, D] float32 turned by the tables' rows:
+    ``x * cos + partner(x) * sin``, the partner reached by a roll along the
+    lanes (no array D/2 wide). With R = D one roll by D/2 serves both halves;
+    a partial rotary's first half looks R/2 up and its second R/2 down (the
+    lanes past R meet a sine of 0). ``back`` is the transpose, which is the
+    turn the other way (times the tables' scale): a cotangent's way home."""
+    d, half = x.shape[-1], rotary_dim // 2
+    if rotary_dim == d:
+        partner = pltpu.roll(x, half, 1)
+    else:
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        partner = jnp.where(lane < half, pltpu.roll(x, d - half, 1), pltpu.roll(x, half, 1))
+    return x * cos - partner * sin if back else x * cos + partner * sin
+
+
+def _turn_kernel(x_ref, cos_ref, sin_ref, o_ref, *, d, rotary_dim, back):
+    cos, sin = cos_ref[...], sin_ref[...]
+    for h in range(x_ref.shape[-1] // d):  # the block's heads side by side, each on its own lanes
+        x = x_ref[:, h * d:(h + 1) * d].astype(jnp.float32)
+        o_ref[:, h * d:(h + 1) * d] = _turn(x, cos, sin, rotary_dim, back).astype(o_ref.dtype)
+
+
+# Heads a block of the passes beside the kernels: 8 x 128 lanes x 512 rows of
+# bfloat16 is 1 MiB in and 1 MiB out a grid step, rows of 2 KiB in HBM.
+_TURN_HEADS, _TURN_ROWS = 8, 512
+
+
+def _heads_a_block(h: int) -> int:
+    """The most heads, up to ``_TURN_HEADS``, that divide ``h``."""
+    return max(n for n in range(1, _TURN_HEADS + 1) if h % n == 0)
+
+
+def _turn_merged(x: jax.Array, cos: jax.Array, sin: jax.Array, rotary_dim: int, back: bool,
+                 interpret: Optional[bool] = None) -> jax.Array:
+    return _turn_pass(x, cos, sin, rotary_dim, back, _interpreted(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("rotary_dim", "back", "interpret"))
+def _turn_pass(x: jax.Array, cos: jax.Array, sin: jax.Array, rotary_dim: int, back: bool, interpret: bool) -> jax.Array:
+    """``x`` [B, T, H * D] with every head turned (or turned ``back``), as ONE
+    pass in the merged layout: the input's dtype in and out, float32 on the
+    tile, rounded once. The tables' rows are fetched once a row block (the
+    heads are the innermost grid axis). Jitted so that a step's passes of one
+    shape (a layer's k forward and recomputed, every layer of a kind) are
+    traced and lowered once: start-up is part of what a donor pays."""
+    b, t, hd = x.shape
+    d = cos.shape[-1]
+    h = hd // d
+    per = _heads_a_block(h)
+    rows = min(_TURN_ROWS, _round_up(t, 16))
+    xp, cosp, sinp = _pad_seq(x, rows, 1), _pad_seq(cos, rows, 0), _pad_seq(sin, rows, 0)
+    block = pl.BlockSpec((None, rows, per * d), lambda i, r, j: (i, r, j))
+    table = pl.BlockSpec((rows, d), lambda i, r, j: (r, 0))
+    out = pl.pallas_call(
+        functools.partial(_turn_kernel, d=d, rotary_dim=rotary_dim, back=back),
+        grid=(b, xp.shape[1] // rows, h // per),
+        in_specs=[block, table, table],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
+        interpret=interpret,
+        name="dvc_rotary_back" if back else "dvc_rotary",
+    )(xp, cosp, sinp)
+    return out[:, :t]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def rotary_merged(x: jax.Array, cos: jax.Array, sin: jax.Array, rotary_dim: int,
+                  interpret: Optional[bool] = None) -> jax.Array:
+    """``ops/attention.rope`` ("half" layout) of ``x`` [B, T, H * D] where the
+    projection left it, by ``rotary_tables``' tables: one pass, and one pass
+    back for its cotangent."""
+    return _turn_merged(x, cos, sin, rotary_dim, False, interpret)
+
+
+def _rotary_fwd(x, cos, sin, rotary_dim, interpret):
+    return _turn_merged(x, cos, sin, rotary_dim, False, interpret), (cos, sin)
+
+
+def _rotary_bwd(rotary_dim, interpret, tables, g):
+    cos, sin = tables
+    return _turn_merged(g, cos, sin, rotary_dim, True, interpret), jnp.zeros_like(cos), jnp.zeros_like(sin)
+
+
+rotary_merged.defvjp(_rotary_fwd, _rotary_bwd)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale, causal, block_q, block_k, tk_valid, n_k, window=None,
+    q_ref, k_ref, v_ref, *rest,
+    scale, causal, block_q, block_k, tk_valid, n_k, window=None, rotary_dim=None,
 ):
+    if rotary_dim is not None:  # this q block's rows of the rotary tables, [bq, D] float32
+        cos_ref, sin_ref, *rest = rest
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     iq = pl.program_id(2)
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
     q = q_ref[...]  # [bq, D], input dtype
+    if rotary_dim is not None:
+        # A q block is taken up once a pass, so its pairs are turned here, on the
+        # tile, and rounded where ``ops/attention.rope`` rounds them.
+        q = _turn(q.astype(jnp.float32), cos_ref[...], sin_ref[...], rotary_dim).astype(q.dtype)
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
         window=window,
@@ -262,38 +437,47 @@ def _fwd_kernel(
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     bq: int, bk: int, interpret: bool, window: Optional[int] = None,
+    heads: Optional[Tuple[int, int]] = None, tables=None, rotary_dim: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(out [B, H, Tq, D], lse [B, H, nq, 1, bq] over the padded rows)."""
-    b, h, tq, d = q.shape
-    tk, dv = k.shape[2], v.shape[3]  # the value head's own width: o's, and the accumulator's
-    group = h // k.shape[1]  # query heads per key/value head
+    """(out [B, H, Tq, Dv], lse [B, H, nq, 1, bq] over the padded rows). With
+    ``heads`` = (H, Hkv) q, k, v and out are merged, ``[B, T, H * D]``; with
+    ``tables`` (cos, sin ``[Tq, D]`` of ``rotary_tables``) the kernel turns q."""
+    merged, seq = heads is not None, 2 if heads is None else 1
+    b, h, h_kv, tq, tk, d, dv = _dims(q, k, v, heads)  # dv: the value head's own width, o's and the accumulator's
+    group = h // h_kv  # query heads per key/value head
     scale = 1.0 / (d ** 0.5)
-    qp, kp, vp = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk)
-    tq_p, tk_p = qp.shape[2], kp.shape[2]
+    qp, kp, vp = _pad_seq(q, bq, seq), _pad_seq(k, bk, seq), _pad_seq(v, bk, seq)
+    tq_p, tk_p = qp.shape[seq], kp.shape[seq]
     n_q, n_k = tq_p // bq, tk_p // bk
 
-    qspec = pl.BlockSpec((None, None, bq, d), lambda i, j, iq: (i, j, iq, 0))
-    if group == 1:
-        kvspec = pl.BlockSpec((None, None, tk_p, d), lambda i, j, iq: (i, j, 0, 0))
-    else:
-        kvspec = pl.BlockSpec((None, None, tk_p, d), lambda i, j, iq: (i, j // group, 0, 0))
+    def at_q(i, j, iq):
+        return i, j, iq
+
+    def at_kv(i, j, iq):  # a group's query heads read one key/value head
+        return i, (j if group == 1 else j // group), 0
+
+    qspec, kvspec = _head_spec(merged, bq, d, at_q), _head_spec(merged, tk_p, d, at_kv)
     ospec, vspec = qspec, kvspec
     if dv != d:  # a value head narrower (or wider) than the key head: v and o in blocks of its width
-        ospec = pl.BlockSpec((None, None, bq, dv), qspec.index_map)
-        vspec = pl.BlockSpec((None, None, tk_p, dv), kvspec.index_map)
+        ospec, vspec = _head_spec(merged, bq, dv, at_q), _head_spec(merged, tk_p, dv, at_kv)
+    turned = []
+    if tables is not None:
+        rows = pl.BlockSpec((bq, d), lambda i, j, iq: (iq, 0))
+        turned = [(_pad_seq(t, bq, 0), rows) for t in tables]
     out, lse = pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_k=n_k, window=window,
+            rotary_dim=None if tables is None else rotary_dim,
         ),
         grid=(b, h, n_q),
-        in_specs=[qspec, kvspec, vspec],
+        in_specs=[qspec, kvspec, vspec] + [spec for _, spec in turned],
         out_specs=[
             ospec,
             pl.BlockSpec((None, None, None, 1, bq), lambda i, j, iq: (i, j, iq, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq_p, dv), q.dtype),
+            jax.ShapeDtypeStruct((b, tq_p, h * dv) if merged else (b, h, tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, n_q, 1, bq), jnp.float32),
         ],
         scratch_shapes=[
@@ -303,17 +487,46 @@ def _flash_forward(
         ],
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel"),
-            vmem_bytes(tq, tk, d, q.dtype, bq, bk),
+            vmem_bytes(tq, tk, d, q.dtype, bq, bk, tables is not None),
         ),
         interpret=interpret,
         name="dvc_flash_fwd" if window is None else "dvc_flash_win_fwd",
-    )(qp, kp, vp)
-    return out[:, :, :tq], lse
+    )(qp, kp, vp, *[t for t, _ in turned])
+    return (out[:, :tq] if merged else out[:, :, :tq]), lse
 
 
 # ---------------------------------------------------------------------------
 # backward: one kernel per k-block, the head's q / dO / statistics resident
 # ---------------------------------------------------------------------------
+
+
+def _delta_kernel(do_ref, o_ref, delta_ref, *, dv):
+    for h in range(do_ref.shape[-1] // dv):  # the block's heads side by side, each on its own lanes
+        prod = do_ref[:, h * dv:(h + 1) * dv].astype(jnp.float32) * o_ref[:, h * dv:(h + 1) * dv].astype(jnp.float32)
+        col = jnp.sum(prod, axis=1, keepdims=True)  # [bq, 1]: a row in HBM, as the forward's lse
+        delta_ref[h] = jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[0:1, :]
+
+
+@functools.partial(jax.jit, static_argnames=("h", "bq", "interpret"))
+def _delta_merged(do: jax.Array, out: jax.Array, h: int, bq: int, interpret: bool) -> jax.Array:
+    """delta_i = sum_d dO_i O_i of merged [B, T, H * Dv] arrays (T whole
+    q-blocks) as the backward's rows [B, H, nq, 1, bq]: each head summed along
+    its own lanes, where XLA's reduce would first re-tile both arrays by head."""
+    b, t, hd = do.shape
+    dv = hd // h
+    per = _heads_a_block(h)
+    block = pl.BlockSpec((None, bq, per * dv), lambda i, r, j: (i, r, j))
+    return pl.pallas_call(
+        functools.partial(_delta_kernel, dv=dv),
+        grid=(b, t // bq, h // per),
+        in_specs=[block, block],
+        out_specs=pl.BlockSpec((None, per, None, 1, bq), lambda i, r, j: (i, j, r, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, t // bq, 1, bq), jnp.float32),
+        compiler_params=_compiler_params(
+            interpret, ("parallel", "parallel", "parallel"), 2 * 2 * bq * per * dv * 4),
+        interpret=interpret,
+        name="dvc_attn_delta",
+    )(do, out)
 
 
 def _bwd_kernel(
@@ -387,33 +600,52 @@ def _bwd_kernel(
 
 
 def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, g,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, heads: Optional[Tuple[int, int]] = None):
+    """(dq, dk, dv) in the layout of q, k, v: by head, or merged with ``heads``
+    = (H, Hkv). The q of ``residuals`` is the one the scores were taken of (a
+    rotary call hands its q turned: this kernel takes no table)."""
     q, k, v, out, lse = residuals
     do = g
-    b, h, tq, d = q.shape
-    h_kv, tk, dv = k.shape[1], k.shape[2], v.shape[3]
+    merged, seq = heads is not None, 2 if heads is None else 1
+    b, h, h_kv, tq, tk, d, dv = _dims(q, k, v, heads)
     group = h // h_kv
     scale = 1.0 / (d ** 0.5)
 
-    # delta_i = sum_d dO_i O_i — the softmax-jacobian diagonal term.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-
-    qp, kp, vp, dop = _pad_seq(q, bq), _pad_seq(k, bk), _pad_seq(v, bk), _pad_seq(do, bq)
-    tq_p, tk_p = qp.shape[2], kp.shape[2]
+    qp, kp, vp, dop = _pad_seq(q, bq, seq), _pad_seq(k, bk, seq), _pad_seq(v, bk, seq), _pad_seq(do, bq, seq)
+    tq_p, tk_p = qp.shape[seq], kp.shape[seq]
     n_q, n_k = tq_p // bq, tk_p // bk
-    delta = jnp.pad(delta, ((0, 0), (0, 0), (0, tq_p - tq))).reshape(b, h, n_q, 1, bq)
+    # delta_i = sum_d dO_i O_i — the softmax-jacobian diagonal term.
+    if merged:
+        delta = _delta_merged(dop, _pad_seq(out, bq, seq), h, bq, interpret)
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, tq_p - tq))).reshape(b, h, n_q, 1, bq)
 
-    head = pl.BlockSpec((None, None, tq_p, d), lambda i, j, ik: (i, j, 0, 0))
+    def at_head(i, j, ik):
+        return i, j, 0
+
+    def at_block(i, j, ik):
+        return i, j, ik
+
+    def at_kv(i, j, ik):  # a group's query heads read one K/V head and each writes its own dk, dv
+        return i, (j if group == 1 else j // group), ik
+
+    head = _head_spec(merged, tq_p, d, at_head)
     rows = pl.BlockSpec((None, None, n_q, 1, bq), lambda i, j, ik: (i, j, 0, 0, 0))
-    kblock = pl.BlockSpec((None, None, bk, d), lambda i, j, ik: (i, j, ik, 0))
-    # A group's query heads read one K/V head and each writes its own dk, dv.
-    kv_in = kblock if group == 1 else pl.BlockSpec(
-        (None, None, bk, d), lambda i, j, ik: (i, j // group, ik, 0))
+    kblock, kv_in = _head_spec(merged, bk, d, at_block), _head_spec(merged, bk, d, at_kv)
     vhead, vblock, v_in = head, kblock, kv_in
     if dv != d:  # do, v and dv in blocks of the value head's width
-        vhead = pl.BlockSpec((None, None, tq_p, dv), head.index_map)
-        vblock = pl.BlockSpec((None, None, bk, dv), kblock.index_map)
-        v_in = pl.BlockSpec((None, None, bk, dv), kv_in.index_map)
+        vhead = _head_spec(merged, tq_p, dv, at_head)
+        vblock, v_in = _head_spec(merged, bk, dv, at_block), _head_spec(merged, bk, dv, at_kv)
+    by_member = merged and group > 1
+    if by_member:
+        # A group's dk and dv as [B, group, T, Hkv * D]: the group's sum is then over a
+        # leading axis and leaves the merged layout (a sum over heads that lie side by
+        # side on the lanes would have to re-tile the whole array first).
+        def member(width):
+            return pl.BlockSpec((None, None, bk, width), lambda i, j, ik: (i, j % group, ik, j // group))
+
+        kblock, vblock = member(d), member(dv)
     dq, dk, dv_ = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal,
@@ -423,9 +655,13 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         in_specs=[head, kv_in, v_in, vhead, rows, rows],
         out_specs=[head, kblock, vblock],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, tq_p, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, tk_p, d), k.dtype),
-            jax.ShapeDtypeStruct((b, h, tk_p, dv), v.dtype),
+            jax.ShapeDtypeStruct((b, tq_p, h * d) if merged else (b, h, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct(
+                (b, group, tk_p, h_kv * d) if by_member else (b, tk_p, h * d) if merged else (b, h, tk_p, d),
+                k.dtype),
+            jax.ShapeDtypeStruct(
+                (b, group, tk_p, h_kv * dv) if by_member else (b, tk_p, h * dv) if merged else (b, h, tk_p, dv),
+                v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tq_p, d), jnp.float32),
@@ -441,6 +677,10 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         interpret=interpret,
         name="dvc_flash_bwd" if window is None else "dvc_flash_win_bwd",
     )(qp, kp, vp, dop, lse, delta)
+    if merged:
+        if by_member:
+            dk, dv_ = (jnp.sum(a, axis=1, dtype=jnp.float32).astype(a.dtype) for a in (dk, dv_))
+        return dq[:, :tq], dk[:, :tk], dv_[:, :tk]
     dk, dv_ = dk[:, :, :tk], dv_[:, :, :tk]
     if group > 1:
         dk, dv_ = (
@@ -455,16 +695,17 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
 # ---------------------------------------------------------------------------
 
 
-def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None) -> Tuple[int, int, bool]:
+def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None,
+             heads: Optional[Tuple[int, int]] = None, turned: bool = False) -> Tuple[int, int, bool]:
     """The call's block sizes and mode: explicit blocks are clipped to the
     (tile-rounded) sequence, missing ones come from ``choose_blocks``."""
-    tq, tk, d = q.shape[2], k.shape[2], q.shape[3]
-    if q.shape[1] % k.shape[1]:
-        raise ValueError(f"{k.shape[1]} key/value heads do not divide {q.shape[1]} query heads")
+    _, h, h_kv, tq, tk, d, _ = _dims(q, k, k, heads)
+    if h % h_kv:
+        raise ValueError(f"{h_kv} key/value heads do not divide {h} query heads")
     if window is not None and (not causal or tq != tk or window < 1):
         raise ValueError("a window needs causal attention over a square sequence")
     if block_q is None or block_k is None:
-        chosen = choose_blocks(tq, tk, d, q.dtype, window)
+        chosen = choose_blocks(tq, tk, d, q.dtype, window, turned)
         if chosen is None:
             raise ValueError(
                 f"flash attention keeps one head in VMEM; Tq={tq}, Tk={tk}, D={d} "
@@ -478,16 +719,17 @@ def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None) -> Tup
 
 
 def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None,
-               v: Optional[jax.Array] = None) -> int:
+               v: Optional[jax.Array] = None, heads: Optional[Tuple[int, int]] = None) -> int:
     """Bytes of the two residuals named by ``KEPT_NAMES`` for one call at
     ``choose_blocks``' blocks, as the chip lays them out: the output with its
     head dim padded to whole lanes (a D=64 output takes a D=128 one's room:
-    the compiled step's kept stack is ``bf16[L,B,H,T,64]`` tiled (8, 128)),
-    and the f32 log-sum-exp rows of whole q-blocks."""
-    bq, _, _ = _resolve(q, k, None, None, False, True, window)
-    b, h, tq, d = q.shape
+    the compiled step's kept stack is ``bf16[L,B,H,T,64]`` tiled (8, 128); a
+    merged call's heads are whole lanes), and the f32 log-sum-exp rows of whole
+    q-blocks."""
+    bq, _, _ = _resolve(q, k, None, None, False, True, window, heads)
+    b, h, _, tq, _, d, dv = _dims(q, k, k if v is None else v, heads)
     if v is not None:  # the output is as wide as the value head
-        d = v.shape[3]
+        d = dv
     return b * h * (tq * _round_up(d, LANES) * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
 
 
@@ -532,3 +774,65 @@ def _fa_bwd(causal, block_q, block_k, interpret, window, residuals, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def flash_attention_merged(
+    q: jax.Array,  # [B, T, H * D] as the projection made it, D whole lanes; turned here if ``cos`` is given
+    k: jax.Array,  # [B, T, Hkv * D], Hkv dividing H; already turned (``rotary_merged``)
+    v: jax.Array,  # [B, T, Hkv * Dv], Dv whole lanes
+    cos: Optional[jax.Array],  # ``rotary_tables`` [T, D] float32, or None: q is scored as it is
+    sin: Optional[jax.Array],
+    heads: Tuple[int, int],  # (H, Hkv)
+    causal: bool = False,
+    window: Optional[int] = None,
+    rotary_dim: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``flash_attention`` on the projections' own layout, giving [B, T, H * Dv]
+    (what the output projection reads): the same two kernels, a head being
+    block ``h`` of the last axis. The forward turns each q block on the tile it
+    has taken up; the backward, whose kernel holds a head's q resident and
+    visits it from every key block, is handed q turned by one pass outside
+    (``_turn_merged``) and its dq takes one pass back."""
+    return _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret))[0]
+
+
+def _interpreted(interpret: Optional[bool]) -> bool:
+    return _interpret_default() if interpret is None else interpret
+
+
+# Both halves are jitted: a model's layers of one kind (Laguna's three sliding
+# layers, its two full ones) call them with equal shapes, and each is traced
+# and lowered once a step program instead of once a layer.
+@functools.partial(jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret"))
+def _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret):
+    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None)
+    tables = None if cos is None else (cos, sin)
+    return _flash_forward(q, k, v, causal, bq, bk, interpret, window, heads, tables, rotary_dim)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret"))
+def _fam_backward(q, k, v, out, lse, cos, sin, g, heads, causal, window, rotary_dim, interpret):
+    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None)
+    if cos is not None:
+        q = _turn_pass(q, cos, sin, rotary_dim, False, interpret)
+    dq, dk, dv = _flash_backward(causal, bq, bk, interpret, (q, k, v, out, lse), g, window, heads)
+    if cos is not None:
+        dq = _turn_pass(dq, cos, sin, rotary_dim, True, interpret)
+    return dq, dk, dv
+
+
+def _fam_fwd(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret):
+    out, lse = _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret))
+    out, lse = checkpoint_name(out, KEPT_NAMES[0]), checkpoint_name(lse, KEPT_NAMES[1])  # as ``_fa_fwd``
+    return out, (q, k, v, out, lse, cos, sin)
+
+
+def _fam_bwd(heads, causal, window, rotary_dim, interpret, residuals, g):
+    cos, sin = residuals[5:]
+    grads = _fam_backward(*residuals, g, heads, causal, window, rotary_dim, _interpreted(interpret))
+    return (*grads, None, None) if cos is None else (*grads, jnp.zeros_like(cos), jnp.zeros_like(sin))
+
+
+flash_attention_merged.defvjp(_fam_fwd, _fam_bwd)

@@ -862,18 +862,23 @@ class Telemetry:
 
     def count_attention_core(
         self, impl: str, t: int, d: int, dtype: str, window: Optional[int] = None,
-        kv_heads: Optional[int] = None,
+        kv_heads: Optional[int] = None, layout: str = "heads", rotary: str = "none",
     ) -> None:
         """One TRACED attention call took core ``impl`` ("flash" | "xla"):
         ops.attention's observer (``set_core_observer``). Counts traces,
         not steps — a compiled step never comes back here. ``window`` is
-        "none" for full attention; ``kv_heads`` the key/value heads it read."""
+        "none" for full attention; ``kv_heads`` the key/value heads it read;
+        ``layout`` what the core was handed ("merged": the projections' own
+        [B, T, H * D]; "heads": [B, H, T, D]) and ``rotary`` where the call's
+        rotary turn ran ("kernel" | "outside" | "none": ``ops/attention.py`` at
+        ``_core_observer``)."""
         if self.enabled:
             self.registry.counter(
                 "swarm.attention_core",
                 "traced attention calls by the core that took them",
             ).inc(impl=impl, T=str(t), D=str(d), dtype=dtype,
-                  window="none" if window is None else str(window), kv_heads=str(kv_heads))
+                  window="none" if window is None else str(window), kv_heads=str(kv_heads),
+                  layout=layout, rotary=rotary)
 
     def count_qkv_projection(self, layout: str, tp: int) -> None:
         """One TRACED fused qkv projection ran ``layout`` ("by_head": divided
@@ -997,16 +1002,21 @@ class Telemetry:
                 out[key] = v
         return out
 
-    def _counts_by(self, counter: str, label: str) -> Dict[str, int]:
+    def _counts_by(self, counter: str, *labels: str) -> Dict[str, int]:
         out: Dict[str, int] = {}
         for rec in self.registry.counter(counter)._scrape()["values"]:
-            key = rec["labels"].get(label, "?")
+            key = "/".join(rec["labels"].get(label, "?") for label in labels)
             out[key] = out.get(key, 0) + int(rec["value"])
         return out
 
     def attention_cores(self) -> Dict[str, int]:
         """Traced attention calls per core, all shapes together."""
         return self._counts_by("swarm.attention_core", "impl")
+
+    def attention_layouts(self) -> Dict[str, int]:
+        """Traced attention calls by what the core was handed and where the
+        rotary turn ran: ``{"merged/kernel": 3, "merged/none": 1}``."""
+        return self._counts_by("swarm.attention_core", "layout", "rotary")
 
     def remat_kept(self) -> Dict[str, int]:
         """Traced rematerialised layers whose checkpoint kept something (the
@@ -1136,6 +1146,8 @@ class Telemetry:
             "spans": spans,
             # how often the fused attention core engaged, in traced calls
             "attention_core": self.attention_cores(),
+            # the same calls by the layout the core was handed and where the rotary turn ran
+            "attention_layout": self.attention_layouts(),
             # how often the fused qkv projection was divided by head over tp
             "qkv_projection": self.qkv_projections(),
             # as how many independent row streams a layer over tp ran ({} for a model never split)

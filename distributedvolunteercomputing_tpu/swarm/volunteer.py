@@ -1335,6 +1335,10 @@ class Volunteer:
             # Traced attention calls by core ({"flash": n} or {"xla": n};
             # empty with telemetry off).
             self.summary["attention_core"] = self.telemetry.attention_cores()
+            # The same calls by what the core was handed and where the rotary
+            # turn ran ({"merged/kernel": n}: the projections' own arrays, q
+            # turned on the kernel's tile; {"heads/none": n}: [B, H, T, D]).
+            self.summary["attention_layout"] = self.telemetry.attention_layouts()
             # Traced fused qkv projections by layout ({"by_head": n} on a mesh
             # whose tp divides the heads, {"fused": n} elsewhere).
             self.summary["qkv_projection"] = self.telemetry.qkv_projections()

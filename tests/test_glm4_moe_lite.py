@@ -281,7 +281,7 @@ def test_latent_attention_goes_through_the_core_at_one_head_dim():
         got = glm._attention(p, x, cfg)
     finally:
         attention.set_core_observer(None)
-    assert seen == [("xla", 64, 16, "float32", None, 4)]   # D = 12 + 4 = the value head, a key head a query head
+    assert seen == [("xla", 64, 16, "float32", None, 4, "heads", "none")]   # D = 12 + 4 = the value head, a key head a query head
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)
         want = x + ref._latent_attention(p, n, ref.hyper(TINY), None)

@@ -508,7 +508,7 @@ def test_per_head_qk_norm_at_64_with_groups_of_four_through_the_core():
         got = lfm2._attention(p, x, cfg)
     finally:
         attention.set_core_observer(None)
-    assert seen == [("xla", 48, 64, "float32", None, 8)]
+    assert seen == [("xla", 48, 64, "float32", None, 8, "heads", "none")]
     hp = {"heads": 32, "n_kv": 8, "head_dim": 64, "theta": cfg.rope_theta, "eps": cfg.rms_eps}
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)

@@ -392,7 +392,8 @@ def test_trace_time_counter(enabled, want):
         assert tel.summary()["attention_core"] == traced
         rec = tel.registry.counter("swarm.attention_core")._scrape()["values"][0]
         assert rec["labels"] == {"impl": "xla", "T": "32", "D": "16", "dtype": "float32",
-                                 "window": "none", "kv_heads": "4"}
+                                 "window": "none", "kv_heads": "4", "layout": "heads", "rotary": "none"}
+        assert tel.summary()["attention_layout"] == {"heads/none": traced["xla"]}
     else:
         assert tel.summary()["attention_core"] == {}
 
@@ -583,3 +584,189 @@ def test_remat_off_keeps_nothing():
         attention.set_kept_observer(None)
     assert not seen
     assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]
+
+
+# -- the projections' own layout, [B, T, H * D] (ops/attention.attention_merged) --
+
+
+def _merged_qkv(b, t, h, hkv, d, dv, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = [(b, t, h * d), (b, t, hkv * d), (b, t, hkv * dv), (b, t, h * dv)]
+    return [jax.random.normal(k, s, dtype) for k, s in zip(ks, shapes)]
+
+
+def _by_head_path(q, k, v, h, hkv, window, rotary):
+    """What every model ran before the merged entry, and what the entry falls back to."""
+    from distributedvolunteercomputing_tpu.ops import attention as A
+
+    qh, kh, vh = A.split_heads(q, h), A.split_heads(k, hkv), A.split_heads(v, hkv)
+    if rotary is not None:
+        qh, kh = A.rope(qh, **rotary._asdict()), A.rope(kh, **rotary._asdict())
+    return A.merge_heads(A.attention_core(qh, kh, vh, causal=True, window=window))
+
+
+def _half(**kw):
+    from distributedvolunteercomputing_tpu.ops.attention import Rotary
+
+    return Rotary(layout="half", **kw)
+
+
+def _interleaved():
+    from distributedvolunteercomputing_tpu.ops.attention import Rotary
+
+    return Rotary()
+
+
+def _yarn():
+    from distributedvolunteercomputing_tpu.ops.attention import yarn_inv_freq
+
+    return _half(rotary_dim=64, inv_freq=yarn_inv_freq(64, 500000.0, 64.0, 64, 64.0, 1.0), scale=1.4)
+
+
+# (b, t, h, hkv, d, dv, window, rotary, the layout and the turn the observer hears, exact)
+_MERGED_CASES = {
+    "full rotary at D = 128": (2, 128, 2, 2, 128, 128, None, _half, "merged/kernel", False),
+    "partial rotary 64 of 128, yarn and a scale": (1, 128, 2, 1, 128, 128, None, _yarn, "merged/kernel", False),
+    "no rotary": (1, 128, 2, 2, 128, 128, None, None, "merged/none", False),
+    "grouped heads, 8 over 2": (1, 128, 8, 2, 128, 128, None, _half, "merged/kernel", False),
+    "a window narrower than a block": (1, 256, 2, 1, 128, 128, 64, _half, "merged/kernel", False),
+    "a window wider than a block": (1, 256, 2, 1, 128, 128, 200, _yarn, "merged/kernel", False),
+    "a sequence that pads": (1, 200, 4, 2, 128, 128, None, _half, "merged/kernel", False),
+    "a padded sequence under a window": (1, 200, 2, 2, 128, 128, 64, _yarn, "merged/kernel", False),
+    "a base of its own": (1, 128, 2, 1, 128, 128, None, lambda: _half(base=1.5e6), "merged/kernel", False),
+    "a value head of 256 under keys of 128": (1, 128, 2, 1, 128, 256, None, _half, "merged/kernel", False),
+    "a head of 256": (1, 128, 2, 1, 256, 256, None, _half, "merged/kernel", False),
+    "a value head of 64: the by-head path": (1, 128, 2, 1, 128, 64, None, _half, "heads/outside", True),
+    "D = 64: the by-head path": (1, 128, 4, 2, 64, 64, None, _half, "heads/outside", True),
+    "D = 64 without rotary: the by-head path": (1, 128, 4, 4, 64, 64, 32, None, "heads/none", True),
+    "interleaved pairs: the by-head path": (1, 128, 2, 2, 128, 128, None, _interleaved, "heads/outside", True),
+}
+
+
+@pytest.mark.parametrize("case", list(_MERGED_CASES))
+def test_merged_entry_equals_the_by_head_path(case):
+    """``attention_merged`` against ``split_heads`` + ``rope`` + ``attention_core``
+    + ``merge_heads``, both on the kernel (interpreted): the output and the
+    gradients of q, k and v. Where the shapes keep the by-head path (a head or a
+    value head that is not whole lanes, interleaved pairs) the entry IS that
+    path and the results are equal to the bit; elsewhere the kernels read the
+    merged arrays and turn the pairs by a lane roll, float32 on the tile as
+    ``rope``, so float32 inputs agree to rounding."""
+    from distributedvolunteercomputing_tpu.ops import attention as A
+
+    b, t, h, hkv, d, dv, window, rotary, heard, exact = _MERGED_CASES[case]
+    rotary = None if rotary is None else rotary()
+    q, k, v, cot = _merged_qkv(b, t, h, hkv, d, dv)
+    seen = []
+    A.set_core_observer(lambda *a: seen.append(f"{a[0]}:{a[6]}/{a[7]}"))
+    try:
+        set_attention_impl("flash")
+        got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
+            q, k, v, h, hkv, causal=True, window=window, rotary=rotary), q, k, v)
+        got = (got, *vjp(cot))
+        assert seen == [f"flash:{heard}"], seen
+        want, vjp = jax.vjp(lambda q, k, v: _by_head_path(q, k, v, h, hkv, window, rotary), q, k, v)
+        want = (want, *vjp(cot))
+    finally:
+        set_attention_impl("auto")
+        A.set_core_observer(None)
+    assert got[0].shape == (b, t, h * dv)
+    for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
+        if exact:
+            assert np.array_equal(np.asarray(x), np.asarray(y)), name
+        else:
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+def test_merged_entry_rounds_where_rope_rounds():
+    """bfloat16 in: a call without rotary is the by-head kernels' arithmetic
+    on another block index, equal to the bit; a rotary call turns in float32
+    and rounds q and k to bfloat16 as ``rope`` does, and its dq loses no more
+    than the by-head path's two roundings."""
+    from distributedvolunteercomputing_tpu.ops import attention as A
+
+    q, k, v, cot = _merged_qkv(1, 128, 4, 2, 128, 128, jnp.bfloat16)
+    try:
+        set_attention_impl("flash")
+        for rotary, tol in ((None, 0.0), (_half(), 2e-2)):
+            got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(q, k, v, 4, 2, causal=True, rotary=rotary), q, k, v)
+            want, vjp_w = jax.vjp(lambda q, k, v: _by_head_path(q, k, v, 4, 2, None, rotary), q, k, v)
+            for x, y in zip((got, *vjp(cot)), (want, *vjp_w(cot))):
+                x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+                assert np.max(np.abs(x - y)) <= tol * max(1.0, np.max(np.abs(y)))
+    finally:
+        set_attention_impl("auto")
+
+
+def test_rotary_tables_are_ropes_own_values():
+    """The tables the kernels turn by hold ``rope``'s cosines and sines lane by
+    lane (a partner's sine with its sign; ones and zeros past ``rotary_dim``),
+    and one pass of ``rotary_merged`` is ``rope`` on every head."""
+    from distributedvolunteercomputing_tpu.ops import attention as A
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    rot = _yarn()
+    cos, sin = pa.rotary_tables(40, 128, rot.base, rot.rotary_dim, rot.inv_freq, rot.scale)
+    angles = np.arange(40)[:, None] * np.asarray(rot.inv_freq)[None, :]
+    np.testing.assert_allclose(np.asarray(cos[:, :32]), 1.4 * np.cos(angles), rtol=1e-5, atol=1e-6)
+    assert np.array_equal(np.asarray(cos[:, :32]), np.asarray(cos[:, 32:64]))
+    assert np.array_equal(np.asarray(sin[:, :32]), -np.asarray(sin[:, 32:64]))
+    assert np.all(np.asarray(cos[:, 64:]) == 1.0) and np.all(np.asarray(sin[:, 64:]) == 0.0)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 3 * 128))
+    got, vjp = jax.vjp(lambda x: pa.rotary_merged(x, cos, sin, 64, True), x)
+    want, vjp_w = jax.vjp(lambda x: A.merge_heads(A.rope(A.split_heads(x, 3), **rot._asdict())), x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(vjp(got)[0]), np.asarray(vjp_w(want)[0]), rtol=1e-5, atol=1e-5)
+
+
+def test_merged_blocks_count_the_tables():
+    """Both cells' shapes keep their blocks with a turned call's table blocks
+    in the estimate (two float32 ``[bq, D]`` blocks, double-buffered, on top of
+    the backward's: 63.0 of the budget's 64 MiB at T=16,384), and a call that
+    turns nothing is estimated as it always was, so that no other caller's
+    kernel is compiled under another limit."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    bf16 = jnp.bfloat16
+    assert pa.choose_blocks(16384, 16384, 128, bf16, window=4096, turned=True) == (1024, 1024)
+    assert pa.choose_blocks(8192, 8192, 128, bf16, window=512, turned=True) == (512, 512)
+    assert pa.choose_blocks(8192, 8192, 128, bf16, turned=True) == (1024, 1024)
+    plain = pa.vmem_bytes(16384, 16384, 128, bf16, 1024, 1024)
+    assert plain == 63963136  # 61.0 MiB: the parent's number, the backward's
+    assert pa.vmem_bytes(16384, 16384, 128, bf16, 1024, 1024, turned=True) == plain + 2 * 2 * 1024 * 128 * 4
+    assert plain + 2 * 2 * 1024 * 128 * 4 <= pa.VMEM_BUDGET_BYTES
+    assert pa.vmem_bytes(1024, 1024, 64, bf16, 1024, 1024) == 30539776  # gpt2's, as at the parent
+
+
+def test_merged_entry_per_shard_under_a_mesh(eight_devices):
+    """Under a step's dp x tp mesh the merged call runs per shard like the
+    by-head one: ``tp`` cuts the last axis into whole heads, the key/value heads
+    with their query heads, the tables whole on every chip; the loss and the
+    gradients are the unsharded call's."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from distributedvolunteercomputing_tpu.ops import attention as A
+
+    mesh = Mesh(np.array(eight_devices[:4]).reshape(2, 2), ("dp", "tp"))
+    q, k, v, cot = _merged_qkv(2, 128, 4, 2, 128, 128)
+    rot = _yarn()
+
+    def loss(q, k, v):
+        return jnp.sum(cot * A.attention_merged(q, k, v, 4, 2, causal=True, window=96, rotary=rot))
+
+    seen = []
+    A.set_core_observer(lambda *a: seen.append(a[6:]))
+    try:
+        set_attention_impl("flash")
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        spec = NamedSharding(mesh, P("dp", None, "tp"))
+        with A.step_mesh(mesh):
+            got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)), in_shardings=(spec,) * 3)(q, k, v)
+    finally:
+        set_attention_impl("auto")
+        A.set_core_observer(None)
+    assert seen == [("merged", "kernel")] * 2
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
+    for x, y in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5)

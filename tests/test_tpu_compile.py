@@ -238,9 +238,15 @@ def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     names = [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
     flash = [n for n in names if n.startswith("dvc_flash_")]
     gmm = [n for n in names if moe_trace.GMM_RE.search(n)]
-    assert len(flash) == 2 and len(gmm) == 12 and len(names) == 14, names
+    # beside them since PR 59: the backward's delta rows and four merged-layout rotary passes (k and its
+    # cotangent, the backward's q and its dq; the forward's q is turned on the kernel's tile)
+    beside = sorted(n.split(".")[0] for n in names if n.startswith(("dvc_attn_", "dvc_rotary")))
+    assert beside == ["dvc_attn_delta"] + ["dvc_rotary"] * 3 + ["dvc_rotary_back"] * 2, names
+    assert len(flash) == 2 and len(gmm) == 12 and len(names) == 14 + len(beside), names
     assert sum(n.startswith("tgmm") for n in gmm) == 3
-    assert all("bf16[4,16,4096,128]" in ln for ln in calls if "dvc_flash_" in ln)
+    # q, k, v and the output where the projections leave them (PR 59): no array by head in the step
+    assert all("bf16[4,4096,2048]" in ln and "bf16[4,16,4096,128]" not in ln for ln in calls if "dvc_flash_" in ln)
+    _no_pass_over_a_head_shaped_array(text, 4, 4096, (16,), float32_too=False)
     assert all("[131072," in ln or "bf16[64," in ln for ln in calls if "gmm" in ln)
 
 
@@ -248,6 +254,30 @@ def _kernel_names(calls):
     import re
 
     return [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
+
+
+def _no_pass_over_a_head_shaped_array(text: str, b: int, t: int, heads, d: int = 128, float32_too: bool = True) -> None:
+    """No instruction of the compiled step's entry computation gives an array
+    by head ([B, H, T, D] or the transposed [B, T, H, D], whole or as rotary's
+    halves and quarters) or a float32 copy of a merged [B, T, H * D], for any
+    of the head counts ``heads``: between a projection and a ``dvc_flash_*``
+    call the arrays stay [B, T, H * D] in the compute dtype (PR 59).
+    ``float32_too`` False where H * D is the model's own width (OLMoE: the
+    residual stream's float32 norms have that shape)."""
+    import re
+
+    counts = "|".join(str(h) for h in heads)
+    widths = "|".join(str(w) for w in (d, d // 2, d // 4))
+    merged = "|".join(str(h * d) for h in heads)
+    refused = re.compile(
+        rf"(?:bf16|f32)\[{b},(?:(?:{counts}),{t}|{t},(?:{counts})),(?:{widths})\]"
+        + (rf"|f32\[{b},{t},(?:{merged})\]" if float32_too else ""))
+    found = []
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(.*?\)|\S+) ", ln)
+        if m and refused.search(m.group(1)):
+            found.append(ln.strip()[:160])
+    assert not found, found[:5]
 
 
 def _share_chunks_hold_seven_grouped_matmuls(names, text: str, layers: int, rows: int, d: int, f: int) -> None:
@@ -282,13 +312,14 @@ def _share_chunks_hold_seven_grouped_matmuls(names, text: str, layers: int, rows
 
 
 @pytest.mark.parametrize("model,batch,layers,shape", [
-    ("gpt2_medium", 16, 2, "bf16[16,16,1024,64]"), ("olmoe_1b_7b", 4, 1, "bf16[4,16,4096,128]")])
+    ("gpt2_medium", 16, 2, "bf16[16,16,1024,64]"), ("olmoe_1b_7b", 4, 1, "bf16[4,4096,2048]")])
 def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, model, batch, layers, shape):
     """The gpt2 and OLMoE cells trace the attention kernels under the names
     they had before the windowed ones existed: ``dvc_flash_fwd`` and
     ``dvc_flash_bwd`` once a scanned layer, forward and backward; the
     recomputed forward holds no kernel; at equal head counts, and no windowed
-    name."""
+    name. Since PR 59 OLMoE's are handed the projections' own ``[4, 4096, 16 *
+    128]`` arrays; gpt2's head of 64 keeps ``[B, H, T, D]``."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -509,6 +540,55 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
     assert kept("gpt2_medium", 1, 1, 16) == (["attention_lse", "attention_out"], [(2, 2 * kernel(16, 16, 1024))])
     assert kept("gpt2_large", 2, 2, 32) == (
         ["attention_lse", "attention_out", "tp_reduced"], [(2, 2 * (kernel(16, 10, 1024) + 41_943_040))])
+
+
+# (model, dp, tp, batch, n_layers, overrides) of each cell's step, and what ``swarm.attention_core`` hears of it
+_CELL_LAYOUTS = {
+    "medium-solo": (("gpt2_medium", 1, 1, 16, 2, {}), {"heads/none": 1}),
+    "large-solo-4chip": (("gpt2_large", 2, 2, 32, 2, {}), {"heads/none": 1}),
+    "olmoe-solo": (("olmoe_1b_7b", 1, 1, 4, 1, {}), {"merged/kernel": 1}),
+    "laguna-solo-8k": (
+        ("laguna_xs2", 1, 1, 4, 5, dict(experts_held=16, vocab=12544)), {"merged/kernel": 5}),
+    "smallthinker-solo-16k": (
+        ("smallthinker_21b_a3b", 1, 1, 2, 4, dict(experts_held=8, vocab=18992)),
+        {"merged/kernel": 1, "merged/none": 1}),  # one traced sliding layer (rotary), one global (none)
+    "lfm2-solo-8k": (
+        ("lfm2_24b_a2b", 1, 1, 4, None, dict(
+            layer_types="conv,full_attention,conv,conv,conv", dense_layers=1, experts_held=8, vocab=8192)),
+        {"heads/none": 1}),  # a head of 64: two heads a lane tile
+    "glm47-flash-solo-8k": (
+        ("glm4_7_flash", 1, 1, 2, 5, dict(experts_held=8, vocab=19360)), {"heads/none": 2}),
+    "nemotron3-nano-solo-8k": (
+        ("nemotron3_nano_30b_a3b", 1, 1, 2, 7, dict(experts_held=8, vocab=16384)), {"heads/none": 1}),
+    "kimi-linear-solo-8k": (
+        ("kimi_linear_48b_a3b", 1, 1, 2, 5, dict(experts_held=8, vocab=20480)), {"heads/none": 1}),
+}
+
+
+@pytest.mark.parametrize("cell", list(_CELL_LAYOUTS))
+def test_which_cells_hand_the_kernels_the_projections_own_arrays(v5e, as_on_the_chip, monkeypatch, cell):
+    """Every cell's step, traced at the cell's size as the chip would trace it
+    (no compile): Laguna's, SmallThinker's and OLMoE's attention calls all take
+    the flash kernels on the merged ``[B, T, H * D]`` layout, a rotary layer's q
+    turned on the kernel's tile (``merged/kernel``) and a layer without
+    position encoding turning nothing (``merged/none``); every other cell's
+    calls are handed ``[B, H, T, D]`` as before (a head of 64, a latent key
+    concatenated by head, a model that calls ``attention_core`` itself): the
+    shapes decide, and ``swarm.attention_core`` says which (PR 59)."""
+    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    (model, dp, tp, batch, n_layers, overrides), want = _CELL_LAYOUTS[cell]
+    tel = Telemetry()
+    attention.set_core_observer(tel.count_attention_core)
+    try:
+        _traced_step(v5e, model, dp, tp, batch, n_layers, **overrides)
+    finally:
+        attention.set_core_observer(None)
+    assert tel.attention_layouts() == want
+    assert tel.attention_cores() == {"flash": sum(want.values())}
 
 
 def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):

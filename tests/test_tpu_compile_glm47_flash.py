@@ -60,15 +60,15 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
+        (impl, t, d, window, kv_heads, *how)))
     attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
     try:
         compiled = _lowered_step(v5e, "glm4_7_flash", 1, 1, 2, n_layers=5, experts_held=8, vocab=19360).compile()
     finally:
         attention.set_core_observer(None)
         attention.set_kept_observer(None)
-    assert seen == [("flash", t, d, None, 20)] * 2, seen          # one traced dense layer, one traced scan body
+    assert seen == [("flash", t, d, None, 20, "heads", "none")] * 2, seen          # one traced dense layer, one traced scan body
     # the output at 20 x 256 a token and the f32 row statistics: 168.8 MB a layer, 845.4 MB a step
     assert kept == [(1, 169_082_880), (4, 4 * 169_082_880)], kept
     text = compiled.as_text()

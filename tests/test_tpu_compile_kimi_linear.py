@@ -46,15 +46,15 @@ def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_t
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
+        (impl, t, d, window, kv_heads, *how)))
     attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
     try:
         compiled = _lowered_step(v5e, "kimi_linear_48b_a3b", 1, 1, 2, n_layers=5, experts_held=8, vocab=20480).compile()
     finally:
         attention.set_core_observer(None)
         attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 192, None, 32)], seen
+    assert seen == [("flash", 8192, 192, None, 32, "heads", "none")], seen  # a key of 192: the by-head entry
     # what the layers keep: the latent layer's output at 32 x 128 a token and its row statistics; a KDA layer nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()

@@ -15,6 +15,7 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _no_pass_over_a_head_shaped_array,
     no_persistent_cache,
     _share_chunks_hold_seven_grouped_matmuls,
     _step_holds_the_groups_its_cell_lists,
@@ -37,8 +38,21 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     chunk of rows, never the S x k = 262,144, seven a layer. That it compiles
     says the step fits the chip beside its state; its temporaries are what
     they were before PR 38 (8.1079e9 then, 8.1090e9 after it: the float32 carry
-    of a run over a tile's edge, ``[rows / 128, d]`` a call; 8.1105e9 since PR 46)."""
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch
+    of a run over a tile's edge, ``[rows / 128, d]`` a call; 8.1105e9 since PR 46).
+    Since PR 59 the kernels are handed q, k and v where the projections leave
+    them, ``[4, 8192, H * 128]``, and write the output the same way: the step
+    holds no array by head and no float32 copy of a merged one (the rotary
+    halves, the head transposes and the float32 query products are gone), the
+    gate reaches its heads through a 0/1 product, and the temporaries fall to
+    6.870e9."""
+    import jax.numpy as jnp
+
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention
+
+    # both layer kinds keep their blocks with a turned call's table blocks counted (25.5 and 46.0 MiB)
+    assert pallas_attention.choose_blocks(8192, 8192, 128, jnp.bfloat16, 512, turned=True) == (512, 512)
+    assert pallas_attention.choose_blocks(8192, 8192, 128, jnp.bfloat16, turned=True) == (1024, 1024)
+    assert pallas_attention.vmem_bytes(8192, 8192, 128, jnp.bfloat16, 1024, 1024, turned=True) <= 46.1 * 2 ** 20
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
@@ -52,9 +66,15 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     win = [n for n in names if n.startswith("dvc_flash_win_")]
     assert len(full) == 4 and sum(n.startswith("dvc_flash_bwd") for n in full) == 2, names
     assert len(win) == 6 and sum(n.startswith("dvc_flash_win_bwd") for n in win) == 3, names
-    assert all("bf16[4,48,8192,128]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
-    assert all("bf16[4,64,8192,128]" in ln for ln in calls if "dvc_flash_win_" in ln)
-    assert all("bf16[4,8,8192,128]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
+    assert all("bf16[4,8192,6144]" in ln for ln in calls if "dvc_flash_fwd" in ln or "dvc_flash_bwd" in ln)
+    assert all("bf16[4,8192,8192]" in ln for ln in calls if "dvc_flash_win_" in ln)
+    assert all("bf16[4,8192,1024]" in ln for ln in calls if "dvc_flash_" in ln)  # 8 key/value heads
+    _no_pass_over_a_head_shaped_array(text, 4, 8192, (64, 48, 8))
+    # beside the kernels, a layer: the backward's delta rows, and one merged-layout rotary pass for k,
+    # its recomputation, the backward's q, dq and dk (the forward's q is turned on the kernel's tile)
+    beside = [n.split(".")[0] for n in names if n.startswith(("dvc_attn_", "dvc_rotary"))]
+    assert sorted(set(beside)) == ["dvc_attn_delta", "dvc_rotary", "dvc_rotary_back"], names
+    assert (beside.count("dvc_attn_delta"), beside.count("dvc_rotary"), beside.count("dvc_rotary_back")) == (5, 15, 10)
     rows = moe_dispatch.share_rows_bound(4 * 8192, 8, 16, 256)
     assert rows == 49152  # three times the even share of 16,384: one chunk a layer on the chip
     assert f"[{rows},2048]" in text and "[262144,2048]" not in text
@@ -64,4 +84,5 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     assert total < 15.75e9, total
     # the parent of PR 36: 8.1125e9; of PR 38: 8.1079e9; of PR 46: 8.1090e9, and 8.1105e9 since (three select passes
     # a layer fewer and the same buffers alive: the heap packs 1.5 MB worse)
-    assert mem.temp_size_in_bytes <= 8.112e9, mem.temp_size_in_bytes
+    # and 6.870e9 since PR 59 (the float32 [4,8192,8192] products and the by-head copies are gone)
+    assert mem.temp_size_in_bytes <= 6.90e9, mem.temp_size_in_bytes

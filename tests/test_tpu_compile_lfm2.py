@@ -48,15 +48,15 @@ def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
+        (impl, t, d, window, kv_heads, *how)))
     try:
         compiled = _lowered_step(
             v5e, "lfm2_24b_a2b", 1, 1, 4, n_layers=None, layer_types="conv,full_attention,conv,conv,conv",
             dense_layers=1, experts_held=8, vocab=8192).compile()
     finally:
         attention.set_core_observer(None)
-    assert set(seen) == {("flash", 8192, 64, None, 8)}, seen
+    assert set(seen) == {("flash", 8192, 64, None, 8, "heads", "none")}, seen  # D = 64: the by-head entry
     text = compiled.as_text()
     _step_holds_the_groups_its_cell_lists(text, "lfm2-solo-8k")
     calls = _kernel_calls(text)

@@ -51,15 +51,15 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     assert (moe_dispatch._padded(2688), moe_dispatch._padded(1856)) == (3072, 2048)
     assert moe_dispatch._megablox_tiling(7680, 3072, 2048) == (512, 1024, 1024)
     seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, d, window, kv_heads)))
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
+        (impl, t, d, window, kv_heads, *how)))
     attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
     try:
         compiled = _lowered_step(v5e, "nemotron3_nano_30b_a3b", 1, 1, 2, n_layers=7, experts_held=8, vocab=16384).compile()
     finally:
         attention.set_core_observer(None)
         attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 128, None, 2)], seen
+    assert seen == [("flash", 8192, 128, None, 2, "heads", "none")], seen  # the model calls attention_core itself
     # what the blocks keep: the attention block's output and row statistics; a state-space block nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()

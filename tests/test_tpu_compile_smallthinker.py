@@ -17,6 +17,7 @@ from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by 
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _no_pass_over_a_head_shaped_array,
     no_persistent_cache,
     _share_chunks_hold_seven_grouped_matmuls,
     _step_holds_the_groups_its_cell_lists,
@@ -56,24 +57,30 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
         assert pallas_attention.choose_blocks(t, t, d, jnp.bfloat16, window) == (1024, 1024)
     used = pallas_attention.vmem_bytes(t, t, d, jnp.bfloat16, 1024, 1024)
     assert 0.9 * pallas_attention.VMEM_BUDGET_BYTES < used <= pallas_attention.VMEM_BUDGET_BYTES
+    # the sliding layers turn their q: the forward's table blocks on top still fit (63.0 of 64 MiB)
+    assert pallas_attention.choose_blocks(t, t, d, jnp.bfloat16, 4096, turned=True) == (1024, 1024)
     assert pallas_attention.choose_blocks(2 * t, 2 * t, d, jnp.bfloat16) is None  # the next doubling does not fit
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None: seen.append(
-        (impl, t, window, kv_heads)))
+    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
+        (impl, t, window, kv_heads, *how)))
     try:
         compiled = _lowered_step(
             v5e, "smallthinker_21b_a3b", 1, 1, 2, n_layers=4, experts_held=8, vocab=18992).compile()
     finally:
         attention.set_core_observer(None)
-    assert sorted(set(seen), key=str) == [("flash", t, 4096, 4), ("flash", t, None, 4)], seen
+    # both layer kinds on the projections' own arrays; the sliding layers' q turned on the kernel's tile
+    assert sorted(set(seen), key=str) == [
+        ("flash", t, 4096, 4, "merged", "kernel"), ("flash", t, None, 4, "merged", "none")], seen
     text = compiled.as_text()
     _step_holds_the_groups_its_cell_lists(text, "smallthinker-solo-16k")
     calls = _kernel_calls(text)
     flash = sorted(n.split(".")[0] for n in _kernel_names(calls) if n.startswith("dvc_flash"))
     assert flash == ["dvc_flash_bwd", "dvc_flash_fwd", "dvc_flash_win_bwd", "dvc_flash_win_fwd"], flash
-    assert all("bf16[2,28,16384,128]" in ln and "bf16[2,4,16384,128]" in ln for ln in calls if "dvc_flash_" in ln)
+    # since PR 59 on the projections' own arrays: 28 query heads and 4 key/value heads side by side
+    assert all("bf16[2,16384,3584]" in ln and "bf16[2,16384,512]" in ln for ln in calls if "dvc_flash_" in ln)
+    _no_pass_over_a_head_shaped_array(text, 2, t, (28, 4))
     assert moe_dispatch.share_rows_bound(2 * t, 6, 8, 64) == 73728  # the dispatch's default, three even shares
     rows = moe_dispatch.share_rows_bound(2 * t, 6, 8, 64, smallthinker.SHARE_ROWS_SLACK)
     assert rows == 104448  # 3.19 S: three held experts that each take every token fit one chunk
@@ -83,4 +90,5 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9, (
         mem.argument_size_in_bytes, mem.temp_size_in_bytes)
-    assert mem.temp_size_in_bytes <= 9.61e9, mem.temp_size_in_bytes
+    # 9.6039e9 until PR 59, 9.0362e9 since (no by-head copy of q, no float32 halves around the sliding layers' kernels)
+    assert mem.temp_size_in_bytes <= 9.05e9, mem.temp_size_in_bytes
