@@ -159,14 +159,18 @@ def rmsnorm(p: Params, x: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (y * p["g"]).astype(x.dtype)
 
 
-def softmax_xent(logits: jax.Array, labels: jax.Array, mask: Optional[jax.Array] = None) -> jax.Array:
-    """Mean cross-entropy; ``labels`` are int ids; optional 0/1 mask."""
+def softmax_xent(logits: jax.Array, labels: jax.Array, mask: Optional[jax.Array] = None,
+                 denominator: Optional[float] = None) -> jax.Array:
+    """Mean cross-entropy; ``labels`` are int ids; optional 0/1 mask, or
+    per-token weights over a ``denominator`` of the caller's."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
     nll = logz - gold
     if mask is not None:
         mask = mask.astype(jnp.float32)
+        if denominator is not None:
+            return jnp.sum(nll * mask) / denominator
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     return jnp.mean(nll)
 
@@ -273,6 +277,7 @@ def lm_xent_chunked(
     mask: Optional[jax.Array] = None,
     chunk: int = 128,
     head_layout: str = "vd",
+    denominator: Optional[float] = None,
 ) -> jax.Array:
     """Mean LM cross-entropy WITHOUT materializing the [B, T, V] f32 logits.
 
@@ -284,7 +289,10 @@ def lm_xent_chunked(
 
     ``head`` is the projection matrix: [V, d] (``head_layout="vd"``, tied
     embeddings — GPT-2/BERT) or [d, V] (``"dv"``, a separate lm_head — Llama).
-    ``mask`` is an optional 0/1 token mask (MLM objective).
+    ``mask`` is an optional 0/1 token mask (MLM objective), over whose sum the
+    loss is the mean; with a ``denominator`` it is per-token WEIGHTS and the loss
+    is the weighted sum over that divisor (a denoising loss: masked tokens by
+    ``1 / t`` over B x L).
     """
     b, t, _ = x.shape
     if t % chunk != 0:
@@ -292,7 +300,7 @@ def lm_xent_chunked(
     n = t // chunk
     if n <= 1:
         logits = _project_vocab(x, head, head_layout)
-        return softmax_xent(logits, labels, mask)
+        return softmax_xent(logits, labels, mask, denominator)
 
     # [n, B, chunk, ...] so scan's leading axis is the chunk index.
     xs = jnp.moveaxis(x.reshape(b, n, chunk, x.shape[-1]), 1, 0)
@@ -317,6 +325,8 @@ def lm_xent_chunked(
 
     zero = jnp.zeros((), jnp.float32)
     (nll_sum, denom), _ = jax.lax.scan(jax.checkpoint(body), (zero, zero), (xs, ls, ms))
+    if denominator is not None:
+        return nll_sum / denominator
     return nll_sum / jnp.maximum(denom, 1.0)
 
 

@@ -1,12 +1,12 @@
 """What the expert families share (``models/laguna.py``, ``smallthinker.py``,
-``lfm2.py``, ``glm4_moe_lite.py``; ``models/olmoe.py`` holds every expert and
+``lfm2.py``, ``glm4_moe_lite.py``, ``sdar_moe.py``; ``models/olmoe.py`` holds every expert and
 takes the balancing term only): a config brings ``top_k``, ``n_experts``,
 ``experts_held`` and ``expert_offset``, its expert layers call
 ``ops/moe_dispatch.share_glu_experts`` for the held experts' part of the sum,
 and this module keeps
 
 - the check that the held experts are a slice of the router's (``check_share``);
-- the sigmoid router (``route``), with the selection bias that the STEP moves
+- the router (``route``: sigmoid scores, or a softmax over all E), with the selection bias that the STEP moves
   where a layer carries the leaf ``bias`` [E] beside ``router`` [d, E];
 - the share's running statistics over the expert layers (``zero_share_stats``,
   ``note_share``), the balancing term of a router trained by an auxiliary loss
@@ -46,10 +46,12 @@ def check_share(cfg) -> None:
 
 
 def route(p_router: jax.Array, h: jax.Array, top_k: int, routed_scale: float,
-          bias: Optional[jax.Array] = None, eps: float = 0.0):
+          bias: Optional[jax.Array] = None, eps: float = 0.0, score: str = "sigmoid"):
     """Router of one layer: ``h`` [S, d] -> (top_idx [S, k], weights [S, k]
-    float32, scores [S, E] float32). Sigmoid scores from a float32 product at
-    the highest precision; the weights are the chosen experts' own scores,
+    float32, scores [S, E] float32). Scores from a float32 product at the
+    highest precision, each expert's own ``sigmoid`` or (``score`` "softmax",
+    SDAR's Qwen3-MoE router) a softmax over all E; the weights are the chosen
+    experts' own scores,
     normalised to sum to 1 (+``eps`` in the divisor, as the family's public
     code has it: 1e-6 LFM2, 1e-20 GLM-4.7-Flash, none Laguna), times
     ``routed_scale``. A selection ``bias`` [E] is added for the CHOICE of the
@@ -58,7 +60,7 @@ def route(p_router: jax.Array, h: jax.Array, top_k: int, routed_scale: float,
         h.astype(jnp.float32), p_router, precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
-    scores = jax.nn.sigmoid(logits)
+    scores = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score](logits)
     if bias is None:
         top_scores, top_idx = jax.lax.top_k(scores, top_k)
     else:

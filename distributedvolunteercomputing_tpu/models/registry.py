@@ -1,7 +1,7 @@
 """Model registry: one bundle per reference workload (BASELINE.json:7-11),
 and the public architectures run at their published sizes beyond them
 (``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``, ``lfm2_24b_a2b``,
-``glm4_7_flash``, ``nemotron3_nano_30b_a3b``, ``kimi_linear_48b_a3b``: each takes the overrides that cut it to one chip's share without touching a
+``glm4_7_flash``, ``nemotron3_nano_30b_a3b``, ``kimi_linear_48b_a3b``, ``sdar_30b_a3b``: each takes the overrides that cut it to one chip's share without touching a
 width).
 
 Bundles are built lazily so importing the registry never pays for the whole
@@ -151,15 +151,19 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # experts: ``n_layers`` (the first so many), ``experts_held`` / ``expert_offset``, ``vocab``; its
     # routers' selection biases are the step's to move
     "kimi_linear_48b_a3b": ("kimi_linear", "KimiLinearConfig"),
+    # 48 Qwen3-MoE layers of 128 experts under a block-diffusion objective (a clean and a noised
+    # copy of every sequence under one three-part mask): ``n_layers``, ``experts_held`` /
+    # ``expert_offset``, ``vocab`` with ``mask_id`` inside it; its loss draws the noise from the step's rng
+    "sdar_30b_a3b": ("sdar_moe", "SdarMoeConfig"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 
 
 def _language_model(name: str, **overrides: Any) -> ModelBundle:
     """The bundle of one of ``_LANGUAGE_MODELS``. A module brings ``init(rng,
-    cfg)`` and either ``loss_and_routes(params, batch, cfg)`` (an expert
-    family: the loss is its first two results) or ``loss_fn(params, batch,
-    rng, cfg)``; ``stepped(cfg)`` where the step moves leaves of its own; and,
+    cfg)`` and either ``loss_fn(params, batch, rng, cfg)`` or
+    ``loss_and_routes(params, batch, cfg)`` (an expert family whose loss draws
+    nothing: the loss is its first two results); ``stepped(cfg)`` where the step moves leaves of its own; and,
     where its config has a ``lora_rank`` above 0, the subtree the swarm
     averages (``lora_subtree`` / ``with_lora_subtree``)."""
     from distributedvolunteercomputing_tpu.training import data
@@ -167,12 +171,12 @@ def _language_model(name: str, **overrides: Any) -> ModelBundle:
     module_name, make_config = _LANGUAGE_MODELS[name]
     module = importlib.import_module(f"{__package__}.{module_name}")
     cfg = dataclasses.replace(functools.reduce(getattr, make_config.split("."), module)(), **overrides)
-    if hasattr(module, "loss_and_routes"):
-        def loss_fn(params, batch, rng):
-            return module.loss_and_routes(params, batch, cfg)[:2]
-    else:
+    if hasattr(module, "loss_fn"):  # a loss that draws from the step's rng
         def loss_fn(params, batch, rng):
             return module.loss_fn(params, batch, rng, cfg)
+    else:
+        def loss_fn(params, batch, rng):
+            return module.loss_and_routes(params, batch, cfg)[:2]
     lora_on = getattr(cfg, "lora_rank", 0) > 0
     return ModelBundle(
         name=name,

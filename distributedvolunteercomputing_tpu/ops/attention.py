@@ -270,7 +270,8 @@ def get_attention_impl() -> str:
     return _impl
 
 
-def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None, turned: bool = False) -> bool:
+def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None, turned: bool = False,
+                    block_diffusion: Optional[int] = None) -> bool:
     if mask is not None:  # flash path has no additive-mask support
         return False
     tq, tk, d = q.shape[-2], k.shape[-2], q.shape[-1]
@@ -298,7 +299,7 @@ def _route_to_flash(q: jax.Array, k: jax.Array, causal: bool, mask, window=None,
             return False
     from distributedvolunteercomputing_tpu.ops.pallas_attention import choose_blocks
 
-    if choose_blocks(tq, tk, d, q.dtype, window, turned) is None:  # one head (and a turned call's tables) does not fit VMEM
+    if choose_blocks(tq, tk, d, q.dtype, window, turned, block_diffusion) is None:  # one head (and a turned call's tables) does not fit VMEM
         return False
     return _shard_axes(q, k) is not None
 
@@ -319,7 +320,8 @@ def _shard_axes(q: jax.Array, k: Optional[jax.Array] = None):
 
 
 def _flash_per_shard(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, window: Optional[int] = None
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool, window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """The kernel on each chip's own batch rows (dp) and heads (tp): attention
     mixes neither, so no q, k or v crosses a chip (a chip's query heads read
@@ -333,9 +335,9 @@ def _flash_per_shard(
 
     def core(q, k, v):  # traced once, at one chip's shapes
         if _kept_ctx is not None:
-            _kept_ctx.append(kept_bytes(q, k, window, v))
+            _kept_ctx.append(kept_bytes(q, k, window, v, block_diffusion=block_diffusion))
         # blocks: pallas_attention.choose_blocks of the (shard's) shape
-        return flash_attention(q, k, v, causal=causal, window=window)
+        return flash_attention(q, k, v, causal=causal, window=window, block_diffusion=block_diffusion)
 
     spec = P(*_shard_axes(q, k), None, None)
     return _per_shard(core, (q, k, v), (spec, spec, spec), spec)
@@ -364,6 +366,17 @@ class Rotary(NamedTuple):
     rotary_dim: Optional[int] = None
     inv_freq: Optional[jax.Array] = None
     scale: float = 1.0
+    positions: Optional[jax.Array] = None  # [T]; the row index where none are given
+
+
+def block_diffusion_mask(t: int, bd: int) -> jax.Array:
+    """``[t, t]`` bool, True where query row i sees key row j under the
+    block-diffusion mask over ``[x_0 ; x_t]`` (``pallas_attention.bd_keep``:
+    clean rows first, then the noised copy, ``t / 2`` each, blocks of ``bd``)."""
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import bd_keep
+
+    rows = jnp.arange(t, dtype=jnp.int32)
+    return bd_keep(rows[:, None], rows[None, :], t // 2, bd)
 
 
 def attention_merged(
@@ -374,7 +387,8 @@ def attention_merged(
     kv_heads: int,
     causal: bool = False,
     window: Optional[int] = None,
-    rotary: Optional[Rotary] = None,  # turns q and k (both by the same positions 0..T-1)
+    rotary: Optional[Rotary] = None,  # turns q and k (both by the same positions: 0..T-1, or its own)
+    block_diffusion: Optional[int] = None,  # rows [x_0 ; x_t] in blocks of so many positions; not causal
 ) -> jax.Array:
     """Attention from the projections' arrays to the output projection's,
     [B, T, H * Dv]: ``merge_heads(attention_core(rope(split_heads(q)),
@@ -388,27 +402,18 @@ def attention_merged(
     the same layout): no transpose, no array D/2 wide and no float32 copy of a
     head-shaped array between a projection and its kernel. The shapes decide;
     nothing else does."""
-    from distributedvolunteercomputing_tpu.ops.pallas_attention import LANES
-
     global _observed_rotary
     b, t, _ = q.shape
-    d, dv = q.shape[-1] // heads, v.shape[-1] // kv_heads
-
-    # what ``_route_to_flash`` and ``_shard_axes`` read of q and k by head, [B, n, T, D]
-    qs, ks = (jax.ShapeDtypeStruct((b, n, x.shape[1], d), x.dtype) for n, x in ((heads, q), (kv_heads, k)))
-    in_place = (
-        _seq_ctx is None and d % LANES == 0 and dv % LANES == 0
-        and (rotary is None or rotary.layout == "half")
-        and (window is None or (causal and window >= 1))
-        and _route_to_flash(qs, ks, causal, None, window, turned=rotary is not None)
-    )
-    if not in_place:
+    d = q.shape[-1] // heads
+    qs, ks = _by_head(q, k, heads, kv_heads)
+    if not merged_in_place(q, k, v, heads, kv_heads, causal, window, rotary, block_diffusion):
         qh, kh, vh = split_heads(q, heads), split_heads(k, kv_heads), split_heads(v, kv_heads)
         if rotary is not None:
             qh, kh = rope(qh, **rotary._asdict()), rope(kh, **rotary._asdict())
         prev, _observed_rotary = _observed_rotary, "none" if rotary is None else "outside"
         try:
-            return merge_heads(attention_core(qh, kh, vh, causal=causal, window=window))
+            return merge_heads(attention_core(
+                qh, kh, vh, causal=causal, window=window, block_diffusion=block_diffusion))
         finally:
             _observed_rotary = prev
     if _core_observer is not None:
@@ -416,10 +421,34 @@ def attention_merged(
             "flash", t, d, jnp.dtype(q.dtype).name, window, kv_heads,
             "merged", "none" if rotary is None else "kernel",
         )
-    return _flash_merged_per_shard(q, k, v, d, _shard_axes(qs, ks), causal, window, rotary)
+    return _flash_merged_per_shard(q, k, v, d, _shard_axes(qs, ks), causal, window, rotary, block_diffusion)
 
 
-def _flash_merged_per_shard(q, k, v, d: int, axes, causal, window, rotary: Optional[Rotary]):
+def _by_head(q, k, heads: int, kv_heads: int):
+    """What ``_route_to_flash`` and ``_shard_axes`` read of merged q and k by head, [B, n, T, D]."""
+    b, d = q.shape[0], q.shape[-1] // heads
+    return tuple(jax.ShapeDtypeStruct((b, n, x.shape[1], d), x.dtype) for n, x in ((heads, q), (kv_heads, k)))
+
+
+def merged_in_place(q, k, v, heads: int, kv_heads: int, causal: bool, window: Optional[int],
+                    rotary: Optional[Rotary], block_diffusion: Optional[int] = None) -> bool:
+    """Whether ``attention_merged`` hands this call (arrays, or their shapes
+    and dtypes) to the kernels on the projections' own layout."""
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import LANES
+
+    d, dv = q.shape[-1] // heads, v.shape[-1] // kv_heads
+    qs, ks = _by_head(q, k, heads, kv_heads)
+    return (
+        _seq_ctx is None and d % LANES == 0 and dv % LANES == 0
+        and (rotary is None or rotary.layout == "half")
+        and (window is None or (causal and window >= 1))
+        and _route_to_flash(qs, ks, causal, None, window, turned=rotary is not None,
+                            block_diffusion=block_diffusion)
+    )
+
+
+def _flash_merged_per_shard(q, k, v, d: int, axes, causal, window, rotary: Optional[Rotary],
+                            block_diffusion: Optional[int] = None):
     """``_flash_per_shard`` for the merged layout, heads of ``d`` lanes: a
     chip's heads are a contiguous part of the last axis (``tp`` of ``axes`` =
     ``_shard_axes`` cuts whole heads, the key/value heads with their query
@@ -432,16 +461,17 @@ def _flash_merged_per_shard(q, k, v, d: int, axes, causal, window, rotary: Optio
     if rotary is not None:
         rotary_dim = d if rotary.rotary_dim is None else rotary.rotary_dim
         tables = pa.rotary_tables(
-            q.shape[1], d, rotary.base, rotary_dim, rotary.inv_freq, rotary.scale)
+            q.shape[1], d, rotary.base, rotary_dim, rotary.inv_freq, rotary.scale, rotary.positions)
 
     def core(q, k, v, *tables):  # traced once, at one chip's shapes
         shard = (q.shape[-1] // d, k.shape[-1] // d)  # this chip's query and key/value heads
         if _kept_ctx is not None:
-            _kept_ctx.append(pa.kept_bytes(q, k, window, v, shard))
+            _kept_ctx.append(pa.kept_bytes(q, k, window, v, shard, block_diffusion))
         cos, sin = tables if tables else (None, None)
         if tables:
             k = pa.rotary_merged(k, cos, sin, rotary_dim)
-        return pa.flash_attention_merged(q, k, v, cos, sin, shard, causal, window, rotary_dim)
+        return pa.flash_attention_merged(
+            q, k, v, cos, sin, shard, causal, window, rotary_dim, None, block_diffusion)
 
     spec = P(axes[0], None, axes[1])
     return _per_shard(core, (q, k, v, *tables), (spec, spec, spec) + (P(None, None),) * len(tables), spec)
@@ -454,8 +484,9 @@ def attention_core(
     causal: bool = False,
     mask: Optional[jax.Array] = None,  # [B, 1|H, Tq, Tk] additive-able bool
     window: Optional[int] = None,  # causal, square: query i sees keys i - window < j <= i
+    block_diffusion: Optional[int] = None,  # not causal, square: ``block_diffusion_mask``
 ) -> jax.Array:
-    plain = window is None and q.shape[1] == k.shape[1]  # what the sp paths compute
+    plain = window is None and block_diffusion is None and q.shape[1] == k.shape[1]  # what the sp paths compute
     if _seq_ctx is not None and mask is None and q.shape[-2] == k.shape[-2] and plain:
         mesh, axis, impl = _seq_ctx
         if impl == "ulysses":
@@ -467,7 +498,7 @@ def attention_core(
         from distributedvolunteercomputing_tpu.parallel.ring_attention import ring_attention_bhtd
 
         return ring_attention_bhtd(q, k, v, mesh, axis, causal)
-    return attention_core_local(q, k, v, causal, mask, window)
+    return attention_core_local(q, k, v, causal, mask, window, block_diffusion)
 
 
 def attention_core_local(
@@ -477,6 +508,7 @@ def attention_core_local(
     causal: bool = False,
     mask: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """The single-device core (flash kernel or fused XLA), with no
     sequence-parallel routing — also the inner attention the Ulysses path
@@ -488,14 +520,17 @@ def attention_core_local(
         raise ValueError("a window needs causal attention over a square sequence")
     if mask is not None and h != h_kv:
         raise ValueError("a mask over grouped key/value heads is not built")
-    flash = _route_to_flash(q, k, causal, mask, window)
+    if block_diffusion is not None and (
+            causal or window is not None or q.shape[-2] != k.shape[-2] or q.shape[-2] % (2 * block_diffusion)):
+        raise ValueError("a block-diffusion mask is over [x_0 ; x_t], two halves of whole blocks, and is its own mask")
+    flash = _route_to_flash(q, k, causal, mask, window, block_diffusion=block_diffusion)
     if _core_observer is not None:
         _core_observer(
             "flash" if flash else "xla", q.shape[-2], q.shape[-1], jnp.dtype(q.dtype).name,
             window, h_kv, "heads", _observed_rotary,
         )
     if flash:
-        return _flash_per_shard(q, k, v, causal, window)
+        return _flash_per_shard(q, k, v, causal, window, block_diffusion)
     scale = 1.0 / (q.shape[-1] ** 0.5)
     if h != h_kv:
         # a group's query heads beside their key/value head: [B, Hkv, G, T, D]
@@ -510,6 +545,8 @@ def attention_core_local(
         if window is not None:
             causal_mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=-window)
         logits = jnp.where(causal_mask, logits, -1e30)
+    if block_diffusion is not None:  # the mask as an explicit array: small sizes, and the kernels' yardstick
+        logits = jnp.where(block_diffusion_mask(logits.shape[-1], block_diffusion), logits, -1e30)
     if mask is not None:
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)

@@ -38,6 +38,20 @@ Design (speeds: PERF.md, Findings of PR 27, measured on a TPU v5e):
   unmasked body, and only the blocks an edge crosses pay for the mask. The
   windowed calls carry their own kernel names (``dvc_flash_win_fwd`` /
   ``dvc_flash_win_bwd``) so that a device trace tells them from the full ones.
+- **A block-diffusion mask** (``block_diffusion=bd``, ``models/sdar_moe.py``):
+  the rows are a sequence's clean copy then its noised copy, ``[x_0 ; x_t]``,
+  L rows each, positions 0..L-1 twice, in blocks of ``bd`` positions. A clean
+  row sees the clean blocks up to and including its own, a noised row the
+  clean blocks strictly before its own and the noised rows of its own block,
+  in both directions; nothing clean sees anything noised. Neither causal nor a
+  band, so the loops' bounds are its own (``_bd_fwd_bounds`` /
+  ``_bd_bwd_bounds``): a query block of the clean half visits the clean key
+  blocks up to its diagonal, one of the noised half the clean key blocks before
+  its own blocks' start and the noised tile (two where a tile's edge falls
+  inside it) its diagonal crosses; the noised keys a clean row would meet under
+  a causal mask over the 2L rows, a quarter of that mask's pairs, are never
+  visited, and only the tiles an edge crosses pay for the mask. Kernel names
+  ``dvc_flash_bd_fwd`` / ``dvc_flash_bd_bwd``.
 - **The value head has its own width** (``v.shape[-1]``, latent attention's 128
   under keys of 128 + 64): the v, o, dO and dv blocks and the output's
   accumulator take it, the scores and dq / dk the key width. With equal widths
@@ -133,13 +147,23 @@ def vmem_bytes(tq: int, tk: int, d: int, dtype, block_q: int, block_k: int, turn
     return resident + streamed + stats + tiles + tables
 
 
+# The largest block under a block-diffusion mask. A noised query block's
+# diagonal tile holds block x bd kept pairs of its block x block, so with n
+# blocks a half the loops visit n^2 + 2n tiles where a causal mask over the 2n
+# would visit 2n^2 + n: 0.667 of them at n = 4 (1,024 at L = 4,096), 0.588 at
+# n = 8 (512), 0.545 at n = 16 (256).
+BD_BLOCK = 512
+
+
 def choose_blocks(
-    tq: int, tk: int, d: int, dtype, window: Optional[int] = None, turned: bool = False
+    tq: int, tk: int, d: int, dtype, window: Optional[int] = None, turned: bool = False,
+    block_diffusion: Optional[int] = None,
 ) -> Optional[Tuple[int, int]]:
     """(block_q, block_k) for a shape, or None where the kernel cannot hold
     one head in VMEM (``turned``: with the rotary tables' blocks of a call that
     turns its q). With a ``window`` the blocks are no larger than the
-    window where the sequence allows it: every block a query block visits is
+    window where the sequence allows it (under a ``block_diffusion`` mask no
+    larger than ``BD_BLOCK``): every block a query block visits is
     then crossed by an edge (two blocks visited for one block's worth of pairs
     inside the band), and still smaller blocks lost to the per-block cost.
     Measured, PR 33, TPU v5e, window 512 at T=8,192, 64 heads over 8, forward
@@ -156,6 +180,8 @@ def choose_blocks(
     largest = PREFERRED_BLOCK
     if window is not None:
         largest = max(128, min(PREFERRED_BLOCK, window))
+    if block_diffusion is not None:
+        largest = BD_BLOCK
 
     def pick(t: int) -> int:
         for b in (PREFERRED_BLOCK, 512, 256, 128):
@@ -228,20 +254,130 @@ def _loop(lo, hi, body) -> None:
 
 
 def _mask_scores(s, q0, kpos: jax.Array, q_axis: int, *, causal: bool, tk_valid: int, ragged: bool,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None, block_diffusion: Optional[int] = None):
     """The scores of a masked block with NEG_INF where they do not count: key
     positions ``kpos`` (an iota the shape of the block) outside the valid
     keys, under causal masking after their query (queries run from ``q0``
-    along ``q_axis``), and under a window ``window`` or more keys before it.
+    along ``q_axis``), and under a window ``window`` or more keys before it;
+    under a ``block_diffusion`` mask, what ``bd_keep`` drops.
     The valid-key compare is emitted only where padded keys exist
     (``ragged``)."""
     keep = kpos < tk_valid if ragged else None
+    if block_diffusion is not None:
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, kpos.shape, q_axis)
+        kept = bd_keep(qpos, kpos, tk_valid // 2, block_diffusion)
+        keep = kept if keep is None else keep & kept
     if causal:
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, kpos.shape, q_axis)
         keep = (kpos <= qpos) if keep is None else keep & (kpos <= qpos)
         if window is not None:
             keep = keep & (kpos > qpos - window)
     return s if keep is None else jnp.where(keep, s, NEG_INF)
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion mask: rows [x_0 ; x_t], ``half`` each, blocks of ``bd``
+# ---------------------------------------------------------------------------
+
+
+def bd_keep(qpos, kpos, half: int, bd: int):
+    """Whether query row ``qpos`` sees key row ``kpos`` (int32 arrays of one
+    shape, rows of ``[x_0 ; x_t]``: clean rows ``0..half-1``, noised rows
+    ``half..2 half - 1``, a row's position its index within its half, its block
+    ``position // bd``): clean -> clean iff the key's block is not after the
+    query's; noised -> clean iff it is strictly before; noised -> noised iff the
+    two are one block; clean -> noised never."""
+    if bd & (bd - 1) == 0:  # a shift where the block is a power of two: the chip has no vector divide
+        shift = bd.bit_length() - 1
+        block = lambda x: jax.lax.shift_right_arithmetic(x, jnp.int32(shift))  # noqa: E731
+    else:
+        block = lambda x: x // bd  # noqa: E731
+    q_clean, k_clean = qpos < half, kpos < half
+    qb = block(jnp.where(q_clean, qpos, qpos - half))
+    kb = block(jnp.where(k_clean, kpos, kpos - half))
+    same = kb == qb
+    return (k_clean & ((kb < qb) | (q_clean & same))) | (~k_clean & ~q_clean & same)
+
+
+def _bd_fwd_bounds(iq, block_q: int, block_k: int, n_k: int, half: int, bd: int, xp=jnp):
+    """The key blocks query block ``iq`` visits under the block-diffusion mask:
+    ``(a, b, c, d)`` for ``[0, a)`` with no mask arithmetic (clean keys every
+    row of the block sees), ``[a, b)`` masked (clean keys some row sees) and
+    ``[c, d)`` masked (the noised keys of the blocks its noised rows lie in).
+    Exact where a half is whole tiles, a superset otherwise (a tile that holds
+    rows of both halves visits what either needs; the mask is exact anywhere).
+    ``xp``: ``jnp`` on a traced ``iq``, ``numpy`` to count (``bd_tiles``)."""
+    t = 2 * half
+    start = lambda x: x // bd * bd  # noqa: E731  the first position of x's block
+    r0 = iq * block_q
+    r1 = xp.minimum(r0 + block_q, t) - 1
+    has_clean, has_noised = r0 < half, r1 >= half
+    c1 = xp.minimum(r1, half - 1)       # the block's last clean row
+    n0 = xp.maximum(r0, half) - half    # its first and last noised positions
+    n1 = r1 - half
+    seen = xp.maximum(xp.where(has_clean, start(c1) + bd, 0), xp.where(has_noised, start(n1), 0))
+    by_all = xp.minimum(xp.where(has_clean, start(r0) + bd, half), xp.where(has_noised, start(n0), half))
+    seen = xp.minimum(seen, half)
+    a = xp.minimum(by_all, seen) // block_k
+    b = (seen + block_k - 1) // block_k
+    c = xp.maximum(b, (half + start(n0)) // block_k)
+    d = xp.where(has_noised, (xp.minimum(half + start(n1) + bd, t) + block_k - 1) // block_k, c)
+    return a, b, c, xp.minimum(xp.maximum(d, c), n_k)
+
+
+def _bd_bwd_bounds(ik, block_q: int, block_k: int, n_q: int, half: int, bd: int, xp=jnp):
+    """The query blocks key block ``ik`` is visited from, the mirror of
+    ``_bd_fwd_bounds``: ``(s1, e1, e2, s3, e3, e4)`` for ``[s1, e1)`` masked,
+    ``[e1, e2)`` unmasked, ``[s3, e3)`` masked, ``[e3, e4)`` unmasked. A key
+    block of clean keys: the clean rows from its first key's block on (masked
+    until every row's block is at or after its last key's), then, past the
+    noised rows that see none of it, the noised rows of later blocks. A key
+    block of noised keys: the noised rows of its own blocks, masked. One that
+    holds both (a half that is not whole tiles): every block from its first
+    clean key's on, masked."""
+    t = 2 * half
+    start = lambda x: x // bd * bd  # noqa: E731
+    ceil = lambda x: (x + block_q - 1) // block_q  # noqa: E731
+    k0 = ik * block_k
+    k1 = xp.minimum(k0 + block_k, t) - 1
+    clean, noised = k0 + block_k <= half, k0 >= half
+    kc1 = xp.minimum(k1, half - 1)
+    t1 = start(k0) // block_q
+    t3 = half // block_q  # the first query block that holds a noised row
+    u1 = xp.clip(ceil(start(kc1)), t1, t3)
+    # where a query block holds rows of both halves it is visited masked, with all after it
+    t4 = (half + start(k0) + bd) // block_q if half % block_q == 0 else t3
+    t4 = xp.minimum(t4, n_q)
+    t5 = xp.clip(ceil(half + start(kc1) + bd), t4, n_q)
+    n0, n1 = xp.maximum(k0, half) - half, k1 - half
+    s3n = (half + start(n0)) // block_q
+    e3n = xp.minimum(ceil(half + start(n1) + bd), n_q)
+    s1 = xp.where(noised, 0, t1)
+    e1 = xp.where(clean, u1, xp.where(noised, 0, n_q))
+    e2 = xp.where(clean, t3, e1)
+    s3 = xp.where(clean, t4, xp.where(noised, s3n, n_q))
+    e3 = xp.where(clean, t5, xp.where(noised, xp.maximum(e3n, s3n), n_q))
+    e4 = xp.where(clean, n_q, e3)
+    return s1, e1, e2, s3, e3, e4
+
+
+def bd_tiles(t: int, bd: int, block_q: int, block_k: int) -> dict:
+    """Tiles the two kernels' loops visit over ``t`` rows (``[x_0 ; x_t]``)
+    under the block-diffusion mask, and what a causal mask over the same rows
+    makes them visit: ``{"fwd", "bwd", "causal_fwd", "causal_bwd"}``, counted
+    from the kernels' own bounds."""
+    import numpy as np
+
+    half = t // 2
+    n_q, n_k = -(-t // block_q), -(-t // block_k)
+    iq, ik = np.arange(n_q), np.arange(n_k)
+    _, b, c, d = _bd_fwd_bounds(iq, block_q, block_k, n_k, half, bd, np)
+    s1, _, e2, s3, _, e4 = _bd_bwd_bounds(ik, block_q, block_k, n_q, half, bd, np)
+    return {
+        "fwd": int(np.sum(b + d - c)), "bwd": int(np.sum(e2 - s1 + e4 - s3)),
+        "causal_fwd": int(np.sum(np.minimum(n_k, ((iq + 1) * block_q - 1) // block_k + 1))),
+        "causal_bwd": int(np.sum(n_q - (ik * block_k) // block_q)),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -252,16 +388,20 @@ def _mask_scores(s, q0, kpos: jax.Array, q_axis: int, *, causal: bool, tk_valid:
 def rotary_tables(
     t: int, d: int, base: float = 10000.0, rotary_dim: Optional[int] = None,
     inv_freq: Optional[jax.Array] = None, scale: float = 1.0,
+    positions: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """(cos, sin), each ``[T, D]`` float32, of ``ops/attention.rope``'s "half"
-    layout with the same ``base``, ``rotary_dim``, ``inv_freq`` and ``scale``,
+    layout with the same ``base``, ``rotary_dim``, ``inv_freq``, ``scale`` and
+    ``positions`` ([T]; the row index where none are given),
     laid out for ``_turn``: a lane's cosine, and its partner's sine with the
     sign the lane takes it with (minus on the first half of the rotated lanes);
     ones and zeros on the lanes a partial rotary passes through. The values are
     ``rope``'s own (same float32 products), made once a call by XLA."""
     r = d if rotary_dim is None else rotary_dim
     freqs = base ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r) if inv_freq is None else inv_freq
-    angles = jnp.arange(t)[:, None].astype(jnp.float32) * freqs[None, :]  # [T, R/2]
+    if positions is None:
+        positions = jnp.arange(t)
+    angles = positions[:, None].astype(jnp.float32) * freqs[None, :]  # [T, R/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
@@ -361,6 +501,12 @@ def _rotary_bwd(rotary_dim, interpret, tables, g):
 rotary_merged.defvjp(_rotary_fwd, _rotary_bwd)
 
 
+def _kernel_name(which: str, window: Optional[int], block_diffusion: Optional[int]) -> str:
+    """A device trace tells the calls apart by name: full, windowed, block-diffusion."""
+    kind = "bd_" if block_diffusion is not None else "" if window is None else "win_"
+    return f"dvc_flash_{kind}{which}"
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -369,6 +515,7 @@ rotary_merged.defvjp(_rotary_fwd, _rotary_bwd)
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *rest,
     scale, causal, block_q, block_k, tk_valid, n_k, window=None, rotary_dim=None,
+    block_diffusion=None,
 ):
     if rotary_dim is not None:  # this q block's rows of the rotary tables, [bq, D] float32
         cos_ref, sin_ref, *rest = rest
@@ -384,7 +531,7 @@ def _fwd_kernel(
         q = _turn(q.astype(jnp.float32), cos_ref[...], sin_ref[...], rotary_dim).astype(q.dtype)
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
-        window=window,
+        window=window, block_diffusion=block_diffusion,
     )
 
     def step(jk, masked: bool):
@@ -411,7 +558,12 @@ def _fwd_kernel(
     if causal:
         n_full = jnp.minimum(n_full, (iq * block_q) // block_k)
         n_end = jnp.minimum(n_k, ((iq + 1) * block_q - 1) // block_k + 1)
-    if window is None:
+    if block_diffusion is not None:
+        a, b, c, d = _bd_fwd_bounds(iq, block_q, block_k, n_k, tk_valid // 2, block_diffusion)
+        _loop(0, a, lambda jk: step(jk, False))
+        _loop(a, b, lambda jk: step(jk, True))
+        _loop(c, d, lambda jk: step(jk, True))
+    elif window is None:
         _loop(0, n_full, lambda jk: step(jk, False))
         _loop(n_full, n_end, lambda jk: step(jk, True))
     else:
@@ -438,6 +590,7 @@ def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
     bq: int, bk: int, interpret: bool, window: Optional[int] = None,
     heads: Optional[Tuple[int, int]] = None, tables=None, rotary_dim: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """(out [B, H, Tq, Dv], lse [B, H, nq, 1, bq] over the padded rows). With
     ``heads`` = (H, Hkv) q, k, v and out are merged, ``[B, T, H * D]``; with
@@ -469,6 +622,7 @@ def _flash_forward(
             _fwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_k=n_k, window=window,
             rotary_dim=None if tables is None else rotary_dim,
+            block_diffusion=block_diffusion,
         ),
         grid=(b, h, n_q),
         in_specs=[qspec, kvspec, vspec] + [spec for _, spec in turned],
@@ -490,7 +644,7 @@ def _flash_forward(
             vmem_bytes(tq, tk, d, q.dtype, bq, bk, tables is not None),
         ),
         interpret=interpret,
-        name="dvc_flash_fwd" if window is None else "dvc_flash_win_fwd",
+        name=_kernel_name("fwd", window, block_diffusion),
     )(qp, kp, vp, *[t for t, _ in turned])
     return (out[:, :tq] if merged else out[:, :, :tq]), lse
 
@@ -532,7 +686,7 @@ def _delta_merged(do: jax.Array, out: jax.Array, h: int, bq: int, interpret: boo
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
-    *, scale, causal, block_q, block_k, tk_valid, n_q, n_k, window=None,
+    *, scale, causal, block_q, block_k, tk_valid, n_q, n_k, window=None, block_diffusion=None,
 ):
     ik = pl.program_id(2)
 
@@ -546,7 +700,7 @@ def _bwd_kernel(
     vblk = v_ref[...]
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
-        window=window,
+        window=window, block_diffusion=block_diffusion,
     )
 
     def step(iq, masked: bool):
@@ -576,7 +730,13 @@ def _bwd_kernel(
         n_masked_end = jnp.minimum(n_q, ((ik + 1) * block_k + block_q - 2) // block_q)
     if tk_valid % block_k:
         n_masked_end = jnp.where(ik == n_k - 1, n_q, n_masked_end)
-    if window is None:
+    if block_diffusion is not None:
+        s1, e1, e2, s3, e3, e4 = _bd_bwd_bounds(ik, block_q, block_k, n_q, tk_valid // 2, block_diffusion)
+        _loop(s1, e1, lambda iq: step(iq, True))
+        _loop(e1, e2, lambda iq: step(iq, False))
+        _loop(s3, e3, lambda iq: step(iq, True))
+        _loop(e3, e4, lambda iq: step(iq, False))
+    elif window is None:
         _loop(first, n_masked_end, lambda iq: step(iq, True))
         _loop(n_masked_end, n_q, lambda iq: step(iq, False))
     else:
@@ -600,7 +760,8 @@ def _bwd_kernel(
 
 
 def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, g,
-                    window: Optional[int] = None, heads: Optional[Tuple[int, int]] = None):
+                    window: Optional[int] = None, heads: Optional[Tuple[int, int]] = None,
+                    block_diffusion: Optional[int] = None):
     """(dq, dk, dv) in the layout of q, k, v: by head, or merged with ``heads``
     = (H, Hkv). The q of ``residuals`` is the one the scores were taken of (a
     rotary call hands its q turned: this kernel takes no table)."""
@@ -650,6 +811,7 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_q=n_q, n_k=n_k, window=window,
+            block_diffusion=block_diffusion,
         ),
         grid=(b, h, n_k),
         in_specs=[head, kv_in, v_in, vhead, rows, rows],
@@ -675,7 +837,7 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
             vmem_bytes(tq, tk, d, q.dtype, bq, bk),
         ),
         interpret=interpret,
-        name="dvc_flash_bwd" if window is None else "dvc_flash_win_bwd",
+        name=_kernel_name("bwd", window, block_diffusion),
     )(qp, kp, vp, dop, lse, delta)
     if merged:
         if by_member:
@@ -696,7 +858,8 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
 
 
 def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None,
-             heads: Optional[Tuple[int, int]] = None, turned: bool = False) -> Tuple[int, int, bool]:
+             heads: Optional[Tuple[int, int]] = None, turned: bool = False,
+             block_diffusion: Optional[int] = None) -> Tuple[int, int, bool]:
     """The call's block sizes and mode: explicit blocks are clipped to the
     (tile-rounded) sequence, missing ones come from ``choose_blocks``."""
     _, h, h_kv, tq, tk, d, _ = _dims(q, k, k, heads)
@@ -704,8 +867,11 @@ def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None,
         raise ValueError(f"{h_kv} key/value heads do not divide {h} query heads")
     if window is not None and (not causal or tq != tk or window < 1):
         raise ValueError("a window needs causal attention over a square sequence")
+    if block_diffusion is not None and (
+            causal or window is not None or tq != tk or block_diffusion < 1 or tq % (2 * block_diffusion)):
+        raise ValueError("a block-diffusion mask is over [x_0 ; x_t], two halves of whole blocks, and is its own mask")
     if block_q is None or block_k is None:
-        chosen = choose_blocks(tq, tk, d, q.dtype, window, turned)
+        chosen = choose_blocks(tq, tk, d, q.dtype, window, turned, block_diffusion)
         if chosen is None:
             raise ValueError(
                 f"flash attention keeps one head in VMEM; Tq={tq}, Tk={tk}, D={d} "
@@ -719,21 +885,23 @@ def _resolve(q, k, block_q, block_k, interpret, causal=True, window=None,
 
 
 def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None,
-               v: Optional[jax.Array] = None, heads: Optional[Tuple[int, int]] = None) -> int:
+               v: Optional[jax.Array] = None, heads: Optional[Tuple[int, int]] = None,
+               block_diffusion: Optional[int] = None) -> int:
     """Bytes of the two residuals named by ``KEPT_NAMES`` for one call at
     ``choose_blocks``' blocks, as the chip lays them out: the output with its
     head dim padded to whole lanes (a D=64 output takes a D=128 one's room:
     the compiled step's kept stack is ``bf16[L,B,H,T,64]`` tiled (8, 128); a
     merged call's heads are whole lanes), and the f32 log-sum-exp rows of whole
     q-blocks."""
-    bq, _, _ = _resolve(q, k, None, None, False, True, window, heads)
+    bq, _, _ = _resolve(q, k, None, None, False, block_diffusion is None, window, heads,
+                        block_diffusion=block_diffusion)
     b, h, _, tq, _, d, dv = _dims(q, k, k if v is None else v, heads)
     if v is not None:  # the output is as wide as the value head
         d = dv
     return b * h * (tq * _round_up(d, LANES) * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(
     q: jax.Array,  # [B, H, Tq, D]
     k: jax.Array,  # [B, Hkv, Tk, D], Hkv dividing H
@@ -743,6 +911,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Drop-in for ops.attention.attention_core (no additive mask support).
 
@@ -751,15 +920,16 @@ def flash_attention(
     from attention_core's bottom-right alignment — the router in
     ops/attention.py only sends square causal shapes here. Query head ``h``
     reads key/value head ``h // (H / Hkv)``. With ``window`` (causal, square)
-    row i attends keys ``i - window < j <= i``.
+    row i attends keys ``i - window < j <= i``. With ``block_diffusion`` (not
+    causal, square, rows ``[x_0 ; x_t]``) row i attends what ``bd_keep`` keeps.
     """
-    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window)
-    return _flash_forward(q, k, v, causal, bq, bk, interp, window)[0]
+    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window, block_diffusion=block_diffusion)
+    return _flash_forward(q, k, v, causal, bq, bk, interp, window, block_diffusion=block_diffusion)[0]
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, window):
-    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window)
-    out, lse = _flash_forward(q, k, v, causal, bq, bk, interp, window)
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, window, block_diffusion):
+    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window, block_diffusion=block_diffusion)
+    out, lse = _flash_forward(q, k, v, causal, bq, bk, interp, window, block_diffusion=block_diffusion)
     # Named so that a checkpoint's policy can keep them (KEPT_NAMES): with the
     # kernel's two results saved, a rematerialised layer's recomputed forward
     # has no use for the kernel and its call there is dead code.
@@ -767,16 +937,16 @@ def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, window):
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, window, residuals, g):
+def _fa_bwd(causal, block_q, block_k, interpret, window, block_diffusion, residuals, g):
     q, k = residuals[0], residuals[1]
-    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window)
-    return _flash_backward(causal, bq, bk, interp, residuals, g, window)
+    bq, bk, interp = _resolve(q, k, block_q, block_k, interpret, causal, window, block_diffusion=block_diffusion)
+    return _flash_backward(causal, bq, bk, interp, residuals, g, window, block_diffusion=block_diffusion)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention_merged(
     q: jax.Array,  # [B, T, H * D] as the projection made it, D whole lanes; turned here if ``cos`` is given
     k: jax.Array,  # [B, T, Hkv * D], Hkv dividing H; already turned (``rotary_merged``)
@@ -788,6 +958,7 @@ def flash_attention_merged(
     window: Optional[int] = None,
     rotary_dim: Optional[int] = None,
     interpret: Optional[bool] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """``flash_attention`` on the projections' own layout, giving [B, T, H * Dv]
     (what the output projection reads): the same two kernels, a head being
@@ -795,7 +966,8 @@ def flash_attention_merged(
     has taken up; the backward, whose kernel holds a head's q resident and
     visits it from every key block, is handed q turned by one pass outside
     (``_turn_merged``) and its dq takes one pass back."""
-    return _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret))[0]
+    return _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret),
+                        block_diffusion)[0]
 
 
 def _interpreted(interpret: Optional[bool]) -> bool:
@@ -805,33 +977,39 @@ def _interpreted(interpret: Optional[bool]) -> bool:
 # Both halves are jitted: a model's layers of one kind (Laguna's three sliding
 # layers, its two full ones) call them with equal shapes, and each is traced
 # and lowered once a step program instead of once a layer.
-@functools.partial(jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret"))
-def _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret):
-    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None)
+@functools.partial(
+    jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret", "block_diffusion"))
+def _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret, block_diffusion=None):
+    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None, block_diffusion)
     tables = None if cos is None else (cos, sin)
-    return _flash_forward(q, k, v, causal, bq, bk, interpret, window, heads, tables, rotary_dim)
+    return _flash_forward(q, k, v, causal, bq, bk, interpret, window, heads, tables, rotary_dim, block_diffusion)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret"))
-def _fam_backward(q, k, v, out, lse, cos, sin, g, heads, causal, window, rotary_dim, interpret):
-    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None)
+@functools.partial(
+    jax.jit, static_argnames=("heads", "causal", "window", "rotary_dim", "interpret", "block_diffusion"))
+def _fam_backward(q, k, v, out, lse, cos, sin, g, heads, causal, window, rotary_dim, interpret,
+                  block_diffusion=None):
+    bq, bk, _ = _resolve(q, k, None, None, interpret, causal, window, heads, cos is not None, block_diffusion)
     if cos is not None:
         q = _turn_pass(q, cos, sin, rotary_dim, False, interpret)
-    dq, dk, dv = _flash_backward(causal, bq, bk, interpret, (q, k, v, out, lse), g, window, heads)
+    dq, dk, dv = _flash_backward(
+        causal, bq, bk, interpret, (q, k, v, out, lse), g, window, heads, block_diffusion)
     if cos is not None:
         dq = _turn_pass(dq, cos, sin, rotary_dim, True, interpret)
     return dq, dk, dv
 
 
-def _fam_fwd(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret):
-    out, lse = _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret))
+def _fam_fwd(q, k, v, cos, sin, heads, causal, window, rotary_dim, interpret, block_diffusion):
+    out, lse = _fam_forward(q, k, v, cos, sin, heads, causal, window, rotary_dim, _interpreted(interpret),
+                            block_diffusion)
     out, lse = checkpoint_name(out, KEPT_NAMES[0]), checkpoint_name(lse, KEPT_NAMES[1])  # as ``_fa_fwd``
     return out, (q, k, v, out, lse, cos, sin)
 
 
-def _fam_bwd(heads, causal, window, rotary_dim, interpret, residuals, g):
+def _fam_bwd(heads, causal, window, rotary_dim, interpret, block_diffusion, residuals, g):
     cos, sin = residuals[5:]
-    grads = _fam_backward(*residuals, g, heads, causal, window, rotary_dim, _interpreted(interpret))
+    grads = _fam_backward(*residuals, g, heads, causal, window, rotary_dim, _interpreted(interpret),
+                          block_diffusion)
     return (*grads, None, None) if cos is None else (*grads, jnp.zeros_like(cos), jnp.zeros_like(sin))
 
 

@@ -71,7 +71,10 @@ COPIES_IN_FLIGHT = 2
 # its log points.
 ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
                 "moe_rows_moved", "moe_chunks_extra", "moe_act_zero_share", "moe_bias_max",
-                "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss")
+                "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss",
+                # a block-diffusion step (models/sdar_moe.py): the share of its tokens the noise
+                # masked, the head's rows over the layers', the attention loops' tiles over a causal mask's
+                "diffusion_masked_share", "diffusion_head_rows_share", "attention_bd_tiles_share")
 ROUTE_EVERY = 10
 # What a step with recurrent mixers says of its chunked scans, noted when and as
 # the routing is: a key's prefix names its span (``ssm_*`` the attributes of an

@@ -40,9 +40,10 @@ VOCABULARY: Dict[str, str] = {
     "moe_route": "moe",
     "loss_head": "loss_head",
     "optimizer": "optimizer",
+    "noising": "other",  # a block-diffusion step's draw of its noise and its noised copy (models/sdar_moe.py)
 }
-OTHER = "other"  # resolved, under no word: embedding, final norm, the layer scan's own slices
-GROUPS: Tuple[str, ...] = (*dict.fromkeys(VOCABULARY.values()), OTHER)
+OTHER = "other"  # resolved, under no word of a group's own: embedding, final norm, the layer scan's own slices
+GROUPS: Tuple[str, ...] = tuple(dict.fromkeys((*VOCABULARY.values(), OTHER)))
 PASSES = ("fwd", "refwd", "bwd")
 
 # A word as a whole identifier: a path element (``/mlp/``) or the argument of

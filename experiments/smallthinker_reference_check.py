@@ -5,7 +5,8 @@ sequence of 16,384 tokens; ``--config lfm2-24b-a2b``: published layers 0 and
 2-5, eight of 64 experts, an eighth of the vocabulary, 8,192 tokens;
 ``--config glm-4.7-flash``: published layers 0-4, likewise;
 ``--config nemotron-3-nano-30b-a3b``: published blocks 0-6, eight of 128 experts;
-``--config kimi-linear-48b-a3b``: published layers 1-5, eight of 256 experts, 4,096 tokens): the
+``--config kimi-linear-48b-a3b``: published layers 1-5, eight of 256 experts, 4,096 tokens;
+``--config sdar-30b-a3b-chat``: published layers 0-4, sixteen of 128 experts, 4,096 data tokens as 8,192 rows): the
 readings that set ``reference_check`` in ``benchmark/configs/<config>.json``.
 
     chiprun -- python experiments/smallthinker_reference_check.py --seeds 3 --left-out
@@ -139,8 +140,11 @@ def main() -> int:
     @jax.jit
     def program(params, tokens, targets):
         def f(p):
-            loss, _, routes = model.loss_and_routes(
-                p, {"tokens": tokens, "targets": targets}, bundle.config)
+            batch = {"tokens": tokens, "targets": targets}
+            if hasattr(model, "loss_fn"):  # a loss that draws its noise: keyed as the harness keys it
+                loss, _, routes = model.loss_and_routes(p, batch, jax.random.PRNGKey(0), bundle.config)
+            else:
+                loss, _, routes = model.loss_and_routes(p, batch, bundle.config)
             return loss, routes
 
         (loss, routes), grads = jax.value_and_grad(f, has_aux=True)(params)

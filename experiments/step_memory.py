@@ -37,6 +37,11 @@ CELLS = {
     # what the cell's batch of 2 was chosen against (PERF.md section 4): 17.21e9, over the chip
     "nemotron3-nano-batch4": ("nemotron3_nano_30b_a3b", 1, 1, 4, {"n_layers": 7, "experts_held": 8, "vocab": 16384}),
     "kimi-linear-solo-8k": ("kimi_linear_48b_a3b", 1, 1, 2, {"n_layers": 5, "experts_held": 8, "vocab": 20480}),
+    "sdar-solo-4k": ("sdar_30b_a3b", 1, 1, 2,
+                     {"n_layers": 5, "experts_held": 16, "vocab": 18992, "mask_id": 18991}),
+    # what the cell's five layers were chosen against (PERF.md section 4): six hold 645.6 M parameters
+    "sdar-six-layers": ("sdar_30b_a3b", 1, 1, 2,
+                        {"n_layers": 6, "experts_held": 16, "vocab": 18992, "mask_id": 18991}),
 }
 
 

@@ -261,6 +261,15 @@ _YARDSTICK_PINS = (
     ("test_manifest_as_the_kimi_tests_asserted_it_nine_places_up", "test_yardstick_scopes.py",
      "asserts Kimi's tail nine places up from the manifest's end; it is eleven now "
      "(checked, two places up, in test_yardstick_collective_pairs.py)"),
+    # PR 60 (sdar-30b-a3b-chat, sdar-solo-4k, attention.bd_device_ms / bd_roofline / bd_tiles_share and
+    # diffusion.head_rows_share; the cell appended to tok_s_chip's list and to twenty-three per-layer lists):
+    # tests/yardstick/test_yardstick_sdar_moe.py asserts what each of these asserted, against the manifest less this
+    # PR's entries.
+    ("test_configuration_file_is_what_the_program_runs", "[sdar-30b-a3b-chat]",
+     "asserts reduced == []; sdar-30b-a3b-chat lists its cut (checked in test_yardstick_sdar_moe.py)"),
+    ("test_manifest_as_the_scope_tests_asserted_it_two_places_up", "test_yardstick_collective_pairs.py",
+     "asserts that PR 57's two metrics end per_layer, 68 entries; PR 60 appended four metrics, a cell and a "
+     "configuration (checked in test_yardstick_sdar_moe.py)"),
 )
 
 

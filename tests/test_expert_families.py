@@ -239,7 +239,8 @@ def test_no_model_knows_the_registry_and_the_shared_helpers_know_no_model():
 
 @pytest.mark.parametrize("name", sorted(_LANGUAGE_MODELS))
 def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves_a_bias(name):
-    rehearsals = {"olmoe_1b_7b": "tiny-rehearsal-olmoe", **{n: r for _, n, r, *_ in FAMILIES.values()}}
+    rehearsals = {"olmoe_1b_7b": "tiny-rehearsal-olmoe", "sdar_30b_a3b": "tiny-rehearsal-sdar",
+                  **{n: r for _, n, r, *_ in FAMILIES.values()}}
     if name in rehearsals:
         overrides = Manifest().load_config(rehearsals[name])["model_overrides"]
     else:

@@ -770,3 +770,139 @@ def test_merged_entry_per_shard_under_a_mesh(eight_devices):
     np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
     for x, y in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5)
+
+
+# -- the block-diffusion mask (rows [x_0 ; x_t], models/sdar_moe.py) -------------------------------
+
+# (b, heads, key/value heads, rows t = 2L, head dim, block length, block_q, block_k)
+_BD_CASES = {
+    "blocks of 4, whole tiles": (2, 2, 2, 64, 16, 4, 16, 16),
+    "blocks of 32, whole tiles": (1, 2, 2, 256, 16, 32, 64, 64),
+    "grouped heads, 4 over 1": (1, 4, 1, 64, 16, 4, 16, 16),
+    "a length that is not a whole tile": (1, 4, 2, 72, 16, 4, 16, 16),
+    "a half that is not whole tiles, blocks of 8": (1, 2, 1, 80, 16, 8, 32, 32),
+    "query tiles wider than key tiles": (1, 2, 2, 128, 16, 4, 32, 16),
+    "key tiles wider than query tiles": (1, 2, 2, 128, 16, 32, 16, 64),
+    "the blocks the code chooses": (1, 2, 1, 64, 16, 4, None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_BD_CASES))
+def test_block_diffusion_kernel_matches_the_xla_core_forward_and_all_three_gradients(case):
+    """The kernels under the three-part mask (interpreted) against the XLA core
+    under the same mask as an explicit array: the output and dq, dk, dv."""
+    from distributedvolunteercomputing_tpu.ops.attention import attention_core_local
+
+    b, h, hkv, t, d, bd, bq, bk = _BD_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, cot = (jax.random.normal(k, (b, h, t, d)) for k in (ks[0], ks[3]))
+    k, v = (jax.random.normal(kk, (b, hkv, t, d)) for kk in ks[1:3])
+    set_attention_impl("xla")
+    try:
+        want, vjp = jax.vjp(lambda q, k, v: attention_core_local(q, k, v, block_diffusion=bd), q, k, v)
+        want = (want, *vjp(cot))
+    finally:
+        set_attention_impl("auto")
+    got, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, False, bq, bk, True, None, bd), q, k, v)
+    for name, x, y in zip(("out", "dq", "dk", "dv"), (got, *vjp(cot)), want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("t,bd,bq,bk", [
+    (64, 4, 16, 16), (64, 4, 32, 16), (64, 4, 16, 32), (48, 4, 16, 16), (80, 8, 32, 32), (72, 4, 16, 16),
+    (256, 32, 64, 64), (144, 4, 144, 144), (40, 4, 16, 16), (96, 12, 32, 32), (2048, 4, 512, 512),
+])
+def test_block_diffusion_loops_visit_every_tile_with_a_kept_pair_once_and_mask_where_an_edge_crosses(t, bd, bq, bk):
+    """Both kernels' loop bounds against the mask itself, tile by tile: every
+    tile that holds a kept pair is visited exactly once, a tile visited with no
+    mask arithmetic is wholly kept, and where the halves are whole tiles no
+    tile is visited for nothing."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+    from distributedvolunteercomputing_tpu.ops.attention import block_diffusion_mask
+
+    half, n_q, n_k = t // 2, -(-t // bq), -(-t // bk)
+    padded = np.zeros((n_q * bq, n_k * bk), bool)
+    padded[:t, :t] = np.asarray(block_diffusion_mask(t, bd))
+    any_kept = padded.reshape(n_q, bq, n_k, bk).any(axis=(1, 3))
+    wholly = np.array([[padded[i * bq:min((i + 1) * bq, t), j * bk:(j + 1) * bk].all() for j in range(n_k)]
+                       for i in range(n_q)])
+    a, b, c, d = pa._bd_fwd_bounds(np.arange(n_q), bq, bk, n_k, half, bd, np)
+    s1, e1, e2, s3, e3, e4 = pa._bd_bwd_bounds(np.arange(n_k), bq, bk, n_q, half, bd, np)
+    fwd, fwd_bare = np.zeros((n_q, n_k), int), np.zeros((n_q, n_k), bool)
+    bwd, bwd_bare = np.zeros((n_q, n_k), int), np.zeros((n_q, n_k), bool)
+    for i in range(n_q):
+        for lo, hi, bare in ((0, a[i], True), (a[i], b[i], False), (c[i], d[i], False)):
+            fwd[i, lo:hi] += 1
+            fwd_bare[i, lo:hi] |= bare
+    for j in range(n_k):
+        for lo, hi, bare in ((s1[j], e1[j], False), (e1[j], e2[j], True), (s3[j], e3[j], False), (e3[j], e4[j], True)):
+            bwd[lo:hi, j] += 1
+            bwd_bare[lo:hi, j] |= bare
+    for visits, bare in ((fwd, fwd_bare), (bwd, bwd_bare)):
+        assert visits.max() == 1 and (visits[any_kept] == 1).all() and wholly[bare].all()
+        if half % bq == 0 and half % bk == 0:
+            assert not visits[~any_kept].any()
+    tiles = pa.bd_tiles(t, bd, bq, bk)
+    assert (tiles["fwd"], tiles["bwd"]) == (fwd.sum(), bwd.sum())
+    causal = np.tril(np.ones((n_q * bq, n_k * bk), bool)).reshape(n_q, bq, n_k, bk).any(axis=(1, 3))
+    assert tiles["causal_fwd"] == tiles["causal_bwd"] == causal.sum()
+
+
+def test_block_diffusion_blocks_and_the_tiles_they_visit_at_the_cells_shape():
+    """``choose_blocks`` / ``vmem_bytes`` at Tq = Tk = 2L = 8,192, head 128,
+    bfloat16, a turned call: blocks of 512 (Laguna's windowed answer), inside
+    the budget, and the loops then visit 80 of a causal mask's 136 tiles; a
+    causal or windowed call's answer is what it was."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    bf16 = jnp.bfloat16
+    assert pa.choose_blocks(8192, 8192, 128, bf16, None, True, 4) == (pa.BD_BLOCK, pa.BD_BLOCK) == (512, 512)
+    assert pa.vmem_bytes(8192, 8192, 128, bf16, 512, 512, turned=True) <= pa.VMEM_BUDGET_BYTES
+    assert pa.choose_blocks(8192, 8192, 128, bf16, None, True) == (1024, 1024)
+    assert pa.choose_blocks(8192, 8192, 128, bf16, 512, True) == (512, 512)
+    tiles = pa.bd_tiles(8192, 4, 512, 512)
+    assert tiles == {"fwd": 80, "bwd": 80, "causal_fwd": 136, "causal_bwd": 136}  # n^2 + 2n of 2n^2 + n, n = 8
+    assert pa.bd_tiles(8192, 4, 1024, 1024)["fwd"] == 24  # of 36: why the blocks are not the preferred 1,024
+    assert pa._kernel_name("fwd", None, 4) == "dvc_flash_bd_fwd" and pa._kernel_name("bwd", None, 4) == "dvc_flash_bd_bwd"
+    assert pa._kernel_name("fwd", None, None) == "dvc_flash_fwd" and pa._kernel_name("bwd", 512, None) == "dvc_flash_win_bwd"
+
+
+def test_block_diffusion_merged_entry_turns_by_position_and_refuses_what_it_is_not():
+    """``attention_merged`` under the mask with positions 0..L-1 twice, on the
+    kernels (interpreted, D = 128: the merged layout, q turned on the tile from
+    tables built of the positions), against the XLA core by head with ``rope`` at
+    the same positions; and what the mask is not (causal, windowed, a length that
+    is not two halves of whole blocks) is refused by kernel and core alike."""
+    from distributedvolunteercomputing_tpu.ops import attention as A
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    b, t, h, hkv, d, bd = 1, 128, 2, 1, 128, 4
+    q, k, v, cot = _merged_qkv(b, t, h, hkv, d, d)
+    rotary = A.Rotary(base=1e6, layout="half", positions=jnp.tile(jnp.arange(t // 2), 2))
+    seen = []
+    A.set_core_observer(lambda *a: seen.append(f"{a[0]}:{a[6]}/{a[7]}"))
+    try:
+        set_attention_impl("flash")
+        got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
+            q, k, v, h, hkv, rotary=rotary, block_diffusion=bd), q, k, v)
+        got = (got, *vjp(cot))
+        set_attention_impl("xla")
+        want, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
+            q, k, v, h, hkv, rotary=rotary, block_diffusion=bd), q, k, v)
+        want = (want, *vjp(cot))
+    finally:
+        set_attention_impl("auto")
+        A.set_core_observer(None)
+    assert seen == ["flash:merged/kernel", "xla:heads/outside"], seen
+    for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5, err_msg=name)
+    # positions matter: the row index in their place is another result
+    plain = A.attention_merged(q, k, v, h, hkv, rotary=A.Rotary(base=1e6, layout="half"), block_diffusion=bd)
+    assert float(jnp.max(jnp.abs(plain - want[0]))) > 1e-3
+    qh = jnp.zeros((1, 2, 64, 16))
+    for bad in (dict(causal=True, block_diffusion=4), dict(causal=True, window=8, block_diffusion=4),
+                dict(block_diffusion=5)):
+        with pytest.raises(ValueError):
+            A.attention_core_local(qh, qh, qh, **bad)
+        with pytest.raises(ValueError):
+            pa.flash_attention(qh, qh, qh, bad.get("causal", False), None, None, True, bad.get("window"), bad["block_diffusion"])

@@ -93,6 +93,79 @@ def test_flash_fwd_bwd_compiles(v5e, shape, blocks):
     assert "dvc_flash_fwd" in text and "dvc_flash_bwd" in text  # the names a trace shows
 
 
+def test_block_diffusion_fwd_bwd_compiles_at_the_cells_shape(v5e):
+    """The kernels under the three-part mask at sdar-solo-4k's layer: 2 x 8,192
+    rows, 32 query heads over 4, head 128, on the projections' own layout, q
+    turned on the tile from tables built of positions 0..4,095 twice."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    one = SingleDeviceSharding(v5e[0])
+    b, t, h, hkv, d = 2, 8192, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=one)
+
+    def fwd_bwd(q, k, v):
+        cos, sin = pa.rotary_tables(t, d, 1e6, positions=jnp.tile(jnp.arange(t // 2), 2))
+
+        def loss(q, k, v):
+            k = pa.rotary_merged(k, cos, sin, d, False)
+            out = pa.flash_attention_merged(q, k, v, cos, sin, (h, hkv), False, None, d, False, 4)
+            return out.astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, q, kv, kv)
+    assert "dvc_flash_bd_fwd" in text and "dvc_flash_bd_bwd" in text  # the names a trace shows
+    assert "dvc_flash_fwd" not in text and "dvc_flash_win" not in text
+
+
+# sha256 (16 digits) of the LOWERED forward + backward of a causal and a windowed call, by head and
+# on the merged layout with the rotary turn, for the described chip with name stacks only (no Python
+# frames: ``jax_traceback_in_locations_limit`` 0). Read at the parent of PR 60 (9422aa4) and at the
+# change by one script; a kernel PR that means to change them reads them again.
+_LOWERED_AS_BEFORE = {
+    ("merged", None): "bf44953dbfd233c5", ("heads", None): "382405a0cf5b529c",
+    ("merged", 512): "7b498243f1bbf819", ("heads", 512): "dcf7cb4c87f2a2d9",
+}
+
+
+@pytest.mark.parametrize("layout,window", list(_LOWERED_AS_BEFORE))
+def test_a_causal_and_a_windowed_call_lower_as_before_the_third_mask(v5e, monkeypatch, layout, window):
+    """The block-diffusion mask is a keyword the causal and windowed calls never
+    pass: what they lower to, kernels' bodies included, is the text it was."""
+    import hashlib
+
+    from distributedvolunteercomputing_tpu.ops import attention, pallas_attention
+    from distributedvolunteercomputing_tpu.utils import jaxenv
+
+    monkeypatch.setattr(jaxenv, "tpu_backend", lambda: True)
+    monkeypatch.setattr(pallas_attention, "tpu_backend", lambda: True)
+    one = SingleDeviceSharding(v5e[0])
+
+    def x(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+
+    def call(q, k, v):
+        if layout == "merged":
+            return attention.attention_merged(
+                q, k, v, 8, 2, causal=True, window=window, rotary=attention.Rotary(layout="half"))
+        return flash_attention(q, k, v, True, None, None, False, window)
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(lambda q, k, v: call(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    shapes = ((x(2, 2048, 8 * 128), x(2, 2048, 2 * 128), x(2, 2048, 2 * 128)) if layout == "merged"
+              else (x(2, 8, 2048, 64), x(2, 2, 2048, 64), x(2, 2, 2048, 64)))
+    before = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = jax.jit(fwd_bwd).lower(*shapes).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", before)
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == _LOWERED_AS_BEFORE[(layout, window)], f"LOWERED {layout} {window} {got}"
+
+
 @pytest.fixture
 def as_on_the_chip(monkeypatch):
     """The program's backend checks answer as they do on the chip: bf16
@@ -562,6 +635,8 @@ _CELL_LAYOUTS = {
         ("nemotron3_nano_30b_a3b", 1, 1, 2, 7, dict(experts_held=8, vocab=16384)), {"heads/none": 1}),
     "kimi-linear-solo-8k": (
         ("kimi_linear_48b_a3b", 1, 1, 2, 5, dict(experts_held=8, vocab=20480)), {"heads/none": 1}),
+    "sdar-solo-4k": (  # one scanned layer under the block-diffusion mask, turned by position on the tile
+        ("sdar_30b_a3b", 1, 1, 2, 5, dict(experts_held=16, vocab=18992, mask_id=18991)), {"merged/kernel": 1}),
 }
 
 
