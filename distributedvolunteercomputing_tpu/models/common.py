@@ -14,6 +14,7 @@ AMP the same way (SURVEY.md L1/L5).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -269,6 +270,78 @@ def _project_vocab(x: jax.Array, head: jax.Array, head_layout: str) -> jax.Array
     return jnp.einsum(eq, x, head.astype(x.dtype), preferred_element_type=jnp.float32)
 
 
+def _xent_chunks(x, head, labels, weights, divisor, chunk: int, head_layout: str,
+                 with_dx: bool = False, with_dhead: bool = False):
+    """ONE ``lax.scan`` over the ``chunk``-sized slices of T: ``(loss, dx, dhead)``,
+    the loss alone (one vocabulary-sized product a chunk) unless a gradient is
+    asked for. A chunk that is asked makes ``dlogits`` while its logits are in
+    hand and spends it at once: ``dx``'s rows (in ``x``'s dtype, stacked by the
+    scan) and ``dhead``'s sum (float32, carried by the loop, in ``head``'s dtype
+    at the end), both of the loss as it is returned, ``nll . weights / divisor``.
+    The products take what autodiff's took (read off the compiled steps of
+    ``olmoe-solo`` and ``medium-solo``): the float32 ``dlogits`` against the
+    operand in compute dtype, float32 out of the MXU."""
+    b, t, _ = x.shape
+    n = t // chunk
+    head_c = head.astype(x.dtype)  # once, not a chunk
+    contract_v = 0 if head_layout == "vd" else 1
+
+    def by_chunk(a):  # [B, T, ...] -> [n, B, chunk, ...]: scan's leading axis is the chunk index
+        return jnp.moveaxis(a.reshape(b, n, chunk, *a.shape[2:]), 1, 0)
+
+    def body(carry, chunk_in):
+        nll_sum, dhead = carry
+        xc, lc, *wc = chunk_in
+        logits = _project_vocab(xc, head_c, head_layout)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
+        nll = (logz - gold) * wc[0] if wc else logz - gold
+        dxc = None
+        if with_dx or with_dhead:
+            hit = lc[..., None] == jnp.arange(logits.shape[-1])
+            scale = (wc[0] / divisor)[..., None] if wc else 1.0 / divisor
+            dlogits = (jnp.exp(logits - logz[..., None]) - hit) * scale
+        if with_dx:
+            dxc = jax.lax.dot_general(dlogits, head_c, (((2,), (contract_v,)), ((), ())),
+                                      preferred_element_type=jnp.float32).astype(x.dtype)
+        if with_dhead:
+            lhs, rhs = (dlogits, xc) if head_layout == "vd" else (xc, dlogits)
+            dhead = dhead + jax.lax.dot_general(lhs, rhs, (((0, 1), (0, 1)), ((), ())),
+                                                preferred_element_type=jnp.float32)
+        return (nll_sum + jnp.sum(nll), dhead), dxc
+
+    chunks = (by_chunk(x), by_chunk(labels)) + (() if weights is None else (by_chunk(weights),))
+    zero = jnp.zeros((), jnp.float32)
+    (nll_sum, dhead), dx = jax.lax.scan(
+        body, (zero, jnp.zeros(head.shape, jnp.float32) if with_dhead else None), chunks)
+    if with_dx:
+        dx = jnp.moveaxis(dx, 0, 1).reshape(x.shape)
+    return nll_sum / divisor, dx, dhead.astype(head.dtype) if with_dhead else None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _xent_scalar(x, head, labels, weights, divisor, chunk, head_layout):
+    return _xent_chunks(x, head, labels, weights, divisor, chunk, head_layout)[0]
+
+
+def _xent_scalar_fwd(x, head, labels, weights, divisor, chunk, head_layout):
+    # symbolic_zeros: each argument comes with whether it is differentiated at all, so a frozen
+    # head (an adapter-only finetune) costs no [d, V] accumulator and no third product
+    loss, dx, dhead = _xent_chunks(
+        x.value, head.value, labels.value, None if weights is None else weights.value, divisor.value,
+        chunk, head_layout, with_dx=x.perturbed, with_dhead=head.perturbed)
+    return loss, (dx, dhead)
+
+
+def _xent_scalar_bwd(chunk, head_layout, residuals, g):
+    # the gradients of the loss times the scalar cotangent (the literal 1.0 under value_and_grad: no pass);
+    # a loss nobody's cotangent reaches never gets here (the backward pass skips an all-zero cotangent)
+    return tuple(None if r is None else r * g.astype(r.dtype) for r in residuals) + (None, None, None)
+
+
+_xent_scalar.defvjp(_xent_scalar_fwd, _xent_scalar_bwd, symbolic_zeros=True)
+
+
 @jax.named_scope("loss_head")  # names the head's ops in a profiler trace
 def lm_xent_chunked(
     x: jax.Array,
@@ -283,51 +356,34 @@ def lm_xent_chunked(
 
     For GPT-2-small shapes (B=8, T=1024, V=50257) the full logits tensor is
     1.6 GB f32 — and its backward residuals double that. This scans over T in
-    ``chunk``-sized slices with a checkpointed body, so peak memory is one
-    [B, chunk, V] buffer (~206 MB at chunk=128) and the backward pass
-    recomputes each chunk's logits instead of saving them.
+    ``chunk``-sized slices, so peak memory is one [B, chunk, V] buffer (~206 MB
+    at chunk=128), and keeps nothing of a chunk for a backward pass: the head
+    is the last thing a forward does and its result is one scalar, so where the
+    loss is differentiated (a ``jax.custom_vjp``) the SAME loop makes each
+    chunk's ``dlogits`` from the logits in hand and both gradient products from
+    it, three vocabulary-sized products a chunk, and the backward pass is the
+    two gradients times the scalar cotangent. An undifferentiated call
+    (evaluation) runs the loop with the logits product alone.
 
     ``head`` is the projection matrix: [V, d] (``head_layout="vd"``, tied
     embeddings — GPT-2/BERT) or [d, V] (``"dv"``, a separate lm_head — Llama).
     ``mask`` is an optional 0/1 token mask (MLM objective), over whose sum the
     loss is the mean; with a ``denominator`` it is per-token WEIGHTS and the loss
     is the weighted sum over that divisor (a denoising loss: masked tokens by
-    ``1 / t`` over B x L).
+    ``1 / t`` over B x L). Neither ``mask`` nor ``denominator`` is differentiated.
     """
     b, t, _ = x.shape
     if t % chunk != 0:
-        chunk = t  # tiny test configs: single chunk, same math
-    n = t // chunk
-    if n <= 1:
-        logits = _project_vocab(x, head, head_layout)
-        return softmax_xent(logits, labels, mask, denominator)
-
-    # [n, B, chunk, ...] so scan's leading axis is the chunk index.
-    xs = jnp.moveaxis(x.reshape(b, n, chunk, x.shape[-1]), 1, 0)
-    ls = jnp.moveaxis(labels.reshape(b, n, chunk), 1, 0)
-    ms = (
-        jnp.moveaxis(mask.astype(jnp.float32).reshape(b, n, chunk), 1, 0)
-        if mask is not None
-        else jnp.ones((n, 1, 1), jnp.float32) * 0  # placeholder, unused
-    )
-    use_mask = mask is not None
-
-    def body(carry, xc_lc_mc):
-        nll_sum, denom = carry
-        xc, lc, mc = xc_lc_mc
-        logits = _project_vocab(xc, head, head_layout)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        nll = logz - gold
-        if use_mask:
-            return (nll_sum + jnp.sum(nll * mc), denom + jnp.sum(mc)), None
-        return (nll_sum + jnp.sum(nll), denom + nll.size * 1.0), None
-
-    zero = jnp.zeros((), jnp.float32)
-    (nll_sum, denom), _ = jax.lax.scan(jax.checkpoint(body), (zero, zero), (xs, ls, ms))
+        chunk = t  # tiny test configs: single chunk, same math, same rule
+    weights = None if mask is None else mask.astype(jnp.float32)
+    # the divisor depends on the batch alone: known before the loop, so a chunk's dlogits is final
     if denominator is not None:
-        return nll_sum / denominator
-    return nll_sum / jnp.maximum(denom, 1.0)
+        divisor = denominator
+    elif weights is not None:
+        divisor = jnp.maximum(jnp.sum(weights), 1.0)
+    else:
+        divisor = b * t
+    return _xent_scalar(x, head, labels, weights, jnp.asarray(divisor, jnp.float32), chunk, head_layout)
 
 
 def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:

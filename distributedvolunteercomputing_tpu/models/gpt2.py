@@ -10,7 +10,8 @@ TPU-first layout decisions:
   times, which cuts compile time and program size on-chip.
 - The loss never materializes the [B, T, 50257] f32 logits tensor
   (1.6 GB at bench shapes); it streams vocab projection + cross-entropy over
-  time chunks (common.lm_xent_chunked) with rematerialized backward.
+  time chunks (common.lm_xent_chunked) and makes both of the head's gradients
+  in that same loop, so nothing of a chunk is kept or recomputed.
 """
 
 from __future__ import annotations

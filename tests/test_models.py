@@ -142,56 +142,154 @@ class TestGQA:
         assert any(float(jnp.abs(g).max()) > 0 for g in lora_leaves)
 
 
+def _count_eqns(jaxpr, counts=None):
+    """primitive name -> how many equations, through every nested jaxpr (a scan's body, a jit's)."""
+    import collections
+
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _count_eqns(sub, counts)
+    return counts
+
+
 class TestChunkedXent:
     """The streamed vocab-projection loss (common.lm_xent_chunked) must be
     numerically identical to materializing the full [B,T,V] logits — in
     value AND gradients — on its real multi-chunk path (n > 1 chunks),
-    which production configs hit (T=1024, chunk=128) but tiny model configs
-    don't (they fall back to the single-chunk branch)."""
+    which production configs hit (T=1024, chunk=128), in both layouts of the
+    head, under a 0/1 mask and under weights over a divisor of the caller's.
+    Its gradients are made in the loop that makes the loss (a custom_vjp): the
+    structural cases hold it to three vocabulary-sized products and one loop."""
 
     B, T, D, V, CHUNK = 2, 16, 8, 11, 4
+    LAYOUTS = ("vd", "dv")
+    KINDS = ("plain", "mask01", "weights_over_denominator")
 
-    def _data(self, mask=False):
+    def _data(self, layout="vd", kind="plain", dtype=jnp.float32):
+        """(full(x, head), chunked(x, head), x, head): the loss from whole logits
+        under plain autodiff, the streamed one, and their arguments."""
         from distributedvolunteercomputing_tpu.models import common
 
         k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(0), 4)
-        x = jax.random.normal(k1, (self.B, self.T, self.D), jnp.float32)
+        x = jax.random.normal(k1, (self.B, self.T, self.D), jnp.float32).astype(dtype)
         head = jax.random.normal(k2, (self.V, self.D), jnp.float32)
+        head = head if layout == "vd" else head.T
         labels = jax.random.randint(k3, (self.B, self.T), 0, self.V)
-        m = (jax.random.uniform(k4, (self.B, self.T)) < 0.4).astype(jnp.float32) if mask else None
-        return common, x, head, labels, m
-
-    @pytest.mark.parametrize("masked", [False, True])
-    def test_matches_full_logits(self, masked):
-        common, x, head, labels, m = self._data(masked)
+        u = jax.random.uniform(k4, (self.B, self.T))
+        m, denominator = {
+            "plain": (None, None),
+            "mask01": ((u < 0.4).astype(jnp.float32), None),
+            "weights_over_denominator": (u * 5, float(self.B * self.T)),
+            "mask_all_zero": (jnp.zeros((self.B, self.T)), None),
+        }[kind]
 
         def full(x, head):
-            logits = jnp.einsum("btd,vd->btv", x, head)
-            return common.softmax_xent(logits, labels, m)
+            return common.softmax_xent(common._project_vocab(x, head, layout), labels, m, denominator)
 
         def chunked(x, head):
-            return common.lm_xent_chunked(x, head, labels, mask=m, chunk=self.CHUNK)
+            return common.lm_xent_chunked(x, head, labels, mask=m, chunk=self.CHUNK, head_layout=layout,
+                                          denominator=denominator)
 
         assert self.T // self.CHUNK > 1  # really exercising the scan path
-        np.testing.assert_allclose(
-            float(chunked(x, head)), float(full(x, head)), rtol=1e-6
-        )
+        return full, chunked, x, head
+
+    @pytest.mark.parametrize("kind", KINDS)
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_matches_full_logits(self, layout, kind):
+        full, chunked, x, head = self._data(layout, kind)
+        np.testing.assert_allclose(float(chunked(x, head)), float(full(x, head)), rtol=1e-6)
         g_full = jax.grad(full, argnums=(0, 1))(x, head)
         g_chunk = jax.grad(chunked, argnums=(0, 1))(x, head)
         for a, b in zip(g_chunk, g_full):
+            assert a.shape == b.shape and a.dtype == b.dtype
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
 
-    def test_dv_head_layout(self):
-        common, x, head, labels, _ = self._data()
-        full = common.softmax_xent(jnp.einsum("btd,dv->btv", x, head.T), labels)
-        chunked = common.lm_xent_chunked(x, head.T, labels, chunk=self.CHUNK, head_layout="dv")
-        np.testing.assert_allclose(float(chunked), float(full), rtol=1e-6)
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_bf16_rows_match_full_logits_as_the_checkpointed_loop_did(self, layout):
+        """``x`` in the compute dtype of the chip: the products take bf16 operands
+        and a float32 ``dlogits``, as autodiff's did. The checkpointed loop this
+        one replaces stood within 1.3e-3 of whole logits here (dhead; its dx was
+        equal), this one within 1.0e-3: held to that distance, not a wider one."""
+        full, chunked, x, head = self._data(layout, "mask01", jnp.bfloat16)
+        np.testing.assert_allclose(float(chunked(x, head)), float(full(x, head)), rtol=1e-6)
+        g_full = jax.grad(full, argnums=(0, 1))(x, head)
+        g_chunk = jax.grad(chunked, argnums=(0, 1))(x, head)
+        assert [g.dtype for g in g_chunk] == [jnp.bfloat16, jnp.float32]
+        for a, b in zip(g_chunk, g_full):
+            np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=0, atol=1.3e-3)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_a_cotangent_other_than_one_scales_both_gradients(self, layout):
+        full, chunked, x, head = self._data(layout, "weights_over_denominator")
+        g_full = jax.grad(lambda x, h: 3.0 * full(x, h), argnums=(0, 1))(x, head)
+        g_chunk = jax.grad(lambda x, h: 3.0 * chunked(x, h), argnums=(0, 1))(x, head)
+        for a, b in zip(g_chunk, g_full):
+            # float32 rounding of sums whose terms are 3 x 5 times the unit case's: its atol by as much
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1.5e-6)
+
+    def test_a_mask_of_zeros_divides_by_one_and_gives_zero_gradients(self):
+        _, chunked, x, head = self._data("vd", "mask_all_zero")
+        loss, grads = jax.value_and_grad(chunked, argnums=(0, 1))(x, head)
+        assert float(loss) == 0.0
+        assert all(np.all(np.asarray(g) == 0.0) for g in grads)  # zeros, not NaN
 
     def test_indivisible_t_falls_back(self):
-        common, x, head, labels, _ = self._data()
-        full = common.softmax_xent(jnp.einsum("btd,vd->btv", x, head), labels)
-        got = common.lm_xent_chunked(x, head, labels, chunk=5)  # 16 % 5 != 0
-        np.testing.assert_allclose(float(got), float(full), rtol=1e-6)
+        from distributedvolunteercomputing_tpu.models import common
+
+        full, _, x, head = self._data()
+        labels = jax.random.randint(jax.random.split(jax.random.PRNGKey(0), 4)[2], (self.B, self.T), 0, self.V)
+
+        def one_chunk(x, head):
+            return common.lm_xent_chunked(x, head, labels, chunk=5)  # 16 % 5 != 0: one chunk, the same rule
+
+        np.testing.assert_allclose(float(one_chunk(x, head)), float(full(x, head)), rtol=1e-6)
+        for a, b in zip(jax.grad(one_chunk, argnums=(0, 1))(x, head), jax.grad(full, argnums=(0, 1))(x, head)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_three_products_and_one_loop_differentiated_one_product_evaluated(self, layout):
+        """The differentiated loss is ONE scan whose chunk holds the logits
+        product and the two gradient products (no second loop, no recomputed
+        logits); an undifferentiated call pays for no gradient."""
+        _, chunked, x, head = self._data(layout, "mask01")
+        differentiated = _count_eqns(jax.make_jaxpr(jax.value_and_grad(chunked, argnums=(0, 1)))(x, head).jaxpr)
+        assert (differentiated["dot_general"], differentiated["scan"]) == (3, 1), differentiated
+        assert not differentiated["checkpoint"] and not differentiated["remat2"]
+        evaluated = _count_eqns(jax.make_jaxpr(chunked)(x, head).jaxpr)
+        assert (evaluated["dot_general"], evaluated["scan"]) == (1, 1), evaluated
+        assert evaluated["exp"] == 1  # the log-sum-exp's, no softmax for a dlogits nobody asked for
+
+    @pytest.mark.parametrize("layout", LAYOUTS)
+    def test_a_head_nobody_differentiates_costs_no_accumulator(self, layout):
+        """An adapter-only finetune freezes the head (models/llama.py's
+        ``stop_gradient`` over the base): the rule sees it is not perturbed and
+        neither multiplies for its gradient nor carries a float32 [V, d] sum.
+        bf16 storage here, so any float32 array of the head's shape in the
+        lowered program could only be that accumulator."""
+        from distributedvolunteercomputing_tpu.models import common
+
+        _, _, x, head = self._data(layout)
+        x, head = x.astype(jnp.bfloat16), head.astype(jnp.bfloat16)
+        labels = jnp.zeros((self.B, self.T), jnp.int32)
+        accumulator = "tensor<%dx%dxf32>" % head.shape
+
+        def loss(x, head, freeze):
+            head = jax.lax.stop_gradient(head) if freeze else head
+            return common.lm_xent_chunked(x, head, labels, chunk=self.CHUNK, head_layout=layout)
+
+        def lowered(fn):  # as for the chip: the CPU's lowering widens a bf16 operand of a product itself
+            return fn.trace(x, head).lower(lowering_platforms=("tpu",)).as_text()
+
+        frozen = jax.jit(jax.grad(lambda x, h: loss(x, h, True), argnums=(0, 1)))
+        assert accumulator not in lowered(frozen)
+        counts = _count_eqns(jax.make_jaxpr(frozen)(x, head).jaxpr)
+        assert (counts["dot_general"], counts["scan"]) == (2, 1), counts
+        dx, dhead = frozen(x, head)
+        assert float(jnp.abs(dx.astype(jnp.float32)).max()) > 0 and not np.any(np.asarray(dhead, np.float32))
+        trained = jax.jit(jax.grad(lambda x, h: loss(x, h, False), argnums=(0, 1)))
+        assert accumulator in lowered(trained)  # the control: this is how it would show
 
 
 class TestViT:

@@ -376,7 +376,7 @@ OLMOE_TINY = dict(n_layers=2, d_model=64, n_heads=4, n_experts=8, top_k=2, d_exp
 
 
 @pytest.mark.parametrize("model,overrides,lines,ops", [
-    ("laguna_xs2", LAGUNA_TINY, 7455, 7018), ("olmoe_1b_7b", OLMOE_TINY, 1654, 1601)])
+    ("laguna_xs2", LAGUNA_TINY, 7383, 6960), ("olmoe_1b_7b", OLMOE_TINY, 1686, 1631)])
 def test_a_silu_models_lowered_step_is_unchanged_and_gains_no_output(monkeypatch, model, overrides, lines, ops):
     """The loss and gradient program of Laguna and of OLMoE, lowered at a tiny
     size: OLMoE's as many lines and operations as at the parent of PR 35 (where
@@ -400,7 +400,15 @@ def test_a_silu_models_lowered_step_is_unchanged_and_gains_no_output(monkeypatch
     7,122) -> (7,455, 7,018): Laguna's gate multiplies the attention's merged
     ``[B, T, H * D]`` result through a 0/1 product (no transpose of the gate,
     none of the product back, no sum by head); at this size the core itself is ``attention_merged``'s by-head
-    fallback, the operations it was, which is why OLMoE's count stands."""
+    fallback, the operations it was, which is why OLMoE's count stood. And at
+    PR 61 both, for the first time OLMoE's: the loss head makes its gradients in
+    the loop that makes its loss (``common.lm_xent_chunked``, a ``custom_vjp``).
+    Laguna's two chunks of 32, (7,455, 7,018) -> (7,383, 6,960): the backward's
+    second loop with its recomputed logits is gone. OLMoE's T of 32 is one
+    chunk, which ran whole logits under plain autodiff and now runs the same
+    rule as a scan of length one, (1,654, 1,601) -> (1,686, 1,631): the loop's
+    own slices and the ``dlogits`` written out where autodiff's transpose rules
+    wrote less text for the same three products."""
     monkeypatch.setattr(moe_dispatch, "SHARE_ROWS_SLACK", 3.0)  # the program's own
     bundle = get_model(model, **overrides)
     params = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))

@@ -274,6 +274,41 @@ def _step_holds_the_groups_its_cell_lists(text: str, cell: str) -> None:
     assert other / len(got) <= SCOPE_OTHER_AT_MOST, (other, len(got))
 
 
+def _head_makes_its_gradients_in_the_loop_of_its_loss(text: str, vocab: int) -> None:
+    """The loss head of a compiled step (``models/common.lm_xent_chunked``, a
+    ``custom_vjp`` since PR 61): exactly three products over the vocabulary
+    under ``loss_head`` (a chunk's logits, ``dx`` and ``dhead``), none of them or
+    of anything else of the head recomputed, all three forward (the backward
+    rule is the residuals times a cotangent that is the literal 1.0 under
+    ``value_and_grad``: no pass over the head's shape is left for it), and every
+    instruction that names the head resolves to it in the scope map."""
+    import re
+
+    from distributedvolunteercomputing_tpu.utils import step_scopes
+
+    def origin(ln):  # the first of the origins XLA joined: the one the scope map counts
+        return ln.split('op_name="')[1].split('"')[0].split(";")[0]
+
+    named = [ln for ln in text.splitlines() if 'op_name="' in ln and "loss_head" in origin(ln)]
+    assert named and not [ln for ln in named if "rematted_computation" in origin(ln)]
+    products = [ln for ln in named if re.search(r" (convolution|dot)\(", ln)]
+    results = dict(re.findall(r"^\s+(?:ROOT )?(%[^\s=]+) = (\S+)", text, re.M))  # every instruction's result type
+
+    def over_the_vocabulary(ln):  # its result or one of its operands holds the vocabulary's axis
+        operands = re.search(r" (?:convolution|dot)\(([^)]*)\)", ln).group(1).split(", ")
+        return any(re.search(rf"[\[,]{vocab}[\],]", t) for t in [ln.split(" = ")[1], *(results[o] for o in operands)])
+
+    assert len(products) == 3 and all(over_the_vocabulary(ln) for ln in products), products
+    assert not [ln for ln in products if "transpose(" in origin(ln)]
+    got = step_scopes.scope_map(text)
+    head = {name: r for name, r in got.items() if r["scope"] == "loss_head"}
+    assert head and {r["pass"] for r in head.values()} <= {"fwd", "bwd"}
+    assert not [name for name, r in head.items() if r["pass"] == "bwd" and str(vocab) in r["result"]]
+    for ln in named:  # none of the head's instructions falls to another group or to ``other``
+        name = ln.split(" = ")[0].replace("ROOT", "").strip().lstrip("%")
+        assert name not in got or step_scopes.group_of(got[name]["scope"]) == "loss_head", ln[:200]
+
+
 def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     """medium-solo's step, auto routing: T=1,024 bf16 takes the fused core,
     forward and backward; the recomputed forward holds no kernel (the layer's
@@ -283,6 +318,7 @@ def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     assert len(calls) == 2
     assert all("bf16[16,16,1024,64]" in ln for ln in calls)
     _step_holds_the_groups_its_cell_lists(text, "medium-solo")
+    _head_makes_its_gradients_in_the_loop_of_its_loss(text, 50257)
 
 
 # ``slow`` since PR 58: one cell-size compile for a described v5e, 42 s of the tier-1 run's six
@@ -307,6 +343,7 @@ def test_olmoe_step_holds_its_kernels(v5e, as_on_the_chip, monkeypatch):
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     text = _step_text(v5e, "olmoe_1b_7b", 1, 1, 4, n_layers=1)
     _step_holds_the_groups_its_cell_lists(text, "olmoe-solo")
+    _head_makes_its_gradients_in_the_loop_of_its_loss(text, 50304)
     calls = _kernel_calls(text)
     names = [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
     flash = [n for n in names if n.startswith("dvc_flash_")]

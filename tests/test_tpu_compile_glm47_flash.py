@@ -87,4 +87,6 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     assert mem.argument_size_in_bytes == pytest.approx(7.0957e9, rel=1e-3)  # float32 parameters and two Adam moments
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert total < 16.25e9, total                        # 16.176e9: the chip takes about 16.9e9 and ran it
-    assert mem.temp_size_in_bytes <= 9.12e9, mem.temp_size_in_bytes   # 9.080e9 (9.219e9 at the levelled chunk)
+    # 9,079,866,368; no more than before the head made its gradients in its loss's loop (9,079,898,624 at PR 60;
+    # 9.219e9 at the levelled chunk): its residuals, dx and the float32 dhead, are the buffers the backward held
+    assert mem.temp_size_in_bytes <= 9_079_898_624, mem.temp_size_in_bytes

@@ -86,3 +86,5 @@ def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_t
     # by-head copies out): under the 17.16e9 that the chip's own compile loaded and ran beside the reference check
     # (memory_peak_bytes 15.04e9 of 16.9e9 then, 15.02e9 now: my chip runs, PR 52 calls 9-10, PR 55 call 1)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+    # 8,142,901,760: no more than before the head made its gradients in its loss's loop (8,142,934,016 at PR 60)
+    assert mem.temp_size_in_bytes <= 8_142_934_016, mem.temp_size_in_bytes
