@@ -15,13 +15,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from distributedvolunteercomputing_tpu.ops import attention as attention_ops
+from distributedvolunteercomputing_tpu.utils import traced
 
 Params = Dict[str, Any]
 
@@ -49,6 +50,26 @@ class SteppedLeaves:
     signal: str
     owns: Callable[[Any], Any]
     rule: Callable[[Any, Any], Any]
+
+
+@dataclasses.dataclass(frozen=True)
+class StepSpan:
+    """A span the train loop records of a model's step, by the name the
+    bundle gives it (``ModelBundle.spans``; a family module's ``spans(cfg)``):
+    every ``ROUTE_EVERY`` steps and at each log point, with whichever of
+    ``keys`` the step's metrics hold, as floats. The loop carries what is
+    declared here and reads none of it.
+
+    ``attrs``: attributes known when the bundle is built (what the config
+    says), on every such span as they are.
+    ``noted``: attribute -> (kind, label) of ``utils/traced.py``: the values
+    that label took in the notes of that kind since the trainer was built,
+    sorted and joined by "+" (which form a scan's op chose when the step was
+    traced); left out while there was no such note."""
+
+    keys: Tuple[str, ...]
+    attrs: Mapping[str, Any] = dataclasses.field(default_factory=dict)
+    noted: Mapping[str, Tuple[str, str]] = dataclasses.field(default_factory=dict)
 
 
 # bf16 on TPU keeps the MXU at full rate; f32 on CPU keeps tests exact enough
@@ -94,7 +115,8 @@ def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[jax.Array, jax.Arr
     split, and ``split_heads``."""
     tp = attention_ops.heads_tp()
     by_head = tp > 1 and n_heads % tp == 0
-    attention_ops.observe_qkv("by_head" if by_head else "fused", tp)
+    # every TRACED fused qkv projection (swarm.qkv_projection, beside swarm.attention_core)
+    traced.note("qkv_projection", layout="by_head" if by_head else "fused", tp=tp)
     if not by_head:
         q, k, v = jnp.split(dense(p, x), 3, axis=-1)
         return tuple(attention_ops.split_heads(a, n_heads) for a in (q, k, v))
@@ -243,7 +265,8 @@ def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_out
     streams = 1
     if rows_independent:
         streams = attention_ops.tp_streams(x.shape[0])
-        attention_ops.observe_streams(streams)
+        # every TRACED scan of layers that couple no rows: 2 streams, or the 1 it fell back to (swarm.tp_streams)
+        traced.note("tp_streams", streams=streams)
     if streams > 1:
         if with_outputs:
             raise ValueError("row streams carry no layer outputs")

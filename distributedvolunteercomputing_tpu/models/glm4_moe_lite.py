@@ -276,3 +276,8 @@ def loss_and_routes(
 def stepped(cfg: Glm4MoeLiteConfig):
     """What the train step needs to move the selection biases itself."""
     return moe.stepped(cfg.bias_gamma)
+
+
+def spans(cfg: Glm4MoeLiteConfig):
+    """The spans the train loop records of this step."""
+    return {"moe.route": moe.route_span(cfg, chunks_extra=True, stepped_bias=True)}

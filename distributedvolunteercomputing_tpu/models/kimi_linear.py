@@ -388,3 +388,10 @@ def loss_and_routes(
 def stepped(cfg: KimiLinearConfig):
     """What the train step needs to move the selection biases itself."""
     return moe.stepped(cfg.bias_gamma)
+
+
+def spans(cfg: KimiLinearConfig):
+    """The spans the train loop records of this step: its routing, and what
+    its KDA layers' scans carry from chunk to chunk (``ops/kda.py`` has one form: none to note)."""
+    return {"moe.route": moe.route_span(cfg, chunks_extra=True, stepped_bias=True),
+            "kda.scan": common.StepSpan(("kda_carry_share", "kda_decay_min", "kda_beta_mean"))}

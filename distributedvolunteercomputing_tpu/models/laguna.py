@@ -251,3 +251,8 @@ def loss_and_routes(
     aux = moe.balance_loss(stats, max(cfg.n_layers - cfg.dense_layers, 1), cfg.n_experts)
     loss = lm + cfg.aux_coef * aux
     return loss, moe.share_metrics(loss, lm, aux, stats, tokens.size, cfg), routes
+
+
+def spans(cfg: LagunaConfig):
+    """The span the train loop records of this step's routing."""
+    return {"moe.route": moe.route_span(cfg)}

@@ -288,3 +288,8 @@ def loss_and_routes(
 def stepped(cfg: LFM2Config):
     """What the train step needs to move the selection biases itself."""
     return moe.stepped(cfg.bias_gamma)
+
+
+def spans(cfg: LFM2Config):
+    """The spans the train loop records of this step."""
+    return {"moe.route": moe.route_span(cfg, chunks_extra=True, stepped_bias=True)}

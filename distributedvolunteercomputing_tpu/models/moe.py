@@ -10,8 +10,8 @@ and this module keeps
   where a layer carries the leaf ``bias`` [E] beside ``router`` [d, E];
 - the share's running statistics over the expert layers (``zero_share_stats``,
   ``note_share``), the balancing term of a router trained by an auxiliary loss
-  (``balance_loss``) and the step's metrics (``share_metrics``: what the loop
-  puts on a ``moe.route`` span, ``training/trainer.ROUTING_KEYS``);
+  (``balance_loss``), the step's metrics (``share_metrics``) and the
+  declaration of the ``moe.route`` span that carries them (``route_span``);
 - the runs of equal layers (``run_layers``) and the stepped bias's rule
   (``balance``, ``stepped``) for the families that have them.
 
@@ -216,6 +216,34 @@ def share_metrics(loss: jax.Array, lm: jax.Array, aux: jax.Array, stats: Dict[st
         metrics["moe_bias_moved"] = jnp.sum(bias_steps(counts) != 0).astype(jnp.float32)
         metrics[COUNTS] = counts  # the step's own: ``stepped`` reads it, the loop never sees it
     return metrics
+
+
+def route_span(cfg, share: bool = True, act_zeros: bool = False, chunks_extra: bool = False,
+               stepped_bias: bool = False, more: Tuple[str, ...] = ()) -> common.StepSpan:
+    """The declaration of a family's ``moe.route`` span (``ModelBundle.spans``).
+    Its keys: what the family's step returns of ``share_metrics``'s (``share``:
+    it counts the rows of a held share; the next two as it starts its
+    statistics, ``zero_share_stats``; ``stepped_bias``: its step moves
+    selection biases) and ``more`` of its own. Its attributes: ``experts_held``
+    where the config says how many, which stream the layer's router reads
+    (``router_site``: its input, before attention, or what attention made of
+    it) and, for a config that lists its layers' token mixers, how many of each kind."""
+    keys = ["moe_load_max", "moe_load_mean", "moe_dropped"]
+    if share:
+        keys += ["moe_rows_held", "moe_rows_moved"]
+    if chunks_extra:
+        keys.append("moe_chunks_extra")
+    if act_zeros:
+        keys.append("moe_act_zero_share")
+    if stepped_bias:
+        keys += ["moe_bias_max", "moe_bias_min", "moe_bias_moved"]
+    attrs = {}
+    if getattr(cfg, "experts_held", None) is not None:
+        attrs["experts_held"] = int(cfg.experts_held)
+    attrs["router_site"] = getattr(cfg, "router_site", "post_attention")
+    layer_types = getattr(cfg, "layer_types", ())
+    attrs.update({f"mixers_{kind}": layer_types.count(kind) for kind in sorted(set(layer_types))})
+    return common.StepSpan((*keys, "aux_loss", "lm_loss", *more), attrs)
 
 
 def is_bias(path: Tuple) -> bool:

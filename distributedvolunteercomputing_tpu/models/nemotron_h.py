@@ -374,3 +374,10 @@ def loss_and_routes(
 def stepped(cfg: NemotronHConfig):
     """What the train step needs to move the selection biases itself."""
     return moe.stepped(cfg.bias_gamma)
+
+
+def spans(cfg: NemotronHConfig):
+    """The spans the train loop records of this step: its routing, and how
+    much its scans carry from chunk to chunk, in which form (``ops/ssd.py``'s note)."""
+    return {"moe.route": moe.route_span(cfg, act_zeros=True, chunks_extra=True, stepped_bias=True),
+            "ssm.scan": common.StepSpan(("ssm_carry_share",), noted={"ssm_form": ("ssd_scan", "form")})}

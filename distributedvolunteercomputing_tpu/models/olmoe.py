@@ -214,3 +214,8 @@ def loss_and_routes(
         "moe_dropped": stats["dropped"],
     }
     return loss, metrics, routes
+
+
+def spans(cfg: OlmoeConfig):
+    """The span the train loop records of this step's routing."""
+    return {"moe.route": moe.route_span(cfg, share=False)}

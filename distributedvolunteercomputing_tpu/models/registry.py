@@ -17,11 +17,11 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 
 import jax
 
-from distributedvolunteercomputing_tpu.models.common import SteppedLeaves
+from distributedvolunteercomputing_tpu.models.common import SteppedLeaves, StepSpan
 
 Batch = Dict[str, jax.Array]
 Metrics = Dict[str, jax.Array]
@@ -50,6 +50,10 @@ class ModelBundle:
     # Leaves the train step moves by the model's own rule and keeps the
     # optimizer off; None for every model whose parameters all follow a gradient.
     stepped: Optional[SteppedLeaves] = None
+    # Spans the train loop records of the step's metrics, by name; empty for a
+    # model whose step says nothing beyond its loss. (No part of the hash: a
+    # bundle is the key of more than one cache.)
+    spans: Mapping[str, StepSpan] = dataclasses.field(default_factory=dict, hash=False)
 
 
 def _mlp(**overrides: Any) -> ModelBundle:
@@ -163,7 +167,8 @@ def _language_model(name: str, **overrides: Any) -> ModelBundle:
     """The bundle of one of ``_LANGUAGE_MODELS``. A module brings ``init(rng,
     cfg)`` and either ``loss_fn(params, batch, rng, cfg)`` or
     ``loss_and_routes(params, batch, cfg)`` (an expert family whose loss draws
-    nothing: the loss is its first two results); ``stepped(cfg)`` where the step moves leaves of its own; and,
+    nothing: the loss is its first two results); ``stepped(cfg)`` where the step moves leaves of its own;
+    ``spans(cfg)`` where the loop records spans of the step's metrics; and,
     where its config has a ``lora_rank`` above 0, the subtree the swarm
     averages (``lora_subtree`` / ``with_lora_subtree``)."""
     from distributedvolunteercomputing_tpu.training import data
@@ -189,6 +194,7 @@ def _language_model(name: str, **overrides: Any) -> ModelBundle:
         avg_select=module.lora_subtree if lora_on else _identity_select,
         avg_merge=module.with_lora_subtree if lora_on else _identity_merge,
         stepped=module.stepped(cfg) if hasattr(module, "stepped") else None,
+        spans=module.spans(cfg) if hasattr(module, "spans") else {},
     )
 
 

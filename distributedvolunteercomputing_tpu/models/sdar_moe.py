@@ -291,3 +291,9 @@ def loss_and_routes(
 def loss_fn(params: common.Params, batch: Dict[str, jax.Array], rng: jax.Array, cfg: SdarMoeConfig):
     """The bundle's loss: this family draws its noise from the step's rng."""
     return loss_and_routes(params, batch, rng, cfg)[:2]
+
+
+def spans(cfg: SdarMoeConfig):
+    """The span the train loop records of this step's routing, with what the block-diffusion objective adds."""
+    return {"moe.route": moe.route_span(cfg, chunks_extra=True, more=(
+        "diffusion_masked_share", "diffusion_head_rows_share", "attention_bd_tiles_share"))}

@@ -257,3 +257,8 @@ def loss_and_routes(
     aux = moe.balance_loss(stats, cfg.n_layers, cfg.n_experts)
     loss = lm + cfg.aux_coef * aux
     return loss, moe.share_metrics(loss, lm, aux, stats, tokens.size, cfg), routes
+
+
+def spans(cfg: SmallThinkerConfig):
+    """The span the train loop records of this step's routing."""
+    return {"moe.route": moe.route_span(cfg, act_zeros=True)}

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from distributedvolunteercomputing_tpu.utils import traced
+
 # Active sequence-parallel context: (mesh, axis_name, impl) or None. When
 # set, the attention core routes to the chosen SP implementation so the
 # model code is unchanged between single-device and sp-sharded runs. Set by
@@ -82,51 +84,28 @@ _AUTO_FLASH_MIN_T = {"bfloat16": 512, "float32": 512}
 # others keep the XLA core.
 _AUTO_FLASH_HEAD_DIMS = (64, 128, 192, 256)
 
-# Called once per TRACED attention call with (impl, T, D, dtype name, window,
-# key/value heads, layout, rotary): the volunteer counts them
-# (swarm.attention_core), so its summary says how many of the step's attention
-# calls took the fused core, how many of those were handed the projections'
-# own [B, T, H * D] arrays (``layout`` "merged"; "heads" is [B, H, T, D]) and
-# where the call's rotary turn ran (``rotary``): "kernel" where the forward
-# kernel turns each q block on the tile (k, and the backward's resident q and
-# its dq, by one merged-layout pass each beside the kernels), "outside" where
-# ``attention_merged`` ran ``rope`` on [B, H, T, D] before the core, "none"
-# where the call was given no rotary description (a model that turns its own
-# parts before ``attention_core``, GLM's and Kimi's latent keys, reads "none").
-# Trace time only: a compiled step never reaches it.
-_core_observer = None
+# Every TRACED attention call is noted (``utils/traced.py``) as "attention_core"
+# with impl, T, D, dtype, window ("none" for full attention), kv_heads, layout
+# and rotary: a volunteer counts them (swarm.attention_core), so its summary
+# says how many of the step's attention calls took the fused core, how many of
+# those were handed the projections' own [B, T, H * D] arrays (``layout``
+# "merged"; "heads" is [B, H, T, D]) and where the call's rotary turn ran
+# (``rotary``): "kernel" where the forward kernel turns each q block on the
+# tile (k, and the backward's resident q and its dq, by one merged-layout pass
+# each beside the kernels), "outside" where ``attention_merged`` ran ``rope``
+# on [B, H, T, D] before the core, "none" where the call was given no rotary
+# description (a model that turns its own parts before ``attention_core``,
+# GLM's and Kimi's latent keys, reads "none").
 # What ``attention_merged`` says of the call it hands to ``attention_core``.
 _observed_rotary = "none"
 
 
-def set_core_observer(fn) -> None:
-    global _core_observer
-    _core_observer = fn
+def _note_core(impl: str, t: int, d: int, dtype, window: Optional[int], kv_heads: int, layout: str,
+               rotary: str) -> None:
+    traced.note("attention_core", impl=impl, T=t, D=d, dtype=jnp.dtype(dtype).name,
+                window="none" if window is None else window, kv_heads=kv_heads, layout=layout, rotary=rotary)
 
 
-# Called once per TRACED fused qkv projection with (layout, tp): "by_head"
-# where the projection was divided by head over the step mesh's ``tp`` axis
-# (models/common.qkv_heads), "fused" where it ran as one [d, 3d] product
-# (swarm.qkv_projection, beside swarm.attention_core).
-_qkv_observer = None
-
-
-def set_qkv_observer(fn) -> None:
-    global _qkv_observer
-    _qkv_observer = fn
-
-
-def observe_qkv(layout: str, tp: int) -> None:
-    if _qkv_observer is not None:
-        _qkv_observer(layout, tp)
-
-
-# Called once per TRACED rematerialised layer whose checkpoint kept something
-# (models/common.remat_layer) with (layers, bytes): how many layers run that
-# one trace (a scan's length) and the bytes one chip keeps of them a step
-# (swarm.remat_kept, beside swarm.attention_core). A layer that ran the XLA
-# core on one chip names nothing, keeps nothing and is not reported.
-_kept_observer = None
 # While remat_layer traces a body: the bytes kept of each kernel call in it
 # and of each value named by ``keep_tp_reduced``.
 _kept_ctx = None
@@ -135,18 +114,16 @@ _kept_ctx = None
 TP_REDUCED = "tp_reduced"
 
 
-def set_kept_observer(fn) -> None:
-    global _kept_observer
-    _kept_observer = fn
-
-
 @contextlib.contextmanager
 def keeping_kernel_results(layers: int, calls: int = 1):
     """Around the trace of one rematerialised layer body that ``layers``
     layers run: gathers what ``_flash_per_shard`` says a chip keeps of each
     kernel call in it, and ``keep_tp_reduced`` of each value it named, and
-    reports the sum; ``calls`` times over where the body runs ``calls`` times
-    what it traced once (a row stream each)."""
+    notes the sum as "remat_kept" with (layers, bytes): how many layers run
+    that one trace (a scan's length) and the bytes one chip keeps of them a
+    step (swarm.remat_kept), ``calls`` times over where the body runs ``calls``
+    times what it traced once (a row stream each). A layer that ran the XLA
+    core on one chip names nothing, keeps nothing and is not noted."""
     global _kept_ctx
     prev, _kept_ctx = _kept_ctx, []
     kept = _kept_ctx
@@ -154,8 +131,8 @@ def keeping_kernel_results(layers: int, calls: int = 1):
         yield
     finally:
         _kept_ctx = prev
-    if kept and _kept_observer is not None:
-        _kept_observer(layers, layers * calls * sum(kept))
+    if kept:
+        traced.note("remat_kept", layers=layers, bytes=layers * calls * sum(kept))
 
 
 def chips_in_step() -> int:
@@ -174,23 +151,6 @@ def heads_tp() -> int:
     if _mesh_ctx is None or "tp" in jax.sharding.get_abstract_mesh().manual_axes:
         return 1
     return _mesh_ctx.shape.get("tp", 1)
-
-
-# Called once per TRACED layer scan of a model whose rows a layer does not
-# couple (models/common.scan_blocks with ``rows_independent``) with how many row
-# streams the scanned body runs: 2 where ``tp_streams`` gave two, 1 where it
-# fell back (swarm.tp_streams, beside swarm.qkv_projection).
-_streams_observer = None
-
-
-def set_streams_observer(fn) -> None:
-    global _streams_observer
-    _streams_observer = fn
-
-
-def observe_streams(streams: int) -> None:
-    if _streams_observer is not None:
-        _streams_observer(streams)
 
 
 def tp_streams(rows: int) -> int:
@@ -416,11 +376,7 @@ def attention_merged(
                 qh, kh, vh, causal=causal, window=window, block_diffusion=block_diffusion))
         finally:
             _observed_rotary = prev
-    if _core_observer is not None:
-        _core_observer(
-            "flash", t, d, jnp.dtype(q.dtype).name, window, kv_heads,
-            "merged", "none" if rotary is None else "kernel",
-        )
+    _note_core("flash", t, d, q.dtype, window, kv_heads, "merged", "none" if rotary is None else "kernel")
     return _flash_merged_per_shard(q, k, v, d, _shard_axes(qs, ks), causal, window, rotary, block_diffusion)
 
 
@@ -524,11 +480,7 @@ def attention_core_local(
             causal or window is not None or q.shape[-2] != k.shape[-2] or q.shape[-2] % (2 * block_diffusion)):
         raise ValueError("a block-diffusion mask is over [x_0 ; x_t], two halves of whole blocks, and is its own mask")
     flash = _route_to_flash(q, k, causal, mask, window, block_diffusion=block_diffusion)
-    if _core_observer is not None:
-        _core_observer(
-            "flash" if flash else "xla", q.shape[-2], q.shape[-1], jnp.dtype(q.dtype).name,
-            window, h_kv, "heads", _observed_rotary,
-        )
+    _note_core("flash" if flash else "xla", q.shape[-2], q.shape[-1], q.dtype, window, h_kv, "heads", _observed_rotary)
     if flash:
         return _flash_per_shard(q, k, v, causal, window, block_diffusion)
     scale = 1.0 / (q.shape[-1] ** 0.5)

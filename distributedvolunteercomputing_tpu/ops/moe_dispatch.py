@@ -59,6 +59,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from distributedvolunteercomputing_tpu.utils import traced
 from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
 # Megablox on one TPU chip where its tiles divide the shapes, ragged_dot
@@ -78,12 +79,6 @@ _MEGABLOX_TILES_KN = (1024, 512, 256, 128)
 # step's time followed the router by 2% from seed to seed.
 _MEGABLOX_PAD = 1024
 
-# Called once per TRACED dispatch with (impl, E, k, rows, held, act): the
-# volunteer counts them (swarm.moe_dispatch), as ops/attention.py's observer
-# does for the cores. ``rows`` are what one grouped matmul is handed, ``act``
-# the experts' kind ("swiglu" | "reglu").
-_dispatch_observer = None
-
 # The gate's activation by the name a model gives (static: it picks the
 # program). A ReLU gate's zeros are counted where they are made (the share of
 # the held rows' hidden activations that are exactly zero: what a sparse
@@ -100,8 +95,17 @@ _COUNTS_ZEROS = ("relu", "relu2")
 
 
 def expert_kind(act: str) -> str:
-    """What the dispatch observer is told of the experts ("swiglu" | "reglu" | "relu2")."""
+    """What a dispatch's note says of the experts ("swiglu" | "reglu" | "relu2")."""
     return (_GATES.get(act) or _UNGATED[act])[1]
+
+
+def _note_dispatch(rows: int, d: int, f: int, n_experts: int, k: int, held: int, act: str) -> None:
+    """Every TRACED dispatch is noted (``utils/traced.py``) as "moe_dispatch":
+    the grouped matmul that takes it, the ``E`` experts routed over and the
+    ``held`` of them on this chip, ``k``, the ``rows`` one grouped matmul is
+    handed and the experts' kind; a volunteer counts them (swarm.moe_dispatch)."""
+    traced.note("moe_dispatch", impl=grouped_matmul_impl(rows, d, f), E=n_experts, k=k, rows=rows, held=held,
+                act=expert_kind(act))
 
 
 def _gated(gate: jax.Array, up: jax.Array, valid, act: str):
@@ -128,11 +132,6 @@ def _hidden(product: jax.Array, f: int, valid, act: str):
     if act in _GATES:
         return _gated(product[:, :f], product[:, f:], valid, act)
     return _UNGATED[act][0](product), _zeros(product, valid)
-
-
-def set_dispatch_observer(fn) -> None:
-    global _dispatch_observer
-    _dispatch_observer = fn
 
 
 def _megablox_tiling(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
@@ -274,8 +273,7 @@ def dropless_glu_experts(
     k = top_idx.shape[1]
     e = w_up.shape[0]
     dtype = x.dtype
-    if _dispatch_observer is not None:
-        _dispatch_observer(grouped_matmul_impl(s * k, d, w_up.shape[2]), e, k, s * k, e, expert_kind(act))
+    _note_dispatch(s * k, d, w_up.shape[2], e, k, e, act)
     order, inv, group_sizes, experts = sort_by_expert(top_idx, e)
     rows = _rows_of_tokens(x, order, inv, k)                       # [S k, d]
     if w_gate is None:
@@ -612,9 +610,7 @@ def share_glu_experts(
         return y, group_sizes, dropped, jnp.asarray(s * k, jnp.int32), zeros
     dtype = x.dtype
     cap = share_rows_bound(s, k, held, n_experts, slack)
-    if _dispatch_observer is not None:
-        _dispatch_observer(
-            grouped_matmul_impl(cap, d, w_up.shape[2]), n_experts, k, cap, held, expert_kind(act))
+    _note_dispatch(cap, d, w_up.shape[2], n_experts, k, held, act)
     if plan is None:
         plan = plan_share(top_idx, expert_offset, held, n_experts, slack)
     elif plan.order.shape[0] % cap:

@@ -50,7 +50,7 @@ forward wrote it (float32: the residual beside the inputs).
 
 A sequence that is no whole number of chunks is padded with positions whose
 ``dt`` is 0 (no decay, no input) and cut again. Which form a traced scan took
-is told to ``set_form_observer``'s function (the ``ssm.scan`` span's ``ssm_form``).
+is noted as "ssd_scan" (``utils/traced.py``; the ``ssm.scan`` span's ``ssm_form``).
 
 MEASURED (PR 49, TPU v5e, nemotron3-nano-solo-8k: 64 heads of 64 in 8 groups,
 state 128, chunks of 128, 2 x 8,192 tokens, bf16; PERF.md, Findings of PR 49).
@@ -83,6 +83,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributedvolunteercomputing_tpu.ops.attention import chips_in_step
+from distributedvolunteercomputing_tpu.utils import traced
 from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
 CHUNK = 128
@@ -481,17 +482,6 @@ def _kernel_bwd(xbc, cum, dt, d, st, dy, groups: int, state: int, interpret: boo
 
 PLAIN, KERNEL, INTERPRET = "plain", "kernel", "interpret"
 
-# Called once per TRACED scan with (form, heads, groups, head_dim, state,
-# chunk): which form the step's state-space mixers took (the ``ssm.scan``
-# span's ``ssm_form``, training/trainer.py). Trace time only.
-_form_observer = None
-
-
-def set_form_observer(fn) -> None:
-    global _form_observer
-    _form_observer = fn
-
-
 @jax.custom_vjp
 def plain_core(xd: jax.Array, cum: jax.Array, b: jax.Array, c: jax.Array) -> jax.Array:
     """``y`` [Z, H, T, P] without the skip, from ``xd = dt x`` [Z, H, T, P],
@@ -579,8 +569,8 @@ def ssd(xbc: jax.Array, dt: jax.Array, a_log: jax.Array, d: jax.Array, groups: i
     z, t, h = dt.shape
     p = (xbc.shape[-1] - 2 * groups * state) // h
     form = form or choose_form(h, groups, p, state, chunk)
-    if _form_observer is not None:
-        _form_observer(form, h, groups, p, state, chunk)
+    # which form a TRACED scan took: the ``ssm.scan`` span's ``ssm_form`` (``models/nemotron_h.spans``)
+    traced.note("ssd_scan", form=form, heads=h, groups=groups, head_dim=p, state=state, chunk=chunk)
     pad = (-t) % chunk
     if pad:
         xbc, dt = (jnp.pad(a, ((0, 0), (0, pad), (0, 0))) for a in (xbc, dt))

@@ -782,6 +782,32 @@ class FlightRecorder:
             self._events.clear()
 
 
+# -- what a traced step chose -------------------------------------------------
+
+# The kinds of ``utils/traced.py`` notes counted as ``swarm.<kind>``, each with
+# its HELP; the labels are the note's own, made where the choice is
+# (docs/OBSERVABILITY.md, "Metric catalog", says what each means).
+TRACED_HELP: Dict[str, str] = {
+    "attention_core": "traced attention calls by the core that took them",
+    "qkv_projection": "traced fused qkv projections by how they were divided over tp",
+    "tp_streams": "traced layer scans by the independent row streams their body runs",
+    "remat_kept": "traced rematerialised layers whose checkpoint kept a kernel's or a tp sum's result",
+    "moe_dispatch": "traced expert dispatches by the grouped matmul that took them",
+}
+# Summary key -> (kind, the labels whose values, "/"-joined, its counts are by):
+# traced attention calls per core ({"flash": n} | {"xla": n}) and by what the
+# core was handed and where the rotary turn ran ({"merged/kernel": 3,
+# "merged/none": 1}); fused qkv projections per layout ({} for a model with
+# separate q, k and v leaves); layer scans by the row streams their body runs
+# ({"2": n} over tp, {"1": n} elsewhere, {} for a model never split).
+TRACED_SUMMARIES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
+    "attention_core": ("attention_core", ("impl",)),
+    "attention_layout": ("attention_core", ("layout", "rotary")),
+    "qkv_projection": ("qkv_projection", ("layout",)),
+    "tp_streams": ("tp_streams", ("streams",)),
+}
+
+
 # -- the bundle --------------------------------------------------------------
 
 
@@ -860,80 +886,13 @@ class Telemetry:
     def event(self, kind: str, **fields: Any) -> None:
         self.recorder.record(kind, **fields)
 
-    def count_attention_core(
-        self, impl: str, t: int, d: int, dtype: str, window: Optional[int] = None,
-        kv_heads: Optional[int] = None, layout: str = "heads", rotary: str = "none",
-    ) -> None:
-        """One TRACED attention call took core ``impl`` ("flash" | "xla"):
-        ops.attention's observer (``set_core_observer``). Counts traces,
-        not steps — a compiled step never comes back here. ``window`` is
-        "none" for full attention; ``kv_heads`` the key/value heads it read;
-        ``layout`` what the core was handed ("merged": the projections' own
-        [B, T, H * D]; "heads": [B, H, T, D]) and ``rotary`` where the call's
-        rotary turn ran ("kernel" | "outside" | "none": ``ops/attention.py`` at
-        ``_core_observer``)."""
-        if self.enabled:
-            self.registry.counter(
-                "swarm.attention_core",
-                "traced attention calls by the core that took them",
-            ).inc(impl=impl, T=str(t), D=str(d), dtype=dtype,
-                  window="none" if window is None else str(window), kv_heads=str(kv_heads),
-                  layout=layout, rotary=rotary)
-
-    def count_qkv_projection(self, layout: str, tp: int) -> None:
-        """One TRACED fused qkv projection ran ``layout`` ("by_head": divided
-        by head over the step mesh's ``tp`` chips | "fused": one [d, 3d]
-        product): models/common.qkv_heads through ops.attention's observer
-        (``set_qkv_observer``)."""
-        if self.enabled:
-            self.registry.counter(
-                "swarm.qkv_projection",
-                "traced fused qkv projections by how they were divided over tp",
-            ).inc(layout=layout, tp=str(tp))
-
-    def count_tp_streams(self, streams: int) -> None:
-        """One TRACED layer scan of a model whose block couples no two rows
-        (gpt2) runs each replica's rows as ``streams`` independent streams:
-        2 where the step's mesh divides a layer over ``tp`` and a replica's
-        rows are even, so that one stream's all-reduce runs beside the
-        other's products, 1 where it fell back to the program it was
-        (models/common.scan_blocks through ops.attention's
-        ``set_streams_observer``)."""
-        if self.enabled:
-            self.registry.counter(
-                "swarm.tp_streams",
-                "traced layer scans by the independent row streams their body runs",
-            ).inc(streams=str(streams))
-
-    def count_remat_kept(self, layers: int, nbytes: int) -> None:
-        """One TRACED rematerialised layer kept something beside its input
-        (models/common.remat_layer through ops.attention's
-        ``set_kept_observer``): the attention kernel's output and row
-        statistics and, where the step's mesh divides the layer over ``tp``,
-        the reduced attention output product. ``layers`` layers run that
-        trace, and ``nbytes`` is what one chip keeps of them a step. A layer
-        on one chip's XLA core keeps nothing and never comes here."""
-        if self.enabled:
-            self.registry.counter(
-                "swarm.remat_kept",
-                "traced rematerialised layers whose checkpoint kept a kernel's or a tp sum's result",
-            ).inc(layers=str(layers), bytes=str(nbytes))
-
-    def count_moe_dispatch(
-        self, impl: str, n_experts: int, top_k: int, rows: int, held: Optional[int] = None,
-        act: str = "swiglu",
-    ) -> None:
-        """One TRACED expert dispatch took grouped matmul ``impl``
-        ("megablox" | "ragged_dot"): ops.moe_dispatch's observer. ``rows`` are
-        the rows one grouped matmul is handed, ``held`` the experts this chip
-        holds of the ``n_experts`` routed over (all of them by default),
-        ``act`` the experts' kind ("swiglu" | "reglu" | "relu2": no gate)."""
-        if self.enabled:
-            self.registry.counter(
-                "swarm.moe_dispatch",
-                "traced expert dispatches by the grouped matmul that took them",
-            ).inc(impl=impl, E=str(n_experts), k=str(top_k), rows=str(rows),
-                  held=str(n_experts if held is None else held), act=act)
+    def count_traced(self, kind: str, labels: Dict[str, Any]) -> None:
+        """One note of ``utils/traced.py``: the code that chose something
+        while a step was TRACED said what, with its own labels. Counted as
+        ``swarm.<kind>`` for the kinds of ``TRACED_HELP``. Counts traces, not
+        steps: a compiled step never comes back here."""
+        if self.enabled and kind in TRACED_HELP:
+            self.registry.counter(f"swarm.{kind}", TRACED_HELP[kind]).inc(**labels)
 
     def _observe_span(self, sp: dict) -> None:
         if self.watchdog.enabled:
@@ -1009,38 +968,20 @@ class Telemetry:
             out[key] = out.get(key, 0) + int(rec["value"])
         return out
 
-    def attention_cores(self) -> Dict[str, int]:
-        """Traced attention calls per core, all shapes together."""
-        return self._counts_by("swarm.attention_core", "impl")
-
-    def attention_layouts(self) -> Dict[str, int]:
-        """Traced attention calls by what the core was handed and where the
-        rotary turn ran: ``{"merged/kernel": 3, "merged/none": 1}``."""
-        return self._counts_by("swarm.attention_core", "layout", "rotary")
-
-    def remat_kept(self) -> Dict[str, int]:
-        """Traced rematerialised layers whose checkpoint kept something (the
-        attention kernel's results; over ``tp`` the reduced attention output
-        product), and the bytes one chip keeps of them a step; empty where
-        every layer ran the XLA core on one chip."""
+    def traced_summary(self) -> Dict[str, Dict[str, int]]:
+        """What the traced step chose, by the summaries' keys
+        (``TRACED_SUMMARIES``), and what its rematerialised layers kept: the
+        traced layers whose checkpoint kept something (the attention kernel's
+        results; over ``tp`` the reduced attention output product) and the
+        bytes one chip keeps of them a step, ``{}`` where every layer ran the
+        XLA core on one chip."""
+        out = {key: self._counts_by(f"swarm.{kind}", *labels) for key, (kind, labels) in TRACED_SUMMARIES.items()}
         recs = self.registry.counter("swarm.remat_kept")._scrape()["values"]
-        if not recs:
-            return {}
-        return {
+        out["remat_kept"] = {
             "traced_layers": sum(int(r["value"]) for r in recs),
             "bytes_a_step": sum(int(r["value"]) * int(r["labels"]["bytes"]) for r in recs),
-        }
-
-    def qkv_projections(self) -> Dict[str, int]:
-        """Traced fused qkv projections per layout; empty for a model with
-        separate q, k and v leaves."""
-        return self._counts_by("swarm.qkv_projection", "layout")
-
-    def tp_streams(self) -> Dict[str, int]:
-        """Traced layer scans by the row streams their body runs (``{"2": n}``
-        over ``tp``, ``{"1": n}`` elsewhere); empty for a model whose layers
-        couple rows and are never split."""
-        return self._counts_by("swarm.tp_streams", "streams")
+        } if recs else {}
+        return out
 
     # -- RPC surface ---------------------------------------------------------
 
@@ -1144,16 +1085,8 @@ class Telemetry:
             "enabled": self.enabled,
             "events_recorded": self.recorder._seq,
             "spans": spans,
-            # how often the fused attention core engaged, in traced calls
-            "attention_core": self.attention_cores(),
-            # the same calls by the layout the core was handed and where the rotary turn ran
-            "attention_layout": self.attention_layouts(),
-            # how often the fused qkv projection was divided by head over tp
-            "qkv_projection": self.qkv_projections(),
-            # as how many independent row streams a layer over tp ran ({} for a model never split)
-            "tp_streams": self.tp_streams(),
-            # what rematerialised layers kept of the attention kernel ({} on the XLA core)
-            "remat_kept": self.remat_kept(),
+            # what the traced step chose (``TRACED_SUMMARIES``) and its rematerialised layers kept
+            **self.traced_summary(),
             # a sparse-expert model's dispatches and routing gauges ({} if dense)
             "moe": self.moe(),
         }

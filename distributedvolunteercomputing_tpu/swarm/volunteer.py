@@ -32,6 +32,7 @@ from distributedvolunteercomputing_tpu.swarm.membership import SwarmMembership
 from distributedvolunteercomputing_tpu.swarm.state_sync import StateSyncService
 from distributedvolunteercomputing_tpu.swarm.transport import Transport, read_secret
 from distributedvolunteercomputing_tpu.training.trainer import Trainer
+from distributedvolunteercomputing_tpu.utils import traced
 from distributedvolunteercomputing_tpu.utils.logging import errstr, get_logger
 from distributedvolunteercomputing_tpu.utils.pytree import tree_size_bytes
 
@@ -529,24 +530,11 @@ class Volunteer:
             )
             for name, t0, t1 in process_phases:
                 tracer.record(f"{process}.{name}", LIFECYCLE, t0, t1 - t0, parent=process)
-        # The attention core reports each traced call (which core took it):
-        # the summary's "attention_core" says how often the fused one engaged.
-        # So does the fused qkv projection (divided by head over tp, or not),
-        # and a rematerialised layer that kept the kernel's results.
-        from distributedvolunteercomputing_tpu.ops.attention import (
-            set_core_observer,
-            set_kept_observer,
-            set_qkv_observer,
-            set_streams_observer,
-        )
-
-        set_core_observer(self.telemetry.count_attention_core if cfg.telemetry else None)
-        set_qkv_observer(self.telemetry.count_qkv_projection if cfg.telemetry else None)
-        set_kept_observer(self.telemetry.count_remat_kept if cfg.telemetry else None)
-        set_streams_observer(self.telemetry.count_tp_streams if cfg.telemetry else None)
-        from distributedvolunteercomputing_tpu.ops.moe_dispatch import set_dispatch_observer
-
-        set_dispatch_observer(self.telemetry.count_moe_dispatch if cfg.telemetry else None)
+        # What the traced step chose (which attention core, how a projection
+        # was divided over tp, what a rematerialised layer kept, which grouped
+        # matmul): the telemetry counts every note (none with telemetry off),
+        # for as long as this holds the subscription (``run`` ends it).
+        self._traced = traced.subscribe(self.telemetry.count_traced)
         self._metrics_server = None
         # Structured-log identity: with DVC_LOG_JSON=1 every line this
         # process emits carries who/where, join-able against traces.
@@ -1332,25 +1320,10 @@ class Volunteer:
             # to the first finished step) and where it went, phase by phase;
             # {} with telemetry off.
             self.summary["lifecycle"] = self.telemetry.lifecycle
-            # Traced attention calls by core ({"flash": n} or {"xla": n};
-            # empty with telemetry off).
-            self.summary["attention_core"] = self.telemetry.attention_cores()
-            # The same calls by what the core was handed and where the rotary
-            # turn ran ({"merged/kernel": n}: the projections' own arrays, q
-            # turned on the kernel's tile; {"heads/none": n}: [B, H, T, D]).
-            self.summary["attention_layout"] = self.telemetry.attention_layouts()
-            # Traced fused qkv projections by layout ({"by_head": n} on a mesh
-            # whose tp divides the heads, {"fused": n} elsewhere).
-            self.summary["qkv_projection"] = self.telemetry.qkv_projections()
-            # Traced layer scans by the independent row streams their body
-            # runs ({"2": n} where the mesh divides a layer over tp and a
-            # replica's rows are even, {"1": n} elsewhere).
-            self.summary["tp_streams"] = self.telemetry.tp_streams()
-            # Traced rematerialised layers that kept the attention kernel's
-            # output and row statistics (over tp also the reduced attention
-            # output product), and the bytes a chip keeps of them a step ({}
-            # where every layer ran the XLA core on one chip).
-            self.summary["remat_kept"] = self.telemetry.remat_kept()
+            # What the traced step chose, by the telemetry's own keys
+            # (``attention_core``, ``attention_layout``, ``qkv_projection``,
+            # ``tp_streams``, ``remat_kept``; each empty with telemetry off).
+            self.summary.update(self.telemetry.traced_summary())
             moe = self.telemetry.moe()
             if moe:
                 # a sparse-expert model: traced dispatches by grouped matmul,
@@ -1395,6 +1368,7 @@ class Volunteer:
         finally:
             self._stop.set()
             report_task.cancel()
+            self._traced.close()
             if self.clocksync is not None:
                 self.clocksync.stop()
             if self.shard_manager is not None:

@@ -32,8 +32,8 @@ import collections
 import concurrent.futures
 import contextlib
 import json
+import functools
 import os
-import sys
 import threading
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
@@ -51,7 +51,7 @@ from distributedvolunteercomputing_tpu.training.steps import (
     make_grad_step,
     make_train_step,
 )
-from distributedvolunteercomputing_tpu.utils import step_scopes
+from distributedvolunteercomputing_tpu.utils import step_scopes, traced
 from distributedvolunteercomputing_tpu.utils.logging import errstr, get_logger
 
 log = get_logger(__name__)
@@ -66,27 +66,21 @@ AveragerFn = Callable[[Any, int], Optional[Any]]
 # bounded and every snapshot still lands, in order.
 COPIES_IN_FLIGHT = 2
 
-# What a sparse-expert step's metrics say of its routing (the attributes of a
-# ``moe.route`` span), and how often, in steps, the loop notes them between
-# its log points.
-ROUTING_KEYS = ("moe_load_max", "moe_load_mean", "moe_dropped", "moe_rows_held",
-                "moe_rows_moved", "moe_chunks_extra", "moe_act_zero_share", "moe_bias_max",
-                "moe_bias_min", "moe_bias_moved", "aux_loss", "lm_loss",
-                # a block-diffusion step (models/sdar_moe.py): the share of its tokens the noise
-                # masked, the head's rows over the layers', the attention loops' tiles over a causal mask's
-                "diffusion_masked_share", "diffusion_head_rows_share", "attention_bd_tiles_share")
+# How often, in steps, the loop notes between its log points what the model's
+# declared spans carry (``ModelBundle.spans``: a sparse-expert step's routing
+# on ``moe.route``, a recurrent mixer's scans on ``ssm.scan`` / ``kda.scan``).
 ROUTE_EVERY = 10
-# What a step with recurrent mixers says of its chunked scans, noted when and as
-# the routing is: a key's prefix names its span (``ssm_*`` the attributes of an
-# ``ssm.scan`` span, ``kda_*`` of a ``kda.scan`` span). Where the prefix's module
-# under ``ops/`` has more than one form (``SCAN_FORMS``), the span also carries
-# ``<prefix>_form``, the form that module took when the step was traced.
-SCAN_KEYS = ("ssm_carry_share", "kda_carry_share", "kda_decay_min", "kda_beta_mean")
-SCAN_FORMS = {"ssm": "ssd"}
 
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
 # ``LIFECYCLE``, which this module does not import.
 LIFECYCLE = "lifecycle"
+
+
+def _gather_noted(noted: Dict[Tuple[str, str], set], kind: str, labels: Dict[str, Any]) -> None:
+    """A subscriber of ``utils/traced.py`` that keeps, per (kind, label) asked for, the values seen."""
+    for (asked, label), seen in noted.items():
+        if asked == kind and label in labels:
+            seen.add(str(labels[label]))
 
 
 def _tree_bytes(tree: Any) -> int:
@@ -364,17 +358,14 @@ class Trainer:
             # (launch_step, launched, future): ``launched`` is the device-side
             # copy of the payload, kept for the flight as the merge's third term.
             self._inflight: Optional[tuple] = None
-            self._routing_pending: Optional[tuple] = None  # (step, its routing scalars still on the device)
-            # Which form each chunked scan took when a step was traced (the observer of its module
-            # under ops/: the kernels on one chip, the plain scan elsewhere): the ``<prefix>.scan``
-            # span's ``<prefix>_form``. A model with such mixers has loaded the module by now; nobody
-            # else pays for its import (it brings Pallas in: 0.9 s of a start-up).
-            self._scan_forms = {}  # prefix -> forms; the observers outlive this trainer: they hold the sets, not ``self``
-            for prefix, module in SCAN_FORMS.items():
-                scans = sys.modules.get(f"distributedvolunteercomputing_tpu.ops.{module}")
-                if scans is not None:
-                    forms = self._scan_forms[prefix] = set()
-                    scans.set_form_observer(lambda form, *shape, forms=forms: forms.add(form))
+            self._spans_pending: Optional[tuple] = None  # (step, its declared spans' scalars still on the device)
+            self._span_keys = frozenset(k for span in bundle.spans.values() for k in span.keys)
+            # What the declarations ask of the traces' notes (``StepSpan.noted``: which form a chunked scan
+            # took when a step was traced), gathered from now on: (kind, label) -> the values seen. The
+            # subscription holds the sets, not ``self``, and ends with this trainer.
+            self._noted = {source: set() for span in bundle.spans.values() for source in span.noted.values()}
+            if self._noted:
+                self._notes = traced.subscribe(functools.partial(_gather_noted, self._noted))
             # The in-flight launch's spans, which wait for their round's key.
             self._launch_spans: tuple = ()
             if mesh is None and (fsdp or seq_sharded):
@@ -647,49 +638,35 @@ class Trainer:
         writer.start()
         return writer
 
-    def _note_routing(self, step_no: int, m: Dict[str, Any], at_log_point: bool) -> None:
-        """Between log points, every ``ROUTE_EVERY`` steps: keep a sparse-expert
-        step's routing statistics (device scalars) and record them as a
-        ``moe.route`` span once they are ready, without waiting for them. A
+    def _note_spans(self, step_no: int, m: Dict[str, Any], at_log_point: bool) -> None:
+        """Between log points, every ``ROUTE_EVERY`` steps: keep what the
+        model's declared spans carry of this step's metrics (device scalars)
+        and record the spans once they are ready, without waiting for them. A
         model whose steps take a second passes a log point a minute; its
         routing should not be that rare, and reading it must not stop the loop."""
-        held = self._routing_pending
+        held = self._spans_pending
         if held is not None and all(v.is_ready() for v in held[1].values()):
-            self._routing_pending = None
-            self._record_routing(*held)
-        if not at_log_point and step_no % ROUTE_EVERY == 0 and self._routing_pending is None:
-            self._routing_pending = (
-                step_no, {k: v for k, v in m.items()
-                          if k in ROUTING_KEYS + SCAN_KEYS and hasattr(v, "is_ready")})
+            self._spans_pending = None
+            self._record_spans(*held)
+        if not at_log_point and step_no % ROUTE_EVERY == 0 and self._spans_pending is None:
+            self._spans_pending = (
+                step_no, {k: v for k, v in m.items() if k in self._span_keys and hasattr(v, "is_ready")})
 
-    def _record_routing(self, step_no: int, m: Dict[str, Any]) -> None:
-        """A sparse-expert step's routing statistics as the attributes of a
-        ``moe.route`` span (child of ``loop.log_sync`` at a log point, where
-        the loss has just been read: the step's other outputs are ready)."""
-        attrs = {
-            k: float(m[k])
-            for k in ROUTING_KEYS
-            if k in m
-        }
-        held = getattr(self.bundle.config, "experts_held", None)
-        if held is not None:  # a model that holds a share of its experts says how many
-            attrs["experts_held"] = int(held)
-        # which stream the layer's router reads: its input (before attention) or
-        # what attention made of it
-        attrs["router_site"] = getattr(self.bundle.config, "router_site", "post_attention")
-        # a model that lists its layers' token mixers says how many of each kind
-        for kind in sorted(set(getattr(self.bundle.config, "layer_types", ()))):
-            attrs[f"mixers_{kind}"] = self.bundle.config.layer_types.count(kind)
-        with self._phase("moe.route", step=step_no, **attrs):
-            pass
-        # a model with recurrent mixers: how much its scans carry from chunk to chunk, and in which form
-        for prefix in dict.fromkeys(k.split("_", 1)[0] for k in SCAN_KEYS):
-            scan = {k: float(m[k]) for k in SCAN_KEYS if k in m and k.startswith(prefix + "_")}
-            if scan:
-                if self._scan_forms.get(prefix):
-                    scan[f"{prefix}_form"] = "+".join(sorted(self._scan_forms[prefix]))
-                with self._phase(f"{prefix}.scan", step=step_no, **scan):
-                    pass
+    def _record_spans(self, step_no: int, m: Dict[str, Any]) -> None:
+        """Each span the model declares, with the step's metrics it names as
+        its attributes beside the declaration's own (children of
+        ``loop.log_sync`` at a log point, where the loss has just been read:
+        the step's other outputs are ready). A span of whose keys the metrics
+        hold none is not recorded."""
+        for name, span in self.bundle.spans.items():
+            attrs = {k: float(m[k]) for k in span.keys if k in m}
+            if not attrs:
+                continue
+            attrs.update(span.attrs)
+            attrs.update({attr: "+".join(sorted(self._noted[source]))
+                          for attr, source in span.noted.items() if self._noted[source]})
+            with self._phase(name, step=step_no, **attrs):
+                pass
 
     @contextlib.contextmanager
     def _round_phase(self, name: str, trace: Optional[str] = None):
@@ -1342,13 +1319,13 @@ class Trainer:
                     else self._phase("loop.log_sync", step=step_no)
                 ):
                     last_loss = float(m["loss"])
-                    if at_log_point and "moe_load_max" in m:
-                        self._record_routing(step_no, m)
+                    if at_log_point:
+                        self._record_spans(step_no, m)
                 self.metrics.record(step_no, m, n_samples=self.batch_size)
             else:
                 self.metrics.count_samples(self.batch_size)
-            if "moe_load_max" in m:
-                self._note_routing(step_no, m, at_log_point)
+            if self._span_keys:
+                self._note_spans(step_no, m, at_log_point)
 
             if self.eval_every and step_no % self.eval_every == 0:
                 ev = self.evaluate()

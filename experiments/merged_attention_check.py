@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.ops import attention as A
+from distributedvolunteercomputing_tpu.utils import traced
 
 # (name, B, T, H, Hkv, D, window, rotary): one layer kind of each cell
 SHAPES = [
@@ -79,7 +80,8 @@ def main():
     args = ap.parse_args()
     os.makedirs("chiprun_out", exist_ok=True)
     seen = []
-    A.set_core_observer(lambda *a: seen.append(a[0] + ":" + "/".join(a[-2:])))
+    listening = traced.subscribe(
+        lambda kind, said: kind == "attention_core" and seen.append("{impl}:{layout}/{rotary}".format(**said)))
     if args.tiny:
         A.set_attention_impl("flash")
     with open("chiprun_out/merged_attention_check.jsonl", "w") as out_file:
@@ -114,6 +116,7 @@ def main():
                 line["ms_by_head"] = timed(fwd_bwd(by_head), (q, k, v, cot), args.iters)
             print(json.dumps(line), flush=True)
             out_file.write(json.dumps(line) + "\n")
+    listening.close()
 
 
 if __name__ == "__main__":

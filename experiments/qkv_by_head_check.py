@@ -53,6 +53,7 @@ from distributedvolunteercomputing_tpu.parallel import make_mesh, make_param_sha
 from distributedvolunteercomputing_tpu.parallel.mesh import parse_mesh_spec
 from distributedvolunteercomputing_tpu.parallel.sharding import batch_sharding
 from distributedvolunteercomputing_tpu.parallel.train_step import step_compiler_options
+from distributedvolunteercomputing_tpu.utils import traced
 
 
 def main() -> int:
@@ -79,12 +80,17 @@ def main() -> int:
     batch = jax.device_put(
         jax.tree_util.tree_map(lambda a: a[:, :args.seq_len], batch), batch_sharding(mesh)
     )
-    layouts = []
-    attention.set_qkv_observer(lambda layout, tp: layouts.append(layout))
-    kept = []  # a chip's bytes kept a step, one entry a trace that kept something
-    attention.set_kept_observer(lambda layers, nbytes: kept.append(nbytes))
-    streams = []  # row streams a traced layer scan ran, one entry a trace
-    attention.set_streams_observer(streams.append)
+    # what the traces note (utils/traced.py), one entry a trace: the qkv projection's layout; a chip's
+    # bytes kept a step, where a layer kept something; the row streams a layer scan ran
+    layouts, kept, streams = [], [], []
+    gathered = {"qkv_projection": ("layout", layouts), "remat_kept": ("bytes", kept), "tp_streams": ("streams", streams)}
+
+    def gather(kind, said):
+        if kind in gathered:
+            label, values = gathered[kind]
+            values.append(said[label])
+
+    listening = traced.subscribe(gather)
 
     def make_loss_and_grads(bundle=bundle):  # a new function each time: jit caches traces by function
         def loss_and_grads(params, batch):
@@ -183,6 +189,7 @@ def main() -> int:
         and result["kept_against_bare"]["loss_abs_err"] <= 0.005
         and result["kept_against_bare"]["grad_rel_err"] <= 0.04
     )
+    listening.close()
     return 0 if ok else 1
 
 

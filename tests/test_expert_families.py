@@ -8,7 +8,9 @@ registry names hand the step leaves of its own."""
 
 import ast
 import dataclasses
+import functools
 import pathlib
+import re
 import types
 
 import jax
@@ -19,7 +21,7 @@ import pytest
 from benchmark.manifest import Manifest
 from distributedvolunteercomputing_tpu import models as models_package
 from distributedvolunteercomputing_tpu.models import (
-    get_model, glm4_moe_lite, kimi_linear, laguna, lfm2, list_models, moe, nemotron_h, smallthinker,
+    common, get_model, glm4_moe_lite, kimi_linear, laguna, lfm2, list_models, moe, nemotron_h, smallthinker,
 )
 from distributedvolunteercomputing_tpu.models.registry import _LANGUAGE_MODELS
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
@@ -237,8 +239,21 @@ def test_no_model_knows_the_registry_and_the_shared_helpers_know_no_model():
     assert not imports_of(package / "moe.py") & (models - {"distributedvolunteercomputing_tpu.models.moe"})
 
 
-@pytest.mark.parametrize("name", sorted(_LANGUAGE_MODELS))
-def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves_a_bias(name):
+def test_the_loop_and_the_swarm_name_no_models_metric_and_no_op_keeps_an_observer_slot():
+    """What a family's step says of itself is declared in ``models/`` (``spans(cfg)``) and what a trace chose is
+    noted where it is chosen (``utils/traced.py``): the train loop and the volunteer carry both without a name."""
+    package = pathlib.Path(models_package.__file__).parent.parent
+    for path in [*sorted((package / "training").glob("*.py")), package / "swarm" / "volunteer.py"]:
+        assert not re.findall(r"\b(?:moe|ssm|kda|diffusion|attention_bd)_[a-z]\w*", path.read_text()), path.name
+    for path in [*(package / "ops").glob("*.py"), *(package / "models").glob("*.py")]:
+        assert not re.findall(r"def set_\w*observer", path.read_text()), path.name
+    assert not any(m.startswith("distributedvolunteercomputing_tpu.ops") for m in imports_of(package / "training" / "trainer.py"))
+
+
+@functools.lru_cache(maxsize=None)
+def tiny_language_model(name):
+    """The registry's language model ``name`` at a tiny size (an expert family's at its rehearsal's) with the
+    shapes of its parameters and of what its loss returns: traced, never compiled."""
     rehearsals = {"olmoe_1b_7b": "tiny-rehearsal-olmoe", "sdar_30b_a3b": "tiny-rehearsal-sdar",
                   **{n: r for _, n, r, *_ in FAMILIES.values()}}
     if name in rehearsals:
@@ -248,6 +263,15 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
         if name == "llama_lora":
             overrides["n_kv_heads"] = 2
     bundle = get_model(name, **overrides)
+    shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    batch = jax.eval_shape(lambda: bundle.make_batch(jax.random.PRNGKey(1), 2))
+    loss, metrics = jax.eval_shape(bundle.loss_fn, shapes, batch, jax.random.PRNGKey(2))
+    return bundle, overrides, shapes, loss, metrics
+
+
+@pytest.mark.parametrize("name", sorted(_LANGUAGE_MODELS))
+def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves_a_bias(name):
+    bundle, overrides, shapes, loss, metrics = tiny_language_model(name)
     assert bundle.name == name and name in list_models()
     for key, value in overrides.items():
         got = getattr(bundle.config, key)
@@ -259,9 +283,55 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
     # the swarm averages the whole tree of every model but the one with adapters
     lora = getattr(bundle.config, "lora_rank", 0) > 0
     assert lora == (name == "llama_lora")
-    shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
     picked = jax.eval_shape(bundle.avg_select, shapes)
     assert (jax.tree_util.tree_structure(picked) == jax.tree_util.tree_structure(shapes)) == (not lora)
-    batch = jax.eval_shape(lambda: bundle.make_batch(jax.random.PRNGKey(1), 2))
-    loss, metrics = jax.eval_shape(bundle.loss_fn, shapes, batch, jax.random.PRNGKey(2))
     assert loss.shape == () and "loss" in metrics
+
+
+SPANS = {"nemotron3_nano_30b_a3b": {"moe.route", "ssm.scan"}, "kimi_linear_48b_a3b": {"moe.route", "kda.scan"},
+         **dict.fromkeys(("olmoe_1b_7b", "laguna_xs2", "smallthinker_21b_a3b", "lfm2_24b_a2b", "glm4_7_flash",
+                          "sdar_30b_a3b"), {"moe.route"})}
+
+
+@pytest.mark.parametrize("name", sorted(_LANGUAGE_MODELS))
+def test_each_key_a_bundles_spans_declare_is_a_key_its_tiny_step_returns(name):
+    bundle, _, _, _, metrics = tiny_language_model(name)
+    assert set(bundle.spans) == SPANS.get(name, set())
+    returned = {k for k, v in metrics.items() if v.shape == ()}   # the step takes the stepped rule's signal out: no scalar
+    declared = [k for span in bundle.spans.values() for k in span.keys]
+    assert len(declared) == len(set(declared)) and set(declared) <= returned
+    if bundle.spans:  # and nothing a family's step says beyond its loss is left off its spans
+        assert returned - set(declared) <= {"loss", "z_loss"}
+    for span in bundle.spans.values():
+        assert not set(span.attrs) & set(span.keys) and not set(span.noted) & (set(span.attrs) | set(span.keys))
+
+
+def test_a_bundle_that_declares_a_span_and_returns_no_routing_still_gets_it_recorded():
+    """The loop records what the bundle declares, whatever the keys are called: a stub model with a scan and no
+    experts gets its span at the log points and every ``ROUTE_EVERY`` steps, with the declaration's own attributes
+    and the label a trace noted; a declared span none of whose keys the step returns is not recorded."""
+    from distributedvolunteercomputing_tpu.models.registry import ModelBundle
+    from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+    from distributedvolunteercomputing_tpu.training.trainer import ROUTE_EVERY, Trainer
+    from distributedvolunteercomputing_tpu.utils import traced
+
+    def loss_fn(params, batch, rng):
+        traced.note("stub_scan", form="plain", width=3)
+        loss = jnp.sum((params["w"] - batch["x"].mean()) ** 2)
+        return loss, {"loss": loss, "stub_carry_share": jnp.float32(0.25), "unnamed": jnp.float32(1.0)}
+
+    bundle = ModelBundle(
+        name="stub", config=None, init=lambda rng: {"w": jnp.zeros((3,))}, loss_fn=loss_fn,
+        make_batch=lambda rng, bs: {"x": jax.random.normal(rng, (bs, 3))},
+        spans={"stub.scan": common.StepSpan(("stub_carry_share", "stub_absent"), {"stub_layers": 2},
+                                            {"stub_form": ("stub_scan", "form")}),
+               "stub.never": common.StepSpan(("stub_absent",))})
+    tel = Telemetry(peer_id="v", enabled=True)
+    trainer = Trainer(bundle, batch_size=2, lr=1e-2, optimizer="sgd", tracer=tel.tracer)
+    trainer.run(steps=ROUTE_EVERY + 5, log_every=ROUTE_EVERY + 5)
+    spans = sorted((s for s in tel.tracer.spans() if s["name"].startswith("stub.")), key=lambda s: s["attrs"]["step"])
+    # noted between the log points without a wait (recorded once its scalars were ready), and at the log point
+    assert [(s["name"], s["attrs"]["step"]) for s in spans] == [("stub.scan", ROUTE_EVERY), ("stub.scan", ROUTE_EVERY + 5)]
+    assert spans[1]["parent"] == "loop.log_sync" != spans[0].get("parent")
+    for s in spans:
+        assert s["attrs"] == {"step": s["attrs"]["step"], "stub_carry_share": 0.25, "stub_layers": 2, "stub_form": "plain"}

@@ -23,6 +23,7 @@ from benchmark.references import glm4_moe_lite as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, glm4_moe_lite as glm, moe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.training import steps
+from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
 
 TINY = tiny_models.rehearsal("glm")
@@ -276,12 +277,11 @@ def test_latent_attention_goes_through_the_core_at_one_head_dim():
     p = one_layer(params, 0)
     x = params["wte"][batch["tokens"]][:1]
     seen = []
-    attention.set_core_observer(lambda *a: seen.append(a))
-    try:
+    with traced.subscribe(lambda kind, labels: seen.append((kind, labels))):
         got = glm._attention(p, x, cfg)
-    finally:
-        attention.set_core_observer(None)
-    assert seen == [("xla", 64, 16, "float32", None, 4, "heads", "none")]   # D = 12 + 4 = the value head, a key head a query head
+    # D = 12 + 4 = the value head, a key head a query head
+    assert seen == [("attention_core", dict(impl="xla", T=64, D=16, dtype="float32", window="none", kv_heads=4,
+                                            layout="heads", rotary="none"))]
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)
         want = x + ref._latent_attention(p, n, ref.hyper(TINY), None)

@@ -465,7 +465,7 @@ def test_the_scan_under_a_mesh_takes_its_chunks_out_of_each_devices_own_shard(ei
 
 def test_train_loop_records_the_scan_span_beside_the_route_span():
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
-    from distributedvolunteercomputing_tpu.training.trainer import SCAN_KEYS, Trainer
+    from distributedvolunteercomputing_tpu.training.trainer import Trainer
 
     tel = Telemetry(peer_id="v", enabled=True)
     bundle = get_model(TINY["registry_model"], **TINY["model_overrides"])
@@ -481,7 +481,8 @@ def test_train_loop_records_the_scan_span_beside_the_route_span():
     attrs = routes[-1]["attrs"]
     assert attrs["mixers_kda"] == 4 and attrs["mixers_latent_attention"] == 1
     assert attrs["experts_held"] == 4 and "moe_chunks_extra" in attrs and "kda_carry_share" not in attrs
-    assert {k for k in SCAN_KEYS if k.startswith("kda_")} == {"kda_carry_share", "kda_decay_min", "kda_beta_mean"}
+    assert set(bundle.spans["kda.scan"].keys) == {"kda_carry_share", "kda_decay_min", "kda_beta_mean"}
+    assert not bundle.spans["kda.scan"].noted
 
 
 def test_run_volunteer_knows_the_model_and_no_training_code_names_it():

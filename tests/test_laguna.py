@@ -22,6 +22,7 @@ from benchmark.references import laguna as ref
 from distributedvolunteercomputing_tpu.models import get_model, laguna, moe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.ops.pallas_attention import choose_blocks, flash_attention
+from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
 
 TINY = tiny_models.rehearsal("laguna")
@@ -210,13 +211,8 @@ def test_the_step_announces_window_and_key_value_heads():
 
     tel = Telemetry(peer_id="t", enabled=True)
     bundle, params, batch = seeded()
-    attention.set_core_observer(tel.count_attention_core)
-    moe_dispatch.set_dispatch_observer(tel.count_moe_dispatch)
-    try:
+    with traced.subscribe(tel.count_traced):
         jax.jit(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
-    finally:
-        attention.set_core_observer(None)
-        moe_dispatch.set_dispatch_observer(None)
     cores = {(r["labels"]["window"], r["labels"]["kv_heads"], r["labels"]["impl"]): r["value"]
              for r in tel.registry.counter("swarm.attention_core")._scrape()["values"]}
     assert cores == {("none", "2", "xla"): 2, ("8", "2", "xla"): 3}

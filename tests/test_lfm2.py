@@ -22,8 +22,9 @@ import pytest
 from benchmark.manifest import Manifest
 from benchmark.references import lfm2 as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, lfm2
-from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, short_conv
+from distributedvolunteercomputing_tpu.ops import moe_dispatch, short_conv
 from distributedvolunteercomputing_tpu.training import steps
+from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
 
 TINY = tiny_models.rehearsal("lfm2")
@@ -503,12 +504,10 @@ def test_per_head_qk_norm_at_64_with_groups_of_four_through_the_core():
          "k_norm": {"g": 1.0 + 0.3 * jax.random.normal(ks[6], (hd,))}}
     x = jax.random.normal(ks[7], (2, 48, d))
     seen = []
-    attention.set_core_observer(lambda *a: seen.append(a))
-    try:
+    with traced.subscribe(lambda kind, labels: seen.append((kind, labels))):
         got = lfm2._attention(p, x, cfg)
-    finally:
-        attention.set_core_observer(None)
-    assert seen == [("xla", 48, 64, "float32", None, 8, "heads", "none")]
+    assert seen == [("attention_core", dict(impl="xla", T=48, D=64, dtype="float32", window="none", kv_heads=8,
+                                            layout="heads", rotary="none"))]
     hp = {"heads": 32, "n_kv": 8, "head_dim": 64, "theta": cfg.rope_theta, "eps": cfg.rms_eps}
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)
@@ -583,11 +582,12 @@ def test_save_and_restore_carry_the_bias(tmp_path):
 
 def test_train_loop_records_the_bias_and_the_mixers_on_the_route_span():
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
-    from distributedvolunteercomputing_tpu.training.trainer import ROUTING_KEYS, Trainer
+    from distributedvolunteercomputing_tpu.training.trainer import Trainer
 
-    assert {"moe_bias_max", "moe_bias_min", "moe_bias_moved", "moe_chunks_extra"} <= set(ROUTING_KEYS)
+    bundle = get_model("lfm2_24b_a2b", **OVERRIDES)
+    assert {"moe_bias_max", "moe_bias_min", "moe_bias_moved", "moe_chunks_extra"} <= set(bundle.spans["moe.route"].keys)
     tel = Telemetry(peer_id="v", enabled=True)
-    tr = Trainer(get_model("lfm2_24b_a2b", **OVERRIDES), batch_size=2, optimizer="adam", lr=1e-3, tracer=tel.tracer)
+    tr = Trainer(bundle, batch_size=2, optimizer="adam", lr=1e-3, tracer=tel.tracer)
     summary = tr.run(steps=11, log_every=5)
     assert math.isfinite(summary["final_loss"])
     routes = [s for s in tel.tracer.spans() if s["name"] == "moe.route"]

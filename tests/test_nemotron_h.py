@@ -23,6 +23,7 @@ from benchmark.manifest import Manifest
 from benchmark.references import nemotron_h as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, moe, nemotron_h
 from distributedvolunteercomputing_tpu.ops import moe_dispatch, ssd
+from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
 
 TINY = tiny_models.rehearsal("nemotron")
@@ -358,23 +359,21 @@ def test_the_gate_less_share_path_is_the_per_token_sum_and_its_products_end_at_t
         assert products == {"forward": 2, "backward": 5}, products
 
 
-def test_the_gate_less_kind_with_every_expert_held_is_dropless_and_the_observer_is_told():
+def test_the_gate_less_kind_with_every_expert_held_is_dropless_and_the_dispatch_is_noted():
     x, idx, gates, _, _ = share_inputs()
     ks = jax.random.split(jax.random.PRNGKey(2), 2)
     w_up, w_down = jax.random.normal(ks[0], (E, D, F)) * 0.3, jax.random.normal(ks[1], (E, F, D)) * 0.3
     seen = []
-    moe_dispatch.set_dispatch_observer(lambda *a: seen.append(a))
-    try:
+    with traced.subscribe(lambda kind, labels: seen.append({"kind": kind, **labels})):
         whole = moe_dispatch.share_glu_experts(x, idx, gates, None, w_up, w_down, 0, E, act="relu2")
         part = moe_dispatch.share_glu_experts(x, idx, gates, None, w_up[4:8], w_down[4:8], 4, E, act="relu2")
-    finally:
-        moe_dispatch.set_dispatch_observer(None)
     direct = moe_dispatch.dropless_glu_experts(x, idx, gates, None, w_up, w_down, "relu2")
     assert np.array_equal(np.asarray(whole[0]), np.asarray(direct[0])) and int(whole[3]) == S * K
     np.testing.assert_allclose(np.asarray(whole[0]), np.asarray(per_token_sum(x, idx, gates, w_up, w_down, 0, E)),
                                rtol=2e-5, atol=2e-5)
     assert int(whole[4]) == int(jnp.sum(jnp.einsum("sd,skdf->skf", x, w_up[idx]) <= 0))
-    assert [s[-1] for s in seen] == ["relu2", "relu2"] and [s[4] for s in seen] == [E, HELD]
+    assert [s["kind"] for s in seen] == ["moe_dispatch"] * 2
+    assert [s["act"] for s in seen] == ["relu2", "relu2"] and [s["held"] for s in seen] == [E, HELD]
     assert moe_dispatch.expert_kind("silu") == "swiglu" and moe_dispatch.expert_kind("relu") == "reglu"
     assert np.isfinite(np.asarray(part[0])).all()
 
@@ -454,32 +453,31 @@ def test_train_loop_records_the_scan_span_beside_the_route_span():
     assert len(routes) == len(scans) >= 2
     for s in scans:
         assert 0.0 < s["attrs"]["ssm_carry_share"] <= 1.0 and set(s["attrs"]) == {"step", "ssm_carry_share", "ssm_form"}
-        assert s["attrs"]["ssm_form"] == ssd.PLAIN        # no TPU here: what a traced scan told ops/ssd's observer
+        assert s["attrs"]["ssm_form"] == ssd.PLAIN        # no TPU here: what a traced scan's note said (ops/ssd.py)
     attrs = routes[-1]["attrs"]
     assert attrs["mixers_mamba"] == 3 and attrs["mixers_experts"] == 3 and attrs["mixers_attention"] == 1
     assert attrs["experts_held"] == 4 and "moe_act_zero_share" in attrs and "moe_chunks_extra" in attrs
     assert "ssm_carry_share" not in attrs
 
 
-def test_a_traced_scan_tells_the_observer_its_form_and_shape():
-    """``ops/ssd.set_form_observer``: one call a TRACED scan with (form, heads,
-    groups, head_dim, state, chunk), as attention's core tells its observer;
+def test_a_traced_scan_notes_its_form_and_shape():
+    """``ops/ssd.ssd``: one note ("ssd_scan") a TRACED scan with form, heads,
+    groups, head_dim, state and chunk, as attention's core notes its calls;
     the tiny model's three state-space blocks are two traces (``ME`` scanned
     twice and ``M*E``), each traced again by its checkpoint's backward."""
     bundle, params, batch = tiny()
     seen = []
-    ssd.set_form_observer(lambda *a: seen.append(a))
-    try:
+    with traced.subscribe(lambda kind, labels: kind == "ssd_scan" and seen.append((kind, tuple(labels.items())))):
         jax.make_jaxpr(lambda p: bundle.loss_fn(p, batch, None)[0])(params)
         forward = len(seen)
         jax.make_jaxpr(jax.grad(lambda p: bundle.loss_fn(p, batch, None)[0]))(params)
-    finally:
-        ssd.set_form_observer(None)
     cfg = bundle.config
     assert forward == 2 and len(seen) > 2 * forward - 1
-    assert set(seen) == {(ssd.PLAIN, cfg.mamba_heads, cfg.n_groups, cfg.mamba_head_dim, cfg.d_state, cfg.chunk)}
+    assert set(seen) == {("ssd_scan", tuple(dict(
+        form=ssd.PLAIN, heads=cfg.mamba_heads, groups=cfg.n_groups, head_dim=cfg.mamba_head_dim, state=cfg.d_state,
+        chunk=cfg.chunk).items()))}
     told = len(seen)
-    jax.make_jaxpr(lambda p: bundle.loss_fn(p, batch, None)[0])(params)      # no observer: nothing is told, nothing fails
+    jax.make_jaxpr(lambda p: bundle.loss_fn(p, batch, None)[0])(params)      # nobody subscribed: nothing is told, nothing fails
     assert len(seen) == told
 
 

@@ -16,6 +16,7 @@ from benchmark import datagen
 from benchmark.references import olmoe as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, olmoe
 from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+from distributedvolunteercomputing_tpu.utils import traced
 
 OVERRIDES = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_experts": 8, "top_k": 2,
              "d_expert": 32, "max_len": 32, "vocab": 256, "xent_chunk": 16}
@@ -384,22 +385,18 @@ def test_train_loop_records_routing_as_a_span_under_the_log_sync_and_as_gauges()
     """At each log point the loop has just read the loss; the step's routing
     statistics ride on a ``moe.route`` span whose parent is ``loop.log_sync``,
     and the telemetry turns the span into the two gauges of the summary."""
-    from distributedvolunteercomputing_tpu.ops import moe_dispatch as md
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
     from distributedvolunteercomputing_tpu.training.trainer import Trainer
 
     tel = Telemetry(peer_id="v", enabled=True)
-    md.set_dispatch_observer(tel.count_moe_dispatch)
     recorded = []
-    try:
+    with traced.subscribe(tel.count_traced):
         tr = Trainer(get_model("olmoe_1b_7b", **OVERRIDES), batch_size=2, optimizer="adam",
                      lr=1e-3, tracer=tel.tracer)
         inner = tr.metrics.record
         tr.metrics.record = lambda step, m, n_samples=0: (
             recorded.append((step, dict(m))), inner(step, m, n_samples=n_samples))
         tr.run(steps=11, log_every=5)
-    finally:
-        md.set_dispatch_observer(None)
     spans = tel.tracer.spans()
     routes = [s for s in spans if s["name"] == "moe.route"]
     assert [s["attrs"]["step"] for s in routes] == [5, 10]
@@ -445,5 +442,5 @@ def test_dropped_rows_accumulate_in_the_gauge():
             pass
     assert tel.moe() == {"load_max_over_mean": 1.5, "dropped_total": 7.0}
     off = Telemetry(peer_id="v", enabled=False)
-    off.count_moe_dispatch("megablox", 64, 8, 131072)
+    off.count_traced("moe_dispatch", dict(impl="megablox", E=64, k=8, rows=131072, held=64, act="swiglu"))
     assert off.moe() == {}

@@ -13,6 +13,7 @@ import pytest
 
 from distributedvolunteercomputing_tpu.ops.attention import attention_core, set_attention_impl
 from distributedvolunteercomputing_tpu.ops.pallas_attention import flash_attention
+from distributedvolunteercomputing_tpu.utils import traced
 
 
 def _qkv(rng, b=2, h=2, tq=40, tk=40, d=16, dtype=jnp.float32):
@@ -370,7 +371,6 @@ def test_trace_time_counter(enabled, want):
     """One count per traced attention call (the scanned block is traced once,
     its rematerialised forward once more), none per executed step, and none
     with telemetry off."""
-    from distributedvolunteercomputing_tpu.ops import attention
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 
     tel = Telemetry(peer_id="t", enabled=enabled)
@@ -378,22 +378,19 @@ def test_trace_time_counter(enabled, want):
     params = bundle.init(jax.random.PRNGKey(0))
     batch = bundle.make_batch(jax.random.PRNGKey(1), 2)
     grad = jax.jit(jax.grad(lambda p: bundle.loss_fn(p, batch, jax.random.PRNGKey(2))[0]))
-    attention.set_core_observer(tel.count_attention_core if enabled else None)
-    try:
+    with traced.subscribe(tel.count_traced):
         jax.block_until_ready(grad(params))
-        traced = tel.attention_cores()
+        cores = tel.traced_summary()["attention_core"]
         jax.block_until_ready(grad(params))  # a compiled step counts nothing
-        assert tel.attention_cores() == traced
-    finally:
-        attention.set_core_observer(None)
-    assert set(traced) == set(want)
+        assert tel.traced_summary()["attention_core"] == cores
+    assert set(cores) == set(want)
     if enabled:
-        assert traced["xla"] >= 1
-        assert tel.summary()["attention_core"] == traced
+        assert cores["xla"] >= 1
+        assert tel.summary()["attention_core"] == cores
         rec = tel.registry.counter("swarm.attention_core")._scrape()["values"][0]
         assert rec["labels"] == {"impl": "xla", "T": "32", "D": "16", "dtype": "float32",
                                  "window": "none", "kv_heads": "4", "layout": "heads", "rotary": "none"}
-        assert tel.summary()["attention_layout"] == {"heads/none": traced["xla"]}
+        assert tel.summary()["attention_layout"] == {"heads/none": cores["xla"]}
     else:
         assert tel.summary()["attention_core"] == {}
 
@@ -501,7 +498,7 @@ def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkp
     mesh = Mesh(np.array(eight_devices[:4]).reshape(2, 1, 1, 1, 2), AXES)
     grad, params = _grad_of(_tiny_gpt2(remat=True), batch_size=4)
     seen = []
-    attention.set_kept_observer(lambda layers, nbytes: seen.append((layers, nbytes)))
+    kept = traced.subscribe(lambda kind, said: kind == "remat_kept" and seen.append((said["layers"], said["bytes"])))
     try:
         set_attention_impl("flash")
         with attention.step_mesh(mesh):
@@ -513,7 +510,7 @@ def test_remat_layer_keeps_through_the_per_shard_call(eight_devices, bare_checkp
             want = jax.jit(grad)(params)
     finally:
         set_attention_impl("auto")
-        attention.set_kept_observer(None)
+        kept.close()
     assert "shard_map" in str(jaxpr)
     assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 2
     assert sorted(bare) == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 4
@@ -548,21 +545,20 @@ def test_remat_kept_counter(model, impl, want):
     with the bytes kept a step (output rows of 128 lanes whatever the head
     dim: the chip's layout), none per executed step, and nothing where the
     layers ran the XLA core; in the summary that ``coord.status`` shows per peer."""
-    from distributedvolunteercomputing_tpu.ops import attention
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 
     tel = Telemetry(peer_id="t", enabled=True)
     grad, params = _grad_of(model(remat=True))
     grad = jax.jit(grad)
-    attention.set_kept_observer(tel.count_remat_kept)
+    counting = traced.subscribe(tel.count_traced)
     try:
         set_attention_impl(impl)
         jax.block_until_ready(grad(params))
-        assert tel.remat_kept() == want
+        assert tel.traced_summary()["remat_kept"] == want
         jax.block_until_ready(grad(params))  # a compiled step counts nothing
     finally:
         set_attention_impl("auto")
-        attention.set_kept_observer(None)
+        counting.close()
     assert tel.summary()["remat_kept"] == want
     if want:
         layers = {r["labels"]["layers"] for r in tel.registry.counter("swarm.remat_kept")._scrape()["values"]}
@@ -571,17 +567,15 @@ def test_remat_kept_counter(model, impl, want):
 
 def test_remat_off_keeps_nothing():
     """``remat=False`` is the body unwrapped: no checkpoint, nothing counted."""
-    from distributedvolunteercomputing_tpu.ops import attention
-
     seen = []
     grad, params = _grad_of(_tiny_gpt2(remat=False))
-    attention.set_kept_observer(lambda *a: seen.append(a))
+    kept = traced.subscribe(lambda kind, said: kind == "remat_kept" and seen.append(said))
     try:
         set_attention_impl("flash")
         jaxpr = jax.make_jaxpr(grad)(params)
     finally:
         set_attention_impl("auto")
-        attention.set_kept_observer(None)
+        kept.close()
     assert not seen
     assert sorted(_kernel_eqns(jaxpr.jaxpr)) == ["dvc_flash_bwd", "dvc_flash_fwd"]
 
@@ -658,7 +652,7 @@ def test_merged_entry_equals_the_by_head_path(case):
     rotary = None if rotary is None else rotary()
     q, k, v, cot = _merged_qkv(b, t, h, hkv, d, dv)
     seen = []
-    A.set_core_observer(lambda *a: seen.append(f"{a[0]}:{a[6]}/{a[7]}"))
+    cores = traced.subscribe(lambda kind, said: seen.append("{impl}:{layout}/{rotary}".format(**said)))
     try:
         set_attention_impl("flash")
         got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
@@ -669,7 +663,7 @@ def test_merged_entry_equals_the_by_head_path(case):
         want = (want, *vjp(cot))
     finally:
         set_attention_impl("auto")
-        A.set_core_observer(None)
+        cores.close()
     assert got[0].shape == (b, t, h * dv)
     for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
         if exact:
@@ -756,7 +750,7 @@ def test_merged_entry_per_shard_under_a_mesh(eight_devices):
         return jnp.sum(cot * A.attention_merged(q, k, v, 4, 2, causal=True, window=96, rotary=rot))
 
     seen = []
-    A.set_core_observer(lambda *a: seen.append(a[6:]))
+    cores = traced.subscribe(lambda kind, said: seen.append((said["layout"], said["rotary"])))
     try:
         set_attention_impl("flash")
         want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -765,7 +759,7 @@ def test_merged_entry_per_shard_under_a_mesh(eight_devices):
             got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)), in_shardings=(spec,) * 3)(q, k, v)
     finally:
         set_attention_impl("auto")
-        A.set_core_observer(None)
+        cores.close()
     assert seen == [("merged", "kernel")] * 2
     np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
     for x, y in zip(got[1], want[1]):
@@ -880,7 +874,7 @@ def test_block_diffusion_merged_entry_turns_by_position_and_refuses_what_it_is_n
     q, k, v, cot = _merged_qkv(b, t, h, hkv, d, d)
     rotary = A.Rotary(base=1e6, layout="half", positions=jnp.tile(jnp.arange(t // 2), 2))
     seen = []
-    A.set_core_observer(lambda *a: seen.append(f"{a[0]}:{a[6]}/{a[7]}"))
+    cores = traced.subscribe(lambda kind, said: seen.append("{impl}:{layout}/{rotary}".format(**said)))
     try:
         set_attention_impl("flash")
         got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
@@ -892,7 +886,7 @@ def test_block_diffusion_merged_entry_turns_by_position_and_refuses_what_it_is_n
         want = (want, *vjp(cot))
     finally:
         set_attention_impl("auto")
-        A.set_core_observer(None)
+        cores.close()
     assert seen == ["flash:merged/kernel", "xla:heads/outside"], seen
     for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5, err_msg=name)

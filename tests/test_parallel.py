@@ -129,13 +129,12 @@ FUSED_QKV_MODELS = {
 @pytest.fixture
 def qkv_layouts():
     """Counts of ``swarm.qkv_projection`` as a volunteer's telemetry takes them."""
-    from distributedvolunteercomputing_tpu.ops import attention
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+    from distributedvolunteercomputing_tpu.utils import traced
 
     tel = Telemetry(peer_id="t")
-    attention.set_qkv_observer(tel.count_qkv_projection)
-    yield lambda: tel.summary()["qkv_projection"]  # what coord.status shows per peer
-    attention.set_qkv_observer(None)
+    with traced.subscribe(tel.count_traced):
+        yield lambda: tel.summary()["qkv_projection"]  # what coord.status shows per peer
 
 
 @pytest.mark.parametrize("n_heads,layout", [(4, "by_head"), (3, "fused")])
@@ -196,6 +195,7 @@ def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, 
     from distributedvolunteercomputing_tpu.models import common
     from distributedvolunteercomputing_tpu.ops import attention
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+    from distributedvolunteercomputing_tpu.utils import traced
 
     NAMED = f"name={attention.TP_REDUCED}]"
     cfg = dict(_LM, n_heads=4, remat=True)
@@ -217,14 +217,11 @@ def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, 
         return float(metrics["loss"]), grads, text
 
     tel = Telemetry(peer_id="t")
-    attention.set_kept_observer(tel.count_remat_kept)
-    try:
+    with traced.subscribe(tel.count_traced):
         ref_loss, ref_grads, ref_text = step_on(None)
-        assert tel.remat_kept() == {} and NAMED not in ref_text
+        assert tel.traced_summary()["remat_kept"] == {} and NAMED not in ref_text
         loss, grads, text = step_on(make_mesh(dp=dp, tp=tp))
-        kept = tel.remat_kept()
-    finally:
-        attention.set_kept_observer(None)
+        kept = tel.traced_summary()["remat_kept"]
     if tp > 1:
         # one scanned block traced for both layers: a chip's [8 / dp, 32, 96] f32 each
         assert kept == {"traced_layers": 1, "bytes_a_step": 2 * (8 // dp) * 32 * 96 * 4}
@@ -246,13 +243,12 @@ def test_remat_layer_keeps_the_reduced_attention_product_over_tp(eight_devices, 
 @pytest.fixture
 def tp_streams():
     """Counts of ``swarm.tp_streams`` as a volunteer's telemetry takes them."""
-    from distributedvolunteercomputing_tpu.ops import attention
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
+    from distributedvolunteercomputing_tpu.utils import traced
 
     tel = Telemetry(peer_id="t")
-    attention.set_streams_observer(tel.count_tp_streams)
-    yield lambda: tel.summary()["tp_streams"]  # what coord.status shows per peer
-    attention.set_streams_observer(None)
+    with traced.subscribe(tel.count_traced):
+        yield lambda: tel.summary()["tp_streams"]  # what coord.status shows per peer
 
 
 def _without_row_streams(monkeypatch):

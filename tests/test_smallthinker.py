@@ -22,7 +22,8 @@ import pytest
 from benchmark.manifest import Manifest
 from benchmark.references import smallthinker as ref
 from distributedvolunteercomputing_tpu.models import common, get_model, moe as share, smallthinker
-from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+from distributedvolunteercomputing_tpu.ops import moe_dispatch
+from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
 
 TINY = tiny_models.rehearsal("smallthinker")
@@ -223,18 +224,13 @@ def test_a_global_layer_encodes_no_position_and_a_sliding_layer_does():
         assert bool(jnp.allclose(a, b, rtol=1e-4, atol=1e-5)) == same, kind
 
 
-def test_the_step_announces_every_layer_kind_to_the_attention_observer():
+def test_the_step_notes_every_layer_kind_of_attention():
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 
     tel = Telemetry(peer_id="v", enabled=True)
-    attention.set_core_observer(tel.count_attention_core)
-    moe_dispatch.set_dispatch_observer(tel.count_moe_dispatch)
-    try:
+    with traced.subscribe(tel.count_traced):
         bundle, params, batch = seeded()
         jax.jit(lambda p: bundle.loss_fn(p, batch, None)[0]).lower(params)
-    finally:
-        attention.set_core_observer(None)
-        moe_dispatch.set_dispatch_observer(None)
     calls = tel.registry.counter("swarm.attention_core")._scrape()["values"]
     seen = {(r["labels"]["window"], r["labels"]["kv_heads"], r["labels"]["T"]): r["value"] for r in calls}
     assert seen == {("none", "2", "64"): 1, ("8", "2", "64"): 1}  # one trace a kind, whatever the depth
@@ -513,5 +509,5 @@ def test_a_silu_models_route_span_says_post_attention_and_carries_no_zero_share(
     assert route["attrs"]["router_site"] == "post_attention" and "moe_act_zero_share" not in route["attrs"]
     assert "act_zero_share" not in tel.summary()["moe"]
     off = Telemetry(peer_id="v", enabled=False)
-    off.count_moe_dispatch("megablox", 64, 6, 73728, 8, "reglu")  # telemetry off: nothing is counted
+    off.count_traced("moe_dispatch", dict(impl="megablox", E=64, k=6, rows=73728, held=8, act="reglu"))  # off: not counted
     assert off.registry.counter("swarm.moe_dispatch")._scrape()["values"] == []

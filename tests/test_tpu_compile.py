@@ -19,6 +19,7 @@ glm47-flash, nemotron, kimi-linear) are compiled in a file each,
 takes one and a half to two minutes and shares nothing with another test.
 """
 
+import contextlib
 import os
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
@@ -33,8 +34,21 @@ from jax.sharding import PartitionSpec as P
 from distributedvolunteercomputing_tpu.ops import mesh_codec
 from distributedvolunteercomputing_tpu.ops.mesh_collective import RingMeanFolder
 from distributedvolunteercomputing_tpu.ops.pallas_attention import flash_attention
+from distributedvolunteercomputing_tpu.utils import traced
 
 CHUNK_ELEMS = (1 << 20) // 2  # a 1 MiB wire chunk of bf16
+
+
+_CORE = ("impl", "T", "D", "window", "kv_heads", "layout", "rotary")   # of an "attention_core" note
+_KEPT = ("layers", "bytes")                                             # of a "remat_kept" note
+
+
+@contextlib.contextmanager
+def _noted(kind, *labels):
+    """What a trace inside the block notes of ``kind`` (``utils/traced.py``): a list of tuples, ``labels``' values a note."""
+    seen = []
+    with traced.subscribe(lambda noted, said: noted == kind and seen.append(tuple(said[label] for label in labels))):
+        yield seen
 
 
 @pytest.fixture(scope="module")
@@ -595,7 +609,7 @@ def test_steps_without_tp_are_the_programs_they_were(v5e, as_on_the_chip, monkey
     one-chip cells' programs and cache keys are what they were. A model whose
     layers couple rows (a share of experts) never asks for streams."""
     from distributedvolunteercomputing_tpu.models import common
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
     from distributedvolunteercomputing_tpu.parallel.mesh import AXES
     from distributedvolunteercomputing_tpu.parallel.train_step import step_compiler_options
 
@@ -606,20 +620,18 @@ def test_steps_without_tp_are_the_programs_they_were(v5e, as_on_the_chip, monkey
     mesh = Mesh(np.asarray(v5e[: dp * tp]).reshape(dp, 1, 1, 1, tp), AXES)
     assert step_compiler_options(mesh) == {}
     assert step_compiler_options(Mesh(np.asarray(v5e).reshape(2, 1, 1, 1, 2), AXES))  # and some with one
-    seen = []
-    attention.set_streams_observer(seen.append)
     # name stacks only: with Python frames a kernel's serialised module follows every line on the way to it
     frames = jax.config.jax_traceback_in_locations_limit
     jax.config.update("jax_traceback_in_locations_limit", 0)
     try:
-        text = _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text()
-        assert seen == asked
+        with _noted("tp_streams", "streams") as seen:
+            text = _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text()
+        assert [streams for (streams,) in seen] == asked
         scan_blocks = common.scan_blocks
         monkeypatch.setattr(common, "scan_blocks", lambda *a, rows_independent=False, **kw: scan_blocks(*a, **kw))
         assert _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text() == text
     finally:
         jax.config.update("jax_traceback_in_locations_limit", frames)
-        attention.set_streams_observer(None)
 
 
 def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
@@ -633,15 +645,9 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
     traced once, at one stream's 8 rows, and runs twice)."""
     import re
 
-    from distributedvolunteercomputing_tpu.ops import attention
-
     def kept(*model_mesh_batch):
-        seen = []
-        attention.set_kept_observer(lambda layers, nbytes: seen.append((layers, nbytes)))
-        try:
+        with _noted("remat_kept", *_KEPT) as seen:
             jaxpr = str(_traced_step(v5e, *model_mesh_batch).jaxpr)
-        finally:
-            attention.set_kept_observer(None)
         return sorted(set(re.findall(r"name\[name=(\w+)\]", jaxpr))), seen  # the names, however often printed
 
     def kernel(b, h, t):  # the output's rows at 128 lanes of bf16 and a float32 log-sum-exp a row
@@ -687,20 +693,17 @@ def test_which_cells_hand_the_kernels_the_projections_own_arrays(v5e, as_on_the_
     calls are handed ``[B, H, T, D]`` as before (a head of 64, a latent key
     concatenated by head, a model that calls ``attention_core`` itself): the
     shapes decide, and ``swarm.attention_core`` says which (PR 59)."""
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     (model, dp, tp, batch, n_layers, overrides), want = _CELL_LAYOUTS[cell]
     tel = Telemetry()
-    attention.set_core_observer(tel.count_attention_core)
-    try:
+    with traced.subscribe(tel.count_traced):
         _traced_step(v5e, model, dp, tp, batch, n_layers, **overrides)
-    finally:
-        attention.set_core_observer(None)
-    assert tel.attention_layouts() == want
-    assert tel.attention_cores() == {"flash": sum(want.values())}
+    assert tel.traced_summary()["attention_layout"] == want
+    assert tel.traced_summary()["attention_core"] == {"flash": sum(want.values())}
 
 
 def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):

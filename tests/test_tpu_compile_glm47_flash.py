@@ -13,9 +13,12 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
+    _CORE,
+    _KEPT,
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _noted,
     no_persistent_cache,
     _share_chunks_hold_seven_grouped_matmuls,
     _step_holds_the_groups_its_cell_lists,
@@ -59,16 +62,9 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     assert pallas_attention.choose_blocks(t, t, 2 * d, jnp.bfloat16) is None
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
-        (impl, t, d, window, kv_heads, *how)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
+    with _noted("attention_core", *_CORE) as seen, _noted("remat_kept", *_KEPT) as kept:
         compiled = _lowered_step(v5e, "glm4_7_flash", 1, 1, 2, n_layers=5, experts_held=8, vocab=19360).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", t, d, None, 20, "heads", "none")] * 2, seen          # one traced dense layer, one traced scan body
+    assert seen == [("flash", t, d, "none", 20, "heads", "none")] * 2, seen          # one traced dense layer, one traced scan body
     # the output at 20 x 256 a token and the f32 row statistics: 168.8 MB a layer, 845.4 MB a step
     assert kept == [(1, 169_082_880), (4, 4 * 169_082_880)], kept
     text = compiled.as_text()

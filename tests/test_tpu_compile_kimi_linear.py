@@ -12,9 +12,12 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
+    _CORE,
+    _KEPT,
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _noted,
     no_persistent_cache,
     _step_holds_the_groups_its_cell_lists,
     v5e,
@@ -40,21 +43,14 @@ def test_kimi_linear_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_t
     the chip."""
     from benchmark import kda_trace
     from distributedvolunteercomputing_tpu.models import kimi_linear
-    from distributedvolunteercomputing_tpu.ops import attention, kda, moe_dispatch
+    from distributedvolunteercomputing_tpu.ops import kda, moe_dispatch
 
     monkeypatch.setattr(kda, "tpu_backend", lambda: True)     # bfloat16 products as the chip takes them
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
-        (impl, t, d, window, kv_heads, *how)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
+    with _noted("attention_core", *_CORE) as seen, _noted("remat_kept", *_KEPT) as kept:
         compiled = _lowered_step(v5e, "kimi_linear_48b_a3b", 1, 1, 2, n_layers=5, experts_held=8, vocab=20480).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 192, None, 32, "heads", "none")], seen  # a key of 192: the by-head entry
+    assert seen == [("flash", 8192, 192, "none", 32, "heads", "none")], seen  # a key of 192: the by-head entry
     # what the layers keep: the latent layer's output at 32 x 128 a token and its row statistics; a KDA layer nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()

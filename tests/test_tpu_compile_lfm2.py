@@ -12,9 +12,11 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
+    _CORE,
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _noted,
     no_persistent_cache,
     _share_chunks_hold_seven_grouped_matmuls,
     _step_holds_the_groups_its_cell_lists,
@@ -43,20 +45,15 @@ def test_lfm2_step_holds_its_kernels_one_trace_a_layer_shape(v5e, as_on_the_chip
     the S x k = 131,072, seven a traced expert layer. That it compiles says it
     fits the chip; its temporaries are 6.186e9 (7.359e9 at 49,152 rows, PR 39)."""
     from distributedvolunteercomputing_tpu.models import lfm2
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
-        (impl, t, d, window, kv_heads, *how)))
-    try:
+    with _noted("attention_core", *_CORE) as seen:
         compiled = _lowered_step(
             v5e, "lfm2_24b_a2b", 1, 1, 4, n_layers=None, layer_types="conv,full_attention,conv,conv,conv",
             dense_layers=1, experts_held=8, vocab=8192).compile()
-    finally:
-        attention.set_core_observer(None)
-    assert set(seen) == {("flash", 8192, 64, None, 8, "heads", "none")}, seen  # D = 64: the by-head entry
+    assert set(seen) == {("flash", 8192, 64, "none", 8, "heads", "none")}, seen  # D = 64: the by-head entry
     text = compiled.as_text()
     _step_holds_the_groups_its_cell_lists(text, "lfm2-solo-8k")
     calls = _kernel_calls(text)

@@ -12,9 +12,12 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
+    _CORE,
+    _KEPT,
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _noted,
     no_persistent_cache,
     _step_holds_the_groups_its_cell_lists,
     v5e,
@@ -42,7 +45,7 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     chunk of 7,680 rows (the even share of 6,144 and a quarter), never the
     S x k = 98,304. That it compiles says it fits the chip."""
     from distributedvolunteercomputing_tpu.models import nemotron_h
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, ssd
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, ssd
 
     monkeypatch.setattr(ssd, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -50,16 +53,9 @@ def test_nemotron_step_holds_its_kernels_one_trace_a_unit_shape(v5e, as_on_the_c
     assert moe_dispatch._megablox_tiling(7680, 2688, 1856) is None and moe_dispatch._megablox_tiling(7680, 1856, 2688) is None
     assert (moe_dispatch._padded(2688), moe_dispatch._padded(1856)) == (3072, 2048)
     assert moe_dispatch._megablox_tiling(7680, 3072, 2048) == (512, 1024, 1024)
-    seen, kept = [], []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
-        (impl, t, d, window, kv_heads, *how)))
-    attention.set_kept_observer(lambda layers, nbytes: kept.append((layers, nbytes)))
-    try:
+    with _noted("attention_core", *_CORE) as seen, _noted("remat_kept", *_KEPT) as kept:
         compiled = _lowered_step(v5e, "nemotron3_nano_30b_a3b", 1, 1, 2, n_layers=7, experts_held=8, vocab=16384).compile()
-    finally:
-        attention.set_core_observer(None)
-        attention.set_kept_observer(None)
-    assert seen == [("flash", 8192, 128, None, 2, "heads", "none")], seen  # the model calls attention_core itself
+    assert seen == [("flash", 8192, 128, "none", 2, "heads", "none")], seen  # the model calls attention_core itself
     # what the blocks keep: the attention block's output and row statistics; a state-space block nothing
     assert kept == [(1, 2 * 32 * 8192 * (128 * 2 + 4))], kept
     text = compiled.as_text()

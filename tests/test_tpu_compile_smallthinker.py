@@ -14,9 +14,11 @@ import pytest
 
 from tests.test_tpu_compile import (  # noqa: F401 — the fixtures are used by name
     as_on_the_chip,
+    _CORE,
     _kernel_calls,
     _kernel_names,
     _lowered_step,
+    _noted,
     _no_pass_over_a_head_shaped_array,
     no_persistent_cache,
     _share_chunks_hold_seven_grouped_matmuls,
@@ -50,7 +52,7 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
     run's product, the heap simulator lays 0.24e9 more out, and a tile of 256
     rows compiles to the same (PERF.md, Findings of PR 38)."""
     from distributedvolunteercomputing_tpu.models import smallthinker
-    from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, pallas_attention
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention
 
     t, d = 16384, 128
     for window in (None, 4096):
@@ -62,17 +64,12 @@ def test_smallthinker_step_runs_every_layer_on_the_flash_kernels_at_16k(v5e, as_
     assert pallas_attention.choose_blocks(2 * t, 2 * t, d, jnp.bfloat16) is None  # the next doubling does not fit
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
-    seen = []
-    attention.set_core_observer(lambda impl, t, d, dtype, window=None, kv_heads=None, *how: seen.append(
-        (impl, t, window, kv_heads, *how)))
-    try:
+    with _noted("attention_core", *(label for label in _CORE if label != "D")) as seen:
         compiled = _lowered_step(
             v5e, "smallthinker_21b_a3b", 1, 1, 2, n_layers=4, experts_held=8, vocab=18992).compile()
-    finally:
-        attention.set_core_observer(None)
     # both layer kinds on the projections' own arrays; the sliding layers' q turned on the kernel's tile
     assert sorted(set(seen), key=str) == [
-        ("flash", t, 4096, 4, "merged", "kernel"), ("flash", t, None, 4, "merged", "none")], seen
+        ("flash", t, "none", 4, "merged", "none"), ("flash", t, 4096, 4, "merged", "kernel")], seen
     text = compiled.as_text()
     _step_holds_the_groups_its_cell_lists(text, "smallthinker-solo-16k")
     calls = _kernel_calls(text)
