@@ -225,8 +225,8 @@ def remat_layer(body, layers: int = 1, calls: int = 1):
     ``w_down`` results are not named: no cell runs them over ``tp`` and their
     room at 8,192-16,384 tokens a row is smaller than this stack. ``layers``:
     how many layers run this one trace (a scan's length), and ``calls``: how
-    often ``body`` runs what it traced once (``scan_blocks``' row streams), for
-    ``swarm.remat_kept``'s bytes."""
+    often ``body`` runs what it traced once (``scan_blocks``' row streams, times
+    the passes of a loop around the scan), for ``swarm.remat_kept``'s bytes."""
     from distributedvolunteercomputing_tpu.ops.pallas_attention import KEPT_NAMES
 
     # a name that no value of the trace carries (TP_REDUCED where tp is 1) keeps nothing
@@ -241,7 +241,7 @@ def remat_layer(body, layers: int = 1, calls: int = 1):
 
 
 def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_outputs: bool = False,
-                rows_independent: bool = False):
+                rows_independent: bool = False, passes: int = 1):
     """Run ``x`` through stacked ``blocks`` with ``lax.scan``; ``body`` is
     ``(layer_params, x) -> x``. With ``remat`` each layer's activations are
     rematerialized in backward (``remat_layer`` per scan step), the standard
@@ -261,7 +261,11 @@ def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_out
     with asynchronous collectives), forward and backward. The checkpoint keeps
     of each half what it kept of the whole. Elsewhere the jaxpr is the one it
     was (the step over a ``tp`` axis is compiled with that axis's options
-    whether or not a model splits)."""
+    whether or not a model splits).
+
+    ``passes``: how often an enclosing loop runs this scan over the same
+    ``blocks`` (a looped model's passes, ``models/ouro.py``: the scan is traced
+    once inside the outer loop's body), for ``swarm.remat_kept``'s bytes."""
     streams = 1
     if rows_independent:
         streams = attention_ops.tp_streams(x.shape[0])
@@ -276,7 +280,7 @@ def scan_blocks(body, blocks: Params, x: jax.Array, remat: bool = True, with_out
         def body(p, hs):
             return tuple(one(p, h) for h in hs)
 
-    fn = remat_layer(body, jax.tree_util.tree_leaves(blocks)[0].shape[0], streams) if remat else body
+    fn = remat_layer(body, jax.tree_util.tree_leaves(blocks)[0].shape[0], streams * passes) if remat else body
 
     def step(h, p):
         return fn(p, h) if with_outputs else (fn(p, h), None)
@@ -294,13 +298,15 @@ def _project_vocab(x: jax.Array, head: jax.Array, head_layout: str) -> jax.Array
 
 
 def _xent_chunks(x, head, labels, weights, divisor, chunk: int, head_layout: str,
-                 with_dx: bool = False, with_dhead: bool = False):
-    """ONE ``lax.scan`` over the ``chunk``-sized slices of T: ``(loss, dx, dhead)``,
-    the loss alone (one vocabulary-sized product a chunk) unless a gradient is
-    asked for. A chunk that is asked makes ``dlogits`` while its logits are in
-    hand and spends it at once: ``dx``'s rows (in ``x``'s dtype, stacked by the
-    scan) and ``dhead``'s sum (float32, carried by the loop, in ``head``'s dtype
-    at the end), both of the loss as it is returned, ``nll . weights / divisor``.
+                 with_dx: bool = False, with_dhead: bool = False, with_dweights: bool = False):
+    """ONE ``lax.scan`` over the ``chunk``-sized slices of T: ``(loss, dx, dhead,
+    dweights)``, the loss alone (one vocabulary-sized product a chunk) unless a
+    gradient is asked for. A chunk that is asked makes ``dlogits`` while its
+    logits are in hand and spends it at once: ``dx``'s rows (in ``x``'s dtype,
+    stacked by the scan) and ``dhead``'s sum (float32, carried by the loop, in
+    ``head``'s dtype at the end), both of the loss as it is returned,
+    ``nll . weights / divisor``; ``dweights`` is the chunk's ``nll / divisor``
+    as it stands (float32, stacked as ``dx`` is), no product.
     The products take what autodiff's took (read off the compiled steps of
     ``olmoe-solo`` and ``medium-solo``): the float32 ``dlogits`` against the
     operand in compute dtype, float32 out of the MXU."""
@@ -318,7 +324,10 @@ def _xent_chunks(x, head, labels, weights, divisor, chunk: int, head_layout: str
         logits = _project_vocab(xc, head_c, head_layout)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * wc[0] if wc else logz - gold
+        nll = logz - gold  # a token's, before its weight
+        dwc = nll / divisor if with_dweights else None
+        if wc:
+            nll = nll * wc[0]
         dxc = None
         if with_dx or with_dhead:
             hit = lc[..., None] == jnp.arange(logits.shape[-1])
@@ -331,15 +340,17 @@ def _xent_chunks(x, head, labels, weights, divisor, chunk: int, head_layout: str
             lhs, rhs = (dlogits, xc) if head_layout == "vd" else (xc, dlogits)
             dhead = dhead + jax.lax.dot_general(lhs, rhs, (((0, 1), (0, 1)), ((), ())),
                                                 preferred_element_type=jnp.float32)
-        return (nll_sum + jnp.sum(nll), dhead), dxc
+        return (nll_sum + jnp.sum(nll), dhead), (dxc, dwc)
 
     chunks = (by_chunk(x), by_chunk(labels)) + (() if weights is None else (by_chunk(weights),))
     zero = jnp.zeros((), jnp.float32)
-    (nll_sum, dhead), dx = jax.lax.scan(
+    (nll_sum, dhead), (dx, dweights) = jax.lax.scan(
         body, (zero, jnp.zeros(head.shape, jnp.float32) if with_dhead else None), chunks)
     if with_dx:
         dx = jnp.moveaxis(dx, 0, 1).reshape(x.shape)
-    return nll_sum / divisor, dx, dhead.astype(head.dtype) if with_dhead else None
+    if with_dweights:
+        dweights = jnp.moveaxis(dweights, 0, 1).reshape(b, t)
+    return nll_sum / divisor, dx, dhead.astype(head.dtype) if with_dhead else None, dweights
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -348,18 +359,23 @@ def _xent_scalar(x, head, labels, weights, divisor, chunk, head_layout):
 
 
 def _xent_scalar_fwd(x, head, labels, weights, divisor, chunk, head_layout):
-    # symbolic_zeros: each argument comes with whether it is differentiated at all, so a frozen
-    # head (an adapter-only finetune) costs no [d, V] accumulator and no third product
-    loss, dx, dhead = _xent_chunks(
+    # symbolic_zeros: each argument comes with whether it is differentiated at all. A frozen head (an
+    # adapter-only finetune) costs no [d, V] accumulator and no third product; weights nobody learns through
+    # (a mask, a noise schedule's 1 / t) cost no stack of the tokens' own losses.
+    loss, dx, dhead, dweights = _xent_chunks(
         x.value, head.value, labels.value, None if weights is None else weights.value, divisor.value,
-        chunk, head_layout, with_dx=x.perturbed, with_dhead=head.perturbed)
-    return loss, (dx, dhead)
+        chunk, head_layout, with_dx=x.perturbed, with_dhead=head.perturbed,
+        with_dweights=weights is not None and weights.perturbed)
+    # the divisor's own: d (sum / divisor) = -loss / divisor (a mask's sum that something is learnt through)
+    ddivisor = -loss / divisor.value if divisor.perturbed else None
+    return loss, (dx, dhead, dweights, ddivisor)
 
 
 def _xent_scalar_bwd(chunk, head_layout, residuals, g):
     # the gradients of the loss times the scalar cotangent (the literal 1.0 under value_and_grad: no pass);
     # a loss nobody's cotangent reaches never gets here (the backward pass skips an all-zero cotangent)
-    return tuple(None if r is None else r * g.astype(r.dtype) for r in residuals) + (None, None, None)
+    dx, dhead, dweights, ddivisor = (None if r is None else r * g.astype(r.dtype) for r in residuals)
+    return dx, dhead, None, dweights, ddivisor
 
 
 _xent_scalar.defvjp(_xent_scalar_fwd, _xent_scalar_bwd, symbolic_zeros=True)
@@ -393,7 +409,12 @@ def lm_xent_chunked(
     ``mask`` is an optional 0/1 token mask (MLM objective), over whose sum the
     loss is the mean; with a ``denominator`` it is per-token WEIGHTS and the loss
     is the weighted sum over that divisor (a denoising loss: masked tokens by
-    ``1 / t`` over B x L). Neither ``mask`` nor ``denominator`` is differentiated.
+    ``1 / t`` over B x L; a looped model's passes by their exit probabilities,
+    ``models/ouro.py``). The weights are differentiated where something is learnt
+    through them (``d loss / d weights = nll / divisor``, which each chunk has in
+    hand: under the same ``symbolic_zeros`` rule as ``x`` and ``head``, so a mask
+    or a schedule that nothing learns through adds nothing to the program); the
+    labels are not.
     """
     b, t, _ = x.shape
     if t % chunk != 0:

@@ -1,7 +1,7 @@
 """Model registry: one bundle per reference workload (BASELINE.json:7-11),
 and the public architectures run at their published sizes beyond them
 (``olmoe_1b_7b``, ``laguna_xs2``, ``smallthinker_21b_a3b``, ``lfm2_24b_a2b``,
-``glm4_7_flash``, ``nemotron3_nano_30b_a3b``, ``kimi_linear_48b_a3b``, ``sdar_30b_a3b``: each takes the overrides that cut it to one chip's share without touching a
+``glm4_7_flash``, ``nemotron3_nano_30b_a3b``, ``kimi_linear_48b_a3b``, ``sdar_30b_a3b``, ``ouro_2_6b``: each takes the overrides that cut it to one chip's share without touching a
 width).
 
 Bundles are built lazily so importing the registry never pays for the whole
@@ -159,6 +159,9 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # copy of every sequence under one three-part mask): ``n_layers``, ``experts_held`` /
     # ``expert_offset``, ``vocab`` with ``mask_id`` inside it; its loss draws the noise from the step's rng
     "sdar_30b_a3b": ("sdar_moe", "SdarMoeConfig"),
+    # 48 dense layers run four times over the same weights, a head and an exit gate after every pass:
+    # ``n_layers``, ``max_len`` (``passes`` is not depth and is not cut)
+    "ouro_2_6b": ("ouro", "OuroConfig"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 

@@ -41,6 +41,9 @@ VOCABULARY: Dict[str, str] = {
     "loss_head": "loss_head",
     "optimizer": "optimizer",
     "noising": "other",  # a block-diffusion step's draw of its noise and its noised copy (models/sdar_moe.py)
+    # a looped model's loop over its passes (models/ouro.py): what lies in it and under no block's word is the loop's
+    # own (the carry's copies, the stack of the passes' states, the shared weights' gradient sums, the passes' norm)
+    "recur": "other",
 }
 OTHER = "other"  # resolved, under no word of a group's own: embedding, final norm, the layer scan's own slices
 GROUPS: Tuple[str, ...] = tuple(dict.fromkeys((*VOCABULARY.values(), OTHER)))

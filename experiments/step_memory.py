@@ -42,6 +42,9 @@ CELLS = {
     # what the cell's five layers were chosen against (PERF.md section 4): six hold 645.6 M parameters
     "sdar-six-layers": ("sdar_30b_a3b", 1, 1, 2,
                         {"n_layers": 6, "experts_held": 16, "vocab": 18992, "mask_id": 18991}),
+    "ouro-solo-4k": ("ouro_2_6b", 1, 1, 2, {"n_layers": 6, "max_len": 4096}),
+    # what the cell's six layers were chosen against (PERF.md section 4): seven hold 561.0 M parameters
+    "ouro-seven-layers": ("ouro_2_6b", 1, 1, 2, {"n_layers": 7, "max_len": 4096}),
 }
 
 

@@ -270,6 +270,17 @@ _YARDSTICK_PINS = (
     ("test_manifest_as_the_scope_tests_asserted_it_two_places_up", "test_yardstick_collective_pairs.py",
      "asserts that PR 57's two metrics end per_layer, 68 entries; PR 60 appended four metrics, a cell and a "
      "configuration (checked in test_yardstick_sdar_moe.py)"),
+    # PR 64 (ouro-2.6b, ouro-solo-4k, recur.exit_entropy / expected_passes / outside_blocks_ms; the cell appended to
+    # tok_s_chip's list and to twenty-one per-layer lists): tests/yardstick/test_yardstick_ouro.py asserts what each
+    # of these asserted, against the manifest less this PR's entries.
+    ("test_configuration_file_is_what_the_program_runs", "[ouro-2.6b]",
+     "asserts reduced == []; ouro-2.6b lists its cut (checked in test_yardstick_ouro.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_sdar_moe.py",
+     "asserts that sdar-solo-4k ends the manifest, SDAR's four metrics per_layer and its lists; PR 64 appended three "
+     "metrics, a cell and a configuration (checked in test_yardstick_ouro.py)"),
+    ("test_manifest_as_the_collective_pairs_tests_asserted_it_before_this_cell", "test_yardstick_sdar_moe.py",
+     "takes SDAR's four metrics, cell and configuration off the manifest's END to run the older tail tests; PR 64's "
+     "entries end it now (checked, with both PRs' entries taken off, in test_yardstick_ouro.py)"),
 )
 
 

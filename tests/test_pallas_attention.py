@@ -423,6 +423,14 @@ def _tiny_laguna(**kw):
         "laguna_xs2", **Manifest().load_config("tiny-rehearsal-laguna")["model_overrides"], **kw)
 
 
+def _tiny_ouro(**kw):
+    """The benchmark's rehearsal cut of Ouro: three scanned layers of 4 heads, T=32, inside a loop of four passes."""
+    from benchmark.manifest import Manifest
+    from distributedvolunteercomputing_tpu.models import get_model
+
+    return get_model("ouro_2_6b", **Manifest().load_config("tiny-rehearsal-ouro")["model_overrides"], **kw)
+
+
 def _kept(b, h, t):
     """Bytes a chip keeps of one f32 call: an output row takes 128 lanes
     whatever the head dim, and a log-sum-exp a row."""
@@ -533,18 +541,21 @@ def test_remat_layer_on_the_xla_core_is_the_bare_checkpoint(bare_checkpoint):
     assert got == jax.jit(grad).lower(params).as_text()
 
 
-@pytest.mark.parametrize("model,impl,want", [
-    (_tiny_gpt2, "flash", {"traced_layers": 1, "bytes_a_step": 2 * _kept(2, 4, 32)}),
-    (_tiny_laguna, "flash", {"traced_layers": 5, "bytes_a_step": 2 * _kept(2, 6, 64) + 3 * _kept(2, 8, 64)}),
-    (_tiny_gpt2, "auto", {}),  # the CPU's auto routing: the XLA core, nothing kept
-    (_tiny_laguna, "auto", {}),
-], ids=["gpt2-flash", "laguna-flash", "gpt2-xla", "laguna-xla"])
-def test_remat_kept_counter(model, impl, want):
+@pytest.mark.parametrize("model,impl,want,scanned", [
+    (_tiny_gpt2, "flash", {"traced_layers": 1, "bytes_a_step": 2 * _kept(2, 4, 32)}, "2"),
+    (_tiny_laguna, "flash", {"traced_layers": 5, "bytes_a_step": 2 * _kept(2, 6, 64) + 3 * _kept(2, 8, 64)}, "1"),
+    # one traced body that a scan of 3 layers runs inside a loop of 4 passes: 12 layer-runs' results are kept
+    (_tiny_ouro, "flash", {"traced_layers": 1, "bytes_a_step": 3 * 4 * _kept(2, 4, 32)}, "3"),
+    (_tiny_gpt2, "auto", {}, None),  # the CPU's auto routing: the XLA core, nothing kept
+    (_tiny_laguna, "auto", {}, None),
+], ids=["gpt2-flash", "laguna-flash", "ouro-flash", "gpt2-xla", "laguna-xla"])
+def test_remat_kept_counter(model, impl, want, scanned):
     """``swarm.remat_kept``: one count per TRACED layer whose checkpoint kept
-    a kernel's results (a scanned block is traced once for all its layers)
-    with the bytes kept a step (output rows of 128 lanes whatever the head
-    dim: the chip's layout), none per executed step, and nothing where the
-    layers ran the XLA core; in the summary that ``coord.status`` shows per peer."""
+    a kernel's results (a scanned block is traced once for all its layers, and
+    once for every pass of a loop around the scan) with the bytes kept a step,
+    the passes counted (output rows of 128 lanes whatever the head dim: the
+    chip's layout), none per executed step, and nothing where the layers ran
+    the XLA core; in the summary that ``coord.status`` shows per peer."""
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 
     tel = Telemetry(peer_id="t", enabled=True)
@@ -562,7 +573,7 @@ def test_remat_kept_counter(model, impl, want):
     assert tel.summary()["remat_kept"] == want
     if want:
         layers = {r["labels"]["layers"] for r in tel.registry.counter("swarm.remat_kept")._scrape()["values"]}
-        assert layers == ({"2"} if model is _tiny_gpt2 else {"1"})
+        assert layers == {scanned}
 
 
 def test_remat_off_keeps_nothing():

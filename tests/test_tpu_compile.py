@@ -335,6 +335,52 @@ def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     _head_makes_its_gradients_in_the_loop_of_its_loss(text, 50257)
 
 
+def test_ouro_step_is_one_loop_of_four_passes_over_one_traced_layer_and_one_head_loop(v5e, as_on_the_chip):
+    """ouro-solo-4k's step at the cell's widths, sequence and batch, two of
+    its six layers (the scanned block appears once whatever the depth; 25 s):
+    ONE traced layer body, so one attention kernel forward and one backward at
+    ``bf16[2,4096,2048]`` (the projections' own arrays) whatever the passes, and
+    none in the recomputed forward; the forward loop over the passes has a
+    trip count of 4 with the layer scan inside it, and so has the backward;
+    the head is ONE loop over all four passes' rows (``[R * B, chunk, V]``
+    logits, three vocabulary-sized products) with ONE float32 ``[d, V]``
+    accumulator; the loop's own instructions resolve to ``recur`` in the scope
+    map (group ``other``), the blocks' inside it to ``attention`` and ``mlp``;
+    and ``swarm.remat_kept`` counts layers x passes of the one trace."""
+    import re
+
+    from distributedvolunteercomputing_tpu.utils import step_scopes
+
+    with _noted("remat_kept", *_KEPT) as kept:
+        text = _step_text(v5e, "ouro_2_6b", 1, 1, 2, max_len=4096)
+    calls = _kernel_calls(text)
+    flash = sorted(n.split(".")[0] for n in _kernel_names(calls) if n.startswith("dvc_flash"))
+    assert flash == ["dvc_flash_bwd", "dvc_flash_fwd"], flash
+    assert all(ln.count("bf16[2,4096,2048]") >= 3 for ln in calls if "dvc_flash_" in ln)
+    # two layers, four passes: the kernel's bf16 output and a float32 log-sum-exp a row, eight times over
+    assert kept == [(2, 2 * 4 * 2 * 16 * 4096 * (128 * 2 + 4))]
+    _step_holds_the_groups_its_cell_lists(text, "ouro-solo-4k")
+    _head_makes_its_gradients_in_the_loop_of_its_loss(text, 49152)
+    # the loops by where they come from: the passes' loop forward and backward, the layer scan inside each, the
+    # head's chunks, and the compiler's own loop over the layers (it casts the shared weights ONCE, outside the passes)
+    whiles = {ln.split('op_name="')[1].split('"')[0]: ln.split(" while(")[0]
+              for ln in text.splitlines() if re.search(r" while\(", ln)}
+    inner = "/while/body/closed_call/while"
+    assert set(whiles) == {"jit(step)/jvp(recur)/while", "jit(step)/jvp(recur)" + inner,
+                           "jit(step)/transpose(jvp(recur))/while", "jit(step)/transpose(jvp(recur))" + inner,
+                           "jit(step)/jvp(loss_head)/while", "jit(step)/while"}, sorted(whiles)
+    # the passes' loop carries the four passes' states and, of passes x layers, a layer-run's input and kept output
+    carried = whiles["jit(step)/jvp(recur)/while"]
+    assert "bf16[4,2,4096,2048]" in carried and carried.count("bf16[4,2,2,4096,2048]") == 2
+    # ONE head loop over all four passes' rows, 32 chunks of [R * B, 128], with ONE float32 [d, V] accumulator
+    head_loop = whiles["jit(step)/jvp(loss_head)/while"]
+    assert "bf16[32,8,128,2048]" in head_loop and head_loop.count("f32[2048,49152]") == 1
+    assert "f32[8,128,49152]" in text
+    got = step_scopes.scope_map(text)
+    scopes = {r["scope"] for r in got.values()}
+    assert {"recur", "attention", "mlp", "loss_head", "optimizer"} <= scopes and step_scopes.group_of("recur") == "other"
+
+
 # ``slow`` since PR 58: one cell-size compile for a described v5e, 42 s of the tier-1 run's six
 # workers and 3.5 to 6 GB of host memory, that shares nothing with another test; the run's other tests did not fit
 # the command's limit beside the eight such compiles (ROADMAP D3). Run it before any chip run of a PR that touches a
@@ -680,6 +726,8 @@ _CELL_LAYOUTS = {
         ("kimi_linear_48b_a3b", 1, 1, 2, 5, dict(experts_held=8, vocab=20480)), {"heads/none": 1}),
     "sdar-solo-4k": (  # one scanned layer under the block-diffusion mask, turned by position on the tile
         ("sdar_30b_a3b", 1, 1, 2, 5, dict(experts_held=16, vocab=18992, mask_id=18991)), {"merged/kernel": 1}),
+    "ouro-solo-4k": (  # one scanned layer, traced once inside the loop over the four passes
+        ("ouro_2_6b", 1, 1, 2, 6, dict(max_len=4096)), {"merged/kernel": 1}),
 }
 
 
