@@ -34,12 +34,47 @@ next-token cross-entropy over the vocabulary (slice); no auxiliary loss. The
 model's multi-token-prediction module (``num_nextn_predict_layers`` 1) is not
 built: ``common.lm_xent_chunked`` scores one next token (ROADMAP R5 (c)).
 
-**Latent attention on the cores the repo has.** q, k and v are built at
-``[B, 20, T, 256]`` (the shared rotary key broadcast to the heads and
-concatenated once a layer) and handed to ``attention_core`` like any other
-model's: query and key head are 192 + 64 = 256, the value head is 256, so the
-flash kernels see ONE head dim. A configuration whose value head differs from
-its query/key head is refused at construction (no core here takes two).
+**Latent attention on the kernels the repo has, in the projections' own
+layout (PR 65).** Query and key head are 192 + 64 = 256, the value head is 256:
+two whole 128-lane tiles, ONE head dim. ``qkv`` makes q, k and v
+``[B, T, 20 x 256]`` by the projections' own products and ``_attention`` hands
+them to ``ops/attention.attention_merged``, whose kernels read a head as block
+``h`` of the last axis; ``W_o`` reads the result where it lies. Nothing
+``[B, H, T, D]``, ``[B, T, H, D]`` or 448 wide exists between ``W_qb`` /
+``W_kvb`` and the kernels, forward, recomputed or backward (built by head, q, k
+and v cost about 70 ms of a 530 ms step in copies, slices and concatenations of
+168 MB arrays: PERF.md, Findings of PR 42 and PR 65). What stood in the way is
+moved onto the WEIGHTS, a few MB a layer, inside the layer; the parameter
+tree, ``init``, a checkpoint and the plain reference keep the published layout:
+
+- *the rotary pairs.* A score is a sum over a head's 256 coordinates, so ONE
+  permutation of them applied to q and k alike leaves it unchanged. Each head's
+  columns of ``wq_b`` are ordered ``[rope 64 | nope 192]`` with the rope columns
+  de-interleaved, and ``wkv_a``'s last 64 the same way: "interleaved" rotary on
+  the published order IS "half" rotary with ``rotary_dim`` 64 on a head's first
+  lanes of the new one, which ``rope(layout="half")`` and
+  ``pallas_attention.rotary_merged`` do;
+- *the one rotary key a token* reaches every head through the key's product:
+  ``k = [c_norm | k_rope_turned] @ [Wk ; E]``, ``Wk`` being ``wkv_b``'s nope
+  columns a head on lanes 64..255 of that head and ``E`` the constant 0/1
+  spread (no broadcast, no concatenation, no 20-fold array);
+- *v* is ``c_norm @ wkv_b``'s value columns: the slice is taken of the weight.
+
+**Why q is turned beside the kernels and not in them.** At D = 256, T = 8,192
+the kernels hold 66,060,288 of their 67,108,864 bytes of VMEM
+(``pallas_attention.vmem_bytes``). A call that turns q on the forward kernel's
+tile adds 4,194,304 bytes of rotary tables, ``choose_blocks(turned=True)`` finds
+no blocks, and ``attention_merged`` would fall back to the by-head path in
+silence. So ``qkv`` turns q itself, by ``rotary_merged``'s one pass in the
+merged layout (``dvc_rotary``; ``dvc_rotary_back`` for its cotangent), and the
+call is given ``rotary=None``: the note reads ``layout="merged"``,
+``rotary="none"``. Off the chip, under a sequence-parallel context or where the
+shapes refuse, ``attention_merged`` itself splits the heads and calls
+``attention_core``, and q is turned by plain ``rope`` on the split heads (so too
+under a mesh of several chips, where Mosaic refuses a bare kernel GSPMD would
+partition): one ``qkv``, decided by the ``merged_in_place`` the entry uses. A
+configuration whose value head differs from its query/key head is refused at
+construction (no core here takes two).
 
 **The selection bias** and the router are ``models/moe.py``'s (shared with
 ``models/lfm2.py``: a float32 leaf ``bias`` a layer, zeros at initialisation,
@@ -57,7 +92,7 @@ Layers of one kind that follow each other are one run, stacked and scanned
 (``models/moe.run_layers``): the published model is the dense layer and a scan
 over 46 expert layers. Every layer is rematerialised by
 ``models/common.remat_layer`` as it stands (it keeps the kernel's output,
-``20 x 256`` a token, and its row statistics). Departures as in
+``20 x 256`` a token, and its row statistics; q's turn is recomputed). Departures as in
 ``models/olmoe.py``: float32 parameters and bfloat16 compute on a TPU, the
 router's product in float32 at the highest precision, rotary angles in float32.
 """
@@ -73,7 +108,8 @@ import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common, moe
 from distributedvolunteercomputing_tpu.models.common import matrix, swiglu, swiglu_init
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads, rope
+from distributedvolunteercomputing_tpu.ops.attention import (
+    attention_merged, chips_in_step, merge_heads, merged_in_place, rope, split_heads)
 from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
 LATENT = "latent_attention"
@@ -201,32 +237,75 @@ def init(rng: jax.Array, cfg: Glm4MoeLiteConfig) -> common.Params:
     }
 
 
+def _half_pairs(w: jax.Array) -> jax.Array:
+    """A rotary block's columns ``[.., rot]`` from the published interleaved
+    pairs (2i, 2i + 1) to "half" pairs (i, i + rot / 2): new ``i`` <- old
+    ``2i``, new ``i + rot / 2`` <- old ``2i + 1``."""
+    pairs = w.reshape(*w.shape[:-1], w.shape[-1] // 2, 2)
+    return jnp.swapaxes(pairs, -1, -2).reshape(w.shape)
+
+
 def qkv(p: common.Params, n: jax.Array, cfg: Glm4MoeLiteConfig):
-    """The latent's three products of the normed stream ``n`` [B, T, d]:
-    q, k, v ``[B, H, T, head_dim]``, rotary applied, the one rotary key a token
-    broadcast to every head."""
+    """The latent's three products of the normed stream ``n`` [B, T, d]: q, k
+    and v ``[B, T, H * head_dim]``, each made where ``attention_merged`` reads
+    it by its projection's own product, rotary applied. A head's lanes of q and
+    k are ``[rope (rot), "half" pairs | nope]``, a fixed permutation of the
+    published ``[nope | rope, interleaved pairs]`` that both carry (a score is a
+    sum over a head's coordinates: it does not see their order); v's are the
+    published ones. The orders, the zero lanes and the 0/1 spread are taken of
+    the WEIGHTS, by head (reshapes, slices, a pad, concatenations: a weight cut
+    by head over ``tp`` stays cut so), never of an activation:
+
+    - q = cq @ wq_b with each head's columns as ``[rope, half pairs | nope]``;
+    - k = c_norm @ Wk + k_rope_turned @ E, as the ONE product
+      ``[c_norm | k_rope_turned] @ [Wk ; E]``: ``Wk`` is ``wkv_b``'s nope
+      columns a head on lanes ``rot..`` of that head (zeros below), ``E`` the
+      constant 0/1 ``[rot, H * head_dim]`` that puts coordinate j of the ONE
+      rotary key on lane j of every head (exact: a rotary lane's sum has one
+      term that is not a zero). ``k_rope`` [B, T, rot] comes off ``wkv_a``'s
+      last columns in half pairs and is turned before it is spread;
+    - v = c_norm @ ``wkv_b``'s value columns.
+
+    q is turned here, beside the kernels (one pass in the merged layout,
+    ``pallas_attention.rotary_merged``, where the call that follows takes the
+    kernels on one chip; plain ``rope`` by head elsewhere), and the attention
+    call is given no rotary: at the published head and 8,192 tokens the kernels
+    hold 66.06 of their 67.1 MB of VMEM, the 4.19 MB of tables a turn on the
+    kernel's tile brings do not fit, and ``attention_merged`` would then take
+    the by-head path without a word (the module text)."""
     dtype = n.dtype
-    b, t, _ = n.shape
-    h, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    t = n.shape[1]
+    h, nope, rot, latent = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
+    wq_b = p["wq_b"].astype(dtype).reshape(cfg.q_lora_rank, h, nope + rot)
+    wq_b = jnp.concatenate([_half_pairs(wq_b[..., nope:]), wq_b[..., :nope]], axis=-1).reshape(cfg.q_lora_rank, -1)
+    wkv_a = p["wkv_a"].astype(dtype)
+    wkv_a = jnp.concatenate([wkv_a[:, :latent], _half_pairs(wkv_a[:, latent:])], axis=-1)
+    wkv_b = p["wkv_b"].astype(dtype).reshape(latent, h, nope + cfg.v_head_dim)
+    wk = jnp.pad(wkv_b[..., :nope], ((0, 0), (0, 0), (rot, 0))).reshape(latent, h * (rot + nope))
+    wk = jnp.concatenate([wk, jnp.tile(jnp.eye(rot, rot + nope, dtype=dtype), (1, h))], axis=0)   # [Wk ; E]
+    wv = wkv_b[..., nope:].reshape(latent, h * cfg.v_head_dim)
+
     cq = common.rmsnorm(p["q_a_norm"], n @ p["wq_a"].astype(dtype), cfg.rms_eps)
-    q = (cq @ p["wq_b"].astype(dtype)).reshape(b, t, h, nope + rot).transpose(0, 2, 1, 3)
-    ckr = n @ p["wkv_a"].astype(dtype)                                   # [B, T, latent + rot]
-    c, k_rope = ckr[..., :cfg.kv_lora_rank], ckr[..., cfg.kv_lora_rank:]
-    kv = common.rmsnorm(p["kv_a_norm"], c, cfg.rms_eps) @ p["wkv_b"].astype(dtype)
-    kv = kv.reshape(b, t, h, nope + cfg.v_head_dim).transpose(0, 2, 1, 3)
-    # the rotary coordinates are a head's LAST ``rot``: turned in place; the key's one vector turned once
-    q = jnp.concatenate(
-        [q[..., :nope], rope(q[..., nope:], base=cfg.rope_theta, layout="interleaved")], axis=-1)
-    k_rope = rope(k_rope[:, None], base=cfg.rope_theta, layout="interleaved")   # [B, 1, T, rot]
-    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (b, h, t, rot))], axis=-1)
-    return q, k, kv[..., nope:]
+    q = cq @ wq_b
+    ckr = n @ wkv_a                                                       # [B, T, latent + rot]
+    c = common.rmsnorm(p["kv_a_norm"], ckr[..., :latent], cfg.rms_eps)
+    k_rope = rope(ckr[..., latent:], base=cfg.rope_theta, layout="half")   # one key a token, [B, T, rot]
+    k = jnp.concatenate([c, k_rope], axis=-1) @ wk
+    v = c @ wv
+    if merged_in_place(q, k, v, h, h, True, None, None) and chips_in_step() == 1:
+        from distributedvolunteercomputing_tpu.ops import pallas_attention
+
+        cos, sin = pallas_attention.rotary_tables(t, rot + nope, cfg.rope_theta, rot)
+        return pallas_attention.rotary_merged(q, cos, sin, rot), k, v
+    q = rope(split_heads(q, h), base=cfg.rope_theta, layout="half", rotary_dim=rot)
+    return merge_heads(q), k, v
 
 
 def _attention(p: common.Params, x: jax.Array, cfg: Glm4MoeLiteConfig) -> jax.Array:
     n = common.rmsnorm(p["ln_mixer"], x, cfg.rms_eps)
     q, k, v = qkv(p, n, cfg)
-    a = attention_core(q, k, v, causal=True)     # 1/sqrt(head_dim): the whole 256
-    return x + merge_heads(a) @ p["wo"].astype(x.dtype)
+    a = attention_merged(q, k, v, cfg.n_heads, cfg.n_heads, causal=True)   # 1/sqrt(head_dim): the whole 256
+    return x + a @ p["wo"].astype(x.dtype)
 
 
 def _layer(p: common.Params, x: jax.Array, stats: Dict[str, jax.Array], cfg: Glm4MoeLiteConfig,

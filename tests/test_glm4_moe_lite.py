@@ -25,6 +25,7 @@ from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch
 from distributedvolunteercomputing_tpu.training import steps
 from distributedvolunteercomputing_tpu.utils import traced
 from tests import tiny_models
+from tests.test_tpu_compile import _CORE, _noted, as_on_the_chip  # noqa: F401 — the fixture is used by name
 
 TINY = tiny_models.rehearsal("glm")
 OVERRIDES = TINY["model_overrides"]
@@ -76,6 +77,56 @@ def whole_error(got, want):
 
 def one_layer(params, run, i=0):
     return jax.tree_util.tree_map(lambda a: a[i], params["blocks"][run])
+
+
+def as_published(x, cfg, rotary=True):
+    """The model's merged ``x`` [B, T, H * D] by head, [B, H, T, D], with a
+    head's columns put back where the published layout has them: from
+    ``[rope, "half" pairs | nope]`` to ``[nope | rope, neighbouring pairs]``
+    (``rotary`` False: a value, whose columns never moved)."""
+    x = attention.split_heads(x, cfg.n_heads)
+    if not rotary:
+        return x
+    rot = cfg.qk_rope_dim
+    halves = x[..., :rot].reshape(*x.shape[:-1], 2, rot // 2)
+    return jnp.concatenate([x[..., rot:], jnp.swapaxes(halves, -1, -2).reshape(*x.shape[:-1], rot)], axis=-1)
+
+
+def one_chip_mesh():
+    """A step mesh of one device: what ``parallel/train_step`` announces on one chip (without one, a process
+    of several devices keeps the kernels off the path)."""
+    from jax.sharding import Mesh
+
+    from distributedvolunteercomputing_tpu.parallel.mesh import AXES
+
+    return Mesh(np.asarray(jax.devices()[:1]).reshape((1,) * len(AXES)), AXES)
+
+
+def qkv_by_head(p, n, cfg):
+    """The model's q, k, v as the published layout has them by head."""
+    q, k, v = glm.qkv(p, n, cfg)
+    return as_published(q, cfg), as_published(k, cfg), as_published(v, cfg, rotary=False)
+
+
+def qkv_as_it_was(p, n, cfg):
+    """``models/glm4_moe_lite.qkv`` until PR 64, kept here as the yardstick of
+    the merged one: q, k, v built at ``[B, H, T, head_dim]``, a head's columns
+    ``[nope | rope]``, neighbouring pairs turned, the one rotary key broadcast
+    to the heads and concatenated."""
+    dtype = n.dtype
+    b, t, _ = n.shape
+    h, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    cq = common.rmsnorm(p["q_a_norm"], n @ p["wq_a"].astype(dtype), cfg.rms_eps)
+    q = (cq @ p["wq_b"].astype(dtype)).reshape(b, t, h, nope + rot).transpose(0, 2, 1, 3)
+    ckr = n @ p["wkv_a"].astype(dtype)
+    c, k_rope = ckr[..., :cfg.kv_lora_rank], ckr[..., cfg.kv_lora_rank:]
+    kv = common.rmsnorm(p["kv_a_norm"], c, cfg.rms_eps) @ p["wkv_b"].astype(dtype)
+    kv = kv.reshape(b, t, h, nope + cfg.v_head_dim).transpose(0, 2, 1, 3)
+    q = jnp.concatenate(
+        [q[..., :nope], attention.rope(q[..., nope:], base=cfg.rope_theta, layout="interleaved")], axis=-1)
+    k_rope = attention.rope(k_rope[:, None], base=cfg.rope_theta, layout="interleaved")
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (b, h, t, rot))], axis=-1)
+    return q, k, kv[..., nope:]
 
 
 # ``reference(grad=False, **static)``: the plain reference's loss (and gradient) as one program a set of static arguments
@@ -239,8 +290,9 @@ def test_the_rotary_key_is_one_vector_a_token_shared_by_every_head():
     cfg = bundle.config
     p = one_layer(params, 0)
     n = common.rmsnorm(p["ln_mixer"], params["wte"][batch["tokens"]][:1], cfg.rms_eps)
-    q, k, v = glm.qkv(p, n, cfg)
     nope, rot, h = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.n_heads
+    assert [a.shape for a in glm.qkv(p, n, cfg)] == [(1, cfg.max_len, h * (nope + rot))] * 3   # as the kernels read them
+    q, k, v = qkv_by_head(p, n, cfg)
     assert q.shape == k.shape == v.shape == (1, h, cfg.max_len, nope + rot)
     shared = np.asarray(k[..., nope:])
     assert np.all(shared == shared[:, :1]) and np.abs(shared).max() > 0        # one key, every head's
@@ -248,7 +300,7 @@ def test_the_rotary_key_is_one_vector_a_token_shared_by_every_head():
     assert not np.allclose(np.asarray(k[:, 0, :, :nope]), np.asarray(k[:, 1, :, :nope]))
 
     def scores(p):
-        q, k, _ = glm.qkv(p, n, cfg)
+        q, k, _ = qkv_by_head(p, n, cfg)
         return np.asarray(jnp.einsum("bhqd,bhkd->bhqk", q, k))
 
     base = scores(p)
@@ -260,7 +312,7 @@ def test_the_rotary_key_is_one_vector_a_token_shared_by_every_head():
     assert moved == [False, True, False, False]
     # position lives in the rotary coordinates alone
     rolled = jnp.roll(n, 5, axis=1)
-    q2, k2, v2 = glm.qkv(p, rolled, cfg)
+    q2, k2, v2 = qkv_by_head(p, rolled, cfg)
     np.testing.assert_allclose(np.asarray(jnp.roll(k[..., :nope], 5, axis=2)), np.asarray(k2[..., :nope]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(jnp.roll(v, 5, axis=2)), np.asarray(v2), atol=1e-5)
     assert np.abs(np.asarray(jnp.roll(k[..., nope:], 5, axis=2) - k2[..., nope:])).max() > 1e-3
@@ -287,6 +339,91 @@ def test_latent_attention_goes_through_the_core_at_one_head_dim():
         want = x + ref._latent_attention(p, n, ref.hyper(TINY), None)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
     assert 256 in attention._AUTO_FLASH_HEAD_DIMS  # the published head takes the kernel on a chip
+
+
+# -- q, k and v where the kernels read them (PR 65) ------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 2 ** -7)], ids=["float32", "bfloat16"])
+def test_merged_qkv_split_by_head_is_what_the_by_head_qkv_built(dtype, tol):
+    """``qkv`` makes q, k and v ``[B, T, H * D]`` by the projections' own
+    products (the column orders, the zero lanes and the 0/1 spread taken of the
+    weights). Split by head and with a head's columns put back, they are what
+    the model built at ``[B, H, T, D]`` until PR 64 (``qkv_as_it_was``: the
+    published order, neighbouring pairs, the shared key broadcast and
+    concatenated), to the compute dtype's rounding: each value is the same
+    products' sum, a pad and a spread add exact zeros, and the turn is the same
+    float32 arithmetic rounded once."""
+    bundle, params, batch = seeded()
+    cfg = bundle.config
+    for run in (0, 1):
+        p = one_layer(params, run)
+        n = common.rmsnorm(p["ln_mixer"], params["wte"][batch["tokens"]], cfg.rms_eps).astype(dtype)
+        got, want = qkv_by_head(p, n, cfg), qkv_as_it_was(p, n, cfg)
+        for name, a, b in zip("qkv", got, want):
+            assert a.shape == b.shape == (2, cfg.n_heads, cfg.max_len, cfg.head_dim) and a.dtype == b.dtype == dtype
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            assert np.abs(b).max() > 1.0, name   # seeded weights: values of some size
+            np.testing.assert_allclose(a, b, rtol=tol, atol=tol, err_msg=name)
+    # the shared key reaches every head through the product: identical lanes, not merely close ones
+    k = np.asarray(attention.split_heads(glm.qkv(p, n, cfg)[1], cfg.n_heads))
+    assert np.all(k[..., :cfg.qk_rope_dim] == k[:, :1, :, :cfg.qk_rope_dim])
+
+
+def test_the_kernel_path_at_a_head_of_256_matches_the_reference_in_the_published_layout():
+    """The path the chip takes, interpreted: two heads of 192 + 64 = 256 (whole
+    lane tiles), the flash route forced, a step mesh of one chip. q is turned
+    by ``rotary_merged``'s one pass in the merged layout, the call is noted
+    ``merged`` with no rotary of its own, and loss and the gradients of
+    ``wq_b``, ``wkv_a``, ``wkv_b`` and ``wo``, leaves that keep the PUBLISHED
+    column order, match the plain reference (which turns neighbouring pairs of
+    a head's last 64 coordinates) at the float32 test's tolerance."""
+    sizes = dict(n_heads=2, qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256)
+    bundle, params, batch = seeded(bias=0.05, **sizes)
+    cfg = bundle.config
+    hp = dict(HP, heads=2, nope=192, rot=64, v_dim=256)
+    attention.set_attention_impl("flash")
+    try:
+        with attention.step_mesh(one_chip_mesh()), _noted("attention_core", *_CORE) as seen:
+            lp, gp = jax.jit(jax.value_and_grad(lambda q: glm.loss_and_routes(q, batch, cfg)[0]))(params)
+    finally:
+        attention.set_attention_impl("auto")
+    # the dense layer and the scanned expert layer
+    assert seen == [("flash", cfg.max_len, 256, "none", 2, "merged", "none")] * 2, seen
+    lr, gr = jax.jit(jax.value_and_grad(lambda q: ref.loss(q, batch["tokens"], batch["targets"], hp)))(params)
+    assert float(lp) == pytest.approx(float(lr), rel=1e-5)
+    errors = leaf_errors(gp, gr)
+    latent = {k: v for k, v in errors.items() if any(k.endswith(f"['{w}']") for w in ("wq_b", "wkv_a", "wkv_b", "wo"))}
+    assert len(latent) == 8 and max(latent.values()) < 1e-4, latent
+    assert max(v for k, v in errors.items() if not k.endswith("['bias']")) < 1e-4
+
+
+def test_a_head_of_256_at_8192_fits_the_kernels_only_without_a_turn_inside_them(as_on_the_chip):
+    """Why ``qkv`` turns q BESIDE the kernels and ``_attention`` hands
+    ``attention_merged`` no rotary (shapes only, nothing compiled): at D = 256,
+    T = 8,192 the kernels hold 66,060,288 of their 67,108,864 bytes of VMEM; a
+    call that turns q on the forward kernel's tile adds 2 x 2 x 1,024 x 256 x 4
+    = 4,194,304 bytes of tables, ``choose_blocks`` finds no blocks, and
+    ``attention_merged`` falls back to the by-head path without a word: every
+    copy this layout exists to avoid would be back."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention
+
+    cfg = glm.Glm4MoeLiteConfig()
+    h, d, t = cfg.n_heads, cfg.head_dim, cfg.max_len
+    x = jax.ShapeDtypeStruct((2, t, h * d), jnp.bfloat16)
+    turned = attention.Rotary(base=cfg.rope_theta, layout="half", rotary_dim=cfg.qk_rope_dim)
+    assert pallas_attention.vmem_bytes(t, t, d, x.dtype, 1024, 1024) == 66_060_288
+    assert pallas_attention.vmem_bytes(t, t, d, x.dtype, 1024, 1024, turned=True) == 66_060_288 + 4_194_304
+    assert pallas_attention.VMEM_BUDGET_BYTES == 67_108_864
+    one_chip = one_chip_mesh()
+    with attention.step_mesh(one_chip):
+        assert attention.merged_in_place(x, x, x, h, h, True, None, None)
+        assert not attention.merged_in_place(x, x, x, h, h, True, None, turned)
+        assert attention.chips_in_step() == 1
+    # half the sequence leaves room for the tables: the trap is this shape's, not the entry's
+    half = jax.ShapeDtypeStruct((2, t // 2, h * d), jnp.bfloat16)
+    with attention.step_mesh(one_chip):
+        assert attention.merged_in_place(half, half, half, h, h, True, None, turned)
 
 
 # -- the selection bias (the router itself: tests/test_expert_families.py) -----------------------------------------------------

@@ -719,7 +719,8 @@ _CELL_LAYOUTS = {
             layer_types="conv,full_attention,conv,conv,conv", dense_layers=1, experts_held=8, vocab=8192)),
         {"heads/none": 1}),  # a head of 64: two heads a lane tile
     "glm47-flash-solo-8k": (
-        ("glm4_7_flash", 1, 1, 2, 5, dict(experts_held=8, vocab=19360)), {"heads/none": 2}),
+        # the dense layer and one scanned expert layer: q turned BESIDE the kernels (the tables do not fit their VMEM at D = 256)
+        ("glm4_7_flash", 1, 1, 2, 5, dict(experts_held=8, vocab=19360)), {"merged/none": 2}),
     "nemotron3-nano-solo-8k": (
         ("nemotron3_nano_30b_a3b", 1, 1, 2, 7, dict(experts_held=8, vocab=16384)), {"heads/none": 1}),
     "kimi-linear-solo-8k": (
@@ -737,10 +738,12 @@ def test_which_cells_hand_the_kernels_the_projections_own_arrays(v5e, as_on_the_
     (no compile): Laguna's, SmallThinker's and OLMoE's attention calls all take
     the flash kernels on the merged ``[B, T, H * D]`` layout, a rotary layer's q
     turned on the kernel's tile (``merged/kernel``) and a layer without
-    position encoding turning nothing (``merged/none``); every other cell's
-    calls are handed ``[B, H, T, D]`` as before (a head of 64, a latent key
-    concatenated by head, a model that calls ``attention_core`` itself): the
-    shapes decide, and ``swarm.attention_core`` says which (PR 59)."""
+    position encoding turning nothing (``merged/none``: GLM's latent layers too
+    since PR 65, which turn q and the one shared key themselves before the
+    call); every other cell's calls are handed ``[B, H, T, D]`` as before (a
+    head of 64, Kimi's latent key of 192 concatenated by head, a model that
+    calls ``attention_core`` itself): the shapes decide, and
+    ``swarm.attention_core`` says which (PR 59)."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
 

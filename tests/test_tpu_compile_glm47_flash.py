@@ -8,6 +8,8 @@ host memory a compile) instead of one worker all six, and, being the files
 with the fewest tests, after the files of many short tests.
 """
 
+import re
+
 import jax.numpy as jnp
 import pytest
 
@@ -37,17 +39,23 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     2 x 8,192 tokens). A head of 256 at T=8,192 is the edge of what the
     whole-head-resident kernels hold: 1,024 x 1,024 blocks are 66.06e6 of the
     67.1e6-byte budget (the same resident bytes as D=128 at T=16,384), and the
-    next doubling of either does not fit. The model builds q, k and v at
-    ``[2, 20, 8192, 256]`` (the one rotary key broadcast to the 20 heads) and
-    both traced layer shapes, the dense layer and ONE scanned expert layer for
-    the four, take the kernel forward and backward only (``remat_layer`` kept
-    the output and row statistics). The share's grouped matmuls see the
+    next doubling of either does not fit, and neither do the rotary tables of a
+    call that turns q on the kernel's tile. Since PR 65 the model makes q, k and
+    v ``[2, 8192, 20 x 256]`` by the projections' own products (the column
+    orders, the key's zero lanes and the shared rotary key's 0/1 spread taken
+    of the weights), turns q by one pass in that layout BESIDE the kernels
+    (``dvc_rotary``, its cotangent ``dvc_rotary_back``) and calls
+    ``attention_merged`` with no rotary: both traced layer shapes, the dense
+    layer and ONE scanned expert layer for the four, are noted ``merged``, the
+    kernels read ``bf16[2,8192,5120]`` where it lies, forward and backward only
+    (``remat_layer`` kept the output and row statistics), and NOTHING by head
+    (``[2,20,8192,..]``, ``[2,8192,20,..]``, the ``448``-wide key-value array)
+    is left anywhere in the compiled step, loops' bodies included. The share's grouped matmuls see the
     dispatch's default chunk of 24,576 rows (three even shares of 8,192: the
     model's own reading refuted the levelled quarter), seven a traced expert
-    layer. Arguments and temporaries are
-    16.18e9 (7.096 + 9.080): OVER the 15.0e9 line of the other share cells'
-    tests, and what the chip still loads and runs (PERF.md, Findings of PR 42);
-    the line here says that nothing more fits."""
+    layer. Arguments and temporaries are 15.55e9 (7.096 + 8.451; 16.18e9 until
+    PR 64, which the chip still loaded and ran: PERF.md, Findings of PR 42 and
+    PR 65): still OVER the 15.0e9 line of the other share cells' tests."""
     from distributedvolunteercomputing_tpu.models import glm4_moe_lite
     from distributedvolunteercomputing_tpu.ops import attention, moe_dispatch, pallas_attention
 
@@ -64,7 +72,7 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
     with _noted("attention_core", *_CORE) as seen, _noted("remat_kept", *_KEPT) as kept:
         compiled = _lowered_step(v5e, "glm4_7_flash", 1, 1, 2, n_layers=5, experts_held=8, vocab=19360).compile()
-    assert seen == [("flash", t, d, "none", 20, "heads", "none")] * 2, seen          # one traced dense layer, one traced scan body
+    assert seen == [("flash", t, d, "none", 20, "merged", "none")] * 2, seen         # one traced dense layer, one traced scan body
     # the output at 20 x 256 a token and the f32 row statistics: 168.8 MB a layer, 845.4 MB a step
     assert kept == [(1, 169_082_880), (4, 4 * 169_082_880)], kept
     text = compiled.as_text()
@@ -73,7 +81,17 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     names = _kernel_names(calls)
     flash = sorted(n.split(".")[0] for n in names if n.startswith("dvc_flash"))
     assert flash == ["dvc_flash_bwd"] * 2 + ["dvc_flash_fwd"] * 2, flash
-    assert all("bf16[2,20,8192,256]" in ln for ln in calls if "dvc_flash_" in ln)
+    assert all("bf16[2,8192,5120]" in ln and "[2,20,8192" not in ln for ln in calls if "dvc_flash_" in ln)
+    # q alone is turned beside the kernels (the key's one vector of 64 by XLA before its spread): forward and
+    # recomputed forward, and the cotangent's way back, a traced layer; delta in the merged layout
+    turns = sorted(n.split(".")[0] for n in names if n.startswith("dvc_rotary"))
+    assert turns == ["dvc_rotary"] * 4 + ["dvc_rotary_back"] * 2, turns
+    assert sum(n.startswith("dvc_attn_delta") for n in names) == 2
+    by_head = sorted(set(re.findall(r"\w+\[2,(?:20,8192|8192,20),\d+\]", text)))
+    assert not by_head, by_head                          # a result or an operand, in the entry computation or any loop's body
+    assert "f32[2,8192,5120]" not in text                # nor a float32 copy of a merged array
+    fwd_blocks = pallas_attention.choose_blocks(t, t, d, jnp.bfloat16, turned=False)
+    assert fwd_blocks == (1024, 1024) and pallas_attention.choose_blocks(t, t, d, jnp.bfloat16, turned=True) is None
     assert moe_dispatch.share_rows_bound(2 * t, 4, 8, 64, moe_dispatch.SHARE_ROWS_SLACK_LEVELLED) == 10240
     rows = moe_dispatch.share_rows_bound(2 * t, 4, 8, 64, glm4_moe_lite.SHARE_ROWS_SLACK)
     assert rows == 24576  # three even shares of 8,192: forty-eight megablox row tiles
@@ -82,7 +100,8 @@ def test_glm47_flash_step_runs_latent_attention_on_the_flash_kernels_at_a_head_o
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(7.0957e9, rel=1e-3)  # float32 parameters and two Adam moments
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    assert total < 16.25e9, total                        # 16.176e9: the chip takes about 16.9e9 and ran it
-    # 9,079,866,368; no more than before the head made its gradients in its loss's loop (9,079,898,624 at PR 60;
-    # 9.219e9 at the levelled chunk): its residuals, dx and the float32 dhead, are the buffers the backward held
-    assert mem.temp_size_in_bytes <= 9_079_898_624, mem.temp_size_in_bytes
+    # 15.548e9 since PR 65 (16.176e9 with q, k and v built by head: "nothing more fits", and the chip, which takes
+    # about 16.9e9, ran it): the by-head copies and the 448-wide key-value array are what left
+    assert total < 15.6e9 < 16.18e9, total
+    # 8,452,265,984 (9,079,866,368 until PR 64; 9.219e9 at the levelled chunk)
+    assert mem.temp_size_in_bytes < 8.46e9, mem.temp_size_in_bytes
