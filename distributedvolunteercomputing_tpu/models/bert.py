@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 MASK_ID = 103  # [MASK] in the BERT-base vocab
 
@@ -57,8 +56,7 @@ def init(rng: jax.Array, cfg: BertConfig) -> common.Params:
 
 
 def _block(p: common.Params, x: jax.Array, cfg: BertConfig) -> jax.Array:
-    q, k, v = common.qkv_heads(p["qkv"], x, cfg.n_heads)
-    attn = merge_heads(attention_core(q, k, v))
+    attn = common.fused_qkv_attention(p["qkv"], x, cfg.n_heads)
     x = common.layernorm(p["ln1"], x + common.dense(p["attn_out"], attn))
     h = common.dense(p["mlp_out"], jax.nn.gelu(common.dense(p["mlp_in"], x)))
     return common.layernorm(p["ln2"], x + h)

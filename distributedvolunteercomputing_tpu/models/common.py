@@ -98,31 +98,43 @@ def dense(p: Params, x: jax.Array, dtype: Optional[jnp.dtype] = None) -> jax.Arr
     return y + p["b"].astype(dtype)
 
 
-def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """q, k, v as [B, H, T, hd] from a fused ``{"w": [d, 3d], "b": [3d]}``
-    leaf (columns q|k|v, each head-major) and the normed input [B, T, d].
+def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[str, Tuple[jax.Array, jax.Array, jax.Array]]:
+    """The layout the step's mesh calls for, and q, k, v in it, from a qkv
+    leaf ``{"w": [d, 3d], "b": [3d]}`` (columns q|k|v, each head-major) and the
+    normed input [B, T, d]:
 
-    Under a traced step whose mesh divides the heads over ``tp``
-    (``attention_ops.heads_tp``) the projection runs BY HEAD: the weight is
-    viewed as [d, 3, H, hd] and laid out over ``tp`` on H, so each chip
-    multiplies by the q, k and v columns of its own heads and the result is
-    born in the layout the per-shard kernel and the row-parallel ``attn_out``
-    want. The stored leaf is sharded on 3d in contiguous parts (chip 0 of a
-    pair holds all of q and half of k), so as one [d, 3d] product half of q
-    and of v, activations, cross the link in every pass of every layer; by
-    head only the weight's shards do. Elsewhere (no step mesh, ``tp`` 1 or
-    manual, heads that ``tp`` does not divide) it is one [d, 3d] product,
-    split, and ``split_heads``."""
+    ``"merged"``, [B, T, d] each, a head a run of ``hd`` lanes as its product
+    leaves it: three products off the weight's three column ranges, so that no
+    [B, T, 3d] result is cut in three and no array by head is written on the
+    way to the kernels (``attention_ops.attention_merged`` reads this layout in
+    place). That is the form with no step mesh, ``tp`` 1 or manual, or heads
+    that ``tp`` does not divide.
+
+    ``"by_head"``, [B, H, T, hd], under a traced step whose mesh divides the
+    heads over ``tp`` (``attention_ops.heads_tp``): the weight is viewed as
+    [d, 3, H, hd] and laid out over ``tp`` on H, so each chip multiplies by the
+    q, k and v columns of its own heads and the result is born in the layout
+    the per-shard kernel and the row-parallel ``attn_out`` want. The stored
+    leaf is sharded on 3d in contiguous parts (chip 0 of a pair holds all of q
+    and half of k), so as one [d, 3d] product half of q and of v, activations,
+    cross the link in every pass of every layer; by head only the weight's
+    shards do. (The merged form over ``tp``, a column-parallel product each
+    from the same weight view, read +4.1% in ``large-solo-4chip`` with one
+    stream all-reduce fewer hidden behind the other stream's work: PERF.md,
+    Findings of PR 66. Not taken until the pair is back, so no model reaches
+    the kernels' merged call of a head of 64 PER SHARD of ``tp``
+    (``merged_in_place`` on ``heads // tp``): tests only,
+    ``test_a_head_of_64_per_shard_under_a_mesh``.)"""
     tp = attention_ops.heads_tp()
-    by_head = tp > 1 and n_heads % tp == 0
-    # every TRACED fused qkv projection (swarm.qkv_projection, beside swarm.attention_core)
-    traced.note("qkv_projection", layout="by_head" if by_head else "fused", tp=tp)
-    if not by_head:
-        q, k, v = jnp.split(dense(p, x), 3, axis=-1)
-        return tuple(attention_ops.split_heads(a, n_heads) for a in (q, k, v))
+    layout = "by_head" if tp > 1 and n_heads % tp == 0 else "merged"
+    # every TRACED qkv projection off one leaf (swarm.qkv_projection, beside swarm.attention_core)
+    traced.note("qkv_projection", layout=layout, tp=tp)
     dtype = compute_dtype()
     d = x.shape[-1]
-    hd = p["b"].shape[-1] // (3 * n_heads)
+    if layout == "merged":
+        w, b, x = p["w"].astype(dtype), p["b"].astype(dtype), x.astype(dtype)
+        return layout, tuple(jnp.dot(x, w[:, s * d:(s + 1) * d]) + b[s * d:(s + 1) * d] for s in range(3))
+    hd = d // n_heads
     w = attention_ops.constrain_in_step(
         p["w"].astype(dtype).reshape(d, 3, n_heads, hd), P(None, None, "tp", None)
     )
@@ -132,8 +144,21 @@ def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[jax.Array, jax.Arr
     x = x.astype(dtype)
     # A product each, straight to [B, H, T, hd]: one einsum to [3, B, H, T, hd]
     # costs a copy of each slice it is cut into (experiments/qkv_projection_sweep.py).
-    q, k, v = (jnp.einsum("btd,dhe->bhte", x, w[:, s]) + b[s] for s in range(3))
-    return q, k, v
+    return layout, tuple(jnp.einsum("btd,dhe->bhte", x, w[:, s]) + b[s] for s in range(3))
+
+
+def fused_qkv_attention(p: Params, x: jax.Array, n_heads: int, causal: bool = False) -> jax.Array:
+    """Self-attention of the normed input [B, T, d] through a fused qkv leaf,
+    [B, T, d] as the output projection reads it. q, k and v where their
+    products leave them go to ``attention_ops.attention_merged``, whose kernels
+    read and write that layout wherever the shapes allow (and which is
+    ``split_heads`` + ``attention_core`` + ``merge_heads`` wherever not); born
+    by head (``qkv_heads``: the heads divided over ``tp``), to
+    ``attention_core``."""
+    layout, (q, k, v) = qkv_heads(p, x, n_heads)
+    if layout == "by_head":
+        return attention_ops.merge_heads(attention_ops.attention_core(q, k, v, causal=causal))
+    return attention_ops.attention_merged(q, k, v, n_heads, n_heads, causal=causal)
 
 
 def matrix(rng: jax.Array, shape: Tuple[int, ...], scale: float = 0.02) -> jax.Array:

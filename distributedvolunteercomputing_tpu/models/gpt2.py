@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, keep_tp_reduced, merge_heads
+from distributedvolunteercomputing_tpu.ops.attention import keep_tp_reduced
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,8 +84,7 @@ def _block(p: common.Params, x: jax.Array, cfg: GPT2Config) -> jax.Array:
     # (a profiler trace otherwise shows anonymous ``fusion.N``).
     with jax.named_scope("attention"):
         h = common.layernorm(p["ln1"], x)
-        q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
-        attn = merge_heads(attention_core(q, k, v, causal=True))
+        attn = common.fused_qkv_attention(p["qkv"], h, cfg.n_heads, causal=True)  # [B, T, d] in the products' own layout
         x = x + keep_tp_reduced(common.dense(p["attn_out"], attn))
     with jax.named_scope("mlp"):
         h = common.layernorm(p["ln2"], x)

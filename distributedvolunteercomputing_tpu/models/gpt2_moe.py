@@ -33,7 +33,6 @@ import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
 from distributedvolunteercomputing_tpu.models.gpt2 import GPT2Config
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,8 +155,7 @@ def init(rng: jax.Array, cfg: GPT2MoEConfig) -> common.Params:
 def _block(p: common.Params, x_aux, cfg: GPT2MoEConfig):
     x, aux = x_aux
     h = common.layernorm(p["ln1"], x)
-    q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
-    attn = merge_heads(attention_core(q, k, v, causal=True))
+    attn = common.fused_qkv_attention(p["qkv"], h, cfg.n_heads, causal=True)
     x = x + common.dense(p["attn_out"], attn)
     h = common.layernorm(p["ln2"], x)
     y, layer_aux = moe_ffn(p["moe"], h, cfg)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 
 from distributedvolunteercomputing_tpu.models import common
-from distributedvolunteercomputing_tpu.ops.attention import attention_core, merge_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,8 +88,7 @@ def _patchify(x: jax.Array, cfg: ViTConfig) -> jax.Array:
 def _block(p: common.Params, x: jax.Array, cfg: ViTConfig) -> jax.Array:
     # Pre-LN (ViT standard): residuals stay un-normalized.
     h = common.layernorm(p["ln1"], x)
-    q, k, v = common.qkv_heads(p["qkv"], h, cfg.n_heads)
-    x = x + common.dense(p["attn_out"], merge_heads(attention_core(q, k, v)))
+    x = x + common.dense(p["attn_out"], common.fused_qkv_attention(p["qkv"], h, cfg.n_heads))
     h = common.layernorm(p["ln2"], x)
     return x + common.dense(p["mlp_out"], jax.nn.gelu(common.dense(p["mlp_in"], h)))
 

@@ -89,7 +89,8 @@ _AUTO_FLASH_HEAD_DIMS = (64, 128, 192, 256)
 # and rotary: a volunteer counts them (swarm.attention_core), so its summary
 # says how many of the step's attention calls took the fused core, how many of
 # those were handed the projections' own [B, T, H * D] arrays (``layout``
-# "merged"; "heads" is [B, H, T, D]) and where the call's rotary turn ran
+# "merged": a head of whole tiles, or of 64 as half of one; "heads" is
+# [B, H, T, D]) and where the call's rotary turn ran
 # (``rotary``): "kernel" where the forward kernel turns each q block on the
 # tile (k, and the backward's resident q and its dq, by one merged-layout pass
 # each beside the kernels), "outside" where ``attention_merged`` ran ``rope``
@@ -354,14 +355,18 @@ def attention_merged(
     [B, T, H * Dv]: ``merge_heads(attention_core(rope(split_heads(q)),
     rope(split_heads(k)), split_heads(v)))``, which is what runs wherever the
     shapes do not allow better. Where the call would take the flash kernel
-    (``_route_to_flash``), both head widths are whole 128-lane tiles and the
-    rotary layout is "half" (or there is none), the kernels read q, k and v and
-    write the output where they lie, a head being a block of the last axis, and
-    the rotary pairs are turned by a roll along a head's own lanes (q on the
-    forward kernel's tile; k, and the backward's q and dq, by one pass each in
-    the same layout): no transpose, no array D/2 wide and no float32 copy of a
-    head-shaped array between a projection and its kernel. The shapes decide;
-    nothing else does."""
+    (``_route_to_flash``) and ``merged_in_place`` says so, the kernels read q,
+    k and v and write the output where they lie. That is: both head widths
+    whole 128-lane tiles and the rotary layout "half" (or none), a head being a
+    block of the last axis and the rotary pairs turned by a roll along a head's
+    own lanes (q on the forward kernel's tile; k, and the backward's q and dq,
+    by one pass each in the same layout); or a head that is a whole part of a
+    tile (GPT-2's 64: two heads a 128-lane block, each run on its own lanes
+    inside a grid step), q, k and v alike, every key/value head its own, as
+    plain causal or full attention with no rotary, window or block-diffusion
+    mask, a chip's heads whole blocks. Either way no transpose, no array D/2
+    wide and no float32 copy of a head-shaped array stands between a projection
+    and its kernel. The shapes (and the step's mesh) decide; nothing else does."""
     global _observed_rotary
     b, t, _ = q.shape
     d = q.shape[-1] // heads
@@ -389,13 +394,20 @@ def _by_head(q, k, heads: int, kv_heads: int):
 def merged_in_place(q, k, v, heads: int, kv_heads: int, causal: bool, window: Optional[int],
                     rotary: Optional[Rotary], block_diffusion: Optional[int] = None) -> bool:
     """Whether ``attention_merged`` hands this call (arrays, or their shapes
-    and dtypes) to the kernels on the projections' own layout."""
-    from distributedvolunteercomputing_tpu.ops.pallas_attention import LANES
+    and dtypes) to the kernels on the projections' own layout: a head of whole
+    128-lane tiles with any mask and a "half" rotary, or a head that is a whole
+    part of a tile (64: two a block) as plain causal or full attention, its
+    blocks whole on every chip's share of the heads."""
+    from distributedvolunteercomputing_tpu.ops.pallas_attention import heads_a_block
 
     d, dv = q.shape[-1] // heads, v.shape[-1] // kv_heads
     qs, ks = _by_head(q, k, heads, kv_heads)
+    axes = _shard_axes(qs, ks)
+    tp = 1 if axes is None or axes[1] is None else _mesh_ctx.shape["tp"]
+    per = heads_a_block(d, dv, heads // tp, kv_heads // tp)  # of one chip's heads
     return (
-        _seq_ctx is None and d % LANES == 0 and dv % LANES == 0
+        _seq_ctx is None and per > 0
+        and (per == 1 or (rotary is None and window is None and block_diffusion is None))
         and (rotary is None or rotary.layout == "half")
         and (window is None or (causal and window >= 1))
         and _route_to_flash(qs, ks, causal, None, window, turned=rotary is not None,

@@ -66,6 +66,30 @@ Design (speeds: PERF.md, Findings of PR 27, measured on a TPU v5e):
   each head along its own lanes (``dvc_attn_delta``; XLA's reduce would first
   re-tile both arrays by head), a group's dk and dv written as ``[B, group, T,
   Hkv * D]`` so that the group's sum is over a leading axis.
+- **A head narrower than a tile is part of a block** (``heads_a_block``, PR 66):
+  where 128 is whole heads (two of 64), the value head is as wide, no
+  key/value head is shared and the (shard's) heads are whole blocks, a block
+  ``[rows, 128]`` of ``[B, T, H * D]`` holds its heads side by side and the
+  grid is ``(batch, H / 2, blocks)``. A grid step runs each head in turn: the
+  SAME one-head body, on the same scratch, with its own running maximum,
+  denominator and accumulators, and nothing is sliced: the other heads' lanes
+  are zeroed in q (forward) or in k and v (backward), so a product that
+  contracts the 128 lanes takes this head's alone (a contraction 128 deep
+  costs the MXU what one of 64 padded to 128 costs by head), a product that
+  keeps them (o, dk, dv) is right on this head's lanes and is chosen by lane
+  when the block is written (``ops/lanes.of_head`` / ``by_head``, shared with
+  the SSD kernels since PR 49), and dq falls on its own lanes of the one
+  accumulator.
+  o, dq, dk and dv leave as lane-dense blocks; lse and delta stay rows by head,
+  ``[B, H, nq, 1, bq]``, a block's heads a grid step. A row of a by-head
+  ``[B, H, T, 64]`` array takes 128 lanes in HBM and in every DMA; here none
+  does. Plain causal or full attention only: grouped heads, the rotary turn, a
+  window or the block-diffusion mask at D < 128 keep the by-head entry
+  (``ops/attention.merged_in_place``). With one head a block the kernels are
+  traced to the text they were. Static lane slices of every load and store
+  (``_delta_kernel``'s way) compile and agree too, and are slower on the chip
+  (``experiments/attention_head64_sweep.py`` carries that form and times both;
+  PERF.md, Findings of PR 66).
 - **The rotary turn by a lane roll** ("half" pairs, ``_turn``): ``x * cos +
   roll(x) * sin`` in float32 on a ``[rows, D]`` tile, tables ``[T, D]`` made
   once a call (``rotary_tables``: ones and zeros on the lanes a partial rotary
@@ -100,6 +124,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distributedvolunteercomputing_tpu.ops.lanes import by_head, lanes_of, of_head
 from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
 NEG_INF = -1e30
@@ -226,6 +251,18 @@ def _head_spec(merged: bool, rows: int, width: int, where) -> pl.BlockSpec:
 
         return pl.BlockSpec((None, rows, width), index)
     return pl.BlockSpec((None, None, rows, width), lambda i, j, g: (*where(i, j, g), 0))
+
+
+def heads_a_block(d: int, dv: int, h: int, h_kv: int) -> int:
+    """How many heads one block of the merged layout holds, or 0 where the
+    kernels cannot read ``[B, T, H * D]`` in place: one where a head is whole
+    128-lane tiles; where it is narrower, as many as fill a tile (two of 64),
+    if a tile is whole heads, the value head is as wide, no key/value head is
+    shared and the (shard's) heads are whole blocks."""
+    if d % LANES == 0 and dv % LANES == 0:
+        return 1
+    per = LANES // d
+    return per if LANES % d == 0 and dv == d and h == h_kv and h % per == 0 else 0
 
 
 def _dot(a: jax.Array, b: jax.Array, dims) -> jax.Array:
@@ -440,9 +477,10 @@ def _turn_kernel(x_ref, cos_ref, sin_ref, o_ref, *, d, rotary_dim, back):
 _TURN_HEADS, _TURN_ROWS = 8, 512
 
 
-def _heads_a_block(h: int) -> int:
-    """The most heads, up to ``_TURN_HEADS``, that divide ``h``."""
-    return max(n for n in range(1, _TURN_HEADS + 1) if h % n == 0)
+def _heads_a_block(h: int, width: int = LANES) -> int:
+    """The most heads of ``width`` lanes, up to ``_TURN_HEADS``, that divide
+    ``h`` and fill whole 128-lane tiles."""
+    return max(n for n in range(1, _TURN_HEADS + 1) if h % n == 0 and n * width % LANES == 0)
 
 
 def _turn_merged(x: jax.Array, cos: jax.Array, sin: jax.Array, rotary_dim: int, back: bool,
@@ -515,75 +553,96 @@ def _kernel_name(which: str, window: Optional[int], block_diffusion: Optional[in
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *rest,
     scale, causal, block_q, block_k, tk_valid, n_k, window=None, rotary_dim=None,
-    block_diffusion=None,
+    block_diffusion=None, head_dim=None,
 ):
     if rotary_dim is not None:  # this q block's rows of the rotary tables, [bq, D] float32
         cos_ref, sin_ref, *rest = rest
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     iq = pl.program_id(2)
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-    q = q_ref[...]  # [bq, D], input dtype
-    if rotary_dim is not None:
-        # A q block is taken up once a pass, so its pairs are turned here, on the
-        # tile, and rounded where ``ops/attention.rope`` rounds them.
-        q = _turn(q.astype(jnp.float32), cos_ref[...], sin_ref[...], rotary_dim).astype(q.dtype)
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
         window=window, block_diffusion=block_diffusion,
     )
 
-    def step(jk, masked: bool):
-        start = pl.multiple_of(jk * block_k, block_k)
-        kblk = k_ref[pl.ds(start, block_k), :]
-        vblk = v_ref[pl.ds(start, block_k), :]
-        s = _dot(q, kblk, _NT) * scale  # f32 [bq, bk]
-        if masked:
-            col = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = _masked(s, iq * block_q, col, 0)
-        m_prev = m_scr[...]  # [bq, LANES], every lane the same
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new[:, 0:1])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_scr[...] = m_new
-        acc_scr[...] = acc_scr[...] * corr[:, 0:1] + _dot(p.astype(vblk.dtype), vblk, _NN)
+    def head(take_q):
+        """One head's pass over its key blocks: its rows of o (float32) and a
+        thunk for its statistics row. ``take_q()``: the q block [bq, width] in
+        the input dtype, taken after the scratch is reset."""
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        q = take_q()
+        if rotary_dim is not None:
+            # A q block is taken up once a pass, so its pairs are turned here, on the
+            # tile, and rounded where ``ops/attention.rope`` rounds them.
+            q = _turn(q.astype(jnp.float32), cos_ref[...], sin_ref[...], rotary_dim).astype(q.dtype)
 
-    # Blocks wholly visible (and wholly inside the valid keys) need no mask;
-    # the ones the diagonal crosses, or a ragged last one, do; the rest of a
-    # causal row's blocks are never visited.
-    n_full = tk_valid // block_k
-    n_end = n_k
-    if causal:
-        n_full = jnp.minimum(n_full, (iq * block_q) // block_k)
-        n_end = jnp.minimum(n_k, ((iq + 1) * block_q - 1) // block_k + 1)
-    if block_diffusion is not None:
-        a, b, c, d = _bd_fwd_bounds(iq, block_q, block_k, n_k, tk_valid // 2, block_diffusion)
-        _loop(0, a, lambda jk: step(jk, False))
-        _loop(a, b, lambda jk: step(jk, True))
-        _loop(c, d, lambda jk: step(jk, True))
-    elif window is None:
-        _loop(0, n_full, lambda jk: step(jk, False))
-        _loop(n_full, n_end, lambda jk: step(jk, True))
-    else:
-        # The band's far edge: the first block that holds a key some row of
-        # this q-block sees, then the first block every row sees all of.
-        row0, row1 = iq * block_q, (iq + 1) * block_q - 1
-        lo = jnp.maximum(row0 - window + 1, 0) // block_k
-        inside = jnp.maximum(row1 - window + block_k, 0) // block_k  # ceil((row1 - w + 1) / bk)
-        a = jnp.clip(inside, lo, n_end)
-        b = jnp.clip(n_full, a, n_end)
-        _loop(lo, a, lambda jk: step(jk, True))
-        _loop(a, b, lambda jk: step(jk, False))
-        _loop(b, n_end, lambda jk: step(jk, True))
+        def step(jk, masked: bool):
+            start = pl.multiple_of(jk * block_k, block_k)
+            kblk = k_ref[pl.ds(start, block_k), :]
+            vblk = v_ref[pl.ds(start, block_k), :]
+            s = _dot(q, kblk, _NT) * scale  # f32 [bq, bk]
+            if masked:
+                col = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = _masked(s, iq * block_q, col, 0)
+            m_prev = m_scr[...]  # [bq, LANES], every lane the same
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, 0:1])
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[...] = m_new
+            acc_scr[...] = acc_scr[...] * corr[:, 0:1] + _dot(p.astype(vblk.dtype), vblk, _NN)
 
-    l = l_scr[...]
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = (acc_scr[...] / l_safe[:, 0:1]).astype(o_ref.dtype)
-    # The statistic is a column here and a row in HBM: one transpose per
-    # q-block instead of a 128-lane broadcast written for every row.
-    lse_ref[...] = jnp.transpose(m_scr[...] + jnp.log(l_safe))[0:1, :]
+        # Blocks wholly visible (and wholly inside the valid keys) need no mask;
+        # the ones the diagonal crosses, or a ragged last one, do; the rest of a
+        # causal row's blocks are never visited.
+        n_full = tk_valid // block_k
+        n_end = n_k
+        if causal:
+            n_full = jnp.minimum(n_full, (iq * block_q) // block_k)
+            n_end = jnp.minimum(n_k, ((iq + 1) * block_q - 1) // block_k + 1)
+        if block_diffusion is not None:
+            a, b, c, d = _bd_fwd_bounds(iq, block_q, block_k, n_k, tk_valid // 2, block_diffusion)
+            _loop(0, a, lambda jk: step(jk, False))
+            _loop(a, b, lambda jk: step(jk, True))
+            _loop(c, d, lambda jk: step(jk, True))
+        elif window is None:
+            _loop(0, n_full, lambda jk: step(jk, False))
+            _loop(n_full, n_end, lambda jk: step(jk, True))
+        else:
+            # The band's far edge: the first block that holds a key some row of
+            # this q-block sees, then the first block every row sees all of.
+            row0, row1 = iq * block_q, (iq + 1) * block_q - 1
+            lo = jnp.maximum(row0 - window + 1, 0) // block_k
+            inside = jnp.maximum(row1 - window + block_k, 0) // block_k  # ceil((row1 - w + 1) / bk)
+            a = jnp.clip(inside, lo, n_end)
+            b = jnp.clip(n_full, a, n_end)
+            _loop(lo, a, lambda jk: step(jk, True))
+            _loop(a, b, lambda jk: step(jk, False))
+            _loop(b, n_end, lambda jk: step(jk, True))
+
+        l = l_scr[...]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        # The statistic is a column here and a row in HBM: one transpose per
+        # q-block instead of a 128-lane broadcast written for every row.
+        return acc_scr[...] / l_safe[:, 0:1], lambda: jnp.transpose(m_scr[...] + jnp.log(l_safe))[0:1, :]
+
+    if head_dim is None:  # the block is one head
+        out, lse = head(lambda: q_ref[...])
+        o_ref[...] = out.astype(o_ref.dtype)
+        lse_ref[...] = lse()
+        return
+    # The block is heads of ``head_dim`` lanes side by side (two of 64), each run in turn
+    # on the same scratch with its own statistics, the block's o written lane-dense.
+    # Nothing is sliced: the other heads' lanes are zeroed in q, so the scores are this head's
+    # alone; p @ v is right on its lanes (v is read whole) and chosen by lane below.
+    outs, rows = [], []
+    for h in range(q_ref.shape[-1] // head_dim):
+        out, lse = head(lambda: of_head(q_ref[...], lanes_of(q_ref), h, head_dim))
+        outs.append(out)
+        rows.append(lse())
+    o_ref[...] = by_head(outs, lanes_of(o_ref), head_dim).astype(o_ref.dtype)
+    lse_ref[...] = jnp.stack(rows)
 
 
 def _flash_forward(
@@ -593,11 +652,13 @@ def _flash_forward(
     block_diffusion: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """(out [B, H, Tq, Dv], lse [B, H, nq, 1, bq] over the padded rows). With
-    ``heads`` = (H, Hkv) q, k, v and out are merged, ``[B, T, H * D]``; with
-    ``tables`` (cos, sin ``[Tq, D]`` of ``rotary_tables``) the kernel turns q."""
+    ``heads`` = (H, Hkv) q, k, v and out are merged, ``[B, T, H * D]`` (a head
+    narrower than a tile: ``heads_a_block`` heads a block); with ``tables``
+    (cos, sin ``[Tq, D]`` of ``rotary_tables``) the kernel turns q."""
     merged, seq = heads is not None, 2 if heads is None else 1
     b, h, h_kv, tq, tk, d, dv = _dims(q, k, v, heads)  # dv: the value head's own width, o's and the accumulator's
     group = h // h_kv  # query heads per key/value head
+    per = heads_a_block(d, dv, h, h_kv) if merged else 1  # heads a block: the grid's second axis counts blocks
     scale = 1.0 / (d ** 0.5)
     qp, kp, vp = _pad_seq(q, bq, seq), _pad_seq(k, bk, seq), _pad_seq(v, bk, seq)
     tq_p, tk_p = qp.shape[seq], kp.shape[seq]
@@ -609,7 +670,7 @@ def _flash_forward(
     def at_kv(i, j, iq):  # a group's query heads read one key/value head
         return i, (j if group == 1 else j // group), 0
 
-    qspec, kvspec = _head_spec(merged, bq, d, at_q), _head_spec(merged, tk_p, d, at_kv)
+    qspec, kvspec = _head_spec(merged, bq, per * d, at_q), _head_spec(merged, tk_p, per * d, at_kv)
     ospec, vspec = qspec, kvspec
     if dv != d:  # a value head narrower (or wider) than the key head: v and o in blocks of its width
         ospec, vspec = _head_spec(merged, bq, dv, at_q), _head_spec(merged, tk_p, dv, at_kv)
@@ -622,13 +683,13 @@ def _flash_forward(
             _fwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_k=n_k, window=window,
             rotary_dim=None if tables is None else rotary_dim,
-            block_diffusion=block_diffusion,
+            block_diffusion=block_diffusion, head_dim=None if per == 1 else d,
         ),
-        grid=(b, h, n_q),
+        grid=(b, h // per, n_q),
         in_specs=[qspec, kvspec, vspec] + [spec for _, spec in turned],
         out_specs=[
             ospec,
-            pl.BlockSpec((None, None, None, 1, bq), lambda i, j, iq: (i, j, iq, 0, 0)),
+            pl.BlockSpec((None, None if per == 1 else per, None, 1, bq), lambda i, j, iq: (i, j, iq, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, tq_p, h * dv) if merged else (b, h, tq_p, dv), q.dtype),
@@ -637,7 +698,7 @@ def _flash_forward(
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, dv), jnp.float32),     # un-normalized output
+            pltpu.VMEM((bq, per * dv), jnp.float32),     # un-normalized output
         ],
         compiler_params=_compiler_params(
             interpret, ("parallel", "parallel", "parallel"),
@@ -668,7 +729,7 @@ def _delta_merged(do: jax.Array, out: jax.Array, h: int, bq: int, interpret: boo
     its own lanes, where XLA's reduce would first re-tile both arrays by head."""
     b, t, hd = do.shape
     dv = hd // h
-    per = _heads_a_block(h)
+    per = _heads_a_block(h, dv)
     block = pl.BlockSpec((None, bq, per * dv), lambda i, r, j: (i, r, j))
     return pl.pallas_call(
         functools.partial(_delta_kernel, dv=dv),
@@ -687,6 +748,7 @@ def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
     *, scale, causal, block_q, block_k, tk_valid, n_q, n_k, window=None, block_diffusion=None,
+    head_dim=None,
 ):
     ik = pl.program_id(2)
 
@@ -694,65 +756,86 @@ def _bwd_kernel(
     def init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    dk_scr[...] = jnp.zeros_like(dk_scr)
-    dv_scr[...] = jnp.zeros_like(dv_scr)
-    kblk = k_ref[...]  # [bk, D]
-    vblk = v_ref[...]
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
         window=window, block_diffusion=block_diffusion,
     )
 
-    def step(iq, masked: bool):
-        start = pl.multiple_of(iq * block_q, block_q)
-        qblk = q_ref[pl.ds(start, block_q), :]
-        doblk = do_ref[pl.ds(start, block_q), :]
-        # Transposed scores [bk, bq]: the statistics are rows [1, bq] and
-        # broadcast along sublanes; dv and dk need no transposed operand.
-        s_t = _dot(kblk, qblk, _NT) * scale
-        if masked:
-            kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
-            s_t = _masked(s_t, iq * block_q, kpos, 1)
-        p_t = jnp.exp(s_t - lse_ref[iq])  # f32 [bk, bq]
-        dv_scr[...] += _dot(p_t.astype(doblk.dtype), doblk, _NN)
-        dp_t = _dot(vblk, doblk, _NT)
-        ds_t = (p_t * (dp_t - delta_ref[iq])).astype(qblk.dtype)
-        # dk and dq accumulate unscaled; the scale is applied once at the end.
-        dk_scr[...] += _dot(ds_t, qblk, _NN)
-        dq_scr[pl.ds(start, block_q), :] += _dot(ds_t, kblk, _TN)
+    def head(take_kv, row=lambda ref, iq: ref[iq]):
+        """One head's pass over the query blocks that see this key block: its
+        dk and dv left in the scratch (unscaled), its share added to
+        ``dq_scr``. ``take_kv()``: the key and value block, [bk, width] each;
+        ``row(ref, iq)``: the head's row of a statistic for query block ``iq``."""
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+        kblk, vblk = take_kv()  # [bk, D]
 
-    # Padded q rows need no mask: their dO and delta are zero, so they add
-    # nothing. Padded keys (a ragged last block) must not receive a share of
-    # a row's probability, so every step of that block is masked.
-    first, n_masked_end = 0, 0
-    if causal:
-        first = (ik * block_k) // block_q
-        n_masked_end = jnp.minimum(n_q, ((ik + 1) * block_k + block_q - 2) // block_q)
-    if tk_valid % block_k:
-        n_masked_end = jnp.where(ik == n_k - 1, n_q, n_masked_end)
-    if block_diffusion is not None:
-        s1, e1, e2, s3, e3, e4 = _bd_bwd_bounds(ik, block_q, block_k, n_q, tk_valid // 2, block_diffusion)
-        _loop(s1, e1, lambda iq: step(iq, True))
-        _loop(e1, e2, lambda iq: step(iq, False))
-        _loop(s3, e3, lambda iq: step(iq, True))
-        _loop(e3, e4, lambda iq: step(iq, False))
-    elif window is None:
-        _loop(first, n_masked_end, lambda iq: step(iq, True))
-        _loop(n_masked_end, n_q, lambda iq: step(iq, False))
+        def step(iq, masked: bool):
+            start = pl.multiple_of(iq * block_q, block_q)
+            qblk = q_ref[pl.ds(start, block_q), :]
+            doblk = do_ref[pl.ds(start, block_q), :]
+            # Transposed scores [bk, bq]: the statistics are rows [1, bq] and
+            # broadcast along sublanes; dv and dk need no transposed operand.
+            s_t = _dot(kblk, qblk, _NT) * scale
+            if masked:
+                kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
+                s_t = _masked(s_t, iq * block_q, kpos, 1)
+            p_t = jnp.exp(s_t - row(lse_ref, iq))  # f32 [bk, bq]
+            dv_scr[...] += _dot(p_t.astype(doblk.dtype), doblk, _NN)
+            dp_t = _dot(vblk, doblk, _NT)
+            ds_t = (p_t * (dp_t - row(delta_ref, iq))).astype(qblk.dtype)
+            # dk and dq accumulate unscaled; the scale is applied once at the end.
+            dk_scr[...] += _dot(ds_t, qblk, _NN)
+            dq_scr[pl.ds(start, block_q), :] += _dot(ds_t, kblk, _TN)
+
+        # Padded q rows need no mask: their dO and delta are zero, so they add
+        # nothing. Padded keys (a ragged last block) must not receive a share of
+        # a row's probability, so every step of that block is masked.
+        first, n_masked_end = 0, 0
+        if causal:
+            first = (ik * block_k) // block_q
+            n_masked_end = jnp.minimum(n_q, ((ik + 1) * block_k + block_q - 2) // block_q)
+        if tk_valid % block_k:
+            n_masked_end = jnp.where(ik == n_k - 1, n_q, n_masked_end)
+        if block_diffusion is not None:
+            s1, e1, e2, s3, e3, e4 = _bd_bwd_bounds(ik, block_q, block_k, n_q, tk_valid // 2, block_diffusion)
+            _loop(s1, e1, lambda iq: step(iq, True))
+            _loop(e1, e2, lambda iq: step(iq, False))
+            _loop(s3, e3, lambda iq: step(iq, True))
+            _loop(e3, e4, lambda iq: step(iq, False))
+        elif window is None:
+            _loop(first, n_masked_end, lambda iq: step(iq, True))
+            _loop(n_masked_end, n_q, lambda iq: step(iq, False))
+        else:
+            # Queries past the band's far edge never see this k-block: the loop
+            # ends with the last q-block that holds a row seeing its last key;
+            # q-blocks whose every row sees every key of it run unmasked.
+            key0, key1 = ik * block_k, (ik + 1) * block_k - 1
+            last = jnp.minimum(n_q, (key1 + window - 1) // block_q + 1)
+            a = jnp.minimum(n_masked_end, last)
+            b = jnp.clip((key0 + window) // block_q, a, last)
+            _loop(first, a, lambda iq: step(iq, True))
+            _loop(a, b, lambda iq: step(iq, False))
+            _loop(b, last, lambda iq: step(iq, True))
+
+    if head_dim is None:  # the block is one head
+        head(lambda: (k_ref[...], v_ref[...]))
+        dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
     else:
-        # Queries past the band's far edge never see this k-block: the loop
-        # ends with the last q-block that holds a row seeing its last key;
-        # q-blocks whose every row sees every key of it run unmasked.
-        key0, key1 = ik * block_k, (ik + 1) * block_k - 1
-        last = jnp.minimum(n_q, (key1 + window - 1) // block_q + 1)
-        a = jnp.minimum(n_masked_end, last)
-        b = jnp.clip((key0 + window) // block_q, a, last)
-        _loop(first, a, lambda iq: step(iq, True))
-        _loop(a, b, lambda iq: step(iq, False))
-        _loop(b, last, lambda iq: step(iq, True))
-
-    dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
-    dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        # The block is heads of ``head_dim`` lanes side by side, each run in turn with its own
+        # rows of lse and delta; dq, dk and dv are written lane-dense. Nothing is sliced: the
+        # other heads' lanes are zeroed in k and v, so the scores and dp are this head's and its
+        # dq lands on its own lanes of the one accumulator; dk and dv are right on its lanes
+        # (q and dO are read whole) and chosen by lane below.
+        dks, dvs, lane = [], [], lanes_of(k_ref)
+        for h in range(k_ref.shape[-1] // head_dim):
+            head(lambda: (of_head(k_ref[...], lane, h, head_dim), of_head(v_ref[...], lane, h, head_dim)),
+                 lambda ref, iq: ref[h, iq])
+            dks.append(dk_scr[...])
+            dvs.append(dv_scr[...])
+        dk_ref[...] = (by_head(dks, lane, head_dim) * scale).astype(dk_ref.dtype)
+        dv_ref[...] = by_head(dvs, lane, head_dim).astype(dv_ref.dtype)
 
     @pl.when(ik == n_k - 1)
     def finalize():
@@ -770,6 +853,7 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
     merged, seq = heads is not None, 2 if heads is None else 1
     b, h, h_kv, tq, tk, d, dv = _dims(q, k, v, heads)
     group = h // h_kv
+    per = heads_a_block(d, dv, h, h_kv) if merged else 1  # heads a block, as the forward
     scale = 1.0 / (d ** 0.5)
 
     qp, kp, vp, dop = _pad_seq(q, bq, seq), _pad_seq(k, bk, seq), _pad_seq(v, bk, seq), _pad_seq(do, bq, seq)
@@ -791,9 +875,9 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
     def at_kv(i, j, ik):  # a group's query heads read one K/V head and each writes its own dk, dv
         return i, (j if group == 1 else j // group), ik
 
-    head = _head_spec(merged, tq_p, d, at_head)
-    rows = pl.BlockSpec((None, None, n_q, 1, bq), lambda i, j, ik: (i, j, 0, 0, 0))
-    kblock, kv_in = _head_spec(merged, bk, d, at_block), _head_spec(merged, bk, d, at_kv)
+    head = _head_spec(merged, tq_p, per * d, at_head)
+    rows = pl.BlockSpec((None, None if per == 1 else per, n_q, 1, bq), lambda i, j, ik: (i, j, 0, 0, 0))
+    kblock, kv_in = _head_spec(merged, bk, per * d, at_block), _head_spec(merged, bk, per * d, at_kv)
     vhead, vblock, v_in = head, kblock, kv_in
     if dv != d:  # do, v and dv in blocks of the value head's width
         vhead = _head_spec(merged, tq_p, dv, at_head)
@@ -811,9 +895,9 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_q=n_q, n_k=n_k, window=window,
-            block_diffusion=block_diffusion,
+            block_diffusion=block_diffusion, head_dim=None if per == 1 else d,
         ),
-        grid=(b, h, n_k),
+        grid=(b, h // per, n_k),
         in_specs=[head, kv_in, v_in, vhead, rows, rows],
         out_specs=[head, kblock, vblock],
         out_shape=[
@@ -826,9 +910,9 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
                 v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((tq_p, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, dv), jnp.float32),
+            pltpu.VMEM((tq_p, per * d), jnp.float32),
+            pltpu.VMEM((bk, per * d), jnp.float32),
+            pltpu.VMEM((bk, per * dv), jnp.float32),
         ],
         compiler_params=_compiler_params(
             # dq accumulates across the k-blocks of a head: that dim is
@@ -889,16 +973,18 @@ def kept_bytes(q: jax.Array, k: jax.Array, window: Optional[int] = None,
                block_diffusion: Optional[int] = None) -> int:
     """Bytes of the two residuals named by ``KEPT_NAMES`` for one call at
     ``choose_blocks``' blocks, as the chip lays them out: the output with its
-    head dim padded to whole lanes (a D=64 output takes a D=128 one's room:
-    the compiled step's kept stack is ``bf16[L,B,H,T,64]`` tiled (8, 128); a
-    merged call's heads are whole lanes), and the f32 log-sum-exp rows of whole
-    q-blocks."""
+    head dim padded to whole lanes where it is by head (a D=64 output takes a
+    D=128 one's room: a kept stack ``bf16[L,B,H,T,64]`` is tiled (8, 128)) and
+    lane-dense where it is merged (``bf16[L,B,T,H*D]``: two heads of 64 fill a
+    tile), and the f32 log-sum-exp rows of whole q-blocks."""
     bq, _, _ = _resolve(q, k, None, None, False, block_diffusion is None, window, heads,
                         block_diffusion=block_diffusion)
     b, h, _, tq, _, d, dv = _dims(q, k, k if v is None else v, heads)
     if v is not None:  # the output is as wide as the value head
         d = dv
-    return b * h * (tq * _round_up(d, LANES) * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
+    if heads is None:  # by head a row of the output is whole lanes, whatever the head
+        d = _round_up(d, LANES)
+    return b * h * (tq * d * jnp.dtype(q.dtype).itemsize + 4 * _round_up(tq, bq))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -948,9 +1034,9 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def flash_attention_merged(
-    q: jax.Array,  # [B, T, H * D] as the projection made it, D whole lanes; turned here if ``cos`` is given
+    q: jax.Array,  # [B, T, H * D] as the projection made it (``heads_a_block`` > 0); turned here if ``cos`` is given
     k: jax.Array,  # [B, T, Hkv * D], Hkv dividing H; already turned (``rotary_merged``)
-    v: jax.Array,  # [B, T, Hkv * Dv], Dv whole lanes
+    v: jax.Array,  # [B, T, Hkv * Dv]: D and Dv whole lanes, or D = Dv a whole part of a tile and Hkv = H
     cos: Optional[jax.Array],  # ``rotary_tables`` [T, D] float32, or None: q is scored as it is
     sin: Optional[jax.Array],
     heads: Tuple[int, int],  # (H, Hkv)
@@ -962,7 +1048,8 @@ def flash_attention_merged(
 ) -> jax.Array:
     """``flash_attention`` on the projections' own layout, giving [B, T, H * Dv]
     (what the output projection reads): the same two kernels, a head being
-    block ``h`` of the last axis. The forward turns each q block on the tile it
+    block ``h`` of the last axis (or two heads of 64 a block: no rotary there).
+    The forward turns each q block on the tile it
     has taken up; the backward, whose kernel holds a head's q resident and
     visits it from every key block, is handed q turned by one pass outside
     (``_turn_merged``) and its dq takes one pass back."""

@@ -83,6 +83,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from distributedvolunteercomputing_tpu.ops.attention import chips_in_step
+from distributedvolunteercomputing_tpu.ops.lanes import by_head, of_head
 from distributedvolunteercomputing_tpu.utils import traced
 from distributedvolunteercomputing_tpu.utils.jaxenv import tpu_backend
 
@@ -269,20 +270,6 @@ def heads_a_tile(heads_a_group: int, head_dim: int) -> int:
     return min(heads_a_group, max(1, 128 // head_dim))
 
 
-def _by_head(values, lane, head_dim: int):
-    """One array from a tile's per-head ``values``: where the ``lane`` of the
-    tile (int32) falls in head ``i``, ``values[i]``."""
-    out = values[-1]
-    for i in range(len(values) - 2, -1, -1):
-        out = jnp.where(lane < (i + 1) * head_dim, values[i], out)
-    return out
-
-
-def _of_head(x, lane, i: int, head_dim: int):
-    """``x`` where the ``lane`` of the tile falls in head ``i``, 0 elsewhere."""
-    return jnp.where((lane >= i * head_dim) & (lane < (i + 1) * head_dim), x, 0.0)
-
-
 def _tile_decays(cum_ref, dt_ref, first: int, per: int, width: int, eye):
     """Of the ``per`` heads from ``first`` of the group: each head's cum along
     the lanes [1, Q] and down the rows [Q, 1]; by lane of the tile, down the
@@ -300,8 +287,8 @@ def _tile_decays(cum_ref, dt_ref, first: int, per: int, width: int, eye):
         es.append(jnp.exp(col))
         ws.append(jnp.exp(_last_as_column(row) - col))
         wholes.append(jnp.exp(row[:, q - 1:]))
-    return (rows, cols, lane, _by_head(dts, lane, p), _by_head(es, lane, p), _by_head(ws, lane, p),
-            _by_head(wholes, lane[:1], p))
+    return (rows, cols, lane, by_head(dts, lane, p), by_head(es, lane, p), by_head(ws, lane, p),
+            by_head(wholes, lane[:1], p))
 
 
 def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, st_ref, s_scr, *, per: int):
@@ -323,7 +310,7 @@ def _fwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, y_ref, st_ref, s_sc
         st_ref[:, at] = s
         xf = x_ref[:, at].astype(_F32)
         xd = (xf * dt).astype(dtype)
-        y = _by_head([_dot((cb * jnp.exp(jnp.where(tri, col - row, -jnp.inf))).astype(dtype), xd, _NN)
+        y = by_head([_dot((cb * jnp.exp(jnp.where(tri, col - row, -jnp.inf))).astype(dtype), xd, _NN)
                       for row, col in zip(rows, cols)], lane, width // per)
         y = y + e * _dot(cm, s.astype(dtype), _NN) + d_ref[:, at] * xf
         y_ref[:, at] = y.astype(dtype)
@@ -366,12 +353,12 @@ def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, st_ref, dy_ref,
         zs, dxds = [], []
         for i, (row, col) in enumerate(zip(rows, cols)):
             lower = jnp.exp(jnp.where(tri, col - row, -jnp.inf))
-            dm = _dot(_of_head(dyf, lane, i, p).astype(dtype), xd, _NT)       # [Q, Q]: over head i's lanes
+            dm = _dot(of_head(dyf, lane, i, p).astype(dtype), xd, _NT)       # [Q, Q]: over head i's lanes
             zs.append(dm * (cb * lower))
             dcb = dcb + dm * lower
             m_t = cb_t * jnp.exp(jnp.where(upper, row - col, -jnp.inf))       # m^T, made as m is, not turned
             dxds.append(_dot(m_t.astype(dtype), dyb, _NN))
-        dxd = _by_head(dxds, lane, p) + w * from_ds
+        dxd = by_head(dxds, lane, p) + w * from_ds
         dye = (e * dyf).astype(dtype)
         dc = dc + _dot(dye, sb, _NT)
         db = db + _dot((w * xdf).astype(dtype), dsb, _NT)
@@ -380,15 +367,15 @@ def _bwd_kernel(x_ref, b_ref, c_ref, cum_ref, dt_ref, d_ref, st_ref, dy_ref,
         to_dt = dxd * xf
         for i, z in enumerate(zs):
             h = first + i
-            mine = _of_head(by_lane, lane, i, p)
+            mine = of_head(by_lane, lane, i, p)
             if z.shape == mine.shape:   # a chunk as wide as a tile: one sum along lanes for both
                 by_row = jnp.sum(z + mine, axis=1, keepdims=True)
             else:
                 by_row = jnp.sum(z, axis=1, keepdims=True) + jnp.sum(mine, axis=1, keepdims=True)
-            dlast = jnp.sum(_of_head(to_last, lane[:1], i, p), axis=1, keepdims=True)
+            dlast = jnp.sum(of_head(to_last, lane[:1], i, p), axis=1, keepdims=True)
             dcum = _as_row(by_row, eye) - jnp.sum(z, axis=0, keepdims=True)
             dcum_ref[h:h + 1, :] = jnp.where(last_lane, dcum + dlast, dcum)
-            ddt_ref[h:h + 1, :] = _as_row(jnp.sum(_of_head(to_dt, lane, i, p), axis=1, keepdims=True), eye)
+            ddt_ref[h:h + 1, :] = _as_row(jnp.sum(of_head(to_dt, lane, i, p), axis=1, keepdims=True), eye)
         dx_ref[:, at] = (d_ref[:, at] * dyf + dt * dxd).astype(dtype)
         dd_ref[:, at] += jnp.sum(dyf * xf, axis=0, keepdims=True)
         ds_scr[:, at] = whole * ds + _dot(c_t, dye, _NN)

@@ -789,7 +789,7 @@ class FlightRecorder:
 # (docs/OBSERVABILITY.md, "Metric catalog", says what each means).
 TRACED_HELP: Dict[str, str] = {
     "attention_core": "traced attention calls by the core that took them",
-    "qkv_projection": "traced fused qkv projections by how they were divided over tp",
+    "qkv_projection": "traced projections off a fused qkv leaf by how they were divided over tp",
     "tp_streams": "traced layer scans by the independent row streams their body runs",
     "remat_kept": "traced rematerialised layers whose checkpoint kept a kernel's or a tp sum's result",
     "moe_dispatch": "traced expert dispatches by the grouped matmul that took them",

@@ -182,7 +182,7 @@ def main() -> int:
         f.write(line + "\n")
     print(line)
     ok = (
-        set(traced_by_head) == {"by_head"} and set(result["traced"]["fused"]) == {"fused"}
+        set(traced_by_head) == {"by_head"} and set(result["traced"]["fused"]) == {"merged"}
         and result["loss_abs_err"] <= 0.005 and result["grad_rel_err"] <= 0.04
         and result["two_streams_against_one"]["loss_abs_err"] <= 0.005
         and result["two_streams_against_one"]["grad_rel_err"] <= 0.04

@@ -600,14 +600,14 @@ def _merged_qkv(b, t, h, hkv, d, dv, dtype=jnp.float32, seed=0):
     return [jax.random.normal(k, s, dtype) for k, s in zip(ks, shapes)]
 
 
-def _by_head_path(q, k, v, h, hkv, window, rotary):
+def _by_head_path(q, k, v, h, hkv, window, rotary, causal=True):
     """What every model ran before the merged entry, and what the entry falls back to."""
     from distributedvolunteercomputing_tpu.ops import attention as A
 
     qh, kh, vh = A.split_heads(q, h), A.split_heads(k, hkv), A.split_heads(v, hkv)
     if rotary is not None:
         qh, kh = A.rope(qh, **rotary._asdict()), A.rope(kh, **rotary._asdict())
-    return A.merge_heads(A.attention_core(qh, kh, vh, causal=True, window=window))
+    return A.merge_heads(A.attention_core(qh, kh, vh, causal=causal, window=window))
 
 
 def _half(**kw):
@@ -628,7 +628,7 @@ def _yarn():
     return _half(rotary_dim=64, inv_freq=yarn_inv_freq(64, 500000.0, 64.0, 64, 64.0, 1.0), scale=1.4)
 
 
-# (b, t, h, hkv, d, dv, window, rotary, the layout and the turn the observer hears, exact)
+# (b, t, h, hkv, d, dv, window, rotary, the layout and the turn the observer hears, exact[, causal, dtype])
 _MERGED_CASES = {
     "full rotary at D = 128": (2, 128, 2, 2, 128, 128, None, _half, "merged/kernel", False),
     "partial rotary 64 of 128, yarn and a scale": (1, 128, 2, 1, 128, 128, None, _yarn, "merged/kernel", False),
@@ -645,6 +645,19 @@ _MERGED_CASES = {
     "D = 64: the by-head path": (1, 128, 4, 2, 64, 64, None, _half, "heads/outside", True),
     "D = 64 without rotary: the by-head path": (1, 128, 4, 4, 64, 64, 32, None, "heads/none", True),
     "interleaved pairs: the by-head path": (1, 128, 2, 2, 128, 128, None, _interleaved, "heads/outside", True),
+    # a head of 64 is half of a 128-lane block: two heads a grid step, each on its own lanes (PR 66)
+    "a head of 64, 16 heads": (2, 128, 16, 16, 64, 64, None, None, "merged/none", False),
+    "a head of 64, not causal": (2, 128, 16, 16, 64, 64, None, None, "merged/none", False, False),
+    "a head of 64, a sequence that pads": (1, 200, 16, 16, 64, 64, None, None, "merged/none", False),
+    "a head of 64, a padded sequence, not causal": (1, 200, 16, 16, 64, 64, None, None, "merged/none", False, False),
+    "a head of 64, several blocks a sequence": (1, 2048, 2, 2, 64, 64, None, None, "merged/none", False),
+    "a head of 64 in bfloat16": (2, 128, 16, 16, 64, 64, None, None, "merged/none", False, True, jnp.bfloat16),
+    "a head of 64 in bfloat16, not causal": (
+        1, 200, 16, 16, 64, 64, None, None, "merged/none", False, False, jnp.bfloat16),
+    "a head of 32: four a block": (1, 128, 8, 8, 32, 32, None, None, "merged/none", False),
+    "an odd head count at D = 64: the by-head path": (1, 128, 3, 3, 64, 64, None, None, "heads/none", True),
+    "grouped heads at D = 64: the by-head path": (1, 128, 4, 2, 64, 64, None, None, "heads/none", True),
+    "a rotary at D = 64: the by-head path": (1, 128, 4, 4, 64, 64, None, _half, "heads/outside", True),
 }
 
 
@@ -659,28 +672,32 @@ def test_merged_entry_equals_the_by_head_path(case):
     ``rope``, so float32 inputs agree to rounding."""
     from distributedvolunteercomputing_tpu.ops import attention as A
 
-    b, t, h, hkv, d, dv, window, rotary, heard, exact = _MERGED_CASES[case]
+    b, t, h, hkv, d, dv, window, rotary, heard, exact, *rest = _MERGED_CASES[case]
+    causal, dtype = (*rest, *(True, jnp.float32)[len(rest):])
     rotary = None if rotary is None else rotary()
-    q, k, v, cot = _merged_qkv(b, t, h, hkv, d, dv)
+    q, k, v, cot = _merged_qkv(b, t, h, hkv, d, dv, dtype)
     seen = []
     cores = traced.subscribe(lambda kind, said: seen.append("{impl}:{layout}/{rotary}".format(**said)))
     try:
         set_attention_impl("flash")
         got, vjp = jax.vjp(lambda q, k, v: A.attention_merged(
-            q, k, v, h, hkv, causal=True, window=window, rotary=rotary), q, k, v)
+            q, k, v, h, hkv, causal=causal, window=window, rotary=rotary), q, k, v)
         got = (got, *vjp(cot))
         assert seen == [f"flash:{heard}"], seen
-        want, vjp = jax.vjp(lambda q, k, v: _by_head_path(q, k, v, h, hkv, window, rotary), q, k, v)
+        want, vjp = jax.vjp(lambda q, k, v: _by_head_path(q, k, v, h, hkv, window, rotary, causal), q, k, v)
         want = (want, *vjp(cot))
     finally:
         set_attention_impl("auto")
         cores.close()
-    assert got[0].shape == (b, t, h * dv)
+    assert got[0].shape == (b, t, h * dv) and got[0].dtype == dtype
     for name, x, y in zip(("out", "dq", "dk", "dv"), got, want):
+        x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
         if exact:
-            assert np.array_equal(np.asarray(x), np.asarray(y)), name
-        else:
-            np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5, err_msg=name)
+            assert np.array_equal(x, y), name
+        elif dtype == jnp.float32:
+            np.testing.assert_allclose(x, y, atol=2e-5, rtol=2e-5, err_msg=name)
+        else:  # bfloat16: each side a rounding of the same float32 sums, a step of 2**-8 of the largest
+            np.testing.assert_allclose(x, y, atol=2e-2 * max(1.0, float(np.max(np.abs(y)))), rtol=2e-2, err_msg=name)
 
 
 def test_merged_entry_rounds_where_rope_rounds():
@@ -772,6 +789,41 @@ def test_merged_entry_per_shard_under_a_mesh(eight_devices):
         set_attention_impl("auto")
         cores.close()
     assert seen == [("merged", "kernel")] * 2
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
+    for x, y in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("heads,heard", [(8, "merged"), (6, "heads")])
+def test_a_head_of_64_per_shard_under_a_mesh(eight_devices, heads, heard):
+    """A head of 64 under a step's dp x tp mesh: where a chip's share of the
+    heads is whole blocks of two (8 heads over tp = 2) the merged call runs per
+    shard, a chip's pairs a contiguous part of the last axis; where it is not (6
+    heads: three a chip) the entry is the by-head path, as on one chip with an
+    odd count. Loss and gradients are the unsharded call's either way."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from distributedvolunteercomputing_tpu.ops import attention as A
+
+    mesh = Mesh(np.array(eight_devices[:4]).reshape(2, 2), ("dp", "tp"))
+    q, k, v, cot = _merged_qkv(2, 128, heads, heads, 64, 64)
+
+    def loss(q, k, v):
+        return jnp.sum(cot * A.attention_merged(q, k, v, heads, heads, causal=True))
+
+    seen = []
+    cores = traced.subscribe(lambda kind, said: seen.append(said["layout"]))
+    try:
+        set_attention_impl("flash")
+        want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        spec = NamedSharding(mesh, P("dp", None, "tp"))
+        with A.step_mesh(mesh):
+            got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)), in_shardings=(spec,) * 3)(q, k, v)
+    finally:
+        set_attention_impl("auto")
+        cores.close()
+    assert seen == ["merged" if heads % 2 == 0 else "heads", heard]  # one chip: pairs of the whole count
     np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
     for x, y in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=2e-5, rtol=2e-5)

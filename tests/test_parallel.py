@@ -137,11 +137,11 @@ def qkv_layouts():
         yield lambda: tel.summary()["qkv_projection"]  # what coord.status shows per peer
 
 
-@pytest.mark.parametrize("n_heads,layout", [(4, "by_head"), (3, "fused")])
+@pytest.mark.parametrize("n_heads,layout", [(4, "by_head"), (3, "merged")])
 @pytest.mark.parametrize("model", sorted(FUSED_QKV_MODELS))
 def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_heads, layout):
     """dp=2,tp=2: the fused qkv projection is divided by head where tp divides
-    the heads and stays one product where it does not; either way the loss and
+    the heads and stays the leaf's three column ranges ([B, T, d] each) where it does not; either way the loss and
     every gradient leaf (plain SGD at lr 1: the step's change of a leaf) are
     the single-device step's."""
     import optax
@@ -157,7 +157,7 @@ def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, m
     ref_state, ref_metrics = make_train_step(bundle.loss_fn, tx, donate=False)(
         TrainState.create(params, tx, jax.random.PRNGKey(2)), batch
     )
-    assert qkv_layouts() == {"fused": 1}  # no step mesh: one trace, one product
+    assert qkv_layouts() == {"merged": 1}  # no step mesh: one trace, q, k and v [B, T, d]
 
     mesh = make_mesh(dp=2, tp=2)
     state, _ = shard_train_state(TrainState.create(params, tx, jax.random.PRNGKey(2)), mesh, tx)
@@ -165,7 +165,7 @@ def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, m
     state, metrics = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False)(
         state, put_batch(batch, mesh)
     )
-    assert qkv_layouts() == ({"fused": 2} if layout == "fused" else {"fused": 1, "by_head": 1})
+    assert qkv_layouts() == ({"merged": 2} if layout == "merged" else {"merged": 1, "by_head": 1})
     # the head-aligned view lives inside the step: leaves keep their stored layout
     assert stored["blocks"]["qkv"]["w"].spec == P(None, None, "tp")
     assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
@@ -375,18 +375,21 @@ def test_sharded_multi_step_runs_the_two_stream_body(eight_devices, tp_streams):
 def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):
     """Inside a ``shard_map`` that has made ``tp`` manual the trace sees one
     chip's share: nothing is left to divide, the projection keeps its fused
-    form; a manual ``pp`` (a pipeline stage) leaves ``tp`` to divide."""
+    leaf's three column ranges ([B, T, d] each); a manual ``pp`` (a pipeline
+    stage) leaves ``tp`` to divide, and q, k and v are born by head."""
     from distributedvolunteercomputing_tpu.models import common
-    from distributedvolunteercomputing_tpu.ops.attention import step_mesh
+    from distributedvolunteercomputing_tpu.ops.attention import merge_heads, step_mesh
     from distributedvolunteercomputing_tpu.parallel.mesh import shard_map_manual
 
     mesh = make_mesh(dp=2, pp=2, tp=2)
     leaf = common.dense_init(jax.random.PRNGKey(0), 32, 96)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
-    want = common.qkv_heads(leaf, x, 4)
-    for axis, layouts in (("tp", {"fused": 2}), ("pp", {"fused": 2, "by_head": 1})):
+    layout, want = common.qkv_heads(leaf, x, 4)
+    assert layout == "merged" and want[0].shape == (4, 8, 32)
+    for axis, layouts in (("tp", {"merged": 2}), ("pp", {"merged": 2, "by_head": 1})):
         def project(x):
-            return jnp.stack(common.qkv_heads(leaf, x, 4))
+            layout, qkv = common.qkv_heads(leaf, x, 4)
+            return jnp.stack([merge_heads(a) if layout == "by_head" else a for a in qkv])
 
         with step_mesh(mesh):
             got = jax.jit(shard_map_manual(project, mesh, P(), P(), axis))(x)

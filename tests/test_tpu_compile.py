@@ -107,6 +107,38 @@ def test_flash_fwd_bwd_compiles(v5e, shape, blocks):
     assert "dvc_flash_fwd" in text and "dvc_flash_bwd" in text  # the names a trace shows
 
 
+@pytest.mark.parametrize(
+    "b,t,heads,causal",
+    [
+        (16, 1024, 16, True),    # medium-solo's layer: one block forward
+        (8, 512, 12, False),     # BERT's layer at its 512 positions: every key seen
+        (2, 4096, 16, False),    # several blocks a sequence, none skipped
+        (2, 4096, 16, True),     # the same under the diagonal
+        (4, 197, 12, False),     # ViT's 197 patches: one padded block, its tail masked
+    ],
+)
+def test_a_block_of_two_heads_of_64_compiles(v5e, b, t, heads, causal):
+    """The kernels on ``[B, T, H * 64]`` (two heads a 128-lane block, PR 66),
+    forward and backward with the delta pass, for Mosaic and not only
+    interpreted: causal as gpt2 calls them and NOT causal as BERT and ViT do
+    (``common.fused_qkv_attention``), one block, several, and a padded one."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    x = jax.ShapeDtypeStruct((b, t, heads * 64), jnp.bfloat16, sharding=SingleDeviceSharding(v5e[0]))
+    assert pa.heads_a_block(64, 64, heads, heads) == 2
+
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            return pa.flash_attention_merged(q, k, v, None, None, (heads, heads), causal, None, None, False) \
+                .astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    calls = _kernel_calls(_compiled_text(fwd_bwd, x, x, x))
+    names = sorted(n.split(".")[0] for n in _kernel_names(calls))
+    assert names == ["dvc_attn_delta", "dvc_flash_bwd", "dvc_flash_fwd"], names
+
+
 def test_block_diffusion_fwd_bwd_compiles_at_the_cells_shape(v5e):
     """The kernels under the three-part mask at sdar-solo-4k's layer: 2 x 8,192
     rows, 32 query heads over 4, head 128, on the projections' own layout, q
@@ -184,12 +216,12 @@ def test_a_causal_and_a_windowed_call_lower_as_before_the_third_mask(v5e, monkey
 def as_on_the_chip(monkeypatch):
     """The program's backend checks answer as they do on the chip: bf16
     compute, auto routing to the kernel, compiled (not interpreted) kernels."""
-    from distributedvolunteercomputing_tpu.ops import pallas_attention, short_conv
+    from distributedvolunteercomputing_tpu.ops import kda, pallas_attention, short_conv, ssd
     from distributedvolunteercomputing_tpu.utils import jaxenv
 
     monkeypatch.setattr(jaxenv, "tpu_backend", lambda: True)
-    monkeypatch.setattr(pallas_attention, "tpu_backend", lambda: True)
-    monkeypatch.setattr(short_conv, "tpu_backend", lambda: True)
+    for module in (kda, pallas_attention, short_conv, ssd):  # each bound the name when it was imported
+        monkeypatch.setattr(module, "tpu_backend", lambda: True)
 
 
 def _traced_step(v5e, model: str, dp: int, tp: int, batch: int, n_layers: int = 2, **overrides):
@@ -325,14 +357,47 @@ def _head_makes_its_gradients_in_the_loop_of_its_loss(text: str, vocab: int) -> 
 
 def test_medium_step_holds_the_kernel(v5e, as_on_the_chip):
     """medium-solo's step, auto routing: T=1,024 bf16 takes the fused core,
-    forward and backward; the recomputed forward holds no kernel (the layer's
-    checkpoint kept its output and row statistics: ``common.remat_layer``)."""
+    forward and backward, on the projections' own ``[16, 1024, 16 x 64]`` arrays
+    (two heads of 64 a 128-lane block, PR 66), with the backward's delta by
+    ``dvc_attn_delta`` in the same layout; the recomputed forward holds no
+    kernel (the layer's checkpoint kept its output and row statistics:
+    ``common.remat_layer``); and no array by head exists anywhere in the
+    optimised step: not q, k, v, o or a cotangent as ``[16,16,1024,64]``, not
+    the kept stack as ``[L,16,16,1024,64]`` (it is ``bf16[L,16,1024,1024]``,
+    lane-dense), not the ``[16,1024,3072]`` result of one fused product cut in
+    three."""
+    import re
+
     text = _step_text(v5e, "gpt2_medium", 1, 1, 16)
     calls = _kernel_calls(text)
-    assert len(calls) == 2
-    assert all("bf16[16,16,1024,64]" in ln for ln in calls)
+    names = sorted(n.split(".")[0] for n in _kernel_names(calls))
+    assert names == ["dvc_attn_delta", "dvc_flash_bwd", "dvc_flash_fwd"], names
+    flash = [ln for ln in calls if "dvc_flash_" in ln]
+    assert len(flash) == 2 and all(ln.count("bf16[16,1024,1024]") >= 4 for ln in flash)  # q, k, v and o (dO) alike
+    assert not re.search(r"\[(?:\d+,)?16,16,1024,64\]|\[16,1024,16,64\]|\[16,1024,3072\]", text)
+    assert "bf16[2,16,1024,1024]" in text  # the kept outputs of the two layers, a row of d lanes
     _step_holds_the_groups_its_cell_lists(text, "medium-solo")
     _head_makes_its_gradients_in_the_loop_of_its_loss(text, 50257)
+
+
+def test_bert_step_holds_the_pair_kernels_where_every_key_is_seen(v5e, as_on_the_chip):
+    """BERT's step at its 512 positions (12 heads of 64, bf16, auto routing)
+    reaches the same pair kernels as gpt2's through ``common.fused_qkv_attention``,
+    NOT causal: the optimised step for the described chip holds the forward,
+    the backward and the delta pass on ``bf16[8,512,768]`` operands and no
+    array by head, and the trace notes ``merged/none``. (No cell runs BERT; the
+    chip has run this call alone: ``experiments/attention_head64_sweep.py``,
+    its ``full`` lines.)"""
+    import re
+
+    with _noted("attention_core", "impl", "T", "D", "layout", "rotary") as cores:
+        text = _step_text(v5e, "bert_mlm", 1, 1, 8)
+    assert cores == [("flash", 512, 64, "merged", "none")], cores
+    calls = _kernel_calls(text)
+    names = sorted(n.split(".")[0] for n in _kernel_names(calls))
+    assert names == ["dvc_attn_delta", "dvc_flash_bwd", "dvc_flash_fwd"], names
+    assert all(ln.count("bf16[8,512,768]") >= 4 for ln in calls if "dvc_flash_" in ln)  # q, k, v and o (dO) alike
+    assert not re.search(r"\[(?:\d+,)?8,12,512,64\]|\[8,512,12,64\]|\[8,512,2304\]", text)
 
 
 def test_ouro_step_is_one_loop_of_four_passes_over_one_traced_layer_and_one_head_loop(v5e, as_on_the_chip):
@@ -482,14 +547,15 @@ def _share_chunks_hold_seven_grouped_matmuls(names, text: str, layers: int, rows
 
 
 @pytest.mark.parametrize("model,batch,layers,shape", [
-    ("gpt2_medium", 16, 2, "bf16[16,16,1024,64]"), ("olmoe_1b_7b", 4, 1, "bf16[4,4096,2048]")])
+    ("gpt2_medium", 16, 2, "bf16[16,1024,1024]"), ("olmoe_1b_7b", 4, 1, "bf16[4,4096,2048]")])
 def test_other_steps_keep_their_kernel_names(v5e, as_on_the_chip, monkeypatch, model, batch, layers, shape):
     """The gpt2 and OLMoE cells trace the attention kernels under the names
     they had before the windowed ones existed: ``dvc_flash_fwd`` and
     ``dvc_flash_bwd`` once a scanned layer, forward and backward; the
     recomputed forward holds no kernel; at equal head counts, and no windowed
     name. Since PR 59 OLMoE's are handed the projections' own ``[4, 4096, 16 *
-    128]`` arrays; gpt2's head of 64 keeps ``[B, H, T, D]``."""
+    128]`` arrays, since PR 66 gpt2's too (``[16, 1024, 16 * 64]``: two heads
+    of 64 a block)."""
     from distributedvolunteercomputing_tpu.ops import moe_dispatch
 
     monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
@@ -684,7 +750,9 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
     """medium-solo's step traces with no ``tp`` to divide a layer: the block's
     checkpoint names the kernel's two results and nothing else (on one chip
     attn_out's result costs a product to make again, not an all-reduce), and
-    ``swarm.remat_kept`` reads the kernel's bytes. The four-chip step names
+    ``swarm.remat_kept`` reads the kernel's bytes: since PR 66 the output where
+    ``attn_out`` reads it, ``bf16[16,1024,1024]`` a layer, half of what the
+    by-head ``[16,16,1024,64]`` took at 128 lanes a row. The four-chip step names
     attn_out's reduced result in its traced block and counts a chip's
     ``bf16[16,1024,1280]`` of it a layer beside the kernel's: the bytes it
     counted as one stream, now two halves of the same stacks (the block is
@@ -696,17 +764,20 @@ def test_one_chip_step_names_nothing_more_to_keep(v5e, as_on_the_chip):
             jaxpr = str(_traced_step(v5e, *model_mesh_batch).jaxpr)
         return sorted(set(re.findall(r"name\[name=(\w+)\]", jaxpr))), seen  # the names, however often printed
 
-    def kernel(b, h, t):  # the output's rows at 128 lanes of bf16 and a float32 log-sum-exp a row
+    def kernel(b, h, t):  # by head: the output's rows at 128 lanes of bf16 and a float32 log-sum-exp a row
         return b * h * t * (128 * 2 + 4)
 
-    assert kept("gpt2_medium", 1, 1, 16) == (["attention_lse", "attention_out"], [(2, 2 * kernel(16, 16, 1024))])
+    def merged(b, h, t, d=64):  # PR 66: a row of the kept output is the model's d lanes of bf16, two heads of 64 a tile
+        return b * t * (h * d * 2 + h * 4)
+
+    assert kept("gpt2_medium", 1, 1, 16) == (["attention_lse", "attention_out"], [(2, 2 * merged(16, 16, 1024))])
     assert kept("gpt2_large", 2, 2, 32) == (
         ["attention_lse", "attention_out", "tp_reduced"], [(2, 2 * (kernel(16, 10, 1024) + 41_943_040))])
 
 
 # (model, dp, tp, batch, n_layers, overrides) of each cell's step, and what ``swarm.attention_core`` hears of it
 _CELL_LAYOUTS = {
-    "medium-solo": (("gpt2_medium", 1, 1, 16, 2, {}), {"heads/none": 1}),
+    "medium-solo": (("gpt2_medium", 1, 1, 16, 2, {}), {"merged/none": 1}),  # two heads of 64 a block
     "large-solo-4chip": (("gpt2_large", 2, 2, 32, 2, {}), {"heads/none": 1}),
     "olmoe-solo": (("olmoe_1b_7b", 1, 1, 4, 1, {}), {"merged/kernel": 1}),
     "laguna-solo-8k": (
@@ -757,16 +828,61 @@ def test_which_cells_hand_the_kernels_the_projections_own_arrays(v5e, as_on_the_
     assert tel.traced_summary()["attention_core"] == {"flash": sum(want.values())}
 
 
-def test_one_chip_step_keeps_the_fused_qkv_product(v5e, as_on_the_chip):
-    """medium-solo's step is the program it was: with one chip the projection
-    is one [.., d] x [d, 3d] product whose 3d-wide result feeds the split,
-    and ``common.qkv_heads`` lays nothing out (no head-major weight view, no
+# sha256 (16 digits) of each OTHER cell's lowered step (its kernels' serialised modules included), as the
+# chip would trace it, name stacks only. Read at the parent of PR 66 (da43337, ``git archive`` with this file copied
+# over it) and at the change: a PR that means to change one of these programs reads them again at its parent.
+_LOWERED_AS_AT_THE_PARENT = {
+    "olmoe-solo": "5b9dc2fbe8b901cf", "laguna-solo-8k": "81c4c0af1909080a", "smallthinker-solo-16k": "cfbe8ccf289f955e",
+    "lfm2-solo-8k": "ad3a2efc0d42feb8", "glm47-flash-solo-8k": "bf05df60b5edbf58", "nemotron3-nano-solo-8k": "e9d8feb1a8c3196f",
+    "kimi-linear-solo-8k": "d71a310a813ced9a", "sdar-solo-4k": "440bade2e2273aa6", "ouro-solo-4k": "a00909f76052a41a",
+    # and the four-chip step: over ``tp`` q, k and v are still born by head (``common.qkv_heads``; PERF.md, PR 66)
+    "large-solo-4chip": "e4334fc0b07a1554",
+}
+
+
+@pytest.mark.parametrize("cell", list(_LOWERED_AS_AT_THE_PARENT))
+def test_the_other_cells_lower_to_the_text_the_parent_lowers(v5e, as_on_the_chip, monkeypatch, cell):
+    """PR 66 gives the flash kernels' merged entry a block of two heads of 64
+    and GPT-2's block the merged entry on one chip. A head of whole tiles is
+    one head a block and its kernels are traced to the text they were
+    (``heads_a_block`` == 1); LFM2, Nemotron and Kimi call ``attention_core``
+    themselves; and a step whose mesh divides GPT-2's heads over ``tp`` keeps
+    the by-head projection and entry. So every cell's step program but
+    ``medium-solo``'s (and ``medium-round``'s, the same step), and with it its
+    compile-cache key and its ``tok_s_chip``, is the parent's."""
+    import hashlib
+
+    from distributedvolunteercomputing_tpu.ops import moe_dispatch
+
+    monkeypatch.setattr(moe_dispatch, "tpu_backend", lambda: True)
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul_impl", lambda m, k, n: "megablox")
+    (model, dp, tp, batch, n_layers, overrides), _ = _CELL_LAYOUTS[cell]
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = _lowered_step(v5e, model, dp, tp, batch, n_layers, **overrides).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == _LOWERED_AS_AT_THE_PARENT[cell], f"LOWERED {cell} {got}"
+
+
+def test_one_chip_step_makes_q_k_and_v_by_three_products_off_the_fused_leaf(v5e, as_on_the_chip):
+    """medium-solo's step (PR 66; until then one [.., d] x [d, 3d] product whose
+    3d-wide result fed a split and three ``split_heads``): with one chip q, k
+    and v are three [.., d] x [d, d] products off the fused leaf's three column
+    ranges, each result [16, 1024, 1024] as the kernels read it; no activation
+    3d wide and none by head exists in the lowered step, and
+    ``common.qkv_heads`` lays nothing out (no head-major weight view, no
     sharding constraint on it)."""
     import re
 
     text = _lowered_step(v5e, "gpt2_medium", 1, 1, 16).as_text()
-    assert re.search(r"stablehlo\.dot_general.*-> tensor<16x1024x3072xbf16>", text)
-    assert re.search(r"stablehlo\.slice.*tensor<16x1024x3072xbf16>\) -> tensor<16x1024x1024xbf16>", text)
+    thirds = re.findall(
+        r"stablehlo\.slice.*tensor<1024x3072xbf16>\) -> tensor<1024x1024xbf16>", text)
+    assert len(thirds) >= 3  # the forward's three column ranges (the recomputed forward's again)
+    assert re.search(r"stablehlo\.dot_general.*tensor<16x1024x1024xbf16>, tensor<1024x1024xbf16>\) -> tensor<16x1024x1024xbf16>", text)
+    assert "16x1024x3072xbf16" not in text and "16x16x1024x64" not in text and "16x1024x16x64" not in text
     assert "1024x3x16x64" not in text  # the weight as [d, 3, H, hd]
     assert not re.search(r"sharding_constraint.*x16x64xbf16>", text)
 
