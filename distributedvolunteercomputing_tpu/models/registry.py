@@ -162,6 +162,10 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # 48 dense layers run four times over the same weights, a head and an exit gate after every pass:
     # ``n_layers``, ``max_len`` (``passes`` is not depth and is not cut)
     "ouro_2_6b": ("ouro", "OuroConfig"),
+    # 48 layers (a gated delta rule under one decay a head, every fourth output-gated attention at a head of
+    # 256) of 512 experts beside a gated shared one: ``n_layers`` (whole periods of four), ``experts_held`` /
+    # ``expert_offset``, ``vocab``
+    "qwen3_next_80b_a3b": ("qwen3_next", "Qwen3NextConfig"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 

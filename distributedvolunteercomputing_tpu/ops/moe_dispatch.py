@@ -389,7 +389,8 @@ def _spread(x: jax.Array, where, k: int) -> jax.Array:
 # The three shifted adds it replaced were 12.0 ms (3 x (1.63 + 2.37)) and 4.5.
 _RUN_TILE = 128
 # Rows at a tile's end that a run which crosses the edge can have left behind:
-# at most k - 1, and one sublane tile of them is read (0.10 ms and 0.04).
+# at most k - 1, and one sublane tile of them is read (0.10 ms and 0.04); a
+# router that chooses more than nine a token (Qwen3-Next's ten) reads two.
 _RUN_CARRY = 8
 
 
@@ -415,8 +416,9 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
     perm, tok_sorted, last_pos = where[2:]
     r, d = rows.shape
     t = _RUN_TILE if r % _RUN_TILE == 0 else 8
-    if r % t or not k - 1 <= _RUN_CARRY <= t:
-        raise ValueError(f"a run of {k} rows in {r} rows: tiles of {t} with {_RUN_CARRY} carried do not cover it")
+    carried = -(-max(k - 1, 1) // _RUN_CARRY) * _RUN_CARRY   # whole sublane tiles that hold k - 1 rows
+    if r % t or carried > t:
+        raise ValueError(f"a run of {k} rows in {r} rows: tiles of {t} with {carried} carried do not cover it")
     dtype = rows.dtype
     # Rows that are not held may hold anything (``grouped_matmul`` does not
     # write them), and the 0/1 product below would turn one NaN into a tile of
@@ -433,8 +435,8 @@ def _combine(rows: jax.Array, where, k: int) -> jax.Array:
     tok = tok_sorted.reshape(r // t, t)
     first = tok[:, 0]
     # what a tile's trailing rows leave to the next tile's first run: [R / T, d], float32, none to the first
-    behind = tok[:-1, t - _RUN_CARRY:] == first[1:, None]
-    carry = jnp.einsum("bj,bjd->bd", behind.astype(dtype), tiles[:-1, t - _RUN_CARRY:],
+    behind = tok[:-1, t - carried:] == first[1:, None]
+    carry = jnp.einsum("bj,bjd->bd", behind.astype(dtype), tiles[:-1, t - carried:],
                        preferred_element_type=jnp.float32)
     carry = jnp.pad(carry, ((1, 0), (0, 0)))
     at = jnp.arange(t, dtype=jnp.int32)

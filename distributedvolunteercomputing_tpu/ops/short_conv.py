@@ -331,10 +331,26 @@ def _stream_vjp_bwd(block, interpret, res, dy):
 causal_conv_kernel.defvjp(_stream_vjp_fwd, _stream_vjp_bwd)
 
 
+# What the stream kernels' backward holds in VMEM a position and channel of its block (the compiler's own count:
+# 79.07 MB for a block of 256 positions of 8,192 channels, PR 67): the five double-buffered bf16 blocks and the
+# float32 copies, shifts and slopes made of them.
+_STREAM_VMEM_BYTES = 38
+
+
+def choose_stream_block(t: int, d: int, taps: int) -> Optional[int]:
+    """``choose_block`` for ONE stream of ``d`` channels, halved until the
+    backward kernel's block fits its VMEM: 256 positions up to 6,898 channels
+    (every stream before Qwen3-Next's: 4,096 and 6,144), 128 for its 8,192."""
+    block = choose_block(t, d, taps)
+    while block is not None and block > 32 and block * d * _STREAM_VMEM_BYTES > _VMEM_LIMIT:
+        block //= 2
+    return block
+
+
 def causal_conv(u: jax.Array, taps: jax.Array, bias: jax.Array) -> jax.Array:
     """``silu(conv(u) + bias)`` over ``u`` [batch, T, d]: the kernels on one TPU
     chip where they take the shape, the plain form elsewhere, as ``short_conv``."""
-    block = choose_block(u.shape[1], u.shape[2], taps.shape[0])
+    block = choose_stream_block(u.shape[1], u.shape[2], taps.shape[0])
     if block is None or not tpu_backend() or chips_in_step() > 1:
         return causal_conv_xla(u, taps, bias)
     return causal_conv_kernel(u, taps, bias, block, False)

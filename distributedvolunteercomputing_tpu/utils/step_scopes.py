@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 VOCABULARY: Dict[str, str] = {
     "attention": "attention",
     "kda": "mixer",
+    "gdn": "mixer",
     "conv_mixer": "mixer",
     "mamba": "mixer",
     "mlp": "mlp",

@@ -6,7 +6,8 @@ sequence of 16,384 tokens; ``--config lfm2-24b-a2b``: published layers 0 and
 ``--config glm-4.7-flash``: published layers 0-4, likewise;
 ``--config nemotron-3-nano-30b-a3b``: published blocks 0-6, eight of 128 experts;
 ``--config kimi-linear-48b-a3b``: published layers 1-5, eight of 256 experts, 4,096 tokens;
-``--config sdar-30b-a3b-chat``: published layers 0-4, sixteen of 128 experts, 4,096 data tokens as 8,192 rows): the
+``--config sdar-30b-a3b-chat``: published layers 0-4, sixteen of 128 experts, 4,096 data tokens as 8,192 rows;
+``--config qwen3-next-80b-a3b``: published layers 0-3, sixteen of 512 experts, 8,192 tokens): the
 readings that set ``reference_check`` in ``benchmark/configs/<config>.json``.
 
     chiprun -- python experiments/smallthinker_reference_check.py --seeds 3 --left-out
@@ -73,6 +74,7 @@ GROUPS = {"router": "router", "experts": "experts", "shared": "shared", "conv": 
           **dict.fromkeys(("wq", "wk", "wv", "wo", "q_norm", "k_norm",
                            "wq_a", "wq_b", "wkv_a", "wkv_b", "q_a_norm", "kv_a_norm"), "attention"),
           **dict.fromkeys(("w_qkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "gate_b", "o_norm"), "kda"),
+          **dict.fromkeys(("w_qkvz", "w_ba"), "gdn"),
           **dict.fromkeys(("w_in", "w_out", "conv_w", "conv_b", "a_log", "dt_bias", "d_skip", "norm"), "mamba")}
 
 

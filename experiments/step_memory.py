@@ -45,6 +45,7 @@ CELLS = {
     "ouro-solo-4k": ("ouro_2_6b", 1, 1, 2, {"n_layers": 6, "max_len": 4096}),
     # what the cell's six layers were chosen against (PERF.md section 4): seven hold 561.0 M parameters
     "ouro-seven-layers": ("ouro_2_6b", 1, 1, 2, {"n_layers": 7, "max_len": 4096}),
+    "qwen3-next-solo-8k": ("qwen3_next_80b_a3b", 1, 1, 2, {"n_layers": 4, "experts_held": 16, "vocab": 18992}),
 }
 
 

@@ -281,14 +281,28 @@ _YARDSTICK_PINS = (
     ("test_manifest_as_the_collective_pairs_tests_asserted_it_before_this_cell", "test_yardstick_sdar_moe.py",
      "takes SDAR's four metrics, cell and configuration off the manifest's END to run the older tail tests; PR 64's "
      "entries end it now (checked, with both PRs' entries taken off, in test_yardstick_ouro.py)"),
+    # PR 67 (qwen3-next-80b-a3b, qwen3-next-solo-8k, gdn.device_ms / gdn.roofline / gdn.carry_share; the cell appended
+    # to tok_s_chip's list and to twenty-nine per-layer lists): tests/yardstick/test_yardstick_qwen3_next.py asserts
+    # what each of these asserted, against the manifest less this PR's entries, which it takes off BY NAME: its own
+    # manifest test pins no position, so the next PR's entries move nothing there.
+    ("test_configuration_file_is_what_the_program_runs", "[qwen3-next-80b-a3b]",
+     "asserts reduced == []; qwen3-next-80b-a3b lists its cut (checked in test_yardstick_qwen3_next.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics", "test_yardstick_ouro.py",
+     "asserts that ouro-solo-4k ends the manifest, Ouro's three metrics per_layer and its lists; PR 67 appended three "
+     "metrics, a cell and a configuration (checked in test_yardstick_qwen3_next.py)"),
+    ("test_manifest_as_the_sdar_tests_asserted_it_before_this_cell", "test_yardstick_ouro.py",
+     "takes Ouro's three metrics, cell and configuration off the manifest's END to run the older tail tests; PR 67's "
+     "entries end it now, and what it takes off instead leaves lists that name a cell the view no longer has: a "
+     "ManifestError from check() (checked, with this PR's entries taken off by name, in test_yardstick_qwen3_next.py)",
+     ValueError),
 )
 
 
 def _mark_yardstick_pins_a_new_cell_moves(items):
     for item in items:
-        for test, where, reason in _YARDSTICK_PINS:
+        for test, where, reason, *also in _YARDSTICK_PINS:   # ``also``: what the pin raises besides an AssertionError
             if getattr(item, "originalname", None) == test and where in item.nodeid:
-                item.add_marker(pytest.mark.xfail(reason=reason, raises=AssertionError, strict=True))
+                item.add_marker(pytest.mark.xfail(reason=reason, raises=(AssertionError, *also), strict=True))
 
 
 # Every program jax compiles holds memory maps of its own until its cache drops it, and a test that runs a model

@@ -295,6 +295,8 @@ RUNS = {
     "one_tile_of_128": (128, 6, [(0, 6), (120, 6)]),
     "two_tiles_of_128": (256, 6, [(100, 6), (123, 6), (245, 5)]),            # 123 .. 128: k - 1 behind, ends on row 128
     "two_tiles_of_128_k_of_8": (256, 8, [(121, 8)]),
+    # Qwen3-Next's ten a token: nine rows behind the edge, more than one sublane tile of carry (PR 67)
+    "two_tiles_of_128_k_of_10": (256, 10, [(119, 10), (200, 10)]),
     "three_tiles_of_128": (384, 6, [(127, 2), (252, 6), (300, 1)]),
 }
 

@@ -800,6 +800,8 @@ _CELL_LAYOUTS = {
         ("sdar_30b_a3b", 1, 1, 2, 5, dict(experts_held=16, vocab=18992, mask_id=18991)), {"merged/kernel": 1}),
     "ouro-solo-4k": (  # one scanned layer, traced once inside the loop over the four passes
         ("ouro_2_6b", 1, 1, 2, 6, dict(max_len=4096)), {"merged/kernel": 1}),
+    "qwen3-next-solo-8k": (  # the period's one attention layer: q and k turned BESIDE the kernels, as GLM's (D = 256)
+        ("qwen3_next_80b_a3b", 1, 1, 2, 4, dict(experts_held=16, vocab=18992)), {"merged/none": 1}),
 }
 
 
@@ -1133,3 +1135,74 @@ def test_the_delta_rule_scan_and_a_value_head_of_128_under_keys_of_192_compile_a
     fwd, bwd = (next(ln for ln in calls if name in ln) for name in ("dvc_flash_fwd", "dvc_flash_bwd"))
     assert len(calls) == 2 and fwd.split(" custom-call(")[0].count("bf16[2,32,8192,128]") == 1    # o at the value width
     assert bwd.split(" custom-call(")[0].count("bf16[2,32,8192,192]") == 2 and "bf16[2,32,8192,128]" in bwd.split(" custom-call(")[0]
+
+
+def test_the_scalar_decay_scan_the_8192_channel_convolution_and_grouped_attention_at_256_compile_at_the_published_mixers(v5e, as_on_the_chip):
+    """qwen3-next-solo-8k's new mixers by the chip's own compiler: the
+    scalar-decay delta rule forward and backward at 16 key heads and 32 value
+    heads of 128, chunks of 64, two sequences of 8,192, fed the convolution's ONE
+    [2, 8192, 8192] array (XLA's loops over the 128 chunks, each carrying the
+    value heads' [2, 32, 128, 128] float32 states: what ``benchmark/gdn_trace.py``
+    tells them by in a trace, and the whole streams a loop carries what it tells a
+    backward loop from a forward one by); the one-stream convolution at 8,192
+    channels (a block of 128 positions: 256 does not fit the backward's VMEM);
+    and the flash kernels at a head of 256, sixteen query heads over two."""
+    from benchmark import gdn_trace, kda_trace
+    from distributedvolunteercomputing_tpu.ops import gdn, pallas_attention, short_conv
+
+    one = SingleDeviceSharding(v5e[0])
+    z, t, hk, hv, d, chunk = 2, 8192, 16, 32, 128, 64
+    qkv = jax.ShapeDtypeStruct((z, t, 2 * hk * d + hv * d), jnp.bfloat16, sharding=one)
+    by_value_head = jax.ShapeDtypeStruct((z, t, hv), jnp.float32, sharding=one)
+    rows = jax.ShapeDtypeStruct((z, t, hv * d), jnp.bfloat16, sharding=one)
+
+    def scan_fwd_bwd(qkv, g, beta, do):
+        o, vjp = jax.vjp(lambda *a: gdn.gdn_with_sums(*a, hk, hv, d, chunk)[0], qkv, g, beta)
+        return o, vjp(do)
+
+    compiled = jax.jit(scan_fwd_bwd).lower(qkv, by_value_head, by_value_head, rows).compile()
+    text = compiled.as_text()
+    scans = [shapes for shapes in (kda_trace.carried(ln.strip()) for ln in text.splitlines() if " while(" in ln)
+             if (z, hv, d, d) in shapes]
+    whole = sorted(sum(len(s) == 3 and s[:2] == (z, t) for s in shapes) for shapes in scans)
+    # one loop forward and one backward, told by the whole streams they carry: qkv, g, beta and o forward; the three,
+    # dO and the three cotangents backward (benchmark/gdn_trace.FORWARD_CARRIES_AT_MOST lies between)
+    assert len(scans) == 2 and whole == [4, 7], (len(scans), whole)
+    assert whole[0] <= gdn_trace.FORWARD_CARRIES_AT_MOST < whole[1]
+    # q and k are never at the value heads' count at a stream's size, the decay never by channel: of [2, 8192, 4096]
+    # there are o and dO (and the cotangent's v part is written into the ONE [2, 8192, 8192]), nothing float32 of
+    # that size and nothing by head
+    assert f"f32[{z},{t},{hv * d}]" not in text and f"[{z},{t},{hv},{d}]" not in text and f"[{z},{hv},{t},{d}]" not in text
+    for shapes, want in zip(sorted(scans, key=len), (1, 2)):    # forward: o and qkv; backward: dO, qkv and its cotangent
+        assert sum(s == (z, t, hv * d) for s in shapes) == 1 and sum(s == (z, t, 2 * hk * d + hv * d) for s in shapes) == want
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.6e9      # the states: 128 x 2 x 32 x 128 x 128 float32
+
+    channels = 2 * hk * d + hv * d
+    assert short_conv.choose_stream_block(t, channels, 4) == 128 and short_conv.choose_stream_block(t, 6144, 4) == 256
+    assert short_conv.choose_stream_block(t, 4096, 4) == short_conv.choose_block(t, 4096, 4) == 256
+    w = jax.ShapeDtypeStruct((4, channels), jnp.float32, sharding=one)
+    bias = jax.ShapeDtypeStruct((channels,), jnp.float32, sharding=one)
+
+    def conv_fwd_bwd(u, w, bias, dy):
+        y, vjp = jax.vjp(lambda *a: short_conv.causal_conv_kernel(*a, 128, False), u, w, bias)
+        return y, vjp(dy)
+
+    names = _kernel_names(_kernel_calls(jax.jit(conv_fwd_bwd).lower(qkv, w, bias, qkv).compile().as_text()))
+    assert len(names) == 2 and sum("dvc_short_conv_fwd" in n for n in names) == 1
+    assert sum("dvc_short_conv_bwd" in n for n in names) == 1
+
+    h, kv, hd = 16, 2, 256
+    assert pallas_attention.choose_blocks(t, t, hd, jnp.bfloat16) == (1024, 1024)
+    assert pallas_attention.choose_blocks(t, t, hd, jnp.bfloat16, None, True) is None      # no room for a turn on the tile
+    q = jax.ShapeDtypeStruct((z, t, h * hd), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((z, t, kv * hd), jnp.bfloat16, sharding=one)
+    assert pallas_attention.heads_a_block(hd, hd, h, kv) == 1
+
+    def attention_fwd_bwd(q, k, v, do):   # as attention_merged hands them on one chip: no rotary (q and k come turned)
+        o, vjp = jax.vjp(lambda *a: pallas_attention.flash_attention_merged(*a, None, None, (h, kv), True, None, None, False),
+                         q, k, v)
+        return o, vjp(do)
+
+    calls = _kernel_calls(jax.jit(attention_fwd_bwd).lower(q, k, k, q).compile().as_text())
+    names = _kernel_names(calls)
+    assert sum("dvc_flash_fwd" in n for n in names) == 1 and sum("dvc_flash_bwd" in n for n in names) == 1
