@@ -40,12 +40,36 @@ cotangent the same way; no slice of a stream's size is ever made), the log decay
 ``g`` and ``beta`` [batch, T, Hv] float32. q and k come UN-NORMED; the step norms
 them on the chunk it holds (q to length K^-1/2, k to length 1), sums the decay
 down the chunk's rows and folds beta in float32. The backward is the reverse
-scan that carries the states' cotangent, recomputes a chunk from the raw streams
-and the states that entered it (float32, kept by the forward: [T / C, batch, Hv,
-V, K]) and hands its cotangents to JAX's transpose of the norms and the running
-sum. There is no kernel: XLA's loops, on every backend, as ``ops/kda.py`` says of
-its own. A sequence that is no whole number of chunks is padded with positions
-that neither decay nor write (``g`` 0, ``beta`` 0) and cut again.
+scan that carries the states' cotangent, recomputes a chunk from the raw streams,
+the states that entered it (float32, kept by the forward: [T / C, batch, Hv, V,
+K]) and the chunk's ``T = (I + L)^-1`` (in the compute dtype, kept by the forward
+too: [T / C, batch, Hk, R, C, C], a scan's ``xs``) and hands its cotangents to
+JAX's transpose of the norms and the running sum. There is no kernel: XLA's
+loops, on every backend, as ``ops/kda.py`` says of its own. A sequence that is no
+whole number of chunks is padded with positions that neither decay nor write
+(``g`` 0, ``beta`` 0) and cut again.
+
+**What of a chunk leaves the backward loop, what does not leave either loop, and
+why (PR 68).** Of a chunk, everything but ``S_0`` is a function of the streams
+alone, and the inverse's levels (two [C, C] x [C, C] products each, ten fusions a
+step) were 46 of the loops' 115 ms in ``qwen3-next-solo-8k``. The backward needs
+the same ``T`` the forward made: the layer is rematerialised, so the recomputed
+forward hands it over by chunk (67 MB in bfloat16 at the cell's shape, alive for
+one layer's backward) and the backward loop makes no inverse: 6.6 ms less a
+layer's backward on the chip. What was ALSO tried, and is not here because the
+chip said no (``PERF.md`` section 6, PR 68; ``experiments/results/
+pr68_gdn_sweep.jsonl``): making a pass's in-chunk matrices, norms and inverses for
+all its chunks at once outside the ``lax.scan``, as batched products under one
+more ``jax.vmap`` (in blocks of 1 to 32 chunks), the loops keeping only what
+reads or writes the carried state. The loops fell from 115 to 46 ms a step and
+the step ROSE from 416 to 498 ms: inside a loop a chunk's [batch, Hk, R, C, C]
+matrices stay in the chip's vector memory from one small fusion to the next and a
+level's product over 64 matrices takes 5 us; outside, the same product over a
+block's 2,048 matrices reads and writes them through HBM and takes 0.18 us a
+matrix where the loop's takes 0.08. The inverse alone outside (forward 10.9 ms a
+layer at its best block of 16, against 9.8 with it inside) loses too. So the
+loops keep their chunk's own work, and only what a later pass would make AGAIN
+is handed over.
 """
 
 from __future__ import annotations
@@ -86,42 +110,48 @@ def _levels(lower):
     return [jnp.where(kda._pairs(row, col, shift), lower, 0.0) for shift in kda._shifts(lower.shape[0])]
 
 
-def _recomputed(st, q, k, v, gc, beta, a, bq):
-    """What both passes make of a chunk: (D, L, B, T = (I + L)^-1 [C, C] float32;
-    e^G, e^{G_C - G} [C, 1] and e^{G_C}; [q S_0 ; k S_0] [2 C, V]; v - e^G (k S_0);
-    U [C, V] in the compute dtype)."""
-    dtype = q.dtype
-    c = q.shape[0]
-    row, col = _grid(c)
+def _in_chunk(gc, beta, a, bq):
+    """(D, L strictly lower, B lower with its diagonal: [C, C] float32) of a value head's ``gc``, ``beta`` [C] and its key head's products."""
+    row, col = _grid(gc.shape[0])
     d = _decay(gc)
-    lower = jnp.where(row > col, beta[:, None] * a * d, 0.0)
-    b = bq * d
-    t = kda._inverse(_levels(lower), dtype == _F32)
+    return d, jnp.where(row > col, beta[:, None] * a * d, 0.0), bq * d
+
+
+def _around(st, t, q, k, v, gc, beta):
+    """What both passes make of a chunk around its ``T = (I + L)^-1`` [C, C] (in
+    the compute dtype) and the state that enters: (e^G, e^{G_C - G} [C, 1] and
+    e^{G_C}; [q S_0 ; k S_0] [2 C, V]; v - e^G (k S_0); U [C, V] in the compute
+    dtype)."""
+    dtype = q.dtype
     e, ew, ec = jnp.exp(gc)[:, None], jnp.exp(gc[-1] - gc)[:, None], jnp.exp(gc[-1])
     held = _dot(jnp.concatenate([q, k], axis=0), st.astype(dtype), _NT)        # [q S_0 ; k S_0]
-    w = v.astype(_F32) - e * held[c:]
-    u = _dot(t.astype(dtype), (beta[:, None] * w).astype(dtype), _NN).astype(dtype)
-    return d, lower, b, t, e, ew, ec, held, w, u
+    w = v.astype(_F32) - e * held[q.shape[0]:]
+    u = _dot(t, (beta[:, None] * w).astype(dtype), _NN).astype(dtype)
+    return e, ew, ec, held, w, u
 
 
 def _value_head_fwd(st, v, gc, beta, a, bq, q, k):
-    """(o [C, V] in the compute dtype, the state that leaves [V, K] float32)."""
+    """(o [C, V] in the compute dtype, the state that leaves [V, K] float32, the
+    chunk's ``T`` [C, C] in the compute dtype: what the backward takes over)."""
     dtype = q.dtype
-    _, _, b, _, e, ew, ec, held, _, u = _recomputed(st, q, k, v, gc, beta, a, bq)
+    _, lower, b = _in_chunk(gc, beta, a, bq)
+    t = kda._inverse(_levels(lower), dtype == _F32).astype(dtype)
+    e, ew, ec, held, _, u = _around(st, t, q, k, v, gc, beta)
     o = e * held[:q.shape[0]] + _dot(b.astype(dtype), u, _NN)
     st_new = ec * st + _dot(u, (k.astype(_F32) * ew).astype(dtype), _TN)
-    return o.astype(dtype), st_new
+    return o.astype(dtype), st_new, t
 
 
-def _value_head_bwd(dst, st, v, gc, beta, do, a, bq, q, k):
+def _value_head_bwd(dst, st, t, v, gc, beta, do, a, bq, q, k):
     """Cotangents (that of the state that entered [V, K]; dv [C, V], dgc, dbeta
     [C] float32; this value head's part of dq, dk [C, K] and of da, dbq [C, C],
     float32) from ``do`` and the cotangent ``dst`` of the state that left; the
-    chunk's forward computed again from ``st``."""
+    chunk's forward computed again from ``st`` around the forward's own ``t``."""
     dtype = q.dtype
     c = q.shape[0]
     row, col = _grid(c)
-    d, lower, b, t, e, ew, ec, held, w, u = _recomputed(st, q, k, v, gc, beta, a, bq)
+    d, lower, b = _in_chunk(gc, beta, a, bq)
+    e, ew, ec, held, w, u = _around(st, t, q, k, v, gc, beta)
     kf = k.astype(_F32)
     stb, dstb, dof = st.astype(dtype), dst.astype(dtype), do.astype(_F32)
     kh = kf * ew
@@ -130,7 +160,7 @@ def _value_head_bwd(dst, st, v, gc, beta, do, a, bq, q, k):
     db = jnp.where(row >= col, _dot(do, u, _NT), 0.0)
     dkh = _dot(u, dstb, _NN)
     # U = (I + L)^-1 X:  dX = (I + L)^-T dU,  dL = -dX U^T;  X = beta (v - e (k S))
-    dx = _dot(t.astype(dtype), du.astype(dtype), _TN)
+    dx = _dot(t, du.astype(dtype), _TN)
     dlower = jnp.where(row > col, -_dot(dx.astype(dtype), u, _NT), 0.0)
     # the two reads of the state, [q S ; k S], and their cotangents down the rows of one product each way
     d_held = jnp.concatenate([e * dof, -(beta[:, None] * e) * dx], axis=0).astype(dtype)
@@ -150,7 +180,7 @@ def _value_head_bwd(dst, st, v, gc, beta, do, a, bq, q, k):
 # ---------------------------------------------------------------------------
 # one chunk of one KEY head with its value heads, as the mixer's streams hold it
 # ---------------------------------------------------------------------------
-# q, k [C, K] un-normed; v, o, do [C, R V]; g, beta [C, R]; the states [R, V, K]
+# q, k [C, K] un-normed; v, o, do [C, R V]; g, beta [C, R]; the states [R, V, K], their chunk's t [R, C, C]
 
 
 def _prepare(q, k, g):
@@ -173,16 +203,16 @@ def _side_by_side(a):
 
 
 def _group_fwd(st, q, k, v, g, beta):
-    """(the states that leave [R, V, K], the chunk's summed log decays [R], o [C, R V])."""
+    """(the states that leave [R, V, K], the chunk's summed log decays [R], its T [R, C, C]; o [C, R V])."""
     r = st.shape[0]
     q, k, gc = _prepare(q, k, g)
     a, bq = _dot(k, k, _NT), _dot(q, k, _NT)             # once a key head, unscaled
-    o, st_new = jax.vmap(_value_head_fwd, in_axes=(0, 0, 1, 1, None, None, None, None))(
+    o, st_new, t = jax.vmap(_value_head_fwd, in_axes=(0, 0, 1, 1, None, None, None, None))(
         st, _by_value_head(v, r), gc, beta.astype(_F32), a, bq, q, k)
-    return st_new, gc[-1], _side_by_side(o)
+    return st_new, gc[-1], t, _side_by_side(o)
 
 
-def _group_bwd(dst, st, q, k, v, g, beta, do):
+def _group_bwd(dst, st, t, q, k, v, g, beta, do):
     """(The cotangent of the states that entered; the raw chunk's dq, dk [C, K],
     dv [C, R V], dg, dbeta [C, R], each in its stream's dtype): the preparation
     differentiated by JAX around the value heads' hand-written cotangents, whose
@@ -193,8 +223,8 @@ def _group_bwd(dst, st, q, k, v, g, beta, do):
     dtype = qn.dtype
     a, bq = _dot(kn, kn, _NT), _dot(qn, kn, _NT)
     dst_prev, dv, dgc, dbeta, dq, dk, da, dbq = jax.vmap(
-        _value_head_bwd, in_axes=(0, 0, 0, 1, 1, 0, None, None, None, None))(
-        dst, st, _by_value_head(v, r), gc, beta.astype(_F32), _by_value_head(do, r), a, bq, qn, kn)
+        _value_head_bwd, in_axes=(0, 0, 0, 0, 1, 1, 0, None, None, None, None))(
+        dst, st, t, _by_value_head(v, r), gc, beta.astype(_F32), _by_value_head(do, r), a, bq, qn, kn)
     da, dbq = jnp.sum(da, axis=0), jnp.sum(dbq, axis=0)
     # a = k k^T, bq = q k^T: [da + da^T ; dbq] k is (dk's part, dq's), and dbq^T q the rest of dk's
     back_k = _dot(jnp.concatenate([da + da.T, dbq], axis=0).astype(dtype), kn, _NN)
@@ -228,33 +258,34 @@ def _chunk_by_key_head(qkv, i, chunk: int, kd: int, key_heads: int):
 
 
 def _scan_fwd(qkv, g, beta, key_heads: int, value_heads: int, key_dim: int, chunk: int):
-    """(o [Z, T, Hv V], every chunk's summed log decay [nc, Z, Hv] float32, the
-    states entering each chunk [nc, Z, Hv, V, K] float32)."""
+    """(o [Z, T, Hv V], every chunk's summed log decay [nc, Z, Hv] float32; what
+    the backward takes over: the states entering each chunk [nc, Z, Hv, V, K]
+    float32 and every chunk's T [nc, Z, Hk, R, C, C] in ``qkv``'s dtype)."""
     z, t, _ = qkv.shape
     kd, vd, by_key_head = _layout(qkv, key_heads, value_heads, key_dim)
-    step_fn = kda._over_heads(_group_fwd, (1, 2), (5, 1))
+    step_fn = kda._over_heads(_group_fwd, (1, 3), (5, 1))
 
     def step(carry, i):
         st, o = carry
-        st_new, total, o_i = step_fn(st.reshape(by_key_head), *_chunk_by_key_head(qkv, i, chunk, kd, key_heads),
-                                     kda._chunk_of(g, i, chunk, key_heads), kda._chunk_of(beta, i, chunk, key_heads))
-        return (st_new.reshape(st.shape), kda._put_chunk(o, o_i, i, chunk)), (total.reshape(z, value_heads), st)
+        st_new, total, t_i, o_i = step_fn(st.reshape(by_key_head), *_chunk_by_key_head(qkv, i, chunk, kd, key_heads),
+                                          kda._chunk_of(g, i, chunk, key_heads), kda._chunk_of(beta, i, chunk, key_heads))
+        return (st_new.reshape(st.shape), kda._put_chunk(o, o_i, i, chunk)), (total.reshape(z, value_heads), st, t_i)
 
     start = (jnp.zeros((z, value_heads, vd // value_heads, key_dim), _F32), jnp.zeros((z, t, vd), qkv.dtype))
-    (_, o), (sums, states) = jax.lax.scan(step, start, kda._chunk_indices(t // chunk))
-    return o, sums, states
+    (_, o), (sums, *kept) = jax.lax.scan(step, start, kda._chunk_indices(t // chunk))
+    return o, sums, kept
 
 
-def _scan_bwd(qkv, g, beta, states, do, key_heads: int, value_heads: int, key_dim: int, chunk: int):
+def _scan_bwd(qkv, g, beta, states, inverses, do, key_heads: int, value_heads: int, key_dim: int, chunk: int):
     z = qkv.shape[0]
     kd, _, by_key_head = _layout(qkv, key_heads, value_heads, key_dim)
-    step_fn = kda._over_heads(_group_bwd, (2, 1), (6, 5))
+    step_fn = kda._over_heads(_group_bwd, (3, 1), (6, 5))
 
     def step(carry, xs):
         dst, (dqkv, dg, dbeta) = carry
-        i, st = xs
+        i, st, t_i = xs
         dst_prev, dq, dk, dv, dg_i, dbeta_i = step_fn(
-            dst.reshape(by_key_head), st.reshape(by_key_head), *_chunk_by_key_head(qkv, i, chunk, kd, key_heads),
+            dst.reshape(by_key_head), st.reshape(by_key_head), t_i, *_chunk_by_key_head(qkv, i, chunk, kd, key_heads),
             *(kda._chunk_of(a, i, chunk, key_heads) for a in (g, beta, do)))
         # the chunk's rows of the ONE cotangent, q's, k's and v's channels side by side as the stream holds them
         rows = jnp.concatenate([a.reshape(z, chunk, -1) for a in (dq, dk, dv)], axis=-1)
@@ -263,7 +294,7 @@ def _scan_bwd(qkv, g, beta, states, do, key_heads: int, value_heads: int, key_di
         return (dst_prev.reshape(dst.shape), grads), None
 
     start = (jnp.zeros(states.shape[1:], _F32), tuple(jnp.zeros_like(a) for a in (qkv, g, beta)))
-    (_, grads), _ = jax.lax.scan(step, start, (kda._chunk_indices(states.shape[0]), states), reverse=True)
+    (_, grads), _ = jax.lax.scan(step, start, (kda._chunk_indices(states.shape[0]), states, inverses), reverse=True)
     return grads
 
 
@@ -276,8 +307,8 @@ def core(qkv, g, beta, key_heads: int, value_heads: int, key_dim: int, chunk: in
 
 
 def _core_fwd(qkv, g, beta, key_heads, value_heads, key_dim, chunk):
-    o, sums, states = _scan_fwd(qkv, g, beta, key_heads, value_heads, key_dim, chunk)
-    return (o, sums), (qkv, g, beta, states)
+    o, sums, kept = _scan_fwd(qkv, g, beta, key_heads, value_heads, key_dim, chunk)
+    return (o, sums), (qkv, g, beta, *kept)
 
 
 core.defvjp(_core_fwd, lambda key_heads, value_heads, key_dim, chunk, res, d:

@@ -7,8 +7,11 @@ decay broadcast over a head's 128 channels and q, k repeated to the 32 value
 heads (what the model would run without a form of its own: the oracle of
 ``tests/test_gdn.py``), so that what the second form buys is read on the chip.
 
-    chiprun -- python experiments/gdn_sweep.py
+    chiprun -- python experiments/gdn_sweep.py [--scalar-only]
     python experiments/gdn_sweep.py --shape 2,40,2,4,16,16 --iters 1
+
+``--scalar-only`` leaves the per-channel form and the float32 comparison out
+(PR 68 timed three forms of this module's loops with it, a minute a form).
 
 A shape is ``batch,T,key_heads,value_heads,head_dim,chunk``. Timed in bf16, at
 decays the model is initialised with (``exp(A_log)`` in (0, 16), ``dt``
@@ -68,6 +71,7 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--exact-tokens", type=int, default=1024)
     ap.add_argument("--out", default="chiprun_out/gdn_sweep.json")
+    ap.add_argument("--scalar-only", action="store_true")
     args = ap.parse_args()
     z, t, hk, hv, d, chunk = (int(n) for n in args.shape.split(","))
     dev = jax.devices()[0]
@@ -82,18 +86,21 @@ def main() -> int:
 
     args_, probe = streams(z, t, hk, hv, d, dtype)
     for name, fn in forms(hk, hv, d, chunk).items():
+        if args.scalar_only and name != "scalar_decay":
+            continue
         fwd = jax.jit(fn)
         both = jax.jit(lambda *a, fn=fn: jax.vjp(fn, *a)[1](probe))
         say(what=name, forward_ms=_time(fwd, args_, args.iters), forward_and_gradients_ms=_time(
             lambda *a, both=both, fwd=fwd: (fwd(*a), both(*a)), args_, args.iters))
-    # the two forms against each other, float32, over the first tokens
-    n = min(args.exact_tokens, t)
-    exact, probe32 = streams(z, n, hk, hv, d, jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        got = {name: (fn(*exact), *jax.vjp(fn, *exact)[1](probe32)) for name, fn in forms(hk, hv, d, chunk).items()}
-    far = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)), 1e-30))  # noqa: E731
-    say(what="scalar_decay against per_channel_broadcast, float32", tokens=n,
-        **{name: far(a, b) for name, a, b in zip(NAMES, got["scalar_decay"], got["per_channel_broadcast"])})
+    if not args.scalar_only:
+        # the two forms against each other, float32, over the first tokens
+        n = min(args.exact_tokens, t)
+        exact, probe32 = streams(z, n, hk, hv, d, jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            got = {name: (fn(*exact), *jax.vjp(fn, *exact)[1](probe32)) for name, fn in forms(hk, hv, d, chunk).items()}
+        far = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)), 1e-30))  # noqa: E731
+        say(what="scalar_decay against per_channel_broadcast, float32", tokens=n,
+            **{name: far(a, b) for name, a, b in zip(NAMES, got["scalar_decay"], got["per_channel_broadcast"])})
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "a") as fh:
         fh.writelines(json.dumps(rec) + "\n" for rec in lines)

@@ -170,3 +170,77 @@ def test_nothing_of_a_streams_size_is_at_the_value_heads_count_but_v_and_o():
     # the forward step's two unscaled in-chunk products: [C, K] x [C, K]^T under the two vmaps (sequences, key heads)
     assert text.count(f"f32[{z},{HK},16,16] = dot_general") == 2
     assert f"f32[{z},{HV},16,16] = dot_general" not in text
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _level_products_by_scan(jaxpr, c):
+    """For each scan that carries the value heads' states, in the program's order: how many products of two [C, C] matrices its body holds."""
+    carries_states = lambda e: e.primitive.name == "scan" and any(  # noqa: E731
+        tuple(v.aval.shape) == (2, HV, DV, DK) for v in e.outvars[:e.params["num_carry"]])
+    return [sum(p.primitive.name == "dot_general" and all(tuple(v.aval.shape)[-2:] == (c, c) for v in p.invars)
+                for sub in jax.core.jaxprs_in_params(e.params) for p in _eqns(sub))
+            for e in _eqns(jaxpr) if carries_states(e)]
+
+
+def test_the_backward_loop_takes_the_inverse_over_and_makes_none():
+    """What shows that the mechanism engaged, in the traced program: a chunk's
+    inverse (levels of two products of two [C, C] matrices each) is made by the
+    FORWARD loop alone and handed to the backward as every chunk's T by chunk;
+    the backward loop's body holds no product of two [C, C] matrices."""
+    (qkv, g, beta), probe = streams(t=64)
+    c = 4                   # chunks of 4 (no head's width, 8 or 16, is a chunk's): one level of two products past the first
+    assert _level_products_by_scan(jax.make_jaxpr(scalar_form(c))(qkv, g, beta).jaxpr, c) == [2]
+    grad = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(scalar_form(c)(*a) * probe), argnums=(0, 1, 2)))(qkv, g, beta)
+    assert _level_products_by_scan(grad.jaxpr, c) == [2, 0]
+    # the backward scan's xs: the chunks' numbers, the states that entered and T [nc, z, Hk, R, C, C]
+    backward = [e for e in _eqns(grad.jaxpr) if e.primitive.name == "scan" and e.params["reverse"]]
+    assert len(backward) == 1 and (16, 2, HK, HV // HK, c, c) in [tuple(v.aval.shape) for v in backward[0].invars]
+
+
+@pytest.mark.parametrize("tokens, dtype, tol", [(96, jnp.float32, (2e-6, 5e-6)), (72, jnp.float32, (2e-6, 5e-6)),
+                                                (80, jnp.bfloat16, (3e-2, 6e-2))],
+                         ids=["six_chunks", "four_chunks_and_a_half", "five_chunks_in_bfloat16"])
+def test_over_several_chunks_with_the_carried_state_alive_the_form_is_the_recurrence(tokens, dtype, tol):
+    """Four chunks of 16 and more, the state carried across every boundary
+    (``carry_share`` 1 at these decays), whole and with a padded tail, float32 at
+    the tolerances of the 2.5-chunk case and bfloat16 at those of the 3-chunk
+    one: ``o`` and the three gradients against the float32 recurrence token by
+    token, so that the T a chunk's backward takes over from its forward meets the
+    cotangent the reverse loop carries at every boundary."""
+    args, probe = streams(seed=4, t=tokens)
+    assert float(gdn.scan_counters(gdn.gdn_with_sums(*args, HK, HV, DK, 16)[1])["carry_share"]) == 1.0
+    want, want_grads = value_and_grads(token_by_token, args, probe)
+    got, grads = value_and_grads(scalar_form(16), (args[0].astype(dtype), args[1], args[2]), probe.astype(dtype))
+    assert got.dtype == dtype and grads[0].dtype == dtype and grads[1].dtype == jnp.float32
+    close(got, want, tol[0], "o")
+    for name, a, b in zip(NAMES, grads, want_grads):
+        close(a, b, tol[1], f"d {name}")
+
+
+@pytest.mark.parametrize("lo, hi, dtype, tol", [(1e-3, 0.3, jnp.float32, 1e-5), (0.7, 4.0, jnp.float32, 1e-5),
+                                                (1e-3, 0.3, jnp.bfloat16, 2e-2)],
+                         ids=["mild", "a_chunk_sums_below_minus_88", "bfloat16"])
+def test_what_the_forward_hands_the_backward_is_the_inverse_of_one_plus_l(lo, hi, dtype, tol):
+    """Every chunk's T [nc, z, Hk, R, C, C] as ``_scan_fwd`` keeps it for the
+    backward, against ``numpy.linalg.inv(I + L)`` of ``L`` built from the
+    definition in float64 (k normed, the decay a difference of running sums),
+    three chunks of 16: float32 at mild and at strong decays, and in bfloat16
+    (the levels' products round their operands) to bfloat16's rounding."""
+    (qkv, g, beta), _ = streams(seed=6, t=48, lo=lo, hi=hi, dtype=dtype)
+    kept = gdn._scan_fwd(qkv, g, beta, HK, HV, DK, 16)[2]
+    assert kept[0].shape == (3, 2, HV, DV, DK) and kept[1].shape == (3, 2, HK, HV // HK, 16, 16) and kept[1].dtype == dtype
+    k = np.asarray(qkv[..., HK * DK:2 * HK * DK].astype(jnp.float32), np.float64).reshape(2, 3, 16, HK, DK)
+    k = k / np.sqrt((k * k).sum(-1, keepdims=True) + kda.L2_EPS)
+    gc = np.cumsum(np.asarray(g, np.float64).reshape(2, 3, 16, HV), axis=2)
+    for z, n, h in [(0, 0, 0), (1, 2, 3), (0, 1, 2)]:
+        kk, gg, bb = k[z, n, :, h // 2], gc[z, n, :, h], np.asarray(beta, np.float64).reshape(2, 3, 16, HV)[z, n, :, h]
+        lower = np.tril(bb[:, None] * (kk @ kk.T) * np.exp(np.minimum(gg[:, None] - gg[None, :], 0.0)), -1)
+        want = np.linalg.inv(np.eye(16) + lower)
+        np.testing.assert_allclose(np.asarray(kept[1][n, z, h // 2, h % 2], np.float64), want, rtol=0,
+                                   atol=tol * np.abs(want).max(), err_msg=str((z, n, h)))

@@ -491,6 +491,42 @@ def _kernel_names(calls):
     return [re.match(r"\s*%([\w.\-]+) =", ln).group(1) for ln in calls]
 
 
+def _level_products_by_loop(text: str, state, c: int) -> list:
+    """For each ``while`` loop that carries an array of ``state``'s shape, in the
+    program's order: how many products of two [.., c, c] matrices (a level of a
+    chunk's triangular inverse) its body and every computation that reaches hold.
+    A product is a ``convolution`` to the TPU compiler, alone or inside a fusion."""
+    import re
+
+    from benchmark import kda_trace
+
+    comps, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{$", ln)
+        if head and not ln.startswith(" "):
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(ln)
+    counts = []
+    for ln in text.splitlines():
+        if " while(" not in ln or state not in kda_trace.carried(ln.strip()):
+            continue
+        reached = [re.search(r"body=%?([\w.\-]+)", ln).group(1)]
+        for comp in reached:    # grows as it is walked: what the body calls, and what that calls
+            reached += [called for inner in comps[comp]
+                        for called in re.findall(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", inner) if called not in reached]
+        n = 0
+        for comp in reached:
+            shapes = {m.group(1): m.group(2).split(",")[-2:]        # an instruction's name -> the last two of its result's dims
+                      for m in (re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]", inner) for inner in comps[comp]) if m}
+            products = [re.findall(r"%[\w.\-]+", inner.split(" convolution(", 1)[1].split(")", 1)[0])
+                        for inner in comps[comp] if " convolution(" in inner]
+            n += sum(all(shapes[name] == [str(c), str(c)] for name in pair) for pair in products)
+        counts.append(n)
+    return counts
+
+
 def _no_pass_over_a_head_shaped_array(text: str, b: int, t: int, heads, d: int = 128, float32_too: bool = True) -> None:
     """No instruction of the compiled step's entry computation gives an array
     by head ([B, H, T, D] or the transposed [B, T, H, D], whole or as rotary's
@@ -1175,7 +1211,16 @@ def test_the_scalar_decay_scan_the_8192_channel_convolution_and_grouped_attentio
     assert f"f32[{z},{t},{hv * d}]" not in text and f"[{z},{t},{hv},{d}]" not in text and f"[{z},{hv},{t},{d}]" not in text
     for shapes, want in zip(sorted(scans, key=len), (1, 2)):    # forward: o and qkv; backward: dO, qkv and its cotangent
         assert sum(s == (z, t, hv * d) for s in shapes) == 1 and sum(s == (z, t, 2 * hk * d + hv * d) for s in shapes) == want
-    assert compiled.memory_analysis().temp_size_in_bytes <= 0.6e9      # the states: 128 x 2 x 32 x 128 x 128 float32
+    # THE MECHANISM (PR 68): a chunk's T = (I + L)^-1 (levels of two [64, 64] x [64, 64] products each) is made by the
+    # FORWARD loop alone and handed over by chunk ([128, 2, 16, 2, 64, 64] bfloat16, a scan's xs: not a whole
+    # stream, so the counts above stand); the backward loop's body holds no such product. Were a level to come
+    # back into it, this fails
+    levels = dict(zip((sum(len(s) == 3 and s[:2] == (z, t) for s in shapes) for shapes in scans),
+                      _level_products_by_loop(text, (z, hv, d, d), chunk)))
+    assert levels == {4: 10, 7: 0}, levels       # five levels of two products past the first, forward; none backward
+    assert f"bf16[{t // chunk},{z},{hk},{hv // hk},{chunk},{chunk}]" in text
+    # 0.67e9 (my compile, PR 68): the states, 128 x 2 x 32 x 128 x 128 float32 (0.54e9), and every chunk's T (0.07e9)
+    assert compiled.memory_analysis().temp_size_in_bytes <= 0.7e9
 
     channels = 2 * hk * d + hv * d
     assert short_conv.choose_stream_block(t, channels, 4) == 128 and short_conv.choose_stream_block(t, 6144, 4) == 256
