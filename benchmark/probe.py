@@ -16,7 +16,9 @@ edits nothing:
 Counters (``compile_summary()``, the codec's ``stats()``, transport bytes,
 ``memory_stats()``) and telemetry spans are read at the window's ends. The
 benchmark's own device work (the reference check) runs after the window has
-closed and the counters are read, so the peak they hold is the program's.
+closed and the counters are read, so the peak they hold is the program's, and
+on a chip the training state has left (``release_training_state``), so what a
+configuration may hold is set by the step and not by the check.
 """
 
 from __future__ import annotations
@@ -66,6 +68,7 @@ class Probe:
         self.merges: List[Dict[str, Any]] = []  # one per parameter swap the loop made
         self.losses: List[Dict[str, Any]] = []  # every loss the loop or the check read
         self.reference: Dict[str, Any] = {}
+        self.released: Dict[str, int] = {}  # what release_training_state() freed and left
         self.round_check: Dict[str, Any] = {}
         self.trace_info: Dict[str, Any] = {}
         self._trace_state = "idle"  # idle -> on -> done
@@ -110,13 +113,42 @@ class Probe:
 
     # -- correctness, once the volunteer has stopped --------------------------
 
+    def release_training_state(self) -> None:
+        """Give the allocator back every device buffer the stopped volunteer
+        still holds: parameters, both Adam moments, step counter and rng,
+        whatever else hangs on the trainer (a round's payload, a device-side
+        copy), every shard of each. Nothing reads them after the window: its
+        counters were taken when it closed, the round check works on host
+        arrays, and the reference check puts the INITIAL parameters back. The
+        leaves are deleted, not dropped for a collector to find, so the next
+        allocation has the room; whoever reads ``trainer.state`` after this
+        is told that the array was deleted."""
+        if self.phase != DONE:
+            raise RuntimeError("the training state is released once the window's counters are read")
+        held = [x for x in jax.tree_util.tree_leaves(vars(self.vol.trainer))
+                if isinstance(x, jax.Array) and not x.is_deleted()]
+        jax.block_until_ready(held)  # a step still in the chip's queue reads them
+        self._log_memory("before the release")
+        for x in held:
+            x.delete()
+        left = jax.live_arrays()  # what someone else than the trainer still holds: the log says so
+        self.released = {"arrays": len(held), "bytes": sum(x.nbytes for x in held),
+                         "left_arrays": len(left), "left_bytes": sum(x.nbytes for x in left)}
+        _log(f"released the training state: {self.released}")
+        self._log_memory("released")
+
     def reference_check(self) -> None:
         """The program's loss and gradients on the initial parameters against
         the plain float32 reference, on seeded sequences, one at a time.
 
-        It runs after the window (two gradient trees beside the training
-        state were the process's peak when it ran before), on the host copy
-        of the initial parameters put back as the trainer shards them."""
+        It runs after the window, on a chip the training state has left
+        (``release_training_state``): the host copy of the initial parameters
+        put back as the trainer sharded them (4 bytes a parameter) and, while
+        ``compare`` reads them, the two sides' gradient trees (8). Both sides
+        read the same parameters, in every sequence, so neither donates them.
+        Of the two, the one whose program takes more temporaries runs first,
+        beside one tree less: the peak is 12 bytes a parameter and the
+        SMALLER of the two sides' temporaries."""
         t_begin = time.perf_counter()
         tr = self.vol.trainer
         rc = self.cfg["reference_check"]
@@ -126,16 +158,15 @@ class Probe:
         arrays = datagen.lm_arrays(
             self.seed + 0x5EED, rc["sequences"], rc["seq_len"], sizes["vocab"]
         )
-        rng = jax.random.PRNGKey(0)
         loss_fn = tr.bundle.loss_fn
+        shardings = jax.tree_util.tree_map(lambda x: x.sharding, tr.state.params)
+        self.release_training_state()
+        rng = jax.random.PRNGKey(0)
 
-        @jax.jit
         def program(params, tokens, targets):
             return jax.value_and_grad(
                 lambda p: loss_fn(p, {"tokens": tokens, "targets": targets}, rng)[0]
             )(params)
-
-        reference = jax.jit(ref.make_loss_and_grad(self.cfg))
 
         @jax.jit
         def compare(gp, gr):
@@ -145,15 +176,20 @@ class Probe:
             den = jax.tree_util.tree_map(lambda b: jax.numpy.sum(b ** 2), gr)
             return num, den
 
-        params = jax.device_put(
-            self._initial_params,
-            jax.tree_util.tree_map(lambda x: x.sharding, tr.state.params),
-        )
+        params = jax.device_put(self._initial_params, shardings)
+        tok, tgt = arrays["tokens"][:1], arrays["targets"][:1]
+        sides = {
+            name: jax.jit(fn).lower(params, tok, tgt).compile()
+            for name, fn in (("program", program), ("reference", ref.make_loss_and_grad(self.cfg)))
+        }
+        temps = {name: c.memory_analysis().temp_size_in_bytes for name, c in sides.items()}
+        order = sorted(sides, key=temps.get, reverse=True)
+        _log(f"reference check: temporaries {temps}, runs {order[0]} first")
         loss_err, num_t, den_t, worst_leaf, losses = 0.0, 0.0, 0.0, 0.0, []
         for s in range(rc["sequences"]):
             tok, tgt = arrays["tokens"][s:s + 1], arrays["targets"][s:s + 1]
-            lp, gp = program(params, tok, tgt)
-            lr, gr = reference(params, tok, tgt)
+            out = {name: sides[name](params, tok, tgt) for name in order}
+            (lp, gp), (lr, gr) = out.pop("program"), out.pop("reference")  # popped: `del` below frees the trees
             num, den = compare(gp, gr)
             del gp, gr
             num = [float(x) for x in jax.tree_util.tree_leaves(num)]

@@ -119,6 +119,15 @@ def parse_args(argv=None) -> argparse.Namespace:
     return args
 
 
+def init_seed_of(cfg: dict, seed: int) -> int:
+    """The seed of the initial parameters. A swarm's volunteers all start from
+    the TASK's parameters (``VolunteerConfig.init_seed``) and differ in their
+    data; a configuration that names the task's seed (``init_seed``) gets it
+    in every run, so that ``--seed`` moves the data and the noise and not the
+    router the held experts' rows follow. The others draw both from ``--seed``."""
+    return int(cfg.get("init_seed", seed))
+
+
 def median(xs):
     xs = list(xs)
     return statistics.median(xs) if xs else None
@@ -228,8 +237,9 @@ def _run(args, manifest, cell, cfg, traffic, chips, seconds, rehearsal, work, pe
         parts["peer_wait_s"] = time.perf_counter() - t
     vcfg = VolunteerConfig(
         model=cfg["registry_model"], model_overrides=dict(cfg.get("model_overrides", {})),
-        data_path=data_path, seed=args.seed, init_seed=args.seed, **fields,
+        data_path=data_path, seed=args.seed, init_seed=init_seed_of(cfg, args.seed), **fields,
     )
+    log(f"seeds: data and noise {vcfg.seed}, initial parameters {vcfg.init_seed}")
     vol = Volunteer(vcfg)
     probe = Probe(vol, cfg, traffic, seconds=seconds, trace=bool(args.trace),
                   workdir=work, seed=args.seed,
@@ -252,7 +262,10 @@ def _run(args, manifest, cell, cfg, traffic, chips, seconds, rehearsal, work, pe
               file=sys.stderr)
         return 1
     # The benchmark's own device work comes after the window and after its
-    # counters are read: the peak memory they hold is the program's.
+    # counters are read (`Probe._close_window`: `probe.after`, the peak memory
+    # they hold is the program's), and the volunteer has stopped: the check
+    # frees the training state first (`Probe.release_training_state`) and runs
+    # on the initial parameters alone. Nothing below reads `trainer.state`.
     probe.reference_check()
     probe.finish()
 

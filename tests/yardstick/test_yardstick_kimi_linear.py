@@ -569,10 +569,15 @@ def test_manifest_tail_as_the_nemotron_tests_asserted_it_three_metrics_and_a_cel
 
 def test_reference_check_limits_are_written_with_their_readings():
     rc = CFG["reference_check"]
-    assert rc["sequences"] == 1 and rc["seq_len"] in (4096, 8192)
+    # since PR 69 at the cell's timed length: one sequence of the 8,192 tokens the step runs two of
+    assert rc["sequences"] == 1 and rc["seq_len"] == 8192 == ref.sizes(CFG)["seq_len"] == CFG["assumed"]["seq_len"]["value"]
     assert 0 < rc["grad_rel_err"] <= 0.15 and 0 < rc["loss_atol"] <= 0.01
     for word in ("flipped", "e4m3", "bfloat16", "left out"):
         assert word in rc["why"], word
+    # the readings at that length are in the text, the ones at 4,096 stay as what they were
+    for word in ("1 x 8,192", "PR 69", "seven", "mean", "standard deviation", "4,096", "PR 52"):
+        assert word in rc["why"], word
+    assert "12 bytes a parameter" in rc["size_why"] and "8,192" in rc["size_why"] and "PR 69" in rc["size_why"]
     for variant in ref.VARIANTS:
         assert variant in rc["left_out"], variant
     assert "to be read" not in (rc["why"] + rc["left_out"] + rc["size_why"]).lower()

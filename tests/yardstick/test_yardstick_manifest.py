@@ -170,3 +170,41 @@ def test_a_later_pr_adds_a_config_a_mix_a_cell_and_a_metric_with_files_only(tmp_
     assert value == 42.0
     with pytest.raises(ManifestError):
         m.cell("no-such-cell")
+
+
+@pytest.mark.parametrize("entry", DOC["configs"], ids=lambda c: c["name"])
+def test_the_reference_check_runs_at_the_timed_length_or_the_file_says_why_not(entry):
+    """`correct` is decided at the length the cell times (one sequence of it), since PR 69
+    in `kimi-linear-solo-8k` too: the check no longer holds the training state, so memory
+    sets no check's length. The three older configurations check half or a quarter of
+    their timed length for another reason, and their `size_why` says so."""
+    cfg = M.load_config(entry["name"])
+    rc = cfg["reference_check"]
+    timed = references.load(cfg["family"]).sizes(cfg)["seq_len"]
+    if "bytes" in cfg.get("parameters", {}):
+        said = cfg["parameters"]["bytes"]
+        assert "16 a parameter in the step" in said and "12 at the reference check" in said, said
+        assert rc["seq_len"] == timed and rc["sequences"] == 1
+        assert "24" in said and "PR 69" in said  # what the cuts were made under stays in the text
+    else:
+        assert entry["name"] in ("gpt2-medium", "gpt2-large", "olmoe-1b-7b")
+        assert rc["seq_len"] < timed and "not set by memory" in rc["size_why"]
+
+
+@pytest.mark.parametrize("entry", DOC["configs"], ids=lambda c: c["name"])
+def test_a_configuration_that_names_the_tasks_initial_parameters_says_why(entry):
+    """``init_seed`` in a configuration file is the TASK's seed for the initial parameters
+    (``benchmark/run.py:init_seed_of``): every run starts from the same router and ``--seed``
+    moves the data. Only a configuration that holds a share of its experts has a reason to
+    (its step's work follows the rows its router sends them), and it gives the readings."""
+    from benchmark import run
+
+    cfg = M.load_config(entry["name"])
+    assert run.init_seed_of(cfg, 7) == cfg.get("init_seed", 7)
+    if "init_seed" not in cfg:
+        assert "init_seed_why" not in cfg
+        return
+    assert isinstance(cfg["init_seed"], int) and 0 <= cfg["init_seed"] < 2 ** 32
+    assert "experts_held" in cfg["model_overrides"], "nothing in a dense step follows the seed"
+    why = cfg["init_seed_why"]
+    assert "tok_s_chip" in why and "PR 69" in why and "refused" in why and len(why) > 200

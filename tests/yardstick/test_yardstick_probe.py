@@ -1,6 +1,10 @@
 """The observer's window logic and its check of a round, on a fake volunteer:
 no device, no socket, no thread, a clock the test moves."""
 
+import json
+import os
+import subprocess
+import sys
 import types
 
 import numpy as np
@@ -210,3 +214,113 @@ def test_a_round_that_never_lands_does_not_hold_the_run_for_ever(clock):
         p.rounds.append({"index": i, "step": 4 * i, "t0": clock.now, "bytes0": 0})
     drive(p, vol.trainer, 1, clock, start=21)
     assert p.phase == probe_mod.WINDOW and p.window["warmup_incomplete"] is True
+
+
+# -- the training state leaves the chip before the reference check ---------------------------
+
+
+def test_the_release_waits_for_the_windows_counters_and_frees_every_buffer_the_trainer_holds(clock):
+    """``release_training_state()`` refuses while the window's counters are
+    unread, then deletes every device array that hangs on the trainer: state,
+    a round's payload, a device-side copy; host arrays are left alone."""
+    import jax
+    import jax.numpy as jnp
+
+    vol, _ = fake_volunteer()
+    tr = vol.trainer
+    p = probe_mod.Probe(vol, M.load_config("gpt2-medium"), M.load_traffic("solo"),
+                        seconds=10.0, trace=False, workdir="/nonexistent", seed=1)
+    p.install()
+    drive(p, tr, 7, clock)
+    assert p.phase == probe_mod.WINDOW and p.after == {}
+    with pytest.raises(RuntimeError, match="counters"):
+        p.release_training_state()
+    assert drive(p, tr, 40, clock, start=8) == 15 and p.after["compile"]["programs"] == 7
+    state = {"params": {"w": jnp.ones((3,))}, "opt_state": ({"w": jnp.zeros((3,))}, {"w": jnp.zeros((3,))}),
+             "step": jnp.int32(15), "rng": jax.random.PRNGKey(0)}
+    payload, host = {"w": jnp.ones((3,))}, np.ones((3,), np.float32)
+    tr.state, tr._inflight, tr._snapshot = state, (10, payload, object()), (10, {"w": host})
+    already = jnp.ones((2,))
+    already.delete()
+    tr._copied_tree = [already]  # a buffer a step donated: nothing to free, nothing to trip over
+    p.release_training_state()
+    leaves = jax.tree_util.tree_leaves((state, payload))
+    assert len(leaves) == 6 and all(x.is_deleted() for x in leaves)
+    assert p.released["arrays"] == 6 and p.released["bytes"] == 4 * 3 * 4 + 4 + 8
+    assert host.sum() == 3.0 and p._initial_params["w"].shape == (3,)
+
+
+STATE_WATCH = """
+import json, sys
+sys.path.insert(0, {root!r})
+import jax
+from benchmark import probe, run
+
+inner = probe.Probe.reference_check
+
+def watched(self):
+    state = self.vol.trainer.state
+    leaves = [x for x in jax.tree_util.tree_leaves(state) if isinstance(x, jax.Array)]
+    live = sum(not x.is_deleted() for x in leaves)
+    inner(self)
+    print("STATE_WATCH " + json.dumps({{
+        "leaves": len(leaves), "live_before": live, "deleted_after": sum(x.is_deleted() for x in leaves),
+        "shards": max(len(x.sharding.device_set) for x in leaves), "same_state": self.vol.trainer.state is state,
+        "reference": self.reference, "released": self.released}}), file=sys.stderr, flush=True)
+
+probe.Probe.reference_check = watched
+sys.exit(run.main())
+"""
+
+# What the parent (841632a, the training state alive through the check) reads on the same seed,
+# `--rehearse <cell> --seed 3000000019 --seconds 3 --trace 0` on the CPU: the release changes no number.
+PARENT_READS = {"loss": 7.692730188369751, "loss_abs_err": 4.76837158203125e-07}
+PARENT_GRADS = {
+    "tiny-rehearsal:solo": (5.659304446100458e-07, 7.364589535834498e-07),
+    "tiny-rehearsal:round-2peer-bf16": (5.659304446100458e-07, 7.364589535834498e-07),
+    "tiny-rehearsal-mesh:solo": (5.200796183884446e-07, 6.345221191692989e-07),  # dp=2,tp=2: large-solo-4chip's stand-in
+}
+
+
+@pytest.mark.parametrize("cell", list(PARENT_GRADS))
+def test_no_leaf_of_the_training_state_outlives_the_check_and_the_check_reads_what_the_parent_read(cell):
+    """A volunteer built from a ``tiny-rehearsal`` configuration, through
+    ``benchmark/run.py`` itself: after ``reference_check()`` every leaf of
+    ``trainer.state`` (parameters, both moments, step, rng; every shard on
+    the four-device mesh) is deleted, the verdict and its four numbers are
+    the parent's, and ``finish()`` (the round check, on host arrays) and the
+    reduce go on to a ``correct`` result line."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1")
+    env.pop("XLA_FLAGS", None)
+    out = subprocess.run(
+        [sys.executable, "-c", STATE_WATCH.format(root=REPO_ROOT), "--rehearse", cell,
+         "--seed", "3000000019", "--seconds", "3", "--trace", "0"],
+        cwd=REPO_ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    saw = json.loads(next(ln for ln in out.stderr.splitlines() if ln.startswith("STATE_WATCH ")).split(" ", 1)[1])
+    assert saw["leaves"] == saw["live_before"] == saw["deleted_after"] >= 50 and saw["same_state"]
+    assert saw["released"]["arrays"] >= saw["leaves"] and saw["released"]["bytes"] > 8_000_000
+    assert saw["shards"] == (4 if "mesh" in cell else 1)
+    # To the digit on the machine that read the parent's; three float32 roundings' room for another CPU's threads.
+    grad, worst = PARENT_GRADS[cell]
+    read = saw["reference"]
+    assert read["ok"] is True and read["loss"] == pytest.approx(PARENT_READS["loss"], rel=1e-6)
+    assert read["loss_abs_err"] <= 4 * PARENT_READS["loss_abs_err"]
+    assert read["grad_rel_err"] == pytest.approx(grad, rel=0.5) and read["worst_leaf_rel_err"] == pytest.approx(worst, rel=0.5)
+    assert sorted(read) == ["grad_rel_err", "loss", "loss_abs_err", "ok", "worst_leaf_rel_err"]
+    line = json.loads(out.stdout.strip().splitlines()[-1])  # the reduce ran to the result line
+    checks = json.loads(next(ln for ln in out.stderr.splitlines() if "] checks: " in ln).split("checks: ", 1)[1])
+    assert line["attempted"] > 10 and checks["reference"] and checks["losses_finite"] and checks["loss_band"], checks
+    if "round" in cell:
+        # whether every round of the window came back in time is the machine's load, not the release
+        assert checks["round_mean"] is True, checks
+    else:
+        assert line["correct"] is True and line["failed"] == 0 and all(checks.values()), checks
+    err = out.stderr
+    # the side whose compiled program takes more temporaries runs first, beside one gradient tree less
+    said = next(ln for ln in err.splitlines() if "reference check: temporaries " in ln).split("temporaries ", 1)[1]
+    temps = json.loads(said.split(", runs ")[0].replace("'", '"'))
+    assert set(temps) == {"program", "reference"} and said.split(", runs ")[1] == f"{max(temps, key=temps.get)} first"
+    assert err.index("window closes") < err.index("released the training state") < err.index("reference check (")
+    if "round" in cell:
+        assert err.index("reference check (") < err.index("round check (")

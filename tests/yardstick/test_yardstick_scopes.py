@@ -206,6 +206,25 @@ def test_an_untraced_run_asks_the_program_for_nothing(monkeypatch):
     with open(f"{REPO_ROOT}/benchmark/run.py") as fh:
         text = fh.read()
     assert text.index("probe.reference_check()") < text.index("elif args.trace:") < text.index("readers.compute(")
+    # and the window's counters are read before the check releases the training state (PR 69): run.py goes on
+    # only from a closed window (`_close_window` fills `probe.after`, then sets the phase), takes the window's peak
+    # from `after`, and calls nothing of the probe between the volunteer's end and the check; the check itself takes
+    # the shardings, releases (which refuses before the phase is `done`), and only then puts the parameters back
+    order = ["summary = asyncio.run(vol.run())", 'if probe.phase != "done":', "probe.reference_check()",
+             "probe.finish()", 'after["memory_peak_bytes"],  # read before the reference check',
+             'device["memory_peak_bytes"] = probe.peak_bytes()']
+    assert [text.index(x) for x in order] == sorted(text.index(x) for x in order)
+    assert "probe." not in text[text.index(order[0]) + len(order[0]):text.index(order[1])]
+    with open(f"{REPO_ROOT}/benchmark/probe.py") as fh:
+        text = fh.read()
+    close = text[text.index("def _close_window"):text.index("# -- profiler")]
+    assert close.index("self.after = self.counters()") < close.index("self.phase = DONE")
+    release = text[text.index("def release_training_state"):text.index("def reference_check")]
+    assert release.index("if self.phase != DONE:") < release.index("x.delete()")
+    check = text[text.index("def reference_check"):text.index("# -- the three seams")]
+    order = ["tr.state.params)", "self.release_training_state()", "jax.device_put(self._initial_params, shardings)"]
+    assert [check.index(x) for x in order] == sorted(check.index(x) for x in order)
+    assert "tr.state" not in check[check.index(order[1]):] and ".trainer.state" not in text[text.index("def finish"):text.index("def on_step")]
 
 
 def test_the_reader_leaves_the_map_beside_the_trace(offered, tmp_path, monkeypatch, capsys):

@@ -431,3 +431,5 @@ def test_rehearsal_cell_runs_end_to_end_on_the_cpu():
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 10
     assert line["metrics"] == {} and line["device"]["platform"] == "cpu"
     assert '"reference": true' in out.stderr and '"no_compile_in_window": true' in out.stderr
+    # the configuration names the task's initial parameters (PR 69): `--seed` moves the data and the noise only
+    assert "seeds: data and noise 3000000019, initial parameters 3100000013" in out.stderr
