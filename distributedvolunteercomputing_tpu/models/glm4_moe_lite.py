@@ -101,7 +101,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -245,7 +245,8 @@ def _half_pairs(w: jax.Array) -> jax.Array:
     return jnp.swapaxes(pairs, -1, -2).reshape(w.shape)
 
 
-def qkv(p: common.Params, n: jax.Array, cfg: Glm4MoeLiteConfig):
+def qkv(p: common.Params, n: jax.Array, cfg, pad: int = 0, inv_freq: Optional[jax.Array] = None,
+        q_scale: float = 1.0):
     """The latent's three products of the normed stream ``n`` [B, T, d]: q, k
     and v ``[B, T, H * head_dim]``, each made where ``attention_merged`` reads
     it by its projection's own product, rotary applied. A head's lanes of q and
@@ -272,32 +273,42 @@ def qkv(p: common.Params, n: jax.Array, cfg: Glm4MoeLiteConfig):
     call is given no rotary: at the published head and 8,192 tokens the kernels
     hold 66.06 of their 67.1 MB of VMEM, the 4.19 MB of tables a turn on the
     kernel's tile brings do not fit, and ``attention_merged`` would then take
-    the by-head path without a word (the module text)."""
+    the by-head path without a word (the module text).
+
+    A family whose key is not whole 128-lane tiles (``models/xing4.py``: 128 +
+    64 = 192 over a value head of 128) asks for ``pad`` zero lanes behind each
+    head of q and k, again of the weights (a zero lane adds nothing to a
+    score), gives its rotary frequencies as ``inv_freq`` (YaRN) and a softmax
+    scale that is not ``1 / sqrt(lanes)`` as ``q_scale`` on ``wq_b`` (the cores
+    divide by the root of the lanes they are handed). The defaults trace to
+    the program this function traced to before it took them."""
     dtype = n.dtype
     t = n.shape[1]
     h, nope, rot, latent = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
-    wq_b = p["wq_b"].astype(dtype).reshape(cfg.q_lora_rank, h, nope + rot)
-    wq_b = jnp.concatenate([_half_pairs(wq_b[..., nope:]), wq_b[..., :nope]], axis=-1).reshape(cfg.q_lora_rank, -1)
+    lanes = rot + nope + pad
+    wq_b = (p["wq_b"] if q_scale == 1.0 else p["wq_b"] * q_scale).astype(dtype).reshape(cfg.q_lora_rank, h, nope + rot)
+    zeros = [jnp.zeros((cfg.q_lora_rank, h, pad), dtype)] if pad else []
+    wq_b = jnp.concatenate([_half_pairs(wq_b[..., nope:]), wq_b[..., :nope], *zeros], axis=-1).reshape(cfg.q_lora_rank, -1)
     wkv_a = p["wkv_a"].astype(dtype)
     wkv_a = jnp.concatenate([wkv_a[:, :latent], _half_pairs(wkv_a[:, latent:])], axis=-1)
     wkv_b = p["wkv_b"].astype(dtype).reshape(latent, h, nope + cfg.v_head_dim)
-    wk = jnp.pad(wkv_b[..., :nope], ((0, 0), (0, 0), (rot, 0))).reshape(latent, h * (rot + nope))
-    wk = jnp.concatenate([wk, jnp.tile(jnp.eye(rot, rot + nope, dtype=dtype), (1, h))], axis=0)   # [Wk ; E]
+    wk = jnp.pad(wkv_b[..., :nope], ((0, 0), (0, 0), (rot, pad))).reshape(latent, h * lanes)
+    wk = jnp.concatenate([wk, jnp.tile(jnp.eye(rot, lanes, dtype=dtype), (1, h))], axis=0)   # [Wk ; E]
     wv = wkv_b[..., nope:].reshape(latent, h * cfg.v_head_dim)
 
     cq = common.rmsnorm(p["q_a_norm"], n @ p["wq_a"].astype(dtype), cfg.rms_eps)
     q = cq @ wq_b
     ckr = n @ wkv_a                                                       # [B, T, latent + rot]
     c = common.rmsnorm(p["kv_a_norm"], ckr[..., :latent], cfg.rms_eps)
-    k_rope = rope(ckr[..., latent:], base=cfg.rope_theta, layout="half")   # one key a token, [B, T, rot]
+    k_rope = rope(ckr[..., latent:], base=cfg.rope_theta, layout="half", inv_freq=inv_freq)   # one key a token, [B, T, rot]
     k = jnp.concatenate([c, k_rope], axis=-1) @ wk
     v = c @ wv
     if merged_in_place(q, k, v, h, h, True, None, None) and chips_in_step() == 1:
         from distributedvolunteercomputing_tpu.ops import pallas_attention
 
-        cos, sin = pallas_attention.rotary_tables(t, rot + nope, cfg.rope_theta, rot)
+        cos, sin = pallas_attention.rotary_tables(t, lanes, cfg.rope_theta, rot, inv_freq)
         return pallas_attention.rotary_merged(q, cos, sin, rot), k, v
-    q = rope(split_heads(q, h), base=cfg.rope_theta, layout="half", rotary_dim=rot)
+    q = rope(split_heads(q, h), base=cfg.rope_theta, layout="half", rotary_dim=rot, inv_freq=inv_freq)
     return merge_heads(q), k, v
 
 

@@ -166,6 +166,10 @@ _LANGUAGE_MODELS: Dict[str, Tuple[str, str]] = {
     # 256) of 512 experts beside a gated shared one: ``n_layers`` (whole periods of four), ``experts_held`` /
     # ``expert_offset``, ``vocab``
     "qwen3_next_80b_a3b": ("qwen3_next", "Qwen3NextConfig"),
+    # 40 layers of latent attention (a key of 192 over a value of 128, YaRN) and 64 experts around FOUR residual
+    # streams mixed by Sinkhorn-normalised maps: ``n_layers`` with ``dense_layers``, ``experts_held`` /
+    # ``expert_offset``, ``vocab``; its routers' selection biases are the step's to move
+    "xing4_29b_a4b": ("xing4", "Xing4Config"),
     "llama_lora": ("llama", "LlamaConfig"),
 }
 

@@ -45,6 +45,9 @@ VOCABULARY: Dict[str, str] = {
     # a looped model's loop over its passes (models/ouro.py): what lies in it and under no block's word is the loop's
     # own (the carry's copies, the stack of the passes' states, the shared weights' gradient sums, the passes' norm)
     "recur": "other",
+    # the residual path of a model that carries several streams (models/xing4.py): a sublayer's maps from the
+    # token's own state, the sum into its input and the mix of the streams with its result
+    "hc": "residual",
 }
 OTHER = "other"  # resolved, under no word of a group's own: embedding, final norm, the layer scan's own slices
 GROUPS: Tuple[str, ...] = tuple(dict.fromkeys((*VOCABULARY.values(), OTHER)))

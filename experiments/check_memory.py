@@ -5,8 +5,9 @@
 Compiles, for a described v5e (as ``experiments/step_memory.py`` does), the two programs that
 ``benchmark/probe.py:reference_check`` runs on one seeded sequence: the program's loss and gradient
 (bf16 compute, the flash kernels) and the configuration's float32 reference; prints each one's
-temporaries, outputs and compile-cache entry, and what the check holds beside them (the training
-state, the initial parameters again, the first gradient tree while the second is made)."""
+temporaries, outputs and compile-cache entry, and what the check holds beside them (the initial
+parameters, and the first gradient tree while the second is made; the training state has left the
+chip since PR 69). ``xing4.0-29b-a4b``: 9.35e9 and 13.59e9 at its 4,096 tokens (PR 70)."""
 import json
 import os
 import sys
@@ -37,7 +38,7 @@ def main(config: str, seq_len=None) -> None:
     def program(p, tokens, targets):
         return jax.value_and_grad(lambda q: bundle.loss_fn(q, {"tokens": tokens, "targets": targets}, rng)[0])(p)
 
-    held = {"training_state": 3 * n_bytes, "initial_parameters": n_bytes}
+    held = {"initial_parameters": n_bytes}  # since PR 69 the training state is released before the check
     for name, fn, beside in (("program", program, 0), ("reference", references.load(cfg["family"]).make_loss_and_grad(cfg), n_bytes)):
         compiled = jax.jit(fn).lower(params, tok, tok).compile()
         mem = compiled.memory_analysis()

@@ -7,7 +7,8 @@ sequence of 16,384 tokens; ``--config lfm2-24b-a2b``: published layers 0 and
 ``--config nemotron-3-nano-30b-a3b``: published blocks 0-6, eight of 128 experts;
 ``--config kimi-linear-48b-a3b``: published layers 1-5, eight of 256 experts, 4,096 tokens;
 ``--config sdar-30b-a3b-chat``: published layers 0-4, sixteen of 128 experts, 4,096 data tokens as 8,192 rows;
-``--config qwen3-next-80b-a3b``: published layers 0-3, sixteen of 512 experts, 8,192 tokens): the
+``--config qwen3-next-80b-a3b``: published layers 0-3, sixteen of 512 experts, 8,192 tokens;
+``--config xing4.0-29b-a4b``: published layers 1-5, eight of 64 experts, four residual streams, 4,096 tokens): the
 readings that set ``reference_check`` in ``benchmark/configs/<config>.json``.
 
     chiprun -- python experiments/smallthinker_reference_check.py --seeds 3 --left-out
@@ -31,11 +32,12 @@ the program's loss and gradients (bf16 compute on a TPU) against
   of them), against itself with the same routes (a variant that scores the
   experts otherwise picks its own): each must land outside a limit.
 
-``--bias``, ``--norm-scale``, ``--qk-scale`` make the parameters of EVERY
+``--bias``, ``--norm-scale``, ``--qk-scale``, ``--maps`` make the parameters of EVERY
 reading seeded non-initial ones (a state a few thousand steps in, not a trained
 one): each router's selection bias ``normal(0, --bias)``, each per-head q / k
 norm's scales ``normal(1, --norm-scale)``, the q and k projections times
-``--qk-scale``. At the initial parameters the bias is zero (adding it to the
+``--qk-scale``; the three scalars ``a`` of every residual map (``models/xing4.py``) set to ``--maps``, so that the
+token's own state moves its maps (0.01 at initialisation: ``static_maps`` cannot show there). At the initial parameters the bias is zero (adding it to the
 weights is then no mistake) and q and k have RMS 0.9 under scales of 1 (the
 norm is nearly the identity), so neither ``bias_in_weights`` nor ``no_qk_norm``
 can show there. With any of the three, every reading also comes BY GROUP of
@@ -51,6 +53,7 @@ with float32 and checks the paths only.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import math
 import os
@@ -70,7 +73,7 @@ from experiments.olmoe_reference_check import _diff2, _norm2, rel_err
 # variants that score the experts otherwise, so they pick their own routes
 OWN_ROUTES = ("router_after_attention", "softmax_for_sigmoid")
 # a leaf's group, by the first of these keys its path holds
-GROUPS = {"router": "router", "experts": "experts", "shared": "shared", "conv": "conv", "mlp": "mlp",
+GROUPS = {"hc_mixer": "residual", "hc_ffn": "residual", "router": "router", "experts": "experts", "shared": "shared", "conv": "conv", "mlp": "mlp",
           **dict.fromkeys(("wq", "wk", "wv", "wo", "q_norm", "k_norm",
                            "wq_a", "wq_b", "wkv_a", "wkv_b", "q_a_norm", "kv_a_norm"), "attention"),
           **dict.fromkeys(("w_qkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "gate_b", "o_norm"), "kda"),
@@ -90,11 +93,13 @@ def by_group(got, want):
     return {g: math.sqrt(num[g] / den[g]) for g in sorted(num) if den[g] > 0}
 
 
-def seeded_state(params, seed, bias, norm_scale, qk_scale):
-    """``params`` a few thousand steps in, by the three options (module docstring)."""
+def seeded_state(params, seed, bias, norm_scale, qk_scale, maps=0.0):
+    """``params`` a few thousand steps in, by the four options (module docstring)."""
     def leaf(path, a):
         key = getattr(path[-1], "key", None)
         inside = [getattr(k, "key", None) for k in path]
+        if key == "a" and maps and ("hc_mixer" in inside or "hc_ffn" in inside):
+            return jnp.full_like(a, maps)
         if key == "bias" and bias:
             return bias * jax.random.normal(jax.random.PRNGKey(seed), a.shape)
         if any(n in inside for n in ("q_norm", "k_norm", "q_a_norm", "kv_a_norm")) and norm_scale:
@@ -120,13 +125,17 @@ def main() -> int:
     ap.add_argument("--norm-scale", type=float, default=0.0,
                     help="spread of the q and k norms' seeded scales about 1 (0: as initialised)")
     ap.add_argument("--qk-scale", type=float, default=1.0, help="the q and k projections times this")
+    ap.add_argument("--maps", type=float, default=0.0,
+                    help="the residual maps' three scalars a sublayer set to this (0: as initialised)")
+    ap.add_argument("--both-states", action="store_true",
+                    help="with a seeded state: read every seed at the initial parameters as well, in the same process")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
 
     cfg = Manifest().load_config(args.config)
     ref = references.load(cfg["family"])
     args.out = args.out or f"chiprun_out/{cfg['family']}_reference_check.json"
-    seeded = bool(args.bias or args.norm_scale or args.qk_scale != 1.0)
+    seeded = bool(args.bias or args.norm_scale or args.qk_scale != 1.0 or args.maps)
     rc = dict(cfg["reference_check"])
     if args.seq_len:
         rc["seq_len"] = args.seq_len
@@ -152,6 +161,7 @@ def main() -> int:
         (loss, routes), grads = jax.value_and_grad(f, has_aux=True)(params)
         return loss, grads, routes
 
+    @functools.lru_cache(maxsize=None)  # a variant's program is compiled once, whatever the states it reads
     def reference_with(variant=None):
         return jax.jit(lambda p, t, y, r=None: jax.value_and_grad(ref.loss)(p, t, y, hp, r, False, variant))
 
@@ -169,21 +179,27 @@ def main() -> int:
     }
 
     rows = []
-    for seed in range(args.first_seed, args.first_seed + args.seeds):
+    # ``--both-states``: every seed at the initial parameters AND at the seeded ones, in one process (a variant's
+    # program compiled once: a compile a variant is what a state costs)
+    states = [False, True] if args.both_states and seeded else [seeded]
+    for seed, seeded in ((seed, state) for seed in range(args.first_seed, args.first_seed + args.seeds)
+                         for state in states):
         params = jax.jit(bundle.init)(jax.random.PRNGKey(seed))
         if seeded:
-            params = seeded_state(params, seed, args.bias, args.norm_scale, args.qk_scale)
+            params = seeded_state(params, seed, args.bias, args.norm_scale, args.qk_scale, args.maps)
         arrays = datagen.lm_arrays(seed + 0x5EED, 1, rc["seq_len"], sizes["vocab"])
         tok, tgt = arrays["tokens"][:1], arrays["targets"][:1]
         lp, gp, mine = program(params, tok, tgt)
         mine = np.asarray(mine)                                # [L, S, k]
         (lr, theirs), gr = reference_alone(params, tok, tgt)
         oh = lambda r: np.eye(n_routed, dtype=bool)[r].any(axis=-2)  # noqa: E731
-        rec = {"seed": seed, "seq_len": rc["seq_len"], "loss_program": float(lp),
+        rec = {"seed": seed, "state": "seeded" if seeded else "initial", "seq_len": rc["seq_len"],
+               "loss_program": float(lp),
                "flipped_share": float(1.0 - (oh(mine) & oh(np.asarray(theirs))).sum() / mine.size)}
         rec["loss_reference"] = float(lr)
         if seeded:
-            rec["seeded"] = {"bias": args.bias, "norm_scale": args.norm_scale, "qk_scale": args.qk_scale}
+            rec["seeded"] = {"bias": args.bias, "norm_scale": args.norm_scale, "qk_scale": args.qk_scale,
+                             "maps": args.maps}
             rec["by_group_no_routes"] = by_group(gp, gr)
         rec["grad_rel_err_no_routes"] = rel_err(gp, gr)
         rec["loss_abs_err_no_routes"] = abs(float(lp) - float(lr))

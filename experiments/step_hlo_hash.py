@@ -28,7 +28,8 @@ CELLS = {"gpt2-medium": ("gpt2_medium", {}, 16),
          "kimi-linear-48b-a3b": ("kimi_linear_48b_a3b", {"n_layers": 5, "experts_held": 8, "expert_offset": 0, "vocab": 20480}, 2),
          "sdar-30b-a3b-chat": ("sdar_30b_a3b", {"n_layers": 5, "experts_held": 16, "expert_offset": 0, "vocab": 18992, "mask_id": 18991}, 2),
          "ouro-2.6b": ("ouro_2_6b", {"n_layers": 6, "max_len": 4096}, 2),
-         "qwen3-next-80b-a3b": ("qwen3_next_80b_a3b", {"n_layers": 4, "experts_held": 16, "expert_offset": 0, "vocab": 18992}, 2)}
+         "qwen3-next-80b-a3b": ("qwen3_next_80b_a3b", {"n_layers": 4, "experts_held": 16, "expert_offset": 0, "vocab": 18992}, 2),
+         "xing4.0-29b-a4b": ("xing4_29b_a4b", {"n_layers": 5, "dense_layers": 1, "experts_held": 8, "expert_offset": 0, "vocab": 16384, "max_len": 4096}, 1)}
 for name in sys.argv[1:] or CELLS:
     model, ov, bs = CELLS[name]
     try:

@@ -19,6 +19,7 @@ import sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
+XING4_LEN = 4096
 # cell -> (registry model, dp, tp, global batch, overrides): benchmark/configs/*.json
 CELLS = {
     "medium-solo": ("gpt2_medium", 1, 1, 16, {"n_layers": 24}),
@@ -46,6 +47,14 @@ CELLS = {
     # what the cell's six layers were chosen against (PERF.md section 4): seven hold 561.0 M parameters
     "ouro-seven-layers": ("ouro_2_6b", 1, 1, 2, {"n_layers": 7, "max_len": 4096}),
     "qwen3-next-solo-8k": ("qwen3_next_80b_a3b", 1, 1, 2, {"n_layers": 4, "experts_held": 16, "vocab": 18992}),
+    "xing4-solo": ("xing4_29b_a4b", 1, 1, 1,
+                   {"n_layers": 5, "dense_layers": 1, "experts_held": 8, "vocab": 16384, "max_len": XING4_LEN}),
+    # the other length of the cell's rule (PERF.md section 4): one sequence of 8,192 if the step reads at or
+    # under 16.4e9, else of 4,096
+    "xing4-solo-8k-tried": ("xing4_29b_a4b", 1, 1, 1,
+                            {"n_layers": 5, "dense_layers": 1, "experts_held": 8, "vocab": 16384, "max_len": 8192}),
+    "xing4-solo-4k-tried": ("xing4_29b_a4b", 1, 1, 1,
+                            {"n_layers": 5, "dense_layers": 1, "experts_held": 8, "vocab": 16384, "max_len": 4096}),
 }
 
 

@@ -295,6 +295,17 @@ _YARDSTICK_PINS = (
      "entries end it now, and what it takes off instead leaves lists that name a cell the view no longer has: a "
      "ManifestError from check() (checked, with this PR's entries taken off by name, in test_yardstick_qwen3_next.py)",
      ValueError),
+    # PR 70 (xing4.0-29b-a4b, xing4-solo, scope.residual_ms / hc.roofline / hc.res_offdiag; the cell appended to
+    # tok_s_chip's list and to the twenty-eight per-layer lists that carry glm47-flash-solo-8k):
+    # tests/yardstick/test_yardstick_xing4.py asserts what each of these asserted, against the manifest less PR 67's
+    # and this PR's entries, taken off BY NAME; its own manifest test pins no position.
+    ("test_configuration_file_is_what_the_program_runs", "[xing4.0-29b-a4b]",
+     "asserts reduced == []; xing4.0-29b-a4b lists its cut (checked, and the older test run with the list emptied, in "
+     "test_yardstick_xing4.py)"),
+    ("test_manifest_as_the_ouro_tests_asserted_it_before_this_cell", "test_yardstick_qwen3_next.py",
+     "runs Ouro's manifest cases against the manifest less PR 67's entries, which no longer ends on Ouro's: PR 70's "
+     "entries follow (the same five cases against the manifest less both PRs' entries, in test_yardstick_xing4.py)",
+     ValueError),
 )
 
 

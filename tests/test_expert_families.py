@@ -258,6 +258,7 @@ def tiny_language_model(name):
     shapes of its parameters and of what its loss returns: traced, never compiled."""
     rehearsals = {"olmoe_1b_7b": "tiny-rehearsal-olmoe", "sdar_30b_a3b": "tiny-rehearsal-sdar",
                   "ouro_2_6b": "tiny-rehearsal-ouro", "qwen3_next_80b_a3b": "tiny-rehearsal-qwen3-next",
+                  "xing4_29b_a4b": "tiny-rehearsal-xing4",
                   **{n: r for _, n, r, *_ in FAMILIES.values()}}
     if name in rehearsals:
         overrides = Manifest().load_config(rehearsals[name])["model_overrides"]
@@ -280,7 +281,7 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
         got = getattr(bundle.config, key)
         assert (list(got) if isinstance(got, tuple) else got) == value
     assert (bundle.stepped is not None) == (name in ("lfm2_24b_a2b", "glm4_7_flash", "nemotron3_nano_30b_a3b",
-                                                     "kimi_linear_48b_a3b"))
+                                                     "kimi_linear_48b_a3b", "xing4_29b_a4b"))
     if bundle.stepped is not None:
         assert bundle.stepped.signal == moe.COUNTS
     # the swarm averages the whole tree of every model but the one with adapters
@@ -294,7 +295,8 @@ def test_a_language_models_bundle_names_stepped_leaves_only_where_the_step_moves
 SPANS = {"nemotron3_nano_30b_a3b": {"moe.route", "ssm.scan"}, "kimi_linear_48b_a3b": {"moe.route", "kda.scan"},
          **dict.fromkeys(("olmoe_1b_7b", "laguna_xs2", "smallthinker_21b_a3b", "lfm2_24b_a2b", "glm4_7_flash",
                           "sdar_30b_a3b"), {"moe.route"}),
-         "ouro_2_6b": {"recur.exit"}, "qwen3_next_80b_a3b": {"moe.route", "gdn.scan", "attention.gate"}}
+         "ouro_2_6b": {"recur.exit"}, "qwen3_next_80b_a3b": {"moe.route", "gdn.scan", "attention.gate"},
+         "xing4_29b_a4b": {"moe.route", "hc.mix"}}
 
 
 @pytest.mark.parametrize("name", sorted(_LANGUAGE_MODELS))

@@ -73,7 +73,8 @@ def test_scope_and_pass_of_an_op_name(op_name, want):
 
 def test_vocabulary_is_one_table_and_every_word_has_a_group():
     assert set(ss.VOCABULARY.values()) | {ss.OTHER} == set(ss.GROUPS)
-    assert ss.GROUPS == ("attention", "mixer", "mlp", "moe", "loss_head", "optimizer", "other")
+    assert ss.GROUPS == ("attention", "mixer", "mlp", "moe", "loss_head", "optimizer", "other", "residual")
+    assert {w for w, g in ss.VOCABULARY.items() if g == "residual"} == {"hc"}
     assert {w for w, g in ss.VOCABULARY.items() if g == "mixer"} == {"kda", "gdn", "conv_mixer", "mamba"}
     assert {w for w, g in ss.VOCABULARY.items() if g == "moe"} == {"moe", "moe_route"}
     assert ss.group_of(None) == ss.group_of("embedding") == "other"

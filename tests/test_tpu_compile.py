@@ -838,6 +838,8 @@ _CELL_LAYOUTS = {
         ("ouro_2_6b", 1, 1, 2, 6, dict(max_len=4096)), {"merged/kernel": 1}),
     "qwen3-next-solo-8k": (  # the period's one attention layer: q and k turned BESIDE the kernels, as GLM's (D = 256)
         ("qwen3_next_80b_a3b", 1, 1, 2, 4, dict(experts_held=16, vocab=18992)), {"merged/none": 1}),
+    "xing4-solo": (  # the dense layer and one scanned expert layer: a key of 192 padded to 256 lanes through the weights
+        ("xing4_29b_a4b", 1, 1, 1, 5, dict(dense_layers=1, experts_held=8, vocab=16384, max_len=4096)), {"merged/none": 2}),
 }
 
 
