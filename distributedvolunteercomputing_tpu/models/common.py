@@ -99,65 +99,47 @@ def dense(p: Params, x: jax.Array, dtype: Optional[jnp.dtype] = None) -> jax.Arr
 
 
 def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[str, Tuple[jax.Array, jax.Array, jax.Array]]:
-    """The layout the step's mesh calls for, and q, k, v in it, from a qkv
-    leaf ``{"w": [d, 3d], "b": [3d]}`` (columns q|k|v, each head-major) and the
-    normed input [B, T, d]:
-
-    ``"merged"``, [B, T, d] each, a head a run of ``hd`` lanes as its product
-    leaves it: three products off the weight's three column ranges, so that no
+    """``"merged"`` and q, k, v in that layout, from a qkv leaf ``{"w": [d, 3d],
+    "b": [3d]}`` (columns q|k|v, each head-major) and the normed input
+    [B, T, d]: [B, T, d] each, a head a run of ``hd`` lanes as its product
+    leaves it. Three products off the weight's three column ranges, so that no
     [B, T, 3d] result is cut in three and no array by head is written on the
     way to the kernels (``attention_ops.attention_merged`` reads this layout in
-    place). That is the form with no step mesh, ``tp`` 1 or manual, or heads
-    that ``tp`` does not divide.
+    place).
 
-    ``"by_head"``, [B, H, T, hd], under a traced step whose mesh divides the
-    heads over ``tp`` (``attention_ops.heads_tp``): the weight is viewed as
-    [d, 3, H, hd] and laid out over ``tp`` on H, so each chip multiplies by the
-    q, k and v columns of its own heads and the result is born in the layout
-    the per-shard kernel and the row-parallel ``attn_out`` want. The stored
+    Under a traced step whose mesh divides the heads over ``tp``
+    (``attention_ops.heads_tp``) the weight is first viewed as [d, 3, H, hd]
+    and laid out over ``tp`` on H, so each of the three is a column-parallel
+    product by the q, k or v columns of a chip's own heads, born where the
+    per-shard kernels and the row-parallel ``attn_out`` read it. The stored
     leaf is sharded on 3d in contiguous parts (chip 0 of a pair holds all of q
     and half of k), so as one [d, 3d] product half of q and of v, activations,
-    cross the link in every pass of every layer; by head only the weight's
-    shards do. (The merged form over ``tp``, a column-parallel product each
-    from the same weight view, read +4.1% in ``large-solo-4chip`` with one
-    stream all-reduce fewer hidden behind the other stream's work: PERF.md,
-    Findings of PR 66. Not taken until the pair is back, so no model reaches
-    the kernels' merged call of a head of 64 PER SHARD of ``tp``
-    (``merged_in_place`` on ``heads // tp``): tests only,
-    ``test_a_head_of_64_per_shard_under_a_mesh``.)"""
+    would cross the link in every pass of every layer; off the view only the
+    weight's and the bias's shards do. With no step mesh, ``tp`` 1 or manual,
+    or heads that ``tp`` does not divide, nothing is viewed or laid out."""
     tp = attention_ops.heads_tp()
-    layout = "by_head" if tp > 1 and n_heads % tp == 0 else "merged"
     # every TRACED qkv projection off one leaf (swarm.qkv_projection, beside swarm.attention_core)
-    traced.note("qkv_projection", layout=layout, tp=tp)
+    traced.note("qkv_projection", layout="merged", tp=tp)
     dtype = compute_dtype()
     d = x.shape[-1]
-    if layout == "merged":
-        w, b, x = p["w"].astype(dtype), p["b"].astype(dtype), x.astype(dtype)
-        return layout, tuple(jnp.dot(x, w[:, s * d:(s + 1) * d]) + b[s * d:(s + 1) * d] for s in range(3))
-    hd = d // n_heads
-    w = attention_ops.constrain_in_step(
-        p["w"].astype(dtype).reshape(d, 3, n_heads, hd), P(None, None, "tp", None)
-    )
-    b = attention_ops.constrain_in_step(
-        p["b"].astype(dtype).reshape(3, n_heads, 1, hd), P(None, "tp", None, None)
-    )
-    x = x.astype(dtype)
-    # A product each, straight to [B, H, T, hd]: one einsum to [3, B, H, T, hd]
-    # costs a copy of each slice it is cut into (experiments/qkv_projection_sweep.py).
-    return layout, tuple(jnp.einsum("btd,dhe->bhte", x, w[:, s]) + b[s] for s in range(3))
+    w, b, x = p["w"].astype(dtype), p["b"].astype(dtype), x.astype(dtype)
+    if tp > 1 and n_heads % tp == 0:
+        w = attention_ops.constrain_in_step(w.reshape(d, 3, n_heads, d // n_heads), P(None, None, "tp", None))
+        b = attention_ops.constrain_in_step(b.reshape(3, n_heads, d // n_heads), P(None, "tp", None))
+        return "merged", tuple(jnp.dot(x, w[:, s].reshape(d, d)) + b[s].reshape(d) for s in range(3))
+    return "merged", tuple(jnp.dot(x, w[:, s * d:(s + 1) * d]) + b[s * d:(s + 1) * d] for s in range(3))
 
 
 def fused_qkv_attention(p: Params, x: jax.Array, n_heads: int, causal: bool = False) -> jax.Array:
     """Self-attention of the normed input [B, T, d] through a fused qkv leaf,
     [B, T, d] as the output projection reads it. q, k and v where their
-    products leave them go to ``attention_ops.attention_merged``, whose kernels
-    read and write that layout wherever the shapes allow (and which is
-    ``split_heads`` + ``attention_core`` + ``merge_heads`` wherever not); born
-    by head (``qkv_heads``: the heads divided over ``tp``), to
-    ``attention_core``."""
-    layout, (q, k, v) = qkv_heads(p, x, n_heads)
-    if layout == "by_head":
-        return attention_ops.merge_heads(attention_ops.attention_core(q, k, v, causal=causal))
+    products leave them (``qkv_heads``) go to ``attention_ops.attention_merged``,
+    whose kernels read and write that layout wherever the shapes allow, on one
+    chip and per shard of ``tp`` alike, and which is ``split_heads`` +
+    ``attention_core`` + ``merge_heads`` wherever not: that fallback is where
+    attention by head now lives for these models (a chip's heads of 64 an odd
+    number, say)."""
+    _, (q, k, v) = qkv_heads(p, x, n_heads)
     return attention_ops.attention_merged(q, k, v, n_heads, n_heads, causal=causal)
 
 

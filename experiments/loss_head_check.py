@@ -4,7 +4,7 @@ checkpointed loop under autodiff that it replaced (PR 61), on the chips.
 
     chiprun --chips 4 -- python experiments/loss_head_check.py
 
-``experiments/qkv_by_head_check.py``'s form: loss and gradients of ``--model``
+``experiments/qkv_over_tp_check.py``'s form: loss and gradients of ``--model``
 on its initial parameters and one seeded batch, traced under the step's mesh
 and compiled with the sharded step's own options, once as the tree has it
 (``models/common.lm_xent_chunked``: a ``custom_vjp``, one scan, three

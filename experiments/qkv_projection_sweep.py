@@ -3,9 +3,12 @@
 
     chiprun -- python experiments/qkv_projection_sweep.py
 
-``models/common.qkv_heads`` divides the fused projection by head over ``tp``;
-what each chip then runs is ``[B, T, d] x [d, 3, H/tp, hd]`` feeding the
-attention kernel as three ``[B, H/tp, T, hd]``. This times the forms that
+Until PR 71 ``models/common.qkv_heads`` divided the fused projection by head
+over ``tp``: each chip ran ``[B, T, d] x [d, 3, H/tp, hd]`` feeding the
+attention kernel as three ``[B, H/tp, T, hd]`` (the shipped form since is
+three merged ``[B, T, d] x [d, d/tp]`` products and the pair kernels; what
+this sweep read of by head, 47% of peak against the merged product's 90%, is
+why: PERF.md, Findings of PR 66 and 71). This times the forms the by-head
 product can take, on ONE chip at a shard's shape (``large-solo-4chip``:
 B 16, T 1,024, d 1,280, 10 of 20 heads, hd 64), inside a rematerialised,
 scanned layer (projection, kernel, merge, a row-parallel product back to d),

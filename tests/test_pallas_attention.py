@@ -796,11 +796,13 @@ def test_merged_entry_per_shard_under_a_mesh(eight_devices):
 
 @pytest.mark.parametrize("heads,heard", [(8, "merged"), (6, "heads")])
 def test_a_head_of_64_per_shard_under_a_mesh(eight_devices, heads, heard):
-    """A head of 64 under a step's dp x tp mesh: where a chip's share of the
-    heads is whole blocks of two (8 heads over tp = 2) the merged call runs per
-    shard, a chip's pairs a contiguous part of the last axis; where it is not (6
-    heads: three a chip) the entry is the by-head path, as on one chip with an
-    odd count. Loss and gradients are the unsharded call's either way."""
+    """A head of 64 under a step's dp x tp mesh, the path ``large-solo-4chip``
+    runs since PR 71 (gpt2-large's 20 heads over tp = 2: five pairs a chip):
+    where a chip's share of the heads is whole blocks of two (8 heads over
+    tp = 2) the merged call runs per shard, a chip's pairs a contiguous part of
+    the last axis; where it is not (6 heads: three a chip) the entry is the
+    by-head path, as on one chip with an odd count. Loss and gradients are the
+    unsharded call's either way."""
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 

@@ -137,13 +137,14 @@ def qkv_layouts():
         yield lambda: tel.summary()["qkv_projection"]  # what coord.status shows per peer
 
 
-@pytest.mark.parametrize("n_heads,layout", [(4, "by_head"), (3, "merged")])
+@pytest.mark.parametrize("n_heads", [4, 3])
 @pytest.mark.parametrize("model", sorted(FUSED_QKV_MODELS))
-def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_heads, layout):
-    """dp=2,tp=2: the fused qkv projection is divided by head where tp divides
-    the heads and stays the leaf's three column ranges ([B, T, d] each) where it does not; either way the loss and
-    every gradient leaf (plain SGD at lr 1: the step's change of a leaf) are
-    the single-device step's."""
+def test_qkv_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_heads):
+    """dp=2,tp=2: q, k and v are born merged ([B, T, d] each) either way, as
+    column-parallel products off the leaf's head-aligned view where tp divides
+    the heads and off the leaf's three column ranges where it does not; the
+    loss and every gradient leaf (plain SGD at lr 1: the step's change of a
+    leaf) are the single-device step's."""
     import optax
 
     bundle = get_model(model, n_heads=n_heads, remat=False, **FUSED_QKV_MODELS[model])
@@ -165,7 +166,7 @@ def test_qkv_by_head_over_tp_matches_single_device(eight_devices, qkv_layouts, m
     state, metrics = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False)(
         state, put_batch(batch, mesh)
     )
-    assert qkv_layouts() == ({"merged": 2} if layout == "merged" else {"merged": 1, "by_head": 1})
+    assert qkv_layouts() == {"merged": 2}
     # the head-aligned view lives inside the step: leaves keep their stored layout
     assert stored["blocks"]["qkv"]["w"].spec == P(None, None, "tp")
     assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
@@ -372,29 +373,96 @@ def test_sharded_multi_step_runs_the_two_stream_body(eight_devices, tp_streams):
         state.params, ref_state.params)
 
 
+# heads of 64 under dp=2, tp=2 -> (what the three products read of the leaf, the layout and the core attention_merged hands on, kernels forced)
+_QKV_OVER_TP = {
+    4: ("view", "merged", "flash"),   # two heads a chip: one 128-lane block, the pair kernels per shard
+    3: ("ranges", "heads", "xla"),    # tp does not divide the heads: nothing laid out, GSPMD's own core
+    6: ("view", "heads", "flash"),    # three heads a chip are no whole blocks: the by-head fallback, per shard
+}
+
+
+@pytest.mark.parametrize("n_heads", list(_QKV_OVER_TP))
+def test_merged_projection_over_tp_is_the_no_mesh_projection(eight_devices, n_heads):
+    """``common.qkv_heads`` and ``fused_qkv_attention`` under a step mesh of
+    dp=2, tp=2 against the same call with no mesh, float32: q, k, v, the
+    attention's output and every gradient (the leaf's weight and bias, ``x``).
+    Where tp divides the heads the three products read the leaf's head-aligned
+    view; where a chip's heads are whole blocks the pair kernels take them in
+    place, and where not ``attention_merged`` falls back to ``split_heads`` +
+    ``attention_core`` + ``merge_heads``: by head lives there and nowhere else."""
+    from jax.sharding import NamedSharding
+
+    from distributedvolunteercomputing_tpu.models import common
+    from distributedvolunteercomputing_tpu.ops.attention import set_attention_impl, step_mesh
+    from distributedvolunteercomputing_tpu.utils import traced
+
+    projection, layout, core = _QKV_OVER_TP[n_heads]
+    d = 64 * n_heads
+    mesh = make_mesh(dp=2, tp=2)
+    leaf = common.dense_init(jax.random.PRNGKey(0), d, 3 * d)
+    leaf["b"] = jax.random.normal(jax.random.PRNGKey(3), (3 * d,)) * 0.1
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 128, d))
+    cots = jax.random.normal(jax.random.PRNGKey(2), (4, 4, 128, d))
+
+    def loss(leaf, x):
+        layout, qkv = common.qkv_heads(leaf, x, n_heads)
+        assert layout == "merged"
+        out = common.fused_qkv_attention(leaf, x, n_heads, causal=True)
+        return jnp.sum(cots * jnp.stack([*qkv, out])), (qkv, out)
+
+    seen = []
+    set_attention_impl("flash")
+    try:
+        want = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(leaf, x)
+        with step_mesh(mesh), traced.subscribe(lambda kind, labels: seen.append((kind, labels))):
+            fn = jax.jit(
+                jax.value_and_grad(loss, argnums=(0, 1), has_aux=True),
+                in_shardings=({"w": NamedSharding(mesh, P(None, "tp")), "b": NamedSharding(mesh, P("tp"))},
+                              NamedSharding(mesh, P("dp"))),
+            ).trace(leaf, x)
+            got = fn.lower().compile()(leaf, x)
+    finally:
+        set_attention_impl("auto")
+    assert {labels["tp"] for kind, labels in seen if kind == "qkv_projection"} == {2}
+    assert ("sharding_constraint" in str(fn.jaxpr)) == (projection == "view")
+    assert [(labels["layout"], labels["impl"]) for kind, labels in seen if kind == "attention_core"] == [(layout, core)]
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-4), got, want)
+    assert float(jnp.abs(want[1][0]["b"]).max()) > 1e-3  # not vacuous
+
+
 def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):
     """Inside a ``shard_map`` that has made ``tp`` manual the trace sees one
-    chip's share: nothing is left to divide, the projection keeps its fused
-    leaf's three column ranges ([B, T, d] each); a manual ``pp`` (a pipeline
-    stage) leaves ``tp`` to divide, and q, k and v are born by head."""
+    chip's share: nothing is left to divide, and the projection reads the
+    fused leaf's three column ranges with no view and no constraint
+    (``tp`` noted 1); a manual ``pp`` (a pipeline stage) leaves ``tp`` to
+    divide, and the three products are column-parallel off the head-aligned
+    view (``tp`` noted 2). Merged, [B, T, d] each, both ways."""
     from distributedvolunteercomputing_tpu.models import common
-    from distributedvolunteercomputing_tpu.ops.attention import merge_heads, step_mesh
+    from distributedvolunteercomputing_tpu.ops.attention import step_mesh
     from distributedvolunteercomputing_tpu.parallel.mesh import shard_map_manual
+    from distributedvolunteercomputing_tpu.utils import traced
 
     mesh = make_mesh(dp=2, pp=2, tp=2)
     leaf = common.dense_init(jax.random.PRNGKey(0), 32, 96)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 32))
     layout, want = common.qkv_heads(leaf, x, 4)
     assert layout == "merged" and want[0].shape == (4, 8, 32)
-    for axis, layouts in (("tp", {"merged": 2}), ("pp", {"merged": 2, "by_head": 1})):
+    for n, (axis, tp) in enumerate((("tp", 1), ("pp", 2)), start=2):
         def project(x):
             layout, qkv = common.qkv_heads(leaf, x, 4)
-            return jnp.stack([merge_heads(a) if layout == "by_head" else a for a in qkv])
+            assert layout == "merged"
+            return jnp.stack(qkv)
 
-        with step_mesh(mesh):
-            got = jax.jit(shard_map_manual(project, mesh, P(), P(), axis))(x)
+        seen = []
+        with step_mesh(mesh), traced.subscribe(lambda kind, labels: seen.append((kind, labels))):
+            fn = jax.jit(shard_map_manual(project, mesh, P(), P(), axis)).trace(x)  # traced once
+            viewed = "sharding_constraint" in str(fn.jaxpr)
+            got = fn.lower().compile()(x)
         np.testing.assert_allclose(got, jnp.stack(want), rtol=1e-5, atol=1e-6)
-        assert qkv_layouts() == layouts
+        assert qkv_layouts() == {"merged": n}
+        assert {labels["tp"] for kind, labels in seen if kind == "qkv_projection"} == {tp}
+        assert viewed == (tp == 2)
 
 
 def test_sharded_step_with_accum_matches_single_device(eight_devices):
