@@ -118,8 +118,9 @@ def qkv_heads(p: Params, x: jax.Array, n_heads: int) -> Tuple[str, Tuple[jax.Arr
     weight's and the bias's shards do. With no step mesh, ``tp`` 1 or manual,
     or heads that ``tp`` does not divide, nothing is viewed or laid out."""
     tp = attention_ops.heads_tp()
-    # every TRACED qkv projection off one leaf (swarm.qkv_projection, beside swarm.attention_core)
-    traced.note("qkv_projection", layout="merged", tp=tp)
+    # every TRACED qkv projection off one leaf (swarm.qkv_projection, beside swarm.attention_core), by the
+    # chips the step's mesh could divide the heads over: the layout is "merged" wherever this runs
+    traced.note("qkv_projection", tp=tp)
     dtype = compute_dtype()
     d = x.shape[-1]
     w, b, x = p["w"].astype(dtype), p["b"].astype(dtype), x.astype(dtype)

@@ -49,6 +49,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import json
+import statistics
 import threading
 import time
 import weakref
@@ -513,8 +514,21 @@ class Tracer:
         # Finished-span hook (the watchdog's per-level round-wall feed):
         # called with each ended span's dict, exceptions swallowed.
         self.on_record: Optional[Callable[[dict], None]] = None
+        # The chip's queue as the train loop stamps it (``chip_timeline``).
+        self.chip: Optional["ChipTimeline"] = None
         if enabled:
             _LIVE_TRACERS.add(self)
+
+    def chip_timeline(self, every: int) -> Optional["ChipTimeline"]:
+        """This tracer's timeline of the chip's queue, made on the first call
+        (by the train loop, which holds a tracer and does not import this
+        module; ``every``: the steps a ``loop.steps`` span covers). None:
+        tracing is off, and nobody stamps anything."""
+        if not self.enabled:
+            return None
+        if self.chip is None:
+            self.chip = ChipTimeline(self, every)
+        return self.chip
 
     def start(self, name: str, trace: Optional[str] = None, **attrs: Any) -> Optional[Span]:
         if not self.enabled:
@@ -685,6 +699,248 @@ def self_seconds(spans: List[dict]) -> List[Optional[float]]:
     return out
 
 
+# -- the chip's queue, as the program stamps it --------------------------------
+
+# A wait of the chip becomes a ``loop.chip_wait`` span over these (every late
+# wait, however short, is in the totals). Chosen on the chip (TPU v5e, PR 72;
+# CHANGES.md has the readings): a late wait under half a millisecond is the
+# runtime's own turn-around between two programs, which no host stamp
+# resolves; a step counts as held where it took longer than the running median
+# of its own time by HELD_MIN_S and by HELD_SHARE of that median, so that the
+# undisturbed window of a cell whose step follows its router
+# (``smallthinker-solo-16k``) records none.
+LATE_SPAN_S = 0.0005
+HELD_MIN_S = 0.005
+HELD_SHARE = 0.08
+
+def _held_over(own: float) -> float:
+    """What a call must take beyond ``own``, its steps' own time, to count as held."""
+    return max(HELD_MIN_S, HELD_SHARE * own)
+
+
+# The spans that put work on the chip or its host link: what a held step may
+# have sat behind in the chip's in-order queue (``chip_waits``).
+CHIP_WORK_SPANS = (
+    "codec.run", "codec.h2d", "codec.d2h", "loop.merge", "loop.merge.h2d", "loop.launch",
+    "loop.snapshot.land",
+)
+
+
+def chip_waits(spans: List[dict]) -> List[Optional[dict]]:
+    """For each span dict, in the order given: None, or for a
+    ``loop.chip_wait`` span ``{"kind", "step", "wait_s", "during",
+    "during_s"}``. A ``late`` wait says itself during which phase of the
+    train thread the chip sat with nothing enqueued. A ``held`` wait is
+    resolved HERE, when somebody reads, and not when it was written: a span
+    still open then (a landing copy's) reaches the ring only when it ends.
+    ``during`` is the span of ``CHIP_WORK_SPANS``, on any thread of the same
+    peer, that overlaps the step's interval (from when it could start,
+    ``t0``, for ``own_s`` seconds, until it was done) most, the shorter one
+    where two overlap it equally (``loop.merge.h2d`` inside ``loop.merge``);
+    ``none`` where nothing of the program overlaps it: a stall with nothing of
+    the program in it."""
+    work = [s for s in spans if s["name"] in CHIP_WORK_SPANS and s.get("dur_s") is not None]
+    out: List[Optional[dict]] = []
+    for s in spans:
+        attrs = s.get("attrs") or {}
+        if s["name"] != "loop.chip_wait" or s.get("dur_s") is None:
+            out.append(None)
+            continue
+        said = {"kind": attrs.get("kind"), "step": attrs.get("step"), "wait_s": s["dur_s"]}
+        if attrs.get("kind") != "held":
+            out.append({**said, "during": attrs.get("during", "loop"),
+                        "during_s": float(attrs.get("during_s", 0.0))})
+            continue
+        a, b = s["t0"], s["t0"] + float(attrs.get("own_s", s["dur_s"]))
+        best = ("none", 0.0, 0.0)
+        for w in work:
+            if w.get("peer") != s.get("peer"):
+                continue
+            over = min(b, w["t0"] + w["dur_s"]) - max(a, w["t0"])
+            if over > 0 and (over, -w["dur_s"]) > (best[1], -best[2]):
+                best = (w["name"], over, w["dur_s"])
+        out.append({**said, "during": best[0], "during_s": best[1]})
+    return out
+
+
+class ChipTimeline:
+    """What follows from two stamps a step, both on one monotonic clock:
+    ``q``, when the call of the step function returned with the step
+    enqueued, and ``d``, when its outputs were ready.
+
+    - ``late = max(0, q - d_prev)``: the chip had finished the step before
+      and the next was not enqueued. The host was late; exact, no model.
+    - ``own = d - max(q, d_prev)``: from when the step could start to when it
+      was done. Its running median over the last ``MEDIAN_OVER`` steps is the
+      step's own time, and ``held = own - median`` where that is over the
+      thresholds above: the step sat in the chip's in-order queue behind
+      something else (a placement, a codec program, the merge) or ran slow.
+
+    ``d`` is a HOST stamp of a device event, and it can only be late: the
+    watcher's wake-up waits for the interpreter and, measured on the chip,
+    for a bulk transfer on the host link (a 1.4 GB snapshot leaving the chip
+    delayed it by up to 0.36 s of a 0.37 s step, the next stamp on time). So
+    a stamp is held back for ``LOOKAHEAD`` entries and corrected by what the
+    later ones prove: the chip runs its queue in order, so a step that looks
+    held was done no later than a later one less the own time (the median) of
+    each step between, and no earlier than its own time after it could start.
+    Every sum below is of corrected stamps.
+
+    The first entry (its call compiled) gives no interval and the next
+    ``WARMUP`` are left out of everything but the median. A call that ran
+    several steps (a scanned prefix) is one entry, its times divided by its
+    steps where a step's time is meant.
+
+    Fed by ONE thread, in the order the steps finish (the train loop's
+    watcher); ``summary()`` may be read from any. It writes, through its
+    tracer: a ``loop.chip_wait`` span for each wait over its threshold, a
+    ``loop.steps`` span every ``every`` steps with the stretch's totals, the
+    counter ``swarm.chip_wait_seconds_total{kind,during}`` (a held wait's
+    ``during`` is ``queue``: what it sat behind is for a reader to say,
+    ``chip_waits``) and the histogram ``swarm.step_seconds``."""
+
+    WARMUP = 8
+    MEDIAN_OVER = 32
+    LOOKAHEAD = 4
+
+    def __init__(self, tracer: "Tracer", every: int, monotonic: Callable[[], float] = time.perf_counter):
+        self._tracer = tracer
+        self._every = int(every)
+        self._monotonic = monotonic
+        self._pending: "deque[tuple]" = deque()  # entries whose ``d`` later entries may still correct
+        self._d_prev: Optional[float] = None
+        self._intervals = 0
+        self._own: "deque[float]" = deque(maxlen=self.MEDIAN_OVER)
+        self._stretch: Optional[Dict[str, Any]] = None
+        self._lock = threading.Lock()  # the totals below, which summary() reads
+        self._walls: "deque[float]" = deque(maxlen=self.MEDIAN_OVER)
+        self._totals = {"steps": 0, "late_s": 0.0, "held_s": 0.0, "covered_s": 0.0, "step_s_max": 0.0}
+        registry = tracer.registry
+        self._waits = registry.counter(
+            "swarm.chip_wait_seconds_total", "seconds the chip waited, by kind and by what the host was in"
+        ) if registry is not None else None
+        self._step_seconds = registry.histogram(
+            "swarm.step_seconds", "a step's wall time, from the step before done to this one done"
+        ) if registry is not None else None
+
+    def _on_tracer_clock(self, t: float) -> float:
+        """A monotonic stamp on the tracer's clock, by one pair taken now."""
+        return self._tracer._clock() - (self._monotonic() - t)
+
+    def step(self, step: int, steps: int, q: float, d: float, phases: Any = ()) -> None:
+        """One call of a step function: it ran ``steps`` steps, the last of
+        them ``step``; ``phases`` are the train thread's ``(name, t0, t1)``
+        since the call before, on the stamps' clock."""
+        self._pending.append((step, steps, q, d, phases))
+        if len(self._pending) > self.LOOKAHEAD:
+            self._settle()
+
+    def _settle(self) -> None:
+        """The oldest pending entry, its ``d`` corrected by the entries after it."""
+        step, steps, q, d, phases = self._pending.popleft()
+        prev = self._d_prev
+        if prev is None:
+            self._d_prev = d
+            return
+        start = max(q, prev)
+        if self._own:
+            median = statistics.median(self._own)
+            expected, over = median * steps, _held_over(median * steps)
+            if d - (start + expected) > over:
+                # Done later than its own time allows: as late as the stamp says, or as
+                # the entries after it prove at most, and no earlier than its own time.
+                proven, behind = d, 0.0
+                for _, n, _, later, _ in self._pending:
+                    behind += n * median
+                    proven = min(proven, later - behind)
+                d = max(proven, start + expected)
+        self._d_prev = d
+        self._intervals += 1
+        own = d - start
+        self._own.append(own / steps)
+        if self._intervals <= self.WARMUP:
+            return
+        late = max(0.0, q - prev)
+        held = own - expected if own - expected > over else 0.0
+        if late > 0.0:
+            during, during_s = "loop", 0.0
+            for name, t0, t1 in phases:
+                over = min(q, t1) - max(prev, t0)
+                if over > during_s:
+                    during, during_s = name, over
+            if self._waits is not None:
+                self._waits.inc(late, kind="late", during=during)
+            if late > LATE_SPAN_S:
+                self._tracer.record(
+                    "loop.chip_wait", "loop", self._on_tracer_clock(prev), late,
+                    step=step, kind="late", during=during, during_s=round(min(during_s, late), 6))
+        if held > 0.0:
+            if self._waits is not None:
+                self._waits.inc(held, kind="held", during="queue")
+            self._tracer.record(
+                "loop.chip_wait", "loop", self._on_tracer_clock(start), held,
+                step=step, kind="held", own_s=round(own, 6))
+        wall = (d - prev) / steps
+        if self._step_seconds is not None:
+            self._step_seconds.observe(wall)
+        with self._lock:
+            tot = self._totals
+            tot["steps"] += steps
+            tot["late_s"] += late
+            tot["held_s"] += held
+            tot["covered_s"] += d - prev
+            tot["step_s_max"] = max(tot["step_s_max"], wall)
+            self._walls.append(wall)
+        st = self._stretch
+        if st is None:
+            st = self._stretch = {"t0": prev, "steps": 0, "late_s": 0.0, "held_s": 0.0, "walls": []}
+        st["steps"] += steps
+        st["late_s"] += late
+        st["held_s"] += held
+        st["walls"].append(wall)
+        st["t1"], st["step"] = d, step
+        if st["steps"] >= self._every:
+            self.flush()
+
+    def flush(self) -> None:
+        """The stretch so far as a ``loop.steps`` span: from the end of the
+        step before its first to the end of its last, so that the stretches
+        of a run lie end to end and their seconds are what their waits are a
+        share of."""
+        st, self._stretch = self._stretch, None
+        if st is None:
+            return
+        self._tracer.record(
+            "loop.steps", "loop", self._on_tracer_clock(st["t0"]), st["t1"] - st["t0"],
+            step=st["step"], steps=st["steps"], late_s=round(st["late_s"], 6), held_s=round(st["held_s"], 6),
+            step_s_p50=round(statistics.median(st["walls"]), 6), step_s_max=round(max(st["walls"]), 6))
+
+    def boundary(self) -> None:
+        """A run of the loop has ended: what is pending is settled, the last
+        stretch written, and the next run's first step has no step before it
+        to wait for."""
+        while self._pending:
+            self._settle()
+        self.flush()
+        self._d_prev = None
+
+    def summary(self) -> Dict[str, Any]:
+        """The operator's numbers: steps counted, seconds the chip waited for
+        the host (``late_s``) and steps sat or ran long (``held_s``), both as
+        a share of the seconds those steps covered (``wait_share``), a step's
+        wall time as the median of the last ``MEDIAN_OVER`` and the longest
+        of the run. Empty until a step is counted."""
+        with self._lock:
+            tot, walls = dict(self._totals), list(self._walls)
+        if not tot["steps"]:
+            return {}
+        return {
+            "steps": tot["steps"], "late_s": round(tot["late_s"], 6), "held_s": round(tot["held_s"], 6),
+            "wait_share": round((tot["late_s"] + tot["held_s"]) / tot["covered_s"], 6),
+            "step_s_p50": round(statistics.median(walls), 6), "step_s_max": round(tot["step_s_max"], 6),
+        }
+
+
 # -- flight recorder ---------------------------------------------------------
 
 
@@ -789,7 +1045,7 @@ class FlightRecorder:
 # (docs/OBSERVABILITY.md, "Metric catalog", says what each means).
 TRACED_HELP: Dict[str, str] = {
     "attention_core": "traced attention calls by the core that took them",
-    "qkv_projection": "traced projections off a fused qkv leaf by how they were divided over tp",
+    "qkv_projection": "traced projections off a fused qkv leaf by the chips their heads were divided over (tp)",
     "tp_streams": "traced layer scans by the independent row streams their body runs",
     "remat_kept": "traced rematerialised layers whose checkpoint kept a kernel's or a tp sum's result",
     "moe_dispatch": "traced expert dispatches by the grouped matmul that took them",
@@ -797,13 +1053,14 @@ TRACED_HELP: Dict[str, str] = {
 # Summary key -> (kind, the labels whose values, "/"-joined, its counts are by):
 # traced attention calls per core ({"flash": n} | {"xla": n}) and by what the
 # core was handed and where the rotary turn ran ({"merged/kernel": 3,
-# "merged/none": 1}); fused qkv projections per layout ({} for a model with
-# separate q, k and v leaves); layer scans by the row streams their body runs
+# "merged/none": 1}); fused qkv projections by the ``tp`` their heads were
+# divided over ({"2": n} on a dp=2,tp=2 mesh, {"1": n} on one chip, {} for a
+# model with separate q, k and v leaves); layer scans by the row streams their body runs
 # ({"2": n} over tp, {"1": n} elsewhere, {} for a model never split).
 TRACED_SUMMARIES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "attention_core": ("attention_core", ("impl",)),
     "attention_layout": ("attention_core", ("layout", "rotary")),
-    "qkv_projection": ("qkv_projection", ("layout",)),
+    "qkv_projection": ("qkv_projection", ("tp",)),
     "tp_streams": ("tp_streams", ("streams",)),
 }
 
@@ -961,6 +1218,12 @@ class Telemetry:
                 out[key] = v
         return out
 
+    def chip(self) -> dict:
+        """The chip's queue as the train loop stamped it
+        (``ChipTimeline.summary``): ``{}`` for a process with no loop, with
+        telemetry off, and until the loop's tenth step is done."""
+        return self.tracer.chip.summary() if self.tracer.chip is not None else {}
+
     def _counts_by(self, counter: str, *labels: str) -> Dict[str, int]:
         out: Dict[str, int] = {}
         for rec in self.registry.counter(counter)._scrape()["values"]:
@@ -1068,9 +1331,10 @@ class Telemetry:
 
     def summary(self) -> dict:
         """Compact per-beat telemetry summary for the volunteer report:
-        schema version, flight-recorder high-water, and per-span
-        count/sum pairs (enough for rate + mean-latency rollups without
-        shipping buckets every heartbeat)."""
+        schema version, flight-recorder high-water, per-span count/sum pairs
+        (enough for rate + mean-latency rollups without shipping buckets
+        every heartbeat), what the traced step chose and what the chip
+        waited for."""
         spans: Dict[str, dict] = {}
         hist = self.registry.histogram("swarm.span_seconds")
         for name in self.SUMMARY_SPANS:
@@ -1089,6 +1353,8 @@ class Telemetry:
             **self.traced_summary(),
             # a sparse-expert model's dispatches and routing gauges ({} if dense)
             "moe": self.moe(),
+            # how much of its steps' wall time this volunteer's chip waited, and for what kind ({} with no loop)
+            "chip": self.chip(),
         }
 
 

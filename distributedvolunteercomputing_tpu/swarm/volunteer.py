@@ -1324,6 +1324,11 @@ class Volunteer:
             # (``attention_core``, ``attention_layout``, ``qkv_projection``,
             # ``tp_streams``, ``remat_kept``; each empty with telemetry off).
             self.summary.update(self.telemetry.traced_summary())
+            # What the chip waited, by the loop's own two stamps a step: for
+            # the host (``late_s``), with the step already in its queue
+            # (``held_s``), both as a share of the steps' wall time ({} with
+            # telemetry off).
+            self.summary["chip"] = self.telemetry.chip()
             moe = self.telemetry.moe()
             if moe:
                 # a sparse-expert model: traced dispatches by grouped matmul,

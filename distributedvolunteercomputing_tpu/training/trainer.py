@@ -34,8 +34,10 @@ import contextlib
 import json
 import functools
 import os
+import queue
 import threading
 import time
+import weakref
 from typing import Any, Callable, Dict, Iterable, Optional, Tuple
 
 import jax
@@ -74,6 +76,48 @@ ROUTE_EVERY = 10
 # Trace id (and root span's name) of the start-up tree; swarm/telemetry.py's
 # ``LIFECYCLE``, which this module does not import.
 LIFECYCLE = "lifecycle"
+
+# The thread that notes when each step's outputs are ready (``_watch_steps``),
+# and what ``run`` puts into its queue as it returns.
+WATCHER = "loop-step-done"
+_RUN_ENDED = object()
+
+
+def _watch_steps(stamps: "queue.SimpleQueue", timeline: Any, annotate: Callable) -> None:
+    """The watcher's body: for every call of a step function the train thread
+    hands over ``(step, steps, q, loss, phases, first)``, wait until ``loss``
+    (an output of that call) is ready, note ``d`` on the clock ``q`` was taken
+    on and give the pair to the timeline (swarm/telemetry.py ``ChipTimeline``),
+    which knows what follows from them. While it waits it holds the annotation
+    ``dvc:loop.step_done``, so a profiler trace has the program's ``d`` beside
+    the step program's own end. ``first`` (the trainer's first call only) ends
+    ``lifecycle.first_step`` and the start-up tree's root. It holds neither the
+    trainer nor anything of a step but its loss; None ends it (the trainer is
+    gone)."""
+    while True:
+        item = stamps.get()
+        if item is None:
+            return
+        if item is _RUN_ENDED:
+            timeline.boundary()
+            continue
+        step_no, steps, q, loss, phases, first = item
+        del item
+        try:
+            with annotate("loop.step_done"), (
+                contextlib.nullcontext() if first is None else annotate("lifecycle.first_step")
+            ):
+                jax.block_until_ready(loss)
+        except Exception as e:  # noqa: BLE001 - a step that failed fails on the train thread
+            log.debug("step %d never became ready: %s", step_no, errstr(e))
+        d = time.perf_counter()
+        del loss
+        try:
+            if first is not None:
+                first()
+            timeline.step(step_no, steps, q, d, phases)
+        except Exception as e:  # noqa: BLE001 - the timeline must never end a run
+            log.debug("chip timeline failed at step %d: %s", step_no, errstr(e))
 
 
 def _gather_noted(noted: Dict[Tuple[str, str], set], kind: str, labels: Dict[str, Any]) -> None:
@@ -216,8 +260,9 @@ class Trainer:
         # The volunteer's span tracer (swarm/telemetry.py ``Tracer``, handed
         # in so this module never imports the swarm): the phases in which
         # the train thread holds the chip up (launch, merge, snapshot, log
-        # sync) become spans and profiler annotations. None: every site is a
-        # no-op.
+        # sync) become spans and profiler annotations, and every step is
+        # stamped twice, enqueued and done, for the tracer's timeline of the
+        # chip's queue. None (or a disabled one): every site is a no-op.
         tracer: Optional[Any] = None,
         # The start-up tree's root span, where the tracer's owner has opened
         # one (``tracer.start("lifecycle", "lifecycle")``); see ``_lifecycle``.
@@ -252,11 +297,21 @@ class Trainer:
         # whoever built the tracer (the volunteer, in its constructor, so that
         # the join and the model's construction are under it) or, for a
         # trainer handed a tracer alone, here. It outlives this constructor:
-        # the first call of a step function (``_call``) takes it, and a waiter
+        # the first call of a step function (``_call``) takes it, and the watcher
         # ends it when that step's outputs are ready. None: tracing is off.
         if tracer is not None and lifecycle is None:
             lifecycle = tracer.start(LIFECYCLE, LIFECYCLE, model=bundle.name)
         self._lifecycle = lifecycle if tracer is not None else None
+        # The chip's queue as this loop sees it (an enabled tracer's
+        # ``ChipTimeline``; None: nothing below is stamped, no thread starts):
+        # ``_call`` notes when each step was enqueued and hands that to the
+        # watcher thread, which notes when it was done, with the phases this
+        # thread went through since the call before, ``(name, t0, t1)`` on the
+        # same clock (``_during``): what the chip waited in, where it waited
+        # for the host.
+        self._timeline = tracer.chip_timeline(ROUTE_EVERY) if tracer is not None else None
+        self._phases: Optional[list] = None if self._timeline is None else []
+        self._stamps: Optional["queue.SimpleQueue"] = None  # made with the watcher, by the first call
         # ``lifecycle.first_batch``: from ``run``'s entry to that first call.
         self._first_batch: Optional[Any] = None
         # Trace id of the phases opened now: "loop" for those that belong to
@@ -551,22 +606,42 @@ class Trainer:
             return contextlib.nullcontext()
         return self.tracer.phase(name, self._phase_trace, **attrs)
 
+    @contextlib.contextmanager
+    def _timed(self, name: str):
+        """``_during`` with a timeline: the body's ``(name, t0, t1)`` joins the
+        phases the next ``_call`` hands to the watcher."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            self._phases.append((name, t0, time.perf_counter()))
+
+    def _during(self, name: str):
+        """``name`` as a phase of this iteration on the timeline's clock: two
+        clock reads and a tuple, no span (nothing without a timeline)."""
+        return contextlib.nullcontext() if self._phases is None else self._timed(name)
+
     def _lifecycle_child(self, name: str, sync: bool = False, **attrs: Any):
         """A phase directly under the start-up tree's root."""
         if self._lifecycle is None:
             return contextlib.nullcontext()
         return self.tracer.child(self._lifecycle, name, sync=sync, **attrs)
 
-    def _call(self, fn: Callable, step_no: int, *args: Any) -> Any:
+    def _call(self, fn: Callable, step_no: int, steps: int, *args: Any) -> Any:
         """``fn(*args)`` for a step function (``_step_fn``, ``_grad_fn``,
-        ``_multi_fn``). The first such call of this trainer is the phase
-        ``lifecycle.step_build``: Python trace, lowering, backend compile or
-        cache load, until the call returns with the step dispatched. From
-        there ``lifecycle.first_step`` runs until the step's metrics (every
-        step function's second output, which no later call donates) are
-        ready; it and the root are ended by a waiter thread, as
-        ``loop.snapshot.land`` is by the landing thread, so this thread goes
-        on dispatching as far ahead of the chip as it did.
+        ``_multi_fn``) that runs ``steps`` steps up to ``step_no``. The first
+        such call of this trainer is the phase ``lifecycle.step_build``:
+        Python trace, lowering, backend compile or cache load, until the call
+        returns with the step dispatched. From there ``lifecycle.first_step``
+        runs until the step's loss (an output no later call donates) is ready.
+
+        With a timeline every call is stamped as it returns, the step
+        enqueued, and handed to the watcher thread (``_watch_steps``) with the
+        step's loss and this iteration's phases; the watcher notes when the
+        loss was ready and, for the first call, ends ``lifecycle.first_step``
+        and the root, as ``loop.snapshot.land`` is ended by the landing
+        thread. This thread never waits for it and goes on dispatching as far
+        ahead of the chip as it did.
 
         With or without a tracer, the first call of each step function leaves
         its arguments' shapes, dtypes and shardings with ``utils.step_scopes``
@@ -575,42 +650,51 @@ class Trainer:
         if fn.__name__ not in self._scoped:
             self._scoped.add(fn.__name__)
             step_scopes.remember(fn, args)
-        root = self._lifecycle
-        if root is None:
+        if self._timeline is None:
             return fn(*args)
-        self._lifecycle = None
-        if self._first_batch is not None:
-            self._first_batch.end()
-        began = time.time()
-        with self.tracer.child(
-            root, "lifecycle.step_build", sync=True, program=f"jit({fn.__name__})"
-        ) as sp:
+        first = None
+        root, self._lifecycle = self._lifecycle, None
+        t0 = time.perf_counter()
+        if root is None:
             out = fn(*args)
-            if sp is not None:
+        else:
+            if self._first_batch is not None:
+                self._first_batch.end()
+            began = time.time()
+            with self.tracer.child(
+                root, "lifecycle.step_build", sync=True, program=f"jit({fn.__name__})"
+            ) as sp:
+                out = fn(*args)
                 built = self._compile_log.summary(since=began, thread=threading.get_ident())
                 sp.attrs.update(
                     trace_s=built["trace_seconds"], lower_s=built["lower_seconds"],
                     backend_s=built["seconds"], cache_load_s=built["cache_load_seconds"],
                     cache="miss" if built["cache_misses"] else "hit" if built["cache_hits"] else "off",
                 )
-        first = self.tracer.start("lifecycle.first_step", root.trace, step=step_no)
-        if first is not None:
-            first.parent = root.name  # the root is never the ambient span
-        metrics = out[1]  # all the waiter holds of the step's outputs
+            span = self.tracer.start("lifecycle.first_step", root.trace, step=step_no)
+            span.parent = root.name  # the root is never the ambient span
+            chips = 1 if self.mesh is None else int(self.mesh.devices.size)
+            compile_log = self._compile_log
 
-        def wait() -> None:
-            try:
-                with self._mark("lifecycle.first_step"):
-                    jax.block_until_ready(metrics)
-            finally:
-                if first is not None:
-                    first.end()
-                root.end(
-                    chips=1 if self.mesh is None else int(self.mesh.devices.size),
-                    cold=self._compile_log.summary()["cache_misses"] > 0,
-                )
+            def first() -> None:
+                span.end()
+                root.end(chips=chips, cold=compile_log.summary()["cache_misses"] > 0)
 
-        threading.Thread(target=wait, name="lifecycle-first-step", daemon=True).start()
+        q = time.perf_counter()
+        self._phases.append(("dispatch", t0, q))
+        if self._stamps is None:
+            self._stamps = stamps = queue.SimpleQueue()
+            threading.Thread(
+                target=_watch_steps, args=(stamps, self._timeline, self.tracer.annotate),
+                name=WATCHER, daemon=True,
+            ).start()
+            weakref.finalize(self, stamps.put, None)  # the watcher ends with this trainer
+        metrics = out[1]  # all the watcher holds of the step's outputs is its loss
+        self._stamps.put((
+            step_no, steps, q, metrics["loss"] if isinstance(metrics, dict) else metrics,
+            self._phases, first,
+        ))
+        self._phases = []
         return out
 
     def _write_step_scopes(self, profile_dir: str) -> threading.Thread:
@@ -620,7 +704,7 @@ class Trainer:
         ``experiments/step_ops_in_trace.py`` joins to the trace's events. jax's
         own caches answer the lowering and the compile in milliseconds; should
         they have dropped the step, it compiles again: so a daemon thread makes
-        the map, as ``lifecycle-first-step`` ends its span."""
+        the map, as the watcher ends ``lifecycle.first_step``."""
         fn = self._step_fn if self._step_fn is not None else self._grad_fn
         program = f"jit({fn.__name__})"
 
@@ -678,7 +762,7 @@ class Trainer:
             trace = getattr(self.tracer, "PENDING", "")
         prev, self._phase_trace = self._phase_trace, trace
         try:
-            with self._phase(name) as sp:
+            with self._during(name), self._phase(name) as sp:
                 yield sp
         finally:
             self._phase_trace = prev
@@ -777,7 +861,7 @@ class Trainer:
         (construction, adoption, restore: nothing is queued on the chip, no
         step can donate, and readers must not see the tree before) reads the
         live buffers and publishes before it returns."""
-        with self._phase("loop.snapshot", step=step_no) as sp:
+        with self._during("loop.snapshot"), self._phase("loop.snapshot", step=step_no) as sp:
             if wait:
                 self._wait_landed()  # an older copy must not land on top of this
                 self._copied = None
@@ -1213,14 +1297,15 @@ class Trainer:
             if self._multi_fn is not None and not profile_dir:
                 n = self._chunk_len(start_step + ran_steps + 1, steps - ran_steps, log_every)
                 if n > 1:
-                    prefix = [next(it) for _ in range(n - 1)]
-                    stacked = jax.tree_util.tree_map(
-                        lambda *xs: jnp.stack(xs), *prefix
-                    )
+                    with self._during("data"):
+                        prefix = [next(it) for _ in range(n - 1)]
+                        stacked = jax.tree_util.tree_map(
+                            lambda *xs: jnp.stack(xs), *prefix
+                        )
                     t_chunk = time.perf_counter()
                     with self._mark("dispatch"):
                         self.state, losses = self._call(
-                            self._multi_fn, start_step + ran_steps + n - 1, self.state, stacked
+                            self._multi_fn, start_step + ran_steps + n - 1, n - 1, self.state, stacked
                         )
                     ran_steps += n - 1
                     if self.averager is not None and self.average_interval_s > 0:
@@ -1268,7 +1353,7 @@ class Trainer:
                                     break
                     else:
                         self.metrics.count_samples(self.batch_size * (n - 1))
-            with self._mark("data"):
+            with self._during("data"), self._mark("data"):
                 batch = next(it)
                 if self._put_batch is not None:
                     batch = self._put_batch(batch)
@@ -1281,7 +1366,7 @@ class Trainer:
                 # gradient is averaged before any optimizer sees it (skipping
                 # steps would let replica params drift with nothing ever
                 # re-contracting them — that's what params mode is for).
-                grads, m, next_rng = self._call(self._grad_fn, step_no, self.state, batch)
+                grads, m, next_rng = self._call(self._grad_fn, step_no, 1, self.state, batch)
                 if step_no >= avg_skip_until:
                     merged = self._run_average_round(grads, step_no, "grads")
                     if merged is not None:
@@ -1295,7 +1380,7 @@ class Trainer:
                     self._take_snapshot(step_no)
             else:
                 with self._mark("dispatch"):
-                    self.state, m = self._call(self._step_fn, step_no, self.state, batch)
+                    self.state, m = self._call(self._step_fn, step_no, 1, self.state, batch)
                 if self._inflight is not None and m_done is not None:
                     # A round is in flight: stay ONE step ahead of the chip,
                     # not a cadence. The round's codec programs and the
@@ -1307,28 +1392,31 @@ class Trainer:
                     # period varies by a cadence from run to run (measured,
                     # PR 27, TPU v5e). The step just dispatched keeps the
                     # chip busy while this waits.
-                    jax.block_until_ready(m_done["loss"])
+                    with self._during("ahead_wait"):
+                        jax.block_until_ready(m_done["loss"])
                 m_done = m
             ran_steps += 1
             at_log_point = bool(log_every) and step_no % log_every == 0
             if sync_every_step or at_log_point:
                 # A span for the log point's sync only: with a sink or a
                 # target every step syncs, and a span a step floods the ring.
-                with (
+                with self._during("loop.log_sync"), (
                     contextlib.nullcontext() if sync_every_step
                     else self._phase("loop.log_sync", step=step_no)
                 ):
                     last_loss = float(m["loss"])
                     if at_log_point:
                         self._record_spans(step_no, m)
-                self.metrics.record(step_no, m, n_samples=self.batch_size)
+                with self._during("metrics"):
+                    self.metrics.record(step_no, m, n_samples=self.batch_size)
             else:
                 self.metrics.count_samples(self.batch_size)
             if self._span_keys:
                 self._note_spans(step_no, m, at_log_point)
 
             if self.eval_every and step_no % self.eval_every == 0:
-                ev = self.evaluate()
+                with self._during("eval"):
+                    ev = self.evaluate()
                 if ev == ev:  # nan = finite dataset exhausted; nothing to record
                     self.metrics.record_event(
                         step_no, "eval",
@@ -1372,7 +1460,8 @@ class Trainer:
                 scopes_writer = self._write_step_scopes(profile_dir)
 
             if self.on_step is not None:
-                self.on_step(self, step_no)
+                with self._during("on_step"):
+                    self.on_step(self, step_no)
 
             if at_log_point:
                 log.info(
@@ -1400,6 +1489,8 @@ class Trainer:
         # Whoever reads host_snapshot() once this returns sees the last
         # boundary (or the drained merge), not one still on its way.
         self._wait_landed()
+        if self._stamps is not None:
+            self._stamps.put(_RUN_ENDED)  # the timeline writes its last stretch; nobody waits for it
         if m is not None:
             last_loss = float(m["loss"])  # sync once at the end regardless
         wall = time.monotonic() - t_start

@@ -81,10 +81,10 @@ def main() -> int:
     batch = jax.device_put(
         jax.tree_util.tree_map(lambda a: a[:, :args.seq_len], batch), batch_sharding(mesh)
     )
-    # what the traces note (utils/traced.py), one entry a trace: the qkv projection's layout; a chip's
+    # what the traces note (utils/traced.py), one entry a trace: the ``tp`` the qkv projection's heads were divided over; a chip's
     # bytes kept a step, where a layer kept something; the row streams a layer scan ran
     layouts, kept, streams = [], [], []
-    gathered = {"qkv_projection": ("layout", layouts), "remat_kept": ("bytes", kept), "tp_streams": ("streams", streams)}
+    gathered = {"qkv_projection": ("tp", layouts), "remat_kept": ("bytes", kept), "tp_streams": ("streams", streams)}
 
     def gather(kind, said):
         if kind in gathered:
@@ -180,7 +180,7 @@ def main() -> int:
         f.write(line + "\n")
     print(line)
     ok = (
-        set(traced_step) == {"merged"} and set(traced_plain) == {"merged"}
+        set(traced_step) == {mesh.shape["tp"]} and set(traced_plain) == {1}  # over tp inside the step, plain without
         and result["loss_abs_err"] <= 0.005 and result["grad_rel_err"] <= 0.04
         and result["two_streams_against_one"]["loss_abs_err"] <= 0.005
         and result["two_streams_against_one"]["grad_rel_err"] <= 0.04

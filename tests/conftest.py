@@ -306,6 +306,21 @@ _YARDSTICK_PINS = (
      "runs Ouro's manifest cases against the manifest less PR 67's entries, which no longer ends on Ouro's: PR 70's "
      "entries follow (the same five cases against the manifest less both PRs' entries, in test_yardstick_xing4.py)",
      ValueError),
+    # PR 72 (tracing: six loop.* metrics of the program's own timeline of the chip's queue appended to per_layer; no
+    # cell, no configuration, no list touched; loop.wait_share and loop.step_wall_max_over_median list the thirteen
+    # solo cells): tests/yardstick/test_yardstick_chip_wait.py runs each of these as it stands against the manifest
+    # less this PR's entries, taken off BY NAME; its own manifest test pins no position.
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics_by_name", "test_yardstick_xing4.py",
+     "asserts the exact set of per-layer metrics xing4-solo reports; loop.wait_share and "
+     "loop.step_wall_max_over_median list it now (checked in test_yardstick_chip_wait.py)"),
+    ("test_manifest_holds_the_new_configuration_cell_and_metrics_by_name", "test_yardstick_qwen3_next.py",
+     "asserts the exact set of per-layer metrics qwen3-next-solo-8k reports; loop.wait_share and "
+     "loop.step_wall_max_over_median list it now (checked in test_yardstick_chip_wait.py)"),
+    ("test_manifest_as_the_qwen3_next_tests_asserted_it_before_this_cell", "test_yardstick_xing4.py",
+     "runs Ouro's manifest cases against the manifest less PR 67's and PR 70's entries, which no longer ends on "
+     "Ouro's: PR 72's six metrics follow (the same five cases against the manifest less all three PRs' entries, in "
+     "test_yardstick_chip_wait.py)",
+     ValueError),
 )
 
 

@@ -20,7 +20,7 @@ from distributedvolunteercomputing_tpu.training.trainer import Trainer
 from distributedvolunteercomputing_tpu.utils import jaxenv
 
 TINY_GPT2 = dict(vocab=128, max_len=32, d_model=64, n_heads=4, n_layers=2, d_ff=128, remat=False)
-WAITER = "lifecycle-first-step"
+WAITER = "loop-step-done"   # training/trainer.WATCHER: the one thread that notes when steps are done
 
 
 def by_name(spans):

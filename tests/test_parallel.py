@@ -128,7 +128,7 @@ FUSED_QKV_MODELS = {
 
 @pytest.fixture
 def qkv_layouts():
-    """Counts of ``swarm.qkv_projection`` as a volunteer's telemetry takes them."""
+    """Counts of ``swarm.qkv_projection`` as a volunteer's telemetry takes them: by the ``tp`` the note carries."""
     from distributedvolunteercomputing_tpu.swarm.telemetry import Telemetry
     from distributedvolunteercomputing_tpu.utils import traced
 
@@ -158,7 +158,7 @@ def test_qkv_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_
     ref_state, ref_metrics = make_train_step(bundle.loss_fn, tx, donate=False)(
         TrainState.create(params, tx, jax.random.PRNGKey(2)), batch
     )
-    assert qkv_layouts() == {"merged": 1}  # no step mesh: one trace, q, k and v [B, T, d]
+    assert qkv_layouts() == {"1": 1}  # no step mesh: one trace, q, k and v [B, T, d]
 
     mesh = make_mesh(dp=2, tp=2)
     state, _ = shard_train_state(TrainState.create(params, tx, jax.random.PRNGKey(2)), mesh, tx)
@@ -166,7 +166,7 @@ def test_qkv_over_tp_matches_single_device(eight_devices, qkv_layouts, model, n_
     state, metrics = make_sharded_train_step(bundle.loss_fn, tx, mesh, donate=False)(
         state, put_batch(batch, mesh)
     )
-    assert qkv_layouts() == {"merged": 2}
+    assert qkv_layouts() == {"1": 1, "2": 1}  # the step's mesh has tp = 2, whether or not it divides the heads
     # the head-aligned view lives inside the step: leaves keep their stored layout
     assert stored["blocks"]["qkv"]["w"].spec == P(None, None, "tp")
     assert all(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
@@ -460,7 +460,7 @@ def test_qkv_stays_fused_where_tp_is_manual(eight_devices, qkv_layouts):
             viewed = "sharding_constraint" in str(fn.jaxpr)
             got = fn.lower().compile()(x)
         np.testing.assert_allclose(got, jnp.stack(want), rtol=1e-5, atol=1e-6)
-        assert qkv_layouts() == {"merged": n}
+        assert sum(qkv_layouts().values()) == n and qkv_layouts()[str(tp)] == (2 if tp == 1 else 1)
         assert {labels["tp"] for kind, labels in seen if kind == "qkv_projection"} == {tp}
         assert viewed == (tp == 2)
 

@@ -617,6 +617,38 @@ class TestOverheadSmoke:
         assert opened == []
 
 
+    @pytest.mark.parametrize("tracer", [None, "disabled"])
+    def test_a_trainer_without_a_live_tracer_stamps_nothing_and_starts_no_watcher(self, tracer, monkeypatch):
+        """The timeline of the chip's queue is on where the trainer has an
+        enabled tracer and off where it has none: no watcher thread, no queue,
+        no list of phases, not one read of the stamps' clock."""
+        import threading
+
+        from distributedvolunteercomputing_tpu.models import get_model
+        from distributedvolunteercomputing_tpu.training import trainer as trainer_mod
+
+        started, stamps = [], []
+        real_thread = threading.Thread
+
+        def counted(*a, **kw):
+            started.append(kw.get("name"))
+            return real_thread(*a, **kw)
+
+        monkeypatch.setattr(threading, "Thread", counted)
+        monkeypatch.setattr(T.ChipTimeline, "step", lambda self, *a: stamps.append(a))
+        monkeypatch.setattr(trainer_mod.Trainer, "_timed", lambda self, name: stamps.append(name))
+        if tracer == "disabled":
+            tracer = T.Tracer(registry=T.MetricsRegistry(), peer_id="off", enabled=False)
+        tr = trainer_mod.Trainer(get_model("mnist_mlp"), batch_size=8, optimizer="sgd", lr=1e-2,
+                                 tracer=tracer, on_step=lambda trainer, step: None)
+        tr.run(steps=12, log_every=4)
+        assert tr._timeline is None and tr._phases is None and tr._stamps is None
+        assert trainer_mod.WATCHER not in started and stamps == []
+        if tracer is not None:
+            assert tracer.chip is None and tracer.spans() == []
+            assert "swarm.step_seconds" not in tracer.registry.scrape()["metrics"]
+
+
 def _span_observations(tele) -> int:
     """Observations the span histogram holds, over every span name."""
     scraped = tele.registry.scrape()["metrics"].get("swarm.span_seconds", {})
