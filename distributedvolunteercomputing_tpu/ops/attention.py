@@ -85,10 +85,11 @@ _AUTO_FLASH_MIN_T = {"bfloat16": 512, "float32": 512}
 _AUTO_FLASH_HEAD_DIMS = (64, 128, 192, 256)
 
 # Every TRACED attention call is noted (``utils/traced.py``) as "attention_core"
-# with impl, T, D, dtype, window ("none" for full attention), kv_heads, layout
-# and rotary: a volunteer counts them (swarm.attention_core), so its summary
-# says how many of the step's attention calls took the fused core, how many of
-# those were handed the projections' own [B, T, H * D] arrays (``layout``
+# with impl, T, D, dtype, window ("none" for full attention), kv_heads, layout,
+# rotary and computed_over_band (a windowed flash call's computed pairs over
+# its band's: whether the strips engaged): a volunteer counts them
+# (swarm.attention_core), so its summary says how many of the step's
+# attention calls took the fused core, how many of those were handed the projections' own [B, T, H * D] arrays (``layout``
 # "merged": a head of whole tiles, or of 64 as half of one; "heads" is
 # [B, H, T, D]) and where the call's rotary turn ran
 # (``rotary``): "kernel" where the forward kernel turns each q block on the
@@ -104,7 +105,23 @@ _observed_rotary = "none"
 def _note_core(impl: str, t: int, d: int, dtype, window: Optional[int], kv_heads: int, layout: str,
                rotary: str) -> None:
     traced.note("attention_core", impl=impl, T=t, D=d, dtype=jnp.dtype(dtype).name,
-                window="none" if window is None else window, kv_heads=kv_heads, layout=layout, rotary=rotary)
+                window="none" if window is None else window, kv_heads=kv_heads, layout=layout, rotary=rotary,
+                computed_over_band=_computed_over_band(impl, t, d, dtype, window, rotary))
+
+
+def _computed_over_band(impl: str, t: int, d: int, dtype, window: Optional[int], rotary: str):
+    """(query, key) pairs the flash kernels' forward computes of a windowed
+    call over the pairs inside its band, to two places (``pallas_attention.
+    window_tiles`` at the call's own blocks): 2.0 where every visited tile is a
+    masked whole one a window wide, about 1 + strip / window where the tiles an
+    edge crosses run as strips. "none" for a call with no window or on the XLA
+    core."""
+    if impl != "flash" or window is None:
+        return "none"
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    tiles = pa.window_tiles(t, window, *pa.choose_blocks(t, t, d, dtype, window, rotary == "kernel"))
+    return round(tiles["fwd"] / tiles["band"], 2)
 
 
 # While remat_layer traces a body: the bytes kept of each kernel call in it

@@ -35,7 +35,17 @@ Design (speeds: PERF.md, Findings of PR 27, measured on a TPU v5e):
 - **A window** (``window=w``: query i sees keys j with i - w < j <= i) moves
   the START of the in-kernel loops as the diagonal moves their end: blocks
   wholly outside the band are never visited, blocks wholly inside run the
-  unmasked body, and only the blocks an edge crosses pay for the mask. The
+  unmasked body, and only the blocks an edge crosses pay for the mask. Where
+  the edges fall corner to corner through the tiles (square blocks that the
+  window and the sequence are whole numbers of: both cells' shapes;
+  ``strip_form``, PR 73) an edge tile is not computed whole: it runs as strips
+  of ``WINDOW_STRIP`` rows over the columns a strip keeps, and where the
+  window is ONE block (every visited tile an edge tile, two for one block's
+  worth of pairs) a strip's keys are one slab of window + strip rows of the
+  resident head, scored by one product under a plain softmax
+  (``_fwd_slabs`` / ``_bwd_slabs``). Any other windowed call (an unaligned
+  window, a padded sequence) keeps the masked whole tiles. ``window_tiles``
+  counts what either computes over the band. The
   windowed calls carry their own kernel names (``dvc_flash_win_fwd`` /
   ``dvc_flash_win_bwd``) so that a device trace tells them from the full ones.
 - **A block-diffusion mask** (``block_diffusion=bd``, ``models/sdar_moe.py``):
@@ -138,6 +148,9 @@ VMEM_BUDGET_BYTES = 64 * 1024 * 1024
 # 512 x 512 blocks (a quarter of the square skipped as masked) are within 3%
 # of each other forward + backward, and everything smaller loses to the
 # per-block cost of the running statistics; at T=2,048 blocks of 1,024 win.
+# A window keeps its blocks as wide as itself for the same reason (PR 33's
+# sweep, ``choose_blocks``) and cuts only the ROWS of a grid step into strips
+# where an edge crosses a tile (``WINDOW_STRIP``, PR 73).
 PREFERRED_BLOCK = 1024
 # The names ``_fa_fwd`` gives the kernel's output and its log-sum-exp rows:
 # what a rematerialised layer keeps of a call (models/common.remat_layer).
@@ -178,6 +191,21 @@ def vmem_bytes(tq: int, tk: int, d: int, dtype, block_q: int, block_k: int, turn
 # would visit 2n^2 + n: 0.667 of them at n = 4 (1,024 at L = 4,096), 0.588 at
 # n = 8 (512), 0.545 at n = 16 (256).
 BD_BLOCK = 512
+# The rows of a strip: where a window's edges fall corner to corner through the
+# tiles (``strip_form``), the tiles an edge crosses run as strips of so many
+# query rows (key rows in the backward) over the columns the strip keeps, so a
+# window one block wide computes (window + strip) / window of its band where
+# two whole tiles compute 2.0, and an edge tile of a wider window costs
+# (1 + strip / block) / 2 of itself. Fewer rows compute fewer pairs and run
+# shallower products (a strip's rows are what streams past each 128 x 128 tile
+# of the other operand). Measured, PR 73, TPU v5e, forward / backward ms
+# (experiments/laguna_attention_sweep.py; PERF.md, Findings of PR 73): window
+# 512 at T=8,192, 512 x 512, 64 heads over 8: whole tiles 15.71 / 22.66,
+# strips of 128 10.11 / 20.03, of 256 8.68 / 18.19, of 512 (a slab of the two
+# tiles under a plain softmax) 7.69 / 20.26; window 4,096 at T=16,384, 1,024 x
+# 1,024, 28 heads over 4: whole tiles 15.36 / 30.44, 128 15.77 / 26.81, 256
+# 14.93 / 26.84, 512 14.90 / 27.91.
+WINDOW_STRIP = 256
 
 
 def choose_blocks(
@@ -189,11 +217,15 @@ def choose_blocks(
     turns its q). With a ``window`` the blocks are no larger than the
     window where the sequence allows it (under a ``block_diffusion`` mask no
     larger than ``BD_BLOCK``): every block a query block visits is
-    then crossed by an edge (two blocks visited for one block's worth of pairs
-    inside the band), and still smaller blocks lost to the per-block cost.
+    then crossed by an edge, and still smaller BLOCKS lost to the per-block
+    cost of the running statistics and the longer grid.
     Measured, PR 33, TPU v5e, window 512 at T=8,192, 64 heads over 8, forward
-    + backward (experiments/laguna_attention_sweep.py): 512 x 512 38.4 ms,
-    256 x 512 44.2, 1,024 x 1,024 49.4, 256 x 256 53.8, 128 x 128 85.2.
+    + backward as masked whole tiles (experiments/laguna_attention_sweep.py):
+    512 x 512 38.4 ms, 256 x 512 44.2, 1,024 x 1,024 49.4, 256 x 256 53.8,
+    128 x 128 85.2. That is why the blocks stay as wide as the window; the two
+    blocks visited for one block's worth of pairs are since PR 73 not computed
+    whole: inside a grid step the rows run as strips (``WINDOW_STRIP``,
+    ``strip_form``: 25.3 ms at the same 512 x 512).
 
     Mosaic wants a block's last two dims to be multiples of the dtype's
     tile ((8, 128) f32, (16, 128) bf16) or the whole dim; the statistics'
@@ -418,6 +450,82 @@ def bd_tiles(t: int, bd: int, block_q: int, block_k: int) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# a window: the tiles the loops visit, and the strips the edge tiles run as
+# ---------------------------------------------------------------------------
+
+
+def strip_form(t: int, window: Optional[int], block_q: int, block_k: int) -> Optional[str]:
+    """How a windowed call over ``t`` rows runs the tiles an edge crosses, read
+    off its shape: ``"slab"`` or ``"edge"`` where the edges fall corner to
+    corner through the tiles (square blocks of whole strips that the window and
+    the sequence are whole numbers of, more than one window of rows), None
+    (masked whole tiles) anywhere else. ``"slab"``, the window one block: a
+    strip's keys, its part of the tile before the diagonal one and of the
+    diagonal one, are ONE slab of window + strip rows, scored by one product
+    under a plain softmax. ``"edge"``, the window several blocks: the far tile
+    and the diagonal tile each run as strips over the columns a strip keeps,
+    inside the running statistics that the whole tiles between them use."""
+    if (window is None or block_q != block_k or block_q % WINDOW_STRIP or window % block_k
+            or t % block_q or t <= window):
+        return None
+    return "slab" if window == block_k else "edge"
+
+
+def _win_fwd_bounds(iq, block_q: int, block_k: int, n_k: int, tk_valid: int, window: int, xp=jnp):
+    """The key blocks query block ``iq`` visits under a window: ``(lo, a, b,
+    end)`` for ``[lo, a)`` masked (the band's far edge: from the first block
+    that holds a key some row sees to the first every row sees all of), ``[a,
+    b)`` with no mask arithmetic and ``[b, end)`` masked (the diagonal, a ragged
+    last block). ``xp``: ``jnp`` on a traced ``iq``, ``numpy`` to count."""
+    row0, row1 = iq * block_q, (iq + 1) * block_q - 1
+    n_full = xp.minimum(tk_valid // block_k, row0 // block_k)
+    n_end = xp.minimum(n_k, row1 // block_k + 1)
+    lo = xp.maximum(row0 - window + 1, 0) // block_k
+    inside = xp.maximum(row1 - window + block_k, 0) // block_k  # ceil((row1 - w + 1) / bk)
+    a = xp.clip(inside, lo, n_end)
+    return lo, a, xp.clip(n_full, a, n_end), n_end
+
+
+def _win_bwd_bounds(ik, block_q: int, block_k: int, n_q: int, n_k: int, tk_valid: int, window: int, xp=jnp):
+    """The query blocks key block ``ik`` is visited from under a window, the
+    mirror of ``_win_fwd_bounds``: ``(first, a, b, last)`` for ``[first, a)``
+    masked (the diagonal; every block where the key block is a ragged last
+    one), ``[a, b)`` unmasked (every row sees every key of it) and ``[b,
+    last)`` masked (up to the last block that holds a row seeing its last key)."""
+    key0, key1 = ik * block_k, (ik + 1) * block_k - 1
+    first = key0 // block_q
+    n_masked_end = xp.minimum(n_q, (key1 + block_q - 1) // block_q)
+    if tk_valid % block_k:
+        n_masked_end = xp.where(ik == n_k - 1, n_q, n_masked_end)
+    last = xp.minimum(n_q, (key1 + window - 1) // block_q + 1)
+    a = xp.minimum(n_masked_end, last)
+    return first, a, xp.clip((key0 + window) // block_q, a, last), last
+
+
+def window_tiles(t: int, window: int, block_q: int, block_k: int) -> dict:
+    """Pairs (query, key) the two kernels compute over ``t`` rows under a
+    window at these blocks, counted from the kernels' own bounds and strips,
+    and pairs inside the band: ``{"fwd", "bwd", "band", "form"}``. A whole
+    tile counts block_q x block_k, a strip its rows times the columns it takes."""
+    import numpy as np
+
+    n_q, n_k = -(-t // block_q), -(-t // block_k)
+    lo, a, b, end = _win_fwd_bounds(np.arange(n_q), block_q, block_k, n_k, t, window, np)
+    first, c, d, last = _win_bwd_bounds(np.arange(n_k), block_q, block_k, n_q, n_k, t, window, np)
+    tile, form = block_q * block_k, strip_form(t, window, block_q, block_k)
+    fwd, bwd = int(np.sum(end - lo)) * tile, int(np.sum(last - first)) * tile
+    if form == "slab":  # every strip, clamped at the sequence's ends or not, takes one slab
+        fwd = bwd = t * (window + WINDOW_STRIP)
+    elif form == "edge":  # strip i of an edge tile takes (i + 1) strips' worth of columns
+        m = block_q // WINDOW_STRIP
+        saved = tile - WINDOW_STRIP * WINDOW_STRIP * m * (m + 1) // 2
+        fwd -= int(np.sum(a - lo + end - b)) * saved
+        bwd -= int(np.sum(c - first + last - d)) * saved
+    ar = np.arange(t)
+    return {"fwd": fwd, "bwd": bwd, "band": int(np.sum(np.minimum(ar + 1, window))), "form": form or "tiles"}
+
+
+# ---------------------------------------------------------------------------
 # the rotary turn ("half" layout: a frequency pairs lanes i and i + R / 2)
 # ---------------------------------------------------------------------------
 
@@ -550,15 +658,59 @@ def _kernel_name(which: str, window: Optional[int], block_diffusion: Optional[in
 # ---------------------------------------------------------------------------
 
 
+def _band(s, rel, off, window: int):
+    """The scores of a strip against its slab with NEG_INF outside the band:
+    ``rel`` a score's key index less its query index (an iota difference, made
+    once a grid step), ``off`` the first query's position less the first key's."""
+    return jnp.where((rel <= off) & (rel > off - window), s, NEG_INF)
+
+
+def _fwd_slabs(q_ref, k_ref, v_ref, cos_ref, sin_ref, o_ref, lse_ref, lse_scr, *,
+               scale, row0, block_q, window, rotary_dim):
+    """A q block under a window one block wide (``strip_form`` "slab"): each
+    strip of WINDOW_STRIP rows sees the window + strip keys that end with its
+    own last row, all of them in the resident head, so its scores are ONE
+    product over that slab and its softmax a plain one: no running maximum, no
+    rescale, and none of the two tiles' pairs outside the slab are computed. In
+    the first q block the slab starts at key 0 and the positions do the rest.
+    Each strip is a body of its own in the kernel's text (two a block), so that
+    one strip's products run beside the other's softmax: a ``fori_loop`` over
+    them read 8.68 ms where this reads 7.18 (PR 73, the sweep of
+    ``WINDOW_STRIP``'s comment), and the step's executable is 0.5 MB larger
+    for it (and 1.5 MB smaller than with the whole tiles)."""
+    strip, width = WINDOW_STRIP, window + WINDOW_STRIP
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (strip, width), 1)
+           - jax.lax.broadcasted_iota(jnp.int32, (strip, width), 0))
+    for r in range(0, block_q, strip):
+        q = q_ref[r:r + strip, :]
+        if rotary_dim is not None:  # turned on the tile, as the whole block is
+            q = _turn(q.astype(jnp.float32), cos_ref[r:r + strip, :], sin_ref[r:r + strip, :],
+                      rotary_dim).astype(q.dtype)
+        start = pl.multiple_of(jnp.maximum(row0 + r - window, 0), strip)
+        s = _band(_dot(q, k_ref[pl.ds(start, width), :], _NT) * scale, rel, row0 + r - start, window)
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        out = _dot(p.astype(v_ref.dtype), v_ref[pl.ds(start, width), :], _NN) / l
+        o_ref[r:r + strip, :] = out.astype(o_ref.dtype)
+        lse_scr[r:r + strip, :] = jnp.broadcast_to(m + jnp.log(l), (strip, LANES))
+    lse_ref[...] = jnp.transpose(lse_scr[...])[0:1, :]  # a column here, a row in HBM, as ``_fwd_kernel``'s
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *rest,
     scale, causal, block_q, block_k, tk_valid, n_k, window=None, rotary_dim=None,
-    block_diffusion=None, head_dim=None,
+    block_diffusion=None, head_dim=None, strips=None,
 ):
+    cos_ref = sin_ref = None
     if rotary_dim is not None:  # this q block's rows of the rotary tables, [bq, D] float32
         cos_ref, sin_ref, *rest = rest
     o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     iq = pl.program_id(2)
+    if strips == "slab":
+        _fwd_slabs(q_ref, k_ref, v_ref, cos_ref, sin_ref, o_ref, lse_ref, m_scr,
+                   scale=scale, row0=iq * block_q, block_q=block_q, window=window, rotary_dim=rotary_dim)
+        return
     _masked = functools.partial(
         _mask_scores, causal=causal, tk_valid=tk_valid, ragged=tk_valid % block_k != 0,
         window=window, block_diffusion=block_diffusion,
@@ -577,21 +729,35 @@ def _fwd_kernel(
             # tile, and rounded where ``ops/attention.rope`` rounds them.
             q = _turn(q.astype(jnp.float32), cos_ref[...], sin_ref[...], rotary_dim).astype(q.dtype)
 
-        def step(jk, masked: bool):
-            start = pl.multiple_of(jk * block_k, block_k)
-            kblk = k_ref[pl.ds(start, block_k), :]
-            vblk = v_ref[pl.ds(start, block_k), :]
-            s = _dot(q, kblk, _NT) * scale  # f32 [bq, bk]
+        def step(jk, masked: bool, rows=..., lo: int = 0, width: int = block_k):
+            """Key block ``jk`` into the running statistics: the whole q block against the
+            whole tile, or a strip of it (``rows``, a static slice) against the ``width``
+            columns from ``lo`` of the tile that the strip keeps. (The whole tile's
+            operations are traced as they were before there were strips: every other
+            caller's kernel keeps its text and its compile-cache key.)"""
+            whole = rows is ...
+            first = (lambda: jk * block_k) if whole else (lambda: jk * block_k + lo)
+            start = pl.multiple_of(first(), block_k if whole else WINDOW_STRIP)
+            kblk = k_ref[pl.ds(start, width), :]
+            vblk = v_ref[pl.ds(start, width), :]
+            s = _dot(q if whole else q[rows], kblk, _NT) * scale  # f32 [bq, bk]
             if masked:
-                col = jk * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                s = _masked(s, iq * block_q, col, 0)
-            m_prev = m_scr[...]  # [bq, LANES], every lane the same
+                col = first() + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                s = _masked(s, iq * block_q if whole else iq * block_q + rows.start, col, 0)
+            m_prev = m_scr[rows]  # [bq, LANES], every lane the same
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new[:, 0:1])
             corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-            m_scr[...] = m_new
-            acc_scr[...] = acc_scr[...] * corr[:, 0:1] + _dot(p.astype(vblk.dtype), vblk, _NN)
+            l_scr[rows] = l_scr[rows] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[rows] = m_new
+            acc_scr[rows] = acc_scr[rows] * corr[:, 0:1] + _dot(p.astype(vblk.dtype), vblk, _NN)
+
+        def edge(jk, far: bool):
+            """A tile a window's edge crosses corner to corner, as strips: a strip of the
+            diagonal tile keeps the tile's columns up to its own rows', one of the far
+            tile the columns from its own rows' on."""
+            for r in range(0, block_q, WINDOW_STRIP):
+                step(jk, True, slice(r, r + WINDOW_STRIP), r if far else 0, block_k - r if far else r + WINDOW_STRIP)
 
         # Blocks wholly visible (and wholly inside the valid keys) need no mask;
         # the ones the diagonal crosses, or a ragged last one, do; the rest of a
@@ -610,16 +776,10 @@ def _fwd_kernel(
             _loop(0, n_full, lambda jk: step(jk, False))
             _loop(n_full, n_end, lambda jk: step(jk, True))
         else:
-            # The band's far edge: the first block that holds a key some row of
-            # this q-block sees, then the first block every row sees all of.
-            row0, row1 = iq * block_q, (iq + 1) * block_q - 1
-            lo = jnp.maximum(row0 - window + 1, 0) // block_k
-            inside = jnp.maximum(row1 - window + block_k, 0) // block_k  # ceil((row1 - w + 1) / bk)
-            a = jnp.clip(inside, lo, n_end)
-            b = jnp.clip(n_full, a, n_end)
-            _loop(lo, a, lambda jk: step(jk, True))
+            lo, a, b, n_end = _win_fwd_bounds(iq, block_q, block_k, n_k, tk_valid, window)
+            _loop(lo, a, (lambda jk: edge(jk, True)) if strips else (lambda jk: step(jk, True)))
             _loop(a, b, lambda jk: step(jk, False))
-            _loop(b, n_end, lambda jk: step(jk, True))
+            _loop(b, n_end, (lambda jk: edge(jk, False)) if strips else (lambda jk: step(jk, True)))
 
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -684,6 +844,7 @@ def _flash_forward(
             block_q=bq, block_k=bk, tk_valid=tk, n_k=n_k, window=window,
             rotary_dim=None if tables is None else rotary_dim,
             block_diffusion=block_diffusion, head_dim=None if per == 1 else d,
+            strips=strip_form(tq, window, bq, bk),
         ),
         grid=(b, h // per, n_q),
         in_specs=[qspec, kvspec, vspec] + [spec for _, spec in turned],
@@ -744,11 +905,38 @@ def _delta_merged(do: jax.Array, out: jax.Array, h: int, bq: int, interpret: boo
     )(do, out)
 
 
+def _bwd_slabs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_scr, *,
+               scale, key0, block_k, rows, window):
+    """A key block under a window one block wide (``strip_form`` "slab"), the
+    mirror of ``_fwd_slabs``: a strip of WINDOW_STRIP keys is seen by the window
+    + strip query rows that start with its own first, all of them in the
+    resident head, so its transposed scores, dp and ds are each ONE product over
+    that slab and its dk and dv are whole after one visit (written, not
+    accumulated). The statistics come as rows of a strip, ``[rows / strip, 1,
+    strip]``: a slab's are so many of them side by side. In the last key block
+    the slab ends with the last row and the positions do the rest."""
+    strip, width = WINDOW_STRIP, window + WINDOW_STRIP
+    rel = (jax.lax.broadcasted_iota(jnp.int32, (strip, width), 0)
+           - jax.lax.broadcasted_iota(jnp.int32, (strip, width), 1))  # key less query: the scores are transposed
+    for c in range(0, block_k, strip):
+        kblk, vblk = k_ref[c:c + strip, :], v_ref[c:c + strip, :]
+        at = jnp.minimum((key0 + c) // strip, (rows - width) // strip)  # the slab's first strip of rows
+        start = pl.multiple_of(at * strip, strip)
+        qslab, doslab = q_ref[pl.ds(start, width), :], do_ref[pl.ds(start, width), :]
+        stat = lambda ref: jnp.concatenate([ref[at + j] for j in range(width // strip)], axis=1)  # noqa: E731,B023
+        s_t = _band(_dot(kblk, qslab, _NT) * scale, rel, start - key0 - c, window)
+        p_t = jnp.exp(s_t - stat(lse_ref))  # f32 [strip, width]
+        dv_ref[c:c + strip, :] = _dot(p_t.astype(doslab.dtype), doslab, _NN).astype(dv_ref.dtype)
+        ds_t = (p_t * (_dot(vblk, doslab, _NT) - stat(delta_ref))).astype(qslab.dtype)
+        dk_ref[c:c + strip, :] = (_dot(ds_t, qslab, _NN) * scale).astype(dk_ref.dtype)
+        dq_scr[pl.ds(start, width), :] += _dot(ds_t, kblk, _TN)
+
+
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
     *, scale, causal, block_q, block_k, tk_valid, n_q, n_k, window=None, block_diffusion=None,
-    head_dim=None,
+    head_dim=None, strips=None,
 ):
     ik = pl.program_id(2)
 
@@ -770,23 +958,39 @@ def _bwd_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
         kblk, vblk = take_kv()  # [bk, D]
 
-        def step(iq, masked: bool):
-            start = pl.multiple_of(iq * block_q, block_q)
-            qblk = q_ref[pl.ds(start, block_q), :]
-            doblk = do_ref[pl.ds(start, block_q), :]
+        def step(iq, masked: bool, keys=..., lo: int = 0, width: int = block_q):
+            """Query block ``iq`` into dk, dv and dq: the whole key block against the
+            whole tile, or a strip of its keys (``keys``, a static slice) against the
+            ``width`` query rows from ``lo`` of the tile that see the strip."""
+            whole = keys is ...
+            first = (lambda: iq * block_q) if whole else (lambda: iq * block_q + lo)
+            start = pl.multiple_of(first(), block_q if whole else WINDOW_STRIP)
+            qblk = q_ref[pl.ds(start, width), :]
+            doblk = do_ref[pl.ds(start, width), :]
+            kstrip, vstrip = (kblk, vblk) if whole else (kblk[keys], vblk[keys])
             # Transposed scores [bk, bq]: the statistics are rows [1, bq] and
             # broadcast along sublanes; dv and dk need no transposed operand.
-            s_t = _dot(kblk, qblk, _NT) * scale
+            s_t = _dot(kstrip, qblk, _NT) * scale
             if masked:
-                kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s_t.shape, 0)
-                s_t = _masked(s_t, iq * block_q, kpos, 1)
-            p_t = jnp.exp(s_t - row(lse_ref, iq))  # f32 [bk, bq]
-            dv_scr[...] += _dot(p_t.astype(doblk.dtype), doblk, _NN)
-            dp_t = _dot(vblk, doblk, _NT)
-            ds_t = (p_t * (dp_t - row(delta_ref, iq))).astype(qblk.dtype)
+                kpos = (ik * block_k if whole else ik * block_k + keys.start) + jax.lax.broadcasted_iota(
+                    jnp.int32, s_t.shape, 0)
+                s_t = _masked(s_t, first(), kpos, 1)
+            # a strip takes its columns of a row off the ref (a windowed block is one head)
+            stat = (lambda ref: row(ref, iq)) if whole else (lambda ref: ref[iq, :, lo:lo + width])
+            p_t = jnp.exp(s_t - stat(lse_ref))  # f32 [bk, bq]
+            dv_scr[keys] += _dot(p_t.astype(doblk.dtype), doblk, _NN)
+            dp_t = _dot(vstrip, doblk, _NT)
+            ds_t = (p_t * (dp_t - stat(delta_ref))).astype(qblk.dtype)
             # dk and dq accumulate unscaled; the scale is applied once at the end.
-            dk_scr[...] += _dot(ds_t, qblk, _NN)
-            dq_scr[pl.ds(start, block_q), :] += _dot(ds_t, kblk, _TN)
+            dk_scr[keys] += _dot(ds_t, qblk, _NN)
+            dq_scr[pl.ds(start, width), :] += _dot(ds_t, kstrip, _TN)
+
+        def edge(iq, far: bool):
+            """A tile a window's edge crosses corner to corner, as strips of keys: a strip
+            of the diagonal tile is seen by the tile's rows from its own on, one of the
+            far tile by the rows up to its own."""
+            for c in range(0, block_k, WINDOW_STRIP):
+                step(iq, True, slice(c, c + WINDOW_STRIP), 0 if far else c, c + WINDOW_STRIP if far else block_q - c)
 
         # Padded q rows need no mask: their dO and delta are zero, so they add
         # nothing. Padded keys (a ragged last block) must not receive a share of
@@ -807,18 +1011,15 @@ def _bwd_kernel(
             _loop(first, n_masked_end, lambda iq: step(iq, True))
             _loop(n_masked_end, n_q, lambda iq: step(iq, False))
         else:
-            # Queries past the band's far edge never see this k-block: the loop
-            # ends with the last q-block that holds a row seeing its last key;
-            # q-blocks whose every row sees every key of it run unmasked.
-            key0, key1 = ik * block_k, (ik + 1) * block_k - 1
-            last = jnp.minimum(n_q, (key1 + window - 1) // block_q + 1)
-            a = jnp.minimum(n_masked_end, last)
-            b = jnp.clip((key0 + window) // block_q, a, last)
-            _loop(first, a, lambda iq: step(iq, True))
+            first, a, b, last = _win_bwd_bounds(ik, block_q, block_k, n_q, n_k, tk_valid, window)
+            _loop(first, a, (lambda iq: edge(iq, False)) if strips else (lambda iq: step(iq, True)))
             _loop(a, b, lambda iq: step(iq, False))
-            _loop(b, last, lambda iq: step(iq, True))
+            _loop(b, last, (lambda iq: edge(iq, True)) if strips else (lambda iq: step(iq, True)))
 
-    if head_dim is None:  # the block is one head
+    if strips == "slab":
+        _bwd_slabs(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dq_scr,
+                   scale=scale, key0=ik * block_k, block_k=block_k, rows=n_q * block_q, window=window)
+    elif head_dim is None:  # the block is one head
         head(lambda: (k_ref[...], v_ref[...]))
         dk_ref[...] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
@@ -876,7 +1077,12 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         return i, (j if group == 1 else j // group), ik
 
     head = _head_spec(merged, tq_p, per * d, at_head)
-    rows = pl.BlockSpec((None, None if per == 1 else per, n_q, 1, bq), lambda i, j, ik: (i, j, 0, 0, 0))
+    strips = strip_form(tq, window, bq, bk)
+    stat = (n_q, 1, bq)
+    if strips == "slab":  # a slab's statistics are whole rows of a strip, side by side (``_bwd_slabs``)
+        stat = (tq_p // WINDOW_STRIP, 1, WINDOW_STRIP)
+        lse, delta = lse.reshape(b, h, *stat), delta.reshape(b, h, *stat)
+    rows = pl.BlockSpec((None, None if per == 1 else per, *stat), lambda i, j, ik: (i, j, 0, 0, 0))
     kblock, kv_in = _head_spec(merged, bk, per * d, at_block), _head_spec(merged, bk, per * d, at_kv)
     vhead, vblock, v_in = head, kblock, kv_in
     if dv != d:  # do, v and dv in blocks of the value head's width
@@ -895,7 +1101,7 @@ def _flash_backward(causal: bool, bq: int, bk: int, interpret: bool, residuals, 
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal,
             block_q=bq, block_k=bk, tk_valid=tk, n_q=n_q, n_k=n_k, window=window,
-            block_diffusion=block_diffusion, head_dim=None if per == 1 else d,
+            block_diffusion=block_diffusion, head_dim=None if per == 1 else d, strips=strips,
         ),
         grid=(b, h // per, n_k),
         in_specs=[head, kv_in, v_in, vhead, rows, rows],

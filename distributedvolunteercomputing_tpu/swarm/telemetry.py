@@ -1053,13 +1053,16 @@ TRACED_HELP: Dict[str, str] = {
 # Summary key -> (kind, the labels whose values, "/"-joined, its counts are by):
 # traced attention calls per core ({"flash": n} | {"xla": n}) and by what the
 # core was handed and where the rotary turn ran ({"merged/kernel": 3,
-# "merged/none": 1}); fused qkv projections by the ``tp`` their heads were
+# "merged/none": 1}) and by window and the pairs the kernels compute over the
+# band's ({"512/1.55": 3, "none/none": 2}: strips; "512/2.0": whole tiles);
+# fused qkv projections by the ``tp`` their heads were
 # divided over ({"2": n} on a dp=2,tp=2 mesh, {"1": n} on one chip, {} for a
 # model with separate q, k and v leaves); layer scans by the row streams their body runs
 # ({"2": n} over tp, {"1": n} elsewhere, {} for a model never split).
 TRACED_SUMMARIES: Dict[str, Tuple[str, Tuple[str, ...]]] = {
     "attention_core": ("attention_core", ("impl",)),
     "attention_layout": ("attention_core", ("layout", "rotary")),
+    "attention_band": ("attention_core", ("window", "computed_over_band")),
     "qkv_projection": ("qkv_projection", ("tp",)),
     "tp_streams": ("tp_streams", ("streams",)),
 }

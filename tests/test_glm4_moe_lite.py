@@ -333,7 +333,7 @@ def test_latent_attention_goes_through_the_core_at_one_head_dim():
         got = glm._attention(p, x, cfg)
     # D = 12 + 4 = the value head, a key head a query head
     assert seen == [("attention_core", dict(impl="xla", T=64, D=16, dtype="float32", window="none", kv_heads=4,
-                                            layout="heads", rotary="none"))]
+                                            layout="heads", rotary="none", computed_over_band="none"))]
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)
         want = x + ref._latent_attention(p, n, ref.hyper(TINY), None)

@@ -507,7 +507,7 @@ def test_per_head_qk_norm_at_64_with_groups_of_four_through_the_core():
     with traced.subscribe(lambda kind, labels: seen.append((kind, labels))):
         got = lfm2._attention(p, x, cfg)
     assert seen == [("attention_core", dict(impl="xla", T=48, D=64, dtype="float32", window="none", kv_heads=8,
-                                            layout="heads", rotary="none"))]
+                                            layout="heads", rotary="none", computed_over_band="none"))]
     hp = {"heads": 32, "n_kv": 8, "head_dim": 64, "theta": cfg.rope_theta, "eps": cfg.rms_eps}
     with jax.default_matmul_precision("highest"):
         n = ref._rmsnorm(p["ln_mixer"]["g"], x, cfg.rms_eps)

@@ -273,6 +273,137 @@ def test_kernel_matches_xla_across_blocks(dtype, tol, t, bq, bk):
         assert np.max(np.abs(a - b)) <= tol * scale, (name, np.max(np.abs(a - b)), scale)
 
 
+@pytest.fixture
+def strip_of_32(monkeypatch):
+    """Strips of 32 rows for the interpreted by-head calls below (the kernels
+    read the constant when they are traced, and ``flash_attention`` is traced a
+    call): the same bounds, slabs and clamps at an eighth of the rows. The
+    compiled strips at their real 256 are held by tests/test_tpu_compile.py."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "WINDOW_STRIP", 32)
+    return 32
+
+
+# t, window, block (in strips), query heads, key/value heads, dtype, tolerance, the form the call's shape reads
+_STRIP_CASES = {
+    "a window of one block, 8 heads over 1": (6, 2, 2, 8, 1, jnp.float32, 5e-5, "slab"),
+    "a window of one block, two blocks in all: every slab is clamped": (4, 2, 2, 2, 2, jnp.float32, 5e-5, "slab"),
+    "a window of one block of four strips in bfloat16": (12, 4, 4, 4, 2, jnp.bfloat16, 4e-2, "slab"),
+    "a block of one strip": (4, 1, 1, 2, 1, jnp.float32, 5e-5, "slab"),
+    "a window of four blocks, 4 heads over 1": (12, 8, 2, 4, 1, jnp.float32, 5e-5, "edge"),
+    "a window of two blocks in bfloat16": (12, 4, 2, 4, 2, jnp.bfloat16, 4e-2, "edge"),
+    "a window of four blocks as wide as the sequence less one": (10, 8, 2, 2, 2, jnp.float32, 5e-5, "edge"),
+    "an unaligned window keeps the whole tiles": (8, None, 2, 4, 2, jnp.float32, 5e-5, None),
+    "a padded sequence keeps the whole tiles": (None, 2, 2, 4, 2, jnp.float32, 5e-5, None),
+    "blocks that are not square keep the whole tiles": (8, 2, (2, 1), 2, 2, jnp.float32, 5e-5, None),
+    "a window as wide as the sequence keeps the whole tiles": (4, 4, 2, 2, 1, jnp.float32, 5e-5, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_STRIP_CASES))
+def test_windowed_strips_match_the_xla_core_forward_and_all_three_gradients(case, strip_of_32):
+    """Where a window's edges fall corner to corner through the tiles the two
+    kernels run the edge tiles as strips (``strip_form``: over ONE slab where
+    the window is a block, over the columns a strip keeps where it is several),
+    anywhere else as the masked whole tiles they were: the output and dq, dk
+    and dv against the XLA core either way, the first query block (its slab
+    clamped at key 0) and the last key block (clamped at the last row) among
+    them, with grouped key/value heads."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+    from distributedvolunteercomputing_tpu.ops.attention import attention_core_local
+
+    t, window, blocks, h, hkv, dtype, tol, form = _STRIP_CASES[case]
+    unit = strip_of_32
+    t = 7 * unit + 40 if t is None else t * unit                # None: a sequence that pads
+    window = unit + 72 if window is None else window * unit     # None: no whole number of strips
+    bq, bk = (b * unit for b in (blocks if isinstance(blocks, tuple) else (blocks, blocks)))
+    assert pa.strip_form(t, window, bq, bk) == form
+    q = jax.random.normal(jax.random.PRNGKey(11), (1, h, t, 32), dtype)
+    k, v = (jax.random.normal(jax.random.PRNGKey(s), (1, hkv, t, 32), dtype) for s in (12, 13))
+    cot = jax.random.normal(jax.random.PRNGKey(14), q.shape, dtype)
+
+    def run(core):
+        return jax.jit(lambda q, k, v: (lambda out, vjp: (out, *vjp(cot)))(*jax.vjp(core, q, k, v)))(q, k, v)
+
+    try:
+        set_attention_impl("xla")
+        want = run(lambda q, k, v: attention_core_local(q, k, v, True, None, window))
+    finally:
+        set_attention_impl("auto")
+    got = run(lambda q, k, v: flash_attention(q, k, v, True, bq, bk, True, window))
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = max(1.0, float(np.max(np.abs(b))))
+        assert np.max(np.abs(a - b)) <= tol * scale, (name, np.max(np.abs(a - b)), scale)
+
+
+def _pairs_by_brute_force(t, window, bq, bk, form, unit):
+    """(pairs the loops compute, pairs in the band) from the mask itself: a
+    tile is visited where it holds a kept pair; whole, or, where an edge
+    crosses it and the strips engage, a strip at a time over the columns from
+    the strip's first kept one to its last (whole strips of them); a slab is a
+    strip's kept columns over both its tiles, never fewer than the window's
+    and a strip's."""
+    i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+    keep = (j <= i) & (j > i - window)
+    computed = 0
+    for r0 in range(0, t, bq):
+        for c0 in range(0, t, bk):
+            tile = keep[r0:r0 + bq, c0:c0 + bk]
+            if not tile.any():
+                continue
+            if form is None or tile.all():
+                computed += bq * bk
+            elif form == "edge":
+                for s0 in range(0, bq, unit):
+                    cols = np.flatnonzero(tile[s0:s0 + unit].any(axis=0))
+                    computed += unit * (cols[-1] // unit - cols[0] // unit + 1) * unit
+        if form == "slab":
+            computed += bq * (window + unit)
+    return computed, int(keep.sum())
+
+
+@pytest.mark.parametrize("t,window,blocks,form", [
+    (16, 4, 4, "slab"), (24, 16, 4, "edge"), (8, None, 2, None), (12, 4, 2, "edge"),
+])
+def test_window_tiles_counts_what_the_mask_says_the_loops_compute(t, window, blocks, form, strip_of_32):
+    """``window_tiles`` (the kernels' own bounds and strips, in numpy) against a
+    count off the mask itself, forward and backward, at a window of one block,
+    of several and one that no strip can cut."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    unit = strip_of_32
+    t, window, b = t * unit, unit + 72 if window is None else window * unit, blocks * unit
+    computed, band = _pairs_by_brute_force(t, window, b, b, form, unit)
+    assert pa.window_tiles(t, window, b, b) == {"fwd": computed, "bwd": computed, "band": band, "form": form or "tiles"}
+
+
+def test_the_cells_windowed_calls_compute_little_more_than_their_band():
+    """Laguna's window of 512 at 512 x 512 and T = 8,192: two whole tiles a
+    query block computed 2.0 of the band, the strips over one slab 1 + strip /
+    window; SmallThinker's 4,096 at 1,024 x 1,024 and T = 16,384: five whole
+    tiles for four computed 1.25, its edge tiles as strips a few hundredths
+    over 1. The note of a traced call carries the ratio."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    unit = pa.WINDOW_STRIP
+    laguna, small = pa.window_tiles(8192, 512, 512, 512), pa.window_tiles(16384, 4096, 1024, 1024)
+    assert (laguna["form"], small["form"]) == ("slab", "edge")
+    assert laguna["fwd"] == laguna["bwd"] == 8192 * (512 + unit)
+    assert laguna["fwd"] / laguna["band"] == pytest.approx((512 + unit) / 512, rel=0.04)  # 1.55 at strips of 256
+    # an edge tile costs (1 + strip / block) / 2 of itself; 28 of the 70 tiles a head visits are edge tiles
+    assert small["fwd"] == small["bwd"] == (42 + 28 * (1 + unit / 1024) / 2) * 1024 * 1024
+    assert 1.0 < small["fwd"] / small["band"] < 1.1
+    whole = lambda *a: None  # noqa: E731
+    form, pa.strip_form = pa.strip_form, whole
+    try:
+        assert pa.window_tiles(8192, 512, 512, 512)["fwd"] / laguna["band"] == pytest.approx(2.0, rel=1e-3)
+        assert pa.window_tiles(16384, 4096, 1024, 1024)["fwd"] / small["band"] == pytest.approx(1.25, rel=1e-3)
+    finally:
+        pa.strip_form = form
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_kernel_at_the_chosen_geometry(causal):
     # No explicit blocks: choose_blocks' own geometry (one padded block here).
@@ -389,7 +520,9 @@ def test_trace_time_counter(enabled, want):
         assert tel.summary()["attention_core"] == cores
         rec = tel.registry.counter("swarm.attention_core")._scrape()["values"][0]
         assert rec["labels"] == {"impl": "xla", "T": "32", "D": "16", "dtype": "float32",
-                                 "window": "none", "kv_heads": "4", "layout": "heads", "rotary": "none"}
+                                 "window": "none", "kv_heads": "4", "layout": "heads", "rotary": "none",
+                                 "computed_over_band": "none"}
+        assert tel.summary()["attention_band"] == {"none/none": cores["xla"]}
         assert tel.summary()["attention_layout"] == {"heads/none": cores["xla"]}
     else:
         assert tel.summary()["attention_core"] == {}
@@ -639,6 +772,10 @@ _MERGED_CASES = {
     "a sequence that pads": (1, 200, 4, 2, 128, 128, None, _half, "merged/kernel", False),
     "a padded sequence under a window": (1, 200, 2, 2, 128, 128, 64, _yarn, "merged/kernel", False),
     "a base of its own": (1, 128, 2, 1, 128, 128, None, lambda: _half(base=1.5e6), "merged/kernel", False),
+    # a window of one block, its edges corner to corner through the tiles: strips over one slab (PR 73)
+    "a window of one block: strips over a slab, 8 heads over 1": (
+        1, 1024, 8, 1, 128, 128, 512, _half, "merged/kernel", False),
+    "a window of one block in bfloat16": (1, 1024, 2, 1, 128, 128, 512, _yarn, "merged/kernel", False, True, jnp.bfloat16),
     "a value head of 256 under keys of 128": (1, 128, 2, 1, 128, 256, None, _half, "merged/kernel", False),
     "a head of 256": (1, 128, 2, 1, 256, 256, None, _half, "merged/kernel", False),
     "a value head of 64: the by-head path": (1, 128, 2, 1, 128, 64, None, _half, "heads/outside", True),

@@ -165,13 +165,49 @@ def test_block_diffusion_fwd_bwd_compiles_at_the_cells_shape(v5e):
     assert "dvc_flash_fwd" not in text and "dvc_flash_win" not in text
 
 
+@pytest.mark.parametrize("b,t,h,hkv,window,form", [
+    (4, 8192, 64, 8, 512, "slab"),      # laguna-solo-8k's sliding layer: a window of one 512-block
+    (2, 16384, 28, 4, 4096, "edge"),    # smallthinker-solo-16k's: four 1,024-blocks, 63 of 64 MiB VMEM
+])
+def test_windowed_strips_compile_at_the_cells_shapes(v5e, b, t, h, hkv, window, form):
+    """The windowed kernels with their edge tiles as strips (PR 73), at the two
+    cells' layers, on the projections' own layout with q turned on the strip:
+    Mosaic takes the dynamic slab starts (whole strips of the resident head),
+    the backward's statistics as rows of a strip side by side and the strips'
+    static column ranges of a tile, inside the VMEM the blocks were chosen for;
+    the blocks are the ones ``choose_blocks`` gave before there were strips."""
+    from distributedvolunteercomputing_tpu.ops import pallas_attention as pa
+
+    one = SingleDeviceSharding(v5e[0])
+    d = 128
+    blocks = pa.choose_blocks(t, t, d, jnp.bfloat16, window, turned=True)
+    assert blocks == (min(window, 1024),) * 2 and pa.strip_form(t, window, *blocks) == form
+    q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, t, hkv * d), jnp.bfloat16, sharding=one)
+
+    def fwd_bwd(q, k, v):
+        cos, sin = pa.rotary_tables(t, d, 1e6)
+
+        def loss(q, k, v):
+            k = pa.rotary_merged(k, cos, sin, d, False)
+            return pa.flash_attention_merged(q, k, v, cos, sin, (h, hkv), True, window, d, False).astype(jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = _compiled_text(fwd_bwd, q, kv, kv)
+    assert "dvc_flash_win_fwd" in text and "dvc_flash_win_bwd" in text  # the names a trace tells them by
+    assert "dvc_flash_fwd" not in text and "dvc_flash_bwd" not in text
+
+
 # sha256 (16 digits) of the LOWERED forward + backward of a causal and a windowed call, by head and
 # on the merged layout with the rotary turn, for the described chip with name stacks only (no Python
 # frames: ``jax_traceback_in_locations_limit`` 0). Read at the parent of PR 60 (9422aa4) and at the
 # change by one script; a kernel PR that means to change them reads them again.
 _LOWERED_AS_BEFORE = {
     ("merged", None): "bf44953dbfd233c5", ("heads", None): "382405a0cf5b529c",
-    ("merged", 512): "7b498243f1bbf819", ("heads", 512): "dcf7cb4c87f2a2d9",
+    # the by-head windowed call is the kernels themselves: since PR 73 its window of one 512-block runs as strips
+    # over a slab (dcf7cb4c87f2a2d9 until then); the merged one here is the XLA core (eight devices, no step mesh)
+    ("merged", 512): "7b498243f1bbf819", ("heads", 512): "4d5f51bea675b593",
 }
 
 
@@ -880,13 +916,20 @@ def test_which_cells_hand_the_kernels_the_projections_own_arrays(v5e, as_on_the_
         _traced_step(v5e, model, dp, tp, batch, n_layers, **overrides)
     assert tel.traced_summary()["attention_layout"] == want
     assert tel.traced_summary()["attention_core"] == {"flash": sum(want.values())}
+    # a windowed call's pairs computed over its band's (PR 73): Laguna's window of one block as strips over one
+    # slab (2.0 as two whole tiles), SmallThinker's four blocks with their edge tiles as strips (1.25 as five)
+    band = {"laguna-solo-8k": {"512/1.55": 3, "none/none": 2}, "smallthinker-solo-16k": {"4096/1.06": 1, "none/none": 1}}
+    assert tel.traced_summary()["attention_band"] == band.get(cell, {"none/none": sum(want.values())})
 
 
 # sha256 (16 digits) of each OTHER cell's lowered step (its kernels' serialised modules included), as the
 # chip would trace it, name stacks only. Read at the parent of PR 66 (da43337, ``git archive`` with this file copied
 # over it) and at the change: a PR that means to change one of these programs reads them again at its parent.
 _LOWERED_AS_AT_THE_PARENT = {
-    "olmoe-solo": "5b9dc2fbe8b901cf", "laguna-solo-8k": "81c4c0af1909080a", "smallthinker-solo-16k": "cfbe8ccf289f955e",
+    "olmoe-solo": "5b9dc2fbe8b901cf",
+    # the two cells with a window, the programs PR 73 means to change (read again at that change; at its parent 13642af
+    # they read 81c4c0af1909080a and cfbe8ccf289f955e): their windowed kernels run the edge tiles as strips
+    "laguna-solo-8k": "3577b463ed3e94e8", "smallthinker-solo-16k": "1fdee51d3596d911",
     "lfm2-solo-8k": "ad3a2efc0d42feb8", "glm47-flash-solo-8k": "bf05df60b5edbf58", "nemotron3-nano-solo-8k": "e9d8feb1a8c3196f",
     "kimi-linear-solo-8k": "d71a310a813ced9a", "sdar-solo-4k": "440bade2e2273aa6", "ouro-solo-4k": "a00909f76052a41a",
     # and the four-chip step, the one program PR 71 means to change (read again at that change; at its parent 015a255 it
@@ -907,7 +950,11 @@ def test_the_other_cells_lower_to_the_text_the_parent_lowers(v5e, as_on_the_chip
     ``medium-round``'s, the same step), and with it its compile-cache key and
     its ``tok_s_chip``, was the parent's. PR 71 gives the step whose mesh
     divides GPT-2's heads over ``tp`` the merged projection and entry too: the
-    four-chip step's hash is read again there, the nine others are untouched."""
+    four-chip step's hash is read again there, the nine others are untouched.
+    PR 73 changes what a WINDOWED call's kernels compute: Laguna's and
+    SmallThinker's hashes are read again there, and the nine cells that run no
+    window keep the text they had (``experiments/step_hlo_hash.py`` says the
+    same of the CPU's lowering, where no kernel is: thirteen equal hashes)."""
     import hashlib
 
     from distributedvolunteercomputing_tpu.ops import moe_dispatch

@@ -44,7 +44,9 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     holds no array by head and no float32 copy of a merged one (the rotary
     halves, the head transposes and the float32 query products are gone), the
     gate reaches its heads through a 0/1 product, and the temporaries fall to
-    6.870e9."""
+    6.870e9. Since PR 73 the windowed kernels run their edge tiles as strips
+    over one slab (same names, same blocks, one kernel each way a layer), and
+    the executable is no larger for it."""
     import jax.numpy as jnp
 
     from distributedvolunteercomputing_tpu.ops import moe_dispatch, pallas_attention
@@ -86,3 +88,6 @@ def test_laguna_step_holds_the_windowed_and_the_full_kernel(v5e, as_on_the_chip,
     # a layer fewer and the same buffers alive: the heap packs 1.5 MB worse)
     # and 6.870e9 since PR 59 (the float32 [4,8192,8192] products and the by-head copies are gone)
     assert mem.temp_size_in_bytes <= 6.90e9, mem.temp_size_in_bytes
+    # the executable: 272.82e6 with the windowed kernels' whole tiles, 271.31e6 since PR 73 with a window of one
+    # block as two strips a grid step, each a body of its own (270.79e6 as a loop over them): start-up reads it
+    assert mem.generated_code_size_in_bytes <= 275e6, mem.generated_code_size_in_bytes
